@@ -33,8 +33,6 @@ Public surface (mirrors the reference component inventory, see SURVEY.md §2):
 
 __version__ = "0.1.0"
 
-import distributed_sigmoid_loss_tpu._jax_compat  # noqa: F401  (installs jax shims first)
-
 from distributed_sigmoid_loss_tpu.ops.sigmoid_loss import (  # noqa: F401
     init_loss_params,
     pairwise_logits,

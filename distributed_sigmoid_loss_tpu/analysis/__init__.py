@@ -16,8 +16,8 @@ Three halves, one Finding stream:
   product (the lattice source for the traced sample), and a drift check
   probing every config through the real imperative refusal layers.
 - :mod:`.repo_lint` is an AST pass over the package + bench.py enforcing
-  repo invariants (trace-time mutable globals, bench compile-shield
-  coverage, doc staleness, slow markers, bench record schema).
+  repo invariants (trace-time mutable globals, doc staleness, slow markers,
+  bench record schema).
 - :mod:`.lock_flow` ("graftguard") is the concurrency half: guarded-by
   inference over every lock-owning class (unguarded writes, un-looped
   ``Condition.wait``, blocking calls under a lock, orphan threads), the

@@ -1,20 +1,18 @@
 """THE declared schema for bench.py's JSON record fields.
 
 Every bench mode (train headline, eval-throughput, context, step/MoE
-breakdowns, backend-error and shield-deferral records) emits one-line JSON
-records that downstream per-metric streams parse. Before this schema each
+breakdowns) emits one-line JSON records that downstream per-metric streams
+parse. Before this schema each
 emit path grew fields independently, so a new config knob (quant_train,
 loss_impl, ring_overlap, ...) could land in one path and silently drift from
-the others — the exact per-path divergence the bench shield's ADVICE round-5
-findings came from.
+the others.
 
 One registry, three consumers:
 
 - ``bench.py`` routes every record through ``_emit`` → :func:`validate_record`
   (stderr warning on violation; the record still prints — a measurement must
   never be lost to its own validator).
-- ``tests/test_bench_shield.py`` / ``tests/test_analysis.py`` assert example
-  records from each emit path validate.
+- ``tests/test_analysis.py`` asserts example records validate.
 - ``analysis/repo_lint.py`` statically cross-checks every record-field string
   literal in bench.py against this registry (rule ``repo-bench-record``), so
   an unregistered field fails tier-1 before it ever runs on a chip.
@@ -80,8 +78,6 @@ BENCH_RECORD_FIELDS = frozenset(
         # moe breakdown
         "dense_mlp_ms", "stages", "tokens", "experts", "num_selected",
         "group", "capacity",
-        # shield deferral records
-        "deferred", "signal", "child_pid", "child_stdout", "child_stderr",
         # data-bench (stage + composed-pipeline records, data/data_bench.py)
         "stage", "data_workers", "native_decode", "worker_scaling",
         "synthetic_pairs_per_sec", "synthetic_ratio", "input_wait_frac",

@@ -8,11 +8,6 @@ review:
   traced behavior must be allowlisted WITH a rationale naming its traced-choice
   recorder (the ``_DEFAULT_BATCH_HEADS`` bench-record-corruption class —
   ops/pallas_short_attention.py, ADVICE round 5).
-- ``repo-bench-shield``: every bench.py flag must be classified — either read
-  by ``_fresh_compile_config`` (shield trigger) or listed in
-  ``_SHIELD_EXEMPT_FLAGS`` with a rationale. Cross-checked against bench.py's
-  ACTUAL argparse tree, not a hand-copied list (the --gradcache-bf16 class:
-  a compile-changing flag that bypassed the shield, ADVICE round 5).
 - ``repo-doc-stale``: every CLI flag and LossConfig field must appear in
   README.md or docs/ (a flag nobody can discover is a flag nobody A/Bs).
 - ``repo-slow-marker``: the registered multi-minute suites must carry the
@@ -27,8 +22,7 @@ review:
 - ``repo-ledger-emit``: bench.py's record prints (``print(json.dumps(...))``)
   may happen ONLY inside ``_emit``, and ``_emit`` must append to the run
   ledger (``obs/ledger.py append_record``) — a new emit path that prints its
-  own JSON bypasses both the schema validator and the perf trajectory, the
-  blind-spot class rounds 4/5 recorded 0.0 into.
+  own JSON bypasses both the schema validator and the perf trajectory.
 - ``repo-chaos-gate``: every fault-injection point in serve/ must be a
   ``maybe_inject("<point>")`` call whose point is a string constant
   registered in ``serve/siege.py CHAOS_POINTS`` with a non-empty rationale,
@@ -52,7 +46,6 @@ __all__ = [
     "REPO_RULES",
     "run_repo_lint",
     "check_mutable_globals",
-    "check_bench_shield",
     "check_doc_staleness",
     "check_slow_markers",
     "check_bench_record_fields",
@@ -66,7 +59,6 @@ __all__ = [
 
 REPO_RULES = (
     "repo-mutable-global",
-    "repo-bench-shield",
     "repo-doc-stale",
     "repo-slow-marker",
     "repo-bench-record",
@@ -312,27 +304,6 @@ def check_mutable_globals(
     return findings
 
 
-def _argparse_dests(tree: ast.Module) -> dict[str, int]:
-    """dest -> lineno for every add_argument call in the module."""
-    dests: dict[str, int] = {}
-    for node in ast.walk(tree):
-        if not (
-            isinstance(node, ast.Call)
-            and isinstance(node.func, ast.Attribute)
-            and node.func.attr == "add_argument"
-            and node.args
-        ):
-            continue
-        first = node.args[0]
-        if not (isinstance(first, ast.Constant) and isinstance(first.value, str)):
-            continue
-        flag = first.value
-        dest = flag[2:].replace("-", "_") if flag.startswith("--") else flag
-        if dest:
-            dests.setdefault(dest, node.lineno)
-    return dests
-
-
 def _argparse_flags(tree: ast.Module) -> dict[str, int]:
     """'--flag' -> lineno for every OPTIONAL add_argument in the module."""
     flags: dict[str, int] = {}
@@ -352,81 +323,6 @@ def _argparse_flags(tree: ast.Module) -> dict[str, int]:
         ):
             flags.setdefault(first.value, node.lineno)
     return flags
-
-
-def _attr_reads_of(tree: ast.Module, func_name: str, obj: str = "args") -> set[str]:
-    for node in ast.walk(tree):
-        if isinstance(node, ast.FunctionDef) and node.name == func_name:
-            return {
-                n.attr
-                for n in ast.walk(node)
-                if isinstance(n, ast.Attribute)
-                and isinstance(n.value, ast.Name)
-                and n.value.id == obj
-            }
-    return set()
-
-
-def _module_dict_keys(tree: ast.Module, var_name: str) -> set[str]:
-    for node in tree.body:
-        if (
-            isinstance(node, ast.Assign)
-            and len(node.targets) == 1
-            and isinstance(node.targets[0], ast.Name)
-            and node.targets[0].id == var_name
-            and isinstance(node.value, ast.Dict)
-        ):
-            return {
-                k.value
-                for k in node.value.keys
-                if isinstance(k, ast.Constant) and isinstance(k.value, str)
-            }
-    return set()
-
-
-def check_bench_shield(bench_source: str | None = None) -> list[Finding]:
-    """repo-bench-shield: every bench flag classified as shield-trigger or
-    exempt-with-rationale — enumerated from the REAL argparse tree."""
-    if bench_source is None:
-        with open(os.path.join(_REPO_ROOT, "bench.py"), encoding="utf-8") as f:
-            bench_source = f.read()
-    tree = ast.parse(bench_source)
-    dests = _argparse_dests(tree)
-    reads = _attr_reads_of(tree, "_fresh_compile_config")
-    exempt = _module_dict_keys(tree, "_SHIELD_EXEMPT_FLAGS")
-    findings = []
-    if not reads:
-        findings.append(Finding(
-            "repo-bench-shield", "bench.py::_fresh_compile_config",
-            "no _fresh_compile_config function found (or it reads no args) — "
-            "the compile shield has no trigger set",
-        ))
-    for dest, line in sorted(dests.items()):
-        if dest not in reads and dest not in exempt:
-            findings.append(Finding(
-                "repo-bench-shield",
-                f"bench.py::{dest}",
-                f"flag --{dest.replace('_', '-')} (line {line}) is neither "
-                "read by _fresh_compile_config nor listed in "
-                "_SHIELD_EXEMPT_FLAGS: a config-changing flag outside the "
-                "shield runs fresh XLA compiles unprotected (the "
-                "--gradcache-bf16 ADVICE class). Classify it.",
-            ))
-    for dest in sorted(exempt - set(dests)):
-        findings.append(Finding(
-            "repo-bench-shield",
-            f"bench.py::{dest}",
-            "_SHIELD_EXEMPT_FLAGS names a flag that is not in the argparse "
-            "tree — stale exemption; drop it",
-        ))
-    for dest in sorted(exempt & reads):
-        findings.append(Finding(
-            "repo-bench-shield",
-            f"bench.py::{dest}",
-            "flag is BOTH a _fresh_compile_config trigger and exempt — "
-            "contradictory classification; pick one",
-        ))
-    return findings
 
 
 def check_doc_staleness(
@@ -1013,7 +909,6 @@ def run_repo_lint(disabled=()) -> list[Finding]:
     """Run every repo rule against the real tree."""
     checks = {
         "repo-mutable-global": check_mutable_globals,
-        "repo-bench-shield": check_bench_shield,
         "repo-doc-stale": check_doc_staleness,
         "repo-slow-marker": check_slow_markers,
         "repo-bench-record": check_bench_record_fields,

@@ -571,8 +571,10 @@ def cmd_train(args) -> int:
         print(mesh_err, file=sys.stderr)
         return 2
     pidx, pcnt = jax.process_index(), jax.process_count()
+    dev0 = jax.devices()[0]
     print(
-        f"mesh: {dict(mesh.shape)} devices={len(jax.devices())}"
+        f"mesh: {dict(mesh.shape)} devices={len(jax.devices())} "
+        f"platform={dev0.platform} device_kind={dev0.device_kind!r}"
         + (f" process {pidx}/{pcnt}" if pcnt > 1 else ""),
         file=sys.stderr,
     )
@@ -842,6 +844,7 @@ def cmd_train(args) -> int:
             print(f"--grad-compression with --pp {args.pp}: {e}",
                   file=sys.stderr)
             return 2
+        jit_step = step_fn  # what static attribution traces (no host wrapper)
         if args.grad_compression in ("adaptive", "learned"):
             # Host-side bit controller around the jitted step: stage the
             # scheme table (a value change of a donated replicated operand —
@@ -910,13 +913,11 @@ def cmd_train(args) -> int:
                 # bytes/param, measured through the SAME pipe so the ratio is
                 # wire time vs wire time, not model vs measurement.
                 bf16_ref_bytes = (n_dcn - 1) * 2 * int(sum(controller_sizes))
-            compiled_step = step_fn
-
             def step_fn(st, batch):
                 nonlocal bf16_ref_dt
                 st = stage_scheme(st, controller.scheme, mesh)
                 t0 = _time.perf_counter()
-                st, metrics = compiled_step(st, batch)
+                st, metrics = jit_step(st, batch)
                 wire = float(metrics["dcn_wire_bytes"])  # blocks on the step
                 step_dt = _time.perf_counter() - t0
                 metrics = dict(metrics)
@@ -981,6 +982,7 @@ def cmd_train(args) -> int:
             moe_aux_weight=moe_aux_w,
             pp_microbatches=pp_micro,
         )
+        jit_step = step_fn
 
     # graftscope wiring: schema-validated metrics lines, host spans (enabled
     # only under --obs-dir — disabled spans are the allocation-free no-op),
@@ -1014,31 +1016,26 @@ def cmd_train(args) -> int:
     )
 
     # Static attribution of THE step that will run (obs/attribution.py):
-    # trace-only — seconds, no compile, chip-free — so every metrics line
-    # carries mfu_est + comm_bytes_total even when no chip ever materializes.
-    att_fields = {}
-    try:
-        from distributed_sigmoid_loss_tpu.obs.attribution import (
-            metrics_line_fields,
-            static_attribution,
-        )
+    # trace-only — seconds, no compile — so every metrics line carries the
+    # step's comm_bytes_total, plus mfu_est when the run's device is a chip
+    # the peaks table lists (a CPU run carries no utilization figure).
+    from distributed_sigmoid_loss_tpu.obs.attribution import (
+        metrics_line_fields,
+        static_attribution,
+    )
 
-        abstract_batch = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), first
-        )
-        att_fields = metrics_line_fields(
-            static_attribution(step_fn, state, abstract_batch),
-            device_kind=jax.devices()[0].device_kind,
-        )
-        print(
-            "obs attribution: "
-            + " ".join(f"{k}={v}" for k, v in sorted(att_fields.items())),
-            file=sys.stderr,
-        )
-    except Exception as e:  # noqa: BLE001 — attribution must never kill a run
-        print(f"WARNING: static attribution failed ({type(e).__name__}: {e}); "
-              "metrics lines will not carry mfu_est/comm_bytes_total",
-              file=sys.stderr)
+    abstract_batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), first
+    )
+    att_fields = metrics_line_fields(
+        static_attribution(jit_step, state, abstract_batch),
+        device_kind=dev0.device_kind,
+    )
+    print(
+        "obs attribution: "
+        + " ".join(f"{k}={v}" for k, v in sorted(att_fields.items())),
+        file=sys.stderr,
+    )
 
     # graftshard placement fields on every metrics line: the mode plus the
     # measured at-rest optimizer bytes per replica (compiler accounting, the
@@ -1173,6 +1170,14 @@ def cmd_train(args) -> int:
         logger.log(step_i, line)
         write_telemetry(step_i, line)
 
+    from distributed_sigmoid_loss_tpu.parallel.mesh import trace_on
+
+    def embed(params, images, tokens):
+        # Traced on the mesh: on several chips the towers' fused attention
+        # kernels must know how their operands are sharded.
+        with trace_on(mesh):
+            return model.apply({"params": params}, images, tokens)[:2]
+
     eval_hook = None
     if args.eval_every:
         from distributed_sigmoid_loss_tpu.eval import retrieval_metrics as _rm
@@ -1222,9 +1227,7 @@ def cmd_train(args) -> int:
             eval_batch = place(first)
         # Jitted once: the hook runs repeatedly inside the train loop, where
         # an eager per-op forward would dominate wall time on real models.
-        eval_fwd = jax.jit(
-            lambda p, im, tk: model.apply({"params": p}, im, tk)[:2]
-        )
+        eval_fwd = jax.jit(embed)
 
         def eval_hook(step_i, st):
             zi, zt = eval_fwd(
@@ -1331,8 +1334,10 @@ def cmd_train(args) -> int:
     from distributed_sigmoid_loss_tpu.eval import retrieval_metrics
 
     held_out = place(next(iter(data)))
-    zimg, ztxt, _ = model.apply(
-        {"params": state.params}, held_out["images"], held_out["tokens"]
+    # Jitted: an eager forward is one tiny compile per op of both full-width
+    # towers — minutes on a cold machine for a closing sanity print.
+    zimg, ztxt = jax.jit(embed)(
+        state.params, held_out["images"], held_out["tokens"]
     )
     rm = retrieval_metrics(zimg, ztxt, mesh=mesh, ks=(1, 5))
     print({k: round(float(v), 4) for k, v in rm.items()}, file=sys.stderr)
@@ -2537,6 +2542,11 @@ def cmd_tokenizer(args) -> int:
 
 
 def main(argv=None) -> int:
+    from distributed_sigmoid_loss_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser(
         prog="distributed_sigmoid_loss_tpu", description=__doc__
     )

@@ -14,6 +14,7 @@ end-to-end target adds ViT-B/16 + text transformer. This core is built TPU-first
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any
 
 import flax.linen as nn
@@ -23,6 +24,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 # Mesh axis name used by tensor-parallel kernel annotations (parallel/mesh.py).
 TP_AXIS = "tp"
+# Mesh axis the batch is sharded over (parallel/mesh.py data_axis).
+DP_AXIS = "dp"
 
 
 def _dtype(name: str):
@@ -43,6 +46,40 @@ def _dot_general(quant):
     if quant == "int8_ste":
         return int8_dot_general_ste
     return int8_dot_general
+
+
+def _fused_attention_per_shard(kernel, q, k, v):
+    """Run a fused (Mosaic) attention ``kernel`` on (b, s, h, dh) arrays that a
+    surrounding ``jit`` may have sharded over several chips.
+
+    A Mosaic kernel is opaque to the SPMD partitioner: where the trace's mesh
+    (``jax.sharding.get_abstract_mesh()`` — the step builders trace under
+    ``parallel.mesh.trace_on(mesh)``) has automatically partitioned axes
+    spanning more than one device, lowering the bare call raises "Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call in a
+    shard_map" — the first thing the dp=4 train step did on a real four-chip
+    host. Attention is independent per (row, head), so the split is exact:
+    rows over ``dp``, heads over ``tp``, where those axes exist and divide;
+    over every other axis the call is replicated, as the partitioner would
+    have left it. On one device, with no mesh in the trace, or where every
+    axis is already manual (inside the pp / compressed steps' ``shard_map``)
+    the kernel is called directly.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = frozenset(mesh.axis_names) - frozenset(mesh.manual_axes)
+    if not auto or mesh.size == 1:
+        return kernel(q, k, v)
+
+    def split(axis, n):
+        return axis if axis in auto and n % mesh.shape[axis] == 0 else None
+
+    spec = P(split(DP_AXIS, q.shape[0]), None, split(TP_AXIS, q.shape[2]), None)
+    return jax.shard_map(
+        kernel, in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names=auto, check_vma=False,
+    )(q, k, v)
 
 
 def _remat_policy(name: str):
@@ -149,8 +186,6 @@ class Attention(nn.Module):
         if self.sp_axis is not None and is_self_attention:
             # Sequence-parallel exact attention: manual over sp only, GSPMD keeps
             # handling any other mesh axes (dp/tp) automatically.
-            from functools import partial
-
             from jax.sharding import PartitionSpec as P
 
             from distributed_sigmoid_loss_tpu.parallel.ring_attention import (
@@ -213,14 +248,19 @@ class Attention(nn.Module):
                 and self.dtype == jnp.bfloat16
                 and flash_attention_available()
             )
-            if use_fused and short_attention_fits(
+            if not use_fused:
+                kernel = dense_attention
+            elif short_attention_fits(
                 q.shape[1], self.width, jnp.dtype(self.dtype).itemsize
             ):
-                out = short_self_attention(q, k, v, self.causal)
-            elif use_fused:
-                out = flash_self_attention(q, k, v, causal=self.causal)
+                kernel = short_self_attention
             else:
-                out = dense_attention(q, k, v, causal=self.causal)
+                kernel = flash_self_attention
+            attend = partial(kernel, causal=self.causal)
+            out = (
+                _fused_attention_per_shard(attend, q, k, v) if use_fused
+                else attend(q, k, v)
+            )
             out = out.astype(self.dtype)
         # Named for the "save_hot" remat policy: with the core output saved, the
         # backward pass needs only q/k/v (for the attention VJP) — the s² core

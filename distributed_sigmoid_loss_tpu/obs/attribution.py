@@ -1,9 +1,8 @@
 """Static step attribution: FLOPs, bytes and collective traffic from the
 PROGRAM, not the chip.
 
-The perf stream has been blind whenever the backend was (BENCH_r04/r05
-recorded 0.0): a number could only be attributed when a chip run succeeded.
-This module derives the attribution STATICALLY, two ways:
+A measured number says how fast; this module says what the program asked the
+chip to do, derived STATICALLY (no chip needed), two ways:
 
 - :func:`jaxpr_costs` / :func:`static_attribution` walk a traced jaxpr (the
   same trace-only harness graftlint's auditor uses — seconds, no compile) and
@@ -35,10 +34,11 @@ all_to_all          ``s·(W-1)/W``            every device keeps 1/W locally
 accessed) into a chip-free roofline: per-term times against a target chip's
 peak MXU rate / HBM bandwidth / ICI bandwidth, ``mfu_est`` = the MFU the
 config cannot exceed on that chip, and ``bound`` naming the limiting
-resource. ``device_kind`` defaults to the repo's target chip (v5e) so the
-estimate exists on CPU-only hosts — that is the point: the next driver-
-verified number arrives with its attribution already pinned, and until it
-does, every train metrics line and bench record carries the estimate.
+resource. ``device_kind=None`` asks for the repo's target chip (v5e) by name
+— the chip-free what-if ``obs regress`` runs on CPU hosts. An ACTUAL device
+kind must be in :data:`CHIP_SPECS`: a device the table does not know raises,
+so a CPU run never carries a v5e ``mfu_est`` (callers on an unlisted device
+omit the field).
 
 ``bytes_est`` (trace-only) sums operand+result bytes per equation with scan
 multipliers — a fusion-ignorant UPPER bound on HBM traffic, reported but
@@ -76,8 +76,8 @@ CHIP_SPECS = {
     "TPU v6e": (918.0, 1640.0, 400.0),
 }
 
-# The repo's roofline target (VERDICT r5 / docs/PERF.md argue against it):
-# estimates on chip-less hosts are computed for this part.
+# The repo's roofline target: what ``device_kind=None`` (the what-if asked
+# for by name) is computed against.
 DEFAULT_CHIP = "TPU v5 lite"
 
 COLLECTIVE_KINDS = (
@@ -369,8 +369,18 @@ def roofline_estimate(
     target chip, the limiting resource, and ``mfu_est`` — the MFU ceiling the
     program's arithmetic/traffic ratio permits there. ``mfu_est`` is an
     upper bound on achievable MFU, not a prediction of the measured one
-    (overlap, dispatch and kernel overheads only lower it further)."""
-    kind = device_kind if device_kind in CHIP_SPECS else DEFAULT_CHIP
+    (overlap, dispatch and kernel overheads only lower it further).
+
+    ``device_kind=None`` is the what-if against :data:`DEFAULT_CHIP`; a named
+    device outside :data:`CHIP_SPECS` raises — an unknown device is an error,
+    not a v5e."""
+    kind = DEFAULT_CHIP if device_kind is None else device_kind
+    if kind not in CHIP_SPECS:
+        raise ValueError(
+            f"no roofline peaks for device_kind {kind!r} (known: "
+            f"{sorted(CHIP_SPECS)}); pass device_kind=None for the "
+            f"{DEFAULT_CHIP} what-if"
+        )
     tflops, hbm_gbps, ici_gbps = CHIP_SPECS[kind]
     compute_s = flops / (tflops * 1e12)
     comm_s = comm_bytes_total / (ici_gbps * 1e9)
@@ -420,13 +430,15 @@ def step_config_attribution(
 
 
 def metrics_line_fields(costs: dict, device_kind: str | None = None) -> dict:
-    """The two attribution scalars every train metrics line carries:
-    ``mfu_est`` (roofline ceiling on the target chip) and
-    ``comm_bytes_total`` (per-device wire bytes per step)."""
-    est = roofline_estimate(
-        costs["flops_est"], costs["comm_bytes_total"], device_kind=device_kind
-    )
-    return {
-        "mfu_est": est["mfu_est"],
-        "comm_bytes_total": float(costs["comm_bytes_total"]),
-    }
+    """The attribution scalars a train metrics line carries:
+    ``comm_bytes_total`` (per-device wire bytes per step, a count from
+    shapes) always, and ``mfu_est`` (roofline ceiling) only when
+    ``device_kind`` is a listed chip or the ``None`` what-if — a run on an
+    unlisted device (the CPU) carries no utilization figure."""
+    fields = {"comm_bytes_total": float(costs["comm_bytes_total"])}
+    if device_kind is None or device_kind in CHIP_SPECS:
+        fields["mfu_est"] = roofline_estimate(
+            costs["flops_est"], costs["comm_bytes_total"],
+            device_kind=device_kind,
+        )["mfu_est"]
+    return fields

@@ -272,7 +272,7 @@ def _bwd_txt_kernel(quant, tp_ref, bias_ref, off_ref, g_ref, *refs):
 
 
 # ---------------------------------------------------------------------------
-# pallas_call plumbing (specs, vma typing, 0.4.x struct compat).
+# pallas_call plumbing (specs, vma typing).
 # ---------------------------------------------------------------------------
 
 
@@ -282,17 +282,13 @@ def _scalar_spec():
 
 def _vma_of(*xs) -> frozenset:
     """Union of the inputs' varying-manual-axes (shard_map's replication
-    typing). Under ``jax.shard_map`` with ``check_vma=True`` (the 0.6
-    default), ``pallas_call`` outputs must declare which mesh axes they vary
-    over; the loss varies over every axis any input varies over. Outside
-    shard_map (and on jax 0.4.x, whose check_rep machinery infers this
-    itself) this is the empty set."""
+    typing). Under ``jax.shard_map`` with ``check_vma=True`` (the default),
+    ``pallas_call`` outputs must declare which mesh axes they vary over; the
+    loss varies over every axis any input varies over. Outside shard_map this
+    is the empty set."""
     vma = frozenset()
     for x in xs:
-        try:
-            vma |= jax.typeof(x).vma
-        except AttributeError:  # plain numpy input or older jax
-            pass
+        vma |= jax.typeof(x).vma
     return vma
 
 
@@ -303,12 +299,7 @@ def _align_vma(x, vma: frozenset):
 
 
 def _struct(shape, vma: frozenset, dtype=jnp.float32):
-    """ShapeDtypeStruct with vma typing where the jax version supports it
-    (0.6+); plain struct on 0.4.x, whose check_rep path needs none."""
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _operand_pack(zimg, ztxt, quant, vma):

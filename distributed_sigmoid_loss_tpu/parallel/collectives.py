@@ -86,14 +86,8 @@ def validate_ring_perm(perm, axis_size: int, axis_name) -> None:
 
 
 def pvary(x: jax.Array, axis_name):
-    """Mark ``x`` as varying over ``axis_name`` under shard_map's replication typing.
-
-    Compat shim: ``lax.pvary`` is deprecated in favor of ``lax.pcast(..,
-    to='varying')``; use whichever this jax version provides.
-    """
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_name, to="varying")
-    return lax.pvary(x, axis_name)
+    """Mark ``x`` as varying over ``axis_name`` under shard_map's replication typing."""
+    return lax.pcast(x, axis_name, to="varying")
 
 
 def _ring_perm(world_size: int, shift: int) -> list[tuple[int, int]]:

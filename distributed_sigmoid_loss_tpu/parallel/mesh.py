@@ -48,6 +48,19 @@ def make_mesh(
     return Mesh(np.asarray(devices[:world_size]), (axis_name,))
 
 
+def trace_on(mesh: Mesh):
+    """Context manager: trace the enclosed code with ``mesh`` as jax's abstract
+    mesh, so code deep in the towers can see which axes exist without the mesh
+    being threaded through every module. The fused attention kernels need it
+    (models/transformer.py ``_fused_attention_per_shard``): a Mosaic kernel
+    under a multi-chip ``jit`` must sit in a ``shard_map``, and ``shard_map``
+    needs a mesh. Enter it INSIDE the jitted function, around ``model.apply``
+    — never around ``model.init``: under a mesh flax applies the kernels'
+    ``tp`` partitioning eagerly, which a mesh without that axis rejects.
+    """
+    return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+
+
 def make_2d_mesh(
     dp: int,
     tp: int,

@@ -901,7 +901,7 @@ def make_compressed_train_step(
         else:
             # Fixed schemes put a compile-time-constant payload on the wire;
             # emit the same accounting so adaptive-vs-fixed A/Bs read one
-            # field (docs/round16_chip_queue.sh).
+            # field.
             fixed = _fixed_wire_bytes(state.params)
             metrics["dcn_wire_bytes"] = jnp.asarray(fixed, jnp.float32)
             metrics["bits_per_param"] = jnp.asarray(

@@ -27,6 +27,7 @@ from flax.training import train_state
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from distributed_sigmoid_loss_tpu.parallel.mesh import trace_on
 from distributed_sigmoid_loss_tpu.parallel.update_shard import (
     apply_sharded_update,
     capture_shardings,
@@ -490,9 +491,16 @@ def init_params(
     that mesh axis — pair with ``make_train_step(pp_microbatches=...)``.
     """
 
+    # Initializers read shapes only. Zeros built INSIDE the jit stand in for
+    # the sample: closing over the concrete batch would bake a global-batch
+    # sized constant (77 MB at B/16 x 128) into the init program.
+    images, tokens = (
+        (sample_batch[k].shape, sample_batch[k].dtype)
+        for k in ("images", "tokens")
+    )
+
     def init_fn(rng):
-        variables = model.init(rng, sample_batch["images"], sample_batch["tokens"])
-        return variables["params"]
+        return model.init(rng, jnp.zeros(*images), jnp.zeros(*tokens))["params"]
 
     abstract = jax.eval_shape(init_fn, rng)
     shardings = param_shardings(mesh, abstract)
@@ -700,11 +708,9 @@ def make_train_step(
         check_vma=loss_check_vma,
     )
     if loss_cfg.loss_impl == "chunked" or loss_cfg.use_pallas:
-        # Grads of the chunk scan must flow through a JITTED shard_map: the
-        # 0.4.x eager/inline transpose cannot type the scan's scalar carry —
-        # and the same inline transpose mis-specs the pallas custom_vjp's
-        # scalar residuals (_jax_compat target). jit-in-jit is a free pjit
-        # inline on >= 0.6.
+        # Grads of the chunk scan and of the pallas custom_vjp flow through a
+        # JITTED shard_map (the arrangement make_sharded_loss_fn's jit=True
+        # gives standalone callers); jit-in-jit is a free pjit inline.
         sharded_loss = jax.jit(sharded_loss)
 
     cached_accum, acc_dt = validate_step_args(
@@ -779,7 +785,7 @@ def make_train_step(
         check_vma=loss_check_vma,
     )
     if loss_cfg.loss_impl == "chunked" or loss_cfg.use_pallas:
-        stacked_loss = jax.jit(stacked_loss)  # same 0.4.x transpose contract
+        stacked_loss = jax.jit(stacked_loss)  # same jitted-shard_map arrangement
 
     def grads_and_metrics_cached(params, batch):
         from distributed_sigmoid_loss_tpu.parallel.microbatch import (
@@ -838,7 +844,10 @@ def make_train_step(
         return loss_sum / accum_steps, lp, jnp.mean(auxs), grads
 
     def step(state: TrainState, batch: dict, param_out_shardings=None):
-        loss, lp, aux, grads = grads_and_metrics(state.params, batch)
+        # Traced on the mesh: the towers' fused attention kernels must know
+        # which axes shard their operands (parallel/mesh.py trace_on).
+        with trace_on(mesh):
+            loss, lp, aux, grads = grads_and_metrics(state.params, batch)
         prev_step = state.step  # apply_gradients increments; EMA warmup wants
         prev_params = state.params  # update_ratio needs the pre-update tree
         # The shared update-shard recipe (parallel/update_shard.py): plain

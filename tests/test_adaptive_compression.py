@@ -25,8 +25,7 @@ Tiering (the 870s tier-1 budget): the module is conftest-standard, but the
 step-level oracles that compile the full (2, 4) hybrid step — parity vs the
 uncompressed step, the scheme-swap no-recompile pin, the 0.25x-bf16 wire
 oracle, the zero1+accum composition, and the full config-product ef-indices
-arming — are ``slow``-marked; docs/round16_chip_queue.sh runs the module
-unfiltered as its pre-flight, so they gate every chip round.
+arming — are ``slow``-marked; run the module unfiltered for those.
 """
 
 import subprocess
@@ -649,8 +648,8 @@ def test_adaptive_convergence_parity_sweep():
     # the narrowest rungs (sign1 / keep-0.25% topk) a ~20% loss lag at step
     # 10 is the measured cost of ~100x less wire; what must NOT happen is a
     # stall (no descent) or a blow-up. Exact parity at int8 rungs is
-    # test_adaptive_step_matches_uncompressed; the chip-side A/B
-    # (docs/round16_chip_queue.sh) is the long-horizon half of the oracle.
+    # test_adaptive_step_matches_uncompressed; a chip-side A/B would be the
+    # long-horizon half of the oracle (none has been run).
     np.testing.assert_allclose(la[-1], lu[-1], rtol=0.25)
     assert la[-1] < lu[0], (la, lu)
 

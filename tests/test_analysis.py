@@ -667,44 +667,6 @@ def test_unregistered_mutable_global_trips():
     assert "stale" in stale[0].detail
 
 
-FAKE_BENCH = """
-import argparse
-
-_SHIELD_EXEMPT_FLAGS = {{
-    "steps": "trip count only",
-{extra_exempt}
-}}
-
-def _fresh_compile_config(args):
-    return bool(args.moe)
-
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--steps", type=int)
-    ap.add_argument("--moe", type=int)
-    ap.add_argument("--frobnicate", action="store_true")
-"""
-
-
-def test_unshielded_fake_bench_flag_trips():
-    findings = repo_lint.check_bench_shield(
-        FAKE_BENCH.format(extra_exempt="")
-    )
-    assert _rules_of(findings) == ["repo-bench-shield"]
-    assert [f.subject for f in findings] == ["bench.py::frobnicate"]
-    # classified (exempted) -> green
-    assert repo_lint.check_bench_shield(
-        FAKE_BENCH.format(extra_exempt='    "frobnicate": "measurement-only",')
-    ) == []
-    # stale exemption -> finding
-    stale = repo_lint.check_bench_shield(
-        FAKE_BENCH.format(
-            extra_exempt='    "frobnicate": "x",\n    "gone": "stale",'
-        )
-    )
-    assert [f.subject for f in stale] == ["bench.py::gone"]
-
-
 def test_undocumented_cli_flag_trips_doc_rule():
     cli_src = (
         "import argparse\n"
@@ -955,16 +917,10 @@ def test_validate_record_contract():
 
 
 def test_bench_emit_paths_validate_against_schema(capsys):
-    import argparse
-
     import bench
 
-    args = argparse.Namespace(
-        eval_throughput=False, context=0, moe_breakdown=False,
-        step_breakdown=False, metric_suffix="", model="tiny", batch=4,
-        steps=2,
-    )
-    bench.emit_backend_error(args, "drill")
+    bench._emit({"metric": "m", "value": 1.0, "unit": "pairs/s/chip",
+                 "model": "tiny", "per_chip_batch": 4, "steps": 2})
     out, err = capsys.readouterr()
     rec = json.loads(out.strip())
     assert validate_record(rec) == []
@@ -998,7 +954,7 @@ def test_cli_lint_json_report(capsys):
     report = json.loads(out)
     assert report["findings"] == []
     assert "repo-doc-stale" in report["disabled"]
-    assert "repo-bench-shield" in report["rules_checked"]
+    assert "repo-bench-record" in report["rules_checked"]
     assert "repo-doc-stale" not in report["rules_checked"]
 
 

@@ -1,0 +1,59 @@
+"""What the chip entry points do where there is no chip, and where the compile
+cache goes. (That chip_smoke.py passes ON the chip is proven by running it
+there — PERF.md's bring-up table; nothing here can show it.)"""
+
+import os
+import subprocess
+import sys
+
+import jax
+
+from distributed_sigmoid_loss_tpu.utils import compile_cache
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_no_chip_means_nonzero_exit_and_no_result():
+    """chip_smoke.py refuses before any compile and prints no result;
+    bench.py exits non-zero and prints no metric row (no ``value: 0.0`` under
+    a device metric's name). Both at once: the cost is two interpreter
+    start-ups, side by side."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    procs = {
+        script: subprocess.Popen(
+            [sys.executable, os.path.join(REPO, script)],
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            env=env, cwd=REPO,
+        )
+        for script in ("chip_smoke.py", "bench.py")
+    }
+    out = {}
+    for script, proc in procs.items():
+        stdout, stderr = proc.communicate(timeout=120)
+        assert proc.returncode not in (0, None), (script, stdout, stderr[-500:])
+        out[script] = (stdout, stderr)
+
+    stdout, stderr = out["chip_smoke.py"]
+    assert "platform=cpu" in stdout  # the banner names what it found
+    assert "refusing to run" in stderr
+    assert '"ok"' not in stdout and "==" not in stdout and "compile" not in stdout
+
+    stdout, stderr = out["bench.py"]
+    assert stdout.strip() == "", stdout  # no row of any kind
+    assert "no metric row written" in stderr
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+    """A set JAX_COMPILATION_CACHE_DIR is left alone (jax reads it itself);
+    unset, the cache is <checkout>/.jax_cache."""
+    updates = []
+    monkeypatch.setattr(jax.config, "update", lambda k, v: updates.append((k, v)))
+
+    monkeypatch.setenv(compile_cache.CACHE_ENV, "/some/dir")
+    assert compile_cache.configure_compile_cache() == "/some/dir"
+    assert updates == []
+
+    monkeypatch.delenv(compile_cache.CACHE_ENV)
+    want = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.configure_compile_cache() == want
+    assert updates == [("jax_compilation_cache_dir", want)]

@@ -8,6 +8,9 @@ CPU against a dense stand-in kernel that honors the exact kernel interface
 numerics remain TPU-only (covered by the tpu-marked parity test at the bottom).
 """
 
+import contextlib
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -167,3 +170,57 @@ def test_flash_kernel_matches_dense_on_tpu(s):
             np.asarray(a, np.float32), np.asarray(b, np.float32),
             rtol=5e-2, atol=5e-2,
         )
+
+
+def test_fused_kernel_runs_per_shard_under_a_sharded_jit(monkeypatch):
+    """A Mosaic kernel cannot be auto-partitioned: under a jit traced on a
+    multi-device mesh (parallel.mesh.trace_on, as the train step does) the
+    dispatch must hand the kernel its LOCAL rows inside a shard_map — the real
+    interpret-mode kernel here, forward and gradients against dense. With no
+    mesh in the trace the kernel is called bare, as on one chip."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_sigmoid_loss_tpu.models import transformer as tr
+    from distributed_sigmoid_loss_tpu.ops import pallas_short_attention as sa
+    from distributed_sigmoid_loss_tpu.parallel.mesh import make_mesh, trace_on
+
+    seen = []
+    real_kernel = sa.short_self_attention
+
+    def interpreted_short(q, k, v, causal=False):
+        seen.append(q.shape)
+        return real_kernel(q, k, v, causal, None, True)
+
+    monkeypatch.setattr(fa, "flash_attention_available", lambda: True)
+    monkeypatch.setattr(sa, "short_self_attention", interpreted_short)
+
+    mesh = make_mesh(4)
+    b, s, width, heads = 8, 16, 64, 2
+    fused = tr.Attention(width=width, num_heads=heads, dtype=jnp.bfloat16)
+    dense = tr.Attention(width=width, num_heads=heads, dtype=jnp.bfloat16,
+                         attn_impl="dense")
+    x = jax.device_put(
+        jax.random.normal(jax.random.key(0), (b, s, width), jnp.bfloat16),
+        NamedSharding(mesh, P("dp")),
+    )
+    params = nn.meta.unbox(dense.init(jax.random.key(1), x))
+
+    def loss_and_grad(module, on_mesh):
+        def loss(p, xx):
+            with trace_on(mesh) if on_mesh else contextlib.nullcontext():
+                return jnp.sum(module.apply(p, xx).astype(jnp.float32) ** 2)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=1))
+
+    want, gwant = loss_and_grad(dense, False)(params, x)
+    got, ggot = loss_and_grad(fused, True)(params, x)
+    assert seen and all(shape[0] == b // 4 for shape in seen), seen
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-2)
+    ggot32, gwant32 = np.asarray(ggot, np.float32), np.asarray(gwant, np.float32)
+    # bf16 on both sides: judge the worst element against the gradient's scale
+    assert np.max(np.abs(ggot32 - gwant32)) <= 2e-2 * np.max(np.abs(gwant32))
+    assert ggot.sharding.spec == P("dp")
+
+    seen.clear()
+    loss_and_grad(fused, False)(params, x)
+    assert seen and all(shape[0] == b for shape in seen), seen

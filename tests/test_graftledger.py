@@ -5,7 +5,7 @@ Four contract families:
 
 - **Ledger** (`obs/ledger.py`): append/read round trips with torn-line
   tolerance, status classification (a dead backend is ``no-backend``, never
-  a 0.0 measurement), backfill from the REAL committed BENCH_r*/MULTICHIP_r*
+  a 0.0 measurement), backfill from driver-format BENCH_r*/MULTICHIP_r*
   round files (761.74 @ r3 must surface as the last verified headline, with
   the r04/r05 outages excluded from baseline stats), and the bench.py
   ``_emit`` integration.
@@ -104,15 +104,55 @@ def test_append_never_raises_on_unwritable_path(capsys):
 
 
 # ---------------------------------------------------------------------------
-# backfill from the REAL committed round files (the r01-r05 trajectory)
+# backfill from driver-format round files (the r01-r05 trajectory; the
+# committed LEDGER.jsonl rows 0-10 hold the real ones' numbers)
 # ---------------------------------------------------------------------------
+
+HEADLINE = "siglip_vitb16_train_pairs_per_sec_per_chip"
+
+
+def _write_round_files(root) -> str:
+    """The driver's round-file formats, with the r01-r05 trajectory: two
+    measured headlines, two outage rounds (headline + 32k-equiv records both
+    dead), one failed and one passed multichip dryrun."""
+    def bench(n, records, rc=0):
+        tail = "some stderr noise\n" + "".join(
+            json.dumps(r) + "\n" for r in records
+        )
+        (root / f"BENCH_r{n:02d}.json").write_text(json.dumps(
+            {"n": n, "cmd": "python bench.py", "rc": rc, "tail": tail,
+             "parsed": records[-1]}
+        ))
+
+    def ok(value, **extra):
+        return {"metric": HEADLINE, "value": value, "unit": "pairs/s/chip",
+                **extra}
+
+    def dead(metric):
+        return {"metric": metric, "value": 0.0, "unit": "pairs/s/chip",
+                "vs_baseline": 0.0,
+                "error": "backend unavailable: backend init/compute hung "
+                         "past 240s (after 3 attempts)"}
+
+    bench(1, [ok(718.23, vs_baseline=0.653)])
+    bench(3, [ok(761.74, vs_baseline=0.692, device_kind="TPU v5 lite",
+                 mfu=0.54)])
+    for n in (4, 5):
+        bench(n, [dead(HEADLINE + "_32k_equiv"), dead(HEADLINE)], rc=1)
+    for n, passed in ((1, False), (2, True)):
+        (root / f"MULTICHIP_r{n:02d}.json").write_text(json.dumps(
+            {"n_devices": 8, "rc": 0 if passed else 1, "ok": passed,
+             "skipped": False, "tail": "dryrun output\n"}
+        ))
+    return str(root)
 
 
 def test_backfill_true_trajectory_and_idempotence(tmp_path):
     path = str(tmp_path / "ledger.jsonl")
-    added = ledger_mod.backfill_round_files(repo_root=REPO_ROOT, path=path)
-    assert len(added) >= 11  # 4 BENCH records + 2 headline-only + 5 multichip
-    assert ledger_mod.backfill_round_files(repo_root=REPO_ROOT, path=path) \
+    root = _write_round_files(tmp_path)
+    added = ledger_mod.backfill_round_files(repo_root=root, path=path)
+    assert len(added) == 8  # 2 measured + 2x2 outage records + 2 multichip
+    assert ledger_mod.backfill_round_files(repo_root=root, path=path) \
         == []  # idempotent
 
     traj = ledger_mod.trajectory(ledger_mod.read_ledger(path))
@@ -220,10 +260,12 @@ def test_bench_emit_appends_to_ledger(tmp_path, monkeypatch, capsys):
 # ---------------------------------------------------------------------------
 
 
-def test_cli_obs_ledger_backfill_and_render(tmp_path, capsys):
+def test_cli_obs_ledger_backfill_and_render(tmp_path, capsys, monkeypatch):
     from distributed_sigmoid_loss_tpu.cli import main
 
     path = str(tmp_path / "ledger.jsonl")
+    # `--backfill` reads the round files beside the package: point it here
+    monkeypatch.setattr(ledger_mod, "_REPO_ROOT", _write_round_files(tmp_path))
     assert main(["obs", "ledger", "--ledger", path, "--backfill"]) == 0
     out, err = capsys.readouterr()
     assert "761.74" in out and "no-backend" in out
@@ -247,8 +289,9 @@ def test_cli_obs_diff_selectors_and_errors(tmp_path, capsys):
     from distributed_sigmoid_loss_tpu.cli import main
 
     path = str(tmp_path / "ledger.jsonl")
-    ledger_mod.backfill_round_files(repo_root=REPO_ROOT, path=path)
-    metric = "siglip_vitb16_train_pairs_per_sec_per_chip"
+    root = _write_round_files(tmp_path)
+    ledger_mod.backfill_round_files(repo_root=root, path=path)
+    metric = HEADLINE
     assert main(["obs", "diff", f"{metric}@0", f"{metric}@1",
                  "--ledger", path]) == 0
     out, _ = capsys.readouterr()
@@ -266,7 +309,7 @@ def test_cli_obs_diff_selectors_and_errors(tmp_path, capsys):
         assert "+6.1%" in out, argv
     # a round file is a valid operand (its tail's last record)
     assert main(["obs", "diff", f"{metric}@0",
-                 os.path.join(REPO_ROOT, "BENCH_r03.json"),
+                 os.path.join(root, "BENCH_r03.json"),
                  "--ledger", path]) == 0
     capsys.readouterr()
     assert main(["obs", "diff", f"{metric}@0", "--ledger", path]) == 2

@@ -13,7 +13,7 @@ from distributed_sigmoid_loss_tpu.models import TextTransformer
 from distributed_sigmoid_loss_tpu.parallel.mesh import make_mesh
 from distributed_sigmoid_loss_tpu.utils.config import TextConfig
 
-# Tier note: excluded from the time-boxed tier-1 gate (-m 'not slow'): sequence-parallel tower suites (also: hard-aborts XLA on jax 0.4.x CPU — see _jax_compat).
+# Tier note: excluded from the time-boxed tier-1 gate (-m 'not slow'): sequence-parallel tower suites.
 pytestmark = pytest.mark.slow
 
 

@@ -606,14 +606,19 @@ def test_roofline_estimate_contract():
     est = roofline_estimate(1e9, 0.0, bytes_accessed=1e12,
                             device_kind="TPU v5 lite")
     assert est["bound"] == "memory"
-    # unknown device kind falls back to the target chip, never raises
-    est = roofline_estimate(1e12, 0.0, device_kind="cpu")
-    assert est["roofline_chip"] == "TPU v5 lite"
-    fields = metrics_line_fields(
-        {"flops_est": 1e12, "comm_bytes_total": 5.0}
-    )
+    # an actual device the peaks table does not list is an error, never a
+    # silent v5e; None is the what-if against the target chip, by name
+    with pytest.raises(ValueError, match="cpu"):
+        roofline_estimate(1e12, 0.0, device_kind="cpu")
+    assert roofline_estimate(1e12, 0.0)["roofline_chip"] == "TPU v5 lite"
+    costs = {"flops_est": 1e12, "comm_bytes_total": 5.0}
+    fields = metrics_line_fields(costs)
     assert set(fields) == {"mfu_est", "comm_bytes_total"}
     assert fields["comm_bytes_total"] == 5.0
+    # a run on an unlisted device keeps the count, drops the utilization
+    assert metrics_line_fields(costs, device_kind="cpu") == {
+        "comm_bytes_total": 5.0
+    }
 
 
 # ---------------------------------------------------------------------------

@@ -27,8 +27,7 @@ Oracles:
 
 Tiering: module is conftest-standard; the step-level oracles that compile
 full train steps on the 8-device CPU mesh are ``slow``-marked (tier-1 runs
-the placement/parity/memory pins, docs/round18_chip_queue.sh runs the module
-unfiltered pre-flight).
+the placement/parity/memory pins; run the module unfiltered for the rest).
 """
 
 import json
