@@ -32,10 +32,9 @@ The subcommands tie the subsystems together:
   ``--json``, per-rule ``--disable``, ``--full-product``, ``--baseline``).
   The same analyzers run in tier-1 (tests/test_analysis.py,
   tests/test_config_space.py) and the dryrun — docs/ANALYSIS.md.
-- ``obs`` — graftscope offline reports: ``obs summarize DIR`` merges the
-  host spans a ``train --obs-dir`` run recorded with any device trace
-  capture under DIR into one where-the-time-goes report, optionally writing
-  a single merged Chrome-trace JSON (``--merged-out``) —
+- ``obs`` — graftscope offline reports: ``obs summarize DIR`` prints the
+  host spans a ``train --obs-dir`` run recorded and the op table of any
+  device trace capture under DIR as one where-the-time-goes report —
   docs/OBSERVABILITY.md.
 
 ``train`` and ``eval`` accept ``--cpu-devices N`` to emulate an N-chip mesh on
@@ -2078,31 +2077,27 @@ def cmd_data_bench(args) -> int:
 
 
 def _load_host_spans(root: str):
-    """(host_trace, spans) aggregated from every host_spans.trace.json under
-    ``root`` — shared by `obs summarize` and the span half of `obs diff`."""
+    """(paths, spans) of every host_spans.trace.json under ``root`` — shared
+    by `obs summarize` and the span half of `obs diff`."""
     import glob as globmod
     import json as jsonmod
 
     from distributed_sigmoid_loss_tpu.obs.spans import Span
 
-    host_trace = None
     host_paths = sorted(
         globmod.glob(os.path.join(root, "**", "host_spans.trace.json"),
                      recursive=True)
     )
     spans: list = []
-    if host_paths:
-        host_trace = {"traceEvents": []}
-        for path in host_paths:
-            with open(path, encoding="utf-8") as f:
-                trace = jsonmod.load(f)
-            host_trace["traceEvents"].extend(trace.get("traceEvents", []))
-        for ev in host_trace["traceEvents"]:
+    for path in host_paths:
+        with open(path, encoding="utf-8") as f:
+            events = jsonmod.load(f).get("traceEvents", [])
+        for ev in events:
             if ev.get("ph") == "X" and "dur" in ev:
                 t0 = ev["ts"] / 1e6
                 spans.append(Span(ev["name"], t0, t0 + ev["dur"] / 1e6,
                                   ev.get("tid", 0)))
-    return host_trace, host_paths, spans
+    return host_paths, spans
 
 
 def _add_obs_args(p) -> None:
@@ -2122,9 +2117,6 @@ def _add_obs_args(p) -> None:
                         "or run dir); ledger/regress: none")
     p.add_argument("--top", type=int, default=12,
                    help="rows per device-op table (obs summarize)")
-    p.add_argument("--merged-out", default="", metavar="PATH",
-                   help="also write one merged Chrome-trace JSON (host + "
-                        "device events; open in ui.perfetto.dev)")
     p.add_argument("--ledger", default="", metavar="PATH",
                    help="ledger file for `obs ledger`/`obs diff` (default: "
                         "DSL_LEDGER_PATH or LEDGER.jsonl at the repo root)")
@@ -2296,8 +2288,8 @@ def _obs_diff(args) -> int:
     if {kind_a, kind_b} == {"spans"}:
         from distributed_sigmoid_loss_tpu.obs.spans import summarize_spans
 
-        rows_a = summarize_spans(_load_host_spans(a)[2])
-        rows_b = summarize_spans(_load_host_spans(b)[2])
+        rows_a = summarize_spans(_load_host_spans(a)[1])
+        rows_b = summarize_spans(_load_host_spans(b)[1])
         if not rows_a or not rows_b:
             print("obs diff: one of the run dirs has no host spans "
                   "(train with --obs-dir)", file=sys.stderr)
@@ -2350,27 +2342,23 @@ def _obs_regress(args) -> int:
 
 
 def _obs_summarize(args) -> int:
-    """``obs summarize DIR``: one merged offline report of a run's host spans
+    """``obs summarize DIR``: one offline report of a run's host spans
     (``host_spans.trace.json`` written by ``train --obs-dir``) and any device
     trace capture (``*.trace.json.gz`` from ``utils.profiling.trace`` /
-    ``bench --profile``) found under DIR — the unified graftscope timeline,
-    no TensorBoard needed. ``--merged-out`` additionally writes one combined
-    Chrome-trace JSON that opens in ui.perfetto.dev with host and device
-    tracks side by side.
+    ``bench --profile``) found under DIR — two tables, no TensorBoard needed.
+    For both on one time axis open the profiler's own capture: while it runs,
+    every enabled span is also a ``TraceAnnotation`` in its host plane
+    (obs/spans.py).
     """
     import glob as globmod
-    import json as jsonmod
 
     if len(args.paths) != 1:
         print("obs summarize needs exactly one DIR operand", file=sys.stderr)
         return 2
     root = args.paths[0]
-    from distributed_sigmoid_loss_tpu.obs.spans import (
-        merge_chrome_traces,
-        summarize_spans,
-    )
+    from distributed_sigmoid_loss_tpu.obs.spans import summarize_spans
 
-    host_trace, host_paths, spans = _load_host_spans(root)
+    host_paths, spans = _load_host_spans(root)
 
     device_files = globmod.glob(
         os.path.join(root, "**", "*.trace.json.gz"), recursive=True
@@ -2415,19 +2403,6 @@ def _obs_summarize(args) -> int:
             print("\n(device trace files found but no 'XLA Ops' track — "
                   "host-only capture?)")
 
-    if args.merged_out:
-        from distributed_sigmoid_loss_tpu.utils.profiling import (
-            _read_trace_files,
-        )
-
-        device_events = _read_trace_files(root) if device_files else ()
-        merged = merge_chrome_traces(host_trace or {"traceEvents": []},
-                                     device_events)
-        with open(args.merged_out, "w", encoding="utf-8") as f:
-            jsonmod.dump(merged, f)
-        print(f"\nmerged chrome trace -> {args.merged_out} "
-              f"({len(merged['traceEvents'])} events; open in "
-              "ui.perfetto.dev)")
     return 0
 
 

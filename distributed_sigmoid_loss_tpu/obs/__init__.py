@@ -5,9 +5,9 @@ Four parts, one goal — every perf or robustness claim arrives with its
 evidence attached, chip or no chip:
 
 - :mod:`.spans` — thread-safe ring-buffered host spans (train loop stages,
-  serve per-request stages) with Chrome-trace export that overlays the
-  device captures from ``utils.profiling.trace``; merged offline by the
-  ``obs summarize`` CLI subcommand.
+  serve per-request stages); while a profiler capture runs they ride in its
+  host plane on the device events' clock; the ring's own Chrome-trace export
+  feeds the ``obs summarize`` CLI subcommand.
 - :mod:`.attribution` — static per-step FLOPs, bytes, and per-kind
   collective wire bytes from the traced jaxpr (no compile), plus compiled-
   executable cost/memory readout, and the chip-free roofline ``mfu_est``
@@ -76,7 +76,6 @@ from distributed_sigmoid_loss_tpu.obs.ledger import (  # noqa: F401
 from distributed_sigmoid_loss_tpu.obs.spans import (  # noqa: F401
     Span,
     SpanRecorder,
-    merge_chrome_traces,
     summarize_spans,
 )
 from distributed_sigmoid_loss_tpu.obs.telemetry import (  # noqa: F401
@@ -89,7 +88,6 @@ __all__ = [
     "Span",
     "SpanRecorder",
     "summarize_spans",
-    "merge_chrome_traces",
     "HealthWatchdog",
     "HealthEvent",
     "FlightRecorder",

@@ -15,10 +15,22 @@ batch assembly and the engine call. :class:`SpanRecorder` fills that half:
   clock read, no lock. The hot train/serve loops stay instrumented
   unconditionally and pay only an attribute check until someone turns
   recording on (pinned by the bounded-overhead test in tests/test_obs.py).
-- **Chrome-trace JSON export**: ``chrome_trace()`` emits the same
-  ``traceEvents`` format the device profiler writes, with a distinct pid, so
-  the host timeline OVERLAYS the device capture in ui.perfetto.dev — and
-  ``obs summarize`` (cli.py) merges both into one offline report.
+- **On the profiler's clock while a capture runs**: an enabled ``span()``
+  also enters a ``jax.profiler.TraceAnnotation`` of the same name, so any
+  capture that is running (``utils.profiling.trace``, ``bench --profile``,
+  the benchmark's traced run) carries ``fetch``, ``h2d_commit``, ``step``,
+  ``eval``, ``checkpoint`` and the serve stages in its host plane, on the
+  clock of the device events: an idle gap of the device can be put down to
+  what the host was doing. With no capture running the annotation is a flag
+  check. ``record()`` — a span whose two ends were seen on different threads —
+  keeps its ``perf_counter`` times only: an annotation opens and closes on
+  one thread.
+- **Chrome-trace JSON export**: ``chrome_trace()`` writes the recorder's own
+  ring in the ``traceEvents`` format, timestamps in ``perf_counter``
+  microseconds. That file (``host_spans.trace.json``) stands alone on its own
+  clock: it opens in ui.perfetto.dev and feeds ``obs summarize``'s host table,
+  but it does not line up with a device capture — the profiler's file is the
+  one that holds both halves.
 
 Nesting needs no explicit tracking: spans carry (tid, ts, dur) and the
 Chrome trace model nests same-thread spans by containment, exactly like the
@@ -40,11 +52,9 @@ __all__ = [
     "Span",
     "SpanRecorder",
     "summarize_spans",
-    "merge_chrome_traces",
 ]
 
-# One pid for every host span so perfetto groups them as a single "process"
-# track alongside the device processes from utils.profiling.trace.
+# One pid for every host span so perfetto groups them as a single "process".
 HOST_PID = 1_000_001
 
 
@@ -80,31 +90,43 @@ _NOOP = _NoopSpan()
 
 
 class _LiveSpan:
-    """Enabled-path context manager: records into its recorder on exit."""
+    """Enabled-path context manager: records into its recorder on exit, and
+    brackets the block with a profiler annotation so a running capture sees
+    the span on its own clock."""
 
-    __slots__ = ("_rec", "_name", "_t0")
+    __slots__ = ("_rec", "_name", "_t0", "_annotation")
 
     def __init__(self, rec: "SpanRecorder", name: str):
         self._rec = rec
         self._name = name
 
     def __enter__(self):
+        # Imported here: obs/ imports without initializing jax, and the
+        # disabled path never gets this far.
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._rec.record(self._name, self._t0, time.perf_counter())
+        t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._rec.record(self._name, self._t0, t1)
         return False
 
 
 class SpanRecorder:
     """Ring-buffered recorder of nested host spans.
 
-    ``with rec.span("step"): ...`` on the caller's thread; ``record(name,
-    t0, t1)`` for spans whose start and end are observed on different control
-    paths (the serve batcher's queue-wait: enqueue happens on the client
-    thread, the batch flush on the worker). ``enabled=False`` (or
-    ``disable()``) turns every ``span()`` into the shared no-op.
+    ``with rec.span("step"): ...`` on the caller's thread (also a profiler
+    annotation, see the module docstring); ``record(name, t0, t1)`` for spans
+    whose start and end are observed on different control paths (the serve
+    batcher's queue-wait: enqueue happens on the client thread, the batch
+    flush on the worker) — ``perf_counter`` times only, never in a profiler
+    capture. ``enabled=False`` (or ``disable()``) turns every ``span()`` into
+    the shared no-op.
     """
 
     def __init__(self, capacity: int = 8192, enabled: bool = True):
@@ -151,10 +173,9 @@ class SpanRecorder:
             return list(self._spans)
 
     def chrome_trace(self, label: str = "host") -> dict:
-        """``{"traceEvents": [...]}`` — the Perfetto/Chrome format the device
-        profiler writes, so this file overlays a ``utils.profiling.trace``
-        capture directly. Timestamps are perf_counter microseconds (a shared
-        monotonic base across every recorder in the process)."""
+        """``{"traceEvents": [...]}`` — the Perfetto/Chrome format.
+        Timestamps are perf_counter microseconds (a shared monotonic base
+        across every recorder in the process, not the profiler's clock)."""
         events: list[dict] = [
             {
                 "ph": "M",
@@ -218,14 +239,3 @@ def summarize_spans(spans: Iterable[Span]) -> dict[str, dict]:
             "max_ms": round(ds[-1], 3),
         }
     return out
-
-
-def merge_chrome_traces(host_trace: dict, device_events: Iterable[list]) -> dict:
-    """One combined ``traceEvents`` stream: host spans + every device event
-    list (as yielded by ``utils.profiling._read_trace_files``). Device and
-    host events keep their own pids, so perfetto shows them as separate
-    processes on one shared timeline."""
-    merged = list(host_trace.get("traceEvents", []))
-    for events in device_events:
-        merged.extend(events)
-    return {"traceEvents": merged}

@@ -271,6 +271,7 @@ def _short_attention_fwd(q, k, v, causal, scale, interpret, batch_heads=None):
             transcendentals=b * h * s * s,
         ),
         interpret=interpret,
+        name="short_attn_fwd",  # what a profile calls this kernel
     )(q.reshape(wide), k.reshape(wide), v.reshape(wide))
     return out.reshape(q.shape), (q, k, v)
 
@@ -306,6 +307,7 @@ def _short_attention_bwd(causal, scale, interpret, batch_heads, residuals, g):
             transcendentals=b * h * s * s,
         ),
         interpret=interpret,
+        name="short_attn_bwd",
     )(q.reshape(wide), k.reshape(wide), v.reshape(wide), g.reshape(wide))
     shape = q.shape
     return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)

@@ -425,6 +425,7 @@ def _fwd(zimg, ztxt, t_prime, bias, pos_offset, quant, tile_b, tile_n, interpret
                                memory_space=pltpu.VMEM),
         out_shape=_struct((1, 1), vma),
         interpret=interpret,
+        name="loss_fwd",  # what a profile calls this kernel
     )(*scalars, *(_align_vma(a, vma) for a in arrays))
     loss = out[0, 0]
     return loss, (zimg, ztxt, t_prime, bias, pos_offset)
@@ -465,6 +466,7 @@ def _bwd(quant, tile_b, tile_n, interpret, res, g):
             _struct((1, 1), vma),
         ],
         interpret=interpret,
+        name="loss_bwd_img",
     )(*scalars, *(_align_vma(a, vma) for a in arrays), *extra[0])
 
     # Pass 2 — transposed grid (j, i), i innermost: dztxt tile j resident
@@ -480,6 +482,7 @@ def _bwd(quant, tile_b, tile_n, interpret, res, g):
         out_specs=[vspec((tile_n, d), lambda j, i: (j, 0))],
         out_shape=[_struct((n, d), vma)],
         interpret=interpret,
+        name="loss_bwd_txt",
     )(*scalars, *(_align_vma(a, vma) for a in arrays), *extra[0])
 
     return (

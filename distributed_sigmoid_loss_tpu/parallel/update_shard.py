@@ -57,6 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, Sharding
 
 __all__ = [
     "UPDATE_SHARDING_MODES",
+    "OPTIMIZER_SCOPE",
     "resolve_update_sharding",
     "shardable",
     "padded_rows",
@@ -76,6 +77,13 @@ UPDATE_SHARDING_MODES = ("off", "zero1", "full")
 # Sentinel for "no captured sharding — leave this leaf to the compiler";
 # distinct from None so pytrees of shardings keep their leaf structure.
 KEEP = object()
+
+# The ``jax.named_scope`` of everything the weight update costs the device:
+# the optax update, the zero1/full constraints, the publish, the EMA. Every
+# operation's path in a profile carries it. XLA fuses each leaf's update with
+# the norms of ``step_metrics`` that read its result, so the benchmark's
+# ``update_and_metrics_ms`` (benchmark/scopes.py) is the device time under both.
+OPTIMIZER_SCOPE = "optimizer"
 
 
 def resolve_update_sharding(update_sharding: str = "", zero1: bool = False) -> str:
@@ -201,24 +209,27 @@ def apply_sharded_update(
       params and the next donated call recompiles on the new layout).
     """
     w = dict(mesh.shape).get(axis_name, 1)
-    if mode == "off" or w <= 1:
-        return state.apply_gradients(grads=grads)
-    if mode == "full":
-        grads = constrain_update_sharding(grads, mesh, axis_name, mode)
-    state = state.apply_gradients(grads=grads)
-    state = state.replace(
-        opt_state=constrain_update_sharding(state.opt_state, mesh, axis_name, mode)
-    )
-    if mode == "full" and param_shardings is not None:
-        def publish(p, s):
-            if not isinstance(s, Sharding):
-                return p
-            return lax.with_sharding_constraint(p, s)
-
+    with jax.named_scope(OPTIMIZER_SCOPE):
+        if mode == "off" or w <= 1:
+            return state.apply_gradients(grads=grads)
+        if mode == "full":
+            grads = constrain_update_sharding(grads, mesh, axis_name, mode)
+        state = state.apply_gradients(grads=grads)
         state = state.replace(
-            params=jax.tree.map(publish, state.params, param_shardings)
+            opt_state=constrain_update_sharding(
+                state.opt_state, mesh, axis_name, mode
+            )
         )
-    return state
+        if mode == "full" and param_shardings is not None:
+            def publish(p, s):
+                if not isinstance(s, Sharding):
+                    return p
+                return lax.with_sharding_constraint(p, s)
+
+            state = state.replace(
+                params=jax.tree.map(publish, state.params, param_shardings)
+            )
+        return state
 
 
 def psum_scatter_shard(x: jax.Array, axis_name: str, w: int) -> jax.Array:

@@ -92,11 +92,13 @@ from distributed_sigmoid_loss_tpu.parallel.update_shard import (
     unpad_like,
 )
 from distributed_sigmoid_loss_tpu.train.train_step import (
+    LOSS_ISLAND_SCOPE,
     TrainState,
     _mean_moe_aux,
     accum_add,
     accum_finish,
     accum_zeros,
+    health_metrics,
     is_pp_block_leaf,
     run_gradcache,
     validate_accum_args,
@@ -540,7 +542,8 @@ def make_compressed_train_step(
                 {"params": params}, images, tokens, mutable=["intermediates"]
             )
             aux = _mean_moe_aux(variables)
-        loss = per_shard(zimg, ztxt, lp["t_prime"], lp["bias"])
+        with jax.named_scope(LOSS_ISLAND_SCOPE):
+            loss = per_shard(zimg, ztxt, lp["t_prime"], lp["bias"])
         if moe_aux_weight is not None:
             loss = loss + moe_aux_weight * aux
         return loss, (lp, aux)
@@ -859,20 +862,7 @@ def make_compressed_train_step(
             state, grads, mesh=mesh, axis_name=axis, mode=update_mode,
             param_shardings=param_out_shardings,
         )
-        # Same health scalars as make_train_step (obs/health.py watchdog
-        # inputs) — the metrics-line contract must not differ per step mode.
-        param_norm = optax.global_norm(state.params)
-        update_norm = optax.global_norm(
-            jax.tree.map(lambda n, o: n - o, state.params, prev_params)
-        )
-        metrics = {
-            "loss": loss,
-            "t": jnp.exp(lp["t_prime"]),
-            "bias": lp["bias"],
-            "grad_norm": optax.global_norm(grads),
-            "param_norm": param_norm,
-            "update_ratio": update_norm / (param_norm + 1e-12),
-        }
+        metrics = health_metrics(loss, lp, grads, state.params, prev_params)
         if moe_aux_weight is not None:
             metrics["moe_aux"] = aux
         if error_feedback:

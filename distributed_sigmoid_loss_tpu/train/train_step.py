@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_sigmoid_loss_tpu.parallel.mesh import trace_on
 from distributed_sigmoid_loss_tpu.parallel.update_shard import (
+    OPTIMIZER_SCOPE,
     apply_sharded_update,
     capture_shardings,
     constrain_update_sharding,
@@ -40,8 +41,22 @@ from distributed_sigmoid_loss_tpu.utils.config import LossConfig, TrainConfig
 __all__ = [
     "make_optimizer", "create_train_state", "init_params", "make_train_step",
     "zero1_constrain", "is_pp_block_leaf", "validate_trainable_quant",
-    "resolve_loss_quant", "TrainState",
+    "resolve_loss_quant", "TrainState", "health_metrics",
+    "LOSS_ISLAND_SCOPE", "ACCUM_SCOPE", "STEP_METRICS_SCOPE",
 ]
+
+# The step program's own names (``jax.named_scope``), one per layer boundary,
+# said once here and in parallel/update_shard.py (``OPTIMIZER_SCOPE``) so both
+# step builders lower to the same paths. They are operation metadata only: the
+# device program is the same with or without them. A profile's operations
+# carry them in their jax path (wrapped by the transformation they went
+# through: ``jvp(loss_island)``, ``transpose(jvp(loss_island))``), and
+# benchmark/scopes.py turns them into ``loss_island_ms``, ``accum_ms`` and
+# (with ``optimizer``) ``update_and_metrics_ms``. The towers need none: flax writes ``visual/...`` and
+# ``textual/...`` into every operation's path.
+LOSS_ISLAND_SCOPE = "loss_island"  # the sharded sigmoid loss, forward and backward
+ACCUM_SCOPE = "accum"  # the gradient accumulator's traffic in the microbatch scan
+STEP_METRICS_SCOPE = "step_metrics"  # the health scalars every step pays
 
 
 def resolve_loss_quant(model: nn.Module, loss_cfg) -> str:
@@ -274,25 +289,54 @@ def validate_step_args(
 
 def accum_zeros(params, acc_dt):
     """Zeroed gradient accumulator in ``acc_dt`` (None = param dtype)."""
-    return jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dt or p.dtype), params)
+    with jax.named_scope(ACCUM_SCOPE):
+        return jax.tree.map(
+            lambda p: jnp.zeros(p.shape, acc_dt or p.dtype), params
+        )
 
 
 def accum_add(acc, g):
     """Upcast-add-round: the sum itself stays f32 per microstep even when the
     carried accumulator is bf16 — THE bf16-accumulator rounding contract
     (tests/test_train_step.py::test_bf16_accumulator_tracks_f32)."""
-    return jax.tree.map(
-        lambda a, g_: (a.astype(g_.dtype) + g_).astype(a.dtype), acc, g
-    )
+    with jax.named_scope(ACCUM_SCOPE):
+        return jax.tree.map(
+            lambda a, g_: (a.astype(g_.dtype) + g_).astype(a.dtype), acc, g
+        )
 
 
 def accum_finish(acc, params, scale=None):
     """Back to param dtype, optionally divided by ``scale`` (the microstep
     count, when the carried value is a sum rather than a mean)."""
-    return jax.tree.map(
-        lambda a, p: (a.astype(p.dtype) / scale if scale else a.astype(p.dtype)),
-        acc, params,
-    )
+    with jax.named_scope(ACCUM_SCOPE):
+        return jax.tree.map(
+            lambda a, p: (
+                a.astype(p.dtype) / scale if scale else a.astype(p.dtype)
+            ),
+            acc, params,
+        )
+
+
+def health_metrics(loss, lp, grads, params, prev_params) -> dict:
+    """The metrics every step flavor reports (the metrics-line contract must
+    not differ per step mode): loss, temperature, bias and the health scalars
+    obs/health.py's watchdog reads — ``grad_norm``, ``param_norm`` and the
+    update-to-param ratio. The per-leaf diff is transient (XLA fuses it into
+    the norm reduction) and the norms are scalar reductions: the cheap in-step
+    tier, read off the metrics line without any extra device sync."""
+    with jax.named_scope(STEP_METRICS_SCOPE):
+        param_norm = optax.global_norm(params)
+        update_norm = optax.global_norm(
+            jax.tree.map(lambda n, o: n - o, params, prev_params)
+        )
+        return {
+            "loss": loss,
+            "t": jnp.exp(lp["t_prime"]),
+            "bias": lp["bias"],
+            "grad_norm": optax.global_norm(grads),
+            "param_norm": param_norm,
+            "update_ratio": update_norm / (param_norm + 1e-12),
+        }
 
 
 def run_gradcache(
@@ -334,9 +378,10 @@ def run_gradcache(
     _, (zis, zts, lps) = lax.scan(embed, None, micro)
     lp = jax.tree.map(lambda x: x[-1], lps)
 
-    loss, island_grads = jax.value_and_grad(island, argnums=(0, 1, 2, 3))(
-        zis, zts, lp["t_prime"], lp["bias"]
-    )
+    with jax.named_scope(LOSS_ISLAND_SCOPE):
+        loss, island_grads = jax.value_and_grad(island, argnums=(0, 1, 2, 3))(
+            zis, zts, lp["t_prime"], lp["bias"]
+        )
     g_zis, g_zts, g_tp, g_bias = jax.tree.map(lax.stop_gradient, island_grads)
 
     def surrogate(p, mb, g_zi, g_zt):
@@ -755,7 +800,8 @@ def make_train_step(
                 mutable=["intermediates"],
             )
             aux = _mean_moe_aux(variables)
-        loss = sharded_loss(zimg, ztxt, lp["t_prime"], lp["bias"])
+        with jax.named_scope(LOSS_ISLAND_SCOPE):
+            loss = sharded_loss(zimg, ztxt, lp["t_prime"], lp["bias"])
         if moe_aux_weight is not None:
             loss = loss + moe_aux_weight * aux
         return loss, (lp, aux)
@@ -868,28 +914,14 @@ def make_train_step(
                 )
             from distributed_sigmoid_loss_tpu.train.ema import update_ema
 
-            state = state.replace(
-                ema=update_ema(
-                    state.ema, state.params, step=prev_step, decay=ema_decay
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                state = state.replace(
+                    ema=update_ema(
+                        state.ema, state.params, step=prev_step,
+                        decay=ema_decay,
+                    )
                 )
-            )
-        # Health scalars (obs/health.py watchdog inputs): param_norm and the
-        # update-to-param ratio. The per-leaf diff is transient (XLA fuses it
-        # into the norm reduction) and the norms are scalar reductions — the
-        # cheap in-step tier; the host-side spike/NaN detection reads these
-        # off the metrics line without any extra device sync.
-        param_norm = optax.global_norm(state.params)
-        update_norm = optax.global_norm(
-            jax.tree.map(lambda n, o: n - o, state.params, prev_params)
-        )
-        metrics = {
-            "loss": loss,
-            "t": jnp.exp(lp["t_prime"]),
-            "bias": lp["bias"],
-            "grad_norm": optax.global_norm(grads),
-            "param_norm": param_norm,
-            "update_ratio": update_norm / (param_norm + 1e-12),
-        }
+        metrics = health_metrics(loss, lp, grads, state.params, prev_params)
         if moe_aux_weight is not None:
             metrics["moe_aux"] = aux
         return state, metrics

@@ -3,6 +3,7 @@ cache goes. (That chip_smoke.py passes ON the chip is proven by running it
 there — PERF.md's bring-up table; nothing here can show it.)"""
 
 import os
+import re
 import subprocess
 import sys
 
@@ -45,15 +46,22 @@ def test_no_chip_means_nonzero_exit_and_no_result():
 
 def test_compile_cache_dir_rule(monkeypatch):
     """A set JAX_COMPILATION_CACHE_DIR is left alone (jax reads it itself);
-    unset, the cache is <checkout>/.jax_cache."""
+    unset, the cache is <checkout>/.jax_cache. Either way the key includes the
+    program's metadata, with source files named relative to the checkout
+    (tests/test_scopes.py says why)."""
     updates = []
     monkeypatch.setattr(jax.config, "update", lambda k, v: updates.append((k, v)))
+    metadata_in_key = ("jax_compilation_cache_include_metadata_in_key", True)
+    relative_sources = (
+        "jax_hlo_source_file_canonicalization_regex", "^" + re.escape(REPO + os.sep)
+    )
 
     monkeypatch.setenv(compile_cache.CACHE_ENV, "/some/dir")
     assert compile_cache.configure_compile_cache() == "/some/dir"
-    assert updates == []
+    assert updates == [metadata_in_key, relative_sources]
 
     monkeypatch.delenv(compile_cache.CACHE_ENV)
+    del updates[:]
     want = os.path.join(REPO, ".jax_cache")
     assert compile_cache.configure_compile_cache() == want
-    assert updates == [("jax_compilation_cache_dir", want)]
+    assert updates == [metadata_in_key, relative_sources, ("jax_compilation_cache_dir", want)]

@@ -91,6 +91,43 @@ def test_disabled_spans_are_allocation_free():
     assert rec.spans() == []
 
 
+def test_disabled_spans_touch_no_jax():
+    """obs/ imports without initializing jax: spans.py names nothing of jax at
+    module level, a disabled recorder never reaches the profiler, and a fresh
+    process that imports obs/ and runs the disabled path has no backend."""
+    import subprocess
+    import sys
+
+    import distributed_sigmoid_loss_tpu.obs.spans as spans_module
+
+    assert not any(
+        getattr(v, "__name__", "").split(".")[0] == "jax"
+        or getattr(v, "__module__", "").split(".")[0] == "jax"
+        for v in vars(spans_module).values()
+    )
+    code = (
+        "from distributed_sigmoid_loss_tpu.obs import SpanRecorder\n"
+        "import jax.profiler\n"
+        "def boom(*a, **k): raise AssertionError('the disabled path opened an annotation')\n"
+        "jax.profiler.TraceAnnotation = boom\n"
+        "rec = SpanRecorder(enabled=False)\n"
+        "with rec.span('hot'): pass\n"
+        "rec.record('cross', 0.0, 1.0)\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge._backends, 'a backend was initialized'\n"
+        "rec.enable()\n"
+        "try:\n"
+        "    with rec.span('hot'): pass\n"
+        "except AssertionError: print('enabled-path-reached-the-profiler')\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=120,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0, out.stderr
+    assert "enabled-path-reached-the-profiler" in out.stdout
+
+
 def test_enabled_spans_record_and_nest():
     rec = SpanRecorder()
     with rec.span("outer"):
@@ -151,18 +188,53 @@ def test_chrome_trace_export_and_summarize(tmp_path):
     assert summary["step"]["total_ms"] >= 0.0
 
 
+def host_plane_events(logdir, names):
+    """Events called one of ``names`` in the host plane of the one profiler
+    capture under ``logdir``: (name, start_ns, duration_ns)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    [path] = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True)
+    return [
+        (ev.name, ev.start_ns, ev.duration_ns)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+        if ev.name in names
+    ]
+
+
 def test_obs_summarize_merges_host_and_device(tmp_path, capsys):
     """The acceptance surface: one `obs summarize DIR` over a dir holding
     BOTH a host-span export and a device capture (the gzipped Perfetto JSON
     utils.profiling.trace writes) prints the host table AND the device
-    hlo_category table, and --merged-out combines every event."""
+    hlo_category table. The one file that holds both halves on one clock is
+    the profiler's own: while a capture runs, an enabled recorder's span is
+    in its host plane, nested as it was on the host and as long as the
+    recorder says."""
     import gzip
 
     from distributed_sigmoid_loss_tpu.cli import main
 
     rec = SpanRecorder()
-    with rec.span("step"):
-        pass
+    capture = tmp_path / "capture"
+    jax.profiler.start_trace(str(capture))
+    try:
+        with rec.span("step"):
+            with rec.span("h2d_commit"):
+                jnp.ones((64, 64)).sum().block_until_ready()
+        rec.record("queue_wait", 0.0, 1.0)  # cross-thread: perf_counter only
+    finally:
+        jax.profiler.stop_trace()
+    seen = {n: (t0, dur) for n, t0, dur in host_plane_events(
+        str(capture), {"step", "h2d_commit", "queue_wait"})}
+    assert set(seen) == {"step", "h2d_commit"}
+    (outer0, outer_dur), (inner0, inner_dur) = seen["step"], seen["h2d_commit"]
+    assert outer0 <= inner0 and inner0 + inner_dur <= outer0 + outer_dur
+    by_name = {s.name: s for s in rec.spans()}
+    assert outer_dur / 1e9 == pytest.approx(by_name["step"].duration_s, abs=2e-3)
     rec.export(str(tmp_path / "host_spans.trace.json"))
     device_events = [
         {"ph": "M", "name": "thread_name", "pid": 7, "tid": 1,
@@ -177,16 +249,10 @@ def test_obs_summarize_merges_host_and_device(tmp_path, capsys):
     ]
     with gzip.open(tmp_path / "dev.trace.json.gz", "wt") as f:
         json.dump({"traceEvents": device_events}, f)
-    merged = str(tmp_path / "merged.json")
-    assert main(["obs", "summarize", str(tmp_path),
-                 "--merged-out", merged]) == 0
+    assert main(["obs", "summarize", str(tmp_path)]) == 0
     out = capsys.readouterr().out
     assert "host spans" in out and "step" in out
     assert "hlo_category" in out and "convolution fusion" in out
-    with open(merged) as f:
-        events = json.load(f)["traceEvents"]
-    # host X event + both device X events survive the merge
-    assert sum(1 for e in events if e.get("ph") == "X") == 3
 
 
 def test_obs_summarize_cli(tmp_path, capsys):
@@ -199,13 +265,20 @@ def test_obs_summarize_cli(tmp_path, capsys):
     assert main(["obs", "summarize", str(tmp_path)]) == 0
     out = capsys.readouterr().out
     assert "host spans" in out and "step" in out
-    # merged trace output
-    merged = str(tmp_path / "merged.json")
-    assert main(["obs", "summarize", str(tmp_path),
-                 "--merged-out", merged]) == 0
+    # --merged-out is gone (the merged file never related the two clocks);
+    # a disabled recorder puts nothing into a capture that is running.
+    with pytest.raises(SystemExit):
+        main(["obs", "summarize", str(tmp_path), "--merged-out", "m.json"])
     capsys.readouterr()
-    with open(merged) as f:
-        assert json.load(f)["traceEvents"]
+    quiet = SpanRecorder(enabled=False)
+    jax.profiler.start_trace(str(tmp_path / "capture"))
+    try:
+        with rec.span("eval"), quiet.span("checkpoint"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    seen = host_plane_events(str(tmp_path / "capture"), {"eval", "checkpoint"})
+    assert [name for name, _, _ in seen] == ["eval"]
     # empty dir is a usage error, not a crash
     empty = tmp_path / "empty"
     empty.mkdir()
