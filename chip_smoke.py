@@ -4,9 +4,9 @@
 One process, no child that needs the chip. On a TPU whose ``device_kind`` the
 peaks table lists it:
 
-1. compiles every Pallas kernel the package ships at B/16's real shapes with
-   ``interpret=False`` and compares each with the repo's plain ``jax.numpy``
-   reference;
+1. compiles every Pallas kernel the package ships at B/16's real shapes (the
+   fused attention also at so400m's heads, 16 x 72) with ``interpret=False``
+   and compares each with the repo's plain ``jax.numpy`` reference;
 2. checks the ring and all-gather sharded losses (loss and grads) against the
    single-device ``sigmoid_loss`` on the same global batch;
 3. builds the B/16 train step (``SigLIPConfig.b16()``: width 768, depth 12,
@@ -25,6 +25,8 @@ last stdout line is ``{"ok": true, "device": {...}}`` with the device as jax
 reports it.
 
     python chip_smoke.py
+    python chip_smoke.py --time-attention   # only: the attention kernels alone,
+                                            # us per program and per head
 """
 
 from __future__ import annotations
@@ -120,9 +122,19 @@ REPORT: dict = {"compile_s": {}, "parity": {}}
 # ---------------------------------------------------------------------------
 
 
+# (name, batch, seq, heads, head size): what one microbatch of a benchmark cell
+# hands each tower's attention (b16-bs256; so400m-mb32x4).
+TOWER_SHAPES = [
+    ("b16_vision", 256, 196, 12, 64),
+    ("b16_text", 256, 64, 12, 64),
+    ("so400m_vision", 32, 256, 16, 72),
+    ("so400m_text", 32, 64, 16, 72),
+]
+
+
 def phase_attention_kernels() -> None:
-    """short_self_attention at both tower shapes and flash_self_attention past
-    the short kernel's envelope, fwd+bwd in bf16, against dense_attention."""
+    """short_self_attention at the four tower shapes and flash_self_attention
+    past the short kernel's envelope, fwd+bwd in bf16, against dense_attention."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -141,21 +153,20 @@ def phase_attention_kernels() -> None:
     def short(q, k, v):
         return short_self_attention(q, k, v, False, None, False)  # interpret=False
 
-    # (name, kernel, batch, seq); h=12, dh=64 = B/16's heads on both towers.
+    # (name, kernel, batch, seq, heads, head size): the four tower shapes at a
+    # small batch, and one sequence past the short kernel's envelope.
     cases = [
-        ("short_attn_s196", short, 8, 196),
-        ("short_attn_s64", short, 8, 64),
-        ("flash_attn_s2048", flash_self_attention, 2, 2048),
-    ]
+        (f"short_attn_{name}", short, 8, s, h, dh) for name, _, s, h, dh in TOWER_SHAPES
+    ] + [("flash_attn_s2048", flash_self_attention, 2, 2048, 12, 64)]
     rng = np.random.default_rng(0)
     problems = []
-    for name, kernel, b, s in cases:
+    for name, kernel, b, s, h, dh in cases:
         check(
-            short_attention_fits(s, 768, 2) == (kernel is short),
+            short_attention_fits(s, h * dh, 2) == (kernel is short),
             f"{name}: dispatch envelope disagrees with the smoke's case table",
         )
         q, k, v, w = (
-            jnp.asarray(rng.standard_normal((b, s, 12, 64)), jnp.bfloat16)
+            jnp.asarray(rng.standard_normal((b, s, h, dh)), jnp.bfloat16)
             for _ in range(4)
         )
 
@@ -173,6 +184,80 @@ def phase_attention_kernels() -> None:
         # (tests/test_flash_attention.py) is 2e-2 forward, 5e-2 gradients.
         problems += parity(name, "out/dq/dk/dv", got, want, 2e-2, 5e-2)
     check(not problems, "; ".join(problems))
+
+
+def kernel_device_us(trace_dir: str, kernels) -> dict:
+    """Median device duration in us of each named Pallas kernel in the newest
+    trace under ``trace_dir``: the custom call's events on the device planes'
+    "XLA Ops" line, found by the kernel's ``name=`` as benchmark/scopes.py does."""
+    import os
+    import statistics
+
+    from jax.profiler import ProfileData
+
+    found = [os.path.join(base, f) for base, _, files in os.walk(trace_dir)
+             for f in files if f.endswith(".xplane.pb")]
+    check(found, f"the profiler wrote no .xplane.pb under {trace_dir}")
+    durations = {k: [] for k in kernels}
+    for plane in ProfileData.from_file(max(found, key=os.path.getmtime)).planes:
+        if not plane.name.startswith("/device:TPU"):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for event in line.events:
+                # An event is named by its HLO instruction, on this libtpu by the
+                # whole line "%name = ...": the name alone decides (a copy next
+                # to the kernel carries the kernel's name in its metadata). Under
+                # a transformation the name is wrapped: transpose_jvp_<kernel>__.
+                instruction = event.name.split(" = ")[0]
+                kernel = next((k for k in kernels if k in instruction), None)
+                if kernel:
+                    durations[kernel].append(event.duration_ns / 1e3)
+    for kernel, found_us in durations.items():
+        check(found_us, f"no event of {kernel} on a device plane's XLA Ops line")
+    return {k: statistics.median(v) for k, v in durations.items()}
+
+
+def phase_attention_timing() -> None:
+    """``python chip_smoke.py --time-attention``: short_attn_fwd and
+    short_attn_bwd alone at the four tower shapes, bf16, device time from the
+    profiler, as us per call, per grid program (one batch row) and per head.
+    Where a kernel PR starts: the per-head cost that does not follow the
+    sequence length is read off the text / vision pairs (PERF.md section 5)."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_sigmoid_loss_tpu.ops.pallas_short_attention import (
+        short_self_attention,
+    )
+
+    rng = np.random.default_rng(0)
+    REPORT["attention_us"] = {}
+    print("  shape            kernel           us/call  us/program  us/head")
+    for name, b, s, h, dh in TOWER_SHAPES:
+        q, k, v, g = (
+            jnp.asarray(rng.standard_normal((b, s, h, dh)), jnp.bfloat16)
+            for _ in range(4)
+        )
+        fwd = jax.jit(short_self_attention)
+        bwd = jax.jit(lambda q, k, v, g: jax.vjp(short_self_attention, q, k, v)[1](g))
+        jax.block_until_ready((fwd(q, k, v), bwd(q, k, v, g)))
+        with tempfile.TemporaryDirectory() as trace_dir:
+            with jax.profiler.trace(trace_dir):
+                for _ in range(8):  # the median of eight calls of each kernel
+                    out = (fwd(q, k, v), bwd(q, k, v, g))
+                jax.block_until_ready(out)
+            us = kernel_device_us(trace_dir, ("short_attn_fwd", "short_attn_bwd"))
+        for kernel, t in us.items():
+            row = {"us_per_call": t, "us_per_program": t / b,
+                   "us_per_head": t / b / h}
+            REPORT["attention_us"][f"{name}/{kernel}"] = row
+            print(f"  {name:16s} {kernel:15s} {t:8.1f}  {t / b:10.3f}  "
+                  f"{t / b / h:7.4f}")
 
 
 def phase_loss_kernel() -> None:
@@ -423,6 +508,10 @@ def phase_train_step(mesh) -> None:
 
 def main() -> int:
     t_start = time.perf_counter()
+    time_attention = sys.argv[1:] == ["--time-attention"]
+    if sys.argv[1:] and not time_attention:
+        print("usage: python chip_smoke.py [--time-attention]", file=sys.stderr)
+        return 2
     # ImportError here = a directory without the package: non-zero, no result.
     from distributed_sigmoid_loss_tpu.obs.attribution import CHIP_SPECS
     from distributed_sigmoid_loss_tpu.utils.compile_cache import (
@@ -455,7 +544,7 @@ def main() -> int:
     from distributed_sigmoid_loss_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh()
-    phases = [
+    phases = [("attention timing", phase_attention_timing)] if time_attention else [
         ("attention kernels", phase_attention_kernels),
         ("loss kernel", phase_loss_kernel),
         ("sharded loss", lambda: phase_sharded_loss(mesh)),

@@ -10,13 +10,28 @@ activations in the whole SigLIP step (7G+ stacked across layers at batch 256).
 
 Design: the kernel consumes q/k/v in the towers' NATIVE (b, s, h·dh) layout — no
 transposes, no layout padding (a (s, width) tile is exactly aligned); one program =
-one batch row, heads handled by a static Python loop over lane slices. Everything
+one batch row, heads handled by a static Python loop over the lanes. Everything
 O(s²) lives and dies in VMEM: logits → softmax → out in forward, the 5-matmul
 gradient chain in backward (probs recomputed, never stored). HBM traffic collapses
 to the unavoidable q/k/v/out (+gradients) reads and writes — measured 5.8× faster
 than the dense path at ViT-B/16 scale, 2.9× at text-tower scale. Numerics: f32
 logits / softmax / accumulation, matmul inputs in the activation dtype (bf16 in
 training) — the same contract as the dense path.
+
+The backward holds its logits KEY-major, (s_k, s_q) = k·qᵀ (PERF.md section 6,
+PR 24). The forward's query-major chain, run backwards, contracts twice over the
+rows of an (s, s) tile (dv = pᵀ·do, dk = dsᵀ·q: Mosaic transposes the tile for
+each) and reduces three times across lanes per head; none of that grows with the
+sequence the way the matmuls do, so at s = 64 it was most of the kernel. Key-major,
+pᵀ and dsᵀ are what the chain holds: dv and dk are plain products, the softmax
+statistics and the VJP's sum(dp ⊙ p) are reductions down the sublanes (adds, then
+one (1, s) row), and only dq = ds·k still contracts over rows. Heads are cut out
+of the lanes in the cheapest way the head size allows, chosen at trace time from
+the operands' shapes (:func:`_bwd_kernel`): any dh — lane slices, as the forward;
+dh = 64 — two heads fill one 128-lane slab, loaded and stored whole, a head
+selected by zeroing the other's lanes in one operand of each product (no rotate
+for a 64-lane offset); dh = 64 and 2·s ≤ 128 (the text tower) — the two heads'
+transposed logits share one lane tile, so the pair costs one chain, not two.
 
 No reference analogue (the reference has no model layer, SURVEY.md §1); this is the
 "pallas kernels for the hot ops" piece of the TPU-first design.
@@ -83,6 +98,10 @@ def reset_traced_bwd_batch_heads() -> None:
 
 _NEG_INF = -1e30
 
+# Lanes of a vector register: where two heads fill them exactly (dh = 64) the
+# backward works on whole slabs.
+_LANES = 128
+
 # Above this sequence length the O(s²) per-head blocks stop fitting VMEM comfortably
 # and a blockwise (true flash / ring) kernel wins; dispatch there instead.
 SHORT_ATTENTION_MAX_SEQ = 1024
@@ -98,9 +117,12 @@ def short_attention_vmem_bytes(s: int, width: int, dtype_bytes: int) -> int:
     """Worst-case VMEM footprint of ONE grid program (width = h·dh).
 
     The backward program is the peak: 7 (s, width) I/O blocks (q, k, v, do, dq, dk,
-    dv) resident for the whole program, plus ~3 live (s, s) f32 per-head
-    intermediates (probs, dp, ds — the compiler can reuse across heads but not
-    within the chain).
+    dv) resident for the whole program, plus 3 live (s, s) f32 intermediates of
+    the key-major chain (pᵀ, dpᵀ, dsᵀ — the compiler reuses them across heads but
+    not within the chain; its one transposed copy of dsᵀ for dq is bf16). Where
+    two heads share a slab the tiles are the same count: (s, 2s) pairs at
+    2·s ≤ 128, where a pair is smaller than one padded (s, 128) tile, and the
+    two heads of a masked pair run one after the other.
     """
     return 7 * s * width * dtype_bytes + 3 * s * s * 4
 
@@ -160,26 +182,98 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, num_heads):
         o_ref[0, :, sl] = _dot(p.astype(v.dtype), v[:, sl], 1, 0).astype(o_ref.dtype)
 
 
+def _key_major_grads(k_x, q_x, v_x, do_x, *, scale, visible):
+    """The gradient chain of one group of query columns, logits held KEY-major
+    (why: the module docstring).
+
+    ``k_x``/``v_x`` are (s_k, c), ``q_x``/``do_x`` (n, c): ``k_x·q_xᵀ`` is the
+    (s_k, n) transposed logits tile (the transposed-RHS product the forward
+    uses) and every per-query statistic is a reduction over axis 0. Only
+    ``dq = ds·k`` contracts over rows: the one tile Mosaic still transposes.
+    ``visible`` is the (s_k, n) causal mask or None. Returns f32
+    (dq (n, c), dk (s_k, c), dv (s_k, c))."""
+    lt = _dot(k_x, q_x, 1, 1) * scale  # (s_k, n) f32
+    if visible is not None:
+        lt = jnp.where(visible, lt, _NEG_INF)
+    e = jnp.exp(lt - jnp.max(lt, axis=0, keepdims=True))
+    pt = e / jnp.sum(e, axis=0, keepdims=True)  # pᵀ
+    dv = _dot(pt.astype(do_x.dtype), do_x, 1, 0)  # pᵀ @ do
+    dpt = _dot(v_x, do_x, 1, 1)  # (do @ vᵀ)ᵀ
+    # Softmax VJP, transposed: dsᵀ = pᵀ ⊙ (dpᵀ − colsum(dpᵀ ⊙ pᵀ)), then the scale.
+    dst = ((pt * (dpt - jnp.sum(dpt * pt, axis=0, keepdims=True))) * scale).astype(
+        q_x.dtype
+    )
+    dk = _dot(dst, q_x, 1, 0)  # dsᵀ @ q
+    dq = _dot(dst, k_x, 0, 0)  # ds @ k
+    return dq, dk, dv
+
+
 def _bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, dq_ref, dk_ref, dv_ref, *, scale, causal, num_heads
 ):
+    """One batch row: :func:`_key_major_grads` per head, or per pair of heads,
+    the heads cut out of the lanes as cheaply as the head size allows (chosen
+    here, at trace time, from the operands' shapes; measured in PERF.md
+    section 6, PR 24)."""
     q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-    dh = q.shape[-1] // num_heads
-    for j in range(num_heads):
-        sl = slice(j * dh, (j + 1) * dh)
-        qh, kh, vh, doh = q[:, sl], k[:, sl], v[:, sl], do[:, sl]
-        # Recompute this head's probs entirely in VMEM.
-        p = _head_probs(qh, kh, scale=scale, causal=causal)  # (s, s) f32
-        p_lo = p.astype(vh.dtype)
-        do_lo = doh.astype(vh.dtype)
-        dv_ref[0, :, sl] = _dot(p_lo, do_lo, 0, 0).astype(dv_ref.dtype)  # pᵀ @ do
-        dp = _dot(do_lo, vh, 1, 1)  # (s, s): do @ vᵀ
-        # Softmax VJP: ds = p ⊙ (dp − rowsum(dp ⊙ p)), then the logits scale.
-        ds = ((p * (dp - jnp.sum(dp * p, axis=-1, keepdims=True))) * scale).astype(
-            qh.dtype
-        )
-        dq_ref[0, :, sl] = _dot(ds, kh, 1, 0).astype(dq_ref.dtype)  # ds @ k
-        dk_ref[0, :, sl] = _dot(ds, qh, 0, 0).astype(dk_ref.dtype)  # dsᵀ @ q
+    s, width = q.shape
+    dh = width // num_heads
+
+    def causal_mask(n):  # (s_k, n): key i is visible to query column j mod s
+        key = lax.broadcasted_iota(jnp.int32, (s, n), 0)
+        query = lax.broadcasted_iota(jnp.int32, (s, n), 1)
+        return jnp.where(query >= s, query - s, query) >= key
+
+    def store(lanes, grads):
+        for ref, g in zip((dq_ref, dk_ref, dv_ref), grads):
+            ref[0, :, lanes] = g.astype(ref.dtype)
+
+    grads_of = functools.partial(_key_major_grads, scale=scale)
+    if 2 * dh != _LANES or num_heads % 2:
+        # Any head size: one head at a time, cut out by lane slices as in the
+        # forward (so400m's dh = 72: no head starts on a register boundary).
+        visible = causal_mask(s) if causal else None
+        for j in range(num_heads):
+            sl = slice(j * dh, (j + 1) * dh)
+            store(sl, grads_of(k[:, sl], q[:, sl], v[:, sl], do[:, sl], visible=visible))
+        return
+    # dh = 64: two heads fill one aligned 128-lane slab, and every load and store
+    # is a whole slab. A head is cut out by zeroing the other head's lanes (a
+    # contraction over 128 lanes, half of them zero, costs the MXU what one over
+    # 64 does), never by a slice at a 64-lane offset, which costs a cross-lane
+    # rotate per register of every operand and result.
+    zero = jnp.zeros((), q.dtype)
+    first = lax.broadcasted_iota(jnp.int32, (s, _LANES), 1) < dh
+    packed = 2 * s <= _LANES and s % 16 == 0
+    if packed:
+        # The text tower: both heads' transposed logits fit one lane tile. Stack
+        # the slab's queries twice along rows, rows [0, s) keeping the first
+        # head's lanes and rows [s, 2s) the second's: k·qqᵀ is [LTa | LTb], and
+        # pᵀ·dd and dsᵀ·qq come out as the finished dv and dk slabs. One chain
+        # for the pair, on full registers.
+        rows = lax.broadcasted_iota(jnp.int32, (2 * s, _LANES), 0)
+        lanes = lax.broadcasted_iota(jnp.int32, (2 * s, _LANES), 1)
+        keep = (rows < s) == (lanes < dh)
+    visible = causal_mask(2 * s if packed else s) if causal else None
+    for j in range(num_heads // 2):
+        sl = slice(_LANES * j, _LANES * (j + 1))
+        q2, k2, v2, do2 = q[:, sl], k[:, sl], v[:, sl], do[:, sl]
+        if packed:
+            qq = jnp.where(keep, jnp.concatenate([q2, q2], axis=0), zero)
+            dd = jnp.where(keep, jnp.concatenate([do2, do2], axis=0), zero)
+            dqq, dk2, dv2 = grads_of(k2, qq, v2, dd, visible=visible)
+            # (2s, 128): the diagonal blocks are the two heads' dq.
+            store(sl, (jnp.where(first, dqq[:s], dqq[s:]), dk2, dv2))
+        else:
+            # One head, then the other, each embedded in the zero-padded slab:
+            # its gradients are zero on the other head's lanes, so the slab's
+            # are the sum.
+            halves = [
+                grads_of(jnp.where(m, k2, zero), jnp.where(m, q2, zero), v2,
+                         jnp.where(m, do2, zero), visible=visible)
+                for m in (first, ~first)
+            ]
+            store(sl, [a + b for a, b in zip(*halves)])
 
 
 def _bwd_kernel_batched(

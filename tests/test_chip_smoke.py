@@ -15,31 +15,37 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_no_chip_means_nonzero_exit_and_no_result():
-    """chip_smoke.py refuses before any compile and prints no result;
-    bench.py exits non-zero and prints no metric row (no ``value: 0.0`` under
-    a device metric's name). Both at once: the cost is two interpreter
-    start-ups, side by side."""
+    """chip_smoke.py refuses before any compile and prints no result, in its
+    timing mode too (no time from a CPU under a kernel's name); bench.py exits
+    non-zero and prints no metric row (no ``value: 0.0`` under a device
+    metric's name). All at once: the cost is three interpreter start-ups, side
+    by side."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
+    commands = (
+        ("chip_smoke.py",), ("chip_smoke.py", "--time-attention"), ("bench.py",),
+    )
     procs = {
-        script: subprocess.Popen(
-            [sys.executable, os.path.join(REPO, script)],
+        command: subprocess.Popen(
+            [sys.executable, os.path.join(REPO, command[0]), *command[1:]],
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
             env=env, cwd=REPO,
         )
-        for script in ("chip_smoke.py", "bench.py")
+        for command in commands
     }
     out = {}
-    for script, proc in procs.items():
+    for command, proc in procs.items():
         stdout, stderr = proc.communicate(timeout=120)
-        assert proc.returncode not in (0, None), (script, stdout, stderr[-500:])
-        out[script] = (stdout, stderr)
+        assert proc.returncode not in (0, None), (command, stdout, stderr[-500:])
+        out[command] = (stdout, stderr)
 
-    stdout, stderr = out["chip_smoke.py"]
-    assert "platform=cpu" in stdout  # the banner names what it found
-    assert "refusing to run" in stderr
-    assert '"ok"' not in stdout and "==" not in stdout and "compile" not in stdout
+    for command in commands[:2]:
+        stdout, stderr = out[command]
+        assert "platform=cpu" in stdout  # the banner names what it found
+        assert "refusing to run" in stderr
+        assert '"ok"' not in stdout and "==" not in stdout and "compile" not in stdout
+        assert "us/" not in stdout
 
-    stdout, stderr = out["bench.py"]
+    stdout, stderr = out[("bench.py",)]
     assert stdout.strip() == "", stdout  # no row of any kind
     assert "no metric row written" in stderr
 
