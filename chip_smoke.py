@@ -32,6 +32,7 @@ reports it.
 from __future__ import annotations
 
 import contextlib
+import functools
 import io
 import json
 import math
@@ -122,18 +123,20 @@ REPORT: dict = {"compile_s": {}, "parity": {}}
 # ---------------------------------------------------------------------------
 
 
-# (name, batch, seq, heads, head size): what one microbatch of a benchmark cell
-# hands each tower's attention (b16-bs256; so400m-mb32x4).
+# (name, batch, seq, heads, head size, causal): what one microbatch of a benchmark
+# cell hands each tower's attention (b16-bs256; so400m-mb32x4; ouro-b16-mb32x2,
+# whose image tower is B/16's).
 TOWER_SHAPES = [
-    ("b16_vision", 256, 196, 12, 64),
-    ("b16_text", 256, 64, 12, 64),
-    ("so400m_vision", 32, 256, 16, 72),
-    ("so400m_text", 32, 64, 16, 72),
+    ("b16_vision", 256, 196, 12, 64, False),
+    ("b16_text", 256, 64, 12, 64, False),
+    ("so400m_vision", 32, 256, 16, 72, False),
+    ("so400m_text", 32, 64, 16, 72, False),
+    ("ouro_text", 32, 256, 16, 128, True),
 ]
 
 
 def phase_attention_kernels() -> None:
-    """short_self_attention at the four tower shapes and flash_self_attention
+    """short_self_attention at the tower shapes and flash_self_attention
     past the short kernel's envelope, fwd+bwd in bf16, against dense_attention."""
     import jax
     import jax.numpy as jnp
@@ -150,17 +153,18 @@ def phase_attention_kernels() -> None:
         dense_attention,
     )
 
-    def short(q, k, v):
-        return short_self_attention(q, k, v, False, None, False)  # interpret=False
+    def short(q, k, v, causal=False):
+        return short_self_attention(q, k, v, causal, None, False)  # interpret=False
 
-    # (name, kernel, batch, seq, heads, head size): the four tower shapes at a
-    # small batch, and one sequence past the short kernel's envelope.
+    # (name, kernel, batch, seq, heads, head size, causal): the tower shapes at
+    # a small batch, and one sequence past the short kernel's envelope.
     cases = [
-        (f"short_attn_{name}", short, 8, s, h, dh) for name, _, s, h, dh in TOWER_SHAPES
-    ] + [("flash_attn_s2048", flash_self_attention, 2, 2048, 12, 64)]
+        (f"short_attn_{name}", short, 8, s, h, dh, causal)
+        for name, _, s, h, dh, causal in TOWER_SHAPES
+    ] + [("flash_attn_s2048", flash_self_attention, 2, 2048, 12, 64, False)]
     rng = np.random.default_rng(0)
     problems = []
-    for name, kernel, b, s, h, dh in cases:
+    for name, kernel, b, s, h, dh, causal in cases:
         check(
             short_attention_fits(s, h * dh, 2) == (kernel is short),
             f"{name}: dispatch envelope disagrees with the smoke's case table",
@@ -178,8 +182,8 @@ def phase_attention_kernels() -> None:
                 fn(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
             )
 
-        got = compile_and_run(name, fwd_bwd(kernel), q, k, v)
-        want = jax.jit(fwd_bwd(dense_attention))(q, k, v)
+        got = compile_and_run(name, fwd_bwd(functools.partial(kernel, causal=causal)), q, k, v)
+        want = jax.jit(fwd_bwd(functools.partial(dense_attention, causal=causal)))(q, k, v)
         # bf16 operands, f32 softmax on both sides: the repo's on-chip bound
         # (tests/test_flash_attention.py) is 2e-2 forward, 5e-2 gradients.
         problems += parity(name, "out/dq/dk/dv", got, want, 2e-2, 5e-2)
@@ -221,7 +225,7 @@ def kernel_device_us(trace_dir: str, kernels) -> dict:
 
 def phase_attention_timing() -> None:
     """``python chip_smoke.py --time-attention``: short_attn_fwd and
-    short_attn_bwd alone at the four tower shapes, bf16, device time from the
+    short_attn_bwd alone at the tower shapes, bf16, device time from the
     profiler, as us per call, per grid program (one batch row) and per head.
     Where a kernel PR starts: the per-head cost that does not follow the
     sequence length is read off the text / vision pairs (PERF.md section 5)."""
@@ -238,13 +242,14 @@ def phase_attention_timing() -> None:
     rng = np.random.default_rng(0)
     REPORT["attention_us"] = {}
     print("  shape            kernel           us/call  us/program  us/head")
-    for name, b, s, h, dh in TOWER_SHAPES:
+    for name, b, s, h, dh, causal in TOWER_SHAPES:
         q, k, v, g = (
             jnp.asarray(rng.standard_normal((b, s, h, dh)), jnp.bfloat16)
             for _ in range(4)
         )
-        fwd = jax.jit(short_self_attention)
-        bwd = jax.jit(lambda q, k, v, g: jax.vjp(short_self_attention, q, k, v)[1](g))
+        attend = functools.partial(short_self_attention, causal=causal)
+        fwd = jax.jit(attend)
+        bwd = jax.jit(lambda q, k, v, g, attend=attend: jax.vjp(attend, q, k, v)[1](g))
         jax.block_until_ready((fwd(q, k, v), bwd(q, k, v, g)))
         with tempfile.TemporaryDirectory() as trace_dir:
             with jax.profiler.trace(trace_dir):

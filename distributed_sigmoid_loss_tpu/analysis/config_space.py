@@ -436,7 +436,12 @@ def probe_imperative(cfg: StepConfig) -> tuple[bool, str]:
     compressed_step.validate_compressed_step_args, called with a superset
     mesh so environment-only refusals never fire). Tower-shape and
     state-content checks (validate_pp_tower, state.ema presence) are
-    environmental, not config-space, and are out of probe scope.
+    environmental, not config-space, and are out of probe scope: the text
+    tower's block options (utils.config.BLOCK_OPTIONS: norm, sandwich_norm,
+    mlp, use_bias, pos, loops) are no axis of this lattice, every step
+    builder takes them as it takes any tower, and the one axis whose builder
+    re-implements the block (``pp``) refuses each by name in
+    validate_pp_tower.
     """
     import argparse
 

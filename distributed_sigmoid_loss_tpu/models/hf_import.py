@@ -31,6 +31,7 @@ from distributed_sigmoid_loss_tpu.utils.config import (
     SigLIPConfig,
     TextConfig,
     ViTConfig,
+    changed_block_options,
 )
 
 __all__ = ["config_from_hf", "params_from_hf", "stack_for_scan"]
@@ -169,6 +170,15 @@ def params_from_hf(state_dict: Mapping, cfg: SigLIPConfig) -> dict:
         raise ValueError(
             "cfg must be HF-shaped (use_proj=False, text pool='last', "
             "scan_layers=False) — build it with config_from_hf"
+        )
+    changed = changed_block_options(cfg.text)
+    if changed:
+        # The mapping below is the SigLIP block's, tensor by tensor (ln1, wi,
+        # pos_embed): another block's tree must not be filled from it.
+        raise ValueError(
+            "params_from_hf maps HF SigLIP checkpoints, whose text block is the "
+            "default one; cfg.text sets "
+            + ", ".join(changed)
         )
     v = {
         "patch_embed": {

@@ -14,12 +14,14 @@ end-to-end target adds ViT-B/16 + text transformer. This core is built TPU-first
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 # Mesh axis name used by tensor-parallel kernel annotations (parallel/mesh.py).
@@ -105,6 +107,64 @@ def _remat_policy(name: str):
     raise ValueError(f"unknown remat_policy: {name!r}")
 
 
+@dataclasses.dataclass(frozen=True)
+class BlockStyle:
+    """What a block is made of: ``TextConfig``'s block options as the modules
+    take them (utils/config.py says what each means). The defaults are the
+    SigLIP block, which is all the image tower has."""
+
+    norm: str = "layernorm"  # "layernorm" | "rmsnorm"
+    sandwich_norm: bool = False
+    mlp: str = "gelu"  # "gelu" | "swiglu"
+    use_bias: bool = True
+    rope_theta: float | None = None  # None = no rotary positions
+
+    @classmethod
+    def of(cls, cfg) -> "BlockStyle":
+        return cls(
+            norm=cfg.norm, sandwich_norm=cfg.sandwich_norm, mlp=cfg.mlp,
+            use_bias=cfg.use_bias,
+            rope_theta=cfg.rope_theta if cfg.pos == "rope" else None,
+        )
+
+    def make_norm(self, dtype, name: str) -> nn.Module:
+        """Both kinds compute their statistics in float32 at eps 1e-6."""
+        if self.norm == "rmsnorm":
+            return nn.RMSNorm(dtype=dtype, name=name)
+        if self.norm == "layernorm":
+            return nn.LayerNorm(dtype=dtype, name=name)
+        raise ValueError(f"unknown norm: {self.norm!r}")
+
+
+def rope(x, theta: float):
+    """Rotary positions on a (b, s, h, dh) projection, rotate-half convention:
+    with the head's lanes cut in halves (x1, x2) and angle[p, i] = p / theta^(2i/dh),
+    out = (x1 cos - x2 sin, x2 cos + x1 sin) at positions p = 0..s-1.
+
+    That is x * cos + swap(x) * (-sin, sin), swap exchanging a head's halves. The
+    exchange is a product with a dh x dh permutation matrix: the MXU moves the
+    lanes, exactly (each output is one input times 1.0), and what is left is one
+    elementwise pass in float32. Written as split / negate / concatenate, XLA
+    moved the halves by relayouts and materialised the float32 copy: 187 ms of a
+    1752 ms step at dh 128, s 256 (PERF.md section 6, PR 25). The tables are
+    constants of the trace."""
+    s, dh = x.shape[1], x.shape[-1]
+    if dh % 2:
+        raise ValueError(f"pos='rope' needs an even head size, got {dh}")
+    angle = np.arange(s)[:, None] / theta ** (np.arange(0, dh, 2) / dh)  # (s, dh/2)
+    cos = np.concatenate([np.cos(angle), np.cos(angle)], -1)
+    sin = np.concatenate([-np.sin(angle), np.sin(angle)], -1)
+    cos, sin = (t.astype(np.float32)[None, :, None, :] for t in (cos, sin))
+    swap = jnp.asarray(np.roll(np.eye(dh), dh // 2, axis=0), x.dtype)
+    with jax.named_scope("rope"):
+        swapped = jax.lax.dot_general(
+            x, swap, (((3,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,  # a float32 tower's permutation stays exact
+            preferred_element_type=jnp.float32,
+        )
+        return (x.astype(jnp.float32) * cos + swapped * sin).astype(x.dtype)
+
+
 class Mlp(nn.Module):
     width: int
     # May be fractional (HF so400m: 4304/1152); the hidden dim is rounded back
@@ -112,24 +172,33 @@ class Mlp(nn.Module):
     mlp_ratio: int | float
     dtype: Any
     quant: bool | str = False  # "" | "int8" | "int8_ste" (see _dot_general)
+    kind: str = "gelu"  # "gelu": wo(gelu(wi x)) | "swiglu": wo(silu(wg x) * (wi x))
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
         hidden = int(round(self.width * self.mlp_ratio))
         dg = _dot_general(self.quant)
+        if self.kind not in ("gelu", "swiglu"):
+            raise ValueError(f"unknown mlp: {self.kind!r}")
+
         # Column-parallel in, row-parallel out: the tp all-reduce happens once, after wo.
-        wi = nn.Dense(
-            hidden,
-            dtype=self.dtype,
-            dot_general=dg,
-            kernel_init=nn.with_partitioning(
-                nn.initializers.xavier_uniform(), (None, TP_AXIS)
-            ),
-            name="wi",
-        )
+        def column_parallel(name):
+            return nn.Dense(
+                hidden,
+                dtype=self.dtype,
+                use_bias=self.use_bias,
+                dot_general=dg,
+                kernel_init=nn.with_partitioning(
+                    nn.initializers.xavier_uniform(), (None, TP_AXIS)
+                ),
+                name=name,
+            )
+
         wo = nn.Dense(
             self.width,
             dtype=self.dtype,
+            use_bias=self.use_bias,
             dot_general=dg,
             kernel_init=nn.with_partitioning(
                 nn.initializers.xavier_uniform(), (TP_AXIS, None)
@@ -138,8 +207,11 @@ class Mlp(nn.Module):
         )
         # Name the wi output so the "save_hot" remat policy keeps it: backward then
         # recomputes only the cheap elementwise gelu, not the big wi matmul.
-        hidden_act = checkpoint_name(wi(x), "mlp_hidden")
-        return wo(nn.gelu(hidden_act, approximate=True))
+        hidden_act = checkpoint_name(column_parallel("wi")(x), "mlp_hidden")
+        if self.kind == "gelu":
+            return wo(nn.gelu(hidden_act, approximate=True))
+        gate = checkpoint_name(column_parallel("wg")(x), "mlp_hidden")
+        return wo(nn.silu(gate) * hidden_act)
 
 
 class Attention(nn.Module):
@@ -161,6 +233,8 @@ class Attention(nn.Module):
     attn_impl: str = "auto"  # "dense" | "flash" | "auto"
     causal: bool = False
     quant: bool | str = False  # "" | "int8" | "int8_ste" (see _dot_general)
+    use_bias: bool = True
+    rope_theta: float | None = None  # rotary positions on q and k (see rope)
 
     @nn.compact
     def __call__(self, x_q, x_kv=None):
@@ -168,13 +242,19 @@ class Attention(nn.Module):
         x_kv = x_q if x_kv is None else x_kv
         head_dim = self.width // self.num_heads
         dg = _dot_general(self.quant)
+        if self.rope_theta is not None and (self.sp_axis is not None or not is_self_attention):
+            raise ValueError(
+                "pos='rope' numbers the positions 0..s-1 of one whole sequence: "
+                "it runs neither sequence-parallel nor in cross-attention"
+            )
 
         qkv_init = nn.with_partitioning(nn.initializers.xavier_uniform(), (None, TP_AXIS))
         out_init = nn.with_partitioning(nn.initializers.xavier_uniform(), (TP_AXIS, None))
+        dense = partial(nn.Dense, dtype=self.dtype, use_bias=self.use_bias, dot_general=dg)
 
-        q = nn.Dense(self.width, dtype=self.dtype, dot_general=dg, kernel_init=qkv_init, name="q")(x_q)
-        k = nn.Dense(self.width, dtype=self.dtype, dot_general=dg, kernel_init=qkv_init, name="k")(x_kv)
-        v = nn.Dense(self.width, dtype=self.dtype, dot_general=dg, kernel_init=qkv_init, name="v")(x_kv)
+        q = dense(self.width, kernel_init=qkv_init, name="q")(x_q)
+        k = dense(self.width, kernel_init=qkv_init, name="k")(x_kv)
+        v = dense(self.width, kernel_init=qkv_init, name="v")(x_kv)
 
         def split(t):
             return t.reshape(t.shape[:-1] + (self.num_heads, head_dim))
@@ -183,6 +263,9 @@ class Attention(nn.Module):
         # backward recompute is layernorm+gelu only).
         q, k, v = (checkpoint_name(t, n) for t, n in
                    ((split(q), "q_proj"), (split(k), "k_proj"), (split(v), "v_proj")))
+        if self.rope_theta is not None:
+            # Outside the attention kernel: one elementwise pass over q and k.
+            q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         if self.sp_axis is not None and is_self_attention:
             # Sequence-parallel exact attention: manual over sp only, GSPMD keeps
             # handling any other mesh axes (dp/tp) automatically.
@@ -267,17 +350,15 @@ class Attention(nn.Module):
         # forward is never re-run.
         out = checkpoint_name(out, "attn_core")
         out = out.reshape(out.shape[:-2] + (self.width,))
-        return nn.Dense(
-            self.width, dtype=self.dtype, dot_general=dg, kernel_init=out_init,
-            name="out",
-        )(out)
+        return dense(self.width, kernel_init=out_init, name="out")(out)
 
 
 class Block(nn.Module):
     """Pre-LN transformer block. ``moe_experts > 0`` swaps the dense MLP for a
     mixture-of-experts layer (models/moe.py) whose expert weights shard over the
     ``ep`` mesh axis; the residual stream is unchanged, so MoE composes with
-    remat/scan/sp exactly like the dense block."""
+    remat/scan/sp exactly like the dense block. ``style`` picks the norm, a
+    second norm on each sub-layer's output, the MLP and rotary positions."""
 
     width: int
     num_heads: int
@@ -292,19 +373,33 @@ class Block(nn.Module):
     moe_capacity_factor: float = 1.25
     moe_group_size: int = 512
     quant: bool | str = False
+    style: BlockStyle = BlockStyle()
 
     @nn.compact
     def __call__(self, x):
-        x = x + Attention(
+        style = self.style
+
+        def norm(name):
+            return style.make_norm(self.dtype, name)
+
+        def post(name, y):  # the sandwich's second norm, on a sub-layer's output
+            return norm(name)(y) if style.sandwich_norm else y
+
+        x = x + post("ln1_post", Attention(
             self.width, self.num_heads, self.dtype,
             sp_axis=self.sp_axis, sp_impl=self.sp_impl,
             attn_impl=self.attn_impl, causal=self.causal,
-            quant=self.quant,
+            quant=self.quant, use_bias=style.use_bias, rope_theta=style.rope_theta,
             name="attn",
-        )(nn.LayerNorm(dtype=self.dtype, name="ln1")(x))
+        )(norm("ln1")(x)))
         if self.moe_experts > 0:
             from distributed_sigmoid_loss_tpu.models.moe import MoeMlp
 
+            if style.mlp != "gelu" or not style.use_bias:
+                raise ValueError(
+                    "moe_experts > 0 has the biased GELU experts only: "
+                    f"mlp={style.mlp!r}, use_bias={style.use_bias} are not built"
+                )
             mlp = MoeMlp(
                 self.width, self.mlp_ratio, self.moe_experts, self.dtype,
                 num_selected=self.moe_num_selected,
@@ -316,9 +411,9 @@ class Block(nn.Module):
         else:
             mlp = Mlp(
                 self.width, self.mlp_ratio, self.dtype, quant=self.quant,
-                name="mlp",
+                kind=style.mlp, use_bias=style.use_bias, name="mlp",
             )
-        x = x + mlp(nn.LayerNorm(dtype=self.dtype, name="ln2")(x))
+        x = x + post("ln2_post", mlp(norm("ln2")(x)))
         return x
 
 
@@ -338,6 +433,7 @@ class _ScanBody(nn.Module):
     moe_capacity_factor: float = 1.25
     moe_group_size: int = 512
     quant: bool | str = False
+    style: BlockStyle = BlockStyle()
 
     @nn.compact
     def __call__(self, carry, _):
@@ -349,14 +445,17 @@ class _ScanBody(nn.Module):
             moe_num_selected=self.moe_num_selected,
             moe_capacity_factor=self.moe_capacity_factor,
             moe_group_size=self.moe_group_size,
-            quant=self.quant,
+            quant=self.quant, style=self.style,
             name="block",
         )(carry)
         return carry, None
 
 
 class Encoder(nn.Module):
-    """Stack of blocks; optionally remat'd and scanned over depth."""
+    """Stack of blocks, then the final norm; optionally remat'd and scanned over
+    depth. ``loops > 1`` runs that whole pass ``loops`` times on one set of
+    weights, which then live under ``loop/`` (a flax path of its own: a profile
+    shows the looped stack's operations under it)."""
 
     width: int
     depth: int
@@ -378,15 +477,19 @@ class Encoder(nn.Module):
     moe_capacity_factor: float = 1.25
     moe_group_size: int = 512
     quant: bool | str = False
+    style: BlockStyle = BlockStyle()
+    loops: int = 1
 
     @nn.compact
     def __call__(self, x):
+        if self.loops > 1:
+            return self._looped(x)
         moe_kw = dict(
             moe_experts=self.moe_experts,
             moe_num_selected=self.moe_num_selected,
             moe_capacity_factor=self.moe_capacity_factor,
             moe_group_size=self.moe_group_size,
-            quant=self.quant,
+            quant=self.quant, style=self.style,
         )
         if self.scan_layers:
             body_cls = _ScanBody
@@ -424,7 +527,24 @@ class Encoder(nn.Module):
                     attn_impl=self.attn_impl, causal=self.causal, **moe_kw,
                     name=f"block{i}",
                 )(x)
-        return nn.LayerNorm(dtype=self.dtype, name="ln_final")(x)
+        return self.style.make_norm(self.dtype, "ln_final")(x)
+
+    def _looped(self, x):
+        """One pass (the layers, then the final norm) as a child ``loop``, run
+        ``loops`` times by a scan that broadcasts its parameters: one set of
+        weights, and the backward pass sums each weight's gradient over the
+        passes in the scan's carry."""
+        fields = {
+            f.name: getattr(self, f.name) for f in dataclasses.fields(self)
+            if f.name not in ("parent", "name", "loops")
+        }
+        one_pass = Encoder(**fields, name="loop")
+        x, _ = nn.scan(
+            lambda module, carry, _: (module(carry), None),
+            variable_broadcast="params", split_rngs={"params": False},
+            variable_axes={"intermediates": 0}, length=self.loops,
+        )(one_pass, x, None)
+        return x
 
 
 class MapHead(nn.Module):

@@ -55,6 +55,7 @@ from distributed_sigmoid_loss_tpu.utils.config import (
     SigLIPConfig,
     TextConfig,
     ViTConfig,
+    changed_block_options,
     tower_quant_mode,
 )
 
@@ -68,6 +69,16 @@ __all__ = [
 
 def validate_pp_tower(cfg: ViTConfig | TextConfig, num_stages: int, name: str) -> None:
     """Raise with an actionable message when a tower can't be pipelined."""
+    changed = changed_block_options(cfg)
+    if changed:
+        # This module re-implements the tower around its blocks (position
+        # table, LayerNorm final norm, one pass): it must not run another
+        # block as the SigLIP one.
+        raise ValueError(
+            f"{name}: pipelined towers run the SigLIP block only (LayerNorm, "
+            "biased GELU MLP, learned positions, one pass); not built for "
+            + ", ".join(changed)
+        )
     if not cfg.scan_layers:
         raise ValueError(
             f"{name}: pipeline parallelism needs scan_layers=True (stage params "

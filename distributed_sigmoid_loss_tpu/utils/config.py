@@ -152,6 +152,28 @@ class TextConfig:
     # "int8": trainable int8 via the straight-through estimator — see
     # ViTConfig.quant_train (same contract, text tower).
     quant_train: Literal["", "int8"] = ""
+    # The block's make-up (BLOCK_OPTIONS below). The defaults are the SigLIP
+    # block — pre-LN LayerNorm, biased projections, a tanh-GELU MLP, a learned
+    # position table, every layer's weights used once — and give today's
+    # parameter tree and numbers. A language-model-class text tower changes them:
+    # "rmsnorm" scales by rsqrt(mean(x^2) + 1e-6), no mean and no bias.
+    norm: Literal["layernorm", "rmsnorm"] = "layernorm"
+    # A second norm on each sub-layer's OUTPUT, before the residual add:
+    # x + norm(f(norm(x))), four norms a layer.
+    sandwich_norm: bool = False
+    # "swiglu" = gated MLP, three matmuls: wo(silu(wg x) * (wi x)).
+    mlp: Literal["gelu", "swiglu"] = "gelu"
+    # False drops the bias of the blocks' attention and MLP projections.
+    use_bias: bool = True
+    # "rope" = rotary positions on q and k (rotate-half convention, positions
+    # 0..s-1, base rope_theta) in place of the learned ``pos_embed`` table.
+    pos: Literal["learned", "rope"] = "learned"
+    rope_theta: float = 10000.0
+    # > 1 runs the whole stack (its ``depth`` layers, then the final norm) this
+    # many times on ONE set of weights, each pass feeding the next; the
+    # embedding is pooled from the last pass. Each weight's gradient is the sum
+    # over its uses.
+    loops: int = 1
 
     @classmethod
     def base(cls, **kw) -> "TextConfig":
@@ -163,6 +185,23 @@ class TextConfig:
             vocab_size=64, context_length=8, width=32, depth=2, num_heads=2,
             embed_dim=16, dtype="float32", remat=False, scan_layers=False,
         )
+
+
+# TextConfig's block options with the value each has in the SigLIP block. Code
+# that re-implements or maps that block (parallel/pp_towers.py,
+# models/hf_import.py) refuses any other value by the option's name.
+BLOCK_OPTIONS = {
+    "norm": "layernorm", "sandwich_norm": False, "mlp": "gelu", "use_bias": True,
+    "pos": "learned", "loops": 1,
+}
+
+
+def changed_block_options(cfg: "ViTConfig | TextConfig") -> list[str]:
+    """The block options ``cfg`` sets away from the SigLIP block, as
+    ``name=value`` for a refusal's message (a ViTConfig has none)."""
+    return [
+        f"{k}={getattr(cfg, k)!r}" for k, v in BLOCK_OPTIONS.items() if getattr(cfg, k, v) != v
+    ]
 
 
 def tower_quant_mode(cfg: "ViTConfig | TextConfig") -> str:
