@@ -10,28 +10,46 @@ activations in the whole SigLIP step (7G+ stacked across layers at batch 256).
 
 Design: the kernel consumes q/k/v in the towers' NATIVE (b, s, h·dh) layout — no
 transposes, no layout padding (a (s, width) tile is exactly aligned); one program =
-one batch row, heads handled by a static Python loop over the lanes. Everything
-O(s²) lives and dies in VMEM: logits → softmax → out in forward, the 5-matmul
-gradient chain in backward (probs recomputed, never stored). HBM traffic collapses
-to the unavoidable q/k/v/out (+gradients) reads and writes — measured 5.8× faster
-than the dense path at ViT-B/16 scale, 2.9× at text-tower scale. Numerics: f32
-logits / softmax / accumulation, matmul inputs in the activation dtype (bf16 in
-training) — the same contract as the dense path.
+one batch row (a few short rows in the forward), heads handled by a static Python
+loop over the lanes. Everything O(s²) lives and dies in VMEM: logits → softmax →
+out in forward, the 5-matmul gradient chain in backward (probs recomputed, never
+stored). HBM traffic collapses to the unavoidable q/k/v/out (+gradients) reads and
+writes — measured 5.8× faster than the dense path at ViT-B/16 scale, 2.9× at
+text-tower scale. Numerics: f32 logits / softmax / accumulation, matmul inputs in
+the activation dtype (bf16 in training) — the same contract as the dense path.
 
-The backward holds its logits KEY-major, (s_k, s_q) = k·qᵀ (PERF.md section 6,
-PR 24). The forward's query-major chain, run backwards, contracts twice over the
-rows of an (s, s) tile (dv = pᵀ·do, dk = dsᵀ·q: Mosaic transposes the tile for
-each) and reduces three times across lanes per head; none of that grows with the
-sequence the way the matmuls do, so at s = 64 it was most of the kernel. Key-major,
-pᵀ and dsᵀ are what the chain holds: dv and dk are plain products, the softmax
-statistics and the VJP's sum(dp ⊙ p) are reductions down the sublanes (adds, then
-one (1, s) row), and only dq = ds·k still contracts over rows. Heads are cut out
-of the lanes in the cheapest way the head size allows, chosen at trace time from
-the operands' shapes (:func:`_bwd_kernel`): any dh — lane slices, as the forward;
-dh = 64 — two heads fill one 128-lane slab, loaded and stored whole, a head
-selected by zeroing the other's lanes in one operand of each product (no rotate
-for a 64-lane offset); dh = 64 and 2·s ≤ 128 (the text tower) — the two heads'
-transposed logits share one lane tile, so the pair costs one chain, not two.
+The two kernels hold their logits in opposite orientations, each measured on
+the chip against the other (PERF.md section 6, PR 24 and PR 27).
+
+The forward is QUERY-major, (s_q, s_k) = q·kᵀ, and normalises AFTER the product:
+out = (exp(l − max) · v) · (1 / rowsum) (:func:`_query_major_out`). The row sum is
+then already the (s, 1) column the (s, dh) output needs, no (s, s) tile is divided
+and none is transposed; key-major, the forward's one product over rows
+(pᵀ-times-v) costs more than the lane reductions it saves at every tower shape.
+The backward is KEY-major, (s_k, s_q) = k·qᵀ (:func:`_key_major_grads`): a
+query-major chain run backwards contracts twice over the rows of an (s, s) tile
+(dv = pᵀ·do, dk = dsᵀ·q: Mosaic transposes the tile for each) and reduces three
+times across lanes per head; key-major, pᵀ and dsᵀ are what the chain holds, dv
+and dk are plain products, the statistics are adds down the sublanes, and only
+dq = ds·k still contracts over rows.
+
+Heads are never cut out of the lanes where a whole register will do; how is chosen
+at trace time from the operands' shapes (:func:`_head_cut`, :func:`_head_windows`).
+The forward loads, for each head, the aligned 128-lane slabs it lies in (one at
+dh = 64 and for half of so400m's dh = 72 heads, two for the heads that straddle a
+boundary, the head itself where dh is a multiple of 128) and selects the head by
+zeroing the other lanes of q alone: k, v and the product keep the window's lanes,
+the head's part of the result is already where the output wants it, and every
+store is a whole slab. A lane slice at an offset that is not a register boundary
+costs a cross-lane rotate per register of every operand and of the result, which
+at dh = 72 was three quarters of the kernel. A forward program takes several batch
+rows where they are short (:func:`_fwd_plan`): the rows' chains are independent
+and the scheduler interleaves them; at the longest sequences the dispatcher admits
+it normalises before the product instead, which holds less VMEM. The backward
+keeps lane slices at any dh but 64; at dh = 64 two heads fill one slab, loaded and
+stored whole, a head selected by zeroing the other's lanes in one operand of each
+product; at dh = 64 and 2·s ≤ 128 (the text tower) the two heads' transposed
+logits share one lane tile, so the pair costs one chain, not two.
 
 No reference analogue (the reference has no model layer, SURVEY.md §1); this is the
 "pallas kernels for the hot ops" piece of the TPU-first design.
@@ -112,6 +130,49 @@ SHORT_ATTENTION_MAX_SEQ = 1024
 _VMEM_BYTES = 16 * 1024 * 1024
 _VMEM_BUDGET_FRACTION = 0.7
 
+# Tokens (rows x s) up to which a forward program takes more than one batch row,
+# and the share of Mosaic's limit the forward's own estimate may reach (_fwd_plan).
+_FWD_MAX_TOKENS = 512
+_FWD_VMEM_FRACTION = 0.9
+
+
+def _fwd_vmem_bytes(rows: int, s: int, width: int, num_heads: int, dtype_bytes: int,
+                    defer: bool) -> int:
+    """What Mosaic allocates for one forward program, by what it was measured to
+    need (compiled for a described v5e over shapes, PERF.md section 6, PR 27): two
+    copies of the 4 (rows, s, width) I/O blocks (the pipeline's), and (s, s) f32
+    tiles, lanes padded to 128. Normalising before the second product
+    (``defer=False``) the tiles are 2, reused from head to head, as the parent's.
+    Normalising after it the exponentials feed the product and the row sum at
+    once, the scheduler runs the next heads' chains meanwhile, and up to one tile
+    a head is live: the speed of that form (section 6) and its price."""
+    tiles = num_heads if defer else 2
+    return (8 * rows * s * width * dtype_bytes
+            + tiles * s * (-(-s // _LANES) * _LANES) * 4)
+
+
+def _fwd_plan(b: int, s: int, width: int, num_heads: int, dtype_bytes: int):
+    """``(rows, defer)``: the batch rows one forward program takes and whether it
+    normalises after the second product, from the shapes alone (trace time).
+
+    Measured on the chip (PERF.md section 6, PR 27). Normalising after the
+    product is 1.1 to 1.5 times as fast at every tower shape. A program's chain
+    per head is serial (product, row max, exp, product), and rows unrolled side by
+    side give the scheduler independent chains to interleave: -38 % at s = 64 with
+    four rows (12 x 64), -9 % at s = 196 with two; eight rows, and a loop over rows
+    in place of the unrolled copies, gain nothing. Both hold more VMEM, so the
+    plan is the most rows of 4, 2, 1 that divide the batch, keep the program at
+    ``_FWD_MAX_TOKENS`` tokens and fit ``_FWD_VMEM_FRACTION`` of Mosaic's limit
+    with the normalisation deferred; past that (s >= 400 or so at the towers'
+    widths) one row, normalised before the product, which needs less than the
+    parent's kernel did wherever the dispatcher admits the shape."""
+    for rows in (4, 2, 1):
+        if (b % rows == 0 and (rows == 1 or rows * s <= _FWD_MAX_TOKENS)
+                and _fwd_vmem_bytes(rows, s, width, num_heads, dtype_bytes, True)
+                <= _VMEM_BYTES * _FWD_VMEM_FRACTION):
+            return rows, True
+    return 1, False
+
 
 def short_attention_vmem_bytes(s: int, width: int, dtype_bytes: int) -> int:
     """Worst-case VMEM footprint of ONE grid program (width = h·dh).
@@ -123,6 +184,12 @@ def short_attention_vmem_bytes(s: int, width: int, dtype_bytes: int) -> int:
     two heads share a slab the tiles are the same count: (s, 2s) pairs at
     2·s ≤ 128, where a pair is smaller than one padded (s, 128) tile, and the
     two heads of a masked pair run one after the other.
+
+    The forward holds 4 blocks and 2 such tiles in this count, less than the
+    backward at every shape, unless it spends VMEM on speed: more rows a program,
+    the normalisation after the product. It does so only where its own, fuller
+    estimate fits (:func:`_fwd_plan`, :func:`_fwd_vmem_bytes`), so the figure
+    the dispatcher checks stays the backward's.
     """
     return 7 * s * width * dtype_bytes + 3 * s * s * 4
 
@@ -163,23 +230,98 @@ def _dot(a, b, contract_a: int, contract_b: int):
     )
 
 
-def _head_probs(qh, kh, *, scale, causal):
-    logits = _dot(qh, kh, 1, 1) * scale  # (s, s)
-    if causal:
-        s = logits.shape[0]
-        rows = lax.broadcasted_iota(jnp.int32, (s, s), 0)
-        cols = lax.broadcasted_iota(jnp.int32, (s, s), 1)
-        logits = jnp.where(rows >= cols, logits, _NEG_INF)
-    return jax.nn.softmax(logits, axis=-1)
+def _head_cut(s: int, dh: int, num_heads: int) -> str:
+    """How the heads are cut out of the lanes, from the shapes alone (trace time):
+    the one decision both kernels read, measured per shape in PERF.md section 6
+    (PR 24 the backward, PR 27 the forward).
+
+    ``"packed"``: dh = 64, an even head count and 2·s ≤ 128: two heads fill one
+    aligned 128-lane slab and their transposed logits one lane tile; the backward
+    runs one chain for the pair. ``"masked"``: dh = 64 otherwise: whole slabs, a
+    head selected by zeroing the other's lanes. ``"sliced"``: any other head size
+    (so400m's 72: no slab holds whole heads; 128: a head is a slab already): the
+    backward takes lane slices. The forward cuts all three alike, by
+    :func:`_head_windows`, which at dh = 64 is the masked slab: at four rows a
+    program a packed forward pair measured no faster than two masked heads."""
+    if 2 * dh != _LANES or num_heads % 2:
+        return "sliced"
+    return "packed" if 2 * s <= _LANES and s % 16 == 0 else "masked"
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, num_heads):
-    q, k, v = q_ref[0], k_ref[0], v_ref[0]  # (s, h·dh)
-    dh = q.shape[-1] // num_heads
-    for j in range(num_heads):
-        sl = slice(j * dh, (j + 1) * dh)
-        p = _head_probs(q[:, sl], k[:, sl], scale=scale, causal=causal)
-        o_ref[0, :, sl] = _dot(p.astype(v.dtype), v[:, sl], 1, 0).astype(o_ref.dtype)
+def _head_windows(num_heads: int, dh: int, width: int):
+    """Per head ``(start, stop, lo, hi)``: lanes [lo, hi) are the head's, and
+    [start, stop) the aligned 128-lane slabs they lie in (``stop`` clipped to a
+    width that is no multiple of 128): what a kernel loads whole to reach the
+    head without a lane rotate."""
+    return [
+        (lo // _LANES * _LANES, min(-(-hi // _LANES) * _LANES, width), lo, hi)
+        for lo, hi in ((j * dh, (j + 1) * dh) for j in range(num_heads))
+    ]
+
+
+def _query_major_out(q_x, k_x, v_x, *, scale, visible, defer):
+    """The forward chain of one head, logits held QUERY-major (why: the module
+    docstring), normalised after the second product if ``defer`` and before it
+    otherwise (:func:`_fwd_plan` says which).
+
+    ``q_x``/``k_x`` are (s, c), zero outside the head's lanes in at least one of
+    them; ``v_x`` is (s, n). Returns f32 (s, n): softmax(q·kᵀ)·v on every lane of
+    ``v_x``, of which the caller keeps the head's. ``visible`` is the (s_q, s_k)
+    causal mask or None. The statistics are f32, the reciprocal exact."""
+    logits = _dot(q_x, k_x, 1, 1) * scale  # (s_q, s_k) f32
+    if visible is not None:
+        logits = jnp.where(visible, logits, _NEG_INF)
+    e = jnp.exp(logits - jnp.max(logits, axis=1, keepdims=True))
+    inv = 1.0 / jnp.sum(e, axis=1, keepdims=True)  # (s, 1): the column the output needs
+    if defer:
+        return _dot(e.astype(v_x.dtype), v_x, 1, 0) * inv
+    return _dot((e * inv).astype(v_x.dtype), v_x, 1, 0)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, num_heads, defer):
+    """A few batch rows (:func:`_fwd_plan`): :func:`_query_major_out` per head on
+    the aligned window :func:`_head_windows` gives it, the output assembled slab
+    by slab in registers and stored whole."""
+    rows, s, width = q_ref.shape
+    windows = _head_windows(num_heads, width // num_heads, width)
+    zero = jnp.zeros((), q_ref.dtype)
+    visible = None
+    if causal:  # (s_q, s_k): key j is visible to query i >= j
+        visible = (lax.broadcasted_iota(jnp.int32, (s, s), 0)
+                   >= lax.broadcasted_iota(jnp.int32, (s, s), 1))
+
+    @functools.cache
+    def lanes_in(n, lo, hi):  # (s, n) mask of the lanes [lo, hi)
+        lane = lax.broadcasted_iota(jnp.int32, (s, n), 1)
+        return (lane >= lo) & (lane < hi)
+
+    # The slabs each head's window covers; the last head with lanes in a slab stores it.
+    slabs = [range(start // _LANES, -(-stop // _LANES)) for start, stop, _, _ in windows]
+    last = {t: j for j, ts in enumerate(slabs) for t in ts}
+
+    def one_row(r, carry):
+        pending = {}  # slab index -> f32 (s, <= 128), some heads' lanes still to come
+        for j, (start, stop, lo, hi) in enumerate(windows):
+            window = slice(start, stop)
+            q_w = q_ref[r, :, window]
+            if (start, stop) != (lo, hi):
+                q_w = jnp.where(lanes_in(stop - start, lo - start, hi - start), q_w, zero)
+            out = _query_major_out(q_w, k_ref[r, :, window], v_ref[r, :, window],
+                                   scale=scale, visible=visible, defer=defer)
+            for t in slabs[j]:
+                a, b = _LANES * t, min(_LANES * (t + 1), width)
+                piece = out[:, a - start:b - start]
+                if t in pending:
+                    piece = jnp.where(lanes_in(b - a, lo - a, hi - a), piece, pending.pop(t))
+                if last[t] == j:
+                    o_ref[r, :, a:b] = piece.astype(o_ref.dtype)
+                else:
+                    pending[t] = piece
+        return carry
+
+    # Unrolled by the loop, not by Python: the same straight-line program for the
+    # scheduler, traced once instead of once a row (-2 s of set-up a tower).
+    lax.fori_loop(0, rows, one_row, 0, unroll=True)
 
 
 def _key_major_grads(k_x, q_x, v_x, do_x, *, scale, visible):
@@ -213,8 +355,8 @@ def _bwd_kernel(
 ):
     """One batch row: :func:`_key_major_grads` per head, or per pair of heads,
     the heads cut out of the lanes as cheaply as the head size allows (chosen
-    here, at trace time, from the operands' shapes; measured in PERF.md
-    section 6, PR 24)."""
+    by :func:`_head_cut`, at trace time, from the operands' shapes; measured in
+    PERF.md section 6, PR 24)."""
     q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
     s, width = q.shape
     dh = width // num_heads
@@ -229,9 +371,10 @@ def _bwd_kernel(
             ref[0, :, lanes] = g.astype(ref.dtype)
 
     grads_of = functools.partial(_key_major_grads, scale=scale)
-    if 2 * dh != _LANES or num_heads % 2:
-        # Any head size: one head at a time, cut out by lane slices as in the
-        # forward (so400m's dh = 72: no head starts on a register boundary).
+    cut = _head_cut(s, dh, num_heads)
+    if cut == "sliced":
+        # Any head size: one head at a time, cut out by lane slices (so400m's
+        # dh = 72: no head starts on a register boundary).
         visible = causal_mask(s) if causal else None
         for j in range(num_heads):
             sl = slice(j * dh, (j + 1) * dh)
@@ -244,7 +387,7 @@ def _bwd_kernel(
     # rotate per register of every operand and result.
     zero = jnp.zeros((), q.dtype)
     first = lax.broadcasted_iota(jnp.int32, (s, _LANES), 1) < dh
-    packed = 2 * s <= _LANES and s % 16 == 0
+    packed = cut == "packed"
     if packed:
         # The text tower: both heads' transposed logits fit one lane tile. Stack
         # the slab's queries twice along rows, rows [0, s) keeping the first
@@ -322,9 +465,9 @@ def _bwd_kernel_batched(
     dv_ref[0] = unheads(dv).astype(dv_ref.dtype)
 
 
-def _specs(b, s, width, n: int):
-    block = pl.BlockSpec((1, s, width), lambda i: (i, 0, 0), memory_space=pltpu.VMEM)
-    return dict(grid=(b,), in_specs=[block] * n, out_specs=block)
+def _specs(b, s, width, n: int, rows: int = 1):
+    block = pl.BlockSpec((rows, s, width), lambda i: (i, 0, 0), memory_space=pltpu.VMEM)
+    return dict(grid=(b // rows,), in_specs=[block] * n, out_specs=block)
 
 
 def _flops(b, s, width, n_matmuls: int) -> int:
@@ -352,9 +495,10 @@ def _short_attention_fwd(q, k, v, causal, scale, interpret, batch_heads=None):
     b, s, h, dh = q.shape
     scale = (dh**-0.5) if scale is None else scale
     wide = (b, s, h * dh)  # free reshape: heads stay on the minor axis
-    spec = _specs(b, s, h * dh, 3)
+    rows, defer = _fwd_plan(b, s, h * dh, h, q.dtype.itemsize)
+    spec = _specs(b, s, h * dh, 3, rows)
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal, num_heads=h),
+        functools.partial(_fwd_kernel, scale=scale, causal=causal, num_heads=h, defer=defer),
         out_shape=jax.ShapeDtypeStruct(wide, q.dtype),
         grid=spec["grid"],
         in_specs=spec["in_specs"],
