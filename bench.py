@@ -121,64 +121,14 @@ def _emit(record: dict, flush: bool = False) -> None:
               file=sys.stderr)
 
 
-def _attn_bwd_record_fields(args) -> dict:
-    """attn_bwd record fields from the kernel choice ACTUALLY resolved at
-    trace time, cross-checked against argv.
-
-    set_bwd_batch_heads is process-global state baked in per trace: a step
-    traced before the flip keeps the other kernel while argv still says
-    ``--attn-bwd batched`` — trusting argv could log an A/B record for a
-    kernel that never ran (advisor, round 5). The traced record is the truth;
-    argv mismatches are flagged in the record AND on stderr so the datapoint
-    never silently enters a per-metric stream under the wrong tag.
-    """
-    from distributed_sigmoid_loss_tpu.ops.pallas_short_attention import (
-        traced_bwd_batch_heads,
-    )
-
-    want = args.attn_bwd
-    traced = traced_bwd_batch_heads()
-    if not traced:
-        # No fused short-attention backward traced at all (dense/flash path,
-        # or a forward-only mode): a non-default request was a no-op.
-        if want == "loop":
-            return {}
-        print(
-            f"WARNING: --attn-bwd {want} requested but no fused "
-            "short-attention backward was traced; tagging the record "
-            "attn_bwd_traced=none",
-            file=sys.stderr,
-        )
-        return {"attn_bwd": want, "attn_bwd_traced": "none",
-                "attn_bwd_mismatch": True}
-    if len(traced) > 1:
-        actual = "mixed"
-    else:
-        actual = "batched" if traced[0] else "loop"
-    fields = {}
-    if actual != "loop":
-        fields["attn_bwd"] = actual
-    if actual != want:
-        print(
-            f"WARNING: --attn-bwd {want} but the traced backward kernel was "
-            f"{actual!r} — recording the traced choice",
-            file=sys.stderr,
-        )
-        fields["attn_bwd"] = actual
-        fields["attn_bwd_argv"] = want
-        fields["attn_bwd_mismatch"] = True
-    return fields
-
-
 def _pallas_record_fields(args) -> dict:
     """Pallas-loss record fields from the kernel choice ACTUALLY resolved at
     trace time, cross-checked against argv.
 
     ``pallas_compatible`` falls back to the XLA block silently at trace time,
     so before round 10 a record could claim ``use_pallas: true`` while every
-    block ran the XLA path (exact same class as the round-5 attn_bwd
-    finding). The streaming kernel records every dispatch resolution
-    process-wide (ops/pallas_sigmoid_loss.traced_loss_kernels); the record
+    block ran the XLA path. The streaming kernel records every dispatch
+    resolution process-wide (ops/pallas_sigmoid_loss.traced_loss_kernels); the record
     carries that truth as ``pallas_engaged``, with ``pallas_mismatch`` set
     (and a stderr warning) whenever any block fell back — so the datapoint
     never silently enters a per-metric stream under the wrong tag.
@@ -689,7 +639,6 @@ def run_step_breakdown(args) -> int:
         record["ring_overlap"] = True
     if args.mu_bf16:
         record["adam_mu_dtype"] = "bfloat16"
-    record.update(_attn_bwd_record_fields(args))
     record.update(_pallas_record_fields(args))
     _emit(record)
     return 0
@@ -966,12 +915,6 @@ def main(argv: list[str]) -> int:
                     help="tower attention core: auto = fused Pallas kernel for "
                          "bf16 self-attention (VMEM-resident at tower seqs, "
                          "blockwise flash beyond), dense = plain XLA einsums")
-    ap.add_argument("--attn-bwd", default="loop", choices=["loop", "batched"],
-                    help="fused short-attention BACKWARD kernel: 'loop' = "
-                         "per-head gradient matmuls (the measured headline "
-                         "behavior), 'batched' = one h-batched dot_general "
-                         "per chain matmul (the round-3 attribution "
-                         "candidate — A/B on chip before adopting)")
     ap.add_argument("--text-attn-impl", default="",
                     choices=["", "auto", "dense", "flash"],
                     help="override the TEXT tower's attention impl only (A/B: "
@@ -1133,13 +1076,6 @@ def main(argv: list[str]) -> int:
         ap.error("--loss-impl chunked / --ring-overlap apply to the sigmoid "
                  "family only (the softmax ring already streams its "
                  "logsumexp)")
-    if args.attn_bwd == "batched":
-        # Process default, baked in at trace time — set before ANY step build.
-        from distributed_sigmoid_loss_tpu.ops.pallas_short_attention import (
-            set_bwd_batch_heads,
-        )
-
-        set_bwd_batch_heads(True)
     modes = {
         "--eval-throughput": args.eval_throughput,
         "--context": bool(args.context),
@@ -1171,7 +1107,6 @@ def main(argv: list[str]) -> int:
             "--precision": args.precision != "default",
             "--accum-negatives": args.accum_negatives != "local",
             "--gradcache-bf16": args.gradcache_bf16,
-            "--attn-bwd": args.attn_bwd != "loop",
             "--quant-train": bool(args.quant_train),
             "--loss-impl": args.loss_impl != "fused",
             "--ring-overlap": args.ring_overlap,
@@ -1200,7 +1135,6 @@ def main(argv: list[str]) -> int:
             "--precision": args.precision != "default",
             "--accum-negatives": args.accum_negatives != "local",
             "--gradcache-bf16": args.gradcache_bf16,
-            "--attn-bwd": args.attn_bwd != "loop",
             "--attn-impl": args.attn_impl != "auto",
             "--text-attn-impl": bool(args.text_attn_impl),
             "--scan-layers": args.scan_layers,
@@ -1238,7 +1172,6 @@ def main(argv: list[str]) -> int:
             "--precision": args.precision != "default",
             "--accum-negatives": args.accum_negatives != "local",
             "--gradcache-bf16": args.gradcache_bf16,
-            "--attn-bwd": args.attn_bwd != "loop",
             "--attn-impl": args.attn_impl != "auto",
             "--text-attn-impl": bool(args.text_attn_impl),
             "--scan-layers": args.scan_layers,
@@ -1803,7 +1736,6 @@ def main(argv: list[str]) -> int:
         record["attn_impl"] = args.attn_impl
     if args.text_attn_impl:
         record["text_attn_impl"] = args.text_attn_impl
-    record.update(_attn_bwd_record_fields(args))
     record.update(_pallas_record_fields(args))
     if args.moe:
         record["moe_experts"] = args.moe

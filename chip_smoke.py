@@ -398,6 +398,19 @@ def phase_cli_train(n_dev: int) -> None:
           f"in {REPORT['cli_train_wall_s']}s wall (compile included)")
 
 
+def _pallas_names(jaxpr) -> list:
+    """``name`` of every pallas_call in a jaxpr, nested jaxprs included."""
+    import jax
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_names(sub)
+    return found
+
+
 def phase_train_step(mesh) -> None:
     """The CLI's step through the builders directly, for what the CLI cannot
     show: fused attention traced, zero recompiles, per-device placement. Its
@@ -406,10 +419,6 @@ def phase_train_step(mesh) -> None:
 
     from distributed_sigmoid_loss_tpu.data import SyntheticImageText, prefetch
     from distributed_sigmoid_loss_tpu.models import SigLIP
-    from distributed_sigmoid_loss_tpu.ops.pallas_short_attention import (
-        reset_traced_bwd_batch_heads,
-        traced_bwd_batch_heads,
-    )
     from distributed_sigmoid_loss_tpu.train import (
         create_train_state,
         make_optimizer,
@@ -439,7 +448,6 @@ def phase_train_step(mesh) -> None:
         create_train_state(jax.random.key(0), model, tx, first, mesh)
     )
     REPORT["compile_s"]["create_train_state"] = round(time.perf_counter() - t0, 2)
-    reset_traced_bwd_batch_heads()
     step, _ = make_train_step(
         model, mesh, LossConfig(variant="ring", precision="default")
     )
@@ -494,10 +502,12 @@ def phase_train_step(mesh) -> None:
 
     check(all(map(math.isfinite, losses)), f"non-finite loss: {losses}")
     check(losses[-1] < losses[0], f"loss did not fall: {losses}")
-    traced = traced_bwd_batch_heads()
-    check(traced != (), "the towers traced no fused short-attention backward: "
-          "attn_impl='auto' fell to dense_attention on the chip")
-    print(f"  fused short-attention backward traced (batch_heads={traced})")
+    # The trace is cached by the calls above: this reads it, it does not retrace.
+    kernels = set(_pallas_names(step.trace(state, staged[0]).jaxpr.jaxpr))
+    check({"short_attn_fwd", "short_attn_bwd"} <= kernels,
+          f"the step's jaxpr holds kernels {sorted(kernels)}, not the fused "
+          "short attention: attn_impl='auto' fell to dense_attention on the chip")
+    print(f"  fused short attention in the step's jaxpr: {sorted(kernels)}")
     check(step._cache_size() == 1,
           f"step recompiled after warm-up: cache size {step._cache_size()}")
     one_shard_per_device(state.params, "params", False)

@@ -46,8 +46,7 @@ BENCH_RECORD_FIELDS = frozenset(
         "loss_family", "precision", "use_pallas", "remat_policy",
         "n_devices", "final_loss", "model_tflops_per_sec_per_chip",
         "peak_hbm_gb", "peak_hbm_live_gb", "scan_layers", "attn_impl",
-        "text_attn_impl", "attn_bwd", "attn_bwd_argv", "attn_bwd_mismatch",
-        "attn_bwd_traced", "pallas_engaged", "pallas_mismatch",
+        "text_attn_impl", "pallas_engaged", "pallas_mismatch",
         "moe_experts", "moe_num_selected",
         "moe_group_size", "moe_capacity_factor", "quant_train", "loss_impl",
         "ring_overlap", "zero1", "update_sharding",

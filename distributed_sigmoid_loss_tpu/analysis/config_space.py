@@ -17,7 +17,7 @@ the audited sample no longer describes what users can build.
 
 The sampled products (:func:`tier1_sample`, :func:`full_product_sample`)
 replace jaxpr_audit's hand-maintained fifteen-config list as the lattice
-source for the jaxpr auditor, obs/attribution and obs/regress. The
+source for the jaxpr auditor and obs/attribution. The
 ``ema`` axis is constraint-only (it changes state contents, not the traced
 step dataflow) and is projected out of every trace sample.
 

@@ -46,6 +46,11 @@ Rules (ids used by ``lint --disable`` and the Finding records):
 
 from __future__ import annotations
 
+from distributed_sigmoid_loss_tpu.analysis.collective_prims import (
+    COLLECTIVES,
+    collective_axes,
+    manual_axis_sizes,
+)
 from distributed_sigmoid_loss_tpu.analysis.findings import Finding
 
 __all__ = [
@@ -90,39 +95,10 @@ DEFAULT_STEP_CONFIGS = (
     "compressed_pallas_chunked",
 )
 
-# Collectives that SUM over their named axes: a second application over the
-# same axis to an already-invariant value is the S-fold overcount.
-_SUM_PRIMS = {"psum", "reduce_scatter"}
-# Reductions whose repeat is idempotent (max of replicated = same value) —
-# still tracked for axis binding, never for double-reduce.
-_IDEMPOTENT_REDUCE_PRIMS = {"pmin", "pmax"}
-_GATHER_PRIMS = {"all_gather"}
-_OTHER_COLLECTIVES = {"ppermute", "all_to_all", "pgather", "pbroadcast"}
-_ALL_COLLECTIVES = (
-    _SUM_PRIMS | _IDEMPOTENT_REDUCE_PRIMS | _GATHER_PRIMS | _OTHER_COLLECTIVES
-    | {"axis_index"}
-)
-
 _REMAT_PRIMS = {"remat2", "remat", "checkpoint"}
 
 # (invariant-over, reduced-over) for a value we know nothing about.
 _VARYING = (frozenset(), frozenset())
-
-
-def _collective_axes(eqn) -> tuple:
-    axes = eqn.params.get("axes", eqn.params.get("axis_name"))
-    if axes is None:
-        return ()
-    if not isinstance(axes, (tuple, list)):
-        axes = (axes,)
-    flat = []
-    for a in axes:
-        if isinstance(a, (tuple, list)):
-            flat.extend(a)
-        else:
-            flat.append(a)
-    # positional (int) axes come from vmap, not meshes — not our concern
-    return tuple(a for a in flat if isinstance(a, str))
 
 
 def _jaxpr_of(obj):
@@ -160,6 +136,9 @@ class _Auditor:
         self.check_bf16_upcast = check_bf16_upcast
         self.findings: list[Finding] = []
         self._seen: set = set()
+        # Axes bound by an enclosing shard_map(check_vma=True): over these
+        # jax's own type says which values are invariant (_walk_shard_map).
+        self.typed: frozenset = frozenset()
 
     def add(self, rule: str, detail: str) -> None:
         key = (rule, detail)
@@ -175,16 +154,23 @@ class _Auditor:
         ``env``: var -> ``(inv, red)`` pair of frozensets: the mesh axes the
         value is known INVARIANT over (replicated; identical on every shard),
         and the subset of those it is invariant over BECAUSE it was already
-        reduced/gathered over them (the double-psum taint; always
-        ``red ⊆ inv``). Unknown vars default to varying ``(∅, ∅)`` — the
-        conservative direction: it can only suppress a finding, never
-        fabricate one. Returns the env (callers map outvars through it).
+        reduced/gathered over them (the double-psum taint). Unknown vars
+        default to varying ``(∅, ∅)`` — the conservative direction: it can
+        only suppress a finding, never fabricate one. Over the axes in
+        ``self.typed`` the invariant set is not inferred but read from the
+        value's type (``axis not in aval.vma``); the taint, which the type
+        does not carry, stays this walk's own. Returns the env (callers map
+        outvars through it).
         """
 
         def get(v):
             if _is_literal(v):
                 return (frozenset(bound), frozenset())
-            return env.get(v, _VARYING)
+            inv, red = env.get(v, _VARYING)
+            if self.typed:
+                vma = getattr(getattr(v, "aval", None), "vma", self.typed)
+                inv = (inv - self.typed) | (self.typed - vma)
+            return (inv, red)
 
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
@@ -205,7 +191,7 @@ class _Auditor:
                 self._walk_shard_map(eqn, env, bound, emit, get)
                 continue
 
-            if name in _ALL_COLLECTIVES:
+            if name in COLLECTIVES:
                 self._walk_collective(eqn, env, bound, emit, get)
                 continue
 
@@ -251,43 +237,38 @@ class _Auditor:
         return env
 
     def _walk_shard_map(self, eqn, env, bound, emit, get) -> None:
-        mesh = eqn.params.get("mesh")
-        auto = eqn.params.get("auto") or frozenset()
-        try:
-            mesh_axes = dict(mesh.shape)
-        except Exception:
-            mesh_axes = {}
-        inner_bound = dict(bound)
-        inner_bound.update(
-            {ax: sz for ax, sz in mesh_axes.items() if ax not in auto}
-        )
-        inner = _jaxpr_of(eqn.params.get("jaxpr"))
-        if inner is None:
-            for ov in eqn.outvars:
-                env[ov] = _VARYING
-            return
-        in_names = eqn.params.get("in_names") or ()
+        manual = eqn.params["manual_axes"]
+        inner_bound = {**bound, **manual_axis_sizes(eqn)}
+        inner = eqn.params["jaxpr"]
         inner_env: dict = {}
-        for i, iv in enumerate(inner.invars):
+        for iv, spec in zip(inner.invars, eqn.params["in_specs"]):
             sharded_over: set = set()
-            if i < len(in_names):
-                for axes_tuple in in_names[i].values():
-                    sharded_over.update(axes_tuple)
+            for part in spec:
+                if part is not None:
+                    sharded_over.update(
+                        part if isinstance(part, tuple) else (part,)
+                    )
             # A P()-replicated input is invariant over every bound axis; a
             # P("dp")-sharded one varies over dp. Neither is REDUCED yet.
             inner_env[iv] = (
                 frozenset(ax for ax in inner_bound if ax not in sharded_over),
                 frozenset(),
             )
-        for cv in getattr(inner, "constvars", ()):
+        for cv in inner.constvars:
             inner_env[cv] = (frozenset(inner_bound), frozenset())
+        outer_typed = self.typed
+        self.typed = (outer_typed - manual) | (
+            manual if eqn.params["check_vma"] else frozenset()
+        )
         self.walk(inner, inner_env, inner_bound, emit)
+        self.typed = outer_typed
         for ov in eqn.outvars:
             env[ov] = _VARYING
 
     def _walk_collective(self, eqn, env, bound, emit, get) -> None:
         name = eqn.primitive.name
-        axes = _collective_axes(eqn)
+        role = COLLECTIVES[name][0]
+        axes = collective_axes(eqn)
         if emit:
             for ax in axes:
                 if ax not in bound:
@@ -298,7 +279,7 @@ class _Auditor:
                         " — the collective would resolve against a stale or "
                         "foreign axis environment",
                     )
-        if name == "ppermute" and emit and axes:
+        if role == "permute" and emit and axes:
             size = bound.get(axes[0])
             if size is not None:
                 from distributed_sigmoid_loss_tpu.parallel.collectives import (
@@ -310,9 +291,11 @@ class _Auditor:
                 ):
                     self.add(
                         "jaxpr-ppermute-bijection",
-                        f"ppermute over {axes[0]!r} (size {size}): {problem}",
+                        f"{name} over {axes[0]!r} (size {size}): {problem}",
                     )
-        if name in _SUM_PRIMS and emit:
+        if role in ("sum", "scatter") and emit:
+            # Both ADD over their axes: a second one over the same axis of a
+            # value already reduced there is the S-fold overcount.
             for v in eqn.invars:
                 if _is_literal(v):
                     # psum of a trace-time constant: either a symbolic-zero
@@ -331,23 +314,27 @@ class _Auditor:
                     )
         # Output invariance + reduction taint:
         axset = frozenset(axes)
-        if name == "psum" or name in _IDEMPOTENT_REDUCE_PRIMS:
+        if role in ("sum", "extremum", "gather"):
+            # Every shard ends with the same value; a max/min adds nothing up,
+            # so it leaves no taint.
+            taint = frozenset() if role == "extremum" else axset
             for ov, v in zip(eqn.outvars, eqn.invars):
                 inv, red = get(v)
-                taint = axset if name == "psum" else frozenset()
                 env[ov] = (inv | axset, (red | taint) & (inv | axset))
-        elif name in _GATHER_PRIMS:
-            inv, red = get(eqn.invars[0])
-            for ov in eqn.outvars:
-                env[ov] = (inv | axset, (red | axset) & (inv | axset))
-        elif name == "axis_index":
+        elif role == "index":
             for ov in eqn.outvars:
                 env[ov] = (frozenset(bound) - axset, frozenset())
-        elif name == "ppermute":
+        elif role == "permute":
             # permuting a replicated value is the identity; varying stays varying
-            for ov in eqn.outvars:
-                env[ov] = get(eqn.invars[0])
-        else:  # reduce_scatter, all_to_all, ...: shards end up with distinct pieces
+            for ov, v in zip(eqn.outvars, eqn.invars):
+                env[ov] = get(v)
+        elif role == "retype":
+            # Retyped as varying, the same bytes: a sum it already went
+            # through still counts against the next one.
+            for ov, v in zip(eqn.outvars, eqn.invars):
+                inv, red = get(v)
+                env[ov] = (inv - axset, red)
+        else:  # scatter, all_to_all, ...: shards end up with distinct pieces
             for ov in eqn.outvars:
                 env[ov] = _VARYING
 
@@ -681,8 +668,7 @@ def _abstract_state(
 
 # Memo for step_config_jaxprs keyed by the RESOLVED mesh size: the traces
 # are deterministic (tiny towers, abstract state, fixed mesh), and the
-# auditor, obs/attribution, and obs/regress all enumerate the same sampled
-# product — one tier-1 run used to pay the trace three times over. The memo
+# auditor and obs/attribution enumerate the same sampled product. The memo
 # is INCREMENTAL per label: the dryrun's --full-product pass reuses every
 # trace the tier-1 sample already paid for and adds only the extra configs.
 # Host-side only; never read inside traced code (allowlisted in repo_lint).
@@ -778,8 +764,7 @@ def _build_step_config(cfg, n_devices: int):
     local_b = quantum * accum_steps * max(pp_microbatches, 1)
     # Batch rows shard over the data axes (dcn and dp; pp stages all see the
     # same rows) — for the legacy labels this reproduces the exact historic
-    # global sizes (2n / 8n / 32n), keeping their memoized traces and the
-    # committed obs/regress baselines byte-comparable.
+    # global sizes (2n / 8n / 32n).
     batch_shards = dp_size * (2 if cfg.compression else 1)
     batch = _abstract_batch(mcfg, local_b * batch_shards)
     tx = make_optimizer(TrainConfig(warmup_steps=1, total_steps=10))

@@ -6,8 +6,8 @@ review:
 
 - ``repo-mutable-global``: module-level mutable state that can influence
   traced behavior must be allowlisted WITH a rationale naming its traced-choice
-  recorder (the ``_DEFAULT_BATCH_HEADS`` bench-record-corruption class —
-  ops/pallas_short_attention.py, ADVICE round 5).
+  recorder (a step traced before the mutation keeps the other behavior while
+  its record claims otherwise).
 - ``repo-doc-stale``: every CLI flag and LossConfig field must appear in
   README.md or docs/ (a flag nobody can discover is a flag nobody A/Bs).
 - ``repo-slow-marker``: the registered multi-minute suites must carry the
@@ -76,15 +76,6 @@ _REPO_ROOT = os.path.dirname(_PACKAGE_DIR)
 # the record emitters cross-check it; host-side caches must never be read
 # inside traced code.
 MUTABLE_GLOBAL_ALLOWLIST = {
-    "ops/pallas_short_attention.py::_DEFAULT_BATCH_HEADS": (
-        "trace-time kernel choice; every resolution is recorded in "
-        "_TRACED_BWD_BATCH_HEADS and bench.py cross-checks records against "
-        "the traced truth (_attn_bwd_record_fields)"
-    ),
-    "ops/pallas_short_attention.py::_TRACED_BWD_BATCH_HEADS": (
-        "IS the traced-choice recorder for _DEFAULT_BATCH_HEADS (append-only "
-        "at trace time; cleared only by the test-isolation reset)"
-    ),
     "ops/pallas_sigmoid_loss.py::_TRACED_LOSS_KERNELS": (
         "trace-time recorder for the streaming-loss-kernel dispatch "
         "(streaming / streaming_int8 / xla fallback); bench.py cross-checks "
@@ -118,10 +109,9 @@ MUTABLE_GLOBAL_ALLOWLIST = {
     ),
     "analysis/jaxpr_audit.py::_STEP_CONFIG_CACHE": (
         "host-side per-label memo of the deterministic step-config traces "
-        "(auditor + obs/attribution + obs/regress share one sampled "
-        "product, and the full-product pass reuses the tier-1 labels; the "
-        "trace used to run 3x per tier-1); never read inside traced code — "
-        "it CONTAINS closed jaxprs, which are inert data"
+        "(auditor + obs/attribution share one sampled product, and the "
+        "full-product pass reuses the tier-1 labels); never read inside "
+        "traced code — it CONTAINS closed jaxprs, which are inert data"
     ),
 }
 
@@ -289,9 +279,8 @@ def check_mutable_globals(
                     f"module-level {name!r} is mutated (line {line}) — "
                     "trace-time mutable global state; a step traced before "
                     "the mutation silently keeps the other behavior while "
-                    "records claim otherwise (the _DEFAULT_BATCH_HEADS "
-                    "class). Either remove it or allowlist it in "
-                    "analysis/repo_lint.py with a rationale naming its "
+                    "records claim otherwise. Either remove it or allowlist "
+                    "it in analysis/repo_lint.py with a rationale naming its "
                     "traced-choice recorder",
                 ))
     for key in sorted(set(allowlist) - seen_keys):
@@ -465,7 +454,7 @@ def check_bench_record_fields(bench_source: str | None = None) -> list[Finding]:
             bench_source = f.read()
     tree = ast.parse(bench_source)
     # Names whose dict keys ARE record fields: the per-mode `record` dicts,
-    # the `fields` dict _attn_bwd_record_fields merges into records, and any
+    # the `fields` dict _pallas_record_fields merges into records, and any
     # dict literal passed straight to _emit(...)/json.dumps(...).
     record_names = {"record", "fields"}
     findings = []

@@ -71,12 +71,14 @@ config in the sampled product; rule catalog in docs/ANALYSIS.md.
 
 from __future__ import annotations
 
+from distributed_sigmoid_loss_tpu.analysis.collective_prims import (
+    COLLECTIVES,
+    collective_axes,
+    names_with_role,
+)
 from distributed_sigmoid_loss_tpu.analysis.findings import Finding
 from distributed_sigmoid_loss_tpu.analysis.jaxpr_audit import (
-    _ALL_COLLECTIVES,
-    _GATHER_PRIMS,
     _Auditor,
-    _collective_axes,
     _is_literal,
     _jaxpr_of,
     _sub_jaxprs,
@@ -104,12 +106,12 @@ SHARD_FLOW_RULES = (
     "jaxpr-gather-placement",
 )
 
+_GATHER_PRIMS = names_with_role("gather")
 # Collectives that synchronize across shards of an axis — the ones whose
-# cross-branch ordering matters for the deadlock check. axis_index is pure
-# (no communication) and ppermute of nothing deadlocks nothing by itself,
-# but a mismatched ppermute still leaves peers waiting, so everything but
-# axis_index counts.
-_SYNC_COLLECTIVES = _ALL_COLLECTIVES - {"axis_index"}
+# cross-branch ordering matters for the deadlock check: everything that puts
+# bytes on the wire (a mismatched ppermute leaves peers waiting too);
+# axis_index and pvary communicate nothing.
+_SYNC_COLLECTIVES = frozenset(COLLECTIVES) - names_with_role("retype", "index")
 
 
 def _collective_sequence(jaxpr, out: list) -> None:
@@ -121,7 +123,7 @@ def _collective_sequence(jaxpr, out: list) -> None:
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         if name in _SYNC_COLLECTIVES:
-            axes = _collective_axes(eqn)
+            axes = collective_axes(eqn)
             if axes:
                 out.append((name, axes))
         for _, inner in _sub_jaxprs(eqn.params):
@@ -136,7 +138,7 @@ class _FlowAuditor(_Auditor):
     def _walk_collective(self, eqn, env, bound, emit, get) -> None:
         name = eqn.primitive.name
         if name in _GATHER_PRIMS and emit:
-            axes = _collective_axes(eqn)
+            axes = collective_axes(eqn)
             v = eqn.invars[0]
             # Scalars exempt: a gathered scalar is bookkeeping wire (the
             # compressed hop's quant-scale exchange double-syncs the two
@@ -467,10 +469,9 @@ def _check_codec_threading(jaxpr, codec_indices, add) -> None:
 # ---------------------------------------------------------------------------
 # jaxpr-gather-placement: the graftshard scatter-then-gather taint pass.
 
-# The primitives that produce a shard-axis-partial value: lax.psum_scatter
-# spells either name depending on the tiled lowering, so accept both (same
-# both-spellings hedge as jaxpr_audit._SUM_PRIMS).
-_SCATTER_PRIMS = frozenset({"psum_scatter", "reduce_scatter"})
+# The primitives that produce a shard-axis-partial value (lax.psum_scatter
+# traces to reduce_scatter).
+_SCATTER_PRIMS = names_with_role("scatter")
 
 
 def _check_gather_placement(jaxpr, axis, add, taint_in=None) -> list:
@@ -501,7 +502,7 @@ def _check_gather_placement(jaxpr, axis, add, taint_in=None) -> list:
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         if name in _SCATTER_PRIMS or name in _GATHER_PRIMS:
-            axes = _collective_axes(eqn)
+            axes = collective_axes(eqn)
             if name in _SCATTER_PRIMS and axis in axes:
                 for ov in eqn.outvars:
                     taint[ov] = True

@@ -2105,16 +2105,15 @@ def _add_obs_args(p) -> None:
     ``main`` (so `obs` shows up in --help) and the standalone intermixed
     parser the obs short-circuit builds, keeping the two in lockstep."""
     p.add_argument("action",
-                   choices=["summarize", "ledger", "diff", "regress"],
+                   choices=["summarize", "ledger", "diff"],
                    help="summarize: aggregate host spans + device op time "
                         "under DIR; ledger: per-metric trajectory summary; "
                         "diff: field-level diff of two records or two run "
-                        "dirs' span summaries; regress: proxy metrics vs "
-                        "the committed baseline (exit 1 on regression)")
+                        "dirs' span summaries")
     p.add_argument("paths", nargs="*",
                    help="summarize: DIR; diff: two operands (metric@N "
                         "ledger selector, entry index, record-JSON path, "
-                        "or run dir); ledger/regress: none")
+                        "or run dir); ledger: none")
     p.add_argument("--top", type=int, default=12,
                    help="rows per device-op table (obs summarize)")
     p.add_argument("--ledger", default="", metavar="PATH",
@@ -2127,17 +2126,6 @@ def _add_obs_args(p) -> None:
                         "committed BENCH_r*/MULTICHIP_r* round files "
                         "(idempotent; rounds whose backend was down land "
                         "as status=no-backend)")
-    p.add_argument("--baseline", default="", metavar="PATH",
-                   help="`obs regress`: baseline file (default: the "
-                        "committed obs/regress_baseline.json)")
-    p.add_argument("--update", action="store_true",
-                   help="`obs regress`: regenerate the baseline from the "
-                        "current tree instead of comparing (commit the "
-                        "result with the change that moved it)")
-    p.add_argument("--cpu-devices", type=int, default=0,
-                   help="`obs regress`: virtual CPU mesh size (default 8 — "
-                        "the same deterministic mesh the committed "
-                        "baseline was generated on)")
 
 
 def cmd_obs(args) -> int:
@@ -2151,16 +2139,11 @@ def cmd_obs(args) -> int:
     - ``obs diff A B`` — field-level diff of two records (ledger selectors
       like ``metric@-1``, entry indices, or record-JSON paths) or of two
       run directories' span summaries.
-    - ``obs regress`` — the chip-free proxy regression gate
-      (obs/regress.py) against the committed baseline; ``--update``
-      regenerates the baseline on the 8-virtual-device CPU mesh.
     """
     if args.action == "ledger":
         return _obs_ledger(args)
     if args.action == "diff":
         return _obs_diff(args)
-    if args.action == "regress":
-        return _obs_regress(args)
     return _obs_summarize(args)
 
 
@@ -2325,20 +2308,6 @@ def _obs_diff(args) -> int:
     if not (d["changed"] or d["added"] or d["removed"]):
         print("  records are identical")
     return 0
-
-
-def _obs_regress(args) -> int:
-    # Same bootstrap discipline as `lint`: the lattice traces shard_map'd
-    # steps, which needs the multi-device virtual mesh.
-    if not args.cpu_devices:
-        args.cpu_devices = 8
-    _bootstrap_devices(args)
-    from distributed_sigmoid_loss_tpu.obs.regress import run_regress
-
-    return run_regress(
-        baseline_path=args.baseline or None,
-        update=args.update,
-    )
 
 
 def _obs_summarize(args) -> int:
@@ -2990,8 +2959,7 @@ def main(argv=None) -> int:
         help="graftscope/graftledger reports: `obs summarize DIR` (merged "
              "host+device timeline), `obs ledger` (the perf trajectory from "
              "the append-only run ledger), `obs diff A B` (record or span "
-             "diffs), `obs regress` (chip-free proxy regression gate vs the "
-             "committed baseline) — docs/OBSERVABILITY.md",
+             "diffs) — docs/OBSERVABILITY.md",
     )
     _add_obs_args(ob)
 

@@ -22,9 +22,6 @@ evidence attached, chip or no chip:
   every bench emit path appends to (record + environment fingerprint +
   explicit status, so a dead backend lands as ``no-backend`` instead of a
   0.0 "measurement"); summarized/diffed by ``obs ledger`` / ``obs diff``.
-- :mod:`.regress` — chip-free regression gates: the config lattice's proxy
-  metrics (closed-form FLOPs, per-kind wire bytes, mfu_est, loss-island
-  temp bytes) vs committed baselines, run by ``obs regress`` in CI/dryrun.
 - :mod:`.telemetry` — live pull-based metrics: the OpenMetrics-style
   ``/metrics`` exporter the serving stack mounts, plus the atomic-rename
   telemetry file the train loop writes under ``--obs-dir``.
@@ -37,8 +34,7 @@ evidence attached, chip or no chip:
 
 Import discipline: this package must stay importable without initializing
 jax (the linter and the CLI's argparse layer import the schema); anything
-jax-touching lives behind function-level imports in :mod:`.attribution`
-and :mod:`.regress`.
+jax-touching lives behind function-level imports in :mod:`.attribution`.
 """
 
 from distributed_sigmoid_loss_tpu.obs.health import (  # noqa: F401

@@ -20,7 +20,7 @@ Per-device collective wire bytes, for a collective whose PER-SHARD operand is
 ``s`` bytes over a mesh axis (or axes) of total size ``W``:
 
 ==================  =======================  =================================
-primitive           bytes per device         rationale
+bucket              bytes per device         rationale
 ==================  =======================  =================================
 all_gather          ``(W-1)·s``              each device receives W-1 shards
 ppermute            ``s``                    one shard sent, one received
@@ -35,7 +35,7 @@ accessed) into a chip-free roofline: per-term times against a target chip's
 peak MXU rate / HBM bandwidth / ICI bandwidth, ``mfu_est`` = the MFU the
 config cannot exceed on that chip, and ``bound`` naming the limiting
 resource. ``device_kind=None`` asks for the repo's target chip (v5e) by name
-— the chip-free what-if ``obs regress`` runs on CPU hosts. An ACTUAL device
+— the chip-free what-if. An ACTUAL device
 kind must be in :data:`CHIP_SPECS`: a device the table does not know raises,
 so a CPU run never carries a v5e ``mfu_est`` (callers on an unlisted device
 omit the field).
@@ -50,6 +50,17 @@ from __future__ import annotations
 
 import math
 from typing import Iterable
+
+from distributed_sigmoid_loss_tpu.analysis.collective_prims import (
+    BUCKETS,
+    COLLECTIVES,
+    collective_axes,
+    manual_axis_sizes,
+)
+from distributed_sigmoid_loss_tpu.analysis.jaxpr_audit import (
+    _jaxpr_of,
+    _sub_jaxprs,
+)
 
 __all__ = [
     "CHIP_SPECS",
@@ -80,33 +91,9 @@ CHIP_SPECS = {
 # for by name) is computed against.
 DEFAULT_CHIP = "TPU v5 lite"
 
-COLLECTIVE_KINDS = (
-    "all_gather", "ppermute", "psum", "psum_scatter", "all_to_all",
-)
-
-# Wire-bytes factor as a function of axis size W, per primitive family.
-_WIRE_FACTORS = {
-    "all_gather": lambda w: w - 1,
-    "ppermute": lambda w: 1.0,
-    "psum": lambda w: 2.0 * (w - 1) / w,
-    "psum_scatter": lambda w: (w - 1) / w,
-    "reduce_scatter": lambda w: (w - 1) / w,
-    "all_to_all": lambda w: (w - 1) / w,
-    "pgather": lambda w: w - 1,
-    "pbroadcast": lambda w: (w - 1) / w,
-}
-
-# Primitive name -> the kind bucket it reports under.
-_KIND_OF = {
-    "all_gather": "all_gather",
-    "pgather": "all_gather",
-    "ppermute": "ppermute",
-    "psum": "psum",
-    "psum_scatter": "psum_scatter",
-    "reduce_scatter": "psum_scatter",
-    "all_to_all": "all_to_all",
-    "pbroadcast": "all_to_all",
-}
+# The reporting buckets; which primitive reports under which, and its wire
+# factor, is analysis/collective_prims.COLLECTIVES (the one table).
+COLLECTIVE_KINDS = BUCKETS
 
 
 def _aval_bytes(v) -> float:
@@ -118,21 +105,6 @@ def _aval_bytes(v) -> float:
     if size is None or dtype is None:
         return 0.0
     return float(size) * getattr(dtype, "itemsize", 4)
-
-
-def _collective_axes(eqn) -> tuple:
-    axes = eqn.params.get("axes", eqn.params.get("axis_name"))
-    if axes is None:
-        return ()
-    if not isinstance(axes, (tuple, list)):
-        axes = (axes,)
-    flat = []
-    for a in axes:
-        if isinstance(a, (tuple, list)):
-            flat.extend(a)
-        else:
-            flat.append(a)
-    return tuple(a for a in flat if isinstance(a, str))
 
 
 def _dot_general_flops(eqn) -> float:
@@ -169,26 +141,6 @@ def _conv_flops(eqn) -> float:
     return 2.0 * math.prod(out.shape) * macs_per_out
 
 
-def _jaxpr_of(obj):
-    if hasattr(obj, "eqns") and hasattr(obj, "invars"):
-        return obj
-    inner = getattr(obj, "jaxpr", None)
-    if inner is not None and hasattr(inner, "eqns"):
-        return inner
-    return None
-
-
-def _sub_jaxprs(params: dict):
-    out = []
-    for k, v in params.items():
-        vals = v if isinstance(v, (tuple, list)) else (v,)
-        for u in vals:
-            j = _jaxpr_of(u)
-            if j is not None:
-                out.append(j)
-    return out
-
-
 class _Costs:
     __slots__ = ("flops", "bytes_est", "comm")
 
@@ -203,19 +155,8 @@ def _walk(jaxpr, bound: dict, mult: float, acc: _Costs) -> None:
         name = eqn.primitive.name
 
         if name == "shard_map":
-            inner_bound = dict(bound)
-            mesh = eqn.params.get("mesh")
-            auto = eqn.params.get("auto") or frozenset()
-            try:
-                inner_bound.update({
-                    ax: sz for ax, sz in dict(mesh.shape).items()
-                    if ax not in auto
-                })
-            except Exception:
-                pass
-            inner = _jaxpr_of(eqn.params.get("jaxpr"))
-            if inner is not None:
-                _walk(inner, inner_bound, mult, acc)
+            inner_bound = {**bound, **manual_axis_sizes(eqn)}
+            _walk(eqn.params["jaxpr"], inner_bound, mult, acc)
             continue
 
         if name == "scan":
@@ -267,15 +208,14 @@ def _walk(jaxpr, bound: dict, mult: float, acc: _Costs) -> None:
                     acc.comm[k] += v
             continue
 
-        if name in _KIND_OF:
-            axes = _collective_axes(eqn)
+        if name in COLLECTIVES:
+            _role, bucket, wire = COLLECTIVES[name]
             w = 1
-            for ax in axes:
+            for ax in collective_axes(eqn):
                 w *= int(bound.get(ax, 1))
-            if w > 1:
-                factor = _WIRE_FACTORS[name](w)
+            if bucket is not None and w > 1:
                 s = sum(_aval_bytes(v) for v in eqn.invars)
-                acc.comm[_KIND_OF[name]] += factor * s * mult
+                acc.comm[bucket] += wire(w) * s * mult
             continue
 
         subs = _sub_jaxprs(eqn.params)
@@ -284,7 +224,7 @@ def _walk(jaxpr, bound: dict, mult: float, acc: _Costs) -> None:
             # recurse only — counting the call's own operand bytes would
             # double what the body already counts. while trip counts are
             # unknowable statically; its body is charged once (documented).
-            for inner in subs:
+            for _, inner in subs:
                 _walk(inner, bound, mult, acc)
             continue
 

@@ -69,50 +69,8 @@ __all__ = [
     "short_self_attention",
     "short_attention_fits",
     "short_attention_vmem_bytes",
-    "short_attention_bwd_batched_fits",
-    "set_bwd_batch_heads",
-    "traced_bwd_batch_heads",
-    "reset_traced_bwd_batch_heads",
     "SHORT_ATTENTION_MAX_SEQ",
 ]
-
-# Process-wide default for the backward kernel choice (see
-# short_self_attention's batch_heads): flipped by bench.py --attn-bwd for the
-# A/B without threading a knob through every tower config. Baked in at TRACE
-# time — set it before building/jitting the step.
-_DEFAULT_BATCH_HEADS = False
-
-# Every backward-kernel choice RESOLVED at trace time in this process. The
-# default above is mutable global state, so a step traced before
-# set_bwd_batch_heads silently keeps the other kernel while argv claims the
-# A/B ran (advisor, round 5) — records must cross-check against what actually
-# traced, not what was requested (bench.py does, before emitting).
-_TRACED_BWD_BATCH_HEADS: set[bool] = set()
-
-
-def set_bwd_batch_heads(enabled: bool) -> None:
-    """Set the process default for ``batch_heads=None`` call sites (the
-    towers). Call BEFORE tracing: compiled programs keep the kernel they were
-    traced with — :func:`traced_bwd_batch_heads` reports what actually did."""
-    global _DEFAULT_BATCH_HEADS
-    _DEFAULT_BATCH_HEADS = bool(enabled)
-
-
-def traced_bwd_batch_heads() -> tuple[bool, ...]:
-    """Distinct backward-kernel choices resolved at trace time so far, sorted.
-
-    ``()`` = no fused short-attention backward has been traced in this
-    process; ``(False,)`` / ``(True,)`` = every trace used the per-head loop /
-    the head-batched kernel; ``(False, True)`` = mixed (some step traced
-    before a ``set_bwd_batch_heads`` flip — the exact record-corruption case
-    the cross-check exists to catch).
-    """
-    return tuple(sorted(_TRACED_BWD_BATCH_HEADS))
-
-
-def reset_traced_bwd_batch_heads() -> None:
-    """Clear the trace record (test isolation)."""
-    _TRACED_BWD_BATCH_HEADS.clear()
 
 _NEG_INF = -1e30
 
@@ -201,22 +159,6 @@ def short_attention_fits(s: int, width: int, dtype_bytes: int) -> bool:
     return (
         s <= SHORT_ATTENTION_MAX_SEQ
         and short_attention_vmem_bytes(s, width, dtype_bytes)
-        <= _VMEM_BYTES * _VMEM_BUDGET_FRACTION
-    )
-
-
-def short_attention_bwd_batched_fits(
-    s: int, width: int, num_heads: int, dtype_bytes: int
-) -> bool:
-    """Whether the HEAD-BATCHED backward fits VMEM: it keeps all h (s, s) f32
-    chain intermediates (probs, dp, ds) live at once — h× the per-head loop's
-    O(s²) footprint — in exchange for issuing each of the 5 gradient matmuls
-    ONCE as an h-batched ``dot_general`` instead of h times at contraction
-    depth dh (64 on the towers — half the MXU's 128 systolic depth). ViT-B/16
-    (s=196, h=12): ~5.5 MB of chain + 2.1 MB of I/O blocks — fits; the
-    per-head loop stays the fallback for bigger shapes."""
-    return (
-        7 * s * width * dtype_bytes + 3 * num_heads * s * s * 4
         <= _VMEM_BYTES * _VMEM_BUDGET_FRACTION
     )
 
@@ -419,52 +361,6 @@ def _bwd_kernel(
             store(sl, [a + b for a, b in zip(*halves)])
 
 
-def _bwd_kernel_batched(
-    q_ref, k_ref, v_ref, do_ref, dq_ref, dk_ref, dv_ref, *, scale, causal, num_heads
-):
-    """Head-BATCHED backward: the round-3 attribution candidate. One h-batched
-    ``dot_general`` per gradient matmul (5 total) instead of a static Python
-    loop issuing each at (s, dh)-contraction — trades h× more live O(s²) VMEM
-    (see :func:`short_attention_bwd_batched_fits`) for fewer, larger MXU
-    dispatches. Numerics identical to :func:`_bwd_kernel`: f32 logits /
-    softmax / chain, matmul inputs in the activation dtype."""
-    q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-    s, width = q.shape
-    dh = width // num_heads
-
-    def heads(x):  # (s, h·dh) -> (h, s, dh)
-        return jnp.swapaxes(x.reshape(s, num_heads, dh), 0, 1)
-
-    def unheads(x):  # (h, s, dh) -> (s, h·dh)
-        return jnp.swapaxes(x, 0, 1).reshape(s, width)
-
-    def bdot(a, b_, ca, cb):
-        return lax.dot_general(
-            a, b_, (((ca,), (cb,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-
-    qh, kh, vh, doh = heads(q), heads(k), heads(v), heads(do)
-    logits = bdot(qh, kh, 2, 2) * scale  # (h, s, s)
-    if causal:
-        rows = lax.broadcasted_iota(jnp.int32, (num_heads, s, s), 1)
-        cols = lax.broadcasted_iota(jnp.int32, (num_heads, s, s), 2)
-        logits = jnp.where(rows >= cols, logits, _NEG_INF)
-    p = jax.nn.softmax(logits, axis=-1)  # (h, s, s) f32
-    p_lo = p.astype(v.dtype)
-    do_lo = doh.astype(v.dtype)
-    dv = bdot(p_lo, do_lo, 1, 1)  # pᵀ @ do: (h, s_k, dh)
-    dp = bdot(do_lo, vh, 2, 2)  # do @ vᵀ: (h, s_q, s_k)
-    ds = ((p * (dp - jnp.sum(dp * p, axis=-1, keepdims=True))) * scale).astype(
-        q.dtype
-    )
-    dq = bdot(ds, kh, 2, 1)  # ds @ k: (h, s_q, dh)
-    dk = bdot(ds, qh, 1, 1)  # dsᵀ @ q: (h, s_k, dh)
-    dq_ref[0] = unheads(dq).astype(dq_ref.dtype)
-    dk_ref[0] = unheads(dk).astype(dk_ref.dtype)
-    dv_ref[0] = unheads(dv).astype(dv_ref.dtype)
-
-
 def _specs(b, s, width, n: int, rows: int = 1):
     block = pl.BlockSpec((rows, s, width), lambda i: (i, 0, 0), memory_space=pltpu.VMEM)
     return dict(grid=(b // rows,), in_specs=[block] * n, out_specs=block)
@@ -474,24 +370,18 @@ def _flops(b, s, width, n_matmuls: int) -> int:
     return 2 * b * s * s * width * n_matmuls
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def short_self_attention(q, k, v, causal: bool = False, scale: float | None = None,
-                         interpret: bool = False,
-                         batch_heads: bool | None = None):
+                         interpret: bool = False):
     """Fused self-attention for VMEM-resident sequences: (b, s, h, dh) → same.
 
     ``interpret=True`` runs the kernel in the Pallas interpreter (CPU testing).
-    ``batch_heads`` selects the backward kernel: None/False keep the per-head
-    loop (the measured round-4 headline behavior); True runs the head-batched
-    gradient chain (requires :func:`short_attention_bwd_batched_fits`) — the
-    round-3 attribution candidate, exposed for the bench ``--attn-bwd`` A/B.
-    Adopt as default only after a measured win.
     """
-    out, _ = _short_attention_fwd(q, k, v, causal, scale, interpret, batch_heads)
+    out, _ = _short_attention_fwd(q, k, v, causal, scale, interpret)
     return out
 
 
-def _short_attention_fwd(q, k, v, causal, scale, interpret, batch_heads=None):
+def _short_attention_fwd(q, k, v, causal, scale, interpret):
     b, s, h, dh = q.shape
     scale = (dh**-0.5) if scale is None else scale
     wide = (b, s, h * dh)  # free reshape: heads stay on the minor axis
@@ -514,27 +404,14 @@ def _short_attention_fwd(q, k, v, causal, scale, interpret, batch_heads=None):
     return out.reshape(q.shape), (q, k, v)
 
 
-def _short_attention_bwd(causal, scale, interpret, batch_heads, residuals, g):
+def _short_attention_bwd(causal, scale, interpret, residuals, g):
     q, k, v = residuals
     b, s, h, dh = q.shape
     scale_v = (dh**-0.5) if scale is None else scale
     wide = (b, s, h * dh)
     spec = _specs(b, s, h * dh, 4)
-    if batch_heads is None:
-        batch_heads = _DEFAULT_BATCH_HEADS
-    # This body runs at TRACE time: what lands in the set is the kernel the
-    # compiled program will actually execute, not what argv asked for.
-    _TRACED_BWD_BATCH_HEADS.add(bool(batch_heads))
-    if batch_heads and not short_attention_bwd_batched_fits(
-        s, h * dh, h, q.dtype.itemsize
-    ):
-        raise ValueError(
-            f"batch_heads backward does not fit VMEM at s={s}, "
-            f"width={h * dh}, h={h}; use the per-head loop"
-        )
-    kernel = _bwd_kernel_batched if batch_heads else _bwd_kernel
     dq, dk, dv = pl.pallas_call(
-        functools.partial(kernel, scale=scale_v, causal=causal, num_heads=h),
+        functools.partial(_bwd_kernel, scale=scale_v, causal=causal, num_heads=h),
         out_shape=[jax.ShapeDtypeStruct(wide, q.dtype)] * 3,
         grid=spec["grid"],
         in_specs=spec["in_specs"],

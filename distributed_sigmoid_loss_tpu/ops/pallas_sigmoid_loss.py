@@ -80,8 +80,8 @@ DEFAULT_TILE_N = 256
 # Every loss-kernel choice RESOLVED at trace time in this process:
 # "streaming" / "streaming_int8" when a dispatch picked the kernel, "xla" when
 # a use_pallas request fell back to the XLA block. A record claiming
-# use_pallas while every block traced the fallback is the config-drift class
-# the attn_bwd round-5 fix exists for — bench.py cross-checks against THIS,
+# use_pallas while every block traced the fallback is config drift between
+# argv and the program — bench.py cross-checks against THIS,
 # not argv (registered in analysis/repo_lint.py MUTABLE_GLOBAL_ALLOWLIST).
 _TRACED_LOSS_KERNELS: set[str] = set()
 

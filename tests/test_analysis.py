@@ -4,7 +4,7 @@ a rule that cannot fire is a rule that silently stopped protecting anything).
 
 Standard tier: the jaxpr audit is trace-only (no compile) — the sampled
 step-config sweep (fifteen legacy + coverage extras) runs in ~45 s on this
-host, memoized per label across the analysis/attribution/regress consumers;
+host, memoized per label across the analysis/attribution consumers;
 everything else is AST/pure-python.
 """
 
@@ -44,6 +44,26 @@ def _mesh8():
 
 def _rules_of(findings):
     return sorted({f.rule for f in findings})
+
+
+def _prim_names(closed, named_axis_only=False) -> set:
+    """Primitive names in a closed jaxpr, nested jaxprs included; with
+    ``named_axis_only`` only equations that run over a named mesh axis."""
+    from distributed_sigmoid_loss_tpu.analysis.collective_prims import (
+        collective_axes,
+    )
+
+    out = set()
+
+    def rec(j):
+        for e in j.eqns:
+            if not named_axis_only or collective_axes(e):
+                out.add(e.primitive.name)
+            for _, inner in jaxpr_audit._sub_jaxprs(e.params):
+                rec(inner)
+
+    rec(closed.jaxpr)
+    return out
 
 
 def _audit_rules(fn, *args, **kwargs):
@@ -161,9 +181,7 @@ def test_weak_float_input_trips_and_int_counter_is_exempt():
 
 
 def test_f64_aval_trips_dtype_rule():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64():
         rules = _audit_rules(
             lambda z: z.astype("float64") * 2, jnp.ones((4,), jnp.float32)
         )
@@ -226,18 +244,7 @@ def test_fifteen_step_configs_audit_green_and_cover_all_paths():
     # all-gather ones all_gathers, chunked a remat'd scan — and every
     # pallas_* config a REAL pallas_call (an incompatible trace shape would
     # silently audit the XLA fallback instead of the new composition).
-    def prims(closed):
-        out = set()
-
-        def rec(j):
-            for e in j.eqns:
-                out.add(e.primitive.name)
-                for _, inner in jaxpr_audit._sub_jaxprs(e.params):
-                    rec(inner)
-
-        rec(closed.jaxpr)
-        return out
-
+    prims = _prim_names
     assert "ppermute" in prims(jaxprs["ring"][0])
     assert "ppermute" in prims(jaxprs["ring_overlap"][0])
     assert "all_gather" in prims(jaxprs["fused"][0])
@@ -293,6 +300,129 @@ def test_pallas_chunk_scan_without_checkpoint_trips():
     assert _audit_rules(
         jax.jit(chunk_loss(True)), x, expect_chunk_checkpoint=True
     ) == []
+
+
+# ---------------------------------------------------------------------------
+# what the auditor reads from the installed jax (PR 28): in_specs, aval.vma,
+# psum_invariant / pvary, and the one table of collective primitives
+# ---------------------------------------------------------------------------
+
+
+def test_shard_map_operands_are_seeded_from_in_specs():
+    """A P("dp") operand enters the body varying over dp, a P() one replicated
+    over every bound axis: read from the equation's in_specs and manual_axes."""
+    mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4), ("dcn", "dp"))
+    fn = shard_map(
+        lambda a, b, c, d: a.sum() + b.sum() + c.sum() + d.sum(),
+        mesh=mesh,
+        in_specs=(P("dp"), P(), P(("dcn", "dp")), P(None, "dcn")),
+        out_specs=P(), check_vma=False,
+    )
+    x = jnp.ones((8, 4))
+    closed = jax.make_jaxpr(fn)(x, x, x, x)
+    body = closed.jaxpr.eqns[0].params["jaxpr"]
+    seeded = {}
+
+    class Spy(jaxpr_audit._Auditor):
+        def walk(self, jaxpr, env, bound, emit):
+            if jaxpr is body:
+                seeded.update(env=dict(env), bound=dict(bound))
+            return super().walk(jaxpr, env, bound, emit)
+
+    Spy("fixture").walk(closed.jaxpr, {}, {}, True)
+    assert seeded["bound"] == {"dcn": 2, "dp": 4}
+    assert [seeded["env"][v][0] for v in body.invars] == [
+        frozenset({"dcn"}), frozenset({"dcn", "dp"}), frozenset(),
+        frozenset({"dp"}),
+    ]
+    assert all(seeded["env"][v][1] == frozenset() for v in body.invars)
+
+
+def test_psum_invariant_of_a_reduced_value_trips_double_psum():
+    """Under check_vma=True jax spells the loss island's sums psum_invariant
+    and retypes an invariant value with pvary. A sum of a value already
+    summed over the axis is the S-fold overcount whatever its type says; a
+    sum of a varying value is the reduction itself."""
+    from distributed_sigmoid_loss_tpu.parallel.collectives import pvary
+
+    mesh = _mesh8()
+
+    def island(body):
+        return shard_map(
+            body, mesh=mesh, in_specs=(P("dp"),), out_specs=P(),
+        )
+
+    twice = island(lambda z: lax.psum(pvary(lax.psum(z, "dp"), "dp"), "dp"))
+    once = island(lambda z: lax.psum(z * 2.0, "dp"))
+    x = jnp.ones((8, 4))
+    names = [e.primitive.name for e in
+             jax.make_jaxpr(twice)(x).jaxpr.eqns[0].params["jaxpr"].eqns]
+    assert names == ["psum_invariant", "pvary", "psum_invariant"]
+    assert _audit_rules(twice, x) == ["jaxpr-double-psum"]
+    assert _audit_rules(once, x) == []
+    # jax's own transpose of a checked island (pvary <-> psum_invariant) is
+    # not the overcount.
+    assert _audit_rules(jax.grad(lambda z: once(z).sum()), x) == []
+
+
+def test_pvary_ends_replication_in_a_checked_island():
+    """Inside check_vma=True "replicated over dp" is the value's type
+    (dp not in aval.vma), and pvary ends it: the fused loss island, forward
+    and backward, holds no redundant gather, and a gather jax itself typed
+    as of a varying value is taken at its word."""
+    from distributed_sigmoid_loss_tpu.ops.sigmoid_loss import init_loss_params
+    from distributed_sigmoid_loss_tpu.parallel import make_sharded_loss_fn
+
+    mesh = _mesh8()
+    loss = make_sharded_loss_fn(mesh, variant="all_gather", jit=False)
+    z = jnp.ones((16, 32))
+    closed = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        init_loss_params(), z, z
+    )
+    assert {"all_gather", "pvary", "psum_invariant"} <= _prim_names(closed)
+    assert shard_flow.audit_shard_flow(closed, label="fused-island") == []
+    assert jaxpr_audit.audit_jaxpr(closed, label="fused-island") == []
+    # check_vma=False has no types: there the walk's own dataflow still sees
+    # the gather of a P() operand (test_redundant_gather_trips_...).
+    typed = shard_map(
+        lambda w: lax.all_gather(w, "dp"),
+        mesh=mesh, in_specs=(P(),), out_specs=P("dp"),
+    )
+    assert _flow_rules(typed, jnp.ones((8, 4))) == []
+
+
+def test_one_table_covers_the_step_configs_collectives():
+    """Every named-axis primitive in the sampled step configurations' jaxprs
+    is a row of analysis/collective_prims.COLLECTIVES (this list is what jax
+    0.9.0 emits for them), and obs/attribution reads the same table: the
+    checked loss island's psum_invariant is wire traffic under comm_bytes_psum."""
+    from distributed_sigmoid_loss_tpu.analysis.collective_prims import (
+        BUCKETS,
+        COLLECTIVES,
+    )
+    from distributed_sigmoid_loss_tpu.obs import attribution
+    from distributed_sigmoid_loss_tpu.ops.sigmoid_loss import init_loss_params
+    from distributed_sigmoid_loss_tpu.parallel import make_sharded_loss_fn
+
+    seen = set()
+    for closed, _kwargs in jaxpr_audit.step_config_jaxprs().values():
+        seen |= _prim_names(closed, named_axis_only=True)
+    assert seen == {
+        "all_gather", "axis_index", "ppermute", "psum", "psum_invariant",
+        "pvary", "reduce_scatter",
+    }
+    assert seen <= set(COLLECTIVES)
+    assert {b for _, b, _ in COLLECTIVES.values() if b} == set(BUCKETS)
+    assert attribution.COLLECTIVE_KINDS == BUCKETS
+
+    loss = make_sharded_loss_fn(_mesh8(), variant="all_gather", jit=False)
+    z = jnp.ones((16, 32))
+    costs = attribution.jaxpr_costs(
+        jax.make_jaxpr(loss)(init_loss_params(), z, z)
+    )
+    # One f32 scalar all-reduced over 8 shards: 2 * 4 * 7/8 bytes a device.
+    assert costs["comm_bytes_psum"] == 2 * 4 * 7 / 8
+    assert costs["comm_bytes_all_gather"] > 0
 
 
 # ---------------------------------------------------------------------------

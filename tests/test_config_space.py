@@ -5,7 +5,7 @@ truth for which step configs exist; it must (a) contain every config the
 auditor historically guarded (the fifteen legacy labels — the acceptance
 pin), (b) agree exactly with the real imperative refusal layers (the drift
 probe, falsified here by injection), and (c) feed the sampled lattice the
-auditor/attribution/regress consumers trace. Plus the Finding surface the
+auditor/attribution consumers trace. Plus the Finding surface the
 PR adds (rule_id + location in --json, baseline ratchet mode).
 
 Standard tier: everything here is pure python over the feature model — the
@@ -74,7 +74,7 @@ def test_labels_are_unique_and_stable():
     for label, cfg in full.items():
         assert cs.label_of(cfg) == label
     # Non-legacy labels are the non-default axes in AXES order — stable
-    # across runs (the per-label trace memo and regress baseline key on it).
+    # across runs (the per-label trace memo keys on it).
     ring_zero1 = cs.StepConfig(variant="ring", update_sharding="zero1")
     assert cs.label_of(ring_zero1) == "variant=ring+update_sharding=zero1"
     assert cs.label_of(cs.StepConfig(update_sharding="full")) == (

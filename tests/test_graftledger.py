@@ -1,7 +1,6 @@
-"""graftledger: the perf-trajectory ledger, chip-free regression gates, and
-live telemetry export.
+"""graftledger: the perf-trajectory ledger and live telemetry export.
 
-Four contract families:
+Three contract families:
 
 - **Ledger** (`obs/ledger.py`): append/read round trips with torn-line
   tolerance, status classification (a dead backend is ``no-backend``, never
@@ -9,17 +8,12 @@ Four contract families:
   round files (761.74 @ r3 must surface as the last verified headline, with
   the r04/r05 outages excluded from baseline stats), and the bench.py
   ``_emit`` integration.
-- **Regress** (`obs/regress.py`): the shipped tree is green against the
-  committed baseline; a seeded synthetic regression (inflated chunked-island
-  temp bytes — the removed-checkpoint signature — or drifted ring traffic)
-  fails with the offending config + metric NAMED. The expensive collection
-  (15-config lattice trace + 4 island compiles) runs once, module-scoped.
 - **Telemetry** (`obs/telemetry.py` + `serve/service.py`): the ``/metrics``
   endpoint serves a schema-complete OpenMetrics snapshot under concurrent
   scrape+request load ACROSS a live ``swap_params`` hot swap — zero request
   errors, compile_count flat, endpoint latency bounded, snapshot reuse
   actually bounding the render rate; the atomic telemetry file is never torn.
-- **CLI**: ``obs ledger`` / ``obs diff`` / ``obs regress`` exit codes and
+- **CLI**: ``obs ledger`` / ``obs diff`` exit codes and
   rendering.
 """
 
@@ -318,119 +312,6 @@ def test_cli_obs_diff_selectors_and_errors(tmp_path, capsys):
     assert main(["obs", "diff", f"{metric}@99", f"{metric}@0",
                  "--ledger", path]) == 2
     capsys.readouterr()
-
-
-# ---------------------------------------------------------------------------
-# regress: proxies, contracts, committed baseline (collection shared)
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def proxies():
-    from distributed_sigmoid_loss_tpu.obs.regress import collect_proxies
-
-    return collect_proxies(n_devices=8)
-
-
-def test_regress_green_against_committed_baseline(proxies):
-    """THE acceptance gate: the shipped tree passes `obs regress` against
-    the committed baseline, contracts included."""
-    import io
-
-    from distributed_sigmoid_loss_tpu.obs.regress import run_regress
-
-    out = io.StringIO()
-    assert run_regress(current=proxies, stream=out) == 0, out.getvalue()
-    text = out.getvalue()
-    # Derive the expected lattice size from the auditor itself (memo hit —
-    # the proxies fixture already traced n=8): a hand-pinned literal here
-    # went stale every time config_space grew an axis.
-    from distributed_sigmoid_loss_tpu.analysis.jaxpr_audit import (
-        step_config_jaxprs,
-    )
-
-    assert f"{len(step_config_jaxprs(8))} step configs" in text
-    assert "green" in text
-
-
-def test_regress_contracts_hold_on_current_tree(proxies):
-    from distributed_sigmoid_loss_tpu.obs.regress import contract_findings
-
-    assert contract_findings(proxies) == []
-    isl = proxies["loss_islands"]
-    # the shipped ratios (PR 3 / PR 7 acceptance numbers, re-derived here)
-    fused = isl["fused"]["temp_bytes"]
-    assert isl["chunked"]["temp_bytes"] / fused < 0.3
-    assert isl["streaming_fused"]["temp_bytes"] / fused < 0.35
-
-
-def test_seeded_island_regression_fails_naming_metric(proxies):
-    """A removed chunk checkpoint inflates the chunked island's temp bytes
-    toward the fused level — seed exactly that signature and the gate must
-    fail NAMING loss_islands::chunked (both the baseline drift and the
-    ratio contract)."""
-    import copy
-    import io
-
-    from distributed_sigmoid_loss_tpu.obs.regress import run_regress
-
-    bad = copy.deepcopy(proxies)
-    bad["loss_islands"]["chunked"]["temp_bytes"] = (
-        bad["loss_islands"]["fused"]["temp_bytes"]
-    )
-    out = io.StringIO()
-    assert run_regress(current=bad, stream=out) == 1
-    text = out.getvalue()
-    assert "loss_islands::chunked" in text
-    assert "temp_bytes" in text
-
-
-def test_seeded_lattice_drift_fails_naming_config_and_metric(proxies):
-    import copy
-    import io
-
-    from distributed_sigmoid_loss_tpu.obs.regress import run_regress
-
-    bad = copy.deepcopy(proxies)
-    bad["step_configs"]["ring_overlap"]["comm_bytes_ppermute"] *= 2
-    out = io.StringIO()
-    assert run_regress(current=bad, stream=out) == 1
-    text = out.getvalue()
-    assert "step_configs::ring_overlap::comm_bytes_ppermute" in text
-
-
-def test_removed_config_and_version_mismatch_semantics(proxies):
-    import copy
-
-    from distributed_sigmoid_loss_tpu.obs.regress import (
-        compare_proxies,
-        load_baseline,
-    )
-
-    base = load_baseline()
-    assert base is not None, "committed baseline missing"
-    gone = copy.deepcopy(proxies)
-    del gone["step_configs"]["chunked"]
-    fails, _ = compare_proxies(gone, base)
-    assert any("step_configs::chunked" in str(f) for f in fails)
-    # jax mismatch: island temp drift becomes a warning, not a failure
-    other = copy.deepcopy(proxies)
-    other["meta"]["jax"] = "99.0"
-    other["loss_islands"]["chunked"]["temp_bytes"] *= 3
-    fails, warns = compare_proxies(other, base)
-    assert not any("loss_islands" in str(f) for f in fails)
-    assert any("loss_islands::chunked" in w for w in warns)
-
-
-def test_baseline_matches_freshly_collected(proxies):
-    """Determinism: the committed baseline IS what this mesh collects —
-    byte-identical closed-form proxies, tolerance-level temp bytes."""
-    from distributed_sigmoid_loss_tpu.obs.regress import load_baseline
-
-    base = load_baseline()
-    assert base["meta"]["n_devices"] == 8
-    if base["meta"]["jax"] == proxies["meta"]["jax"]:
-        assert base["step_configs"] == proxies["step_configs"]
 
 
 # ---------------------------------------------------------------------------

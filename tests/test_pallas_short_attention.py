@@ -30,8 +30,7 @@ CASES = [
 # transposed one. Then one case for each way the backward cuts dh = 64 heads out
 # of a 128-lane slab (CASES' dh = 32 takes the lane-slice chain): s = 196 and
 # causal s = 80, two heads masked in turn; s = 64 non-causal, the two heads'
-# logits packed into one lane tile. Not in the batched-parity test:
-# batch_heads=True refuses the so400m shape in f32.
+# logits packed into one lane tile.
 CELL_CASES = [
     (2, 64, 16, 72, False),
     (1, 256, 16, 72, False),
@@ -131,78 +130,20 @@ def test_bf16_gradients_match_dense_at_the_cell_shapes(b, s, h, dh, causal):
         assert err <= tol, f"{name}: {err:.3e} > {tol}"
 
 
-@pytest.mark.parametrize("b,s,h,dh,causal", CASES)
-def test_batched_bwd_matches_per_head_loop(b, s, h, dh, causal):
-    """The head-batched backward (round-3 attribution candidate, bench
-    --attn-bwd batched) must reproduce the per-head loop's gradients — same
-    chain, same f32 softmax/logits numerics, different MXU dispatch shape."""
-    rng = np.random.default_rng(2)
-    q, k, v = (
-        jnp.asarray(rng.standard_normal((b, s, h, dh)), jnp.float32)
-        for _ in range(3)
-    )
-    w = jnp.asarray(rng.standard_normal((b, s, h, dh)), jnp.float32)
+def test_no_mutable_global_left_in_the_attention_kernel():
+    """PR 28: the backward is chosen from the shapes alone, so the kernel file
+    mutates no module-level state and repo_lint's allowlist exempts nothing in
+    it. The one ``ops/`` entry left is the streaming loss kernel's trace
+    recorder (ROADMAP D11)."""
+    from distributed_sigmoid_loss_tpu.analysis import repo_lint
 
-    def grads(batch_heads):
-        return jax.grad(
-            lambda q, k, v: jnp.sum(
-                short_self_attention(q, k, v, causal, None, True, batch_heads)
-                * w
-            ),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-
-    for g_b, g_l in zip(grads(True), grads(False)):
-        np.testing.assert_allclose(
-            np.asarray(g_b), np.asarray(g_l), atol=2e-5
-        )
-
-
-def test_traced_bwd_choice_is_recorded_at_trace_time():
-    """The bench record cross-check's data source: tracing the backward must
-    record the kernel choice RESOLVED (default or explicit), so a step traced
-    before a set_bwd_batch_heads flip is detectable (advisor, round 5)."""
-    from distributed_sigmoid_loss_tpu.ops import pallas_short_attention as psa
-
-    rng = np.random.default_rng(3)
-    q, k, v = (
-        jnp.asarray(rng.standard_normal((1, 8, 2, 4)), jnp.float32)
-        for _ in range(3)
-    )
-    psa.reset_traced_bwd_batch_heads()
-    try:
-        assert psa.traced_bwd_batch_heads() == ()
-        jax.grad(
-            lambda q: jnp.sum(short_self_attention(q, k, v, False, None, True))
-        )(q)
-        assert psa.traced_bwd_batch_heads() == (False,)  # default: per-head loop
-        jax.grad(
-            lambda q: jnp.sum(
-                short_self_attention(q, k, v, False, None, True, True)
-            )
-        )(q)
-        assert psa.traced_bwd_batch_heads() == (False, True)  # mixed → detectable
-    finally:
-        psa.reset_traced_bwd_batch_heads()
-
-
-def test_batched_bwd_fits_check():
-    from distributed_sigmoid_loss_tpu.ops.pallas_short_attention import (
-        short_attention_bwd_batched_fits,
-        short_self_attention as ssa,
-    )
-
-    # ViT-B/16 and text shapes fit; a 1024-seq 16-head tower does not.
-    assert short_attention_bwd_batched_fits(196, 768, 12, 2)
-    assert short_attention_bwd_batched_fits(64, 768, 12, 2)
-    assert not short_attention_bwd_batched_fits(1024, 1024, 16, 2)
-    q = jnp.zeros((1, 1024, 16, 64), jnp.bfloat16)
-    with pytest.raises(ValueError, match="batch_heads"):
-        jax.grad(
-            lambda q: jnp.sum(
-                ssa(q, q, q, False, None, True, True).astype(jnp.float32)
-            )
-        )(q)
+    rel = "ops/pallas_short_attention.py"
+    with open(psa.__file__, encoding="utf-8") as f:
+        findings = repo_lint.check_mutable_globals({rel: f.read()}, allowlist={})
+    assert findings == [], [str(f) for f in findings]
+    assert [k for k in repo_lint.MUTABLE_GLOBAL_ALLOWLIST if k.startswith("ops/")] == [
+        "ops/pallas_sigmoid_loss.py::_TRACED_LOSS_KERNELS"
+    ]
 
 
 def test_custom_scale():
