@@ -15,6 +15,7 @@ end-to-end target adds ViT-B/16 + text transformer. This core is built TPU-first
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from functools import partial
 from typing import Any
 
@@ -28,6 +29,11 @@ from jax.ad_checkpoint import checkpoint_name
 TP_AXIS = "tp"
 # Mesh axis the batch is sharded over (parallel/mesh.py data_axis).
 DP_AXIS = "dp"
+# The variable collection in which an accumulating step (train/train_step.py)
+# offers a scanned stack its slice of the gradient accumulator, and the step
+# program's name for the accumulator's traffic (benchmark/scopes.py reads it).
+GRAD_SINK = "grad_sink"
+ACCUM_SCOPE = "accum"
 
 
 def _dtype(name: str):
@@ -417,8 +423,64 @@ class Block(nn.Module):
         return x
 
 
+@jax.custom_vjp
+def _add_grads_into(params, stacks, layer):
+    """``(params, stacks)`` as they came. Backwards, the stacks' cotangent
+    gains the parameters' gradient at row ``layer`` (accum_add's
+    upcast-add-round on that row, in place) and the parameters get none: seeded
+    with the accumulator, the cotangent that comes out is the new accumulator."""
+    return params, stacks
+
+
+def _add_grads_into_bwd(layer, cts):
+    grads, stacks = cts
+
+    def add_row(acc, g):
+        row = jax.lax.dynamic_index_in_dim(acc, layer, 0, keepdims=False)
+        row = (row.astype(g.dtype) + g).astype(acc.dtype)
+        return jax.lax.dynamic_update_index_in_dim(acc, row, layer, 0)
+
+    with jax.named_scope(ACCUM_SCOPE):
+        return None, jax.tree.map(add_row, stacks, grads), None
+
+
+_add_grads_into.defvjp(
+    lambda params, stacks, layer: ((params, stacks), layer), _add_grads_into_bwd
+)
+
+
+def split_grad_sink(tree):
+    """``(stacks, rest)`` of a parameter-shaped dict: the scanned, dense,
+    unlooped stacks (``blocks`` of an :class:`Encoder`), whose weight gradients
+    an accumulating step may have added into the accumulator inside the layer
+    loop, and every other leaf. Empty branches are dropped."""
+    stacks, rest = {}, {}
+    for key, sub in tree.items():
+        if key == "blocks" and "moe" not in sub["block"]:
+            stacks[key] = sub
+        elif isinstance(sub, Mapping) and key != "loop":
+            inner, outer = split_grad_sink(sub)
+            if inner:
+                stacks[key] = inner
+            if outer:
+                rest[key] = outer
+        else:
+            rest[key] = sub
+    return stacks, rest
+
+
+def merge_grad_sink(stacks, rest):
+    """Inverse of :func:`split_grad_sink`."""
+    out = dict(rest)
+    for key, sub in stacks.items():
+        out[key] = merge_grad_sink(sub, rest[key]) if key in rest else sub
+    return out
+
+
 class _ScanBody(nn.Module):
-    """Scan-compatible block wrapper: ``(carry, _) -> (carry, None)``."""
+    """Scan-compatible block wrapper: ``(carry, layer) -> (carry, None)``;
+    ``layer`` is the layer's index where an accumulating step offers a sink
+    (``GRAD_SINK``, carried whole by the scan), else None."""
 
     width: int
     num_heads: int
@@ -436,8 +498,21 @@ class _ScanBody(nn.Module):
     style: BlockStyle = BlockStyle()
 
     @nn.compact
-    def __call__(self, carry, _):
-        carry = Block(
+    def __call__(self, carry, layer):
+        block_cls = Block
+        if layer is not None:
+            # The accumulating step's sink (Encoder): this layer's parameters
+            # pass _add_grads_into with the carried stacks.
+            def through_sink(collections):
+                params, stacks = _add_grads_into(
+                    collections["params"], collections[GRAD_SINK], layer
+                )
+                return {"params": params, GRAD_SINK: stacks}
+
+            block_cls = nn.map_variables(
+                Block, ("params", GRAD_SINK), through_sink, mutable=True
+            )
+        carry = block_cls(
             self.width, self.num_heads, self.mlp_ratio, self.dtype,
             sp_axis=self.sp_axis, sp_impl=self.sp_impl,
             attn_impl=self.attn_impl, causal=self.causal,
@@ -455,7 +530,15 @@ class Encoder(nn.Module):
     """Stack of blocks, then the final norm; optionally remat'd and scanned over
     depth. ``loops > 1`` runs that whole pass ``loops`` times on one set of
     weights, which then live under ``loop/`` (a flax path of its own: a profile
-    shows the looped stack's operations under it)."""
+    shows the looped stack's operations under it).
+
+    A scanned stack run once takes a ``GRAD_SINK`` collection (applied with
+    ``mutable=[GRAD_SINK]``): the gradient accumulator of its ``blocks``, which
+    it hands back unchanged. Differentiated with that output's cotangent seeded
+    with the accumulator, the backward layer loop carries it and adds each
+    layer's weight gradients to their row (:func:`_add_grads_into`); what comes
+    out as the sink's cotangent is the new accumulator, and ``blocks`` get no
+    gradient of their own. Without the collection nothing changes."""
 
     width: int
     depth: int
@@ -482,6 +565,13 @@ class Encoder(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        sunk = bool(self.variables.get(GRAD_SINK))
+        if sunk and not (self.scan_layers and self.loops == 1):
+            raise ValueError(
+                f"{GRAD_SINK!r} is for a scanned stack run once: a looped stack "
+                f"(loops={self.loops}) would add it once a pass, unrolled layers "
+                "(scan_layers=False) have no layer loop to add it in"
+            )
         if self.loops > 1:
             return self._looped(x)
         moe_kw = dict(
@@ -501,9 +591,12 @@ class Encoder(nn.Module):
                 )
             # One set of stacked params, compiled once: lax.scan over depth.
             # The sown MoE aux losses ride the scan with a leading depth axis.
+            # A sink rides it whole, as a carry: the backward layer loop then
+            # carries the accumulator and adds each layer's row in place.
             scanned = nn.scan(
                 body_cls,
                 variable_axes={"params": 0, "intermediates": 0},
+                variable_carry=GRAD_SINK if sunk else False,
                 split_rngs={"params": True},
                 length=self.depth,
                 metadata_params={nn.PARTITION_NAME: None},
@@ -513,7 +606,7 @@ class Encoder(nn.Module):
                 sp_axis=self.sp_axis, sp_impl=self.sp_impl,
                 attn_impl=self.attn_impl, causal=self.causal, **moe_kw,
                 name="blocks",
-            )(x, None)
+            )(x, jnp.arange(self.depth) if sunk else None)
         else:
             block_cls = (
                 nn.remat(Block, policy=_remat_policy(self.remat_policy))

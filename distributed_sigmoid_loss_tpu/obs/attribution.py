@@ -68,6 +68,7 @@ __all__ = [
     "COLLECTIVE_KINDS",
     "jaxpr_costs",
     "static_attribution",
+    "accum_placement",
     "attribution_of_compiled",
     "roofline_estimate",
     "step_config_attribution",
@@ -270,6 +271,20 @@ def static_attribution(fn, *args, bound_axes: dict | None = None) -> dict:
     import jax
 
     return jaxpr_costs(jax.make_jaxpr(fn)(*args), bound_axes=bound_axes)
+
+
+def accum_placement(step) -> dict | None:
+    """Where a traced train step's microbatch accumulation adds a parameter's
+    gradient, from the record ``make_train_step`` writes while it traces
+    (train/train_step.py): parameter bytes added inside the backward layer loop
+    (scanned stacks, in the weight-gradient matmul's epilogue), bytes
+    ``accum_add`` carries as a pass of its own, and the first's share. None
+    for a step that has not traced yet or does not accumulate."""
+    record = getattr(step, "accum_record", None)
+    if not record:
+        return None
+    total = record["layer_loop_bytes"] + record["accum_add_bytes"]
+    return dict(record, layer_loop_share=record["layer_loop_bytes"] / total)
 
 
 def attribution_of_compiled(compiled) -> dict:

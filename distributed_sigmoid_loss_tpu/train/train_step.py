@@ -27,6 +27,12 @@ from flax.training import train_state
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from distributed_sigmoid_loss_tpu.models.transformer import (
+    ACCUM_SCOPE,
+    GRAD_SINK,
+    merge_grad_sink,
+    split_grad_sink,
+)
 from distributed_sigmoid_loss_tpu.parallel.mesh import trace_on
 from distributed_sigmoid_loss_tpu.parallel.update_shard import (
     OPTIMIZER_SCOPE,
@@ -54,8 +60,14 @@ __all__ = [
 # benchmark/scopes.py turns them into ``loss_island_ms``, ``accum_ms`` and
 # (with ``optimizer``) ``update_and_metrics_ms``. The towers need none: flax writes ``visual/...`` and
 # ``textual/...`` into every operation's path.
+# Beside the names, the step's one counter, written while it traces: where the
+# microbatch accumulation adds a parameter's gradient, in parameter bytes
+# (``step.accum_record``: ``layer_loop_bytes`` inside the backward layer loop,
+# ``accum_add_bytes`` as accum_add's pass of its own; empty for a step that has
+# not traced or does not accumulate). obs/attribution.py accum_placement reads it.
 LOSS_ISLAND_SCOPE = "loss_island"  # the sharded sigmoid loss, forward and backward
-ACCUM_SCOPE = "accum"  # the gradient accumulator's traffic in the microbatch scan
+# ACCUM_SCOPE ("accum", models/transformer.py beside the one add that runs in a
+# tower): the gradient accumulator's traffic in the microbatch scan.
 STEP_METRICS_SCOPE = "step_metrics"  # the health scalars every step pays
 
 
@@ -285,6 +297,10 @@ def validate_step_args(
                 f"{pipeline_axis!r} axis, got {mesh_axis_names}"
             )
     return cached_accum, acc_dt
+
+
+def _tree_bytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
 def accum_zeros(params, acc_dt):
@@ -651,7 +667,15 @@ def make_train_step(
     (inherent to accumulation, same as open_clip without its re-encoding trick):
     each microbatch contrasts only against its own texts, so the negative set per
     loss term is ``global/accum_steps``, not ``global`` — UNLESS
-    ``accum_negatives="global"`` (below).
+    ``accum_negatives="global"`` (below). Where a tower's layers are scanned
+    (and dense, and run once) its stack's weight gradients are added into the
+    accumulator inside the backward layer loop, in the weight-gradient
+    matmul's fusion, and no gradient stack is made for them (``GRAD_SINK``,
+    models/transformer.py Encoder); every other leaf, and every leaf of an
+    unrolled or looped tower, of the pp towers and of the "global" path, is
+    added by ``accum_add`` after the microbatch's backward pass. Same adds,
+    same roundings; ``step.accum_record`` says how many parameter bytes went
+    which way.
 
     ``accum_negatives="global"`` (with ``accum_steps > 1``) computes the EXACT
     full-batch loss under accumulation, GradCache-style (Gao et al. 2021;
@@ -782,29 +806,34 @@ def make_train_step(
         validate_pp_tower(model.cfg.vision, pp_stages, "vision")
         validate_pp_tower(model.cfg.text, pp_stages, "text")
 
-    def loss_fn(params, batch):
+    def loss_fn(params, batch, sink=None):
+        """``loss, (lp, aux, the sink as the model hands it back)``."""
+        variables, mutable = {"params": params}, []
+        if sink:
+            variables[GRAD_SINK] = sink
+            mutable.append(GRAD_SINK)
+        if moe_aux_weight is not None:
+            mutable.append("intermediates")
+        updated = {}
         if pp_microbatches:
             zimg, ztxt, lp = siglip_forward_pp(
                 model.cfg, params, batch["images"], batch["tokens"],
                 mesh=mesh, num_microbatches=pp_microbatches,
             )
-            aux = jnp.zeros(())
-        elif moe_aux_weight is None:
+        elif not mutable:
             zimg, ztxt, lp = model.apply(
-                {"params": params}, batch["images"], batch["tokens"]
+                variables, batch["images"], batch["tokens"]
             )
-            aux = jnp.zeros(())
         else:
-            (zimg, ztxt, lp), variables = model.apply(
-                {"params": params}, batch["images"], batch["tokens"],
-                mutable=["intermediates"],
+            (zimg, ztxt, lp), updated = model.apply(
+                variables, batch["images"], batch["tokens"], mutable=mutable
             )
-            aux = _mean_moe_aux(variables)
+        aux = jnp.zeros(()) if moe_aux_weight is None else _mean_moe_aux(updated)
         with jax.named_scope(LOSS_ISLAND_SCOPE):
             loss = sharded_loss(zimg, ztxt, lp["t_prime"], lp["bias"])
         if moe_aux_weight is not None:
             loss = loss + moe_aux_weight * aux
-        return loss, (lp, aux)
+        return loss, (lp, aux, updated.get(GRAD_SINK, {}))
 
     # accum_negatives="global": the stacked-embedding loss island. Each device
     # sees its LOCAL rows of every microbatch (M, mb/dp, d) and flattens them
@@ -833,10 +862,14 @@ def make_train_step(
     if loss_cfg.loss_impl == "chunked" or loss_cfg.use_pallas:
         stacked_loss = jax.jit(stacked_loss)  # same jitted-shard_map arrangement
 
+    accum_record: dict = {}
+
     def grads_and_metrics_cached(params, batch):
         from distributed_sigmoid_loss_tpu.parallel.microbatch import (
             microbatch_split,
         )
+
+        accum_record.update(layer_loop_bytes=0, accum_add_bytes=_tree_bytes(params))
 
         micro = jax.tree.map(
             lambda x: microbatch_split(x, accum_steps, mesh, axis, what="accum_steps"),
@@ -856,9 +889,9 @@ def make_train_step(
         if cached_accum:
             return grads_and_metrics_cached(params, batch)
         if accum_steps == 1:
-            (loss, (lp, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, batch
-            )
+            (loss, (lp, aux, _)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(params, batch)
             return loss, lp, aux, grads
 
         # Interleaved per-device-chunk split (parallel/microbatch.py): the
@@ -874,19 +907,44 @@ def make_train_step(
             batch
         )
 
+        # Where layers are scanned (models/transformer.py Encoder) the stack's
+        # accumulator goes through the model as GRAD_SINK: handed back by the
+        # forward, seeded with itself as that output's cotangent, it comes out
+        # of the backward layer loop with every layer's weight gradient added
+        # to its row, on the weight-gradient matmul's result, and no gradient
+        # stack is made. Every other leaf keeps accum_add. The pp towers never
+        # see it.
+        in_loop, outside = split_grad_sink(params)
+        if pp_microbatches:
+            in_loop, outside = {}, params
+        accum_record.update(
+            layer_loop_bytes=_tree_bytes(in_loop), accum_add_bytes=_tree_bytes(outside)
+        )
+        in_loop, outside = (accum_zeros(t, acc_dt) for t in (in_loop, outside))
+
         def body(carry, mb):
-            loss_sum, grad_sum = carry
-            (loss, (lp, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, mb
+            loss_sum, in_loop, outside = carry
+
+            def through_sink(p, sink):
+                loss, (lp, aux, sink) = loss_fn(p, mb, sink)
+                return (loss, sink), (lp, aux)
+
+            (loss, _), grads_of, (lp, aux) = jax.vjp(
+                through_sink, params, in_loop, has_aux=True
             )
-            carry = (loss_sum + loss, accum_add(grad_sum, grads))
+            grads, in_loop = grads_of((jnp.ones_like(loss), in_loop))
+            if in_loop:
+                grads = split_grad_sink(grads)[1]
+            carry = (loss_sum + loss, in_loop, accum_add(outside, grads))
             return carry, (lp, aux)
 
-        (loss_sum, grad_sum), (lps, auxs) = lax.scan(
-            body, (jnp.zeros(()), accum_zeros(params, acc_dt)), micro
+        (loss_sum, in_loop, outside), (lps, auxs) = lax.scan(
+            body, (jnp.zeros(()), in_loop, outside), micro
         )
         lp = jax.tree.map(lambda x: x[-1], lps)
-        grads = accum_finish(grad_sum, params, scale=accum_steps)
+        grads = accum_finish(
+            merge_grad_sink(in_loop, outside), params, scale=accum_steps
+        )
         return loss_sum / accum_steps, lp, jnp.mean(auxs), grads
 
     def step(state: TrainState, batch: dict, param_out_shardings=None):
@@ -931,7 +989,9 @@ def make_train_step(
         "tokens": NamedSharding(mesh, P(axis)),
     }
     if update_mode != "full":
-        return jax.jit(step, donate_argnums=(0,)), batch_sharding
+        jitted = jax.jit(step, donate_argnums=(0,))
+        jitted.accum_record = accum_record
+        return jitted, batch_sharding
 
     # Full mode: the publish constraint needs the params' at-rest shardings,
     # which only a CONCRETE state carries — capture them from the first call
@@ -959,4 +1019,5 @@ def make_train_step(
     # AOT path (bench.py's step.lower(...).compile()): same capture, same
     # single inner jit — lowering and calling share one executable.
     sharded_step.lower = lambda state, batch: _inner(state).lower(state, batch)
+    sharded_step.accum_record = accum_record
     return sharded_step, batch_sharding
