@@ -72,19 +72,26 @@ def train_flops_per_pair(cfg) -> float:
     return 3.0 * forward_flops_per_pair(cfg)
 
 
-def attention_flops_per_pair(cfg) -> float:
-    """Scores and values in the blocks' self-attention, forward + backward, both
-    towers: 3 x 4 s^2 w = 12 s^2 w per layer per sequence. What the fused
-    attention kernels have to do in a step, recomputation not counted."""
-    v, t = cfg.vision, cfg.text
-    sv, st = vision_tokens(v), t.context_length
-    return float(12 * sv * sv * v.width * v.depth + 12 * st * st * t.width * t.depth)
+def attention_layers_least_s(s: int, w: int, applications: int, peaks: dict, itemsize: int = 2) -> float:
+    """The least time for ``applications`` softmax-attention cores of ``s`` tokens
+    at width ``w``, forward + backward, recomputation not counted: the larger of
+    12 s^2 w operations each (scores and values, 3 x 4 s^2 w) over the bf16 peak
+    and 12 x itemsize s w bytes each over the HBM peak (forward reads q, k, v and
+    writes the output; backward reads those four and the output's cotangent and
+    writes three)."""
+    return max(
+        12.0 * s * s * w * applications / (peaks["bf16_tflops"] * 1e12),
+        12.0 * itemsize * s * w * applications / (peaks["hbm_gb_per_s"] * 1e9),
+    )
 
 
-def attention_bytes_per_pair(cfg, itemsize: int = 2) -> float:
-    """The least HBM traffic of those kernels: forward reads q, k, v and writes
-    the output (4 s w); backward reads q, k, v, the output and its cotangent and
-    writes three cotangents (8 s w)."""
-    v, t = cfg.vision, cfg.text
-    sv, st = vision_tokens(v), t.context_length
-    return float(12 * itemsize * (sv * v.width * v.depth + st * t.width * t.depth))
+def attention_least_s(cfg, tower: str, sequences: int, peaks: dict) -> float:
+    """The least time the chip could take for the softmax-attention cores that
+    ``tower`` ("visual" | "textual") runs through the program's fused kernels for
+    ``sequences`` pairs: a SigLIP tower applies each of its ``depth`` layers once
+    a sequence. Memory-bound at these lengths: s/2 operations a byte, 98 (image)
+    and 32 (text), against the v5e's 240. A count module whose tower runs no such
+    layer returns 0, and the roofline readers then say nothing."""
+    t = cfg.vision if tower == "visual" else cfg.text
+    s = vision_tokens(t) if tower == "visual" else t.context_length
+    return attention_layers_least_s(s, t.width, sequences * t.depth, peaks)

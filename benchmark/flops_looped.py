@@ -12,6 +12,7 @@ a GELU MLP this is ``flops.py``.
 from __future__ import annotations
 
 # benchmark/ is on sys.path: run.py and the tools under tests/ put it there.
+import flops
 from flops import mlp_hidden, vision_forward_flops
 
 MLP_MATMULS = {"gelu": 2, "swiglu": 3}
@@ -46,17 +47,12 @@ def train_flops_per_pair(cfg) -> float:
     return 3.0 * forward_flops_per_pair(cfg)
 
 
-def text_attention_least_s(t, sequences: int, peaks: dict, itemsize: int = 2) -> float:
-    """The least time the chip could take for the text tower's block attention
-    over ``sequences`` captions, forward + backward, recomputation not counted:
-    per layer application the larger of 12 s^2 w operations over the bf16 peak
-    and 12 x itemsize s w bytes over the HBM peak (forward reads q, k, v and
-    writes the output; backward reads those four and the cotangent and writes
-    three). At s = 256 that is s/2 = 128 operations a byte against the v5e's
-    240: bound by memory."""
-    s, w, n = t.context_length, t.width, sequences * layer_applications(t)
-    return max(
-        12.0 * s * s * w * n / (peaks["bf16_tflops"] * 1e12),
-        12.0 * itemsize * s * w * n / (peaks["hbm_gb_per_s"] * 1e9),
-    )
-
+def attention_least_s(cfg, tower: str, sequences: int, peaks: dict) -> float:
+    """``flops.attention_least_s`` with every layer application counted: the text
+    tower runs ``loops x depth`` of them a caption. At s = 256 that is s/2 = 128
+    operations a byte against the v5e's 240: bound by memory. The image tower is
+    ``flops.py``'s."""
+    if tower == "visual":
+        return flops.attention_least_s(cfg, tower, sequences, peaks)
+    t = cfg.text
+    return flops.attention_layers_least_s(t.context_length, t.width, sequences * layer_applications(t), peaks)

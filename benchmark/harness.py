@@ -285,4 +285,9 @@ def info_line(tag: str, **fields) -> None:
 
 
 def result_line(result: dict) -> None:
+    """The result as the last line of standard output, and what ``correct``
+    compared, each number beside its limit, as the last lines of standard error."""
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in result.get("compared", {}).items():
+        print(f"benchmark: compared {name} = {value!r}, limit {limit!r}", file=sys.stderr)
+    sys.stderr.flush()

@@ -12,14 +12,27 @@ from __future__ import annotations
 import dataclasses
 import gc
 import math
+import os
 import statistics
 import time
 import types
 
 # benchmark/ is on sys.path: run.py and the tools under tests/ put it there.
-import flops
 import harness
-import reference
+
+
+def load_modules(config: dict):
+    """The plain reference and the operation count of a configuration, the two
+    files under benchmark/ its ``modules`` names (benchmark/README.md has what
+    each must offer): the model's mathematics and its count belong to the
+    configuration, not to the job."""
+    names = config.get("modules", {})
+    if set(names) != {"reference", "count"}:
+        raise harness.Refused('the configuration file needs "modules": {"reference": ..., "count": ...}')
+    return tuple(
+        harness.load_module(os.path.join(harness.BENCH_DIR, names[kind] + ".py"))
+        for kind in ("reference", "count")
+    )
 
 
 def build_config(config: dict, traffic: dict):
@@ -98,7 +111,7 @@ def make_batch(built, key):
     return jax.jit(gen, out_shardings=built.batch_shardings)(key)
 
 
-def make_system_check(built):
+def make_system_check(built, reference):
     """``f(params, sample) -> (loss, zimg, ztxt, grads)``: the model's forward as
     the step calls it (the cell's configuration, attention path, dtype and mesh)
     and the program's sharded loss, differentiated through."""
@@ -127,7 +140,7 @@ def make_system_check(built):
     return check
 
 
-def check_against_reference(built, mix, state, batch) -> dict:
+def check_against_reference(built, mix, state, batch, reference) -> dict:
     """Checks (1) and (3) of ``correct``, and the reference's loss on the whole
     batch for (2). Runs before the first step, which donates the state."""
     import jax
@@ -138,7 +151,7 @@ def check_against_reference(built, mix, state, batch) -> dict:
         lambda b: jax.tree.map(lambda x: x[:n], b), out_shardings=built.batch_shardings
     )(batch)
     sys_loss, sys_zimg, sys_ztxt, sys_grads = jax.device_get(
-        make_system_check(built)(state.params, sample)
+        make_system_check(built, reference)(state.params, sample)
     )
 
     # The reference runs on one device, on plain unsharded arrays: a replicated
@@ -182,6 +195,7 @@ def run(ctx) -> dict:
     from distributed_sigmoid_loss_tpu.train import create_train_state
 
     cell, mix, phases = ctx.cell, ctx.cell.traffic, ctx.phases
+    reference, count = load_modules(cell.config)
     built = build_step(cell, ctx.devices)
     counter = harness.CompileCounter()
     phases.done("import")
@@ -198,7 +212,7 @@ def run(ctx) -> dict:
     phases.done("compile_or_load")
     memory = compiled.memory_analysis()
 
-    checks = check_against_reference(built, mix, state, batch)
+    checks = check_against_reference(built, mix, state, batch, reference)
     phases.done("reference")
 
     # Warm-up: three steps, each synced; the first is the correctness step.
@@ -260,9 +274,18 @@ def run(ctx) -> dict:
         "loss_err": reference.LOSS_BOUND, "sample_loss_err": reference.LOSS_BOUND,
     }
     verdicts = {name: bool(checks[name] <= bound) for name, bound in bounds.items()}
-    verdicts["losses_finite"] = failed == 0 and all(map(math.isfinite, warm_losses))
+    nonfinite = failed + sum(not math.isfinite(x) for x in warm_losses)
+    verdicts["losses_finite"] = nonfinite == 0
     verdicts["no_recompile"] = recompiles == 0
     harness.info_line("correct", verdicts=verdicts, bounds=bounds, **checks)
+    # Each number compared beside its limit: the result line's last key, and the
+    # last lines of standard error.
+    # (a number that is not finite goes as its name: the line stays JSON)
+    compared = {
+        name: [float(checks[name]) if math.isfinite(checks[name]) else repr(float(checks[name])), bound]
+        for name, bound in bounds.items()
+    }
+    compared.update(nonfinite_losses=[nonfinite, 0], recompiles=[recompiles, 0])
 
     steps_done = marks[-1][0]
     pairs_per_s_per_chip = built.global_pairs * steps_done / elapsed / cell.chips
@@ -289,7 +312,7 @@ def run(ctx) -> dict:
     if ctx.peaks is not None:
         end_to_end["pairs_per_s_per_chip"] = pairs_per_s_per_chip
         end_to_end["mfu_pct"] = (
-            100.0 * flops.train_flops_per_pair(built.cfg) * pairs_per_s_per_chip
+            100.0 * count.train_flops_per_pair(built.cfg) * pairs_per_s_per_chip
             / (ctx.peaks["bf16_tflops"] * 1e12)
         )
     return {
@@ -304,6 +327,8 @@ def run(ctx) -> dict:
             "memory_analysis": memory,
             "pairs_per_chip_per_step": mix["pairs_per_chip_per_step"],
             "cfg": built.cfg,
+            "count": count,
         },
         "xplane": tracer.xplane,
+        "compared": compared,
     }

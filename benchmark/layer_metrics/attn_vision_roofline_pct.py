@@ -1,4 +1,4 @@
-"""attn_roofline_pct for the vision tower alone: the least time for its block attention in a step (12 s^2 w operations, 24 s w bytes per layer per sequence, forward + backward, recomputation not counted; memory-bound at s = 196 and 256) over the time of its two kernels. benchmark/scopes.py."""
+"""The least time the chip could take for the softmax attention the image tower runs through the fused kernels in a step, as the configuration's count module has it (`attention_least_s`: per layer application the larger of 12 s^2 w operations over the bf16 peak and 24 s w bytes over the HBM peak, forward + backward, recomputation not counted, every application the tower does; memory-bound at s = 196 and 256), over the time of its `short_attn_fwd` / `short_attn_bwd` kernels. Nothing where the tower has no such kernel or the count says it runs no such layer. benchmark/scopes.py."""
 
 META = {
     "name": "attn_vision_roofline_pct", "unit": "%", "better": "higher", "source": "device_trace",

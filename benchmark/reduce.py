@@ -370,12 +370,16 @@ def reduce_xplane(path: str, n_devices: int) -> dict:
     active = {n: r for n, r in per_device.items() if r["busy_s"] > 0}
     if len(active) < n_devices:
         raise RuntimeError("a device of the cell ran no operation in the traced window")
-    slowest = max(active.values(), key=lambda r: statistics.median(r["step_ms"] or [0.0]))
+    slowest_name = max(active, key=lambda n: statistics.median(active[n]["step_ms"] or [0.0]))
+    slowest = active[slowest_name]
     return {
         "busy_s": statistics.fmean(r["busy_s"] for r in active.values()),
         "window_s": slowest["window_s"],
         "device": slowest,
         "per_device": per_device,
+        # The reported device's parsed events (``ops``, ``modules``), for the readers that
+        # cut them by the program's own names (scopes.py): the file is read once.
+        "plane": raw["devices"][slowest_name],
         "breakdown": {"device_ops": slowest["top_ops"], "idle_gaps": slowest["idle_gaps"]},
     }
 
@@ -412,6 +416,7 @@ if __name__ == "__main__":
     elif len(sys.argv) == 3 and sys.argv[1] == "--reduce":
         out = reduce_xplane(sys.argv[2], 1)
         out.pop("per_device")
+        out.pop("plane")
         print(json.dumps(out, indent=1))
     else:
         raise SystemExit(__doc__)

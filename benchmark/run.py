@@ -4,11 +4,13 @@
     python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 One process, one cell, the cell's chips. The last line of standard output is one
-JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and,
-traced, ``breakdown``. With ``--trace 0`` the metrics are the cell's end-to-end
-metrics, with ``--trace 1`` its per-layer metrics. ``--rehearse`` runs the same
-control flow on the CPU at the tiny cells under ``benchmark/tests/rehearsal`` and
-prints no metric: no time, rate or utilization comes from a CPU.
+JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``,
+traced ``breakdown``, and last ``compared`` (each number ``correct`` compared
+beside its limit, which are also the last lines of standard error). With
+``--trace 0`` the metrics are the cell's end-to-end metrics, with ``--trace 1``
+its per-layer metrics. ``--rehearse`` runs the same control flow on the CPU at
+the tiny cells under ``benchmark/tests/rehearsal`` and prints no metric: no
+time, rate or utilization comes from a CPU.
 """
 
 from __future__ import annotations
@@ -55,6 +57,7 @@ def main(argv=None) -> int:
     elif args.trace:
         import reduce
 
+        # The file is parsed once: the readers find the reported device's events in the reduction.
         trace = reduce.reduce_xplane(out["xplane"], n_devices=cell.chips)
         result["metrics"] = harness.read_layer_metrics(cell, {
             "trace": trace, "counters": out["counters"], "peaks": peaks,
@@ -69,6 +72,7 @@ def main(argv=None) -> int:
             for m in cell.metrics("end_to_end") if m["name"] in out["end_to_end"]
         }
     result["device"] = device
+    result["compared"] = out["compared"]
     harness.result_line(result)
     return 0
 

@@ -1,8 +1,7 @@
 """Device time by the program's own names: what the readers of
 ``update_and_metrics_ms``, ``accum_ms``, ``loss_island_ms``, ``tower_elementwise_ms``,
 ``attn_{vision,text}_{fwd,bwd}_ms``, ``attn_{vision,text}_roofline_pct`` and
-``unscoped_pct`` under ``layer_metrics/`` share (benchmark/README.md's file table
-dates from PR 22 and lacks this file).
+``unscoped_pct`` under ``layer_metrics/`` share.
 
 ``reduce.py`` cuts a traced step by XLA's categories (matmul, custom call,
 collective, other). The program now names its layers from inside: a
@@ -16,11 +15,11 @@ went through: ``jit(step)/transpose(jvp(loss_island))/shard_map/...``,
 A path belongs to a name when one of its components, wrappers peeled, equals it;
 the outermost such component decides.
 
-This module finds the traced run's ``.xplane.pb`` the way ``TraceWindow.stop``
-does (the newest under ``harness.TRACE_DIR/<cell>``), reads it once per process,
-takes the plane of the device the reduction reported, and windows and self-times
-its operations with ``reduce``'s own functions, so its parts add up to the
-reduction's busy time. Every operation lands in exactly one part:
+This module takes the events of the device the reduction reported from the
+reduction itself (``ctx["trace"]["plane"]``: run.py parses the file once), and
+windows and self-times its operations with ``reduce``'s own functions, once per
+traced run, so its parts add up to the reduction's busy time. Every operation
+lands in exactly one part:
 
 - ``optimizer``, ``loss_island``, ``accum``, ``step_metrics``: under that scope,
   whatever its category (the loss island's matmul, on four chips its permutes);
@@ -33,23 +32,22 @@ edge is soft by a fusion or two; ``unscoped_pct`` and the closure test
 (tests/test_scopes.py) keep that honest. Between ``optimizer`` and
 ``step_metrics`` the edge is gone: each leaf's AdamW update and the norms that
 read its result are one fusion, named after its reduction, so the two parts are
-read as one metric (``update_and_metrics_ms``). Where the trace has no such file, or no
-operation under a name (the parent of the PR that added the name, PR 22's
-fixtures), a reader gets ``None`` and the metric is left out.
+read as one metric (``update_and_metrics_ms``). Where no operation of the trace
+carries a name (the parent of the PR that added the name, PR 22's fixtures), a
+reader gets ``None`` and the metric is left out.
+
+The attention rooflines divide a count by a kernel time. The count is the
+configuration's: ``ctx["counters"]["count"]`` is the count module its file names
+(``"modules"``), and ``attention_least_s`` there says what softmax attention
+each tower runs through the fused kernels. A tower with none reads nothing.
 """
 
 from __future__ import annotations
 
 import collections
-import functools
-import os
 import re
-import sys
-import time
 
 # benchmark/ is on sys.path: run.py and the tools under tests/ put it there.
-import flops
-import harness
 import reduce
 
 SCOPES = ("optimizer", "loss_island", "accum", "step_metrics")
@@ -88,13 +86,6 @@ def kernel_of(path: str) -> tuple[str, str] | None:
     return (tower, kernel) if kernel and tower else None
 
 
-def newest_xplane(cell_name: str) -> str | None:
-    found = []
-    for base, _, files in os.walk(os.path.join(harness.TRACE_DIR, cell_name)):
-        found += [os.path.join(base, f) for f in files if f.endswith(".xplane.pb")]
-    return max(found, key=os.path.getmtime) if found else None
-
-
 def split_events(ops: list, modules: list) -> dict:
     """One device's operations, windowed to the traced steps as
     ``reduce.reduce_events`` windows them, to self seconds by part, by part
@@ -120,25 +111,16 @@ def split_events(ops: list, modules: list) -> dict:
     }
 
 
-@functools.lru_cache(maxsize=1)
-def _split_file(path: str, device: str) -> dict:
-    start = time.perf_counter()
-    plane = reduce.read_xplane(path)["devices"][device]
-    out = split_events(plane["ops"], plane["modules"])
-    print(f"benchmark: scopes: read {os.path.relpath(path, harness.CHECKOUT)} a second time "
-          f"in {time.perf_counter() - start:.1f} s", file=sys.stderr)
-    return out
+def split_once(trace: dict, key: str, split_events) -> dict:
+    """``split_events`` of the reported device's events, computed once per traced
+    run and kept with the reduction under ``key``."""
+    if key not in trace:
+        trace[key] = split_events(trace["plane"]["ops"], trace["plane"]["modules"])
+    return trace[key]
 
 
-def split(ctx) -> dict | None:
-    """``split_events`` of the traced run's file for the device the reduction
-    reported (``ctx["trace"]["device"]``); None where there is no such file."""
-    path = newest_xplane(ctx["cell"].name)
-    if path is None:
-        return None
-    trace = ctx["trace"]
-    device = next(n for n, r in trace["per_device"].items() if r is trace["device"])
-    return _split_file(path, device)
+def split(ctx) -> dict:
+    return split_once(ctx["trace"], "scopes", split_events)
 
 
 # -- what the readers call ------------------------------------------------------
@@ -146,9 +128,9 @@ def split(ctx) -> dict | None:
 
 def _per_step_ms(ctx, seconds_of) -> float | None:
     """``seconds_of(split(ctx))`` per traced step, in ms. None where there is no
-    traced file, or no time at all: no operation carried the name."""
+    time at all: no operation carried the name."""
     s = split(ctx)
-    seconds = seconds_of(s) if s and s["steps"] else 0.0
+    seconds = seconds_of(s) if s["steps"] else 0.0
     return 1e3 * seconds / s["steps"] if seconds else None
 
 
@@ -171,23 +153,20 @@ def kernel_ms(ctx, tower: str, kernel: str | None = None) -> float | None:
 
 
 def tower_roofline_pct(ctx, tower: str) -> float | None:
-    """``attn_roofline_pct``'s arithmetic for one tower: the least time the chip
-    could take for that tower's block attention in a step (forward + backward,
-    recomputation not counted: the larger of 12 s^2 w operations per layer per
-    sequence over the bf16 peak and 24 s w bytes over the HBM peak) over the
-    time of that tower's attention kernels."""
+    """The least time the chip could take for the softmax attention ``tower``
+    runs through the fused kernels in a step, as the configuration's count module
+    has it (``attention_least_s``: forward + backward, recomputation not counted,
+    every layer application the tower really does), over the time of that tower's
+    attention kernels. None where the tower has no kernel time or the count says
+    it runs no such layer."""
     kernels_ms = kernel_ms(ctx, tower)
     if not kernels_ms:
         return None
-    counters, peaks = ctx["counters"], ctx["peaks"]
-    cfg = counters["cfg"].vision if tower == "visual" else counters["cfg"].text
-    seq = flops.vision_tokens(cfg) if tower == "visual" else cfg.context_length
-    sequences = counters["pairs_per_chip_per_step"] * cfg.depth
-    least_s = max(
-        12.0 * seq * seq * cfg.width * sequences / (peaks["bf16_tflops"] * 1e12),
-        24.0 * seq * cfg.width * sequences / (peaks["hbm_gb_per_s"] * 1e9),
+    counters = ctx["counters"]
+    least_s = counters["count"].attention_least_s(
+        counters["cfg"], tower, counters["pairs_per_chip_per_step"], ctx["peaks"]
     )
-    return 100.0 * 1e3 * least_s / kernels_ms
+    return 100.0 * 1e3 * least_s / kernels_ms if least_s else None
 
 
 def unscoped_pct(ctx) -> float | None:
@@ -195,6 +174,6 @@ def unscoped_pct(ctx) -> float | None:
     the program named nothing (no operation under any of its four scopes): the
     share then says nothing about the names."""
     s = split(ctx)
-    if not s or not any(s["part_s"].get(name) for name in SCOPES):
+    if not any(s["part_s"].get(name) for name in SCOPES):
         return None
     return 100.0 * s["part_s"].get("unscoped", 0.0) / sum(s["part_s"].values())

@@ -8,6 +8,7 @@ import flops
 import pytest
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PEAKS = json.load(open(os.path.join(BENCH_DIR, "peaks.json")))["devices"]["TPU v5 lite"]
 
 
 def config(name):
@@ -30,17 +31,16 @@ SO_VISION = 27 * 8097103872 + 346816512 + 1358954496
 SO_TEXT = 27 * 1967652864 + 2654208
 
 
-@pytest.mark.parametrize("name, vision, text, attention", [
-    ("siglip-b16-224", B16_VISION, B16_TEXT, 12 * 12 * 768 * (196**2 + 64**2)),
-    ("siglip-so400m-14-224", SO_VISION, SO_TEXT, 12 * 27 * 1152 * (256**2 + 64**2)),
+@pytest.mark.parametrize("name, vision, text", [
+    ("siglip-b16-224", B16_VISION, B16_TEXT),
+    ("siglip-so400m-14-224", SO_VISION, SO_TEXT),
 ])
-def test_flops_match_hand_computed(name, vision, text, attention):
+def test_flops_match_hand_computed(name, vision, text):
     cfg = config(name)
     assert flops.vision_forward_flops(cfg.vision) == vision
     assert flops.text_forward_flops(cfg.text) == text
     assert flops.forward_flops_per_pair(cfg) == vision + text
     assert flops.train_flops_per_pair(cfg) == 3 * (vision + text)
-    assert flops.attention_flops_per_pair(cfg) == attention
 
 
 def test_totals_are_the_ones_perf_md_quotes():
@@ -53,13 +53,24 @@ def test_fractional_mlp_ratio_rounds_to_the_published_hidden_size():
     assert flops.mlp_hidden(768, 4) == 3072
 
 
-def test_attention_is_memory_bound_on_the_v5e():
-    """The roofline share divides by the larger of the two bounds; say which:
-    12 s^2 w operations over 24 s w bytes is s/2 per byte, 98 (image) and 32
-    (text) against the chip's 197e12 / 819e9 = 240."""
-    peaks = json.load(open(os.path.join(BENCH_DIR, "peaks.json")))["devices"]["TPU v5 lite"]
-    for name in ("siglip-b16-224", "siglip-so400m-14-224"):
-        cfg = config(name)
-        compute_s = flops.attention_flops_per_pair(cfg) / (peaks["bf16_tflops"] * 1e12)
-        memory_s = flops.attention_bytes_per_pair(cfg) / (peaks["hbm_gb_per_s"] * 1e9)
-        assert memory_s > compute_s
+@pytest.mark.parametrize("name, tower, s, w, depth", [
+    ("siglip-b16-224", "visual", 196, 768, 12), ("siglip-b16-224", "textual", 64, 768, 12),
+    ("siglip-so400m-14-224", "visual", 256, 1152, 27), ("siglip-so400m-14-224", "textual", 64, 1152, 27),
+])
+def test_attention_least_time_by_hand_and_memory_bound_on_the_v5e(name, tower, s, w, depth):
+    """What the per-tower rooflines divide: per layer per sequence 12 s^2 w
+    operations (scores and values, forward + backward) against 24 s w bytes, the
+    larger of the two times; s/2 operations a byte, 98 (image) and 32 (text),
+    against the chip's 197e12 / 819e9 = 240, so the bytes decide. 256 pairs."""
+    least = flops.attention_least_s(config(name), tower, 256, PEAKS)
+    by_bytes = 24.0 * s * w * depth * 256 / 819e9
+    by_operations = 12.0 * s * s * w * depth * 256 / 197e12
+    assert least == pytest.approx(by_bytes, rel=1e-12) and by_bytes > by_operations
+    assert flops.attention_least_s(config(name), tower, 512, PEAKS) == pytest.approx(2 * least, rel=1e-12)
+
+
+def test_attention_least_time_turns_compute_bound_past_480_tokens():
+    """The other branch of the larger-of: at s = 1024 the operations decide."""
+    cfg = config("siglip-b16-224")
+    cfg.text.context_length = 1024
+    assert flops.attention_least_s(cfg, "textual", 1, PEAKS) == pytest.approx(12.0 * 1024**2 * 768 * 12 / 197e12)

@@ -66,6 +66,33 @@ def test_end_to_end_metrics():
         assert m["source"] in ("host_clock", "device_trace")
 
 
+# What jobs/train_step.py and the readers ask of the two files a configuration
+# names under "modules" (benchmark/README.md, "What a configuration brings").
+REFERENCE_INTERFACE = (
+    "first_blocks", "microbatch_rows", "make_batch_loss", "make_sample_grads", "tree_max_rel_err", "max_rel_err",
+    "EMBED_BOUND", "LOSS_BOUND", "GRAD_BOUND",
+)
+COUNT_INTERFACE = ("train_flops_per_pair", "attention_least_s")
+
+
+def assert_modules_offer_the_interface(config_body):
+    """conftest.py has put benchmark/ on sys.path: the modules import each other by name."""
+    assert set(config_body["modules"]) == {"reference", "count"}
+    for kind, interface in (("reference", REFERENCE_INTERFACE), ("count", COUNT_INTERFACE)):
+        path = os.path.join(BENCH_DIR, config_body["modules"][kind] + ".py")
+        assert os.path.isfile(path), path
+        module = load(path)
+        assert not [name for name in interface if not hasattr(module, name)], path
+        assert all(callable(getattr(module, name)) for name in interface if name.islower())
+        assert all(0 < getattr(module, name) < 1 for name in interface if name.isupper())
+
+
+def test_the_rehearsal_configurations_name_their_modules_too():
+    rehearsal = os.path.join(BENCH_DIR, "tests", "rehearsal")
+    for config in json.load(open(os.path.join(rehearsal, "BENCHMARK.json")))["configs"]:
+        assert_modules_offer_the_interface(json.load(open(os.path.join(rehearsal, config["file"]))))
+
+
 @pytest.mark.parametrize("cell", MANIFEST["workloads"], ids=lambda w: w["name"])
 def test_every_cell_has_its_files(cell):
     config = next(c for c in MANIFEST["configs"] if c["name"] == cell["config"])
@@ -78,6 +105,7 @@ def test_every_cell_has_its_files(cell):
     assert mix["chips"] == cell["chips"]
     assert mix["microbatch"] * mix["step"]["accum_steps"] == mix["pairs_per_chip_per_step"]
     assert os.path.isfile(os.path.join(BENCH_DIR, "jobs", mix["job"] + ".py"))
+    assert_modules_offer_the_interface(body)
     for kind in ("end_to_end", "per_layer"):
         assert any("workloads" not in m or cell["name"] in m["workloads"] for m in MANIFEST[kind])
 

@@ -150,6 +150,16 @@ def test_recorded_four_chip_trace():
         assert other["group_s"]["custom_call"] == pytest.approx(1.3882e-3, rel=2e-4)
 
 
+# What PR 22's fixture can feed: it was recorded before the program named its scopes
+# and kernels, and trimmed of its jax paths, so the readers of reduce.py's categories
+# and of the job's counters read it and scopes.py's stay silent (tests/test_scopes.py
+# has those, on the fixtures recorded with the names in).
+PR22_READERS = {
+    "recompiles", "trace_lower_s", "device_step_ms", "peak_hbm_gb", "matmul_share_pct", "matmul_tflops",
+    "custom_call_share_pct", "device_idle_pct", "hbm_live_peak_gb",
+}
+
+
 def test_every_reader_reads_the_recorded_trace():
     """The readers of layer_metrics/ over the recorded trace and counters like
     the job's: the path run.py takes with --trace 1, which no CPU rehearsal does."""
@@ -170,12 +180,13 @@ def test_every_reader_reads_the_recorded_trace():
     values = harness.read_layer_metrics(cell, {
         "trace": reduce.reduce_xplane(os.path.join(FIXTURES, "fixture-1chip.xplane.pb"), 1),
         "counters": {"recompiles": 0, "trace_lower_s": 7.37, "memory_analysis": memory,
-                     "pairs_per_chip_per_step": 32, "cfg": cfg},
+                     "pairs_per_chip_per_step": 32, "cfg": cfg,
+                     "count": harness.load_module(os.path.join(harness.BENCH_DIR, "flops.py"))},
         "peaks": harness.peaks_for("TPU v5 lite"), "devices": memory_watch.devices,
         "memory_peak_bytes": memory_watch.peak_bytes, "cell": cell,
     })
     got = {name: m["value"] for name, m in values.items()}
-    assert set(got) == {m["name"] for m in cell.metrics("per_layer")}  # no collectives: one chip
+    assert set(got) == PR22_READERS < {m["name"] for m in cell.metrics("per_layer")}  # no collectives: one chip
     assert got["device_step_ms"] == pytest.approx((11.20597625 + 11.20720875) / 2)
     assert got["matmul_share_pct"] == pytest.approx(100 * 0.009969768198 / 0.022111646306)
     assert got["matmul_tflops"] == pytest.approx(1702870844916 / 0.009969768198 / 1e12)
@@ -183,5 +194,4 @@ def test_every_reader_reads_the_recorded_trace():
     assert got["device_idle_pct"] == pytest.approx(100 * (1 - 0.022111646306 / 0.02241831625))
     assert got["peak_hbm_gb"] == pytest.approx(1.077646848)
     assert got["hbm_live_peak_gb"] == pytest.approx(1.171126272)  # in use + reserved > peak in use
-    assert 0 < got["attn_roofline_pct"] < 100
     assert got["recompiles"] == 0 and got["trace_lower_s"] == 7.37

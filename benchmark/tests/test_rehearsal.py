@@ -20,19 +20,26 @@ def run(*args, env_extra=None):
     )
 
 
-@pytest.mark.parametrize("cell, devices", [("tiny-mb8x2", 1), ("tiny-bs8-dp4", 4)])
+@pytest.mark.parametrize("cell, devices", [("tiny-mb8x2", 1), ("tiny-looped-mb8x2", 1), ("tiny-bs8-dp4", 4)])
 @pytest.mark.parametrize("trace", ["0", "1"])
 def test_rehearsal_prints_no_device_metric(cell, devices, trace):
     proc = run("--rehearse", "--workload", cell, "--seed", "3", "--seconds", "1", "--trace", trace)
     assert proc.returncode == 0, proc.stderr[-2000:]
     result = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
     assert result["metrics"] == {}
     assert result["device"]["platform"] == "cpu" and result["device"]["count"] == devices
     assert "busy_s" not in result["device"]
     earlier = [json.loads(line) for line in proc.stdout.splitlines()[:-1]]
     assert [info["info"] for info in earlier] == ["correct"]  # no time, rate or size from a CPU
+    # Each number `correct` compared beside its limit: the line's last key and stderr's last lines.
+    compared = result["compared"]
+    assert set(compared) == {"embed_err", "grad_err", "loss_err", "sample_loss_err", "nonfinite_losses", "recompiles"}
+    assert all(value <= limit for value, limit in compared.values())
+    assert compared["grad_err"][1] == (0.125 if "looped" in cell else 0.06)  # the configuration's own reference's
+    last = proc.stderr.strip().splitlines()[-len(compared):]
+    assert [line.split()[2] for line in last] == list(compared) and all("limit" in line for line in last)
 
 
 def test_measurement_refuses_without_a_listed_tpu():

@@ -1,7 +1,8 @@
 """scopes.py: path-to-part matching on hand-written paths, the parts of a synthetic
 trace adding up to its busy time, every new reader silent on PR 22's scope-less
-fixtures, and every reader of a cell reading fixtures recorded on the TPU v5e
-with the program's scopes and kernel names in (PR 23):
+fixtures, every reader of a cell reading fixtures recorded on the TPU v5e
+with the program's scopes and kernel names in (PR 23), and the attention
+rooflines reading the configuration's own count (PR 31):
 
     chiprun --chips 1 -- python benchmark/tests/record_fixture.py fixture-1chip
     chiprun --chips 4 -- python benchmark/tests/record_fixture.py fixture-4chip
@@ -15,7 +16,6 @@ keeps it and changes nothing else.
 
 import json
 import os
-import shutil
 import types
 
 import harness
@@ -118,43 +118,47 @@ def test_parts_of_a_synthetic_trace_add_up_to_its_busy_time():
 # -- through the readers, on recorded traces --------------------------------------
 
 
-def read_cell(monkeypatch, tmp_path, cell_name, fixture, n_devices, pairs):
-    """The per-layer metrics of ``cell_name`` as run.py reads them with --trace 1,
-    with ``fixture`` standing where the traced run writes its file."""
-    monkeypatch.setattr(harness, "TRACE_DIR", str(tmp_path))
-    scopes._split_file.cache_clear()
-    if fixture:
-        os.makedirs(tmp_path / cell_name / "plugins" / "profile")
-        shutil.copy(os.path.join(FIXTURES, fixture), tmp_path / cell_name / "plugins" / "profile")
+def count_module(name):
+    """A count module by file, as jobs/train_step.py loads the configuration's:
+    benchmark/<name>.py, or a synthetic one under tests/counts/."""
+    base = harness.BENCH_DIR if os.sep not in name else os.path.dirname(os.path.abspath(__file__))
+    return harness.load_module(os.path.join(base, name + ".py"))
+
+
+def read_cell(cell_name, fixture, n_devices, pairs, count="flops", **text):
+    """The per-layer metrics of ``cell_name`` as run.py reads them with --trace 1:
+    the reduction of ``fixture`` and counters like the job's; ``text`` overrides
+    fields of the fixture configuration's text tower."""
     cell = harness.Cell(cell_name, rehearse=False)
     widths = json.load(open(os.path.join(harness.REHEARSAL_DIR, "configs", "b16-depth2.json")))
     cfg = types.SimpleNamespace(
-        vision=types.SimpleNamespace(**widths["vision"]), text=types.SimpleNamespace(**widths["text"]))
-    trace = reduce.reduce_xplane(os.path.join(FIXTURES, fixture or "fixture-1chip.xplane.pb"), n_devices)
+        vision=types.SimpleNamespace(**widths["vision"]),
+        text=types.SimpleNamespace(**{**widths["text"], **text}))
+    trace = reduce.reduce_xplane(os.path.join(FIXTURES, fixture), n_devices)
     values = harness.read_layer_metrics(cell, {
         "trace": trace,
         "counters": {"recompiles": 0, "trace_lower_s": 7.37, "pairs_per_chip_per_step": pairs, "cfg": cfg,
+                     "count": count_module(count),
                      "memory_analysis": types.SimpleNamespace(peak_memory_in_bytes=1077646848)},
         "peaks": harness.peaks_for("TPU v5 lite"), "devices": [], "memory_peak_bytes": 1171126272, "cell": cell,
     })
     return cell, trace, {name: m["value"] for name, m in values.items()}
 
 
-@pytest.mark.parametrize("fixture", [None, "fixture-1chip.xplane.pb"])
-def test_new_readers_are_silent_without_the_names(monkeypatch, tmp_path, fixture):
-    """No traced file at all, and PR 22's fixture, recorded before the program
-    named anything and trimmed of its jax paths: nothing to read, nothing raised,
-    and PR 22's readers read what they read before."""
-    cell, _, got = read_cell(monkeypatch, tmp_path, "b16-mb128x8", fixture, 1, 32)
+def test_new_readers_are_silent_without_the_names():
+    """PR 22's fixture, recorded before the program named anything and trimmed of
+    its jax paths: nothing to read, nothing raised, and PR 22's readers read what
+    they read before."""
+    cell, _, got = read_cell("b16-mb128x8", "fixture-1chip.xplane.pb", 1, 32)
     assert not NEW_METRICS & set(got)
     assert set(got) == {m["name"] for m in cell.metrics("per_layer")} - NEW_METRICS
 
 
-def test_every_reader_reads_the_scoped_one_chip_trace(monkeypatch, tmp_path):
+def test_every_reader_reads_the_scoped_one_chip_trace():
     """PR 22's test_every_reader_reads_the_recorded_trace over the full list, on
     the fixture cell recorded with the scopes in (2 x 16 pairs accumulated, B/16
     widths, two blocks, one chip)."""
-    cell, trace, got = read_cell(monkeypatch, tmp_path, "b16-mb128x8", "fixture-1chip-scoped.xplane.pb", 1, 32)
+    cell, trace, got = read_cell("b16-mb128x8", "fixture-1chip-scoped.xplane.pb", 1, 32)
     assert set(got) == {m["name"] for m in cell.metrics("per_layer")} >= NEW_METRICS
     d = trace["device"]
     busy_ms = 1e3 * trace["busy_s"] / d["steps"]
@@ -175,19 +179,18 @@ def test_every_reader_reads_the_scoped_one_chip_trace(monkeypatch, tmp_path):
     assert metrics_ms > update_ms > got["loss_island_ms"]
     assert got["update_and_metrics_ms"] == pytest.approx(update_ms + metrics_ms, rel=1e-12)
     assert 0 < got["attn_text_roofline_pct"] < got["attn_vision_roofline_pct"] < 100
-    # The aggregate roofline is the two towers' least times over the two towers' kernel times.
-    vision_ms = got["attn_vision_fwd_ms"] + got["attn_vision_bwd_ms"]
-    text_ms = got["attn_text_fwd_ms"] + got["attn_text_bwd_ms"]
-    least_ms = got["attn_vision_roofline_pct"] / 100 * vision_ms + got["attn_text_roofline_pct"] / 100 * text_ms
-    assert 100 * least_ms / kernels_ms == pytest.approx(got["attn_roofline_pct"], rel=1e-3)
+    # Each roofline is its tower's least time (flops.py: 24 s w bytes a layer a sequence at 819 GB/s) over its kernels'.
+    for tower, s_w in (("vision", 196 * 768), ("text", 64 * 768)):
+        tower_ms = got[f"attn_{tower}_fwd_ms"] + got[f"attn_{tower}_bwd_ms"]
+        assert got[f"attn_{tower}_roofline_pct"] == pytest.approx(100 * 1e3 * 24.0 * s_w * 2 * 32 / 819e9 / tower_ms)
     # copy-done / async-done carry no path; at this toy size (an 11 ms step) they weigh 10 %, at a cell's 2 %.
     assert got["unscoped_pct"] == pytest.approx(9.9, abs=0.2)
 
 
-def test_every_reader_reads_the_scoped_four_chip_trace(monkeypatch, tmp_path):
+def test_every_reader_reads_the_scoped_four_chip_trace():
     """The dp=4 fixture: the kernels sit in a shard_map, the ring's permutes belong
     to the loss island, the gradient all-reduce to ``collective``."""
-    cell, trace, got = read_cell(monkeypatch, tmp_path, "b16-bs256-dp4", "fixture-4chip-scoped.xplane.pb", 4, 32)
+    cell, trace, got = read_cell("b16-bs256-dp4", "fixture-4chip-scoped.xplane.pb", 4, 32)
     assert set(got) == {m["name"] for m in cell.metrics("per_layer")} >= NEW_METRICS - {"accum_ms"}
     d = trace["device"]
     s = scopes.split({"cell": cell, "trace": trace})
@@ -200,3 +203,62 @@ def test_every_reader_reads_the_scoped_four_chip_trace(monkeypatch, tmp_path):
     kernels_ms = sum(got[f"attn_{tower}_{way}_ms"] for tower in ("vision", "text") for way in ("fwd", "bwd"))
     assert kernels_ms == pytest.approx(1e3 * d["group_s"]["custom_call"] / d["steps"], rel=1e-3)
     assert got["unscoped_pct"] < 15
+
+
+# -- the attention rooflines read the configuration's count (PR 31) -----------------
+
+SCOPED = [("b16-mb128x8", "fixture-1chip-scoped.xplane.pb", 1), ("b16-bs256-dp4", "fixture-4chip-scoped.xplane.pb", 4)]
+COUNTS = ["flops", "flops_looped", "counts/no_text_attention", "counts/one_text_layer_in_five"]
+
+
+def test_a_text_tower_without_attention_layers_reads_no_roofline():
+    """The wire ISSUE 31 found: a text tower that runs no softmax attention through
+    the fused kernels was counted as `depth` attention layers over whatever custom
+    calls the step had. Its count says 0, the reader says nothing, and the image
+    tower's reading and the kernel times stand."""
+    _, trace, base = read_cell("b16-mb128x8", SCOPED[0][1], 1, 32, depth=5)
+    cell, _, got = read_cell("b16-mb128x8", SCOPED[0][1], 1, 32, "counts/no_text_attention", depth=5)
+    assert "attn_text_roofline_pct" in base and "attn_text_roofline_pct" not in got
+    assert set(got) == set(base) - {"attn_text_roofline_pct"}
+    assert all(got[name] == base[name] for name in got)
+    ctx = {"trace": trace, "peaks": harness.peaks_for("TPU v5 lite"), "cell": cell, "counters": {
+        "count": count_module("counts/no_text_attention"), "cfg": None, "pairs_per_chip_per_step": 32}}
+    assert scopes.tower_roofline_pct(ctx, "textual") is None and scopes.kernel_ms(ctx, "textual") > 0
+
+
+def test_one_attention_layer_in_five_reads_a_fifth_of_all_layers():
+    """A hybrid stack counts the applications it does: five layers of which one is
+    attention read a fifth of what five attention layers read over the same kernel
+    time; the image tower does not move."""
+    _, _, every = read_cell("b16-mb128x8", SCOPED[0][1], 1, 32, "flops", depth=5)
+    _, _, fifth = read_cell("b16-mb128x8", SCOPED[0][1], 1, 32, "counts/one_text_layer_in_five", depth=5)
+    assert fifth["attn_text_roofline_pct"] == pytest.approx(every["attn_text_roofline_pct"] / 5, rel=1e-12)
+    assert fifth["attn_vision_roofline_pct"] == every["attn_vision_roofline_pct"]
+
+
+def test_the_looped_count_reads_loops_times_the_unlooped_one():
+    """`ouro-b16-mb32x2`'s readers with the looped count: the text tower's roofline
+    is `loops` x what flops.py reads (what `looped_attn_roofline_pct` read beside
+    `attn_text_roofline_pct` before PR 31), and nothing else moves."""
+    got = {}
+    for count, loops in (("flops", 1), ("flops_looped", 1), ("flops_looped", 4)):
+        cell, _, got[count, loops] = read_cell("ouro-b16-mb32x2", SCOPED[0][1], 1, 32, count, loops=loops)
+    assert set(got["flops", 1]) == {m["name"] for m in cell.metrics("per_layer")} - {"text_loops_ms", "rope_ms"}
+    assert got["flops_looped", 1] == got["flops", 1]
+    assert got["flops_looped", 4].pop("attn_text_roofline_pct") == 4 * got["flops", 1].pop("attn_text_roofline_pct")
+    assert got["flops_looped", 4] == got["flops", 1]
+
+
+@pytest.mark.parametrize("count", COUNTS)
+@pytest.mark.parametrize("cell_name, fixture, n_devices", SCOPED, ids=["one-chip", "four-chips"])
+def test_no_share_of_a_roofline_or_a_peak_leaves_0_100(cell_name, fixture, n_devices, count):
+    """Every reader whose name holds `roofline` or `mfu`, on both scoped fixtures,
+    under every count module there is: nothing, or a share in (0, 100]. Never 0
+    for a tower without the work, never the 110 % to 596 % a miscount gives."""
+    cell, _, got = read_cell(cell_name, fixture, n_devices, 32, count, depth=5)
+    shares = [m["name"] for m in cell.metrics("per_layer") if "roofline" in m["name"] or "mfu" in m["name"]]
+    assert sorted(shares) == ["attn_text_roofline_pct", "attn_vision_roofline_pct"]
+    for name in shares:
+        assert name not in got or 0 < got[name] <= 100, (name, got[name])
+    assert "attn_vision_roofline_pct" in got
+    assert ("attn_text_roofline_pct" in got) == (count != "counts/no_text_attention")
