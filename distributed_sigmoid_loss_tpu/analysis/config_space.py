@@ -51,6 +51,8 @@ __all__ = [
     "probe_imperative",
     "tier1_sample",
     "violations",
+    "TOWER_EXCLUSIONS",
+    "tower_exclusion_drift",
 ]
 
 # The rule this module emits (catalog constant, mirrored in
@@ -438,10 +440,13 @@ def probe_imperative(cfg: StepConfig) -> tuple[bool, str]:
     state-content checks (validate_pp_tower, state.ema presence) are
     environmental, not config-space, and are out of probe scope: the text
     tower's block options (utils.config.BLOCK_OPTIONS: norm, sandwich_norm,
-    mlp, use_bias, pos, loops) are no axis of this lattice, every step
+    mlp, use_bias, pos, loops, norm_eps, mixers, leading_dense_layers and the
+    moe_router group) are no axis of this lattice, every step
     builder takes them as it takes any tower, and the one axis whose builder
     re-implements the block (``pp``) refuses each by name in
-    validate_pp_tower.
+    validate_pp_tower. What a block option excludes beside ``pp`` is stated
+    once in :data:`TOWER_EXCLUSIONS` and probed by
+    :func:`tower_exclusion_drift`.
     """
     import argparse
 
@@ -589,3 +594,83 @@ def config_space_drift_findings(
                 )
             )
     return findings
+
+
+# -- what a text tower's block options exclude ----------------------------------
+#
+# The block options are tower shape, not step lattice (see probe_imperative).
+# The ones that bring another kind of layer exclude other tower settings; each
+# line is (the option as a configuration sets it, the setting it excludes, the
+# name the refusal gives that setting, where the refusal lives, why).
+# tower_exclusion_drift builds each pair and checks the refusal.
+_MIXED = {"mixers": ("kda", "mla")}
+_DROPLESS = {"moe_router": "sigmoid", "moe_experts": 4}
+_UNLIKE_LAYERS = "models/transformer.py::Encoder._check_unlike_layers"
+TOWER_EXCLUSIONS: tuple = (
+    (_MIXED, {"sequence_parallel_axis": "sp"}, "sequence_parallel_axis=", _UNLIKE_LAYERS,
+     "a recurrence carried across sequence shards, and latent attention over them, are not built"),
+    (_MIXED, {"quant_train": "int8"}, "quant=", _UNLIKE_LAYERS,
+     "the mixers' projections and cores have no int8 path"),
+    (_MIXED, {"pos": "learned"}, "pos=", _UNLIKE_LAYERS,
+     "the recurrence and the causal masks carry the order: no position table, no rotation"),
+    (_MIXED, {"causal": False}, "causal=", _UNLIKE_LAYERS, "a recurrence has a direction"),
+    (_MIXED, {"loops": 2}, "loops=", _UNLIKE_LAYERS, "a looped mixed stack is not built"),
+    (_DROPLESS, {"quant_train": "int8"}, "quant=", "models/transformer.py::Block",
+     "the dropless experts have no int8 path"),
+    (_DROPLESS, {"mlp": "gelu"}, "mlp=", "models/transformer.py::Block",
+     "the sigmoid-routed experts are bias-free SwiGLU"),
+)
+# The options that change the block refuse the pipeline by their own name.
+PP_REFUSES: tuple = (
+    "mixers", "leading_dense_layers", "norm_eps", "moe_router", "moe_route_scale",
+    "moe_shared_experts", "moe_hidden", "moe_experts_held",
+)
+
+
+def tower_exclusion_drift() -> list[str]:
+    """Probe :data:`TOWER_EXCLUSIONS` and :data:`PP_REFUSES` through the real
+    modules (shapes only): each pair must be refused with the excluded setting's
+    name in the message, each option alone must build, and the pipeline must
+    refuse each block option by name. Returns what disagrees, as text."""
+    import dataclasses as dc
+
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_sigmoid_loss_tpu.models.text import TextTransformer
+    from distributed_sigmoid_loss_tpu.parallel.pp_towers import validate_pp_tower
+    from distributed_sigmoid_loss_tpu.utils.config import BLOCK_OPTIONS, TextConfig
+
+    base = dc.replace(
+        TextConfig.tiny_test(), causal=True, pos="none", pool="last", norm="rmsnorm",
+        mlp="swiglu", use_bias=False,
+    )
+    tokens = jax.ShapeDtypeStruct((2, base.context_length), jnp.int32)
+
+    def builds(cfg):
+        try:
+            jax.eval_shape(TextTransformer(cfg).init, jax.random.key(0), tokens)
+        except ValueError as e:
+            return str(e)
+        return None
+
+    drift = []
+    for option, excluded, named, source, _ in TOWER_EXCLUSIONS:
+        alone = builds(dc.replace(base, **option))
+        if alone is not None:
+            drift.append(f"{option} alone is refused ({source}): {alone}")
+        both = builds(dc.replace(base, **option, **excluded))
+        if both is None or named not in both:
+            drift.append(f"{option} with {excluded} is not refused by {named!r} ({source}): {both}")
+    if set(PP_REFUSES) - set(BLOCK_OPTIONS):
+        drift.append(f"PP_REFUSES names no block option: {sorted(set(PP_REFUSES) - set(BLOCK_OPTIONS))}")
+    changed = {"mixers": ("kda", "mla"), "moe_router": "sigmoid", "norm_eps": 1e-5, "moe_route_scale": 2.5}
+    for name in PP_REFUSES:
+        cfg = dc.replace(TextConfig.tiny_test(), scan_layers=True, **{name: changed.get(name, 1)})
+        try:
+            validate_pp_tower(cfg, 2, "text")
+            drift.append(f"the pipelined towers take {name}")
+        except ValueError as e:
+            if f"{name}=" not in str(e):
+                drift.append(f"the pipelined towers refuse {name} without naming it: {e}")
+    return drift

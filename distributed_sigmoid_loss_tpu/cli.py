@@ -64,6 +64,36 @@ def _bootstrap_devices(args) -> None:
         jax.config.update("jax_platforms", "cpu")
 
 
+def _config_from_file(path: str):
+    """``SigLIPConfig`` from a configuration file's ``vision`` / ``text`` /
+    ``loss`` sections, by dataclass field name (benchmark/configs/*.json have
+    them; their other keys say where the numbers come from and are not read)."""
+    import dataclasses
+    import json
+
+    from distributed_sigmoid_loss_tpu.utils.config import (
+        LossConfig,
+        SigLIPConfig,
+        TextConfig,
+        ViTConfig,
+    )
+
+    with open(path) as f:
+        body = json.load(f)
+
+    def section(cls, name):
+        given = body.get(name, {})
+        unknown = set(given) - {f.name for f in dataclasses.fields(cls)}
+        if unknown:
+            raise SystemExit(f"{path}: {cls.__name__} has no field(s) {sorted(unknown)}")
+        return cls(**given)
+
+    return SigLIPConfig(
+        vision=section(ViTConfig, "vision"), text=section(TextConfig, "text"),
+        loss=section(LossConfig, "loss"),
+    )
+
+
 def _model_config(args):
     from distributed_sigmoid_loss_tpu.utils.config import SigLIPConfig
 
@@ -74,12 +104,17 @@ def _model_config(args):
             f"--tiny conflicts with --model {args.model}; pass one or the other"
         )
     name = "tiny" if getattr(args, "tiny", False) else args.model
-    cfg = {
-        "tiny": SigLIPConfig.tiny_test,
-        "l14": SigLIPConfig.l14,
-        "so400m": SigLIPConfig.so400m,
-        "b16": SigLIPConfig.b16,
-    }[name]()
+    if getattr(args, "model_config", ""):
+        if name != "b16":
+            raise SystemExit(f"--model-config conflicts with --model {name}; pass one or the other")
+        cfg = _config_from_file(args.model_config)
+    else:
+        cfg = {
+            "tiny": SigLIPConfig.tiny_test,
+            "l14": SigLIPConfig.l14,
+            "so400m": SigLIPConfig.so400m,
+            "b16": SigLIPConfig.b16,
+        }[name]()
     moe = getattr(args, "moe_experts", 0)
     if moe:
         # Shared by train AND eval: a checkpoint trained with --moe-experts can
@@ -2540,6 +2575,11 @@ def main(argv=None) -> int:
                          "(factored second moments, biggest-model memory)")
     tr.add_argument("--model", choices=["b16", "l14", "so400m", "tiny"], default="b16")
     tr.add_argument("--tiny", action="store_true", help="alias for --model tiny")
+    tr.add_argument("--model-config", default="", metavar="FILE",
+                    help="a configuration file in place of --model: JSON with "
+                         "vision / text / loss sections by SigLIPConfig field "
+                         "name (benchmark/configs/*.json), e.g. a text tower of "
+                         "several layer kinds with routed experts")
     tr.add_argument("--accum", type=int, default=1, help="grad-accumulation microsteps")
     tr.add_argument("--accum-bf16", action="store_true",
                     help="bf16 gradient accumulator under --accum (adds stay "

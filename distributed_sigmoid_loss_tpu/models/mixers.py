@@ -1,0 +1,175 @@
+"""Token mixers of a stack of several layer kinds (``TextConfig.mixers``), beside
+the block's softmax ``Attention`` (models/transformer.py): a gated delta-rule
+layer ("kda") and latent attention ("mla"). Imported only where a configuration
+names one. Both are causal, carry no position encoding and no bias; their
+statistics, gates and decays are float32 whatever the tower's dtype.
+
+With x the (s, width) normalised stream of one sequence:
+
+    KDA   q, k, v = silu(conv(x Wq)), silu(conv(x Wk)), silu(conv(x Wv))   # conv: causal depthwise, no bias
+          q_h = l2norm(q_h) dk^-1/2 ;  k_h = l2norm(k_h)                    # per head
+          g   = -exp(A_log_h) softplus((x Wfa) Wfb + dt_bias)               # log-decay per key channel
+          beta_h = sigmoid(x Wb)
+          o   = gated delta rule (ops/gated_delta_rule.py)
+          out = (RMS_head(o) sigmoid((x Wga) Wgb)) Wo
+    MLA   q_h = (x Wq)_h ;  [c, kr] = x Wkva ;  [kn_h, v_h] = (RMS(c) Wkvb)_h
+          k_h = [kn_h, kr]   (kr shared by all heads, carried unrotated)
+          out = softmax(q_h k_h^T (dn + dr)^-1/2 + causal) v_h -> Wo
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import (
+    chunk_gated_delta_rule,
+    short_causal_conv,
+)
+
+F32 = jnp.float32
+L2_EPS = 1e-6
+# The program's names for these layers' device time (benchmark/scopes_kimi.py):
+# flax writes the modules' own names ("kda", "mla") into every operation's path.
+KDA_CORE_SCOPE = "kda_core"  # the recurrence alone, inside "kda"
+MLA_CORE_SCOPE = "mla_core"  # scores, softmax and values, inside "mla"
+CHUNK = 64  # tokens a chunk of the delta rule: one MXU tile of intra-chunk products
+
+
+def l2norm(x):
+    """x / sqrt(sum x^2 + 1e-6) over the last axis, in float32."""
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
+
+
+def _decay_rate_init(key, shape, dtype=F32):
+    """A_log: log of a rate uniform in [1, 16), per head."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=F32):
+    """dt_bias: softplus^-1 of a step log-uniform in [1e-3, 1e-1]."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3), math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class KdaMixer(nn.Module):
+    """The gated delta-rule layer: ``num_heads`` heads with ``head_dim`` key and
+    value channels each, chunks of ``CHUNK`` tokens."""
+
+    width: int
+    num_heads: int
+    head_dim: int
+    conv_size: int
+    dtype: Any
+    norm_eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, _ = x.shape
+        h, d = self.num_heads, self.head_dim
+        dense = partial(
+            nn.Dense, use_bias=False, dtype=self.dtype,
+            kernel_init=nn.initializers.xavier_uniform(),
+        )
+        bound = self.conv_size**-0.5
+
+        def conv_init(key, shape, dtype=F32):
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+        def branch(name):
+            y = dense(h * d, name=name)(x)
+            taps = self.param(name + "_conv", conv_init, (self.conv_size, h * d), F32)
+            return nn.silu(short_causal_conv(y, taps)).reshape(b, s, h, d)
+
+        q, k, v = branch("q"), branch("k"), branch("v")
+        q = (l2norm(q) * d**-0.5).astype(self.dtype)
+        k = l2norm(k).astype(self.dtype)
+        rate = jnp.exp(self.param("A_log", _decay_rate_init, (h,), F32))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (h * d,), F32)
+        step = dense(h * d, name="f_b")(dense(d, name="f_a")(x)).astype(F32) + dt_bias
+        g = -rate[:, None] * jax.nn.softplus(step).reshape(b, s, h, d)
+        beta = jax.nn.sigmoid(dense(h, name="beta")(x).astype(F32))
+        with jax.named_scope(KDA_CORE_SCOPE):
+            o = chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, dtype=self.dtype)
+        scale = self.param("o_norm", nn.initializers.ones, (d,), F32)
+        o = o.astype(F32)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + self.norm_eps) * scale
+        gate = dense(h * d, name="g_b")(dense(d, name="g_a")(x)).astype(F32)
+        o = (o * jax.nn.sigmoid(gate).reshape(b, s, h, d)).astype(self.dtype)
+        return dense(self.width, name="out")(o.reshape(b, s, h * d))
+
+
+def pad_heads_to_one_size(attend, q, k, v, multiple: int = 1):
+    """Run an attention core that takes one head size on query/key heads wider
+    than the value heads: v is zero-padded to the key's width and the output cut
+    back, which is exact (a zero value channel stays zero); q and k are padded
+    alike up to a ``multiple`` of lanes (zero channels add nothing to a score)."""
+    dqk, dv = q.shape[-1], v.shape[-1]
+    wide = -(-max(dqk, dv) // multiple) * multiple
+
+    def pad(t):
+        return jnp.pad(t, ((0, 0),) * 3 + ((0, wide - t.shape[-1]),))
+
+    return attend(pad(q), pad(k), pad(v))[..., :dv]
+
+
+class LatentAttention(nn.Module):
+    """Causal latent attention without position encoding: per head a key part
+    of ``nope_dim`` expanded from the ``kv_rank`` latent and one ``shared_dim``
+    key part shared by all heads; value heads of ``v_dim``."""
+
+    width: int
+    num_heads: int
+    nope_dim: int
+    shared_dim: int
+    v_dim: int
+    kv_rank: int
+    dtype: Any
+    norm_eps: float = 1e-5
+    attn_impl: str = "auto"
+
+    @nn.compact
+    def __call__(self, x):
+        from distributed_sigmoid_loss_tpu.models.transformer import _fused_attention_per_shard
+        from distributed_sigmoid_loss_tpu.ops.flash_attention import (
+            flash_attention_available,
+            flash_self_attention,
+        )
+        from distributed_sigmoid_loss_tpu.parallel.ring_attention import dense_attention
+
+        b, s, _ = x.shape
+        h, dn, dr, dv = self.num_heads, self.nope_dim, self.shared_dim, self.v_dim
+        dense = partial(
+            nn.Dense, use_bias=False, dtype=self.dtype,
+            kernel_init=nn.initializers.xavier_uniform(),
+        )
+        q = dense(h * (dn + dr), name="q")(x).reshape(b, s, h, dn + dr)
+        latent = dense(self.kv_rank + dr, name="kv_a")(x)
+        c, shared = latent[..., : self.kv_rank], latent[..., self.kv_rank :]
+        c = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="kv_norm")(c)
+        expanded = dense(h * (dn + dv), name="kv_b")(c).reshape(b, s, h, dn + dv)
+        k = jnp.concatenate(
+            [expanded[..., :dn], jnp.broadcast_to(shared[:, :, None, :], (b, s, h, dr))], -1
+        )
+        v = expanded[..., dn:]
+        if self.attn_impl == "flash" and not flash_attention_available():
+            raise ValueError("attn_impl='flash' requires a TPU backend; use 'auto'")
+        # As in Attention: the fused kernel's backward is bf16-grade, so "auto"
+        # takes it for a bf16 tower on a TPU only. The blocked kernel never
+        # writes the (b, h, s, s) scores to HBM.
+        fused = self.attn_impl == "flash" or (
+            self.attn_impl == "auto" and self.dtype == jnp.bfloat16 and flash_attention_available()
+        )
+        core = partial(flash_self_attention if fused else dense_attention, causal=True, scale=(dn + dr) ** -0.5)
+        if fused:  # a Mosaic kernel under a multi-chip jit sits in a shard_map
+            core = partial(_fused_attention_per_shard, core)
+        with jax.named_scope(MLA_CORE_SCOPE):
+            out = pad_heads_to_one_size(core, q, k, v, multiple=128 if fused else 1)
+        out = out.astype(self.dtype).reshape(b, s, h * dv)
+        return dense(self.width, name="out")(out)

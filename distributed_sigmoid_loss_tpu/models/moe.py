@@ -33,6 +33,7 @@ code pulls it with ``mutable=["intermediates"]`` and adds
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any
 
 import flax.linen as nn
@@ -50,6 +51,12 @@ __all__ = [
     "build_dispatch",
     "expert_apply",
     "moe_capacity",
+    "SharedExpertMoe",
+    "SELECT_BIAS",
+    "MOE_ROUTE_SCOPE",
+    "sigmoid_route",
+    "dispatch_plan",
+    "routed_experts",
 ]
 
 
@@ -256,3 +263,224 @@ class MoeMlp(nn.Module):
             xg, dispatch, combine, wi, wo, self.dtype, quant=self.quant
         )
         return y.reshape(*lead, d)
+
+
+# -- sigmoid-routed, dropless, with a shared expert and a chip's share ---------
+#
+# The routed layer of the latent-attention language models (``moe_router =
+# "sigmoid"``). With x one token, E experts, k chosen:
+#
+#     s = sigmoid(x Wr) in R^E ;  I = top_k(s + b) ;  w_i = scale * s_i / sum_{j in I} s_j
+#     y = Shared(x) + sum_{i in I, i held here} w_i E_i(x) ;  E(x) = (silu(x Wg) * (x Wu)) Wd
+#
+# ``b`` decides the selection only (SELECT_BIAS: no gradient reaches it, and
+# train/train_step.py gives it no decay and no optimizer state). A chip holds
+# experts [first_held, first_held + held): the router scores all E, and what
+# the absent experts would add is left out. Nothing is dropped: the assignments
+# to held experts are sorted by expert, and each expert runs its own segment in
+# blocks of rows, as many blocks as its load needs (one loop over all of them,
+# whose trip count the device reads). The rows are bounded by T * k, the true worst case, and
+# only the index arrays have that size: tokens are gathered a block at a time.
+
+SELECT_BIAS = "select_bias"
+# The program's name for everything of the layer but the expert and shared
+# products: scores, selection, the sort, the gathers and scatters.
+MOE_ROUTE_SCOPE = "moe_route"
+# Rows of one block of an expert's segment: a held expert of the cell sees 512
+# tokens a microbatch, so a balanced expert is one block and one pair of products.
+BLOCK_ROWS = 512
+F32 = jnp.float32
+
+
+def sigmoid_route(x, wr, select_bias, k: int, scale: float):
+    """``(idx, weights)`` of ``(T, k)`` for tokens ``x`` (T, d): float32 scores
+    at full matmul precision, selection by score + bias, weights by score."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(x.astype(F32), wr, precision=jax.lax.Precision.HIGHEST)
+    )
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias), k)
+    chosen = jnp.take_along_axis(scores, idx, -1)
+    return idx, scale * chosen / jnp.sum(chosen, -1, keepdims=True)
+
+
+def dispatch_plan(idx, weights, first: int, held: int):
+    """The assignments to experts ``[first, first + held)`` sorted by expert:
+    ``(token, row_weight, starts, counts)``, the first two ``(T * k,)`` (rows
+    past ``starts[-1] + counts[-1]`` belong to absent experts), the last two
+    ``(held,)``: expert e's rows are ``starts[e] : starts[e] + counts[e]``."""
+    k = idx.shape[-1]
+    local = idx - first
+    key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    edges = jnp.searchsorted(key[order], jnp.arange(held + 1, dtype=key.dtype))
+    return (
+        (order // k).astype(jnp.int32), weights.reshape(-1)[order],
+        edges[:-1].astype(jnp.int32), jnp.diff(edges).astype(jnp.int32),
+    )
+
+
+def _block_plan(starts, counts, block: int):
+    """The held experts' segments cut into blocks of ``block`` rows, expert after
+    expert: ``(blocks in all, first block of each expert)``."""
+    per_expert = -(-counts // block)
+    first = jnp.cumsum(per_expert) - per_expert
+    return jnp.sum(per_expert), first
+
+
+def _block_rows(i, first, block, token, row_weight, starts, counts, tokens):
+    """Block ``i`` of the plan: its expert, its rows, their tokens and weights,
+    and where to scatter (a row past its segment's end scatters out of range,
+    which drops it, and weighs nothing)."""
+    e = jnp.sum(first <= i) - 1  # the last expert whose first block is not after i
+    rows = starts[e] + (i - first[e]) * block + jnp.arange(block, dtype=jnp.int32)
+    valid = rows < starts[e] + counts[e]
+    rows = jnp.where(valid, rows, 0)
+    tok = token[rows]
+    return e, rows, valid, tok, jnp.where(valid, tok, tokens), jnp.where(valid, row_weight[rows], 0.0)
+
+
+def _gather_rows(x, tok):
+    with jax.named_scope(MOE_ROUTE_SCOPE):
+        return x[tok]
+
+
+def _scatter_add_rows(y, to, rows):
+    with jax.named_scope(MOE_ROUTE_SCOPE):
+        return y.at[to].add(rows, mode="drop")
+
+
+def _expert_mlp(xb, wg, wu, wd, e, dt):
+    """One block through expert ``e``: float32 gate and up, the hidden
+    activation and the output, with what the backward needs of them."""
+    wg_e, wu_e, wd_e = (jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False).astype(dt) for w in (wg, wu, wd))
+    gate = jnp.dot(xb, wg_e, preferred_element_type=F32)
+    up = jnp.dot(xb, wu_e, preferred_element_type=F32)
+    sig = jax.nn.sigmoid(gate)
+    hidden = (gate * sig * up).astype(dt)
+    return (wg_e, wu_e, wd_e), (gate, up, sig, hidden), jnp.dot(hidden, wd_e, preferred_element_type=F32)
+
+
+def _routed_forward(x, wg, wu, wd, token, row_weight, starts, counts, block):
+    tokens, dt = x.shape[0], x.dtype
+    total, first = _block_plan(starts, counts, block)
+
+    def step(i, carry):
+        y, done = carry
+        e, _, valid, tok, to, wts = _block_rows(i, first, block, token, row_weight, starts, counts, tokens)
+        _, _, out = _expert_mlp(_gather_rows(x, tok), wg, wu, wd, e, dt)
+        return _scatter_add_rows(y, to, out * wts[:, None]), done + jnp.sum(valid, dtype=jnp.int32)
+
+    y, done = jax.lax.fori_loop(0, total, step, (jnp.zeros(x.shape, F32), jnp.zeros((), jnp.int32)))
+    return y.astype(dt), done
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(8,))
+def routed_experts(x, wg, wu, wd, token, row_weight, starts, counts, block):
+    """``(y, rows_done)``: the held experts' weighted part of the layer's output
+    for tokens ``x`` (T, d), following :func:`dispatch_plan`, ``block`` rows at
+    a time, one loop over every expert's blocks whose trip count the device
+    reads; ``rows_done`` counts the assignments that ran (all of them)."""
+    return _routed_forward(x, wg, wu, wd, token, row_weight, starts, counts, block)
+
+
+def _routed_experts_fwd(x, wg, wu, wd, token, row_weight, starts, counts, block):
+    out = _routed_forward(x, wg, wu, wd, token, row_weight, starts, counts, block)
+    return out, (x, wg, wu, wd, token, row_weight, starts, counts)
+
+
+def _routed_experts_bwd(block, saved, cts):
+    """The same loop backwards, each block's forward recomputed; the weight
+    gradients ride it in float32, a block adding into its expert's row."""
+    x, wg, wu, wd, token, row_weight, starts, counts = saved
+    dy = cts[0]
+    tokens, dt = x.shape[0], x.dtype
+    total, first = _block_plan(starts, counts, block)
+
+    def add_row(acc, e, g):
+        return jax.lax.dynamic_update_index_in_dim(
+            acc, jax.lax.dynamic_index_in_dim(acc, e, 0, keepdims=False) + g, e, 0
+        )
+
+    def step(i, carry):
+        dx, d_weight, g_wg, g_wu, g_wd = carry
+        e, rows, valid, tok, to, wts = _block_rows(i, first, block, token, row_weight, starts, counts, tokens)
+        xb = _gather_rows(x, tok)
+        (wg_e, wu_e, wd_e), (gate, up, sig, hidden), out = _expert_mlp(xb, wg, wu, wd, e, dt)
+        dyb = jnp.where(valid[:, None], _gather_rows(dy, tok).astype(F32), 0.0)
+        d_weight = d_weight.at[jnp.where(valid, rows, d_weight.shape[0])].set(
+            jnp.sum(dyb * out, -1), mode="drop"
+        )
+        dyw = (dyb * wts[:, None]).astype(dt)
+        d_hidden = jnp.dot(dyw, wd_e.T, preferred_element_type=F32)
+        d_up = (d_hidden * gate * sig).astype(dt)
+        d_gate = (d_hidden * up * sig * (1.0 + gate * (1.0 - sig))).astype(dt)
+        dxb = jnp.dot(d_gate, wg_e.T, preferred_element_type=F32) + jnp.dot(
+            d_up, wu_e.T, preferred_element_type=F32
+        )
+        return (
+            _scatter_add_rows(dx, to, dxb), d_weight,
+            add_row(g_wg, e, jnp.dot(xb.T, d_gate, preferred_element_type=F32)),
+            add_row(g_wu, e, jnp.dot(xb.T, d_up, preferred_element_type=F32)),
+            add_row(g_wd, e, jnp.dot(hidden.T, dyw, preferred_element_type=F32)),
+        )
+
+    zeros = (jnp.zeros(x.shape, F32), jnp.zeros(row_weight.shape, F32), *(jnp.zeros(w.shape, F32) for w in (wg, wu, wd)))
+    dx, d_weight, g_wg, g_wu, g_wd = jax.lax.fori_loop(0, total, step, zeros)
+    return (
+        dx.astype(dt), g_wg.astype(wg.dtype), g_wu.astype(wu.dtype), g_wd.astype(wd.dtype),
+        None, d_weight.astype(row_weight.dtype), None, None,
+    )
+
+
+routed_experts.defvjp(_routed_experts_fwd, _routed_experts_bwd)
+
+
+class SharedExpertMoe(nn.Module):
+    """The sigmoid-routed layer above: ``num_experts`` routed bias-free SwiGLU
+    experts ``hidden`` wide, ``num_selected`` a token, ``shared_experts`` more
+    that every token runs, and of the routed ones ``experts_held`` (0 = all)
+    here, from ``first_held`` on. Sows ``moe_load`` into ``"intermediates"``:
+    the held experts' token counts and the assignments that did not run (0)."""
+
+    width: int
+    hidden: int
+    num_experts: int
+    num_selected: int
+    dtype: Any
+    route_scale: float = 1.0
+    shared_experts: int = 0
+    experts_held: int = 0
+    first_held: int = 0
+
+    @nn.compact
+    def __call__(self, x):
+        from distributed_sigmoid_loss_tpu.models.transformer import Mlp
+
+        d, e, k = self.width, self.num_experts, self.num_selected
+        held = self.experts_held or e
+        if not 0 < k <= e or not 0 <= self.first_held <= e - held:
+            raise ValueError(
+                f"moe_num_selected={k}, moe_experts_held={held} (from {self.first_held}) "
+                f"do not fit moe_experts={e}"
+            )
+        lead = x.shape[:-1]
+        xt = x.reshape(-1, d)
+        block = min(BLOCK_ROWS, xt.shape[0])
+        with jax.named_scope(MOE_ROUTE_SCOPE):
+            wr = self.param("router", nn.initializers.normal(0.02), (d, e), F32)
+            select_bias = self.param(SELECT_BIAS, nn.initializers.zeros, (e,), F32)
+            idx, weights = sigmoid_route(xt, wr, select_bias, k, self.route_scale)
+            token, row_weight, starts, counts = dispatch_plan(idx, weights, self.first_held, held)
+        per_expert = nn.initializers.variance_scaling(1.0, "fan_avg", "uniform", batch_axis=(0,))
+        wg = self.param("wg", per_expert, (held, d, self.hidden), F32)
+        wu = self.param("wi", per_expert, (held, d, self.hidden), F32)
+        wd = self.param("wo", per_expert, (held, self.hidden, d), F32)
+        y, done = routed_experts(xt, wg, wu, wd, token, row_weight, starts, counts, block)
+        self.sow("intermediates", "moe_load", {"tokens": counts, "dropped": jnp.sum(counts) - done})
+        y = y.reshape(*lead, d)
+        if self.shared_experts:
+            y = y + Mlp(
+                d, self.shared_experts * self.hidden / d, self.dtype,
+                kind="swiglu", use_bias=False, name="shared",
+            )(x)
+        return y

@@ -3,8 +3,9 @@
 embedding space. Embedding normalization stays outside the model (reference
 convention, test_distributed_sigmoid_loss.py:96-101). ``TextConfig``'s block
 options turn it into a language-model-class encoder: causal, rotary positions
-in place of the position table, RMSNorm sandwich blocks with a gated MLP, the
-stack run ``loops`` times on one set of weights."""
+in place of the position table (or none: ``pos="none"``), RMSNorm sandwich
+blocks with a gated MLP, the stack run ``loops`` times on one set of weights, or
+a stack of several layer kinds with routed experts (``mixers``)."""
 
 from __future__ import annotations
 
@@ -14,10 +15,48 @@ import jax.numpy as jnp
 from distributed_sigmoid_loss_tpu.models.transformer import (
     BlockStyle,
     Encoder,
+    LayerSpec,
     MapHead,
     _dtype,
 )
 from distributed_sigmoid_loss_tpu.utils.config import TextConfig, tower_quant_mode
+
+
+def layer_specs(cfg: TextConfig) -> tuple[LayerSpec, ...]:
+    """``Encoder``'s layers where the configuration gives the stack layer by
+    layer (``mixers``, ``leading_dense_layers``) or asks for the sigmoid-routed
+    experts; none for the stack every tower had."""
+    if not (cfg.mixers or cfg.leading_dense_layers or cfg.moe_router != "softmax"):
+        return ()
+    mixer_fields = {
+        "attn": (),
+        "kda": (("head_dim", cfg.kda_head_dim), ("conv_size", cfg.kda_conv_size)),
+        "mla": (
+            ("nope_dim", cfg.mla_qk_nope_dim), ("shared_dim", cfg.mla_qk_shared_dim),
+            ("v_dim", cfg.mla_v_dim), ("kv_rank", cfg.mla_kv_rank),
+        ),
+    }
+    mixers = cfg.mixers or ("attn",) * cfg.depth
+    if not set(mixers) <= set(mixer_fields):
+        raise ValueError(f"unknown mixer in mixers={mixers}: want one of {sorted(mixer_fields)}")
+    if set(mixers) != {"attn"} and cfg.pos != "none":
+        raise ValueError(
+            f"mixers={mixers} (a recurrence or latent attention, with no position "
+            f"encoding) is not built for pos={cfg.pos!r}"
+        )
+    if cfg.moe_router not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown moe_router: {cfg.moe_router!r}")
+    experts_fields = ()
+    if cfg.moe_router == "sigmoid":
+        experts_fields = (
+            ("hidden", cfg.moe_hidden or int(round(cfg.width * cfg.mlp_ratio))),
+            ("route_scale", cfg.moe_route_scale), ("shared_experts", cfg.moe_shared_experts),
+            ("experts_held", cfg.moe_experts_held),
+        )
+    return tuple(
+        LayerSpec(kind, mixer_fields[kind], i < cfg.leading_dense_layers, experts_fields)
+        for i, kind in enumerate(mixers)
+    )
 
 
 class TextTransformer(nn.Module):
@@ -28,6 +67,7 @@ class TextTransformer(nn.Module):
         """token_ids: (batch, context_length) int32 → (batch, embed_dim)."""
         cfg = self.cfg
         dtype = _dtype(cfg.dtype)
+        layers = layer_specs(cfg)
 
         emb = nn.Embed(
             cfg.vocab_size,
@@ -44,7 +84,7 @@ class TextTransformer(nn.Module):
                 jnp.float32,
             )
             x = x + pos.astype(dtype)
-        elif cfg.pos != "rope":  # rotary positions go on q and k, in the blocks
+        elif cfg.pos not in ("rope", "none"):  # rotary positions go on q and k, in the blocks
             raise ValueError(f"unknown pos: {cfg.pos!r}")
 
         x = Encoder(
@@ -56,7 +96,7 @@ class TextTransformer(nn.Module):
             moe_num_selected=cfg.moe_num_selected,
             moe_capacity_factor=cfg.moe_capacity_factor,
             moe_group_size=cfg.moe_group_size, quant=tower_quant_mode(cfg),
-            style=BlockStyle.of(cfg), loops=cfg.loops,
+            style=BlockStyle.of(cfg), loops=cfg.loops, layers=layers,
             name="encoder",
         )(x)
 

@@ -124,6 +124,7 @@ class BlockStyle:
     mlp: str = "gelu"  # "gelu" | "swiglu"
     use_bias: bool = True
     rope_theta: float | None = None  # None = no rotary positions
+    norm_eps: float = 1e-6
 
     @classmethod
     def of(cls, cfg) -> "BlockStyle":
@@ -131,15 +132,29 @@ class BlockStyle:
             norm=cfg.norm, sandwich_norm=cfg.sandwich_norm, mlp=cfg.mlp,
             use_bias=cfg.use_bias,
             rope_theta=cfg.rope_theta if cfg.pos == "rope" else None,
+            norm_eps=cfg.norm_eps,
         )
 
     def make_norm(self, dtype, name: str) -> nn.Module:
-        """Both kinds compute their statistics in float32 at eps 1e-6."""
+        """Both kinds compute their statistics in float32, at ``norm_eps``."""
         if self.norm == "rmsnorm":
-            return nn.RMSNorm(dtype=dtype, name=name)
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=dtype, name=name)
         if self.norm == "layernorm":
-            return nn.LayerNorm(dtype=dtype, name=name)
+            return nn.LayerNorm(epsilon=self.norm_eps, dtype=dtype, name=name)
         raise ValueError(f"unknown norm: {self.norm!r}")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One layer of a stack given layer by layer, as ``Block`` takes it
+    (``models/text.py layer_specs`` makes them from ``TextConfig``): the token
+    mixer with its module's own size fields, and what the MLP is where
+    ``moe_experts > 0``. The default is the block every tower had."""
+
+    mixer: str = "attn"  # "attn" | "kda" | "mla" (models/mixers.py)
+    mixer_fields: tuple = ()  # (name, value) pairs of KdaMixer / LatentAttention
+    dense_mlp: bool = False  # a leading layer keeps the dense MLP
+    experts_fields: tuple = ()  # (name, value) pairs of SharedExpertMoe; none = MoeMlp
 
 
 def rope(x, theta: float):
@@ -380,10 +395,11 @@ class Block(nn.Module):
     moe_group_size: int = 512
     quant: bool | str = False
     style: BlockStyle = BlockStyle()
+    spec: LayerSpec = LayerSpec()
 
     @nn.compact
     def __call__(self, x):
-        style = self.style
+        style, spec = self.style, self.spec
 
         def norm(name):
             return style.make_norm(self.dtype, name)
@@ -391,14 +407,42 @@ class Block(nn.Module):
         def post(name, y):  # the sandwich's second norm, on a sub-layer's output
             return norm(name)(y) if style.sandwich_norm else y
 
-        x = x + post("ln1_post", Attention(
-            self.width, self.num_heads, self.dtype,
-            sp_axis=self.sp_axis, sp_impl=self.sp_impl,
-            attn_impl=self.attn_impl, causal=self.causal,
-            quant=self.quant, use_bias=style.use_bias, rope_theta=style.rope_theta,
-            name="attn",
-        )(norm("ln1")(x)))
-        if self.moe_experts > 0:
+        if spec.mixer == "attn":
+            mixer = Attention(
+                self.width, self.num_heads, self.dtype,
+                sp_axis=self.sp_axis, sp_impl=self.sp_impl,
+                attn_impl=self.attn_impl, causal=self.causal,
+                quant=self.quant, use_bias=style.use_bias, rope_theta=style.rope_theta,
+                name="attn",
+            )
+        elif spec.mixer in ("kda", "mla"):
+            from distributed_sigmoid_loss_tpu.models.mixers import KdaMixer, LatentAttention
+
+            sized = dict(
+                width=self.width, num_heads=self.num_heads, dtype=self.dtype,
+                norm_eps=style.norm_eps, **dict(spec.mixer_fields),
+            )
+            if spec.mixer == "kda":
+                mixer = KdaMixer(**sized, name="kda")
+            else:
+                mixer = LatentAttention(**sized, attn_impl=self.attn_impl, name="mla")
+        else:
+            raise ValueError(f"unknown mixer: mixers has {spec.mixer!r}")
+        x = x + post("ln1_post", mixer(norm("ln1")(x)))
+        routed = self.moe_experts > 0 and not spec.dense_mlp
+        if routed and spec.experts_fields:
+            from distributed_sigmoid_loss_tpu.models.moe import SharedExpertMoe
+
+            if style.mlp != "swiglu" or style.use_bias or self.quant:
+                raise ValueError(
+                    "moe_router='sigmoid' has the bias-free, unquantised SwiGLU experts only: "
+                    f"mlp={style.mlp!r}, use_bias={style.use_bias}, quant={self.quant!r} are not built"
+                )
+            mlp = SharedExpertMoe(
+                width=self.width, num_experts=self.moe_experts, num_selected=self.moe_num_selected,
+                dtype=self.dtype, **dict(spec.experts_fields), name="moe",
+            )
+        elif routed:
             from distributed_sigmoid_loss_tpu.models.moe import MoeMlp
 
             if style.mlp != "gelu" or not style.use_bias:
@@ -496,6 +540,7 @@ class _ScanBody(nn.Module):
     moe_group_size: int = 512
     quant: bool | str = False
     style: BlockStyle = BlockStyle()
+    spec: LayerSpec = LayerSpec()
 
     @nn.compact
     def __call__(self, carry, layer):
@@ -520,7 +565,7 @@ class _ScanBody(nn.Module):
             moe_num_selected=self.moe_num_selected,
             moe_capacity_factor=self.moe_capacity_factor,
             moe_group_size=self.moe_group_size,
-            quant=self.quant, style=self.style,
+            quant=self.quant, style=self.style, spec=self.spec,
             name="block",
         )(carry)
         return carry, None
@@ -562,6 +607,9 @@ class Encoder(nn.Module):
     quant: bool | str = False
     style: BlockStyle = BlockStyle()
     loops: int = 1
+    # One spec a layer, or none: every layer the default block. Layers that are
+    # all alike and attend are one stack (scanned where ``scan_layers`` says).
+    layers: tuple[LayerSpec, ...] = ()
 
     @nn.compact
     def __call__(self, x):
@@ -572,6 +620,13 @@ class Encoder(nn.Module):
                 f"(loops={self.loops}) would add it once a pass, unrolled layers "
                 "(scan_layers=False) have no layer loop to add it in"
             )
+        layers = self.layers or (LayerSpec(),) * self.depth
+        if len(layers) != self.depth:
+            raise ValueError(f"mixers names {len(layers)} layers, depth={self.depth}")
+        # Several layer kinds, or a leading dense layer: each layer is a module
+        # of its own (no stack of like trees to scan), unrolled, remat per layer.
+        unlike = len(set(layers)) > 1 or layers[0].mixer != "attn"
+        self._check_mixers(tuple(spec.mixer for spec in layers))
         if self.loops > 1:
             return self._looped(x)
         moe_kw = dict(
@@ -581,7 +636,7 @@ class Encoder(nn.Module):
             moe_group_size=self.moe_group_size,
             quant=self.quant, style=self.style,
         )
-        if self.scan_layers:
+        if self.scan_layers and not unlike:
             body_cls = _ScanBody
             if self.remat:
                 # prevent_cse=False is safe (and faster) under scan.
@@ -604,7 +659,7 @@ class Encoder(nn.Module):
             x, _ = scanned(
                 self.width, self.num_heads, self.mlp_ratio, self.dtype,
                 sp_axis=self.sp_axis, sp_impl=self.sp_impl,
-                attn_impl=self.attn_impl, causal=self.causal, **moe_kw,
+                attn_impl=self.attn_impl, causal=self.causal, **moe_kw, spec=layers[0],
                 name="blocks",
             )(x, jnp.arange(self.depth) if sunk else None)
         else:
@@ -617,10 +672,29 @@ class Encoder(nn.Module):
                 x = block_cls(
                     self.width, self.num_heads, self.mlp_ratio, self.dtype,
                     sp_axis=self.sp_axis, sp_impl=self.sp_impl,
-                    attn_impl=self.attn_impl, causal=self.causal, **moe_kw,
+                    attn_impl=self.attn_impl, causal=self.causal, **moe_kw, spec=layers[i],
                     name=f"block{i}",
                 )(x)
         return self.style.make_norm(self.dtype, "ln_final")(x)
+
+    def _check_mixers(self, mixers):
+        """What a recurrence or latent attention over one whole causal sequence
+        does not run with, each refusal by the option's name (a position table
+        is ``models/text.py``'s to refuse)."""
+        refused = {
+            "causal=False": not self.causal,
+            f"sequence_parallel_axis={self.sp_axis!r}": self.sp_axis is not None,
+            f"quant={self.quant!r}": bool(self.quant),
+            f"rope_theta={self.style.rope_theta!r} (pos='rope')": self.style.rope_theta is not None,
+            "sandwich_norm=True": self.style.sandwich_norm,
+            f"loops={self.loops}": self.loops > 1,
+        }
+        if set(mixers) != {"attn"} and any(refused.values()):
+            raise ValueError(
+                f"mixers={mixers} (a recurrence or latent attention over one "
+                "whole causal sequence, unquantised, with no position encoding) "
+                "is not built for " + ", ".join(k for k, v in refused.items() if v)
+            )
 
     def _looped(self, x):
         """One pass (the layers, then the final norm) as a child ``loop``, run

@@ -69,6 +69,7 @@ __all__ = [
     "jaxpr_costs",
     "static_attribution",
     "accum_placement",
+    "mixed_stack",
     "attribution_of_compiled",
     "roofline_estimate",
     "step_config_attribution",
@@ -285,6 +286,18 @@ def accum_placement(step) -> dict | None:
         return None
     total = record["layer_loop_bytes"] + record["accum_add_bytes"]
     return dict(record, layer_loop_share=record["layer_loop_bytes"] / total)
+
+
+def mixed_stack(step) -> dict | None:
+    """What a traced train step's text tower runs where it is a stack of
+    several layer kinds with dropless routed experts, from the record
+    ``make_train_step`` writes while it traces (``step.stack_record``): the
+    layers' kinds in order (``mixer+mlp`` / ``mixer+moe``), experts held / in
+    all / per token, the assignments to held experts a token is expected to
+    make under uniform routing, the tokens of a microbatch and the rows the
+    dispatch is bounded by (every token choosing held experts only). None for a
+    step that has not traced yet or runs no such tower."""
+    return dict(getattr(step, "stack_record", None) or {}) or None
 
 
 def attribution_of_compiled(compiled) -> dict:

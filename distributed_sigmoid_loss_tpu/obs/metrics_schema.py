@@ -44,6 +44,11 @@ TRAIN_METRICS_FIELDS = frozenset({
     # train/train_step.py + train/compressed_step.py step metrics
     "loss", "t", "bias", "grad_norm", "param_norm", "update_ratio",
     "moe_aux", "ef_norm",
+    # train/train_step.py, a dropless routed text tower (models/moe.py
+    # SharedExpertMoe): assignments to the experts held here, the fullest and
+    # the mean held expert's tokens, assignments that did not run (always 0)
+    "moe_local_assignments", "moe_max_expert_tokens", "moe_mean_expert_tokens",
+    "moe_dropped_tokens",
     # train/compressed_step.py DCN wire accounting: per-device egress bytes
     # per sync round, payload bits per parameter, the residual-carry norm
     # (ef_norm's registered successor — both emitted), and the adaptive

@@ -1,0 +1,191 @@
+"""The gated delta rule with a decay per key channel (the recurrence of a KDA
+layer), token by token and in the chunked form the towers run.
+
+Per head, with a state S in R^(dk x dv), S_0 = 0, a log-decay g_t <= 0 per key
+channel and a write strength beta_t in (0, 1):
+
+    S_t = (I - beta_t k_t k_t^T) diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+:func:`gated_delta_rule_recurrent` is that, one token at a time (``lax.scan``
+over the sequence): the definition, for tests. :func:`chunk_gated_delta_rule`
+computes the same o in chunks of C tokens. With u_t = beta_t (v_t - (exp(g_t)
+k_t)^T S_{t-1}) the update is S_t = diag(exp(g_t)) S_{t-1} + k_t u_t^T, and
+inside a chunk that starts at state S, with G_t the running sum of g from the
+chunk's first token:
+
+    (I + A) U = beta V - (beta K exp(G)) S,   A[t, i] = beta_t sum_c k_tc k_ic exp(G_tc - G_ic), i < t
+    O = (Q exp(G)) S + (tril(P) + diag(q_t . k_t)) U,   P[t, i] = sum_c q_tc k_ic exp(G_tc - G_ic), i < t
+    S' = diag(exp(G_C)) S + (K exp(G_C - G))^T U
+
+so the chunk's own work is matrix products (on the MXU) and the state crosses
+chunks in a scan of s / C steps.
+
+**No quotient of decays.** exp(G_t - G_i) does not factor into exp(G_t) *
+exp(-G_i) safely: a channel that forgets fast overflows the second factor
+within a chunk. A and P are built by halving instead: for a block [a, a + 2h)
+with its left half ending at m, every pair (t in the right half, i in the left
+half) has G_t - G_i = (G_t - G_m) + (G_m - G_i), two exponents <= 0, so that
+quadrant is one (h x dk) @ (dk x h) product of safely scaled operands; the two
+halves recurse, down to single tokens. Six levels at C = 64, the operations of
+one C x C product in all, exact for any g <= 0. The inverse of the unit lower
+triangular I + A grows in the same sweep, [[Ta, 0], [-Tb A_ba Ta, Tb]] from the
+halves' inverses, in float32 (a Neumann series would cancel catastrophically
+where keys repeat).
+
+The backward pass is ``jax.grad``'s through this, recomputed (``jax.checkpoint``)
+and run a few batch rows at a time (``lax.map``), so that what lives at once is
+a pass's intermediates and not a layer's. On the chip (PERF.md section 5) this
+form is far from its roofline: every level's scaled operands cross HBM in many
+small operations, where a kernel would keep a chunk in VMEM. (All six levels as
+one masked batched product were tried: fewer operations, 20 % slower, three
+times the generated code.)
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["gated_delta_rule_recurrent", "chunk_gated_delta_rule", "short_causal_conv"]
+
+F32 = jnp.float32
+# Float32 intermediates of one pass of the chunked form, in bytes per array: the
+# batch rows of a pass are chosen to stay under it.
+_PASS_BYTES = 48 * 2**20
+
+
+def short_causal_conv(x, kernel):
+    """Causal depthwise convolution along the sequence: ``x`` (b, s, channels),
+    ``kernel`` (taps, channels), out[t] = sum_j kernel[j] x[t - (taps - 1) + j],
+    zeros before the first token, no bias. A few shifted products: the taps are
+    four."""
+    taps = kernel.shape[0]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    s = x.shape[1]
+    return sum(padded[:, j : j + s] * kernel[j].astype(x.dtype) for j in range(taps))
+
+
+def gated_delta_rule_recurrent(q, k, v, g, beta):
+    """The recurrence, one token at a time, in float32 at full matmul precision.
+    q, k, g: (b, s, h, dk); v: (b, s, h, dv); beta: (b, s, h). Returns (b, s, h, dv)."""
+    q, k, v, g, beta = (x.astype(F32) for x in (q, k, v, g, beta))
+    b, s, h, dk = q.shape
+    hi = jax.lax.Precision.HIGHEST
+
+    def step(state, x):
+        q_t, k_t, v_t, g_t, beta_t = x
+        state = state * jnp.exp(g_t)[..., None]
+        seen = jnp.einsum("bhk,bhkv->bhv", k_t, state, precision=hi)
+        state = state + (beta_t[..., None] * k_t)[..., None] * (v_t - seen)[..., None, :]
+        return state, jnp.einsum("bhk,bhkv->bhv", q_t, state, precision=hi)
+
+    xs = tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta))
+    _, out = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), F32), xs)
+    return jnp.moveaxis(out, 0, 1)
+
+
+def _chunked(q, k, v, g, beta, *, chunk: int, dt):
+    """The chunked form on (rows, s, h, d) operands, s a multiple of ``chunk``."""
+    rows, s, h, dk = q.shape
+    dv, n = v.shape[-1], s // chunk
+    precision = jax.lax.Precision.HIGHEST if dt == F32 else None
+
+    def mm(spec, a, b_):
+        return jnp.einsum(
+            spec, a.astype(dt), b_.astype(dt), preferred_element_type=F32, precision=precision
+        )
+
+    def mm32(spec, a, b_):
+        return jnp.einsum(spec, a, b_, precision=jax.lax.Precision.HIGHEST)
+
+    def by_chunk(x):  # (rows, s, h, d) -> (rows, h, n, chunk, d)
+        return jnp.moveaxis(x.reshape(rows, n, chunk, h, -1), 3, 1)
+
+    q, k, v = by_chunk(q), by_chunk(k), by_chunk(v)
+    big_g = jnp.cumsum(by_chunk(g.astype(F32)), axis=-2)  # G, running from the chunk's start
+    beta = by_chunk(beta.astype(F32)[..., None])  # (rows, h, n, chunk, 1)
+    lead = big_g.shape[:-2]
+    qk = jnp.stack([k, q]).astype(F32)  # the two row operands of A and P
+
+    # A, P and (I + A)^-1 by halving, bottom-up: blocks of h tokens pair into 2h.
+    p_low = jnp.zeros((*lead, chunk, 1, 1), F32)
+    t_inv = jnp.ones((*lead, chunk, 1, 1), F32)
+    half = 1
+    while half < chunk:
+        nb = chunk // (2 * half)
+        g_b = big_g.reshape(*lead, nb, 2, half, dk)
+        ref = g_b[..., 0, half - 1 :, :]  # the left half's last token, (…, nb, 1, dk)
+        right = qk.reshape(2, *lead, nb, 2, half, dk)[..., 1, :, :] * jnp.exp(g_b[..., 1, :, :] - ref)
+        left = k.astype(F32).reshape(*lead, nb, 2, half, dk)[..., 0, :, :] * jnp.exp(ref - g_b[..., 0, :, :])
+        quadrant = mm("r...tc,...ic->r...ti", right, left)  # (2, …, nb, half, half)
+        a_ba = quadrant[0] * beta.reshape(*lead, nb, 2, half, 1)[..., 1, :, :]
+        halves = t_inv.reshape(*lead, nb, 2, half, half)
+        t_a, t_b = halves[..., 0, :, :], halves[..., 1, :, :]
+        t_ba = -mm32("...ij,...jk->...ik", t_b, mm32("...ij,...jk->...ik", a_ba, t_a))
+        zeros = jnp.zeros_like(t_ba)
+        t_inv = jnp.concatenate(
+            [jnp.concatenate([t_a, zeros], -1), jnp.concatenate([t_ba, t_b], -1)], -2
+        )
+        lows = p_low.reshape(*lead, nb, 2, half, half)
+        p_low = jnp.concatenate(
+            [jnp.concatenate([lows[..., 0, :, :], zeros], -1),
+             jnp.concatenate([quadrant[1], lows[..., 1, :, :]], -1)], -2
+        )
+        half *= 2
+    t_inv, p_low = t_inv[..., 0, :, :], p_low[..., 0, :, :]  # (…, chunk, chunk)
+    diagonal = jnp.sum(q.astype(F32) * k.astype(F32), -1)  # q_t . k_t: a token reads its own write
+    p = p_low + diagonal[..., None] * jnp.eye(chunk, dtype=F32)
+
+    decay = jnp.exp(big_g)  # from the chunk's start to each token, <= 1
+    to_end = jnp.exp(big_g[..., -1:, :] - big_g)  # from each token to the chunk's end, <= 1
+    w = mm("...ti,...ic->...tc", t_inv, beta * k.astype(F32) * decay)
+    u_free = mm("...ti,...iv->...tv", t_inv, beta * v.astype(F32))
+    q_in = q.astype(F32) * decay
+    k_out = k.astype(F32) * to_end
+    end_decay = decay[..., -1, :]  # (…, dk)
+
+    def across(state, x):  # one chunk: state (rows, h, dk, dv)
+        w_n, u_n, q_n, p_n, k_n, d_n = x
+        u = u_n - mm("...tc,...cv->...tv", w_n, state)
+        out = mm("...tc,...cv->...tv", q_n, state) + mm("...ti,...iv->...tv", p_n, u)
+        state = state * d_n[..., None] + mm("...tc,...tv->...cv", k_n, u)
+        return state, out
+
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (w, u_free, q_in, p, k_out, end_decay))
+    _, out = jax.lax.scan(across, jnp.zeros((rows, h, dk, dv), F32), xs)
+    # (n, rows, h, chunk, dv) -> (rows, s, h, dv)
+    return jnp.transpose(out, (1, 0, 3, 2, 4)).reshape(rows, s, h, dv)
+
+
+def _rows_per_pass(b: int, s: int, h: int, dk: int) -> int:
+    """The most batch rows (a divisor of ``b``) whose float32 (rows, s, h, dk)
+    array stays under ``_PASS_BYTES``."""
+    fit = max(1, _PASS_BYTES // (s * h * dk * 4))
+    return max(r for r in range(1, b + 1) if b % r == 0 and r <= fit)
+
+
+def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, dtype=None):
+    """o of the recurrence above, chunked. q, k: (b, s, h, dk); v: (b, s, h, dv);
+    g: (b, s, h, dk) float32 log-decay <= 0; beta: (b, s, h). ``dtype`` is the
+    operand type of the chunk's matrix products (default: v's); sums, decays,
+    the triangular inverse and the carried state are float32. Returns (b, s, h,
+    dv) in ``dtype``."""
+    if chunk & (chunk - 1):
+        raise ValueError(f"chunk must be a power of two, got {chunk}")
+    dt = jnp.dtype(dtype or v.dtype)
+    b, s, h, dk = q.shape
+    pad = -s % chunk
+    if pad:  # later tokens never reach earlier outputs: zeros at the end are inert
+        q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    core = jax.checkpoint(partial(_chunked, chunk=chunk, dt=dt))
+    rows = _rows_per_pass(b, s + pad, h, dk)
+    if rows == b:
+        out = core(q, k, v, g, beta)
+    else:
+        passes = tuple(x.reshape(b // rows, rows, *x.shape[1:]) for x in (q, k, v, g, beta))
+        out = jax.lax.map(lambda xs: core(*xs), passes).reshape(b, s + pad, h, -1)
+    return out[:, :s].astype(dt)

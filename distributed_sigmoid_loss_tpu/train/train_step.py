@@ -17,6 +17,7 @@ SigLIP step. TPU-native structure:
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import flax.linen as nn
@@ -69,6 +70,11 @@ LOSS_ISLAND_SCOPE = "loss_island"  # the sharded sigmoid loss, forward and backw
 # ACCUM_SCOPE ("accum", models/transformer.py beside the one add that runs in a
 # tower): the gradient accumulator's traffic in the microbatch scan.
 STEP_METRICS_SCOPE = "step_metrics"  # the health scalars every step pays
+# Leaves that decide something discrete and are set by a training recipe's own
+# rule, not by the optimizer (models/moe.py SELECT_BIAS, a router's selection
+# bias): no gradient reaches them, and create_train_state gives them no decay,
+# no update and no optimizer state.
+NO_UPDATE_LEAVES = ("select_bias",)
 
 
 def resolve_loss_quant(model: nn.Module, loss_cfg) -> str:
@@ -297,6 +303,58 @@ def validate_step_args(
                 f"{pipeline_axis!r} axis, got {mesh_axis_names}"
             )
     return cached_accum, acc_dt
+
+
+def no_update_mask(params):
+    """``params``-shaped booleans, False at the NO_UPDATE_LEAVES; None where the
+    tree has none (every model but one with a sigmoid router)."""
+    mask = jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) not in NO_UPDATE_LEAVES, params
+    )
+    return None if all(jax.tree.leaves(mask)) else mask
+
+
+def _dropless_text(model):
+    """The text configuration of a model whose text tower routes without
+    dropping (``moe_router="sigmoid"``), else None."""
+    t = getattr(getattr(model, "cfg", None), "text", None)
+    return t if t is not None and t.moe_experts and t.moe_router == "sigmoid" else None
+
+
+def stack_record_of(t, tokens_shape) -> dict:
+    """What a step with a dropless mixed text stack runs, from shapes alone
+    (``step.stack_record``, read by obs/attribution.py mixed_stack)."""
+    held = t.moe_experts_held or t.moe_experts
+    routed = [i >= t.leading_dense_layers for i in range(t.depth)]
+    mixers = t.mixers or ("attn",) * t.depth
+    tokens = math.prod(tokens_shape)
+    return {
+        "layer_kinds": [f"{m}+{'moe' if r else 'mlp'}" for m, r in zip(mixers, routed)],
+        "experts_held": held, "experts_total": t.moe_experts,
+        "experts_per_token": t.moe_num_selected,
+        "expected_local_assignments_per_token": t.moe_num_selected * held / t.moe_experts,
+        "tokens_per_microbatch": tokens,
+        # The sort's rows, the true worst case: every token picks held experts only.
+        "dispatch_rows_bound": tokens * t.moe_num_selected,
+    }
+
+
+def _route_load(variables) -> dict:
+    """The sown ``moe_load`` of every dropless layer (models/moe.py) as the
+    metrics line's counters: assignments to experts held here, the fullest and
+    the mean held expert's tokens, assignments that did not run (always 0)."""
+    leaves = [
+        (getattr(path[-1], "key", None), leaf)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(variables.get("intermediates", {}))
+        if any(getattr(k, "key", None) == "moe_load" for k in path)
+    ]
+    tokens = jnp.stack([x for k, x in leaves if k == "tokens"]).astype(jnp.float32)
+    return {
+        "moe_local_assignments": tokens.sum(),
+        "moe_max_expert_tokens": tokens.max(),
+        "moe_mean_expert_tokens": tokens.mean(),
+        "moe_dropped_tokens": sum(x for k, x in leaves if k == "dropped").astype(jnp.float32),
+    }
 
 
 def _tree_bytes(tree) -> int:
@@ -625,6 +683,12 @@ def create_train_state(
     # param shardings — or their update-shard placement — and scalar counters
     # replicate) is committed to the mesh — required for sharding-stable
     # checkpoint restore.
+    frozen = no_update_mask(params)
+    if frozen is not None:
+        # Only the other leaves reach the optimizer: a masked-out leaf has no
+        # state, and its update is its gradient, which is zero.
+        tx = optax.masked(tx, frozen)
+
     def create(p):
         state = TrainState.create(apply_fn=model.apply, params=p, tx=tx)
         if mode != "off":
@@ -806,14 +870,20 @@ def make_train_step(
         validate_pp_tower(model.cfg.vision, pp_stages, "vision")
         validate_pp_tower(model.cfg.text, pp_stages, "text")
 
+    dropless = _dropless_text(model)
+    stack_record: dict = {}
+
     def loss_fn(params, batch, sink=None):
-        """``loss, (lp, aux, the sink as the model hands it back)``."""
+        """``loss, (lp, aux, the sink as the model hands it back, the routed
+        layers' load counters)``."""
         variables, mutable = {"params": params}, []
         if sink:
             variables[GRAD_SINK] = sink
             mutable.append(GRAD_SINK)
-        if moe_aux_weight is not None:
+        if moe_aux_weight is not None or dropless is not None:
             mutable.append("intermediates")
+        if dropless is not None:
+            stack_record.update(stack_record_of(dropless, batch["tokens"].shape))
         updated = {}
         if pp_microbatches:
             zimg, ztxt, lp = siglip_forward_pp(
@@ -833,7 +903,8 @@ def make_train_step(
             loss = sharded_loss(zimg, ztxt, lp["t_prime"], lp["bias"])
         if moe_aux_weight is not None:
             loss = loss + moe_aux_weight * aux
-        return loss, (lp, aux, updated.get(GRAD_SINK, {}))
+        load = _route_load(updated) if dropless is not None and not pp_microbatches else {}
+        return loss, (lp, aux, updated.get(GRAD_SINK, {}), load)
 
     # accum_negatives="global": the stacked-embedding loss island. Each device
     # sees its LOCAL rows of every microbatch (M, mb/dp, d) and flattens them
@@ -883,16 +954,16 @@ def make_train_step(
             # The optimized objective includes the aux term; report the same
             # loss the other paths do (metrics, divergence check, A/B curves).
             loss = loss + moe_aux_weight * mean_aux
-        return loss, lp, mean_aux, grads
+        return loss, lp, mean_aux, grads, {}
 
     def grads_and_metrics(params, batch):
         if cached_accum:
             return grads_and_metrics_cached(params, batch)
         if accum_steps == 1:
-            (loss, (lp, aux, _)), grads = jax.value_and_grad(
+            (loss, (lp, aux, _, load)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params, batch)
-            return loss, lp, aux, grads
+            return loss, lp, aux, grads, load
 
         # Interleaved per-device-chunk split (parallel/microbatch.py): the
         # reshuffle is layout-only, no cross-device all-to-all. Microbatch
@@ -926,32 +997,36 @@ def make_train_step(
             loss_sum, in_loop, outside = carry
 
             def through_sink(p, sink):
-                loss, (lp, aux, sink) = loss_fn(p, mb, sink)
-                return (loss, sink), (lp, aux)
+                loss, (lp, aux, sink, load) = loss_fn(p, mb, sink)
+                return (loss, sink), (lp, aux, load)
 
-            (loss, _), grads_of, (lp, aux) = jax.vjp(
+            (loss, _), grads_of, (lp, aux, load) = jax.vjp(
                 through_sink, params, in_loop, has_aux=True
             )
             grads, in_loop = grads_of((jnp.ones_like(loss), in_loop))
             if in_loop:
                 grads = split_grad_sink(grads)[1]
             carry = (loss_sum + loss, in_loop, accum_add(outside, grads))
-            return carry, (lp, aux)
+            return carry, (lp, aux, load)
 
-        (loss_sum, in_loop, outside), (lps, auxs) = lax.scan(
+        (loss_sum, in_loop, outside), (lps, auxs, loads) = lax.scan(
             body, (jnp.zeros(()), in_loop, outside), micro
         )
         lp = jax.tree.map(lambda x: x[-1], lps)
         grads = accum_finish(
             merge_grad_sink(in_loop, outside), params, scale=accum_steps
         )
-        return loss_sum / accum_steps, lp, jnp.mean(auxs), grads
+        # Over the microbatches: counts add up, the fullest expert is the fullest
+        # of any microbatch, the mean is the mean.
+        over = {"moe_max_expert_tokens": jnp.max, "moe_mean_expert_tokens": jnp.mean}
+        load = {k: over.get(k, jnp.sum)(v) for k, v in loads.items()}
+        return loss_sum / accum_steps, lp, jnp.mean(auxs), grads, load
 
     def step(state: TrainState, batch: dict, param_out_shardings=None):
         # Traced on the mesh: the towers' fused attention kernels must know
         # which axes shard their operands (parallel/mesh.py trace_on).
         with trace_on(mesh):
-            loss, lp, aux, grads = grads_and_metrics(state.params, batch)
+            loss, lp, aux, grads, load = grads_and_metrics(state.params, batch)
         prev_step = state.step  # apply_gradients increments; EMA warmup wants
         prev_params = state.params  # update_ratio needs the pre-update tree
         # The shared update-shard recipe (parallel/update_shard.py): plain
@@ -982,6 +1057,7 @@ def make_train_step(
         metrics = health_metrics(loss, lp, grads, state.params, prev_params)
         if moe_aux_weight is not None:
             metrics["moe_aux"] = aux
+        metrics.update(load)
         return state, metrics
 
     batch_sharding = {
@@ -990,7 +1066,7 @@ def make_train_step(
     }
     if update_mode != "full":
         jitted = jax.jit(step, donate_argnums=(0,))
-        jitted.accum_record = accum_record
+        jitted.accum_record, jitted.stack_record = accum_record, stack_record
         return jitted, batch_sharding
 
     # Full mode: the publish constraint needs the params' at-rest shardings,
@@ -1019,5 +1095,5 @@ def make_train_step(
     # AOT path (bench.py's step.lower(...).compile()): same capture, same
     # single inner jit — lowering and calling share one executable.
     sharded_step.lower = lambda state, batch: _inner(state).lower(state, batch)
-    sharded_step.accum_record = accum_record
+    sharded_step.accum_record, sharded_step.stack_record = accum_record, stack_record
     return sharded_step, batch_sharding

@@ -166,14 +166,59 @@ class TextConfig:
     # False drops the bias of the blocks' attention and MLP projections.
     use_bias: bool = True
     # "rope" = rotary positions on q and k (rotate-half convention, positions
-    # 0..s-1, base rope_theta) in place of the learned ``pos_embed`` table.
-    pos: Literal["learned", "rope"] = "learned"
+    # 0..s-1, base rope_theta) in place of the learned ``pos_embed`` table;
+    # "none" = neither (causal mixers carry the order).
+    pos: Literal["learned", "rope", "none"] = "learned"
     rope_theta: float = 10000.0
     # > 1 runs the whole stack (its ``depth`` layers, then the final norm) this
     # many times on ONE set of weights, each pass feeding the next; the
     # embedding is pooled from the last pass. Each weight's gradient is the sum
     # over its uses.
     loops: int = 1
+    # The normalisations' epsilon (LayerNorm and RMSNorm alike).
+    norm_eps: float = 1e-6
+    # A stack of several layer kinds in one order: the token mixer of each of
+    # the ``depth`` layers, "attn" (the block's softmax attention), "kda" (a
+    # chunked gated delta rule behind a short causal convolution,
+    # ops/gated_delta_rule.py) or "mla" (latent attention: keys and values
+    # expanded from one low-rank latent, a key part shared by all heads, value
+    # heads narrower than key heads; models/mixers.py). Empty = "attn" in every
+    # layer, today's stack. A mixed stack is causal, takes ``pos="none"``, and
+    # runs its layers unrolled with remat per layer: no two neighbours share a
+    # parameter tree to scan over, so ``scan_layers`` does not apply to it.
+    mixers: tuple[str, ...] = ()
+    # The first layers keep the dense MLP where ``moe_experts > 0``.
+    leading_dense_layers: int = 0
+    # "kda": key and value head size (``num_heads`` heads; the low-rank gates
+    # pass through this many channels too) and the convolution's kernel size.
+    kda_head_dim: int = 128
+    kda_conv_size: int = 4
+    # "mla": per head a key part expanded from the latent and a key part shared
+    # by all heads (queries are their sum wide), the value head, the latent.
+    mla_qk_nope_dim: int = 128
+    mla_qk_shared_dim: int = 64
+    mla_v_dim: int = 128
+    mla_kv_rank: int = 512
+    # "sigmoid" = the router of the latent-attention language models: scores
+    # sigmoid(x Wr) in float32, the ``moe_num_selected`` largest of scores + a
+    # selection bias (a leaf that takes no gradient, decay or optimizer state),
+    # weights = the chosen scores renormalised x ``moe_route_scale``; bias-free
+    # SwiGLU experts ``moe_hidden`` wide, ``moe_shared_experts`` more that every
+    # token runs; dispatched by a sort over the held experts' assignments with
+    # no capacity: no token is dropped whatever the imbalance. "softmax" is the
+    # capacity-dropping GShard layer (k in {1, 2}, biased GELU experts).
+    moe_router: Literal["softmax", "sigmoid"] = "softmax"
+    moe_route_scale: float = 1.0
+    moe_shared_experts: int = 0
+    moe_hidden: int = 0  # 0 = round(width * mlp_ratio), the dense MLP's
+    # The chip's share of the routed experts, experts 0..held-1 (0 = all): the
+    # router keeps its ``moe_experts`` outputs and its top-k, this chip computes
+    # its own experts' part and leaves out what the absent ones would add.
+    moe_experts_held: int = 0
+
+    def __post_init__(self):
+        # A configuration file gives a list; modules hash their configuration.
+        object.__setattr__(self, "mixers", tuple(self.mixers))
 
     @classmethod
     def base(cls, **kw) -> "TextConfig":
@@ -192,7 +237,9 @@ class TextConfig:
 # models/hf_import.py) refuses any other value by the option's name.
 BLOCK_OPTIONS = {
     "norm": "layernorm", "sandwich_norm": False, "mlp": "gelu", "use_bias": True,
-    "pos": "learned", "loops": 1,
+    "pos": "learned", "loops": 1, "norm_eps": 1e-6, "mixers": (),
+    "leading_dense_layers": 0, "moe_router": "softmax", "moe_route_scale": 1.0,
+    "moe_shared_experts": 0, "moe_hidden": 0, "moe_experts_held": 0,
 }
 
 

@@ -379,3 +379,34 @@ def test_eval_real_data_shards(tmp_path):
     metrics = eval(proc.stdout.strip().splitlines()[-1])
     assert "i2t_recall@1" in metrics, metrics
     assert any(k.startswith("zeroshot") for k in metrics), metrics
+
+
+def test_train_a_mixed_text_stack_from_a_configuration_file(tmp_path):
+    """--model-config: a text tower of several layer kinds with dropless routed
+    experts (tests/test_hybrid_tower.py has the toy configuration) through
+    `train` with accumulation on two data shards: finite losses, the routing
+    counters in every metrics line, nothing dropped."""
+    import dataclasses
+
+    from distributed_sigmoid_loss_tpu.utils.config import TextConfig, ViTConfig
+
+    text = dataclasses.replace(
+        TextConfig.tiny_test(), context_length=16, depth=5, causal=True, pool="last", norm="rmsnorm",
+        norm_eps=1e-5, mlp="swiglu", use_bias=False, pos="none", mixers=("kda", "kda", "kda", "mla", "kda"),
+        leading_dense_layers=1, kda_head_dim=16, mla_qk_nope_dim=16, mla_qk_shared_dim=8, mla_v_dim=16,
+        mla_kv_rank=12, moe_experts=16, moe_num_selected=4, moe_router="sigmoid", moe_route_scale=2.446,
+        moe_shared_experts=1, moe_hidden=24, moe_experts_held=4,
+    )
+    path = tmp_path / "tiny-hybrid.json"
+    path.write_text(json.dumps({
+        "vision": dataclasses.asdict(ViTConfig.tiny_test()), "text": dataclasses.asdict(text), "loss": {},
+    }))
+    proc = _run(["train", "--cpu-devices", "2", "--model-config", str(path), "--steps", "3",
+                 "--batch", "8", "--accum", "2"])
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.startswith("{")]
+    assert [l["step"] for l in lines] == [1, 2, 3]
+    assert all(l["moe_dropped_tokens"] == 0 and l["moe_local_assignments"] > 0 for l in lines)
+    assert lines[-1]["loss"] < lines[0]["loss"]
+    clash = _run(["train", "--tiny", "--model-config", str(path)])
+    assert clash.returncode != 0 and "--model-config conflicts" in clash.stderr

@@ -1,0 +1,237 @@
+"""The layers a mixed text stack brings (tests/test_hybrid_tower.py has the tower):
+the chunked gated delta rule against the token-by-token recurrence, latent attention
+against the plain reference (``benchmark/reference_kimi.py``) and its padded-value
+path, the sigmoid router's rules, the dropless dispatch, and a chip's share of the
+experts against the whole layer."""
+
+import os
+import sys
+import types
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_sigmoid_loss_tpu.models.mixers import LatentAttention, pad_heads_to_one_size
+from distributed_sigmoid_loss_tpu.models.moe import (
+    SELECT_BIAS,
+    SharedExpertMoe,
+    dispatch_plan,
+    sigmoid_route,
+)
+from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import (
+    chunk_gated_delta_rule,
+    gated_delta_rule_recurrent,
+)
+from distributed_sigmoid_loss_tpu.parallel.ring_attention import dense_attention
+
+BENCH_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+if BENCH_DIR not in sys.path:
+    sys.path.insert(0, BENCH_DIR)
+
+import reference_kimi  # noqa: E402
+
+
+def moved(params, scale=0.05):
+    """Every leaf away from its initial value (norm scales start at one, the
+    selection bias at zero): a dropped scale or a dropped leaf then shows."""
+    leaves, tree = jax.tree.flatten(nn.meta.unbox(params))
+    keys = jax.random.split(jax.random.key(3), len(leaves))
+    return jax.tree.unflatten(tree, [
+        x + scale * jax.random.normal(k, x.shape, x.dtype) if x.ndim else x for x, k in zip(leaves, keys)
+    ])
+
+
+# -- (a) the chunked delta rule against the recurrence ----------------------------
+
+
+def delta_rule_inputs(s, dtype, b=2, h=3, dk=16, dv=8):
+    ks = jax.random.split(jax.random.key(s), 5)
+    q, k = (jax.random.normal(key, (b, s, h, dk)) for key in ks[:2])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk**-0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, s, h, dv))
+    # log-decays from 2.5e-3 to 33 a token: slow channels, and channels that
+    # forget within a chunk (a quotient of decays would overflow there)
+    g = -jnp.exp(jax.random.uniform(ks[3], (b, s, h, dk), minval=-6.0, maxval=3.5))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    return tuple(x.astype(dtype) for x in (q, k, v)) + (g, beta)
+
+
+# bf16: the operands of the chunk's products are rounded to 8 bits (2^-9 relative
+# each) and summed in float32; measured here 4e-3 to 9e-3 forward, to 2.4e-2 on
+# the gradients over these seeds.
+@pytest.mark.parametrize("dtype, bound", [(jnp.float32, 1e-5), (jnp.bfloat16, 5e-2)], ids=["f32", "bf16"])
+@pytest.mark.parametrize("chunks", [1, 2, 5])
+def test_the_chunked_delta_rule_is_the_recurrence(chunks, dtype, bound):
+    chunk = 8
+    args = delta_rule_inputs(chunks * chunk, dtype)
+    weight = jnp.cos(jnp.arange(2 * chunks * chunk * 3 * 8, dtype=jnp.float32)).reshape(2, -1, 3, 8)
+
+    def grads(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (fn(*a).astype(jnp.float32) * weight).sum(), argnums=(0, 1, 2, 3, 4)
+        ))(*args)
+
+    want_out = jax.jit(gated_delta_rule_recurrent)(*args)
+    got_out = jax.jit(lambda *a: chunk_gated_delta_rule(*a, chunk=chunk))(*args)
+    assert got_out.dtype == dtype and bool(jnp.isfinite(got_out.astype(jnp.float32)).all())
+    assert reference_kimi._base.max_rel_err(got_out, want_out) < bound
+    (_, got), (_, want) = grads(lambda *a: chunk_gated_delta_rule(*a, chunk=chunk)), grads(gated_delta_rule_recurrent)
+    for name, g, w in zip("q k v g beta".split(), got, want):
+        assert reference_kimi._base.max_rel_err(g, w) < bound, name
+
+
+def test_a_sequence_that_is_no_multiple_of_the_chunk_and_a_batch_run_in_passes(monkeypatch):
+    from distributed_sigmoid_loss_tpu.ops import gated_delta_rule
+
+    args = delta_rule_inputs(20, jnp.float32, b=4)
+    want = gated_delta_rule_recurrent(*args)
+    assert reference_kimi._base.max_rel_err(chunk_gated_delta_rule(*args, chunk=8), want) < 1e-5
+    monkeypatch.setattr(gated_delta_rule, "_PASS_BYTES", 24 * 3 * 16 * 4 * 2)  # two rows a pass
+    assert gated_delta_rule._rows_per_pass(4, 24, 3, 16) == 2
+    assert reference_kimi._base.max_rel_err(chunk_gated_delta_rule(*args, chunk=8), want) < 1e-5
+
+
+# -- (b) latent attention ----------------------------------------------------------
+
+
+def test_latent_attention_at_heads_of_192_and_128_matches_the_reference():
+    t = types.SimpleNamespace(num_heads=2, mla_qk_nope_dim=128, mla_qk_shared_dim=64, mla_v_dim=128,
+                              mla_kv_rank=32, norm_eps=1e-5)
+    layer = LatentAttention(48, 2, 128, 64, 128, 32, jnp.float32)
+    x = jax.random.normal(jax.random.key(0), (2, 12, 48), jnp.float32)
+    params = moved(layer.init(jax.random.key(1), x)["params"])
+    assert params["q"]["kernel"].shape == (48, 2 * 192) and params["kv_a"]["kernel"].shape == (48, 32 + 64)
+    assert params["kv_b"]["kernel"].shape == (32, 2 * 256) and params["out"]["kernel"].shape == (2 * 128, 48)
+    with jax.default_matmul_precision("highest"):
+        got = layer.apply({"params": params}, x)
+        want = reference_kimi.mla(x, params, t)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # causal: a later token does not reach an earlier output
+    later = layer.apply({"params": params}, x.at[:, 8:].add(1.0))
+    np.testing.assert_array_equal(got[:, :8], later[:, :8])
+
+
+def test_the_padded_value_path_equals_the_unpadded():
+    keys = jax.random.split(jax.random.key(5), 3)
+    q, k = (jax.random.normal(key, (2, 12, 3, 192)) for key in keys[:2])
+    v = jax.random.normal(keys[2], (2, 12, 3, 128))
+    scale = 192**-0.5
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    scores = jnp.where(jnp.tril(jnp.ones((12, 12), bool)), scores, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
+    seen = {}
+
+    def attend(q, k, v):  # a core that takes one head size, as the fused kernels do
+        seen["shapes"] = (q.shape[-1], k.shape[-1], v.shape[-1])
+        return dense_attention(q, k, v, causal=True, scale=scale)
+
+    got = pad_heads_to_one_size(attend, q, k, v, multiple=128)
+    assert seen["shapes"] == (256, 256, 256) and got.shape == want.shape
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    pad_heads_to_one_size(attend, q, k, v)
+    assert seen["shapes"] == (192, 192, 192)
+
+
+# -- (c) routing -------------------------------------------------------------------
+
+
+def test_selection_is_by_score_plus_bias_and_weights_are_by_score():
+    x = jax.random.normal(jax.random.key(0), (32, 8))
+    wr = jax.random.normal(jax.random.key(1), (8, 16))
+    scores = jax.nn.sigmoid(x @ wr)
+    idx0, w0 = sigmoid_route(x, wr, jnp.zeros(16), 4, 2.446)
+    np.testing.assert_array_equal(np.sort(idx0, -1), np.sort(np.argsort(-scores, -1)[:, :4], -1))
+    np.testing.assert_allclose(w0.sum(-1), 2.446, rtol=1e-6)  # renormalised, then scaled
+    np.testing.assert_allclose(w0, 2.446 * np.take_along_axis(np.asarray(scores), np.asarray(idx0), -1)
+                               / np.take_along_axis(np.asarray(scores), np.asarray(idx0), -1).sum(-1, keepdims=True), rtol=1e-6)
+    # a bias on expert 7 puts it into every token's set and leaves the scores that weigh alone
+    bias = jnp.zeros(16).at[7].set(10.0)
+    idx1, w1 = sigmoid_route(x, wr, bias, 4, 2.446)
+    assert bool((idx1 == 7).any(-1).all()) and not bool((idx0 == 7).any(-1).all())
+    chosen = np.take_along_axis(np.asarray(scores), np.asarray(idx1), -1)
+    np.testing.assert_allclose(w1, 2.446 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-6)
+    # no gradient reaches the bias
+    grad = jax.grad(lambda b: sigmoid_route(x, wr, b, 4, 2.446)[1].sum())(bias)
+    assert not np.asarray(grad).any()
+
+
+@pytest.fixture(autouse=True)
+def eight_rows_a_block(monkeypatch):
+    """The toy layers cut an expert's segment into blocks of 8 rows (the cell's
+    512 would hold every toy expert in one): a loaded expert runs several."""
+    import sys
+
+    monkeypatch.setattr(sys.modules["distributed_sigmoid_loss_tpu.models.moe"], "BLOCK_ROWS", 8)
+
+
+def routed_layer(held, first=0, shared=1, experts=16, k=4):
+    return SharedExpertMoe(8, 12, experts, k, jnp.float32, route_scale=2.446, shared_experts=shared,
+                           experts_held=held, first_held=first)
+
+
+def test_nothing_is_dropped_when_every_token_picks_one_held_expert():
+    layer = routed_layer(held=4)
+    x = jax.random.normal(jax.random.key(0), (3, 20, 8))
+    params = moved(layer.init(jax.random.key(1), x)["params"])
+    # expert 2 is in every token's set: 60 tokens on one expert of four, eight rows a block
+    params[SELECT_BIAS] = jnp.zeros(16).at[2].set(10.0)
+    y, state = layer.apply({"params": params}, x, mutable=["intermediates"])
+    load = state["intermediates"]["moe_load"][0]
+    assert int(load["tokens"][2]) == 60 and int(load["dropped"]) == 0
+    t = types.SimpleNamespace(moe_num_selected=4, moe_route_scale=2.446)
+    np.testing.assert_allclose(y, reference_kimi.moe(x, params, t), atol=2e-5)
+
+
+def test_the_shared_expert_alone_answers_where_no_held_expert_is_chosen():
+    layer = routed_layer(held=4)
+    x = jax.random.normal(jax.random.key(0), (2, 10, 8))
+    params = moved(layer.init(jax.random.key(1), x)["params"])
+    params[SELECT_BIAS] = jnp.zeros(16).at[:4].set(-10.0)  # the held experts are never chosen
+    y, state = layer.apply({"params": params}, x, mutable=["intermediates"])
+    assert not np.asarray(state["intermediates"]["moe_load"][0]["tokens"]).any()
+    s = params["shared"]
+    shared = reference_kimi.swiglu(x, s["wg"]["kernel"], s["wi"]["kernel"], s["wo"]["kernel"])
+    np.testing.assert_allclose(y, shared, atol=1e-6)
+    # and the gradient of a routed layer with nothing routed here is the shared expert's
+    grads = jax.grad(lambda p: layer.apply({"params": p}, x).sum())(params)
+    assert not np.asarray(grads["wg"]).any() and np.asarray(grads["shared"]["wg"]["kernel"]).any()
+
+
+def test_the_sorted_plan_lists_every_assignment_to_a_held_expert_once():
+    idx = jnp.array([[0, 5, 9], [5, 1, 2], [9, 8, 5], [3, 5, 0]])
+    weights = jnp.arange(12, dtype=jnp.float32).reshape(4, 3)
+    token, row_weight, starts, counts = dispatch_plan(idx, weights, first=4, held=4)  # experts 4..7
+    np.testing.assert_array_equal(counts, [0, 4, 0, 0])
+    np.testing.assert_array_equal(starts, [0, 0, 4, 4])
+    np.testing.assert_array_equal(token[:4], [0, 1, 2, 3])
+    np.testing.assert_array_equal(row_weight[:4], [1.0, 3.0, 8.0, 10.0])
+
+
+# -- (d) the share ties to the model -------------------------------------------------
+
+
+def test_four_shares_of_four_experts_and_the_shared_expert_once_are_the_whole_layer():
+    """16 experts over 4 chips: each share routes over all 16 and computes its 4;
+    the routed parts add up, with the shared expert counted once, to what the
+    reference gives for the layer with all 16 held."""
+    whole = routed_layer(held=0)
+    x = jax.random.normal(jax.random.key(0), (2, 24, 8))
+    params = moved(whole.init(jax.random.key(1), x)["params"])
+    t = types.SimpleNamespace(moe_num_selected=4, moe_route_scale=2.446)
+    with jax.default_matmul_precision("highest"):
+        want = reference_kimi.moe(x, params, t)
+        s = params["shared"]
+        shared = reference_kimi.swiglu(x, s["wg"]["kernel"], s["wi"]["kernel"], s["wo"]["kernel"])
+        total, seen = shared, 0
+        for share in range(4):
+            part = dict(params, **{n: params[n][4 * share : 4 * share + 4] for n in ("wg", "wi", "wo")})
+            y, state = routed_layer(held=4, first=4 * share).apply({"params": part}, x, mutable=["intermediates"])
+            total = total + (y - shared)
+            seen += int(state["intermediates"]["moe_load"][0]["tokens"].sum())
+        np.testing.assert_allclose(whole.apply({"params": params}, x), want, atol=2e-5)
+    assert seen == 2 * 24 * 4  # every assignment ran on exactly one share
+    np.testing.assert_allclose(total, want, atol=3e-5)

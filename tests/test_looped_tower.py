@@ -302,7 +302,12 @@ def test_causal_short_attention_at_heads_of_128_matches_dense():
 
 # -- code that re-implements or maps the SigLIP block refuses another ------------
 
-CHANGED = dict(norm="rmsnorm", sandwich_norm=True, mlp="swiglu", use_bias=False, pos="rope", loops=4)
+CHANGED = dict(
+    norm="rmsnorm", sandwich_norm=True, mlp="swiglu", use_bias=False, pos="rope", loops=4,
+    # the options of a stack of several layer kinds (tests/test_hybrid_tower.py)
+    norm_eps=1e-5, mixers=("kda", "mla"), leading_dense_layers=1, moe_router="sigmoid",
+    moe_route_scale=2.446, moe_shared_experts=1, moe_hidden=24, moe_experts_held=4,
+)
 
 
 def test_every_block_option_has_a_refusal_case():
