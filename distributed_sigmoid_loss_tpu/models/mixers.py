@@ -38,7 +38,9 @@ L2_EPS = 1e-6
 # flax writes the modules' own names ("kda", "mla") into every operation's path.
 KDA_CORE_SCOPE = "kda_core"  # the recurrence alone, inside "kda"
 MLA_CORE_SCOPE = "mla_core"  # scores, softmax and values, inside "mla"
-CHUNK = 64  # tokens a chunk of the delta rule: one MXU tile of intra-chunk products
+# Tokens a chunk of the delta rule: what one program of the kernels (ops/pallas_delta_rule.py)
+# holds in VMEM per head, six halving levels and a 64 x 64 float32 inverse; the XLA form's too.
+CHUNK = 64
 
 
 def l2norm(x):

@@ -56,9 +56,10 @@ def _dot_general(quant):
     return int8_dot_general
 
 
-def _fused_attention_per_shard(kernel, q, k, v):
-    """Run a fused (Mosaic) attention ``kernel`` on (b, s, h, dh) arrays that a
-    surrounding ``jit`` may have sharded over several chips.
+def _fused_attention_per_shard(kernel, q, k, v, *more):
+    """Run a fused (Mosaic) attention ``kernel`` on (b, s, h, dh) arrays (and
+    ``more`` per-head operands, (b, s, h, ...)) that a surrounding ``jit`` may
+    have sharded over several chips.
 
     A Mosaic kernel is opaque to the SPMD partitioner: where the trace's mesh
     (``jax.sharding.get_abstract_mesh()`` — the step builders trace under
@@ -78,16 +79,17 @@ def _fused_attention_per_shard(kernel, q, k, v):
     mesh = jax.sharding.get_abstract_mesh()
     auto = frozenset(mesh.axis_names) - frozenset(mesh.manual_axes)
     if not auto or mesh.size == 1:
-        return kernel(q, k, v)
+        return kernel(q, k, v, *more)
 
     def split(axis, n):
         return axis if axis in auto and n % mesh.shape[axis] == 0 else None
 
     spec = P(split(DP_AXIS, q.shape[0]), None, split(TP_AXIS, q.shape[2]), None)
+    operands = (q, k, v, *more)
     return jax.shard_map(
-        kernel, in_specs=(spec, spec, spec), out_specs=spec,
+        kernel, in_specs=tuple(P(*spec[:x.ndim]) for x in operands), out_specs=spec,
         axis_names=auto, check_vma=False,
-    )(q, k, v)
+    )(*operands)
 
 
 def _remat_policy(name: str):

@@ -294,8 +294,11 @@ def mixed_stack(step) -> dict | None:
     ``make_train_step`` writes while it traces (``step.stack_record``): the
     layers' kinds in order (``mixer+mlp`` / ``mixer+moe``), experts held / in
     all / per token, the assignments to held experts a token is expected to
-    make under uniform routing, the tokens of a microbatch and the rows the
-    dispatch is bounded by (every token choosing held experts only). None for a
+    make under uniform routing, the tokens of a microbatch, the rows the
+    dispatch is bounded by (every token choosing held experts only) and, per
+    delta-rule layer (``kda_core``, by layer index), which core it took
+    (``"kernel"``: the Pallas kernels; ``"chunked"``: XLA operations) with the
+    rows, heads and chunks of a call. None for a
     step that has not traced yet or runs no such tower."""
     return dict(getattr(step, "stack_record", None) or {}) or None
 

@@ -33,13 +33,28 @@ triangular I + A grows in the same sweep, [[Ta, 0], [-Tb A_ba Ta, Tb]] from the
 halves' inverses, in float32 (a Neumann series would cancel catastrophically
 where keys repeat).
 
-The backward pass is ``jax.grad``'s through this, recomputed (``jax.checkpoint``)
-and run a few batch rows at a time (``lax.map``), so that what lives at once is
-a pass's intermediates and not a layer's. On the chip (PERF.md section 5) this
-form is far from its roofline: every level's scaled operands cross HBM in many
-small operations, where a kernel would keep a chunk in VMEM. (All six levels as
-one masked batched product were tried: fewer operations, 20 % slower, three
-times the generated code.)
+**Which call takes which path** (:func:`delta_rule_core`, from what a call can
+see: no option). Operands in bfloat16 on a TPU with dk = dv a multiple of 128
+lanes run the chunk's work in two Pallas kernels (ops/pallas_delta_rule.py,
+``kda_fwd`` / ``kda_bwd``): a chunk of a few heads stays in VMEM from q, k, v,
+g, beta to o, the state crosses chunks in VMEM scratch along a sequential grid
+axis, the operands are read where they lie ((b, s, h x d), a head an aligned
+128-lane window), and the backward is a kernel of its own that recomputes a
+chunk from the saved operands and the chunk's incoming state (which the
+differentiated forward writes: float32, 64 KB a chunk-head). The same halving,
+the same block recursion for the inverse, the same operand types: bf16-grade
+gradients, as the fused attention kernels', hence a bf16 tower only. What stays
+in XLA around them: the padding of a sequence that is no multiple of the chunk
+and the transposition of beta (2 MB). Under a ``jit`` over a mesh the kernels
+sit in the ``shard_map`` the attention kernels use. Every other call (float32
+operands, the CPU, a head size that is no multiple of 128) takes
+:func:`_chunked` below: XLA operations, its backward ``jax.grad``'s, recomputed
+(``jax.checkpoint``) and run a few batch rows at a time (``lax.map``). It is
+the oracle next to :func:`gated_delta_rule_recurrent`; on the chip it is a
+hundred passes over HBM a call (PERF.md section 6, PR 32 and 34: at 16 rows x
+1024 tokens x 32 heads 46.4 ms forward and 151.3 forward + backward where the
+kernels take 10.9 and 26.9). (All six levels as one masked batched product were
+tried there: fewer operations, 20 % slower, three times the generated code.)
 """
 
 from __future__ import annotations
@@ -49,7 +64,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-__all__ = ["gated_delta_rule_recurrent", "chunk_gated_delta_rule", "short_causal_conv"]
+__all__ = [
+    "gated_delta_rule_recurrent", "chunk_gated_delta_rule", "delta_rule_core", "short_causal_conv",
+]
 
 F32 = jnp.float32
 # Float32 intermediates of one pass of the chunked form, in bytes per array: the
@@ -167,6 +184,23 @@ def _rows_per_pass(b: int, s: int, h: int, dk: int) -> int:
     return max(r for r in range(1, b + 1) if b % r == 0 and r <= fit)
 
 
+def delta_rule_core(rows: int, tokens: int, heads: int, dk: int, dv: int, dtype, chunk: int = 64) -> dict:
+    """Which core a call of :func:`chunk_gated_delta_rule` takes, from what it
+    can see, and the sizes of the call: ``core`` is ``"kernel"`` (the Pallas
+    kernels: bfloat16 operands, a TPU backend, dk = dv a multiple of 128, as
+    ``Attention`` and ``LatentAttention`` choose their fused kernels) or
+    ``"chunked"``. The mixer runs what this says and the step's trace-time
+    record (``train_step.stack_record_of``) reports it."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention  # the towers' one question about the backend
+
+    kernel = (
+        jnp.dtype(dtype) == jnp.bfloat16 and flash_attention.flash_attention_available()
+        and dk == dv and dk % 128 == 0
+    )
+    return {"core": "kernel" if kernel else "chunked", "rows": rows, "heads": heads,
+            "chunks": -(-tokens // chunk)}
+
+
 def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, dtype=None):
     """o of the recurrence above, chunked. q, k: (b, s, h, dk); v: (b, s, h, dv);
     g: (b, s, h, dk) float32 log-decay <= 0; beta: (b, s, h). ``dtype`` is the
@@ -181,6 +215,15 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, dtype=None):
     if pad:  # later tokens never reach earlier outputs: zeros at the end are inert
         q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v, g))
         beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    if delta_rule_core(b, s, h, dk, v.shape[-1], dt, chunk)["core"] == "kernel":
+        from distributed_sigmoid_loss_tpu.models.transformer import _fused_attention_per_shard
+        from distributed_sigmoid_loss_tpu.ops.pallas_delta_rule import delta_rule_kernel
+
+        # a Mosaic kernel under a multi-chip jit sits in a shard_map
+        out = _fused_attention_per_shard(
+            partial(delta_rule_kernel, chunk=chunk), q.astype(dt), k.astype(dt), v.astype(dt), g, beta
+        )
+        return out[:, :s]
     core = jax.checkpoint(partial(_chunked, chunk=chunk, dt=dt))
     rows = _rows_per_pass(b, s + pad, h, dk)
     if rows == b:

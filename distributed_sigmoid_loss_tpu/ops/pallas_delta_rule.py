@@ -1,0 +1,444 @@
+"""The gated delta rule's chunked form (ops/gated_delta_rule.py has the
+equations) as two Pallas TPU kernels: a chunk of C tokens of a few heads lives in
+VMEM from its operands to its output, the state crosses chunks in VMEM scratch
+along a sequential axis of the grid, and HBM sees q, k, v, g, beta, o (and, for
+the backward, each chunk's incoming state) once.
+
+One program = one batch row, ``heads`` heads (a static loop over aligned lane
+windows of the (1, C, heads x d) blocks, as ``short_attn_fwd`` cuts its heads),
+one chunk. The grid is (rows, head groups, chunks), chunks innermost and in order
+(the backward walks them last to first). Per head the program holds what
+``_chunked`` holds per chunk:
+
+- the running sum G of g and G_C - G as one product of a 0/1 matrix with g
+  (:func:`_decay_ops`; g is float32, so the product takes it in three bf16 pieces,
+  exact because the matrix is 0/1, and adds in float32), and per halving level
+  the exponent of each token against its block's reference token, G_t - G_ref or
+  G_ref - G_t, the reference rows broadcast along sublanes
+  (:func:`_level_exponents`): ``_chunked``'s differences, ``_chunked``'s precision;
+- A and P by the same halving as ``_chunked`` (no quotient of decays: both
+  factors of a level are exponentials of sums of g <= 0), each level one
+  (2C x dk) @ (dk x C) product of operands in the tower's dtype, of which the
+  level's quadrants are kept by a mask (:func:`_levels`);
+- (I + A)^-1 by the same block recursion, float32 at the MXU's full precision:
+  with T block-diagonal at level l, T <- T - T (A_l T) is all of that level's
+  [[Ta, 0], [-Tb A_ba Ta, Tb]] at once. A level is two DEPENDENT 64 x 64
+  products, and what they cost is their latency: the program's heads go through
+  the levels side by side (:func:`_inverses`), which halved the forward on the
+  chip (PERF.md section 6, PR 34);
+- W, U, O and the next state as in ``_chunked``; the state is kept transposed
+  (dv x dk), so its decay is a multiply along lanes.
+
+The backward recomputes all of that from the saved operands and the chunk's
+incoming state, and is the gradient of the same function: products take
+operands of the tower's dtype where the forward's do, the inverse's cotangent
+-T^T dT T^T is float32. G enters a chunk only through factors (row e^G) and
+(column e^-G), so its cotangent is accumulated from the scaled operands'
+(+ for a level's row tokens, - for its column tokens; the reference token's
+cancels) and g's is one reversed running sum of it.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["delta_rule_kernel", "heads_per_program"]
+
+F32 = jnp.float32
+_HIGHEST = lax.Precision.HIGHEST
+
+
+def heads_per_program(num_heads: int) -> int:
+    """Heads one program takes: their chains are independent, so the scheduler
+    interleaves them (PERF.md section 6, PR 34)."""
+    return max(n for n in (4, 2, 1) if num_heads % n == 0)
+
+
+def _decay_ops(chunk: int) -> np.ndarray:
+    """The 0/1 matrix (2C, C) whose product with g (C, dk) is the running sum
+    G_t (rows t: the sum up to t) and G_C - G_t (rows C + t: the sum after t)."""
+    t = np.arange(chunk)[:, None]
+    j = np.arange(chunk)[None, :]
+    return np.concatenate([j <= t, j > t]).astype(np.float32)
+
+
+def _levels(chunk: int) -> np.ndarray:
+    """(C, C) int32: for i < t the halving level whose quadrant holds the pair
+    (the highest bit in which t and i differ), log2 C on the diagonal, -1 above."""
+    t = np.arange(chunk)[:, None]
+    i = np.arange(chunk)[None, :]
+    top = np.floor(np.log2(np.maximum(t ^ i, 1))).astype(np.int32)
+    return np.where(t > i, top, np.where(t == i, chunk.bit_length() - 1, -1)).astype(np.int32)
+
+
+def _dot(a, b, contract_a=1, contract_b=0, precision=None):
+    return lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
+        preferred_element_type=F32, precision=precision,
+    )
+
+
+def _dot01(ops, x):
+    """``ops @ x`` for a 0/1 matrix (bf16) and a float32 x, to float32 accuracy:
+    x in three bf16 pieces, each product exact, summed in float32."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(F32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(F32)).astype(jnp.bfloat16)
+    return _dot(ops, hi) + _dot(ops, mid) + _dot(ops, lo)
+
+
+def _level_exponents(run):
+    """Per halving level l (half h = 2^l) the exponent of each token against its
+    block's reference token, the last of the left half of its 2h-block: G_t -
+    G_ref for a token of the right half, G_ref - G_t for one of the left half,
+    both <= 0 for g <= 0. ``run`` is G (C, dk) float32; the reference rows are
+    sublane broadcasts, whole (8, 128) tiles where a block is that large."""
+    chunk, dk = run.shape
+    token = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+    tiles = run.reshape(chunk // 8, 8, dk)
+    row_in_tile = lax.broadcasted_iota(jnp.int32, tiles.shape, 1)
+    out = []
+    for lv in range(chunk.bit_length() - 1):
+        half = 1 << lv
+        if half >= 4:
+            blocks = run.reshape(chunk // (2 * half), 2 * half, dk)
+            ref = jnp.broadcast_to(blocks[:, half - 1:half], blocks.shape)
+        else:  # several blocks to a tile: the tile's reference rows, block by block
+            ref = jnp.broadcast_to(tiles[:, half - 1:half], tiles.shape)
+            for start in range(2 * half, 8, 2 * half):
+                ref = jnp.where(row_in_tile >= start,
+                                jnp.broadcast_to(tiles[:, start + half - 1:start + half], tiles.shape), ref)
+        ref = ref.reshape(chunk, dk)
+        out.append(jnp.where((token >> lv) & 1 == 1, run - ref, ref - run))
+    return out
+
+
+def _scores(q, k, g, ops, level):
+    """One head's chunk up to the matrix to invert. q, k (C, dk) in the tower's
+    dtype, g (C, dk) float32. Returns exp(G), exp(G_C - G) (C, dk), the levels'
+    decays, all <= 1, A / beta and P (C, C) float32."""
+    chunk = q.shape[0]
+    dt = q.dtype
+    sums = _dot01(ops, g)  # G and G_C - G
+    eg, ee = jnp.exp(sums[:chunk]), jnp.exp(sums[chunk:])
+    e_levels = [jnp.exp(x) for x in _level_exponents(sums[:chunk])]
+    qf, kf = q.astype(F32), k.astype(F32)
+    a0 = jnp.zeros((chunk, chunk), F32)
+    p = jnp.zeros((chunk, chunk), F32)
+    for lv, e_l in enumerate(e_levels):
+        kl = (kf * e_l).astype(dt)
+        x = _dot(jnp.concatenate([kl, (qf * e_l).astype(dt)], 0), kl, 1, 1)  # (2C, C)
+        a0 = jnp.where(level == lv, x[:chunk], a0)
+        p = jnp.where(level == lv, x[chunk:], p)
+    p = jnp.where(level == len(e_levels), _dot(q, k, 1, 1), p)  # q_t . k_t: a token reads its own write
+    return (eg, ee, e_levels), a0, p
+
+
+def _inverses(a_of, level):
+    """(I + A)^-1 of every head of the program, float32, level by level with the
+    heads side by side: a level is two dependent products whose latency, not
+    whose size, is the cost, and the heads' chains are independent."""
+    chunk = level.shape[0]
+    rows = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    eye = jnp.where(rows == cols, 1.0, 0.0)
+    t_of = [eye - jnp.where(level == 0, a, 0.0) for a in a_of]
+    for lv in range(1, chunk.bit_length() - 1):
+        right = [_dot(jnp.where(level == lv, a, 0.0), t, precision=_HIGHEST) for a, t in zip(a_of, t_of)]
+        t_of = [t - _dot(t, x, precision=_HIGHEST) for t, x in zip(t_of, right)]
+    return t_of
+
+
+def _outputs(q, k, v, beta, z, e, p, t_inv):
+    """One head's chunk from the inverse on: z the incoming state transposed
+    (dv, dk) float32. Returns what the output, the next state and the backward
+    are made of."""
+    chunk, dk = q.shape
+    dt = q.dtype
+    qf, kf = q.astype(F32), k.astype(F32)
+    eg, ee, _ = e
+    kb = (beta * kf * eg).astype(dt)
+    vb = (beta * v.astype(F32)).astype(dt)
+    tb = t_inv.astype(dt)
+    wu = _dot(tb, jnp.concatenate([kb, vb], 1))  # (C, dk + dv)
+    wb = wu[:, :dk].astype(dt)
+    zb = z.astype(dt)
+    qg = (qf * eg).astype(dt)
+    ws = _dot(jnp.concatenate([wb, qg], 0), zb, 1, 1)  # (2C, dv)
+    ub = (wu[:, dk:] - ws[:chunk]).astype(dt)
+    pb = p.astype(dt)
+    out = ws[chunk:] + _dot(pb, ub)
+    ke = (kf * ee).astype(dt)
+    end_decay = eg[chunk - 1:]  # (1, dk): exp(G_C)
+    z_next = z * end_decay + _dot(ub, ke, 0, 0)  # (dv, dk)
+    return dict(qf=qf, kf=kf, eg=eg, ee=ee, kb=kb, vb=vb, tb=tb, wb=wb, zb=zb, qg=qg, ub=ub,
+                pb=pb, ke=ke, end_decay=end_decay, out=out, z_next=z_next)
+
+
+def _cotangents_to_inverse(f, z, d_out, dz_next):
+    """The backward of :func:`_outputs` down to the inverse's cotangent."""
+    chunk, dk = f["qf"].shape
+    dt = d_out.dtype
+    dzb = dz_next.astype(dt)
+    # the next state: z' = z exp(G_C) + U^T (K exp(G_C - G))
+    d_end = jnp.sum(z * dz_next, 0, keepdims=True) * f["end_decay"]  # cotangent of G_C, (1, dk)
+    d_ke = _dot(f["ub"], dzb)  # (C, dk)
+    # the output: O = (Q e^G) z^T + P U;   U = U0 - W z^T
+    d_u = _dot(f["ke"], dzb, 1, 1) + _dot(f["pb"], d_out, 0, 0)  # (C, dv)
+    d_ub = d_u.astype(dt)
+    d_p = _dot(d_out, f["ub"], 1, 1)  # (C, C)
+    both = jnp.concatenate([d_out, -d_ub], 0)
+    through_z = _dot(both, f["zb"])  # (2C, dk): d(Q e^G), dW
+    d_qg, d_w = through_z[:chunk], through_z[chunk:]
+    dz = dz_next * f["end_decay"] + _dot(both, jnp.concatenate([f["qg"], f["wb"]], 0), 0, 0)
+    # W, U0 = T [beta K e^G | beta V]
+    d_wu = jnp.concatenate([d_w.astype(dt), d_ub], 1)  # (C, dk + dv)
+    d_t = _dot(d_wu, jnp.concatenate([f["kb"], f["vb"]], 1), 1, 1)  # (C, C)
+    d_kv = _dot(f["tb"], d_wu, 0, 0)  # T^T [dW | dU0]
+    return dict(d_end=d_end, d_ke=d_ke, d_p=d_p, d_qg=d_qg, dz=dz, d_t=d_t,
+                d_kb=d_kv[:, :dk], d_vb=d_kv[:, dk:])
+
+
+def _cotangents_of_operands(q, k, v, beta, e, a0, f, c, d_a, ops, level):
+    """The rest of the backward: ``c`` from :func:`_cotangents_to_inverse`,
+    ``d_a`` the cotangent of A (strict lower triangle). Returns float32 (dq, dk,
+    dv, dg, dbeta (C, 1))."""
+    chunk = q.shape[0]
+    dt = q.dtype
+    levels = chunk.bit_length() - 1
+    qf, kf, eg, ee = f["qf"], f["kf"], f["eg"], f["ee"]
+    through_kb = c["d_kb"] * kf * eg  # its beta, k and G cotangents share this
+    d_beta = (jnp.sum(through_kb, 1, keepdims=True) + jnp.sum(c["d_vb"] * v.astype(F32), 1, keepdims=True)
+              + jnp.sum(d_a * a0, 1, keepdims=True))
+    d_k = c["d_kb"] * beta * eg
+    d_g_run = through_kb * beta  # the cotangent of the running sum G, (C, dk)
+    d_x = jnp.concatenate([d_a * beta, c["d_p"]], 0)  # (2C, C): the cotangents of A / beta and P
+    level2 = jnp.concatenate([level, level], 0)
+    d_q = jnp.zeros_like(qf)
+    token = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+    for lv, e_l in enumerate(e[2]):
+        kl_f, ql_f = kf * e_l, qf * e_l
+        kl = kl_f.astype(dt)
+        d_xl = jnp.where(level2 == lv, d_x, 0.0).astype(dt)
+        as_rows = _dot(d_xl, kl)  # (2C, dk): k and q as the level's row tokens
+        as_cols = _dot(d_xl, jnp.concatenate([kl, ql_f.astype(dt)], 0), 0, 0)  # (C, dk): k as its column tokens
+        d_kl = as_rows[:chunk] + as_cols
+        d_ql = as_rows[chunk:]
+        d_k = d_k + d_kl * e_l
+        d_q = d_q + d_ql * e_l
+        # a right-half token's exponent is G_t - G_ref, a left-half token's G_ref - G_t
+        sign = jnp.where((token >> lv) & 1 == 1, 1.0, -1.0)
+        d_g_run = d_g_run + sign * (d_kl * kl_f + d_ql * ql_f)
+    own = jnp.sum(jnp.where(level == levels, c["d_p"], 0.0), 1, keepdims=True)  # dP[t, t]
+    d_q = d_q + own * kf + c["d_qg"] * eg
+    d_k = d_k + own * qf + c["d_ke"] * ee
+    to_end = c["d_ke"] * kf * ee  # cotangent of G_C - G_t
+    d_g_run = d_g_run + c["d_qg"] * qf * eg - to_end
+    d_end = c["d_end"] + jnp.sum(to_end, 0, keepdims=True)
+    d_g_run = jnp.where(token == chunk - 1, d_g_run + d_end, d_g_run)
+    # G is the running sum of g: dg_j = sum over t >= j of dG_t
+    d_g = lax.dot_general(
+        ops[:chunk].astype(F32), d_g_run, (((0,), (0,)), ((), ())),
+        preferred_element_type=F32, precision=_HIGHEST)
+    return d_q, d_k, c["d_vb"] * beta, d_g, d_beta
+
+
+def _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads):
+    """What both kernels start with, per head of the program: its operands (q,
+    k, v, g, beta (C, 1): aligned lane windows of the blocks), its scores, and
+    the inverses, the heads side by side."""
+    dk, dv = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
+    operands = [
+        (q_ref[0, :, j * dk:(j + 1) * dk], k_ref[0, :, j * dk:(j + 1) * dk], v_ref[0, :, j * dv:(j + 1) * dv],
+         g_ref[0, :, j * dk:(j + 1) * dk], beta_ref[0, 0, :, j:j + 1])
+        for j in range(heads)
+    ]
+    scores = [_scores(q, k, g, ops, level) for q, k, _, g, _ in operands]
+    inverses = _inverses([a0 * beta for (_, a0, _), (*_, beta) in zip(scores, operands)], level)
+    return operands, scores, inverses
+
+
+def _fwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
+                heads, save_states):
+    states_ref = rest[0] if save_states else None
+    z_ref = rest[-1]
+    dk, dv = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        z_ref[...] = jnp.zeros_like(z_ref)
+
+    ops, level = ops_ref[...], level_ref[...]
+    operands, scores, inverses = _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads)
+    for j, ((q, k, v, _, beta), (e, _, p), t_inv) in enumerate(zip(operands, scores, inverses)):
+        z = z_ref[j]
+        if save_states:
+            states_ref[0, 0, :, j * dk:(j + 1) * dk] = z
+        f = _outputs(q, k, v, beta, z, e, p, t_inv)
+        o_ref[0, :, j * dv:(j + 1) * dv] = f["out"].astype(o_ref.dtype)
+        z_ref[j] = f["z_next"]
+
+
+def _bwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dz_ref, *, heads):
+    dk, dv = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dz_ref[...] = jnp.zeros_like(dz_ref)
+
+    ops, level = ops_ref[...], level_ref[...]
+    chunk = level.shape[0]
+    operands, scores, inverses = _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads)
+    forwards, partials = [], []
+    for j, ((q, k, v, _, beta), (e, _, p), t_inv) in enumerate(zip(operands, scores, inverses)):
+        z = states_ref[0, 0, :, j * dk:(j + 1) * dk]
+        f = _outputs(q, k, v, beta, z, e, p, t_inv)
+        c = _cotangents_to_inverse(f, z, do_ref[0, :, j * dv:(j + 1) * dv], dz_ref[j])
+        dz_ref[j] = c["dz"]
+        forwards.append(f)
+        partials.append(c)
+    # T = (I + A)^-1: dA = -T^T dT T^T on the strict lower triangle, the heads side by side
+    right = [_dot(c["d_t"], t, 1, 1, precision=_HIGHEST) for c, t in zip(partials, inverses)]
+    d_as = [jnp.where((level >= 0) & (level < chunk.bit_length() - 1), -_dot(t, x, 0, 0, precision=_HIGHEST), 0.0)
+            for t, x in zip(inverses, right)]
+    lane = lax.broadcasted_iota(jnp.int32, (chunk, heads), 1)
+    d_betas = jnp.zeros((chunk, heads), F32)
+    for j, ((q, k, v, _, beta), (e, a0, _), f, c, d_a) in enumerate(zip(operands, scores, forwards, partials, d_as)):
+        d_q, d_k, d_v, d_g, d_beta = _cotangents_of_operands(q, k, v, beta, e, a0, f, c, d_a, ops, level)
+        lk, lv = slice(j * dk, (j + 1) * dk), slice(j * dv, (j + 1) * dv)
+        dq_ref[0, :, lk] = d_q.astype(dq_ref.dtype)
+        dk_ref[0, :, lk] = d_k.astype(dk_ref.dtype)
+        dv_ref[0, :, lv] = d_v.astype(dv_ref.dtype)
+        dg_ref[0, :, lk] = d_g
+        d_betas = jnp.where(lane == j, d_beta, d_betas)
+    dbeta_ref[0, 0] = d_betas
+
+
+def _by_group(beta, heads):  # (b, s, h) -> (b, h / heads, s, heads): a program's heads on the lanes
+    b, s, h = beta.shape
+    return jnp.transpose(beta.reshape(b, s, h // heads, heads), (0, 2, 1, 3))
+
+
+def _call(kernel, name, operands, outs, *, b, s, h, dk, dv, chunk, heads, backward, interpret):
+    """One of the two kernels over the grid (rows, head groups, chunks), the
+    backward's chunks last to first. ``operands`` are (kind, array) and ``outs``
+    (kind, shape): "token" (b, s, h x d), "beta" (b, h / heads, s, heads),
+    "state" (b, chunks, dv, h x dk)."""
+    n = s // chunk
+
+    def at(c):
+        return n - 1 - c if backward else c
+
+    def spec(kind, shape):
+        block, index = {
+            "token": ((1, chunk, shape[-1] // h * heads), lambda r, hg, c: (r, at(c), hg)),
+            "beta": ((1, 1, chunk, heads), lambda r, hg, c: (r, hg, at(c), 0)),
+            "state": ((1, 1, dv, heads * dk), lambda r, hg, c: (r, at(c), 0, hg)),
+        }[kind]
+        return pl.BlockSpec(block, index, memory_space=pltpu.VMEM)
+
+    def whole(x):
+        return pl.BlockSpec(x.shape, lambda r, hg, c: (0, 0), memory_space=pltpu.VMEM)
+
+    consts = (jnp.asarray(_decay_ops(chunk), jnp.bfloat16), jnp.asarray(_levels(chunk)))
+    moved = sum(x.size * x.dtype.itemsize for _, x in [*operands, *outs])
+    return pl.pallas_call(
+        kernel,
+        out_shape=[o for _, o in outs],
+        grid=(b, h // heads, n),
+        in_specs=[whole(c) for c in consts] + [spec(kind, x.shape) for kind, x in operands],
+        out_specs=[spec(kind, o.shape) for kind, o in outs],
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), F32)],  # the state, or its cotangent
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=int(_chunk_flops(chunk, dk, dv, backward) * b * h * n), bytes_accessed=int(moved),
+            transcendentals=int(b * h * n * (chunk.bit_length() + 1) * chunk * dk)),
+        interpret=interpret,
+        name=name,  # what a profile calls this kernel
+    )(*consts, *(x for _, x in operands))
+
+
+def _chunk_flops(chunk, dk, dv, backward):
+    """Operations of a chunk-head's products, a float32 one counted as six bf16
+    passes: what the scheduler is told (``cost_estimate``), not a metric."""
+    levels = chunk.bit_length() - 1
+    cc, cd, dd = chunk * chunk, chunk * (dk + dv) // 2, dk * dv
+    sums = 3 * 2 * cc * dk  # G and G_C - G, g in three pieces
+    scores = (levels + 1) * 2 * cc * dk
+    inverse = (levels - 1) * 2 * 6 * cc * chunk
+    rest = 2 * cc * cd + 2 * chunk * dd + cc * dv + chunk * dd
+    forward = 2 * (sums + scores + inverse + rest)
+    if not backward:
+        return forward
+    return forward + 2 * (5 * chunk * dd + 2 * cc * dv + 4 * cc * cd + 2 * 6 * cc * chunk
+                          + levels * 4 * cc * dk + 6 * cc * dk)
+
+
+def _operands(q, k, v, g, beta, heads):
+    b, s = q.shape[:2]
+    wide = [x.reshape(b, s, -1) for x in (q, k, v, g.astype(F32))]  # free: heads stay on the lanes
+    return [("token", x) for x in wide] + [("beta", _by_group(beta.astype(F32), heads))]
+
+
+def _forward(q, k, v, g, beta, chunk, interpret, save_states):
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    heads = heads_per_program(h)
+    outs = [("token", jax.ShapeDtypeStruct((b, s, h * dv), v.dtype))]
+    if save_states:
+        outs.append(("state", jax.ShapeDtypeStruct((b, s // chunk, dv, h * dk), F32)))
+    out, *states = _call(
+        functools.partial(_fwd_kernel, heads=heads, save_states=save_states), "kda_fwd",
+        _operands(q, k, v, g, beta, heads), outs,
+        b=b, s=s, h=h, dk=dk, dv=dv, chunk=chunk, heads=heads, backward=False, interpret=interpret)
+    return out.reshape(b, s, h, dv), states
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def delta_rule_kernel(q, k, v, g, beta, chunk: int = 64, interpret: bool = False):
+    """o of the gated delta rule through the kernels. q, k, g: (b, s, h, dk); v:
+    (b, s, h, dv); beta: (b, s, h); s a multiple of ``chunk``, a power of two.
+    Returns (b, s, h, dv) in v's dtype. Differentiated, it saves its operands
+    and each chunk's incoming state (float32, b x s / chunk x h x dk x dv: under
+    a rematerialised layer they live from the layer's second forward to its
+    backward). ``interpret=True`` runs the Pallas interpreter (CPU testing)."""
+    return _forward(q, k, v, g, beta, chunk, interpret, False)[0]
+
+
+def _vjp_fwd(q, k, v, g, beta, chunk, interpret):
+    out, (states,) = _forward(q, k, v, g, beta, chunk, interpret, True)
+    return out, (q, k, v, g, beta, states)
+
+
+def _vjp_bwd(chunk, interpret, residuals, d_out):
+    q, k, v, g, beta, states = residuals
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    heads = heads_per_program(h)
+
+    def token(d, dtype):
+        return ("token", jax.ShapeDtypeStruct((b, s, h * d), dtype))
+
+    d_q, d_k, d_v, d_g, d_beta = _call(
+        functools.partial(_bwd_kernel, heads=heads), "kda_bwd",
+        [*_operands(q, k, v, g, beta, heads), ("state", states),
+         ("token", d_out.astype(v.dtype).reshape(b, s, -1))],
+        [token(dk, q.dtype), token(dk, k.dtype), token(dv, v.dtype), token(dk, F32),
+         ("beta", jax.ShapeDtypeStruct((b, h // heads, s, heads), F32))],
+        b=b, s=s, h=h, dk=dk, dv=dv, chunk=chunk, heads=heads, backward=True, interpret=interpret)
+    d_beta = jnp.transpose(d_beta, (0, 2, 1, 3)).reshape(b, s, h)
+    return (d_q.reshape(q.shape), d_k.reshape(k.shape), d_v.reshape(v.shape),
+            d_g.reshape(g.shape).astype(g.dtype), d_beta.astype(beta.dtype))
+
+
+delta_rule_kernel.defvjp(_vjp_fwd, _vjp_bwd)

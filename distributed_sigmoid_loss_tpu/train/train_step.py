@@ -328,7 +328,7 @@ def stack_record_of(t, tokens_shape) -> dict:
     routed = [i >= t.leading_dense_layers for i in range(t.depth)]
     mixers = t.mixers or ("attn",) * t.depth
     tokens = math.prod(tokens_shape)
-    return {
+    record = {
         "layer_kinds": [f"{m}+{'moe' if r else 'mlp'}" for m, r in zip(mixers, routed)],
         "experts_held": held, "experts_total": t.moe_experts,
         "experts_per_token": t.moe_num_selected,
@@ -337,6 +337,16 @@ def stack_record_of(t, tokens_shape) -> dict:
         # The sort's rows, the true worst case: every token picks held experts only.
         "dispatch_rows_bound": tokens * t.moe_num_selected,
     }
+    if "kda" in mixers:
+        from distributed_sigmoid_loss_tpu.models.mixers import CHUNK
+        from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import delta_rule_core
+
+        # By the rule KdaMixer's call runs by: which core each delta-rule layer takes
+        # ("kernel" / "chunked") and the rows, heads and chunks of a call.
+        rows, length = tokens_shape
+        core = delta_rule_core(rows, length, t.num_heads, t.kda_head_dim, t.kda_head_dim, t.dtype, CHUNK)
+        record["kda_core"] = {i: dict(core) for i, m in enumerate(mixers) if m == "kda"}
+    return record
 
 
 def _route_load(variables) -> dict:

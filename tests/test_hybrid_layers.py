@@ -7,6 +7,7 @@ experts against the whole layer."""
 import os
 import sys
 import types
+from functools import partial
 
 import flax.linen as nn
 import jax
@@ -23,6 +24,7 @@ from distributed_sigmoid_loss_tpu.models.moe import (
 )
 from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import (
     chunk_gated_delta_rule,
+    delta_rule_core,
     gated_delta_rule_recurrent,
 )
 from distributed_sigmoid_loss_tpu.parallel.ring_attention import dense_attention
@@ -93,6 +95,167 @@ def test_a_sequence_that_is_no_multiple_of_the_chunk_and_a_batch_run_in_passes(m
     monkeypatch.setattr(gated_delta_rule, "_PASS_BYTES", 24 * 3 * 16 * 4 * 2)  # two rows a pass
     assert gated_delta_rule._rows_per_pass(4, 24, 3, 16) == 2
     assert reference_kimi._base.max_rel_err(chunk_gated_delta_rule(*args, chunk=8), want) < 1e-5
+
+
+# -- (a') the same through the Pallas kernels (ops/pallas_delta_rule.py), interpreted ----
+
+
+def kernel_rule(*args, **kw):
+    from distributed_sigmoid_loss_tpu.ops.pallas_delta_rule import delta_rule_kernel
+
+    return delta_rule_kernel(*args, interpret=True, **kw)
+
+
+def value_and_grads(fn, args, weight):
+    return jax.jit(jax.value_and_grad(
+        lambda *a: (fn(*a).astype(jnp.float32) * weight).sum(), argnums=(0, 1, 2, 3, 4)
+    ))(*args)
+
+
+def ripple(shape):
+    return jnp.cos(jnp.arange(np.prod(shape), dtype=jnp.float32)).reshape(shape)
+
+
+# At the cell's chunk and head size (64 tokens, dk = dv = 128). Against the
+# recurrence at the chunked form's bound; against the chunked form in bf16 the
+# forward rounds where it rounds (measured here: o to 5e-4, a bf16 step of a few
+# entries; the gradients to 8e-3, a cotangent rounded to bf16 at another place).
+@pytest.mark.parametrize("chunks", [1, 2, 5])
+def test_the_kernels_are_the_recurrence_and_the_chunked_form(chunks):
+    args = delta_rule_inputs(chunks * 64, jnp.bfloat16, b=1, h=2, dk=128, dv=128)
+    weight = ripple(args[2].shape)
+    want_out = jax.jit(gated_delta_rule_recurrent)(*args)
+    twin_out = jax.jit(lambda *a: chunk_gated_delta_rule(*a, chunk=64))(*args)
+    _, got = value_and_grads(kernel_rule, args, weight)
+    out = jax.jit(kernel_rule)(*args)
+    assert out.dtype == jnp.bfloat16 and bool(jnp.isfinite(out.astype(jnp.float32)).all())
+    assert reference_kimi._base.max_rel_err(out, want_out) < 5e-2
+    assert reference_kimi._base.max_rel_err(out, twin_out) < 5e-3
+    _, want = value_and_grads(gated_delta_rule_recurrent, args, weight)
+    _, twin = value_and_grads(lambda *a: chunk_gated_delta_rule(*a, chunk=64), args, weight)
+    for name, g, w, t in zip("q k v g beta".split(), got, want, twin):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert reference_kimi._base.max_rel_err(g, w) < 5e-2, name
+        assert reference_kimi._base.max_rel_err(g, t) < 1.5e-2, name
+
+
+def test_the_kernels_in_float32_are_the_recurrence_to_rounding():
+    """The dispatcher never hands the kernels float32 operands; their arithmetic
+    (the decays from g itself, the float32 inverse) shows at that precision."""
+    args = delta_rule_inputs(2 * 16, jnp.float32, h=2, dk=32, dv=32)
+    weight = ripple(args[2].shape)
+    _, got = value_and_grads(lambda *a: kernel_rule(*a, chunk=16), args, weight)
+    _, want = value_and_grads(gated_delta_rule_recurrent, args, weight)
+    assert reference_kimi._base.max_rel_err(kernel_rule(*args, chunk=16), gated_delta_rule_recurrent(*args)) < 1e-5
+    for name, g, w in zip("q k v g beta".split(), got, want):
+        assert reference_kimi._base.max_rel_err(g, w) < 1e-5, name
+
+
+@pytest.mark.parametrize("rows, heads", [(1, 1), (3, 2), (16, 1), (2, 8)], ids=["b1", "b3", "b16", "two-head-groups"])
+def test_the_kernels_take_any_batch_and_several_head_groups(rows, heads):
+    from distributed_sigmoid_loss_tpu.ops.pallas_delta_rule import heads_per_program
+
+    args = delta_rule_inputs(32, jnp.bfloat16, b=rows, h=heads, dk=32, dv=32)
+    assert heads // heads_per_program(heads) == (2 if heads == 8 else 1)
+    weight = ripple(args[2].shape)
+    _, got = value_and_grads(lambda *a: kernel_rule(*a, chunk=16), args, weight)
+    _, want = value_and_grads(gated_delta_rule_recurrent, args, weight)
+    assert reference_kimi._base.max_rel_err(kernel_rule(*args, chunk=16), gated_delta_rule_recurrent(*args)) < 5e-2
+    for name, g, w in zip("q k v g beta".split(), got, want):
+        assert reference_kimi._base.max_rel_err(g, w) < 5e-2, name
+
+
+def as_kernel_call(monkeypatch):
+    """Send :func:`chunk_gated_delta_rule` down the kernel path on this CPU: the
+    backend question answered as on a TPU, the kernels interpreted."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_delta_rule
+
+    interpreted = partial(pallas_delta_rule.delta_rule_kernel, interpret=True)
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
+    monkeypatch.setattr(pallas_delta_rule, "delta_rule_kernel", interpreted)
+
+
+def test_a_sequence_that_is_no_multiple_of_the_chunk_through_the_kernels(monkeypatch):
+    as_kernel_call(monkeypatch)
+    args = delta_rule_inputs(70, jnp.bfloat16, b=1, h=1, dk=128, dv=128)
+    weight = ripple(args[2].shape)
+    assert delta_rule_core(1, 70, 1, 128, 128, jnp.bfloat16) == {"core": "kernel", "rows": 1, "heads": 1, "chunks": 2}
+    got_out, got = value_and_grads(lambda *a: chunk_gated_delta_rule(*a).astype(jnp.float32), args, weight)
+    want_out, want = value_and_grads(gated_delta_rule_recurrent, args, weight)
+    out = chunk_gated_delta_rule(*args)
+    assert out.shape == (1, 70, 1, 128) and out.dtype == jnp.bfloat16
+    assert reference_kimi._base.max_rel_err(out, gated_delta_rule_recurrent(*args)) < 5e-2
+    for name, g, w in zip("q k v g beta".split(), got, want):
+        assert g.shape == w.shape and reference_kimi._base.max_rel_err(g, w) < 5e-2, name
+
+
+def test_a_channel_that_forgets_fast_stays_finite_in_the_kernels():
+    """g about -30 a token: exp(-G) passes float32's range within a chunk, so a
+    quotient of decays would overflow; the halving's exponents are all <= 0."""
+    q, k, v, g, beta = delta_rule_inputs(128, jnp.bfloat16, b=1, h=1, dk=128, dv=128)
+    g = jnp.where(jnp.arange(128) % 3 == 0, -30.0, g)  # every third channel
+    assert float(jnp.cumsum(g, 1).min()) < -1900  # exp(1900) is not a float32
+    args = (q, k, v, g, beta)
+    weight = ripple(v.shape)
+    _, got = value_and_grads(kernel_rule, args, weight)
+    _, want = value_and_grads(gated_delta_rule_recurrent, args, weight)
+    assert reference_kimi._base.max_rel_err(kernel_rule(*args), gated_delta_rule_recurrent(*args)) < 5e-2
+    for name, g_, w in zip("q k v g beta".split(), got, want):
+        assert bool(jnp.isfinite(g_.astype(jnp.float32)).all()), name
+        assert reference_kimi._base.max_rel_err(g_, w) < 5e-2, name
+
+
+def test_repeated_keys_do_not_cancel_in_the_kernels_inverse():
+    """Every token writes the same key at full strength and nothing decays:
+    I + A is ones below the diagonal times beta, its Neumann series alternates
+    through terms of 1e17 before it ends, and the block recursion does not."""
+    q, k, v, g, beta = delta_rule_inputs(64, jnp.bfloat16, b=1, h=1, dk=128, dv=128)
+    k = jnp.broadcast_to(k[:, :1], k.shape)
+    g = jnp.zeros_like(g) - 1e-4
+    beta = jnp.full_like(beta, 0.98)
+    args = (q, k, v, g, beta)
+    weight = ripple(v.shape)
+    _, got = value_and_grads(kernel_rule, args, weight)
+    _, want = value_and_grads(gated_delta_rule_recurrent, args, weight)
+    assert reference_kimi._base.max_rel_err(kernel_rule(*args), gated_delta_rule_recurrent(*args)) < 5e-2
+    for name, g_, w in zip("q k v g beta".split(), got, want):
+        assert bool(jnp.isfinite(g_.astype(jnp.float32)).all()), name
+        assert reference_kimi._base.max_rel_err(g_, w) < 5e-2, name
+
+
+@pytest.mark.parametrize("dtype, tpu, d, core", [
+    (jnp.bfloat16, True, 128, "kernel"), (jnp.bfloat16, True, 256, "kernel"),
+    (jnp.float32, True, 128, "chunked"), (jnp.bfloat16, False, 128, "chunked"), (jnp.bfloat16, True, 64, "chunked"),
+], ids=["bf16-tpu-128", "bf16-tpu-256", "float32", "cpu", "head-64"])
+def test_which_core_a_call_takes_follows_from_dtype_backend_and_head_size(monkeypatch, dtype, tpu, d, core):
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, gated_delta_rule, pallas_delta_rule
+
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: tpu)
+    assert delta_rule_core(3, 100, 2, d, d, dtype) == {"core": core, "rows": 3, "heads": 2, "chunks": 2}
+    taken = []
+    monkeypatch.setattr(pallas_delta_rule, "delta_rule_kernel",
+                        lambda q, *a, **kw: taken.append("kernel") or jnp.zeros_like(q))
+    chunked = gated_delta_rule._chunked
+    monkeypatch.setattr(gated_delta_rule, "_chunked", lambda *a, **kw: taken.append("chunked") or chunked(*a, **kw))
+    chunk_gated_delta_rule(*delta_rule_inputs(16, dtype, b=1, h=1, dk=d, dv=d), chunk=8)
+    assert taken == [core]
+
+
+def test_the_steps_record_names_the_core_by_the_same_rule(monkeypatch):
+    from distributed_sigmoid_loss_tpu.ops import flash_attention
+    from distributed_sigmoid_loss_tpu.train.train_step import stack_record_of
+    from distributed_sigmoid_loss_tpu.utils.config import TextConfig
+
+    t = TextConfig(width=256, depth=3, num_heads=2, mixers=("kda", "mla", "kda"), pos="none", dtype="bfloat16",
+                   moe_experts=4, moe_router="sigmoid", kda_head_dim=128)
+    assert stack_record_of(t, (16, 1024))["kda_core"] == {
+        i: {"core": "chunked", "rows": 16, "heads": 2, "chunks": 16} for i in (0, 2)}  # this CPU
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
+    assert {x["core"] for x in stack_record_of(t, (16, 1024))["kda_core"].values()} == {"kernel"}
+    float32 = TextConfig(width=256, depth=1, num_heads=2, mixers=("kda",), pos="none", dtype="float32",
+                         moe_experts=4, moe_router="sigmoid")
+    assert stack_record_of(float32, (3, 100))["kda_core"] == {0: {"core": "chunked", "rows": 3, "heads": 2, "chunks": 2}}
+    assert "kda_core" not in stack_record_of(TextConfig(depth=2, moe_experts=4, moe_router="sigmoid"), (4, 8))
 
 
 # -- (b) latent attention ----------------------------------------------------------
