@@ -440,8 +440,8 @@ def probe_imperative(cfg: StepConfig) -> tuple[bool, str]:
     state-content checks (validate_pp_tower, state.ema presence) are
     environmental, not config-space, and are out of probe scope: the text
     tower's block options (utils.config.BLOCK_OPTIONS: norm, sandwich_norm,
-    mlp, use_bias, pos, loops, norm_eps, mixers, leading_dense_layers and the
-    moe_router group) are no axis of this lattice, every step
+    mlp, use_bias, pos, loops, norm_eps, mixers, leading_dense_layers, mla_q_rank
+    and the moe_router group) are no axis of this lattice, every step
     builder takes them as it takes any tower, and the one axis whose builder
     re-implements the block (``pp``) refuses each by name in
     validate_pp_tower. What a block option excludes beside ``pp`` is stated
@@ -611,8 +611,10 @@ TOWER_EXCLUSIONS: tuple = (
      "a recurrence carried across sequence shards, and latent attention over them, are not built"),
     (_MIXED, {"quant_train": "int8"}, "quant=", _UNLIKE_LAYERS,
      "the mixers' projections and cores have no int8 path"),
-    (_MIXED, {"pos": "learned"}, "pos=", _UNLIKE_LAYERS,
-     "the recurrence and the causal masks carry the order: no position table, no rotation"),
+    (_MIXED, {"pos": "learned"}, "pos=", "models/text.py::layer_specs",
+     "the recurrence and the causal masks carry the order: no position table"),
+    (_MIXED, {"pos": "rope"}, "pos=", "models/text.py::layer_specs",
+     "a recurrence takes no rotation; a stack of latent attention alone rotates its shared-width parts"),
     (_MIXED, {"causal": False}, "causal=", _UNLIKE_LAYERS, "a recurrence has a direction"),
     (_MIXED, {"loops": 2}, "loops=", _UNLIKE_LAYERS, "a looped mixed stack is not built"),
     (_DROPLESS, {"quant_train": "int8"}, "quant=", "models/transformer.py::Block",
@@ -623,7 +625,7 @@ TOWER_EXCLUSIONS: tuple = (
 # The options that change the block refuse the pipeline by their own name.
 PP_REFUSES: tuple = (
     "mixers", "leading_dense_layers", "norm_eps", "moe_router", "moe_route_scale",
-    "moe_shared_experts", "moe_hidden", "moe_experts_held",
+    "moe_shared_experts", "moe_hidden", "moe_experts_held", "mla_q_rank",
 )
 
 

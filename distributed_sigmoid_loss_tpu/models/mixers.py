@@ -1,8 +1,9 @@
 """Token mixers of a stack of several layer kinds (``TextConfig.mixers``), beside
 the block's softmax ``Attention`` (models/transformer.py): a gated delta-rule
 layer ("kda") and latent attention ("mla"). Imported only where a configuration
-names one. Both are causal, carry no position encoding and no bias; their
-statistics, gates and decays are float32 whatever the tower's dtype.
+names one. Both are causal and carry no bias; their statistics, gates and decays
+are float32 whatever the tower's dtype. The recurrence takes no position
+encoding; latent attention none, or a rotation of its shared-width parts.
 
 With x the (s, width) normalised stream of one sequence:
 
@@ -12,9 +13,11 @@ With x the (s, width) normalised stream of one sequence:
           beta_h = sigmoid(x Wb)
           o   = gated delta rule (ops/gated_delta_rule.py)
           out = (RMS_head(o) sigmoid((x Wga) Wgb)) Wo
-    MLA   q_h = (x Wq)_h ;  [c, kr] = x Wkva ;  [kn_h, v_h] = (RMS(c) Wkvb)_h
-          k_h = [kn_h, kr]   (kr shared by all heads, carried unrotated)
-          out = softmax(q_h k_h^T (dn + dr)^-1/2 + causal) v_h -> Wo
+    MLA   q_h = (x Wq)_h, or (RMS(x Wqa) Wqb)_h with a query rank
+          [c, kr] = x Wkva ;  [kn_h, v_h] = (RMS(c) Wkvb)_h
+          [qn_h, qr_h] = q_h ;  with rope_theta: qr_h = rope(qr_h), kr = rope(kr)
+          k_h = [kn_h, kr]   (kr shared by all heads, rotated once)
+          out = softmax([qn_h, qr_h] k_h^T (dn + dr)^-1/2 + causal) v_h -> Wo
 """
 
 from __future__ import annotations
@@ -38,9 +41,12 @@ L2_EPS = 1e-6
 # flax writes the modules' own names ("kda", "mla") into every operation's path.
 KDA_CORE_SCOPE = "kda_core"  # the recurrence alone, inside "kda"
 MLA_CORE_SCOPE = "mla_core"  # scores, softmax and values, inside "mla"
+MLA_ROPE_SCOPE = "mla_rope"  # the rotation of the queries' and the key's shared-width parts, inside "mla"
 # Tokens a chunk of the delta rule: what one program of the kernels (ops/pallas_delta_rule.py)
 # holds in VMEM per head, six halving levels and a 64 x 64 float32 inverse; the XLA form's too.
 CHUNK = 64
+# The blocked attention kernel's head sizes: a multiple of a vector register's lanes.
+FUSED_LANES = 128
 
 
 def l2norm(x):
@@ -107,13 +113,19 @@ class KdaMixer(nn.Module):
         return dense(self.width, name="out")(o.reshape(b, s, h * d))
 
 
+def one_head_size(dqk: int, dv: int, multiple: int = 1) -> int:
+    """The head size ``pad_heads_to_one_size`` runs a core at."""
+    return -(-max(dqk, dv) // multiple) * multiple
+
+
 def pad_heads_to_one_size(attend, q, k, v, multiple: int = 1):
-    """Run an attention core that takes one head size on query/key heads wider
-    than the value heads: v is zero-padded to the key's width and the output cut
-    back, which is exact (a zero value channel stays zero); q and k are padded
-    alike up to a ``multiple`` of lanes (zero channels add nothing to a score)."""
-    dqk, dv = q.shape[-1], v.shape[-1]
-    wide = -(-max(dqk, dv) // multiple) * multiple
+    """Run an attention core that takes one head size on query/key heads and
+    value heads of two: the narrower are zero-padded to the wider, up to a
+    ``multiple`` of lanes, and the output cut back to the value's width, which
+    is exact (a zero value channel stays zero, zero channels add nothing to a
+    score). Heads that are one size, a multiple already, reach the core as they are."""
+    dv = v.shape[-1]
+    wide = one_head_size(q.shape[-1], dv, multiple)
 
     def pad(t):
         return jnp.pad(t, ((0, 0),) * 3 + ((0, wide - t.shape[-1]),))
@@ -121,10 +133,30 @@ def pad_heads_to_one_size(attend, q, k, v, multiple: int = 1):
     return attend(pad(q), pad(k), pad(v))[..., :dv]
 
 
+def latent_attention_core(attn_impl: str, dtype) -> str:
+    """Which core a ``LatentAttention`` call takes, from what it can see:
+    ``"flash"`` (the library's blocked kernel, which never writes the (b, h, s,
+    s) scores to HBM) or ``"dense"`` (XLA). As in ``Attention``: the fused
+    kernel's backward is bf16-grade, so "auto" takes it for a bf16 tower on a
+    TPU only. The mixer runs what this says and the step's trace-time record
+    (``train_step.stack_record_of``) reports it."""
+    from distributed_sigmoid_loss_tpu.ops.flash_attention import flash_attention_available
+
+    if attn_impl == "flash" and not flash_attention_available():
+        raise ValueError("attn_impl='flash' requires a TPU backend; use 'auto'")
+    fused = attn_impl == "flash" or (
+        attn_impl == "auto" and jnp.dtype(dtype) == jnp.bfloat16 and flash_attention_available()
+    )
+    return "flash" if fused else "dense"
+
+
 class LatentAttention(nn.Module):
-    """Causal latent attention without position encoding: per head a key part
-    of ``nope_dim`` expanded from the ``kv_rank`` latent and one ``shared_dim``
-    key part shared by all heads; value heads of ``v_dim``."""
+    """Causal latent attention: per head a key part of ``nope_dim`` expanded
+    from the ``kv_rank`` latent and one ``shared_dim`` key part shared by all
+    heads; value heads of ``v_dim``. ``q_rank > 0`` brings the queries through
+    a normalised latent of that width (``q_a``, ``q_norm``, ``q_b`` in place of
+    ``q``); ``rope_theta`` rotates the ``shared_dim`` wide parts, each head's
+    query part and the one key part, and nothing else."""
 
     width: int
     num_heads: int
@@ -135,14 +167,13 @@ class LatentAttention(nn.Module):
     dtype: Any
     norm_eps: float = 1e-5
     attn_impl: str = "auto"
+    q_rank: int = 0
+    rope_theta: float | None = None
 
     @nn.compact
     def __call__(self, x):
-        from distributed_sigmoid_loss_tpu.models.transformer import _fused_attention_per_shard
-        from distributed_sigmoid_loss_tpu.ops.flash_attention import (
-            flash_attention_available,
-            flash_self_attention,
-        )
+        from distributed_sigmoid_loss_tpu.models.transformer import _fused_attention_per_shard, rope
+        from distributed_sigmoid_loss_tpu.ops.flash_attention import flash_self_attention
         from distributed_sigmoid_loss_tpu.parallel.ring_attention import dense_attention
 
         b, s, _ = x.shape
@@ -151,27 +182,31 @@ class LatentAttention(nn.Module):
             nn.Dense, use_bias=False, dtype=self.dtype,
             kernel_init=nn.initializers.xavier_uniform(),
         )
-        q = dense(h * (dn + dr), name="q")(x).reshape(b, s, h, dn + dr)
+        if self.q_rank:
+            cq = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="q_norm")(
+                dense(self.q_rank, name="q_a")(x)
+            )
+            q = dense(h * (dn + dr), name="q_b")(cq)
+        else:
+            q = dense(h * (dn + dr), name="q")(x)
+        q = q.reshape(b, s, h, dn + dr)
         latent = dense(self.kv_rank + dr, name="kv_a")(x)
         c, shared = latent[..., : self.kv_rank], latent[..., self.kv_rank :]
         c = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="kv_norm")(c)
         expanded = dense(h * (dn + dv), name="kv_b")(c).reshape(b, s, h, dn + dv)
+        if self.rope_theta is not None:
+            with jax.named_scope(MLA_ROPE_SCOPE):  # the one key part as a single head
+                q = jnp.concatenate([q[..., :dn], rope(q[..., dn:], self.rope_theta)], -1)
+                shared = rope(shared[:, :, None, :], self.rope_theta)[:, :, 0]
         k = jnp.concatenate(
             [expanded[..., :dn], jnp.broadcast_to(shared[:, :, None, :], (b, s, h, dr))], -1
         )
         v = expanded[..., dn:]
-        if self.attn_impl == "flash" and not flash_attention_available():
-            raise ValueError("attn_impl='flash' requires a TPU backend; use 'auto'")
-        # As in Attention: the fused kernel's backward is bf16-grade, so "auto"
-        # takes it for a bf16 tower on a TPU only. The blocked kernel never
-        # writes the (b, h, s, s) scores to HBM.
-        fused = self.attn_impl == "flash" or (
-            self.attn_impl == "auto" and self.dtype == jnp.bfloat16 and flash_attention_available()
-        )
+        fused = latent_attention_core(self.attn_impl, self.dtype) == "flash"
         core = partial(flash_self_attention if fused else dense_attention, causal=True, scale=(dn + dr) ** -0.5)
         if fused:  # a Mosaic kernel under a multi-chip jit sits in a shard_map
             core = partial(_fused_attention_per_shard, core)
         with jax.named_scope(MLA_CORE_SCOPE):
-            out = pad_heads_to_one_size(core, q, k, v, multiple=128 if fused else 1)
+            out = pad_heads_to_one_size(core, q, k, v, multiple=FUSED_LANES if fused else 1)
         out = out.astype(self.dtype).reshape(b, s, h * dv)
         return dense(self.width, name="out")(out)

@@ -33,16 +33,19 @@ def layer_specs(cfg: TextConfig) -> tuple[LayerSpec, ...]:
         "kda": (("head_dim", cfg.kda_head_dim), ("conv_size", cfg.kda_conv_size)),
         "mla": (
             ("nope_dim", cfg.mla_qk_nope_dim), ("shared_dim", cfg.mla_qk_shared_dim),
-            ("v_dim", cfg.mla_v_dim), ("kv_rank", cfg.mla_kv_rank),
+            ("v_dim", cfg.mla_v_dim), ("kv_rank", cfg.mla_kv_rank), ("q_rank", cfg.mla_q_rank),
+            ("rope_theta", cfg.rope_theta if cfg.pos == "rope" else None),
         ),
     }
     mixers = cfg.mixers or ("attn",) * cfg.depth
     if not set(mixers) <= set(mixer_fields):
         raise ValueError(f"unknown mixer in mixers={mixers}: want one of {sorted(mixer_fields)}")
-    if set(mixers) != {"attn"} and cfg.pos != "none":
+    # Latent attention rotates its shared-width parts; a recurrence carries the
+    # order itself, and neither takes a position table.
+    if "kda" in mixers and cfg.pos != "none" or "mla" in mixers and cfg.pos == "learned":
         raise ValueError(
-            f"mixers={mixers} (a recurrence or latent attention, with no position "
-            f"encoding) is not built for pos={cfg.pos!r}"
+            f"mixers={mixers} is not built for pos={cfg.pos!r}: a recurrence ('kda') takes "
+            "pos='none', latent attention ('mla') 'none' or 'rope'"
         )
     if cfg.moe_router not in ("softmax", "sigmoid"):
         raise ValueError(f"unknown moe_router: {cfg.moe_router!r}")

@@ -682,20 +682,21 @@ class Encoder(nn.Module):
     def _check_mixers(self, mixers):
         """What a recurrence or latent attention over one whole causal sequence
         does not run with, each refusal by the option's name (a position table
-        is ``models/text.py``'s to refuse)."""
+        is ``models/text.py``'s to refuse, and a rotation beside a recurrence)."""
         refused = {
             "causal=False": not self.causal,
             f"sequence_parallel_axis={self.sp_axis!r}": self.sp_axis is not None,
             f"quant={self.quant!r}": bool(self.quant),
-            f"rope_theta={self.style.rope_theta!r} (pos='rope')": self.style.rope_theta is not None,
+            f"rope_theta={self.style.rope_theta!r} (pos='rope')":
+                self.style.rope_theta is not None and "kda" in mixers,
             "sandwich_norm=True": self.style.sandwich_norm,
             f"loops={self.loops}": self.loops > 1,
         }
         if set(mixers) != {"attn"} and any(refused.values()):
             raise ValueError(
                 f"mixers={mixers} (a recurrence or latent attention over one "
-                "whole causal sequence, unquantised, with no position encoding) "
-                "is not built for " + ", ".join(k for k, v in refused.items() if v)
+                "whole causal sequence, unquantised, a recurrence with no position "
+                "encoding) is not built for " + ", ".join(k for k, v in refused.items() if v)
             )
 
     def _looped(self, x):

@@ -298,8 +298,14 @@ def mixed_stack(step) -> dict | None:
     dispatch is bounded by (every token choosing held experts only) and, per
     delta-rule layer (``kda_core``, by layer index), which core it took
     (``"kernel"``: the Pallas kernels; ``"chunked"``: XLA operations) with the
-    rows, heads and chunks of a call. None for a
-    step that has not traced yet or runs no such tower."""
+    rows, heads and chunks of a call, and per latent-attention layer (``mla``,
+    by layer index) what it is made of: the queries' latent (``q_rank``, 0 = one
+    projection) and the keys' and values' (``kv_rank``), the width of the
+    rotated parts and their base (``rotated_dim`` 0 and ``rope_theta`` None
+    where nothing is rotated), the core it took (``"flash"``: the library's
+    blocked kernel; ``"dense"``: XLA), the query/key and value head sizes, the
+    one head size the core ran at and whether any head was zero-padded to it.
+    None for a step that has not traced yet or runs no such tower."""
     return dict(getattr(step, "stack_record", None) or {}) or None
 
 

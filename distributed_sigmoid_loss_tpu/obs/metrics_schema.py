@@ -32,6 +32,8 @@ __all__ = [
     "TRAIN_METRICS_PREFIXES",
     "SERVE_STATS_FIELDS",
     "HEALTH_EVENT_FIELDS",
+    "STACK_RECORD_FIELDS",
+    "STACK_RECORD_MLA_FIELDS",
     "validate_metrics",
 ]
 
@@ -109,6 +111,35 @@ SERVE_STATS_FIELDS = frozenset({
 # obs/health.py HealthEvent.record() — the structured watchdog events the
 # train loop writes through the same logger.
 HEALTH_EVENT_FIELDS = frozenset({"metric", "step", "event", "detail"})
+
+# train/train_step.py stack_record_of: the trace-time record of a step whose
+# text tower is a stack given layer by layer with dropless routed experts
+# (``step.stack_record``, read by obs/attribution.py mixed_stack). No metrics
+# line: what the step is made of, from shapes alone, beside the counters above
+# that say what the routing did. Field -> meaning.
+STACK_RECORD_FIELDS = {
+    "layer_kinds": "each layer as mixer+mlp or mixer+moe, in order",
+    "experts_held": "routed experts this chip holds",
+    "experts_total": "routed experts the router scores",
+    "experts_per_token": "experts a token chooses",
+    "expected_local_assignments_per_token": "assignments to held experts a token makes under uniform routing",
+    "tokens_per_microbatch": "text tokens one microbatch routes",
+    "dispatch_rows_bound": "the sort's rows: every token choosing held experts only",
+    "kda_core": "per delta-rule layer: the core it took (kernel / chunked), rows, heads and chunks of a call",
+    "mla": "per latent-attention layer: what it is made of (the fields below)",
+}
+# One latent-attention layer's entry of ``mla``.
+STACK_RECORD_MLA_FIELDS = {
+    "q_rank": "width of the queries' normalised latent; 0 = one projection",
+    "kv_rank": "width of the keys' and values' normalised latent",
+    "rotated_dim": "width of the rotated parts (each head's query part, the one shared key part); 0 = none",
+    "rope_theta": "the rotation's base; None where nothing is rotated",
+    "core": "flash (the library's blocked kernel) or dense (XLA), as the dispatcher chose",
+    "qk_dim": "query and key head size",
+    "v_dim": "value head size",
+    "core_head_dim": "the one head size the core ran at",
+    "padded": "whether any head was zero-padded to core_head_dim",
+}
 
 
 def validate_metrics(

@@ -346,6 +346,28 @@ def stack_record_of(t, tokens_shape) -> dict:
         rows, length = tokens_shape
         core = delta_rule_core(rows, length, t.num_heads, t.kda_head_dim, t.kda_head_dim, t.dtype, CHUNK)
         record["kda_core"] = {i: dict(core) for i, m in enumerate(mixers) if m == "kda"}
+    if "mla" in mixers:
+        from distributed_sigmoid_loss_tpu.models.mixers import (
+            FUSED_LANES,
+            latent_attention_core,
+            one_head_size,
+        )
+
+        # By the rule LatentAttention's call runs by: the query latent, what is
+        # rotated, which core each latent-attention layer takes ("flash" / "dense")
+        # and the one head size it runs at, zero-padded to or not.
+        core = latent_attention_core(t.attn_impl, t.dtype)
+        dqk = t.mla_qk_nope_dim + t.mla_qk_shared_dim
+        ran_at = one_head_size(dqk, t.mla_v_dim, FUSED_LANES if core == "flash" else 1)
+        rotated = t.pos == "rope"
+        made_of = {
+            "q_rank": t.mla_q_rank, "kv_rank": t.mla_kv_rank,
+            "rotated_dim": t.mla_qk_shared_dim if rotated else 0,
+            "rope_theta": t.rope_theta if rotated else None,
+            "core": core, "qk_dim": dqk, "v_dim": t.mla_v_dim, "core_head_dim": ran_at,
+            "padded": ran_at != dqk or ran_at != t.mla_v_dim,
+        }
+        record["mla"] = {i: dict(made_of) for i, m in enumerate(mixers) if m == "mla"}
     return record
 
 

@@ -166,8 +166,9 @@ class TextConfig:
     # False drops the bias of the blocks' attention and MLP projections.
     use_bias: bool = True
     # "rope" = rotary positions on q and k (rotate-half convention, positions
-    # 0..s-1, base rope_theta) in place of the learned ``pos_embed`` table;
-    # "none" = neither (causal mixers carry the order).
+    # 0..s-1, base rope_theta) in place of the learned ``pos_embed`` table: on
+    # the whole head of an "attn" layer, on the ``mla_qk_shared_dim`` wide parts
+    # of an "mla" layer; "none" = neither (causal mixers carry the order).
     pos: Literal["learned", "rope", "none"] = "learned"
     rope_theta: float = 10000.0
     # > 1 runs the whole stack (its ``depth`` layers, then the final norm) this
@@ -182,10 +183,11 @@ class TextConfig:
     # chunked gated delta rule behind a short causal convolution,
     # ops/gated_delta_rule.py) or "mla" (latent attention: keys and values
     # expanded from one low-rank latent, a key part shared by all heads, value
-    # heads narrower than key heads; models/mixers.py). Empty = "attn" in every
-    # layer, today's stack. A mixed stack is causal, takes ``pos="none"``, and
-    # runs its layers unrolled with remat per layer: no two neighbours share a
-    # parameter tree to scan over, so ``scan_layers`` does not apply to it.
+    # heads of their own width; models/mixers.py). Empty = "attn" in every
+    # layer, today's stack. A mixed stack is causal, takes ``pos="none"`` (or
+    # "rope" where no layer is a recurrence), and runs its layers unrolled with
+    # remat per layer: no two neighbours share a parameter tree to scan over, so
+    # ``scan_layers`` does not apply to it.
     mixers: tuple[str, ...] = ()
     # The first layers keep the dense MLP where ``moe_experts > 0``.
     leading_dense_layers: int = 0
@@ -199,6 +201,9 @@ class TextConfig:
     mla_qk_shared_dim: int = 64
     mla_v_dim: int = 128
     mla_kv_rank: int = 512
+    # > 0: the queries come through a latent too, x Wqa -> RMSNorm -> Wqb, this
+    # many channels wide; 0 = one projection.
+    mla_q_rank: int = 0
     # "sigmoid" = the router of the latent-attention language models: scores
     # sigmoid(x Wr) in float32, the ``moe_num_selected`` largest of scores + a
     # selection bias (a leaf that takes no gradient, decay or optimizer state),
@@ -239,7 +244,7 @@ BLOCK_OPTIONS = {
     "norm": "layernorm", "sandwich_norm": False, "mlp": "gelu", "use_bias": True,
     "pos": "learned", "loops": 1, "norm_eps": 1e-6, "mixers": (),
     "leading_dense_layers": 0, "moe_router": "softmax", "moe_route_scale": 1.0,
-    "moe_shared_experts": 0, "moe_hidden": 0, "moe_experts_held": 0,
+    "moe_shared_experts": 0, "moe_hidden": 0, "moe_experts_held": 0, "mla_q_rank": 0,
 }
 
 

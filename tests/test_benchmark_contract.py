@@ -15,7 +15,10 @@ BENCH_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file_
 if BENCH_DIR not in sys.path:
     sys.path.insert(0, BENCH_DIR)
 
-FILES = ("test_manifest", "test_flops", "test_flops_looped", "test_scopes", "test_flops_kimi", "test_scopes_kimi")
+FILES = (
+    "test_manifest", "test_flops", "test_flops_looped", "test_scopes", "test_flops_kimi", "test_scopes_kimi",
+    "test_flops_glm", "test_scopes_glm",
+)
 
 
 def _collect(stem: str) -> dict:

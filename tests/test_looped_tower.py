@@ -307,6 +307,8 @@ CHANGED = dict(
     # the options of a stack of several layer kinds (tests/test_hybrid_tower.py)
     norm_eps=1e-5, mixers=("kda", "mla"), leading_dense_layers=1, moe_router="sigmoid",
     moe_route_scale=2.446, moe_shared_experts=1, moe_hidden=24, moe_experts_held=4,
+    # latent attention's query latent (tests/test_glm_tower.py)
+    mla_q_rank=8,
 )
 
 
