@@ -5,6 +5,14 @@ names one. Both are causal and carry no bias; their statistics, gates and decays
 are float32 whatever the tower's dtype. The recurrence takes no position
 encoding; latent attention none, or a rotation of its shared-width parts.
 
+A KDA layer keeps every array (b, s, h x d), a head an aligned window of the
+lanes, as its kernels read and write them; its per-head statistics (the l2 norm
+of q and k, the RMS of o) run where the core runs (``ops/gated_delta_rule.py
+delta_rule_core``): on the head's tile inside the Pallas kernels, or in XLA on a
+(b, s, h, d) view around the chunked form (the CPU, float32). On a TPU the two
+shapes are two tilings, so a per-head view around a reduction over d is a copy
+through HBM each way (PERF.md section 6, PR 36).
+
 With x the (s, width) normalised stream of one sequence:
 
     KDA   q, k, v = silu(conv(x Wq)), silu(conv(x Wk)), silu(conv(x Wv))   # conv: causal depthwise, no bias
@@ -31,12 +39,11 @@ import jax
 import jax.numpy as jnp
 
 from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import (
-    chunk_gated_delta_rule,
+    normed_chunk_gated_delta_rule,
     short_causal_conv,
 )
 
 F32 = jnp.float32
-L2_EPS = 1e-6
 # The program's names for these layers' device time (benchmark/scopes_kimi.py):
 # flax writes the modules' own names ("kda", "mla") into every operation's path.
 KDA_CORE_SCOPE = "kda_core"  # the recurrence alone, inside "kda"
@@ -47,12 +54,6 @@ MLA_ROPE_SCOPE = "mla_rope"  # the rotation of the queries' and the key's shared
 CHUNK = 64
 # The blocked attention kernel's head sizes: a multiple of a vector register's lanes.
 FUSED_LANES = 128
-
-
-def l2norm(x):
-    """x / sqrt(sum x^2 + 1e-6) over the last axis, in float32."""
-    x = x.astype(F32)
-    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
 
 
 def _decay_rate_init(key, shape, dtype=F32):
@@ -79,7 +80,6 @@ class KdaMixer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        b, s, _ = x.shape
         h, d = self.num_heads, self.head_dim
         dense = partial(
             nn.Dense, use_bias=False, dtype=self.dtype,
@@ -93,24 +93,24 @@ class KdaMixer(nn.Module):
         def branch(name):
             y = dense(h * d, name=name)(x)
             taps = self.param(name + "_conv", conv_init, (self.conv_size, h * d), F32)
-            return nn.silu(short_causal_conv(y, taps)).reshape(b, s, h, d)
+            return nn.silu(short_causal_conv(y, taps))
 
+        # q and k go to the core raw, o comes back over its head's rms times the scale: the per-head
+        # norms run where the core runs, and nothing here is viewed (b, s, h, d).
         q, k, v = branch("q"), branch("k"), branch("v")
-        q = (l2norm(q) * d**-0.5).astype(self.dtype)
-        k = l2norm(k).astype(self.dtype)
         rate = jnp.exp(self.param("A_log", _decay_rate_init, (h,), F32))
         dt_bias = self.param("dt_bias", _dt_bias_init, (h * d,), F32)
         step = dense(h * d, name="f_b")(dense(d, name="f_a")(x)).astype(F32) + dt_bias
-        g = -rate[:, None] * jax.nn.softplus(step).reshape(b, s, h, d)
+        g = -jnp.repeat(rate, d) * jax.nn.softplus(step)
         beta = jax.nn.sigmoid(dense(h, name="beta")(x).astype(F32))
-        with jax.named_scope(KDA_CORE_SCOPE):
-            o = chunk_gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, dtype=self.dtype)
         scale = self.param("o_norm", nn.initializers.ones, (d,), F32)
-        o = o.astype(F32)
-        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + self.norm_eps) * scale
+        with jax.named_scope(KDA_CORE_SCOPE):
+            o = normed_chunk_gated_delta_rule(
+                q, k, v, g, beta, scale, o_eps=self.norm_eps, chunk=CHUNK, dtype=self.dtype
+            )
         gate = dense(h * d, name="g_b")(dense(d, name="g_a")(x)).astype(F32)
-        o = (o * jax.nn.sigmoid(gate).reshape(b, s, h, d)).astype(self.dtype)
-        return dense(self.width, name="out")(o.reshape(b, s, h * d))
+        o = (o * jax.nn.sigmoid(gate)).astype(self.dtype)
+        return dense(self.width, name="out")(o)
 
 
 def one_head_size(dqk: int, dv: int, multiple: int = 1) -> int:

@@ -297,7 +297,10 @@ def mixed_stack(step) -> dict | None:
     make under uniform routing, the tokens of a microbatch, the rows the
     dispatch is bounded by (every token choosing held experts only) and, per
     delta-rule layer (``kda_core``, by layer index), which core it took
-    (``"kernel"``: the Pallas kernels; ``"chunked"``: XLA operations) with the
+    (``"kernel"``: the Pallas kernels; ``"chunked"``: XLA operations), where
+    its per-head norms ran (``qk_norm``, the l2 norm of q and k, and ``o_norm``,
+    the head RMS norm of o: ``"kernel"``, on the head's tile inside the
+    kernels, or ``"xla"``, on a (b, s, h, d) view around the core) with the
     rows, heads and chunks of a call, and per latent-attention layer (``mla``,
     by layer index) what it is made of: the queries' latent (``q_rank``, 0 = one
     projection) and the keys' and values' (``kv_rank``), the width of the

@@ -125,7 +125,8 @@ STACK_RECORD_FIELDS = {
     "expected_local_assignments_per_token": "assignments to held experts a token makes under uniform routing",
     "tokens_per_microbatch": "text tokens one microbatch routes",
     "dispatch_rows_bound": "the sort's rows: every token choosing held experts only",
-    "kda_core": "per delta-rule layer: the core it took (kernel / chunked), rows, heads and chunks of a call",
+    "kda_core": "per delta-rule layer: the core it took (kernel / chunked), where its per-head norms ran "
+                "(qk_norm, o_norm: kernel / xla), rows, heads and chunks of a call",
     "mla": "per latent-attention layer: what it is made of (the fields below)",
 }
 # One latent-attention layer's entry of ``mla``.

@@ -43,11 +43,21 @@ axis, the operands are read where they lie ((b, s, h x d), a head an aligned
 chunk from the saved operands and the chunk's incoming state (which the
 differentiated forward writes: float32, 64 KB a chunk-head). The same halving,
 the same block recursion for the inverse, the same operand types: bf16-grade
-gradients, as the fused attention kernels', hence a bf16 tower only. What stays
-in XLA around them: the padding of a sequence that is no multiple of the chunk
-and the transposition of beta (2 MB). Under a ``jit`` over a mesh the kernels
-sit in the ``shard_map`` the attention kernels use. Every other call (float32
+gradients, as the fused attention kernels', hence a bf16 tower only. The
+mixer's per-head statistics run in them too (:func:`normed_chunk_gated_delta_rule`,
+the mixer's call): q's and k's l2 norm where a head's rows are loaded, forward
+and backward (the cotangents the kernels return are the raw branches'), and o
+over its head's root mean square on the float32 tile before it is stored, its
+backward from the stored o and each row's saved 1 / rms. What stays in XLA
+around them: the padding of a sequence that is no multiple of the chunk, the
+transposition of beta and of the saved 1 / rms (2 MB each), the head norm's
+scale (a product with a (h x d,) vector) and no per-head (b, s, h, d) array:
+on a TPU that shape and (b, s, h x d) are two tilings, and a view of one as the
+other around a reduction over d is a copy through HBM each way (PERF.md section
+6, PR 36). Under a ``jit`` over a mesh the kernels sit in a ``shard_map`` as the
+attention kernels do (:func:`_kernels_per_shard`). Every other call (float32
 operands, the CPU, a head size that is no multiple of 128) takes
+:func:`l2norm` and the head norm in XLA on the per-head form and between them
 :func:`_chunked` below: XLA operations, its backward ``jax.grad``'s, recomputed
 (``jax.checkpoint``) and run a few batch rows at a time (``lax.map``). It is
 the oracle next to :func:`gated_delta_rule_recurrent`; on the chip it is a
@@ -65,13 +75,21 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "gated_delta_rule_recurrent", "chunk_gated_delta_rule", "delta_rule_core", "short_causal_conv",
+    "gated_delta_rule_recurrent", "chunk_gated_delta_rule", "normed_chunk_gated_delta_rule", "delta_rule_core",
+    "l2norm", "short_causal_conv",
 ]
 
 F32 = jnp.float32
+L2_EPS = 1e-6
 # Float32 intermediates of one pass of the chunked form, in bytes per array: the
 # batch rows of a pass are chosen to stay under it.
 _PASS_BYTES = 48 * 2**20
+
+
+def l2norm(x):
+    """x / sqrt(sum x^2 + 1e-6) over the last axis, in float32."""
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
 
 
 def short_causal_conv(x, kernel):
@@ -185,20 +203,30 @@ def _rows_per_pass(b: int, s: int, h: int, dk: int) -> int:
 
 
 def delta_rule_core(rows: int, tokens: int, heads: int, dk: int, dv: int, dtype, chunk: int = 64) -> dict:
-    """Which core a call of :func:`chunk_gated_delta_rule` takes, from what it
-    can see, and the sizes of the call: ``core`` is ``"kernel"`` (the Pallas
-    kernels: bfloat16 operands, a TPU backend, dk = dv a multiple of 128, as
-    ``Attention`` and ``LatentAttention`` choose their fused kernels) or
-    ``"chunked"``. The mixer runs what this says and the step's trace-time
-    record (``train_step.stack_record_of``) reports it."""
+    """Which core a call of :func:`chunk_gated_delta_rule` or
+    :func:`normed_chunk_gated_delta_rule` takes, from what it can see, and the
+    sizes of the call: ``core`` is ``"kernel"`` (the Pallas kernels: bfloat16
+    operands, a TPU backend, dk = dv a multiple of 128, as ``Attention`` and
+    ``LatentAttention`` choose their fused kernels) or ``"chunked"``;
+    ``qk_norm`` and ``o_norm`` are where the mixer's l2 norm of q and k and its
+    head RMS norm of o run, which follow the core: ``"kernel"`` (on the head's
+    tile, inside ``kda_fwd`` / ``kda_bwd``) or ``"xla"``. The mixer runs what
+    this says and the step's trace-time record
+    (``train_step.stack_record_of``) reports it."""
     from distributed_sigmoid_loss_tpu.ops import flash_attention  # the towers' one question about the backend
 
     kernel = (
         jnp.dtype(dtype) == jnp.bfloat16 and flash_attention.flash_attention_available()
         and dk == dv and dk % 128 == 0
     )
-    return {"core": "kernel" if kernel else "chunked", "rows": rows, "heads": heads,
-            "chunks": -(-tokens // chunk)}
+    norms = "kernel" if kernel else "xla"
+    return {"core": "kernel" if kernel else "chunked", "qk_norm": norms, "o_norm": norms,
+            "rows": rows, "heads": heads, "chunks": -(-tokens // chunk)}
+
+
+def _power_of_two(chunk):
+    if chunk & (chunk - 1):
+        raise ValueError(f"chunk must be a power of two, got {chunk}")
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, dtype=None):
@@ -207,8 +235,7 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, dtype=None):
     operand type of the chunk's matrix products (default: v's); sums, decays,
     the triangular inverse and the carried state are float32. Returns (b, s, h,
     dv) in ``dtype``."""
-    if chunk & (chunk - 1):
-        raise ValueError(f"chunk must be a power of two, got {chunk}")
+    _power_of_two(chunk)
     dt = jnp.dtype(dtype or v.dtype)
     b, s, h, dk = q.shape
     pad = -s % chunk
@@ -216,14 +243,11 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, dtype=None):
         q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v, g))
         beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
     if delta_rule_core(b, s, h, dk, v.shape[-1], dt, chunk)["core"] == "kernel":
-        from distributed_sigmoid_loss_tpu.models.transformer import _fused_attention_per_shard
         from distributed_sigmoid_loss_tpu.ops.pallas_delta_rule import delta_rule_kernel
 
-        # a Mosaic kernel under a multi-chip jit sits in a shard_map
-        out = _fused_attention_per_shard(
-            partial(delta_rule_kernel, chunk=chunk), q.astype(dt), k.astype(dt), v.astype(dt), g, beta
-        )
-        return out[:, :s]
+        wide = (x.reshape(b, s + pad, -1) for x in (q.astype(dt), k.astype(dt), v.astype(dt), g))  # free views
+        out = _kernels_per_shard(partial(delta_rule_kernel, chunk=chunk), *wide, beta)
+        return out.reshape(b, s + pad, h, -1)[:, :s]
     core = jax.checkpoint(partial(_chunked, chunk=chunk, dt=dt))
     rows = _rows_per_pass(b, s + pad, h, dk)
     if rows == b:
@@ -232,3 +256,58 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, dtype=None):
         passes = tuple(x.reshape(b // rows, rows, *x.shape[1:]) for x in (q, k, v, g, beta))
         out = jax.lax.map(lambda xs: core(*xs), passes).reshape(b, s + pad, h, -1)
     return out[:, :s].astype(dt)
+
+
+def _kernels_per_shard(kernel, *wide):
+    """``models/transformer.py _fused_attention_per_shard`` for operands with
+    the heads on the lanes ((b, s, h x d); beta (b, s, h)): under a ``jit`` over
+    several chips a Mosaic kernel sits in a ``shard_map``, rows over ``dp`` and
+    whole heads over ``tp`` where those axes exist and divide."""
+    from jax.sharding import PartitionSpec as P
+
+    from distributed_sigmoid_loss_tpu.models.transformer import DP_AXIS, TP_AXIS
+
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = frozenset(mesh.axis_names) - frozenset(mesh.manual_axes)
+    if not auto or mesh.size == 1:
+        return kernel(*wide)
+    b, _, h = wide[-1].shape  # beta
+
+    def split(axis, n):
+        return axis if axis in auto and n % mesh.shape[axis] == 0 else None
+
+    spec = P(split(DP_AXIS, b), None, split(TP_AXIS, h))
+    return jax.shard_map(
+        kernel, in_specs=(spec,) * len(wide), out_specs=spec, axis_names=auto, check_vma=False
+    )(*wide)
+
+
+def normed_chunk_gated_delta_rule(q, k, v, g, beta, o_scale, *, o_eps: float, chunk: int = 64, dtype=None):
+    """The mixer's call, the rule between its per-head norms: from the RAW q
+    and k, q_h = l2norm(q_h) dk^-1/2 and k_h = l2norm(k_h), o of the recurrence,
+    and of o the head RMS norm, o_h rsqrt(mean o_h^2 + ``o_eps``) ``o_scale``
+    ((dv,)). Every operand has its heads on the lanes: q, k, g (float32
+    log-decay <= 0): (b, s, h x dk); v: (b, s, h x dv); beta: (b, s, h).
+    Returns (b, s, h x dv) float32. Where :func:`delta_rule_core` says
+    ``"kernel"`` the norms run inside the kernels, on the head's tile (which
+    stores what it normalised in ``dtype``, default v's), and no per-head (b, s,
+    h, d) array exists around them; anywhere else :func:`l2norm` in XLA, then
+    :func:`chunk_gated_delta_rule` on the per-head form, then the head norm of
+    its float32 output."""
+    _power_of_two(chunk)
+    dt = jnp.dtype(dtype or v.dtype)
+    b, s, h = beta.shape
+    dk, dv = q.shape[-1] // h, v.shape[-1] // h
+    if delta_rule_core(b, s, h, dk, dv, dt, chunk)["core"] == "kernel":
+        from distributed_sigmoid_loss_tpu.ops.pallas_delta_rule import delta_rule_kernel
+
+        operands = (q.astype(dt), k.astype(dt), v.astype(dt), g, beta)
+        if s % chunk:  # a zero row stays zero under both norms: inert, as in chunk_gated_delta_rule
+            operands = tuple(jnp.pad(x, ((0, 0), (0, -s % chunk), (0, 0))) for x in operands)
+        kernel = partial(delta_rule_kernel, chunk=chunk, qk_norm=True, o_eps=o_eps)
+        return _kernels_per_shard(kernel, *operands)[:, :s].astype(F32) * jnp.tile(o_scale, h)
+    q, k, v, g = (x.reshape(b, s, h, -1) for x in (q, k, v, g))
+    q, k = (l2norm(q) * dk**-0.5).astype(dt), l2norm(k).astype(dt)
+    o = chunk_gated_delta_rule(q, k, v, g, beta, chunk=chunk, dtype=dt).astype(F32)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + o_eps) * o_scale
+    return o.reshape(b, s, h * dv)

@@ -27,7 +27,14 @@ one chunk. The grid is (rows, head groups, chunks), chunks innermost and in orde
   the levels side by side (:func:`_inverses`), which halved the forward on the
   chip (PERF.md section 6, PR 34);
 - W, U, O and the next state as in ``_chunked``; the state is kept transposed
-  (dv x dk), so its decay is a multiply along lanes.
+  (dv x dk), so its decay is a multiply along lanes;
+- the mixer's per-head statistics, where the call asks for them (the mixer's
+  does: ``qk_norm``, ``o_eps``), on the (C, d) tile a head is here anyway: a
+  raw head of q and k is l2-normalised row by row where it is loaded (a lane
+  reduction of eight registers, in float32, then the cast XLA would have made
+  before HBM: :func:`_l2norm`), and o leaves over its rows' root mean square
+  (:func:`_rms`). Around the kernels these would be reductions over d of a
+  (b, s, h, d) view of a (b, s, h x d) array, on a TPU a copy each way.
 
 The backward recomputes all of that from the saved operands and the chunk's
 incoming state, and is the gradient of the same function: products take
@@ -35,7 +42,14 @@ operands of the tower's dtype where the forward's do, the inverse's cotangent
 -T^T dT T^T is float32. G enters a chunk only through factors (row e^G) and
 (column e^-G), so its cotangent is accumulated from the scaled operands'
 (+ for a level's row tokens, - for its column tokens; the reference token's
-cancels) and g's is one reversed running sum of it.
+cancels) and g's is one reversed running sum of it. The l2 norms' cotangents
+rs (d - n (n . d)) are applied to the float32 d_q and d_k before their final
+cast, so what is returned are the raw operands' cotangents. The head norm's
+backward comes first and waits for nothing the chunk recomputes: it reads the
+normalised o the forward stored and each row's 1 / rms, which the
+differentiated forward writes beside the states (recomputed from the chunk's
+own o it would hold every product of the backward back: 4.2 ms a call on the
+chip, PERF.md section 6, PR 36).
 """
 
 from __future__ import annotations
@@ -48,6 +62,8 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import L2_EPS
 
 __all__ = ["delta_rule_kernel", "heads_per_program"]
 
@@ -251,13 +267,46 @@ def _cotangents_of_operands(q, k, v, beta, e, a0, f, c, d_a, ops, level):
     return d_q, d_k, c["d_vb"] * beta, d_g, d_beta
 
 
-def _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads):
+def _l2norm(x, scale=1.0):
+    """A head's raw (C, d) window as the mixer feeds the rule: ``l2norm`` of
+    ops/gated_delta_rule.py row by row in float32 (a lane reduction), times
+    ``scale``, in x's dtype: the arithmetic XLA runs where the kernels do not,
+    in its order. Also the unit rows and their 1 / norm, for the cotangent."""
+    xf = x.astype(F32)
+    rs = lax.rsqrt(jnp.sum(xf * xf, -1, keepdims=True) + L2_EPS)
+    unit = xf * rs
+    return (unit if scale == 1.0 else unit * scale).astype(x.dtype), unit, rs
+
+
+def _l2norm_cotangent(x, d, scale=1.0):
+    """The cotangent of the raw window ``x`` from the float32 cotangent ``d`` of
+    ``_l2norm(x, scale)``: rs (d - n (n . d)), one more lane reduction."""
+    _, unit, rs = _l2norm(x)
+    if scale != 1.0:
+        d = d * scale
+    return rs * (d - unit * jnp.sum(unit * d, -1, keepdims=True))
+
+
+def _rms(out, eps):
+    """A head's float32 (C, dv) output tile over its rows' root mean square
+    (the mixer's head norm before its scale), and 1 / that, (C, 1)."""
+    r = lax.rsqrt(jnp.mean(out * out, -1, keepdims=True) + eps)
+    return out * r, r
+
+
+def _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads, qk_norm):
     """What both kernels start with, per head of the program: its operands (q,
-    k, v, g, beta (C, 1): aligned lane windows of the blocks), its scores, and
-    the inverses, the heads side by side."""
+    k, v, g, beta (C, 1): aligned lane windows of the blocks; q and k
+    normalised here where they arrive raw, ``qk_norm``), its scores, and the
+    inverses, the heads side by side."""
     dk, dv = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
+
+    def rows(ref, j, scale):
+        x = ref[0, :, j * dk:(j + 1) * dk]
+        return _l2norm(x, scale)[0] if qk_norm else x
+
     operands = [
-        (q_ref[0, :, j * dk:(j + 1) * dk], k_ref[0, :, j * dk:(j + 1) * dk], v_ref[0, :, j * dv:(j + 1) * dv],
+        (rows(q_ref, j, dk**-0.5), rows(k_ref, j, 1.0), v_ref[0, :, j * dv:(j + 1) * dv],
          g_ref[0, :, j * dk:(j + 1) * dk], beta_ref[0, 0, :, j:j + 1])
         for j in range(heads)
     ]
@@ -267,9 +316,10 @@ def _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads):
 
 
 def _fwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
-                heads, save_states):
-    states_ref = rest[0] if save_states else None
-    z_ref = rest[-1]
+                heads, save_states, qk_norm, o_eps):
+    # the differentiated forward also writes each chunk's incoming state and, with the head norm, each row's 1 / rms
+    *saved, z_ref = rest
+    states_ref, r_ref = (*saved, None, None)[:2]
     dk, dv = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
 
     @pl.when(pl.program_id(2) == 0)
@@ -277,18 +327,29 @@ def _fwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
         z_ref[...] = jnp.zeros_like(z_ref)
 
     ops, level = ops_ref[...], level_ref[...]
-    operands, scores, inverses = _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads)
+    operands, scores, inverses = _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads, qk_norm)
+    lane = lax.broadcasted_iota(jnp.int32, (level.shape[0], heads), 1)
+    rs = jnp.zeros(lane.shape, F32)
     for j, ((q, k, v, _, beta), (e, _, p), t_inv) in enumerate(zip(operands, scores, inverses)):
         z = z_ref[j]
         if save_states:
             states_ref[0, 0, :, j * dk:(j + 1) * dk] = z
         f = _outputs(q, k, v, beta, z, e, p, t_inv)
-        o_ref[0, :, j * dv:(j + 1) * dv] = f["out"].astype(o_ref.dtype)
+        out = f["out"]
+        if o_eps is not None:
+            out, r = _rms(out, o_eps)
+            rs = jnp.where(lane == j, r, rs)  # a program's heads on the lanes, as beta
+        o_ref[0, :, j * dv:(j + 1) * dv] = out.astype(o_ref.dtype)
         z_ref[j] = f["z_next"]
+    if r_ref is not None:
+        r_ref[0, 0] = rs
 
 
-def _bwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dz_ref, *, heads):
+def _bwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref, *rest,
+                heads, qk_norm, o_norm):
+    # with the head norm: the normalised output the forward stored and its saved 1 / rms
+    unit_ref, r_ref = rest[:2] if o_norm else (None, None)
+    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dz_ref = rest[-6:]
     dk, dv = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
 
     @pl.when(pl.program_id(2) == 0)
@@ -297,12 +358,17 @@ def _bwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, states
 
     ops, level = ops_ref[...], level_ref[...]
     chunk = level.shape[0]
-    operands, scores, inverses = _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads)
+    operands, scores, inverses = _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads, qk_norm)
     forwards, partials = [], []
     for j, ((q, k, v, _, beta), (e, _, p), t_inv) in enumerate(zip(operands, scores, inverses)):
         z = states_ref[0, 0, :, j * dk:(j + 1) * dk]
         f = _outputs(q, k, v, beta, z, e, p, t_inv)
-        c = _cotangents_to_inverse(f, z, do_ref[0, :, j * dv:(j + 1) * dv], dz_ref[j])
+        d_out = do_ref[0, :, j * dv:(j + 1) * dv]
+        if o_norm:  # the head norm's backward, from what the forward left: nothing here waits for the chunk's recomputation
+            unit, d_unit = unit_ref[0, :, j * dv:(j + 1) * dv].astype(F32), d_out.astype(F32)
+            d_out = r_ref[0, 0, :, j:j + 1] * (d_unit - unit * jnp.mean(unit * d_unit, -1, keepdims=True))
+            d_out = d_out.astype(do_ref.dtype)
+        c = _cotangents_to_inverse(f, z, d_out, dz_ref[j])
         dz_ref[j] = c["dz"]
         forwards.append(f)
         partials.append(c)
@@ -315,6 +381,9 @@ def _bwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, states
     for j, ((q, k, v, _, beta), (e, a0, _), f, c, d_a) in enumerate(zip(operands, scores, forwards, partials, d_as)):
         d_q, d_k, d_v, d_g, d_beta = _cotangents_of_operands(q, k, v, beta, e, a0, f, c, d_a, ops, level)
         lk, lv = slice(j * dk, (j + 1) * dk), slice(j * dv, (j + 1) * dv)
+        if qk_norm:  # the cotangents of the raw branches, before the final cast
+            d_q = _l2norm_cotangent(q_ref[0, :, lk], d_q, dk**-0.5)
+            d_k = _l2norm_cotangent(k_ref[0, :, lk], d_k)
         dq_ref[0, :, lk] = d_q.astype(dq_ref.dtype)
         dk_ref[0, :, lk] = d_k.astype(dk_ref.dtype)
         dv_ref[0, :, lv] = d_v.astype(dv_ref.dtype)
@@ -385,60 +454,80 @@ def _chunk_flops(chunk, dk, dv, backward):
 
 
 def _operands(q, k, v, g, beta, heads):
-    b, s = q.shape[:2]
-    wide = [x.reshape(b, s, -1) for x in (q, k, v, g.astype(F32))]  # free: heads stay on the lanes
-    return [("token", x) for x in wide] + [("beta", _by_group(beta.astype(F32), heads))]
+    return [("token", x) for x in (q, k, v, g.astype(F32))] + [("beta", _by_group(beta.astype(F32), heads))]
 
 
-def _forward(q, k, v, g, beta, chunk, interpret, save_states):
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
-    heads = heads_per_program(h)
-    outs = [("token", jax.ShapeDtypeStruct((b, s, h * dv), v.dtype))]
+def _sizes(q, v, beta):
+    (b, s, h), heads = beta.shape, heads_per_program(beta.shape[-1])
+    return dict(b=b, s=s, h=h, dk=q.shape[-1] // h, dv=v.shape[-1] // h, heads=heads)
+
+
+def _forward(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps, save_states):
+    sizes = _sizes(q, v, beta)
+    b, s, h, dk, dv, heads = (sizes[x] for x in ("b", "s", "h", "dk", "dv", "heads"))
+    outs = [("token", jax.ShapeDtypeStruct(v.shape, v.dtype))]
     if save_states:
         outs.append(("state", jax.ShapeDtypeStruct((b, s // chunk, dv, h * dk), F32)))
-    out, *states = _call(
-        functools.partial(_fwd_kernel, heads=heads, save_states=save_states), "kda_fwd",
-        _operands(q, k, v, g, beta, heads), outs,
-        b=b, s=s, h=h, dk=dk, dv=dv, chunk=chunk, heads=heads, backward=False, interpret=interpret)
-    return out.reshape(b, s, h, dv), states
+        if o_eps is not None:
+            outs.append(("beta", jax.ShapeDtypeStruct((b, h // heads, s, heads), F32)))
+    out, *saved = _call(
+        functools.partial(_fwd_kernel, heads=heads, save_states=save_states, qk_norm=qk_norm, o_eps=o_eps),
+        "kda_fwd", _operands(q, k, v, g, beta, heads), outs,
+        **sizes, chunk=chunk, backward=False, interpret=interpret)
+    return out, saved
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def delta_rule_kernel(q, k, v, g, beta, chunk: int = 64, interpret: bool = False):
-    """o of the gated delta rule through the kernels. q, k, g: (b, s, h, dk); v:
-    (b, s, h, dv); beta: (b, s, h); s a multiple of ``chunk``, a power of two.
-    Returns (b, s, h, dv) in v's dtype. Differentiated, it saves its operands
-    and each chunk's incoming state (float32, b x s / chunk x h x dk x dv: under
-    a rematerialised layer they live from the layer's second forward to its
-    backward). ``interpret=True`` runs the Pallas interpreter (CPU testing)."""
-    return _forward(q, k, v, g, beta, chunk, interpret, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _wide_kernel(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps):
+    return _forward(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps, False)[0]
 
 
-def _vjp_fwd(q, k, v, g, beta, chunk, interpret):
-    out, (states,) = _forward(q, k, v, g, beta, chunk, interpret, True)
-    return out, (q, k, v, g, beta, states)
+def _vjp_fwd(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps):
+    out, (states, *r) = _forward(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps, True)
+    return out, (q, k, v, g, beta, states, *((out, *r) if o_eps is not None else ()))
 
 
-def _vjp_bwd(chunk, interpret, residuals, d_out):
-    q, k, v, g, beta, states = residuals
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
-    heads = heads_per_program(h)
+def _vjp_bwd(chunk, interpret, qk_norm, o_eps, residuals, d_out):
+    q, k, v, g, beta, states, *normed = residuals  # with the head norm: the normalised output and 1 / rms
+    sizes = _sizes(q, v, beta)
+    b, s, h, heads = (sizes[x] for x in ("b", "s", "h", "heads"))
 
-    def token(d, dtype):
-        return ("token", jax.ShapeDtypeStruct((b, s, h * d), dtype))
+    def like(x, dtype=None):
+        return ("token", jax.ShapeDtypeStruct(x.shape, dtype or x.dtype))
 
     d_q, d_k, d_v, d_g, d_beta = _call(
-        functools.partial(_bwd_kernel, heads=heads), "kda_bwd",
-        [*_operands(q, k, v, g, beta, heads), ("state", states),
-         ("token", d_out.astype(v.dtype).reshape(b, s, -1))],
-        [token(dk, q.dtype), token(dk, k.dtype), token(dv, v.dtype), token(dk, F32),
-         ("beta", jax.ShapeDtypeStruct((b, h // heads, s, heads), F32))],
-        b=b, s=s, h=h, dk=dk, dv=dv, chunk=chunk, heads=heads, backward=True, interpret=interpret)
-    d_beta = jnp.transpose(d_beta, (0, 2, 1, 3)).reshape(b, s, h)
-    return (d_q.reshape(q.shape), d_k.reshape(k.shape), d_v.reshape(v.shape),
-            d_g.reshape(g.shape).astype(g.dtype), d_beta.astype(beta.dtype))
+        functools.partial(_bwd_kernel, heads=heads, qk_norm=qk_norm, o_norm=o_eps is not None), "kda_bwd",
+        [*_operands(q, k, v, g, beta, heads), ("state", states), ("token", d_out.astype(v.dtype)),
+         *zip(("token", "beta"), normed)],
+        [like(q), like(k), like(v), like(g, F32), ("beta", jax.ShapeDtypeStruct((b, h // heads, s, heads), F32))],
+        **sizes, chunk=chunk, backward=True, interpret=interpret)
+    d_beta = jnp.transpose(d_beta, (0, 2, 1, 3)).reshape(beta.shape)
+    return d_q, d_k, d_v, d_g.astype(g.dtype), d_beta.astype(beta.dtype)
 
 
-delta_rule_kernel.defvjp(_vjp_fwd, _vjp_bwd)
+_wide_kernel.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+def delta_rule_kernel(q, k, v, g, beta, chunk: int = 64, interpret: bool = False, qk_norm: bool = False,
+                      o_eps: float | None = None):
+    """o of the gated delta rule through the kernels. q, k, g: (b, s, h x dk),
+    a head an aligned 128-lane window, as the kernels read them; v: (b, s, h x
+    dv); beta: (b, s, h); s a multiple of ``chunk``, a power of two. Returns (b,
+    s, h x dv) in v's dtype. The mixer's per-head statistics run on the tile the
+    program holds, forward and backward. With ``qk_norm`` q and k arrive raw:
+    q_h = l2norm(q_h) dk^-1/2, k_h = l2norm(k_h) at the load, and the cotangents
+    are the raw operands'. With ``o_eps`` what is returned is o over its head's
+    root mean square, o_h rsqrt(mean o_h^2 + o_eps) (the head RMS norm before
+    its scale), taken on the float32 tile before it is stored. The per-head form
+    (b, s, h, d) is taken too and returned (free views around the same call).
+    Differentiated, it saves its operands as they came, each chunk's incoming
+    state (float32, b x s / chunk x h x dk x dv: under a rematerialised layer
+    they live from the layer's second forward to its backward) and, with
+    ``o_eps``, what it returned and each row's 1 / rms (float32, b x s x h), so
+    that the norm's backward waits for nothing the chunk recomputes.
+    ``interpret=True`` runs the Pallas interpreter (CPU testing)."""
+    if q.ndim == 3:
+        return _wide_kernel(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps)
+    b, s, h, _ = q.shape
+    wide = (x.reshape(b, s, -1) for x in (q, k, v, g))
+    return _wide_kernel(*wide, beta, chunk, interpret, qk_norm, o_eps).reshape(b, s, h, -1)

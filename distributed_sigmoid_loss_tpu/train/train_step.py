@@ -342,7 +342,8 @@ def stack_record_of(t, tokens_shape) -> dict:
         from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import delta_rule_core
 
         # By the rule KdaMixer's call runs by: which core each delta-rule layer takes
-        # ("kernel" / "chunked") and the rows, heads and chunks of a call.
+        # ("kernel" / "chunked"), where its per-head norms run ("kernel" / "xla") and
+        # the rows, heads and chunks of a call.
         rows, length = tokens_shape
         core = delta_rule_core(rows, length, t.num_heads, t.kda_head_dim, t.kda_head_dim, t.dtype, CHUNK)
         record["kda_core"] = {i: dict(core) for i, m in enumerate(mixers) if m == "kda"}

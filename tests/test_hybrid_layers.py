@@ -26,6 +26,8 @@ from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import (
     chunk_gated_delta_rule,
     delta_rule_core,
     gated_delta_rule_recurrent,
+    normed_chunk_gated_delta_rule,
+    l2norm,
 )
 from distributed_sigmoid_loss_tpu.parallel.ring_attention import dense_attention
 
@@ -179,7 +181,8 @@ def test_a_sequence_that_is_no_multiple_of_the_chunk_through_the_kernels(monkeyp
     as_kernel_call(monkeypatch)
     args = delta_rule_inputs(70, jnp.bfloat16, b=1, h=1, dk=128, dv=128)
     weight = ripple(args[2].shape)
-    assert delta_rule_core(1, 70, 1, 128, 128, jnp.bfloat16) == {"core": "kernel", "rows": 1, "heads": 1, "chunks": 2}
+    assert delta_rule_core(1, 70, 1, 128, 128, jnp.bfloat16) == {
+        "core": "kernel", "qk_norm": "kernel", "o_norm": "kernel", "rows": 1, "heads": 1, "chunks": 2}
     got_out, got = value_and_grads(lambda *a: chunk_gated_delta_rule(*a).astype(jnp.float32), args, weight)
     want_out, want = value_and_grads(gated_delta_rule_recurrent, args, weight)
     out = chunk_gated_delta_rule(*args)
@@ -223,6 +226,207 @@ def test_repeated_keys_do_not_cancel_in_the_kernels_inverse():
         assert reference_kimi._base.max_rel_err(g_, w) < 5e-2, name
 
 
+# -- (a'') the norms inside the kernels: raw q and k, the heads on the lanes --------------
+
+O_EPS = 1e-5
+
+
+def raw_inputs(s, h, norm=None, zero_key_at=None, d=128):
+    """The mixer's operands as it hands them to the core: q, k raw (any row
+    norm; ``norm`` sets it), every operand (b, s, h x d) in bf16, g float32,
+    and the head norm's scale (d,)."""
+    q, k, v, g, beta = delta_rule_inputs(s, jnp.float32, b=1, h=h, dk=d, dv=d)
+    rows = jnp.exp(jax.random.normal(jax.random.key(s + h), (2, 1, s, h, 1))) if norm is None else jnp.full((2, 1, 1, 1, 1), norm)
+    q, k = q * d**0.5 * rows[0], k * rows[1]  # delta_rule_inputs normalised them: undo, then scale
+    if zero_key_at is not None:
+        k = k.at[:, zero_key_at].set(0.0)
+    scale = 1.0 + 0.3 * jax.random.normal(jax.random.key(7), (d,))
+    return tuple(x.reshape(1, s, -1).astype(jnp.bfloat16) for x in (q, k, v)) + (g.reshape(1, s, -1), beta, scale)
+
+
+def on_raw(rule):
+    """A rule of normalised per-head operands as a function of the raw wide
+    ones, with the head norm of its output where a scale comes too: what the
+    mixer runs where the kernels do not."""
+    def fn(q, k, v, g, beta, scale=None):
+        b, s, h = beta.shape
+        q, k, v, g = (x.reshape(b, s, h, -1) for x in (q, k, v, g))
+        q, k = (l2norm(q) * q.shape[-1] ** -0.5).astype(q.dtype), l2norm(k).astype(k.dtype)
+        o = rule(q, k, v, g, beta)
+        if scale is not None:
+            o = o.astype(jnp.float32)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + O_EPS) * scale
+        return o.reshape(b, s, -1)
+    return fn
+
+
+def six_grads(fn, args, weight):
+    return jax.jit(jax.grad(lambda *a: (fn(*a).astype(jnp.float32) * weight).sum(), argnums=tuple(range(6))))(*args)
+
+
+@pytest.mark.parametrize("tokens, heads, norm, zero_key_at", [
+    (128, 1, None, None), (64, 4, None, None), (70, 2, None, None), (64, 1, None, 5), (64, 1, 1e-3, None), (64, 1, 1e3, None),
+], ids=["one-head", "four-heads", "padded", "zero-key", "rows-of-1e-3", "rows-of-1e3"])
+def test_the_kernels_with_the_norms_inside_are_the_norms_around_the_rule(monkeypatch, tokens, heads, norm, zero_key_at):
+    """The normalised o and all six cotangents (of the RAW q and k, of v, g,
+    beta and the head norm's scale) from the kernels that normalise on the
+    head's tile, against l2norm in XLA, the chunked form or the recurrence, and
+    the head norm in XLA: the bounds of the kernel tests above, but that the
+    kernels round o after its norm where the chunked form rounds before it (a
+    bf16 step on entries of any size: 1e-2). A zero key takes the eps path
+    (rs = 1e3, its cotangent rs d), rows of norm 1e-3 stand beside eps, rows of
+    1e3 far above it."""
+    from distributed_sigmoid_loss_tpu.ops import gated_delta_rule
+
+    as_kernel_call(monkeypatch)
+    args = raw_inputs(tokens, heads, norm, zero_key_at)
+    weight = ripple(args[2].shape)
+    pad = -tokens % 64
+    chunked = on_raw(lambda *a: jax.checkpoint(partial(gated_delta_rule._chunked, chunk=64, dt=jnp.bfloat16))(
+        *(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in a))[:, :tokens].astype(jnp.bfloat16))
+    kernels = partial(normed_chunk_gated_delta_rule, o_eps=O_EPS)
+    out = jax.jit(kernels)(*args)
+    assert out.shape == args[2].shape and out.dtype == jnp.float32
+    assert reference_kimi._base.max_rel_err(out, jax.jit(on_raw(gated_delta_rule_recurrent))(*args)) < 5e-2
+    assert reference_kimi._base.max_rel_err(out, jax.jit(chunked)(*args)) < 1e-2
+    got, want, twin = (six_grads(fn, args, weight) for fn in (kernels, on_raw(gated_delta_rule_recurrent), chunked))
+    # the zero key's row is a thousand times the others (rs = 1e3), so there the measure is one row's own
+    # relative error, not a tensor's largest entry's: bf16's grade, as against the recurrence
+    twin_bound = 1.5e-2 if zero_key_at is None else 5e-2
+    for name, g, w, t in zip("q k v g beta scale".split(), got, want, twin):
+        assert g.dtype == w.dtype and g.shape == w.shape and bool(jnp.isfinite(g.astype(jnp.float32)).all()), name
+        assert reference_kimi._base.max_rel_err(g, w) < 5e-2, name
+        assert reference_kimi._base.max_rel_err(g, t) < twin_bound, name
+    if zero_key_at is not None:  # the eps path moved something: the zero key's cotangent is not zero
+        assert float(jnp.abs(got[1][0, zero_key_at].astype(jnp.float32)).max()) > 0
+
+
+def test_the_per_head_call_form_and_the_wide_one_are_one_kernel():
+    """The kernels' entry takes the heads on the lanes, as the mixer hands them,
+    or (b, s, h, d), as the tests above do: free views around one call. Each
+    norm is an option of its own: without ``qk_norm`` q and k are used as they
+    come, without ``o_eps`` o is returned as it is."""
+    q, k, v, g, beta, _ = raw_inputs(64, 2)
+    per_head = tuple(x.reshape(1, 64, 2, 128) for x in (q, k, v, g))
+    for kw in ({}, {"qk_norm": True}, {"o_eps": O_EPS}, {"qk_norm": True, "o_eps": O_EPS}):
+        wide = kernel_rule(q, k, v, g, beta, **kw)
+        assert wide.shape == (1, 64, 256) and wide.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(kernel_rule(*per_head, beta, **kw).reshape(1, 64, 256), wide)
+    normed = on_raw(kernel_rule)(q, k, v, g, beta)
+    # the same arithmetic in the same order: l2norm in XLA before the kernels, or inside them
+    assert reference_kimi._base.max_rel_err(kernel_rule(q, k, v, g, beta, qk_norm=True), normed) < 1e-6
+    assert reference_kimi._base.max_rel_err(kernel_rule(q, k, v, g, beta), normed) > 1e-2
+
+
+def test_the_kernels_sit_in_a_shard_map_under_a_jit_over_several_chips(monkeypatch):
+    """A Mosaic kernel cannot be partitioned automatically: under a jit traced on
+    a mesh (``parallel.mesh.trace_on``, as the train step does) the mixer's call
+    hands the kernels their LOCAL rows, the heads on the lanes; with no mesh in
+    the trace the kernels are called bare. The same numbers either way."""
+    import contextlib
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_delta_rule
+    from distributed_sigmoid_loss_tpu.parallel.mesh import make_mesh, trace_on
+
+    seen, real = [], pallas_delta_rule.delta_rule_kernel
+
+    def interpreted(q, *a, **kw):
+        seen.append(q.shape)
+        return real(q, *a, interpret=True, **kw)
+
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
+    monkeypatch.setattr(pallas_delta_rule, "delta_rule_kernel", interpreted)
+    mesh = make_mesh(2)
+    one = raw_inputs(64, 2)
+    args = tuple(jax.device_put(jnp.concatenate([x, x[::-1]] * 2), NamedSharding(mesh, P("dp"))) for x in one[:5]) + one[5:]
+    weight = ripple(args[2].shape)
+
+    def grads(on_mesh):
+        def loss(*a):
+            with trace_on(mesh) if on_mesh else contextlib.nullcontext():
+                return (normed_chunk_gated_delta_rule(*a, o_eps=O_EPS) * weight).sum()
+        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6))))(*args)
+
+    want, want_grads = grads(False)
+    assert seen and {shape for shape in seen} == {(4, 64, 256)}
+    seen.clear()
+    got, got_grads = grads(True)
+    assert seen and {shape for shape in seen} == {(2, 64, 256)}  # a chip's rows
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for name, g, w in zip("q k v g beta scale".split(), got_grads, want_grads):
+        assert reference_kimi._base.max_rel_err(g, w) < 1e-5, name
+    assert got_grads[0].sharding.spec == P("dp")
+
+
+# -- (a3) the layer around the kernels stays on (b, s, h x d) -----------------------------
+
+
+def kda_layer_loss(tokens=70, heads=2, d=128):
+    """A delta-rule layer in bf16 at the kernels' head size, moved parameters, and
+    a loss of its output as a function of (params, x)."""
+    from distributed_sigmoid_loss_tpu.models.mixers import KdaMixer
+
+    layer = KdaMixer(width=64, num_heads=heads, head_dim=d, conv_size=4, dtype=jnp.bfloat16)
+    x = jax.random.normal(jax.random.key(0), (2, tokens, 64), jnp.bfloat16)
+    params = moved(layer.init(jax.random.key(1), x)["params"])
+    weight = ripple((2, tokens, 64))
+    return (lambda p, x: (layer.apply({"params": p}, x).astype(jnp.float32) * weight).sum()), layer, params, x
+
+
+def test_a_delta_rule_layer_on_the_kernel_path_is_the_layer_on_the_chunked_path(monkeypatch):
+    loss, layer, params, x = kda_layer_loss()
+
+    def run():  # fresh functions a call: each trace asks the backend question again
+        out = jax.jit(lambda p, x: layer.apply({"params": p}, x))(params, x)
+        return out, *jax.jit(jax.grad(lambda p, x: loss(p, x), argnums=(0, 1)))(params, x)
+
+    want_out, want, want_x = run()
+    as_kernel_call(monkeypatch)
+    got_out, got, got_x = run()
+    assert got_out.dtype == jnp.bfloat16 and not np.array_equal(got_out, want_out)  # another path did run
+    assert reference_kimi._base.max_rel_err(got_out, want_out) < 1.5e-2
+    errs = reference_kimi.tree_max_rel_err(got, want)
+    assert len(errs) == len(jax.tree.leaves(params)) == 15
+    assert max(errs.values()) < 5e-2, max(errs, key=errs.get)
+    assert reference_kimi._base.max_rel_err(got_x, want_x) < 5e-2
+
+
+def per_head_values(jaxpr, heads, d):
+    """Every rank-4 (b, s, h, d) value of a jaxpr outside its ``pallas_call``s, as
+    (primitive, shape): what a per-head view of a (b, s, h x d) array leaves."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        found += [(eqn.primitive.name, v.aval.shape) for v in (*eqn.invars, *eqn.outvars)
+                  if getattr(v.aval, "ndim", 0) == 4 and v.aval.shape[2:] == (heads, d)]
+        for value in eqn.params.values():  # a jit's, a remat's or a custom rule's body
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    found += per_head_values(inner, heads, d)
+    return found
+
+
+def test_no_per_head_array_exists_around_the_kernels(monkeypatch):
+    """On a TPU (b, s, h, d) and (b, s, h x d) are two tilings, so a per-head
+    view of a wide array around a reduction over d is a copy through HBM each
+    way (PERF.md section 6, PR 36). On the kernel path the layer, forward and
+    backward, holds no such value: no reshape to or from one, no
+    ``broadcast_in_dim`` to one; the per-head statistics run inside the
+    kernels. The chunked path has them, so the search does find what it looks
+    for."""
+    loss, _, params, x = kda_layer_loss()
+    chunked = per_head_values(jax.make_jaxpr(jax.value_and_grad(lambda p, x: loss(p, x)))(params, x).jaxpr, 2, 128)
+    assert {"reshape", "broadcast_in_dim"} <= {name for name, _ in chunked}
+    as_kernel_call(monkeypatch)
+    traced = jax.make_jaxpr(jax.value_and_grad(lambda p, x: loss(p, x), argnums=(0, 1)))(params, x)
+    assert str(traced).count("pallas_call") == 2  # kda_fwd writing the states, kda_bwd
+    assert per_head_values(traced.jaxpr, 2, 128) == []
+
+
 @pytest.mark.parametrize("dtype, tpu, d, core", [
     (jnp.bfloat16, True, 128, "kernel"), (jnp.bfloat16, True, 256, "kernel"),
     (jnp.float32, True, 128, "chunked"), (jnp.bfloat16, False, 128, "chunked"), (jnp.bfloat16, True, 64, "chunked"),
@@ -231,14 +435,21 @@ def test_which_core_a_call_takes_follows_from_dtype_backend_and_head_size(monkey
     from distributed_sigmoid_loss_tpu.ops import flash_attention, gated_delta_rule, pallas_delta_rule
 
     monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: tpu)
-    assert delta_rule_core(3, 100, 2, d, d, dtype) == {"core": core, "rows": 3, "heads": 2, "chunks": 2}
+    assert delta_rule_core(3, 100, 2, d, d, dtype) == {
+        "core": core, **dict.fromkeys(("qk_norm", "o_norm"), "kernel" if core == "kernel" else "xla"), "rows": 3, "heads": 2, "chunks": 2}
     taken = []
     monkeypatch.setattr(pallas_delta_rule, "delta_rule_kernel",
                         lambda q, *a, **kw: taken.append("kernel") or jnp.zeros_like(q))
     chunked = gated_delta_rule._chunked
     monkeypatch.setattr(gated_delta_rule, "_chunked", lambda *a, **kw: taken.append("chunked") or chunked(*a, **kw))
-    chunk_gated_delta_rule(*delta_rule_inputs(16, dtype, b=1, h=1, dk=d, dv=d), chunk=8)
+    args = delta_rule_inputs(16, dtype, b=1, h=1, dk=d, dv=d)
+    chunk_gated_delta_rule(*args, chunk=8)
     assert taken == [core]
+    # the mixer's entry, on raw q and k with the heads on the lanes: the norms go where the core goes
+    normed = []
+    monkeypatch.setattr(gated_delta_rule, "l2norm", lambda x: normed.append("xla") or x.astype(jnp.float32))
+    normed_chunk_gated_delta_rule(*(x.reshape(1, 16, -1) for x in args[:4]), args[4], jnp.ones(d), o_eps=1e-5, chunk=8)
+    assert taken == [core, core] and normed == ([] if core == "kernel" else ["xla", "xla"])
 
 
 def test_the_steps_record_names_the_core_by_the_same_rule(monkeypatch):
@@ -249,12 +460,14 @@ def test_the_steps_record_names_the_core_by_the_same_rule(monkeypatch):
     t = TextConfig(width=256, depth=3, num_heads=2, mixers=("kda", "mla", "kda"), pos="none", dtype="bfloat16",
                    moe_experts=4, moe_router="sigmoid", kda_head_dim=128)
     assert stack_record_of(t, (16, 1024))["kda_core"] == {
-        i: {"core": "chunked", "rows": 16, "heads": 2, "chunks": 16} for i in (0, 2)}  # this CPU
+        i: {"core": "chunked", "qk_norm": "xla", "o_norm": "xla", "rows": 16, "heads": 2, "chunks": 16} for i in (0, 2)}  # this CPU
     monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
-    assert {x["core"] for x in stack_record_of(t, (16, 1024))["kda_core"].values()} == {"kernel"}
+    assert stack_record_of(t, (16, 1024))["kda_core"] == {
+        i: {"core": "kernel", "qk_norm": "kernel", "o_norm": "kernel", "rows": 16, "heads": 2, "chunks": 16} for i in (0, 2)}
     float32 = TextConfig(width=256, depth=1, num_heads=2, mixers=("kda",), pos="none", dtype="float32",
                          moe_experts=4, moe_router="sigmoid")
-    assert stack_record_of(float32, (3, 100))["kda_core"] == {0: {"core": "chunked", "rows": 3, "heads": 2, "chunks": 2}}
+    assert stack_record_of(float32, (3, 100))["kda_core"] == {
+        0: {"core": "chunked", "qk_norm": "xla", "o_norm": "xla", "rows": 3, "heads": 2, "chunks": 2}}
     assert "kda_core" not in stack_record_of(TextConfig(depth=2, moe_experts=4, moe_router="sigmoid"), (4, 8))
 
 

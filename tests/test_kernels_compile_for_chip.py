@@ -5,6 +5,8 @@ tests of tests/test_hybrid_layers.py cannot see it. Nothing runs. Every test
 that describes a topology lives in this one file (one worker loads the TPU's
 library, inside a fixture, never at import)."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -52,3 +54,56 @@ def test_the_delta_rule_kernels_compile_for_a_v5e(one_chip, heads, tokens):
     both = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
     assert both.as_text().count("tpu_custom_call") == 2  # kda_fwd writing the states, kda_bwd
     assert [x.shape for x in both.out_info] == [a.shape for a in args]
+
+
+MOSAIC_VMEM_LIMIT = 16 * 2**20  # what a Mosaic kernel may use on a v5e unless it asks for more (these do not)
+
+
+def vmem_asked(jaxpr) -> list:
+    """Per ``pallas_call`` of a jaxpr (name, bytes): every block twice (the
+    pipeline holds the next one) and the scratch."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            mapping = eqn.params["grid_mapping"]
+            blocks = sum(
+                math.prod(getattr(x, "block_size", None) or 1 for x in m.block_shape) * m.array_aval.dtype.itemsize
+                for m in mapping.block_mappings)
+            scratch = eqn.params["jaxpr"].invars[len(eqn.params["jaxpr"].invars) - mapping.num_scratch_operands:]
+            found.append((eqn.params["name"], 2 * blocks + sum(
+                math.prod(v.aval.shape) * v.aval.dtype.itemsize for v in scratch)))
+            continue
+        for value in eqn.params.values():  # a jit's or a custom rule's body
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                found += vmem_asked(inner)
+    return found
+
+
+# The mixer's call at the cell's shape (two rows of it): raw q and k, o over its head's rms, the
+# heads on the lanes, four heads a program; the norms run on the tile the program holds.
+@pytest.mark.parametrize("heads, tokens", [(32, 1024), (2, 128)], ids=["cell", "two-heads"])
+def test_the_kernels_with_the_norms_inside_compile_for_a_v5e(one_chip, heads, tokens):
+    from distributed_sigmoid_loss_tpu.ops.pallas_delta_rule import heads_per_program
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wide = (2, tokens, heads * 128)
+    args = (of(wide, jnp.bfloat16),) * 3 + (of(wide, jnp.float32), of((2, tokens, heads), jnp.float32))
+    assert heads_per_program(heads) == (4 if heads == 32 else 2)
+
+    def normed(q, k, v, g, beta):
+        return delta_rule_kernel(q, k, v, g, beta, qk_norm=True, o_eps=1e-5)
+
+    def loss(*a):
+        return (normed(*a).astype(jnp.float32) ** 2).sum()
+
+    forward = jax.jit(normed).lower(*args).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1
+    grads = jax.grad(loss, argnums=tuple(range(5)))
+    both = jax.jit(grads).lower(*args).compile()
+    assert both.as_text().count("tpu_custom_call") == 2  # kda_fwd writing the states, kda_bwd
+    assert [x.shape for x in both.out_info] == [a.shape for a in args]
+    asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
+    assert set(asked) == {"kda_fwd", "kda_bwd"} and max(asked.values()) < MOSAIC_VMEM_LIMIT // 2, asked
