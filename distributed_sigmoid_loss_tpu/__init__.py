@@ -31,12 +31,21 @@ Public surface (mirrors the reference component inventory, see SURVEY.md §2):
   batches, prefetch), configs, parity-data recipe, metrics logging, profiling.
 """
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()  # before the eager imports below, jax's included
+
 __version__ = "0.1.0"
 
-from distributed_sigmoid_loss_tpu.ops.sigmoid_loss import (  # noqa: F401
+from distributed_sigmoid_loss_tpu.ops.sigmoid_loss import (  # noqa: E402, F401
     init_loss_params,
     pairwise_logits,
     sigmoid_xent,
     sigmoid_loss,
     sigmoid_loss_block,
 )
+from distributed_sigmoid_loss_tpu.obs.spans import RECORDER as _RECORDER  # noqa: E402
+
+# The first span of the process's record of start-up (obs/spans.py): this package's
+# eager imports, with jax's where this import is the first to ask for it.
+_RECORDER.record("startup.import", _IMPORT_T0, _time.perf_counter())

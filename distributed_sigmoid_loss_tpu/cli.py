@@ -1018,14 +1018,16 @@ def cmd_train(args) -> int:
         )
         jit_step = step_fn
 
-    # graftscope wiring: schema-validated metrics lines, host spans (enabled
-    # only under --obs-dir — disabled spans are the allocation-free no-op),
-    # the health watchdog, and the always-on flight recorder.
+    # graftscope wiring: schema-validated metrics lines, host spans (the
+    # process's one recorder, which has the start-up spans already; without
+    # --obs-dir it is disabled before the loop — disabled spans are the
+    # allocation-free no-op), the health watchdog, and the always-on flight
+    # recorder.
     from distributed_sigmoid_loss_tpu.obs import (
         FlightRecorder,
         HealthWatchdog,
-        SpanRecorder,
     )
+    from distributed_sigmoid_loss_tpu.obs.spans import RECORDER as spans
     from distributed_sigmoid_loss_tpu.obs.metrics_schema import (
         HEALTH_EVENT_FIELDS,
         TRAIN_METRICS_FIELDS,
@@ -1039,7 +1041,6 @@ def cmd_train(args) -> int:
     )
     if args.obs_dir:
         os.makedirs(args.obs_dir, exist_ok=True)
-    spans = SpanRecorder(enabled=bool(args.obs_dir))
     flight = FlightRecorder(
         path=os.path.join(args.obs_dir, "flight.json") if args.obs_dir
         else None
@@ -1203,6 +1204,10 @@ def cmd_train(args) -> int:
         flight.note_metrics(step_i, line)
         logger.log(step_i, line)
         write_telemetry(step_i, line)
+        if loop_t0:  # the first step has ended: where the time before it went
+            from distributed_sigmoid_loss_tpu.obs.spans import startup_line
+
+            print(startup_line(spans.spans(), loop_t0.pop()), file=sys.stderr)
 
     from distributed_sigmoid_loss_tpu.parallel.mesh import trace_on
 
@@ -1284,6 +1289,12 @@ def cmd_train(args) -> int:
         stash = os.path.join(args.ckpt_dir, "tokenizer.json")
         if os.path.abspath(args.tokenizer) != os.path.abspath(stash):
             shutil.copyfile(args.tokenizer, stash)
+    # The loop's span sites (`step`, `eval`, `h2d_commit`, the resilient loop's)
+    # record only under --obs-dir; the start-up spans stay in the ring either way.
+    spans.enabled = bool(args.obs_dir)
+    import time as _time
+
+    loop_t0 = [_time.perf_counter()]  # taken by the first metrics line
     if args.ckpt_dir:
         # Preemption-safe resilient loop: resumes from the newest checkpoint in
         # --ckpt-dir, saves every --ckpt-every steps and on SIGTERM, rolls back
@@ -2112,8 +2123,8 @@ def cmd_data_bench(args) -> int:
 
 
 def _load_host_spans(root: str):
-    """(paths, spans) of every host_spans.trace.json under ``root`` — shared
-    by `obs summarize` and the span half of `obs diff`."""
+    """(paths, spans, counters) of every host_spans.trace.json under ``root``
+    — shared by `obs summarize` and the span half of `obs diff`."""
     import glob as globmod
     import json as jsonmod
 
@@ -2124,15 +2135,18 @@ def _load_host_spans(root: str):
                      recursive=True)
     )
     spans: list = []
+    counters: dict = {}
     for path in host_paths:
         with open(path, encoding="utf-8") as f:
-            events = jsonmod.load(f).get("traceEvents", [])
-        for ev in events:
+            trace = jsonmod.load(f)
+        for ev in trace.get("traceEvents", []):
             if ev.get("ph") == "X" and "dur" in ev:
                 t0 = ev["ts"] / 1e6
                 spans.append(Span(ev["name"], t0, t0 + ev["dur"] / 1e6,
-                                  ev.get("tid", 0)))
-    return host_paths, spans
+                                  ev.get("tid", 0), ev.get("args")))
+        for name, n in trace.get("counters", {}).items():
+            counters[name] = counters.get(name, 0) + n
+    return host_paths, spans, counters
 
 
 def _add_obs_args(p) -> None:
@@ -2362,7 +2376,7 @@ def _obs_summarize(args) -> int:
     root = args.paths[0]
     from distributed_sigmoid_loss_tpu.obs.spans import summarize_spans
 
-    host_paths, spans = _load_host_spans(root)
+    host_paths, spans, counters = _load_host_spans(root)
 
     device_files = globmod.glob(
         os.path.join(root, "**", "*.trace.json.gz"), recursive=True
@@ -2378,12 +2392,18 @@ def _obs_summarize(args) -> int:
     if spans:
         print(f"== host spans ({len(spans)} retained, "
               f"{len(host_paths)} file(s))")
-        print(f"  {'span':<28}{'count':>7}{'total ms':>11}{'mean ms':>9}"
-              f"{'p50':>8}{'p95':>8}{'max':>9}")
+        # self ms: a name's time less what the spans inside its spans cover
+        # (`init_state` less its programs' trace, lowering and compile).
+        print(f"  {'span':<28}{'count':>7}{'total ms':>11}{'self ms':>11}"
+              f"{'mean ms':>9}{'p50':>8}{'p95':>8}{'max':>9}")
         for name, row in summarize_spans(spans).items():
             print(f"  {name:<28}{row['count']:>7}{row['total_ms']:>11.1f}"
+                  f"{row['self_ms']:>11.1f}"
                   f"{row['mean_ms']:>9.2f}{row['p50_ms']:>8.2f}"
                   f"{row['p95_ms']:>8.2f}{row['max_ms']:>9.2f}")
+        if counters:
+            print("  counters: " + " ".join(
+                f"{k}={v}" for k, v in sorted(counters.items())))
 
     if device_files:
         from distributed_sigmoid_loss_tpu.utils.profiling import (

@@ -1,13 +1,21 @@
-"""graftscope: the unified observability layer — host tracing spans, static
-step attribution, a training health watchdog, and the metrics schema.
+"""graftscope: the unified observability layer — host tracing spans and the
+process's record of start-up, static step attribution, a training health
+watchdog, the metrics schema, the run ledger, live telemetry and the lock
+witness.
 
-Four parts, one goal — every perf or robustness claim arrives with its
+Seven parts, one goal — every perf or robustness claim arrives with its
 evidence attached, chip or no chip:
 
 - :mod:`.spans` — thread-safe ring-buffered host spans (train loop stages,
-  serve per-request stages); while a profiler capture runs they ride in its
-  host plane on the device events' clock; the ring's own Chrome-trace export
-  feeds the ``obs summarize`` CLI subcommand.
+  serve per-request stages) with optional ``attrs``, self time by containment
+  and named counters; while a profiler capture runs they ride in its host
+  plane on the device events' clock; the ring's own Chrome-trace export feeds
+  the ``obs summarize`` CLI subcommand. ``spans.RECORDER`` is the one recorder
+  of the process, on from import: the program's start-up boundaries
+  (``startup.import``, ``startup.compile_cache``, ``startup.mesh``,
+  ``init_state`` > ``init_params``, ``startup.step_builder``) and jax's own
+  trace / lower / backend-compile spans (``utils/compile_cache.py``) write into
+  it; ``train --obs-dir`` and the benchmark's set-up readers read it.
 - :mod:`.attribution` — static per-step FLOPs, bytes, and per-kind
   collective wire bytes from the traced jaxpr (no compile), plus compiled-
   executable cost/memory readout, and the chip-free roofline ``mfu_est``
@@ -70,8 +78,10 @@ from distributed_sigmoid_loss_tpu.obs.ledger import (  # noqa: F401
     trajectory_summary,
 )
 from distributed_sigmoid_loss_tpu.obs.spans import (  # noqa: F401
+    RECORDER,
     Span,
     SpanRecorder,
+    self_times,
     summarize_spans,
 )
 from distributed_sigmoid_loss_tpu.obs.telemetry import (  # noqa: F401
@@ -81,8 +91,10 @@ from distributed_sigmoid_loss_tpu.obs.telemetry import (  # noqa: F401
 )
 
 __all__ = [
+    "RECORDER",
     "Span",
     "SpanRecorder",
+    "self_times",
     "summarize_spans",
     "HealthWatchdog",
     "HealthEvent",

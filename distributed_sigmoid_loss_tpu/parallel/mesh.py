@@ -75,3 +75,11 @@ def make_2d_mesh(
         raise ValueError(f"dp*tp={dp * tp} exceeds visible devices ({len(devices)})")
     grid = np.asarray(devices[: dp * tp]).reshape(dp, tp)
     return Mesh(grid, axis_names)
+
+
+# The start-up span of the mesh's construction (obs/spans.py), applied here at the
+# file's end: the compile cache's key holds the source lines of whatever jax traces
+# through this file (utils/compile_cache.py), so nothing above may move.
+from distributed_sigmoid_loss_tpu.obs.spans import spanned as _spanned  # noqa: E402
+
+make_mesh = _spanned("startup.mesh")(make_mesh)

@@ -1130,3 +1130,15 @@ def make_train_step(
     sharded_step.lower = lambda state, batch: _inner(state).lower(state, batch)
     sharded_step.accum_record, sharded_step.stack_record = accum_record, stack_record
     return sharded_step, batch_sharding
+
+
+# The start-up spans of this file's boundaries (obs/spans.py), applied here at the
+# file's end: the compile cache's key holds the source lines of every function jax
+# traces above (utils/compile_cache.py), so none of them may move. jax's own trace /
+# lower / compile spans of the two init programs fall inside `init_state` by
+# containment; its self time is the programs running and the placement.
+from distributed_sigmoid_loss_tpu.obs.spans import spanned as _spanned  # noqa: E402
+
+init_params = _spanned("init_params")(init_params)
+create_train_state = _spanned("init_state")(create_train_state)
+make_train_step = _spanned("startup.step_builder")(make_train_step)

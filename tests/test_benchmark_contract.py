@@ -1,7 +1,7 @@
 """The benchmark's own chip-free tests, run by the gate the driver runs: the
 manifest against the contract and the files it names, the count modules by hand,
-the readers' cuts, and the (0, 100] sweep of every reader with ``roofline`` or
-``mfu`` in its name. ``benchmark/tests`` holds the files (``python -m pytest
+the readers' cuts, the (0, 100] sweep of every reader with ``roofline`` or
+``mfu`` in its name, and the set-up readers on a recorder filled by hand. ``benchmark/tests`` holds the files (``python -m pytest
 benchmark/tests -q`` runs them with the rehearsals, which start processes and stay
 there); each is loaded by path and its cases are collected here under its name."""
 
@@ -17,7 +17,7 @@ if BENCH_DIR not in sys.path:
 
 FILES = (
     "test_manifest", "test_flops", "test_flops_looped", "test_scopes", "test_flops_kimi", "test_scopes_kimi",
-    "test_flops_glm", "test_scopes_glm",
+    "test_flops_glm", "test_scopes_glm", "test_setup_record",
 )
 
 
@@ -70,3 +70,28 @@ def test_manifest__per_layer_metric_has_its_reader(metric):
 
 
 test_manifest__per_layer_metric_has_its_reader.pytestmark = _reader_case.pytestmark
+
+
+# `test_scopes_glm.py`, a file of the accepted benchmark, counts the per-layer metrics of its
+# cell: 20 at PR 35. PR 37 adds seven set-up readers with no `workloads` list (every cell has a
+# set-up), and may not edit that file. Until a `benchmark` PR writes the new count into it, the
+# case runs here with those seven left out of the cell's list, and this file holds that the cell
+# reports exactly them besides.
+SETUP_READERS = (
+    "startup_s", "import_s", "init_s", "trace_lower_total_s", "backend_compile_s", "cache_load_s",
+    "compile_cache_hit_pct",
+)
+_glm_reader_case = globals()["test_scopes_glm__every_reader_of_the_cell_reads_the_hand_made_step"]
+
+
+def test_scopes_glm__every_reader_of_the_cell_reads_the_hand_made_step(monkeypatch):
+    import harness
+
+    metrics = harness.Cell.metrics
+    of_the_cell = {m["name"] for m in metrics(harness.Cell("glm-b16-p16-s4096", rehearse=False), "per_layer")}
+    assert set(SETUP_READERS) <= of_the_cell and len(of_the_cell) == 20 + len(SETUP_READERS)
+    monkeypatch.setattr(
+        harness.Cell, "metrics",
+        lambda self, kind: [m for m in metrics(self, kind) if m["name"] not in SETUP_READERS],
+    )
+    _glm_reader_case()
