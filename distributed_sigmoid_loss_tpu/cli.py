@@ -1205,9 +1205,12 @@ def cmd_train(args) -> int:
         logger.log(step_i, line)
         write_telemetry(step_i, line)
         if loop_t0:  # the first step has ended: where the time before it went
+            from distributed_sigmoid_loss_tpu.obs.attribution import mixed_stack, mixed_stack_line
             from distributed_sigmoid_loss_tpu.obs.spans import startup_line
 
             print(startup_line(spans.spans(), loop_t0.pop()), file=sys.stderr)
+            if (stack := mixed_stack_line(mixed_stack(jit_step))) is not None:
+                print(stack, file=sys.stderr)  # a mixed stack: which cores the trace took
 
     from distributed_sigmoid_loss_tpu.parallel.mesh import trace_on
 

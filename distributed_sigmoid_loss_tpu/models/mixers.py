@@ -5,13 +5,20 @@ names one. Both are causal and carry no bias; their statistics, gates and decays
 are float32 whatever the tower's dtype. The recurrence takes no position
 encoding; latent attention none, or a rotation of its shared-width parts.
 
-A KDA layer keeps every array (b, s, h x d), a head an aligned window of the
-lanes, as its kernels read and write them; its per-head statistics (the l2 norm
-of q and k, the RMS of o) run where the core runs (``ops/gated_delta_rule.py
-delta_rule_core``): on the head's tile inside the Pallas kernels, or in XLA on a
-(b, s, h, d) view around the chunked form (the CPU, float32). On a TPU the two
-shapes are two tilings, so a per-head view around a reduction over d is a copy
-through HBM each way (PERF.md section 6, PR 36).
+Latent attention's core (scores, causal softmax, values; scope ``mla_core``) is
+one of three, by :func:`latent_attention_core`, from what the call can see:
+``"kernel"``, the repo's own Pallas pair (``ops/pallas_latent_attention.py``:
+``mla_attn_fwd`` / ``mla_attn_bwd``), which reads and writes (b, s, h x d);
+``"flash"``, the library's blocked kernel through ``ops/flash_attention.py``,
+for a sequence whose head does not fit the pair's VMEM; ``"dense"``, XLA (the
+CPU, float32). The layer has one body for the three and, like a KDA layer,
+holds no per-head (b, s, h, d) activation around its core (XLA keeps such an
+array in another tiling, so a view of it around a Mosaic kernel is a copy
+through HBM each way): what a per-head form would cut, pad, join and exchange
+in the activations it does to the COLUMNS of the projections' weights
+(``_recut``, an ``nn.Dense``'s ``dot_general``), and q, k, v are born, and o is
+consumed, with the heads on the lanes. The pair takes them so; the library's
+kernel and XLA take a (b, s, h, d) view of them.
 
 With x the (s, width) normalised stream of one sequence:
 
@@ -37,8 +44,10 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import (
+    kernels_per_shard,
     normed_chunk_gated_delta_rule,
     short_causal_conv,
 )
@@ -113,41 +122,86 @@ class KdaMixer(nn.Module):
         return dense(self.width, name="out")(o)
 
 
-def one_head_size(dqk: int, dv: int, multiple: int = 1) -> int:
-    """The head size ``pad_heads_to_one_size`` runs a core at."""
-    return -(-max(dqk, dv) // multiple) * multiple
+def _whole_registers(d: int) -> int:
+    return -(-d // FUSED_LANES) * FUSED_LANES
 
 
-def pad_heads_to_one_size(attend, q, k, v, multiple: int = 1):
-    """Run an attention core that takes one head size on query/key heads and
-    value heads of two: the narrower are zero-padded to the wider, up to a
-    ``multiple`` of lanes, and the output cut back to the value's width, which
-    is exact (a zero value channel stays zero, zero channels add nothing to a
-    score). Heads that are one size, a multiple already, reach the core as they are."""
-    dv = v.shape[-1]
-    wide = one_head_size(q.shape[-1], dv, multiple)
+def latent_attention_core(attn_impl: str, dtype, tokens: int, dqk: int, dv: int) -> dict:
+    """Which core a ``LatentAttention`` call takes, from what it can see, and
+    the sizes it runs at. ``core``: ``"kernel"`` (the repo's Pallas pair,
+    ``ops/pallas_latent_attention.py``, on (b, s, h x d)), ``"flash"`` (the
+    library's blocked kernel on a (b, s, h, d) view) or ``"dense"`` (XLA, on the
+    same view). As in ``Attention``: a fused kernel's backward is bf16-grade,
+    so "auto" takes one for a bf16 tower on a TPU only; "flash" asks for one
+    whatever the dtype. Of the fused two the pair is taken wherever a head's
+    sequence fits its VMEM (``latent_attention_plan``; 4096 tokens of 256-wide
+    heads hold 51 of its 100 MiB), the library's kernel past that.
+    ``core_head_dim`` / ``core_v_dim`` are a head's window of the lanes, the
+    query/key and value head sizes the core runs at (the pair: each in whole
+    128-lane registers; the library's kernel: both the wider of the two, in
+    whole registers; dense: as they are), ``padded`` whether any head is
+    zero-padded to its window, ``block`` the tokens a block of a fused core
+    (None: dense) and ``core_tokens`` the sequence with the zero rows that fill
+    its last block. The mixer runs what this says and the step's trace-time
+    record (``train_step.stack_record_of``) reports it."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention
+    from distributed_sigmoid_loss_tpu.ops.pallas_latent_attention import latent_attention_plan
 
-    def pad(t):
-        return jnp.pad(t, ((0, 0),) * 3 + ((0, wide - t.shape[-1]),))
-
-    return attend(pad(q), pad(k), pad(v))[..., :dv]
-
-
-def latent_attention_core(attn_impl: str, dtype) -> str:
-    """Which core a ``LatentAttention`` call takes, from what it can see:
-    ``"flash"`` (the library's blocked kernel, which never writes the (b, h, s,
-    s) scores to HBM) or ``"dense"`` (XLA). As in ``Attention``: the fused
-    kernel's backward is bf16-grade, so "auto" takes it for a bf16 tower on a
-    TPU only. The mixer runs what this says and the step's trace-time record
-    (``train_step.stack_record_of``) reports it."""
-    from distributed_sigmoid_loss_tpu.ops.flash_attention import flash_attention_available
-
-    if attn_impl == "flash" and not flash_attention_available():
+    if attn_impl == "flash" and not flash_attention.flash_attention_available():
         raise ValueError("attn_impl='flash' requires a TPU backend; use 'auto'")
     fused = attn_impl == "flash" or (
-        attn_impl == "auto" and jnp.dtype(dtype) == jnp.bfloat16 and flash_attention_available()
+        attn_impl == "auto" and jnp.dtype(dtype) == jnp.bfloat16 and flash_attention.flash_attention_available()
     )
-    return "flash" if fused else "dense"
+    core, windows, plan = "dense", (dqk, dv), {"block": None, "tokens": tokens}
+    if fused:
+        windows = (_whole_registers(dqk), _whole_registers(dv))
+        core, plan = "kernel", latent_attention_plan(tokens, *windows, jnp.dtype(dtype).itemsize)
+        if plan is None:
+            core, windows, plan = "flash", (max(windows),) * 2, flash_attention.flash_attention_plan(tokens)
+    return {"core": core, "core_head_dim": windows[0], "core_v_dim": windows[1], "block": plan["block"],
+            "core_tokens": plan["tokens"], "padded": windows != (dqk, dv)}
+
+
+def _head_columns(kernel, heads: int, take, wide: int, at: int = 0):
+    """(fan_in, h x d) -> (fan_in, h x wide): of each head's d columns those
+    ``take`` names (a slice or indices), from lane ``at`` of the head's window
+    of ``wide`` lanes on, zeros in the window's other lanes."""
+    fan_in, width = kernel.shape
+    if isinstance(take, slice) and take == slice(None) and width == heads * wide:
+        return kernel  # every head's columns, already a window each
+    cols = kernel.reshape(fan_in, heads, -1)[:, :, take]
+    return jnp.pad(cols, ((0, 0), (0, 0), (at, wide - at - cols.shape[-1]))).reshape(fan_in, heads * wide)
+
+
+def _recut(*cuts):
+    """An ``nn.Dense``'s ``dot_general`` that multiplies by the kernel as each
+    of ``cuts`` returns it (kernel (fan_in, features) -> a matrix), the products
+    side by side on the lanes: what a per-head (b, s, h, d) form cuts, pads,
+    joins or permutes in the ACTIVATIONS this does to the weights' columns, so
+    that every activation is born (b, s, h x d). The module keeps its
+    parameter, under its name, shape and initialiser, and stays an ``nn.Dense``
+    to whatever wraps one."""
+    def dot_general(x, kernel, dims, precision=None):
+        return jnp.concatenate([jax.lax.dot_general(x, cut(kernel), dims, precision=precision) for cut in cuts], -1)
+    return dot_general
+
+
+def _rope_tables_on_the_lanes(tokens: int, theta: float, heads: int, dn: int, dr: int, wide: int):
+    """``models/transformer.py rope``'s tables for a (b, s, h x wide) array
+    whose heads hold their rotated part in lanes [dn, dn + dr): (s, h x wide)
+    float32 cos and signed sin, 1 and 0 in the lanes that are not rotated. Read
+    off ``rope`` itself while tracing, which is linear: of a head that is 1 in
+    its first half and 0 in its second it returns (cos, sin), of the other
+    (-sin, cos). A head's window is a constant of the trace, as in ``rope``."""
+    from distributed_sigmoid_loss_tpu.models.transformer import rope
+
+    first = np.arange(dr) < dr // 2
+    units = np.stack([first, ~first]).astype(np.float32)[:, None, None, :]
+    with jax.ensure_compile_time_eval():
+        a, b = np.asarray(rope(jnp.broadcast_to(units, (2, tokens, 1, dr)), theta))[:, :, 0]
+    pad = ((0, 0), (dn, wide - dn - dr))
+    cos, sin = np.pad(np.where(first, a, b), pad, constant_values=1.0), np.pad(np.where(first, b, a), pad)
+    return tuple(jnp.tile(jnp.asarray(t, F32), (1, heads)) for t in (cos, sin))
 
 
 class LatentAttention(nn.Module):
@@ -156,7 +210,19 @@ class LatentAttention(nn.Module):
     heads; value heads of ``v_dim``. ``q_rank > 0`` brings the queries through
     a normalised latent of that width (``q_a``, ``q_norm``, ``q_b`` in place of
     ``q``); ``rope_theta`` rotates the ``shared_dim`` wide parts, each head's
-    query part and the one key part, and nothing else."""
+    query part and the one key part, and nothing else.
+
+    Around the core no activation takes the per-head (b, s, h, d) form: XLA
+    holds such an array in another tiling than (b, s, h x d), so every view
+    between the two around a Mosaic kernel is a copy through HBM (PERF.md
+    section 6, PR 36 and 38). Instead the projections' COLUMNS are recut
+    (:func:`_recut`) into a head's window of the lanes as
+    :func:`latent_attention_core` sizes it (zero columns pad a head): the
+    queries with, for the rotation, their exchanged halves beside them (the
+    same weights with the columns exchanged: the same products, so the same
+    numbers), the keys from the expansion's key columns with the shared part
+    placed into every head's window by a 0/1 product, the values from its value
+    columns. The pair takes them as they are; the other cores a view."""
 
     width: int
     num_heads: int
@@ -173,6 +239,7 @@ class LatentAttention(nn.Module):
     @nn.compact
     def __call__(self, x):
         from distributed_sigmoid_loss_tpu.models.transformer import _fused_attention_per_shard, rope
+        from distributed_sigmoid_loss_tpu.ops import pallas_latent_attention
         from distributed_sigmoid_loss_tpu.ops.flash_attention import flash_self_attention
         from distributed_sigmoid_loss_tpu.parallel.ring_attention import dense_attention
 
@@ -182,31 +249,42 @@ class LatentAttention(nn.Module):
             nn.Dense, use_bias=False, dtype=self.dtype,
             kernel_init=nn.initializers.xavier_uniform(),
         )
+        sizes = latent_attention_core(self.attn_impl, self.dtype, s, dn + dr, dv)
+        wq, wv, scale = sizes["core_head_dim"], sizes["core_v_dim"], (dn + dr) ** -0.5
+        rotated = self.rope_theta is not None
+        halves = np.r_[dn + dr // 2 : dn + dr, dn : dn + dr // 2]  # rope's exchange of a head's halves
+        cq, q_name = x, "q"
         if self.q_rank:
-            cq = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="q_norm")(
+            cq, q_name = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="q_norm")(
                 dense(self.q_rank, name="q_a")(x)
-            )
-            q = dense(h * (dn + dr), name="q_b")(cq)
-        else:
-            q = dense(h * (dn + dr), name="q")(x)
-        q = q.reshape(b, s, h, dn + dr)
+            ), "q_b"
+        window = partial(_head_columns, heads=h, wide=wq)
+        queries = [partial(window, take=slice(None))] + [partial(window, take=halves, at=dn)] * rotated
+        q = dense(h * (dn + dr), name=q_name, dot_general=_recut(*queries))(cq)
         latent = dense(self.kv_rank + dr, name="kv_a")(x)
         c, shared = latent[..., : self.kv_rank], latent[..., self.kv_rank :]
         c = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="kv_norm")(c)
-        expanded = dense(h * (dn + dv), name="kv_b")(c).reshape(b, s, h, dn + dv)
-        if self.rope_theta is not None:
-            with jax.named_scope(MLA_ROPE_SCOPE):  # the one key part as a single head
-                q = jnp.concatenate([q[..., :dn], rope(q[..., dn:], self.rope_theta)], -1)
-                shared = rope(shared[:, :, None, :], self.rope_theta)[:, :, 0]
-        k = jnp.concatenate(
-            [expanded[..., :dn], jnp.broadcast_to(shared[:, :, None, :], (b, s, h, dr))], -1
-        )
-        v = expanded[..., dn:]
-        fused = latent_attention_core(self.attn_impl, self.dtype) == "flash"
-        core = partial(flash_self_attention if fused else dense_attention, causal=True, scale=(dn + dr) ** -0.5)
-        if fused:  # a Mosaic kernel under a multi-chip jit sits in a shard_map
-            core = partial(_fused_attention_per_shard, core)
+        keys_and_values = _recut(partial(window, take=slice(0, dn)), partial(window, take=slice(dn, dn + dv), wide=wv))
+        k, v = jnp.split(dense(h * (dn + dv), name="kv_b", dot_general=keys_and_values)(c), [h * wq], -1)
+        if rotated:
+            with jax.named_scope(MLA_ROPE_SCOPE):
+                q, exchanged = jnp.split(q, 2, -1)
+                cos, sin = _rope_tables_on_the_lanes(s, self.rope_theta, h, dn, dr, wq)
+                q = (q.astype(F32) * cos + exchanged.astype(F32) * sin).astype(q.dtype)
+                shared = rope(shared[:, :, None, :], self.rope_theta)[:, :, 0]  # the one key part as a single head
+        place = np.zeros((dr, h, wq), np.float32)  # the one shared part into every head's window: a 0/1 product
+        place[np.arange(dr), :, dn + np.arange(dr)] = 1.0
+        place = jnp.asarray(place.reshape(dr, h * wq), shared.dtype)
         with jax.named_scope(MLA_CORE_SCOPE):
-            out = pad_heads_to_one_size(core, q, k, v, multiple=FUSED_LANES if fused else 1)
-        out = out.astype(self.dtype).reshape(b, s, h * dv)
-        return dense(self.width, name="out")(out)
+            k = k + jnp.dot(shared, place, precision=jax.lax.Precision.HIGHEST)  # exact in a float32 tower too
+            if sizes["core"] == "kernel":
+                core = partial(pallas_latent_attention.latent_attention_kernel, head_dims=(wq, wv), scale=scale)
+                out = kernels_per_shard(core, h, q, k, v)  # a Mosaic kernel under a multi-chip jit sits in a shard_map
+            else:
+                core = partial(flash_self_attention if sizes["core"] == "flash" else dense_attention, causal=True, scale=scale)
+                if sizes["core"] == "flash":
+                    core = partial(_fused_attention_per_shard, core)
+                out = core(*(t.reshape(b, s, h, -1) for t in (q, k, v)))
+        if wv != dv:  # a value head narrower than its window: its zero lanes go
+            out = out.reshape(b, s, h, wv)[..., :dv]
+        return dense(self.width, name="out")(out.astype(self.dtype).reshape(b, s, h * dv))

@@ -70,6 +70,7 @@ __all__ = [
     "static_attribution",
     "accum_placement",
     "mixed_stack",
+    "mixed_stack_line",
     "attribution_of_compiled",
     "roofline_estimate",
     "step_config_attribution",
@@ -305,11 +306,31 @@ def mixed_stack(step) -> dict | None:
     by layer index) what it is made of: the queries' latent (``q_rank``, 0 = one
     projection) and the keys' and values' (``kv_rank``), the width of the
     rotated parts and their base (``rotated_dim`` 0 and ``rope_theta`` None
-    where nothing is rotated), the core it took (``"flash"``: the library's
-    blocked kernel; ``"dense"``: XLA), the query/key and value head sizes, the
-    one head size the core ran at and whether any head was zero-padded to it.
-    None for a step that has not traced yet or runs no such tower."""
+    where nothing is rotated), the core it took (``"kernel"``: the repo's
+    Pallas pair on (b, s, h x d), ``ops/pallas_latent_attention.py``;
+    ``"flash"``: the library's blocked kernel; ``"dense"``: XLA), the query/key
+    and value head sizes, the sizes the core ran them at and whether any head
+    was zero-padded to them, the tokens a block of a fused core and the sequence
+    with the zero rows that fill its last block. None for a step that has not
+    traced yet or runs no such tower. :func:`mixed_stack_line` is the same on
+    one line, which ``train`` prints once a run."""
     return dict(getattr(step, "stack_record", None) or {}) or None
+
+
+def mixed_stack_line(record: dict | None) -> str | None:
+    """What ``mixed_stack`` found, on one line, so that a run says without a
+    trace which cores engaged: per latent-attention and per delta-rule layer the
+    core taken with the blocks and head sizes it ran at. None for no record."""
+    if not record:
+        return None
+    parts = [" ".join(record["layer_kinds"])]
+    for i, m in sorted(record.get("mla", {}).items()):
+        ran = f"heads {m['core_head_dim']}/{m['core_v_dim']}" + (" (zero-padded)" if m["padded"] else "")
+        blocks = "" if m["block"] is None else f", {m['core_tokens'] // m['block']} blocks of {m['block']} tokens"
+        parts.append(f"mla[{i}] core={m['core']} {ran}{blocks}")
+    for i, k in sorted(record.get("kda_core", {}).items()):
+        parts.append(f"kda[{i}] core={k['core']} qk_norm={k['qk_norm']} o_norm={k['o_norm']}, {k['chunks']} chunks")
+    return "stack: " + "; ".join(parts)
 
 
 def attribution_of_compiled(compiled) -> dict:

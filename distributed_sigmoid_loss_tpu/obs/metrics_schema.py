@@ -135,11 +135,15 @@ STACK_RECORD_MLA_FIELDS = {
     "kv_rank": "width of the keys' and values' normalised latent",
     "rotated_dim": "width of the rotated parts (each head's query part, the one shared key part); 0 = none",
     "rope_theta": "the rotation's base; None where nothing is rotated",
-    "core": "flash (the library's blocked kernel) or dense (XLA), as the dispatcher chose",
+    "core": "kernel (the repo's Pallas pair on (b, s, h x d)), flash (the library's blocked kernel) or dense (XLA), "
+            "as the dispatcher chose",
     "qk_dim": "query and key head size",
     "v_dim": "value head size",
-    "core_head_dim": "the one head size the core ran at",
-    "padded": "whether any head was zero-padded to core_head_dim",
+    "core_head_dim": "the query and key head size the core ran at",
+    "core_v_dim": "the value head size the core ran at",
+    "padded": "whether any head was zero-padded to core_head_dim / core_v_dim",
+    "block": "tokens a block of a fused core; None for dense",
+    "core_tokens": "the sequence the core ran, with the zero rows that fill its last block",
 }
 
 

@@ -12,6 +12,13 @@ lengths that aren't block-aligned (ViT-B/16 has s=196): inputs are zero-padded t
 block multiple and masked via segment ids (pad tokens get a different segment id, so
 real queries never attend them; padded query rows are sliced off afterwards).
 
+Who calls it: ``Attention`` (models/transformer.py) for a sequence past the
+VMEM-resident kernel (ops/pallas_short_attention.py; 638 tokens at the towers'
+widths), and ``LatentAttention`` (models/mixers.py) only for a sequence whose head
+does not fit the VMEM of the repo's own causal kernel pair
+(ops/pallas_latent_attention.py, which took its place in both routed cells in PR 38:
+the pair works on (b, s, h x d) and needs none of the transposes below).
+
 There is no reference analogue (the reference has no model layer — SURVEY.md §1); this
 is TPU-first engineering for the BASELINE.json end-to-end throughput target.
 """
@@ -21,7 +28,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_self_attention", "flash_attention_available"]
+__all__ = ["flash_self_attention", "flash_attention_available", "flash_attention_plan"]
 
 # The kernel's minor-most compute tile: sequence blocks must be multiples of this to
 # satisfy the (8, 128) f32 / (16, 128) bf16 TPU tiling on the logits' lane dim.
@@ -41,6 +48,15 @@ def _block_size(s_pad: int) -> int:
     """Largest power-of-two block ≤512 dividing the padded length — the kernel
     requires divisibility in BOTH grid directions (backward also blocks q)."""
     return next(b for b in (512, 256, 128) if s_pad % b == 0)
+
+
+def flash_attention_plan(tokens: int) -> dict:
+    """What a call at this length runs at, from the length alone (as
+    ``ops/pallas_latent_attention.py latent_attention_plan`` says of the repo's
+    pair): ``tokens``, the sequence zero-padded to the kernel's tile, and
+    ``block``, the tokens a block in every direction."""
+    padded = _pad_len(tokens)
+    return {"block": _block_size(padded), "tokens": padded}
 
 
 def _prepare_inputs(q, k, v):

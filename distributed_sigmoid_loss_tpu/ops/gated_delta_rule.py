@@ -55,7 +55,7 @@ scale (a product with a (h x d,) vector) and no per-head (b, s, h, d) array:
 on a TPU that shape and (b, s, h x d) are two tilings, and a view of one as the
 other around a reduction over d is a copy through HBM each way (PERF.md section
 6, PR 36). Under a ``jit`` over a mesh the kernels sit in a ``shard_map`` as the
-attention kernels do (:func:`_kernels_per_shard`). Every other call (float32
+attention kernels do (:func:`kernels_per_shard`). Every other call (float32
 operands, the CPU, a head size that is no multiple of 128) takes
 :func:`l2norm` and the head norm in XLA on the per-head form and between them
 :func:`_chunked` below: XLA operations, its backward ``jax.grad``'s, recomputed
@@ -76,7 +76,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "gated_delta_rule_recurrent", "chunk_gated_delta_rule", "normed_chunk_gated_delta_rule", "delta_rule_core",
-    "l2norm", "short_causal_conv",
+    "l2norm", "short_causal_conv", "kernels_per_shard",
 ]
 
 F32 = jnp.float32
@@ -246,7 +246,7 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, dtype=None):
         from distributed_sigmoid_loss_tpu.ops.pallas_delta_rule import delta_rule_kernel
 
         wide = (x.reshape(b, s + pad, -1) for x in (q.astype(dt), k.astype(dt), v.astype(dt), g))  # free views
-        out = _kernels_per_shard(partial(delta_rule_kernel, chunk=chunk), *wide, beta)
+        out = kernels_per_shard(partial(delta_rule_kernel, chunk=chunk), h, *wide, beta)
         return out.reshape(b, s + pad, h, -1)[:, :s]
     core = jax.checkpoint(partial(_chunked, chunk=chunk, dt=dt))
     rows = _rows_per_pass(b, s + pad, h, dk)
@@ -258,11 +258,12 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, dtype=None):
     return out[:, :s].astype(dt)
 
 
-def _kernels_per_shard(kernel, *wide):
+def kernels_per_shard(kernel, heads: int, *wide):
     """``models/transformer.py _fused_attention_per_shard`` for operands with
-    the heads on the lanes ((b, s, h x d); beta (b, s, h)): under a ``jit`` over
-    several chips a Mosaic kernel sits in a ``shard_map``, rows over ``dp`` and
-    whole heads over ``tp`` where those axes exist and divide."""
+    ``heads`` heads on the lanes ((b, s, h x d); beta (b, s, h)): under a
+    ``jit`` over several chips a Mosaic kernel sits in a ``shard_map``, rows
+    over ``dp`` and whole heads over ``tp`` where those axes exist and divide.
+    ``kernel`` sees a shard: it reads the number of its heads off the widths."""
     from jax.sharding import PartitionSpec as P
 
     from distributed_sigmoid_loss_tpu.models.transformer import DP_AXIS, TP_AXIS
@@ -271,7 +272,7 @@ def _kernels_per_shard(kernel, *wide):
     auto = frozenset(mesh.axis_names) - frozenset(mesh.manual_axes)
     if not auto or mesh.size == 1:
         return kernel(*wide)
-    b, _, h = wide[-1].shape  # beta
+    b, h = wide[0].shape[0], heads
 
     def split(axis, n):
         return axis if axis in auto and n % mesh.shape[axis] == 0 else None
@@ -305,7 +306,7 @@ def normed_chunk_gated_delta_rule(q, k, v, g, beta, o_scale, *, o_eps: float, ch
         if s % chunk:  # a zero row stays zero under both norms: inert, as in chunk_gated_delta_rule
             operands = tuple(jnp.pad(x, ((0, 0), (0, -s % chunk), (0, 0))) for x in operands)
         kernel = partial(delta_rule_kernel, chunk=chunk, qk_norm=True, o_eps=o_eps)
-        return _kernels_per_shard(kernel, *operands)[:, :s].astype(F32) * jnp.tile(o_scale, h)
+        return kernels_per_shard(kernel, h, *operands)[:, :s].astype(F32) * jnp.tile(o_scale, h)
     q, k, v, g = (x.reshape(b, s, h, -1) for x in (q, k, v, g))
     q, k = (l2norm(q) * dk**-0.5).astype(dt), l2norm(k).astype(dt)
     o = chunk_gated_delta_rule(q, k, v, g, beta, chunk=chunk, dtype=dt).astype(F32)

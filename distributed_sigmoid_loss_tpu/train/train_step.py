@@ -348,25 +348,25 @@ def stack_record_of(t, tokens_shape) -> dict:
         core = delta_rule_core(rows, length, t.num_heads, t.kda_head_dim, t.kda_head_dim, t.dtype, CHUNK)
         record["kda_core"] = {i: dict(core) for i, m in enumerate(mixers) if m == "kda"}
     if "mla" in mixers:
-        from distributed_sigmoid_loss_tpu.models.mixers import (
-            FUSED_LANES,
-            latent_attention_core,
-            one_head_size,
-        )
+        from distributed_sigmoid_loss_tpu.models.mixers import latent_attention_core
 
         # By the rule LatentAttention's call runs by: the query latent, what is
-        # rotated, which core each latent-attention layer takes ("flash" / "dense")
-        # and the one head size it runs at, zero-padded to or not.
-        core = latent_attention_core(t.attn_impl, t.dtype)
+        # rotated, and what ``latent_attention_core`` says of this length and these
+        # head sizes: the core each latent-attention layer takes ("kernel": the
+        # repo's Pallas pair on (b, s, h x d); "flash": the library's blocked
+        # kernel; "dense": XLA), the query/key and value head sizes it runs at
+        # (``core_head_dim``, ``core_v_dim``), zero-padded to or not, the tokens a
+        # block of a fused core (``block``) and the sequence with the zero rows
+        # that fill its last block (``core_tokens``).
         dqk = t.mla_qk_nope_dim + t.mla_qk_shared_dim
-        ran_at = one_head_size(dqk, t.mla_v_dim, FUSED_LANES if core == "flash" else 1)
         rotated = t.pos == "rope"
+        sizes = latent_attention_core(t.attn_impl, t.dtype, tokens_shape[-1], dqk, t.mla_v_dim)
         made_of = {
             "q_rank": t.mla_q_rank, "kv_rank": t.mla_kv_rank,
             "rotated_dim": t.mla_qk_shared_dim if rotated else 0,
             "rope_theta": t.rope_theta if rotated else None,
-            "core": core, "qk_dim": dqk, "v_dim": t.mla_v_dim, "core_head_dim": ran_at,
-            "padded": ran_at != dqk or ran_at != t.mla_v_dim,
+            "qk_dim": dqk, "v_dim": t.mla_v_dim,
+            **sizes,
         }
         record["mla"] = {i: dict(made_of) for i, m in enumerate(mixers) if m == "mla"}
     return record

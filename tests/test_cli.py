@@ -408,5 +408,9 @@ def test_train_a_mixed_text_stack_from_a_configuration_file(tmp_path):
     assert [l["step"] for l in lines] == [1, 2, 3]
     assert all(l["moe_dropped_tokens"] == 0 and l["moe_local_assignments"] > 0 for l in lines)
     assert lines[-1]["loss"] < lines[0]["loss"]
+    # once a run, beside `startup:`: which cores the trace took (obs/attribution.py mixed_stack_line)
+    stack = [l for l in proc.stderr.splitlines() if l.startswith("stack: ")]
+    assert len(stack) == 1 and "mla[3] core=dense heads 24/16;" in stack[0], proc.stderr[-2000:]
+    assert "kda[0] core=chunked qk_norm=xla o_norm=xla, 1 chunks" in stack[0]
     clash = _run(["train", "--tiny", "--model-config", str(path)])
     assert clash.returncode != 0 and "--model-config conflicts" in clash.stderr

@@ -26,7 +26,6 @@ from distributed_sigmoid_loss_tpu.models.mixers import (
     MLA_ROPE_SCOPE,
     LatentAttention,
     latent_attention_core,
-    pad_heads_to_one_size,
 )
 from distributed_sigmoid_loss_tpu.models.moe import SELECT_BIAS, SharedExpertMoe
 from distributed_sigmoid_loss_tpu.models.transformer import rope
@@ -136,42 +135,83 @@ def test_the_references_blocks_of_queries_are_the_whole_softmax(monkeypatch):
         np.testing.assert_allclose(reference_glm.causal_attention(q, k, v, 0.25), want, atol=2e-6)
 
 
-@pytest.mark.parametrize("dqk, dv, multiple, ran_at", [
-    (256, 256, 128, 256),  # this tower's: the blocked kernel's own size, nothing padded
-    (192, 128, 128, 256),  # the unrotated layer's
-    (32, 48, 1, 48),  # values wider than keys, on the dense path
+@pytest.mark.parametrize("dn, dr, dv, ran_at", [
+    (192, 64, 256, 256),  # this tower's: the blocked kernel's own size, nothing padded
+    (128, 64, 128, 256),  # the unrotated layer's, rotated
+    (24, 8, 48, 128),  # values wider than keys
 ])
-def test_heads_reach_a_core_at_one_size(dqk, dv, multiple, ran_at):
-    keys = jax.random.split(jax.random.key(5), 3)
-    q, k = (jax.random.normal(key, (2, 12, 3, dqk)) for key in keys[:2])
-    v = jax.random.normal(keys[2], (2, 12, 3, dv))
-    want = dense_attention(q, k, v, causal=True, scale=0.1) if dqk == dv else None
-    seen = {}
+def test_heads_reach_the_library_kernel_at_one_size(monkeypatch, dn, dr, dv, ran_at):
+    """Past the pair's VMEM the rotated layer hands the library's kernel (here a dense
+    stand-in) (b, s, h, ``ran_at``) views of its wide q, k and v, the heads zero-padded by
+    the weights' columns: the layer on the dense path at its own sizes, values and gradients."""
+    from test_hybrid_layers import library_kernel_stand_in, moved, ripple
 
-    def attend(q, k, v):  # a core that takes one head size, as the fused kernels do
-        seen["shapes"] = (q.shape[-1], k.shape[-1], v.shape[-1])
-        return dense_attention(q, k, v, causal=True, scale=0.1)
+    sizes = dict(width=48, num_heads=3, nope_dim=dn, shared_dim=dr, v_dim=dv, kv_rank=20, dtype=jnp.float32,
+                 q_rank=24, rope_theta=1e4)
+    dense, fused = LatentAttention(**sizes, attn_impl="dense"), LatentAttention(**sizes, attn_impl="flash")
+    x = jax.random.normal(jax.random.key(0), (2, 12, 48), jnp.float32)
+    params = moved(dense.init(jax.random.key(1), x)["params"])
 
-    got = pad_heads_to_one_size(attend, q, k, v, multiple=multiple)
-    assert seen["shapes"] == (ran_at,) * 3 and got.shape == v.shape
-    if want is None:
-        scores = jnp.where(jnp.tril(jnp.ones((12, 12), bool)), jnp.einsum("bqhd,bkhd->bhqk", q, k) * 0.1, -jnp.inf)
-        want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
-    np.testing.assert_allclose(got, want, atol=1e-5)
+    def loss(layer):
+        return lambda p, x: (layer.apply({"params": p}, x) * ripple((2, 12, 48))).sum()
+
+    want, want_grads = jax.value_and_grad(loss(dense), argnums=(0, 1))(params, x)
+    seen = []
+    library_kernel_stand_in(monkeypatch, seen)
+    got, got_grads = jax.value_and_grad(loss(fused), argnums=(0, 1))(params, x)
+    assert seen == [((2, 12, 3, ran_at),) * 3]
+    np.testing.assert_allclose(got, want, rtol=1e-4)  # a sum with cancellation
+    assert max(reference_glm._base.tree_max_rel_err(got_grads, want_grads).values()) < 1e-4
+
+
+def test_the_benchmarks_planted_faults_reach_the_layer_on_the_kernel_path(monkeypatch):
+    """``benchmark/tests/controls_glm.py`` plants ``no_rope`` by swapping ``models/transformer.py rope``
+    for the identity and ``fp8`` by wrapping ``nn.Dense.__call__``. With the core through the pair
+    (interpreted) both still reach the whole layer: the queries' rotation reads its tables off
+    ``rope``, and every projection, the recut ones too, is an ``nn.Dense`` call with one output."""
+    from distributed_sigmoid_loss_tpu.models import transformer
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_latent_attention
+
+    monkeypatch.setattr(pallas_latent_attention, "latent_attention_kernel",
+                        partial(pallas_latent_attention.latent_attention_kernel, interpret=True))
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
+    sizes = dict(width=48, num_heads=2, nope_dim=64, shared_dim=64, v_dim=128, kv_rank=32, dtype=jnp.float32,
+                 q_rank=24, attn_impl="flash")
+    rotated, unrotated = LatentAttention(**sizes, rope_theta=1e4), LatentAttention(**sizes)
+    x = jax.random.normal(jax.random.key(0), (2, 40, 48), jnp.float32)
+    params = rotated.init(jax.random.key(1), x)
+    sound, without = rotated.apply(params, x), unrotated.apply(params, x)
+    assert float(jnp.abs(sound - without).max()) > 1e-2
+    with monkeypatch.context() as planted:  # no_rope: neither the queries' parts nor the shared key part turn
+        planted.setattr(transformer, "rope", lambda x, theta: x)
+        np.testing.assert_allclose(rotated.apply(params, x), without, rtol=1e-6, atol=1e-7)
+    seen, exact = {}, nn.Dense.__call__
+
+    def noted(self, x):
+        seen[self.name] = exact(self, x)
+        return seen[self.name]
+
+    with monkeypatch.context() as planted:  # fp8 rounds what these calls return
+        planted.setattr(nn.Dense, "__call__", noted)
+        np.testing.assert_array_equal(rotated.apply(params, x), sound)
+    assert {name: y.shape[-1] for name, y in seen.items()} == {
+        "q_a": 24, "q_b": 2 * 2 * 128, "kv_a": 32 + 64, "kv_b": 2 * 128 + 2 * 128, "out": 48}  # q beside its exchanged halves; k beside v
 
 
 @pytest.mark.parametrize("attn_impl, dtype, tpu, core", [
-    ("auto", "bfloat16", True, "flash"), ("auto", "float32", True, "dense"), ("auto", "bfloat16", False, "dense"),
-    ("dense", "bfloat16", True, "dense"), ("flash", "float32", True, "flash"),
+    ("auto", "bfloat16", True, "kernel"), ("auto", "float32", True, "dense"), ("auto", "bfloat16", False, "dense"),
+    ("dense", "bfloat16", True, "dense"), ("flash", "float32", True, "kernel"),
 ])
 def test_which_core_latent_attention_takes_follows_from_dtype_and_backend(monkeypatch, attn_impl, dtype, tpu, core):
+    """At this cell's call, 4096 tokens of 256-wide heads (tests/test_hybrid_layers.py has the rule by head size and
+    length): a fused core is the repo's own kernel pair."""
     from distributed_sigmoid_loss_tpu.ops import flash_attention
 
     monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: tpu)
-    assert latent_attention_core(attn_impl, dtype) == core
+    assert latent_attention_core(attn_impl, dtype, 4096, 256, 256)["core"] == core
     if not tpu:
         with pytest.raises(ValueError, match="attn_impl='flash'"):
-            latent_attention_core("flash", dtype)
+            latent_attention_core("flash", dtype, 4096, 256, 256)
 
 
 # -- (b) rank 0 and no rotation are the unrotated layer, bit for bit --------------------
@@ -179,7 +219,8 @@ def test_which_core_latent_attention_takes_follows_from_dtype_and_backend(monkey
 
 class LatentAttentionBeforeTheRank(nn.Module):
     """``LatentAttention`` as it stood before it took a query rank and a rotation
-    (PR 32), on the dense path: the oracle for "nothing changed where neither is set"."""
+    (PR 32), on the dense path and the per-head (b, s, h, d) form: the oracle for
+    "nothing changed where neither is set"."""
 
     width: int
     num_heads: int
@@ -199,7 +240,7 @@ class LatentAttentionBeforeTheRank(nn.Module):
         c = nn.RMSNorm(epsilon=1e-5, dtype=jnp.float32, name="kv_norm")(c)
         expanded = dense(h * (dn + dv), name="kv_b")(c).reshape(b, s, h, dn + dv)
         k = jnp.concatenate([expanded[..., :dn], jnp.broadcast_to(shared[:, :, None, :], (b, s, h, dr))], -1)
-        out = pad_heads_to_one_size(partial(dense_attention, causal=True, scale=(dn + dr) ** -0.5), q, k, expanded[..., dn:])
+        out = dense_attention(q, k, expanded[..., dn:], causal=True, scale=(dn + dr) ** -0.5)
         return dense(self.width, name="out")(out.astype(jnp.float32).reshape(b, s, h * dv))
 
 
@@ -210,9 +251,8 @@ def test_rank_zero_and_no_rotation_are_the_layer_as_it_was_bit_for_bit():
     p_now, p_before = now.init(jax.random.key(1), x)["params"], before.init(jax.random.key(1), x)["params"]
     assert jax.tree.structure(p_now) == jax.tree.structure(p_before)
     assert all(np.array_equal(a, b) for a, b in zip(jax.tree.leaves(p_now), jax.tree.leaves(p_before)))
+    # the same numbers from another program: since PR 38 the layer cuts and joins the weights' columns, not (b, s, h, d) views
     np.testing.assert_array_equal(now.apply({"params": p_now}, x), before.apply({"params": p_before}, x))
-    lowered = lambda layer, p: jax.jit(layer.apply).lower({"params": p}, x).as_text()  # noqa: E731
-    assert lowered(now, p_now) == lowered(before, p_before)  # the same program, not only the same numbers
 
 
 def test_the_hybrid_towers_tree_is_untouched_by_the_new_fields():
@@ -395,14 +435,15 @@ def test_the_tower_through_the_train_step():
     assert set(record["mla"]) == {0, 1, 2} and set(record["mla"][0]) == set(STACK_RECORD_MLA_FIELDS)
     assert record["mla"][1] == {
         "q_rank": 10, "kv_rank": 12, "rotated_dim": 8, "rope_theta": 1e6, "core": "dense",
-        "qk_dim": 20, "v_dim": 16, "core_head_dim": 20, "padded": True,
+        "qk_dim": 20, "v_dim": 16, "core_head_dim": 20, "core_v_dim": 16, "padded": False, "block": None, "core_tokens": 16,
     }
     assert step._cache_size() == 1
 
 
 def test_the_record_of_the_cells_stack_on_a_tpu(monkeypatch):
-    """By the rule the mixer runs by: on a TPU in bf16 the cell's 256-wide heads
-    reach the blocked kernel unpadded; the unrotated cell's 192 / 128 are padded to 256."""
+    """By the rule the mixer runs by: on a TPU in bf16 every latent-attention layer of both cells takes the repo's
+    kernel pair in blocks of 512 tokens; this cell's 256-wide heads reach it unpadded, the unrotated cell's 192 / 128
+    padded to whole registers, 256 / 128."""
     from distributed_sigmoid_loss_tpu.ops import flash_attention
     from distributed_sigmoid_loss_tpu.train.train_step import stack_record_of
 
@@ -411,14 +452,23 @@ def test_the_record_of_the_cells_stack_on_a_tpu(monkeypatch):
     made_of = stack_record_of(cfg.text, (4, 4096))["mla"]
     assert sorted(made_of) == [0, 1, 2, 3, 4]
     assert made_of[0] == {
-        "q_rank": 768, "kv_rank": 512, "rotated_dim": 64, "rope_theta": 1e6, "core": "flash",
-        "qk_dim": 256, "v_dim": 256, "core_head_dim": 256, "padded": False,
+        "q_rank": 768, "kv_rank": 512, "rotated_dim": 64, "rope_theta": 1e6, "core": "kernel",
+        "qk_dim": 256, "v_dim": 256, "core_head_dim": 256, "core_v_dim": 256, "padded": False, "block": 512, "core_tokens": 4096,
     }
+    assert all(made_of[i] == made_of[0] for i in made_of)
     _, kimi = cell_config("kimi-b16-p64-s1024")
     assert stack_record_of(kimi.text, (16, 1024))["mla"] == {3: {
-        "q_rank": 0, "kv_rank": 512, "rotated_dim": 0, "rope_theta": None, "core": "flash",
-        "qk_dim": 192, "v_dim": 128, "core_head_dim": 256, "padded": True,
+        "q_rank": 0, "kv_rank": 512, "rotated_dim": 0, "rope_theta": None, "core": "kernel",
+        "qk_dim": 192, "v_dim": 128, "core_head_dim": 256, "core_v_dim": 128, "padded": True, "block": 512, "core_tokens": 1024,
     }}
+    # the same on one line, as `train` prints it once a run: a run says without a trace which cores engaged
+    from distributed_sigmoid_loss_tpu.obs.attribution import mixed_stack_line
+
+    line = mixed_stack_line(stack_record_of(cfg.text, (4, 4096)))
+    assert line.startswith("stack: mla+mlp mla+moe mla+moe mla+moe mla+moe; mla[0] core=kernel heads 256/256, 8 blocks of 512 tokens;")
+    line = mixed_stack_line(stack_record_of(kimi.text, (16, 1024)))
+    assert "mla[3] core=kernel heads 256/128 (zero-padded), 2 blocks of 512 tokens" in line
+    assert "kda[0] core=kernel qk_norm=kernel o_norm=kernel, 16 chunks" in line and mixed_stack_line(None) is None
 
 
 CONTROLS = ("no_rope", "no_q_norm", "lost_expert", "select_by_score", "fp8")

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_sigmoid_loss_tpu.models.mixers import LatentAttention, pad_heads_to_one_size
+from distributed_sigmoid_loss_tpu.models.mixers import LatentAttention
 from distributed_sigmoid_loss_tpu.models.moe import (
     SELECT_BIAS,
     SharedExpertMoe,
@@ -491,25 +491,244 @@ def test_latent_attention_at_heads_of_192_and_128_matches_the_reference():
     np.testing.assert_array_equal(got[:, :8], later[:, :8])
 
 
-def test_the_padded_value_path_equals_the_unpadded():
-    keys = jax.random.split(jax.random.key(5), 3)
-    q, k = (jax.random.normal(key, (2, 12, 3, 192)) for key in keys[:2])
-    v = jax.random.normal(keys[2], (2, 12, 3, 128))
-    scale = 192**-0.5
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    scores = jnp.where(jnp.tril(jnp.ones((12, 12), bool)), scores, -jnp.inf)
-    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
-    seen = {}
+def library_kernel_stand_in(monkeypatch, seen):
+    """A TPU whose pair's VMEM holds no head: the layer takes the library's blocked
+    kernel, here dense attention that notes the per-head shapes it is given."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_latent_attention
 
-    def attend(q, k, v):  # a core that takes one head size, as the fused kernels do
-        seen["shapes"] = (q.shape[-1], k.shape[-1], v.shape[-1])
-        return dense_attention(q, k, v, causal=True, scale=scale)
+    def attend(q, k, v, **kw):
+        seen.append((q.shape, k.shape, v.shape))
+        return dense_attention(q, k, v, **kw)
 
-    got = pad_heads_to_one_size(attend, q, k, v, multiple=128)
-    assert seen["shapes"] == (256, 256, 256) and got.shape == want.shape
-    np.testing.assert_allclose(got, want, atol=1e-5)
-    pad_heads_to_one_size(attend, q, k, v)
-    assert seen["shapes"] == (192, 192, 192)
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
+    monkeypatch.setattr(pallas_latent_attention, "_VMEM_LIMIT", 0)
+    monkeypatch.setattr(flash_attention, "flash_self_attention", attend)
+
+
+def test_past_the_pairs_vmem_the_layer_runs_the_library_kernel_at_one_padded_head_size(monkeypatch):
+    """192 / 128 heads reach a core that takes one head size as (b, s, h, 256) views of the
+    same wide q, k and v (zero columns of the weights pad them, which is exact: a zero
+    value channel stays zero, zero channels add nothing to a score), and the output's zero
+    lanes are cut: the layer on the dense path, values and gradients."""
+    sizes = dict(width=48, num_heads=3, nope_dim=128, shared_dim=64, v_dim=128, kv_rank=32, dtype=jnp.float32)
+    dense, fused = LatentAttention(**sizes, attn_impl="dense"), LatentAttention(**sizes, attn_impl="flash")
+    x = jax.random.normal(jax.random.key(0), (2, 12, 48), jnp.float32)
+    params = moved(dense.init(jax.random.key(1), x)["params"])
+
+    def loss(layer):
+        return lambda p, x: (layer.apply({"params": p}, x) * ripple((2, 12, 48))).sum()
+
+    want, want_grads = jax.value_and_grad(loss(dense), argnums=(0, 1))(params, x)
+    seen = []
+    library_kernel_stand_in(monkeypatch, seen)
+    got, got_grads = jax.value_and_grad(loss(fused), argnums=(0, 1))(params, x)
+    assert seen == [((2, 12, 3, 256),) * 3]
+    np.testing.assert_allclose(got, want, rtol=1e-4)  # a sum with cancellation
+    assert max(reference_kimi._base.tree_max_rel_err(got_grads, want_grads).values()) < 1e-4
+
+
+# -- (b') latent attention's core through the Pallas pair (ops/pallas_latent_attention.py), interpreted ----
+
+
+def attention_inputs(tokens, dqk, dv, dtype=jnp.float32, rows=1, heads=2, seed=7):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    q, k = (jax.random.normal(key, (rows, tokens, heads, dqk), jnp.float32).astype(dtype) for key in keys[:2])
+    v, weight = (jax.random.normal(key, (rows, tokens, heads, dv), jnp.float32).astype(dtype) for key in keys[2:])
+    return q, k, v, weight
+
+
+def pair_and_dense(monkeypatch, block, dqk, dv):
+    """The kernel pair, interpreted, on heads zero-padded to whole registers (the layer
+    pads them by its weights' columns), at ``block`` tokens a block; and dense causal
+    attention in float32."""
+    from distributed_sigmoid_loss_tpu.ops import pallas_latent_attention
+
+    monkeypatch.setattr(pallas_latent_attention, "_BLOCK", block)
+    scale = dqk**-0.5
+
+    def pair(q, k, v):  # the heads on the lanes, each in whole registers
+        (b, s, h, _), wq, wv = q.shape, -(-dqk // 128) * 128, -(-dv // 128) * 128
+        wide = (jnp.pad(t, ((0, 0),) * 3 + ((0, w - t.shape[-1]),)).reshape(b, s, h * w) for t, w in ((q, wq), (k, wq), (v, wv)))
+        out = pallas_latent_attention.latent_attention_kernel(*wide, head_dims=(wq, wv), scale=scale, interpret=True)
+        return out.reshape(b, s, h, wv)[..., :dv]
+
+    def dense(q, k, v):
+        return dense_attention(*(x.astype(jnp.float32) for x in (q, k, v)), causal=True, scale=scale)
+
+    return pair, dense
+
+
+def attention_value_and_grads(core, q, k, v, weight):
+    def loss(q, k, v):
+        return (core(q, k, v).astype(jnp.float32) * weight.astype(jnp.float32)).sum()
+    return core(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("dtype, out_tol, grad_tol", [(jnp.float32, 2e-5, 2e-5), (jnp.bfloat16, 2e-2, 5e-2)],
+                         ids=["f32", "bf16"])  # bf16: the library kernel's own tolerances (tests/test_flash_attention.py)
+@pytest.mark.parametrize("dqk, dv", [(256, 256), (192, 128)], ids=["glm-256-256", "kimi-192-128-padded"])
+@pytest.mark.parametrize("tokens, block", [(128, 128), (384, 128), (200, 128), (640, 512)],
+                         ids=["one-block", "three-blocks", "padded-to-two", "512-blocks-padded"])
+def test_the_pair_is_dense_causal_attention_forward_and_in_its_three_gradients(
+        monkeypatch, tokens, block, dqk, dv, dtype, out_tol, grad_tol):
+    pair, dense = pair_and_dense(monkeypatch, block, dqk, dv)
+    q, k, v, weight = attention_inputs(tokens, dqk, dv, dtype)
+    got, got_grads = attention_value_and_grads(pair, q, k, v, weight)
+    want, want_grads = attention_value_and_grads(dense, q, k, v, weight)
+    assert got.shape == v.shape and got.dtype == dtype
+    np.testing.assert_allclose(got.astype(jnp.float32), want, rtol=out_tol, atol=out_tol)
+    for name, g, w in zip("qkv", got_grads, want_grads):
+        assert g.shape == w.shape and g.dtype == dtype, name
+        np.testing.assert_allclose(g.astype(jnp.float32), w, rtol=grad_tol, atol=grad_tol * float(jnp.abs(w).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("tokens, block", [(128, 128), (384, 128), (200, 128)], ids=["one-block", "three-blocks", "padded"])
+def test_the_forwards_saved_column_is_the_dense_log_sum_exp(monkeypatch, tokens, block):
+    from distributed_sigmoid_loss_tpu.ops import pallas_latent_attention
+
+    monkeypatch.setattr(pallas_latent_attention, "_BLOCK", block)
+    q, k, v, _ = attention_inputs(tokens, 128, 128, rows=2)
+    whole = ((0, 0), (0, -tokens % block), (0, 0))  # the entry's zero rows at the end
+    out, lse = pallas_latent_attention._forward(
+        *(jnp.pad(t.reshape(2, tokens, 2 * 128), whole) for t in (q, k, v)), 2, 128**-0.5, True)
+    out, lse = out[:, :tokens].reshape(v.shape), lse.reshape(2, 2, -1)[..., :tokens]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * 128**-0.5
+    scores = jnp.where(jnp.tril(jnp.ones((tokens, tokens), bool)), scores, -jnp.inf)
+    assert lse.shape == (2, 2, tokens) and lse.dtype == jnp.float32  # one column a row: nothing 128 lanes wide
+    np.testing.assert_allclose(lse, jax.nn.logsumexp(scores, axis=-1), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(out, dense_attention(q, k, v, causal=True, scale=128**-0.5), atol=2e-5)
+
+
+def test_no_block_above_the_diagonal_is_visited(monkeypatch):
+    """Keys and values of the LAST block poisoned with NaN: a masked score would
+    still be NaN (0 x NaN in the product with the values), so every output row
+    and every dq row of the earlier blocks stays finite and equal to the clean
+    run only if their programs never read a block above the diagonal."""
+    pair, _ = pair_and_dense(monkeypatch, 128, 128, 128)
+    q, k, v, weight = attention_inputs(384, 128, 128)
+    weight = weight.at[:, 256:].set(0.0)  # the poisoned rows' own outputs carry no cotangent
+    clean, clean_grads = attention_value_and_grads(pair, q, k, v, weight)
+    got, got_grads = attention_value_and_grads(pair, q, k.at[:, 256:].set(jnp.nan), v.at[:, 256:].set(jnp.nan), weight)
+    assert bool(jnp.isnan(got[:, 256:]).all())  # the last block's own rows do see them
+    np.testing.assert_array_equal(got[:, :256], clean[:, :256])
+    np.testing.assert_array_equal(got_grads[0][:, :256], clean_grads[0][:, :256])  # dq of the earlier rows
+
+
+@pytest.mark.parametrize("attn_impl, dtype, tpu, tokens, dqk, dv, want", [
+    ("auto", "bfloat16", True, 4096, 256, 256, dict(core="kernel", core_head_dim=256, core_v_dim=256, block=512, core_tokens=4096, padded=False)),
+    ("auto", "bfloat16", True, 1024, 192, 128, dict(core="kernel", core_head_dim=256, core_v_dim=128, block=512, core_tokens=1024, padded=True)),
+    ("auto", "bfloat16", True, 700, 256, 256, dict(core="kernel", core_head_dim=256, core_v_dim=256, block=512, core_tokens=1024, padded=False)),
+    ("auto", "bfloat16", True, 200, 64, 64, dict(core="kernel", core_head_dim=128, core_v_dim=128, block=256, core_tokens=256, padded=True)),
+    ("auto", "bfloat16", True, 16384, 256, 256, dict(core="flash", core_head_dim=256, core_v_dim=256, block=512, core_tokens=16384, padded=False)),
+    ("auto", "bfloat16", True, 16200, 192, 128, dict(core="flash", core_head_dim=256, core_v_dim=256, block=128, core_tokens=16256, padded=True)),
+    ("auto", "float32", True, 4096, 256, 256, dict(core="dense", core_head_dim=256, core_v_dim=256, block=None, core_tokens=4096, padded=False)),
+    ("auto", "bfloat16", False, 1024, 192, 128, dict(core="dense", core_head_dim=192, core_v_dim=128, block=None, core_tokens=1024, padded=False)),
+    ("dense", "bfloat16", True, 4096, 256, 256, dict(core="dense", core_head_dim=256, core_v_dim=256, block=None, core_tokens=4096, padded=False)),
+    ("flash", "float32", True, 4096, 256, 256, dict(core="kernel", core_head_dim=256, core_v_dim=256, block=512, core_tokens=4096, padded=False)),
+], ids=["glm-cell", "kimi-cell", "padded-length", "narrow-heads", "past-vmem", "past-vmem-padded", "float32", "cpu",
+        "asked-dense", "asked-fused"])
+def test_which_core_latent_attention_takes_follows_from_dtype_backend_heads_and_length(
+        monkeypatch, attn_impl, dtype, tpu, tokens, dqk, dv, want):
+    from distributed_sigmoid_loss_tpu.models.mixers import latent_attention_core
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_latent_attention
+    from distributed_sigmoid_loss_tpu.parallel import ring_attention
+
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: tpu)
+    assert latent_attention_core(attn_impl, dtype, tokens, dqk, dv) == want
+    # the mixer runs what the rule says, at the sizes it says
+    taken = []
+
+    def core_named(name):
+        def core(q, k, v, **kw):  # the pair takes the heads on the lanes, (b, s, h x d), and their sizes; the others a view
+            taken.append((name, q.shape[1], *kw.get("head_dims", (q.shape[-1], v.shape[-1]))))
+            return jnp.zeros_like(v)
+        return core
+
+    monkeypatch.setattr(pallas_latent_attention, "latent_attention_kernel", core_named("kernel"))
+    monkeypatch.setattr(flash_attention, "flash_self_attention", core_named("flash"))
+    monkeypatch.setattr(ring_attention, "dense_attention", core_named("dense"))
+    short = min(tokens, 16)  # the length is the rule's; the stand-in cores see what reaches them
+    if short == tokens or want["core"] == "dense":
+        layer = LatentAttention(32, 2, dqk - 8, 8, dv, 12, jnp.dtype(dtype), attn_impl=attn_impl)
+        x = jnp.zeros((1, short, 32), jnp.dtype(dtype))
+        jax.eval_shape(lambda x: layer.init_with_output(jax.random.key(0), x)[0], x)
+        short_rule = latent_attention_core(attn_impl, dtype, short, dqk, dv)
+        assert taken == [(short_rule["core"], short, short_rule["core_head_dim"], short_rule["core_v_dim"])]
+
+
+def test_a_latent_attention_layer_on_the_kernel_path_is_the_layer_on_the_dense_path(monkeypatch):
+    """The whole layer, values and parameter gradients, with its core through the
+    interpreted pair (192 / 128 heads padded to 256 / 128, a sequence padded to
+    its block) against the same layer on the dense path: the same parameter tree,
+    the projections' columns recut where the dense path cuts and joins per-head
+    activations. Around the kernels no transpose and no per-head (b, s, h, d)
+    array but the one shared key part's rotation as a single head."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_latent_attention
+
+    real = pallas_latent_attention.latent_attention_kernel
+    monkeypatch.setattr(pallas_latent_attention, "latent_attention_kernel", partial(real, interpret=True))
+    sizes = dict(width=48, num_heads=2, nope_dim=128, shared_dim=64, v_dim=128, kv_rank=32, dtype=jnp.float32,
+                 q_rank=24, rope_theta=1e4)
+    dense, fused = LatentAttention(**sizes, attn_impl="dense"), LatentAttention(**sizes, attn_impl="flash")
+    x = jax.random.normal(jax.random.key(0), (2, 40, 48), jnp.float32)
+    params = moved(dense.init(jax.random.key(1), x)["params"])
+    weight = ripple((2, 40, 48))
+
+    def loss(layer):
+        return lambda p, x: (layer.apply({"params": p}, x) * weight).sum()
+
+    want, want_grads = jax.value_and_grad(loss(dense), argnums=(0, 1))(params, x)
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
+    got, got_grads = jax.value_and_grad(loss(fused), argnums=(0, 1))(params, x)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    worst = reference_kimi._base.tree_max_rel_err(got_grads, want_grads)
+    assert max(worst.values()) < 1e-3, worst
+    traced = jax.make_jaxpr(jax.value_and_grad(loss(fused), argnums=(0, 1)))(params, x)
+    assert str(traced).count("pallas_call") == 2  # mla_attn_fwd, mla_attn_bwd
+    assert "transpose[" not in str(traced).replace("transpose[permutation=(1, 0)]", "")  # but the weights' own
+    assert per_head_values(traced.jaxpr, 2, 256) == [] and per_head_values(traced.jaxpr, 2, 128) == []
+
+
+@pytest.mark.parametrize("dp, tp, shard", [(2, 1, (2, 24, 4 * 128)), (2, 2, (2, 24, 2 * 128)), (1, 4, (4, 24, 1 * 128))],
+                         ids=["dp2", "dp2-tp2", "tp4"])
+def test_the_pair_sits_in_a_shard_map_under_a_jit_over_several_chips(monkeypatch, dp, tp, shard):
+    """As the delta rule's kernels above: traced on a mesh the pair sees a chip's
+    rows and, over ``tp``, a chip's whole heads (it reads their number off the
+    widths it is given); bare without one. The same values and gradients."""
+    import contextlib
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_latent_attention
+    from distributed_sigmoid_loss_tpu.parallel.mesh import make_2d_mesh, trace_on
+
+    seen, real = [], pallas_latent_attention.latent_attention_kernel
+
+    def interpreted(q, k, v, **kw):
+        seen.append((q.shape, v.shape, kw["head_dims"]))
+        return real(q, k, v, interpret=True, **kw)
+
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
+    monkeypatch.setattr(pallas_latent_attention, "latent_attention_kernel", interpreted)
+    mesh = make_2d_mesh(dp, tp)
+    layer = LatentAttention(32, 4, 120, 8, 128, 12, jnp.float32, attn_impl="flash", rope_theta=1e4)
+    x = jax.device_put(jax.random.normal(jax.random.key(0), (4, 24, 32), jnp.float32), NamedSharding(mesh, P("dp")))
+    params = layer.init(jax.random.key(1), x)["params"]
+    seen.clear()
+
+    def grads(on_mesh):
+        def loss(p, x):
+            with trace_on(mesh) if on_mesh else contextlib.nullcontext():
+                return (layer.apply({"params": p}, x) ** 2).sum()
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+
+    want, want_grads = grads(False)
+    assert set(seen) == {((4, 24, 4 * 128), (4, 24, 4 * 128), (128, 128))}  # the heads on the lanes
+    seen.clear()
+    got, got_grads = grads(True)
+    assert set(seen) == {(shard, shard, (128, 128))}  # a chip's rows and heads, at the same head sizes
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert max(reference_kimi._base.tree_max_rel_err(got_grads, want_grads).values()) < 1e-4
 
 
 # -- (c) routing -------------------------------------------------------------------

@@ -1,5 +1,6 @@
-"""The delta-rule kernels compiled by the chip's own compiler for a TPU v5e that
-is described and not attached, at the hybrid cell's widths: what Mosaic refuses
+"""The delta-rule kernels and latent attention's kernel pair compiled by the chip's
+own compiler for a TPU v5e that is described and not attached, at the two routed
+cells' widths: what Mosaic refuses
 (a slice off the tiling, too much VMEM) the interpreter accepts, so the CPU
 tests of tests/test_hybrid_layers.py cannot see it. Nothing runs. Every test
 that describes a topology lives in this one file (one worker loads the TPU's
@@ -13,6 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from distributed_sigmoid_loss_tpu.ops.pallas_delta_rule import delta_rule_kernel
+from distributed_sigmoid_loss_tpu.ops.pallas_latent_attention import latent_attention_kernel, latent_attention_plan
 
 
 @pytest.fixture(scope="module")
@@ -107,3 +109,28 @@ def test_the_kernels_with_the_norms_inside_compile_for_a_v5e(one_chip, heads, to
     assert [x.shape for x in both.out_info] == [a.shape for a in args]
     asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
     assert set(asked) == {"kda_fwd", "kda_bwd"} and max(asked.values()) < MOSAIC_VMEM_LIMIT // 2, asked
+
+
+# Latent attention's core as the mixer calls it, the heads on the lanes: the GLM cell's (20 heads of 256 / 256 at 4096
+# tokens), Kimi's (32 heads of 192 / 128 zero-padded to 256 / 128 at 1024), and a length that is padded to its block.
+@pytest.mark.parametrize("heads, tokens, dqk, dv", [(20, 4096, 256, 256), (32, 1024, 256, 128), (2, 700, 128, 128)],
+                         ids=["glm-cell", "kimi-cell", "padded-length"])
+def test_latent_attentions_kernel_pair_compiles_for_a_v5e(one_chip, heads, tokens, dqk, dv):
+    def of(d):
+        return jax.ShapeDtypeStruct((2, tokens, heads * d), jnp.bfloat16, sharding=one_chip)
+
+    args = (of(dqk), of(dqk), of(dv))
+    core = lambda q, k, v: latent_attention_kernel(q, k, v, head_dims=(dqk, dv))  # noqa: E731
+    forward = jax.jit(core).lower(*args).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1
+
+    def loss(*a):
+        return (core(*a).astype(jnp.float32) ** 2).sum()
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    both = jax.jit(grads).lower(*args).compile()
+    assert both.as_text().count("tpu_custom_call") == 2  # mla_attn_fwd leaving the log-sum-exp column, mla_attn_bwd
+    assert [x.shape for x in both.out_info] == [a.shape for a in args]
+    asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
+    plan = latent_attention_plan(tokens, dqk, dv)
+    assert set(asked) == {"mla_attn_fwd", "mla_attn_bwd"} and max(asked.values()) <= plan["vmem_bytes"], (asked, plan)
