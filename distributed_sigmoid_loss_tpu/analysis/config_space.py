@@ -440,8 +440,8 @@ def probe_imperative(cfg: StepConfig) -> tuple[bool, str]:
     state-content checks (validate_pp_tower, state.ema presence) are
     environmental, not config-space, and are out of probe scope: the text
     tower's block options (utils.config.BLOCK_OPTIONS: norm, sandwich_norm,
-    mlp, use_bias, pos, loops, norm_eps, mixers, leading_dense_layers, mla_q_rank
-    and the moe_router group) are no axis of this lattice, every step
+    mlp, use_bias, pos, loops, norm_eps, norm_unit_offset, mixers,
+    leading_dense_layers, mla_q_rank and the moe_router group) are no axis of this lattice, every step
     builder takes them as it takes any tower, and the one axis whose builder
     re-implements the block (``pp``) refuses each by name in
     validate_pp_tower. What a block option excludes beside ``pp`` is stated
@@ -605,6 +605,7 @@ def config_space_drift_findings(
 # tower_exclusion_drift builds each pair and checks the refusal.
 _MIXED = {"mixers": ("kda", "mla")}
 _DROPLESS = {"moe_router": "sigmoid", "moe_experts": 4}
+_EVA = {"mixers": ("eva", "eva"), "pos": "rope", "eva_window": 4, "eva_chunk": 2}
 _UNLIKE_LAYERS = "models/transformer.py::Encoder._check_unlike_layers"
 TOWER_EXCLUSIONS: tuple = (
     (_MIXED, {"sequence_parallel_axis": "sp"}, "sequence_parallel_axis=", _UNLIKE_LAYERS,
@@ -617,6 +618,13 @@ TOWER_EXCLUSIONS: tuple = (
      "a recurrence takes no rotation; a stack of latent attention alone rotates its shared-width parts"),
     (_MIXED, {"causal": False}, "causal=", _UNLIKE_LAYERS, "a recurrence has a direction"),
     (_MIXED, {"loops": 2}, "loops=", _UNLIKE_LAYERS, "a looped mixed stack is not built"),
+    (_EVA, {"sequence_parallel_axis": "sp"}, "sequence_parallel_axis=", _UNLIKE_LAYERS,
+     "summaries across sequence shards are not built"),
+    (_EVA, {"quant_train": "int8"}, "quant=", _UNLIKE_LAYERS, "the mixer's projections and core have no int8 path"),
+    (_EVA, {"causal": False}, "causal=", _UNLIKE_LAYERS, "a window's queries meet earlier windows only"),
+    (_EVA, {"loops": 2}, "loops=", _UNLIKE_LAYERS, "a looped stack of windowed chunk attention is not built"),
+    (_EVA, {"context_length": 10}, "context_length=", "models/text.py::layer_specs", "a sequence is whole windows"),
+    (_EVA, {"eva_chunk": 3}, "eva_chunk=", "models/text.py::layer_specs", "a window is whole chunks"),
     (_DROPLESS, {"quant_train": "int8"}, "quant=", "models/transformer.py::Block",
      "the dropless experts have no int8 path"),
     (_DROPLESS, {"mlp": "gelu"}, "mlp=", "models/transformer.py::Block",
@@ -625,7 +633,7 @@ TOWER_EXCLUSIONS: tuple = (
 # The options that change the block refuse the pipeline by their own name.
 PP_REFUSES: tuple = (
     "mixers", "leading_dense_layers", "norm_eps", "moe_router", "moe_route_scale",
-    "moe_shared_experts", "moe_hidden", "moe_experts_held", "mla_q_rank",
+    "moe_shared_experts", "moe_hidden", "moe_experts_held", "mla_q_rank", "norm_unit_offset",
 )
 
 
@@ -661,7 +669,7 @@ def tower_exclusion_drift() -> list[str]:
         alone = builds(dc.replace(base, **option))
         if alone is not None:
             drift.append(f"{option} alone is refused ({source}): {alone}")
-        both = builds(dc.replace(base, **option, **excluded))
+        both = builds(dc.replace(base, **{**option, **excluded}))
         if both is None or named not in both:
             drift.append(f"{option} with {excluded} is not refused by {named!r} ({source}): {both}")
     if set(PP_REFUSES) - set(BLOCK_OPTIONS):

@@ -1,9 +1,10 @@
-"""Token mixers of a stack of several layer kinds (``TextConfig.mixers``), beside
+"""Token mixers of a stack given layer by layer (``TextConfig.mixers``), beside
 the block's softmax ``Attention`` (models/transformer.py): a gated delta-rule
-layer ("kda") and latent attention ("mla"). Imported only where a configuration
-names one. Both are causal and carry no bias; their statistics, gates and decays
-are float32 whatever the tower's dtype. The recurrence takes no position
-encoding; latent attention none, or a rotation of its shared-width parts.
+layer ("kda"), latent attention ("mla") and windowed chunk attention ("eva").
+Imported only where a configuration names one. All are causal and carry no
+bias; their statistics, gates and decays are float32 whatever the tower's dtype.
+The recurrence takes no position encoding; latent attention none, or a rotation
+of its shared-width parts; windowed chunk attention rotates whole heads.
 
 Latent attention's core (scores, causal softmax, values; scope ``mla_core``) is
 one of three, by :func:`latent_attention_core`, from what the call can see:
@@ -33,6 +34,22 @@ With x the (s, width) normalised stream of one sequence:
           [qn_h, qr_h] = q_h ;  with rope_theta: qr_h = rope(qr_h), kr = rope(kr)
           k_h = [kn_h, kr]   (kr shared by all heads, rotated once)
           out = softmax([qn_h, qr_h] k_h^T (dn + dr)^-1/2 + causal) v_h -> Wo
+    EVA   q_h, k_h, v_h = rope((x Wq)_h), rope((x Wk)_h), (x Wv)_h          # whole heads rotated, positions 0..s-1
+          chunk c = tokens Cc .. Cc+C-1, in window floor(Cc / W) ;  window(t) = floor(t / W)
+          a_j  = softmax over j in c of (k_j . phi_h) d^-1/2                # phi_h, mu_h: (d,) leaves per head
+          kc_c = sum_j a_j k_j + mu_h ;  vc_c = sum_j a_j v_j               # scope eva_summary, from the rotated keys
+          s_tj = q_t . k_j d^-1/2   for j <= t in window(t)                 # exact, causal, inside the window
+          r_tc = q_t . kc_c d^-1/2  for every chunk c of a window before window(t)
+          o_t  = (sum_j e^s_tj v_j + sum_c e^r_tc vc_c) / (sum_j e^s_tj + sum_c e^r_tc)   # scope eva_core: one softmax
+          out  = concat_h(o_t) Wo
+
+Windowed chunk attention's core is one of two, by :func:`eva_attention_core`:
+``"kernel"``, the Pallas pair ``eva_attn_fwd`` / ``eva_attn_bwd``
+(``ops/pallas_eva_attention.py``) on (b, s, h x d), or ``"dense"``, XLA on a
+per-head view (the CPU, float32). The rotation and the pooling run on (b, s, h
+x d) too: a head's halves are exchanged by two shifts of the lanes, a head's
+dot with phi and the weights' way back onto its lanes are 0/1-patterned
+products.
 """
 
 from __future__ import annotations
@@ -58,6 +75,8 @@ F32 = jnp.float32
 KDA_CORE_SCOPE = "kda_core"  # the recurrence alone, inside "kda"
 MLA_CORE_SCOPE = "mla_core"  # scores, softmax and values, inside "mla"
 MLA_ROPE_SCOPE = "mla_rope"  # the rotation of the queries' and the key's shared-width parts, inside "mla"
+EVA_SUMMARY_SCOPE = "eva_summary"  # the pooling of keys and values into chunk summaries, inside "eva"
+EVA_CORE_SCOPE = "eva_core"  # both score sets, the one softmax and the values, inside "eva"
 # Tokens a chunk of the delta rule: what one program of the kernels (ops/pallas_delta_rule.py)
 # holds in VMEM per head, six halving levels and a 64 x 64 float32 inverse; the XLA form's too.
 CHUNK = 64
@@ -288,3 +307,142 @@ class LatentAttention(nn.Module):
         if wv != dv:  # a value head narrower than its window: its zero lanes go
             out = out.reshape(b, s, h, wv)[..., :dv]
         return dense(self.width, name="out")(out.astype(self.dtype).reshape(b, s, h * dv))
+
+
+def eva_attention_core(attn_impl: str, dtype, tokens: int, window: int, chunk: int, head_dim: int) -> dict:
+    """Which core an ``EvaAttention`` call takes, from what it can see:
+    ``"kernel"`` (the Pallas pair ``eva_attn_fwd`` / ``eva_attn_bwd``,
+    ``ops/pallas_eva_attention.py``, on (b, s, h x d)) or ``"dense"`` (XLA, on
+    a per-head view). As in ``Attention``: a fused kernel's backward is
+    bf16-grade, so "auto" takes it for a bf16 tower on a TPU only, and only
+    where the kernels take the shapes (``eva_attention_plan``); "flash" asks for
+    it whatever the dtype and fails where they do not. With the core: ``block``
+    (the tokens a block of the kernel; None: dense), ``windows`` and
+    ``summaries`` a sequence. The mixer runs what this says and the step's
+    trace-time record (``train_step.stack_record_of``) reports it."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention
+    from distributed_sigmoid_loss_tpu.ops.pallas_eva_attention import eva_attention_plan
+
+    if attn_impl == "flash" and not flash_attention.flash_attention_available():
+        raise ValueError("attn_impl='flash' requires a TPU backend; use 'auto'")
+    plan = eva_attention_plan(tokens, window, chunk, head_dim, jnp.dtype(dtype).itemsize)
+    if attn_impl == "flash" and plan is None:
+        raise ValueError(f"attn_impl='flash': the eva kernels do not take {tokens} tokens in windows of {window}, "
+                         f"chunks of {chunk} and heads of {head_dim}")
+    fused = plan is not None and (attn_impl == "flash" or (
+        attn_impl == "auto" and jnp.dtype(dtype) == jnp.bfloat16 and flash_attention.flash_attention_available()
+    ))
+    return {"core": "kernel" if fused else "dense", "block": plan["block"] if fused else None,
+            "windows": tokens // window, "summaries": tokens // chunk}
+
+
+def rope_on_the_lanes(x, heads: int, theta: float):
+    """``models/transformer.py rope`` on (b, s, h x d), the heads on the lanes:
+    the same tables and the same float32 pass, the exchange of a head's halves
+    by two shifts of the whole lane axis (a lane of a head's first half reads d
+    / 2 lanes up, of its second half d / 2 lanes down, which never leaves the
+    head), so that no per-head (b, s, h, d) view is made. Bit for bit ``rope``'s
+    numbers."""
+    from distributed_sigmoid_loss_tpu.models.transformer import rope_tables
+
+    s, d = x.shape[1], x.shape[-1] // heads
+    cos, sin = (jnp.tile(jnp.asarray(t), (1, heads)) for t in rope_tables(s, d, theta))
+    first = jnp.asarray(np.arange(heads * d) % d < d // 2)
+    with jax.named_scope("rope"):
+        x32 = x.astype(F32)
+        swapped = jnp.where(first, jnp.roll(x32, -(d // 2), -1), jnp.roll(x32, d // 2, -1))
+        return (x32 * cos + swapped * sin).astype(x.dtype)
+
+
+def eva_summaries(k, v, phi, mu, chunk: int, dtype):
+    """The chunks' pooled keys and values. k, v: (b, s, h x d), k rotated; phi,
+    mu: (h, d) float32. a = softmax over a chunk's tokens of (k . phi_h) d^-1/2,
+    kc = sum a k + mu_h, vc = sum a v, in float32, returned (b, s / chunk, h x d)
+    in ``dtype``. A head's dot with phi and the weights' way back onto its lanes
+    are products with (h x d, h) and (h, h x d) matrices that are zero outside
+    the head: the MXU does what a per-head view would relayout for."""
+    b, s, width = k.shape
+    h, d = phi.shape
+    of_head = jnp.asarray(np.repeat(np.eye(h, dtype=np.float32), d, axis=0))  # (h x d, h): lane -> its head
+    # Operands in the tower's dtype, float32 sums: exact in a float32 tower, one MXU pass in a bf16 one.
+    product = partial(jnp.dot, precision=jax.lax.Precision.HIGHEST, preferred_element_type=F32)
+    logits = product(k, (of_head * phi.reshape(width, 1)).astype(k.dtype)) * d**-0.5  # (b, s, h)
+    a = jax.nn.softmax(logits.reshape(b, s // chunk, chunk, h), axis=2).reshape(b, s, h)
+    # (b, s, h x d): each head's weight on its lanes. Float32 out although the values are the operand's: asked for
+    # in bf16 the product cost the cell's step 400 ms more under the matmuls' name (PERF.md section 6, PR 39).
+    a = product(a.astype(k.dtype), of_head.T.astype(k.dtype))
+
+    def pooled(x):
+        return (a * x.astype(F32)).reshape(b, s // chunk, chunk, width).sum(2)
+
+    return (pooled(k) + mu.reshape(width)).astype(dtype), pooled(v).astype(dtype)
+
+
+def eva_core_dense(q, k, v, kc, vc, *, heads: int, window: int, scale: float):
+    """The core in XLA, on a per-head view: q, k, v (b, s, h x d), kc, vc (b, s /
+    chunk, h x d) -> (b, s, h x d). Inside a window ``dense_attention``'s causal
+    scores; a query of window w also scores every summary of the windows before
+    w; one float32 softmax over both sets. A sequence of one window IS
+    ``dense_attention``."""
+    from distributed_sigmoid_loss_tpu.parallel.ring_attention import _NEG_INF, dense_attention
+
+    b, s, width = q.shape
+    n, d = s // window, width // heads
+    per_window = kc.shape[1] // n
+    if n == 1:
+        return dense_attention(*(t.reshape(b, s, heads, d) for t in (q, k, v)), causal=True, scale=scale).reshape(b, s, width)
+    q, k, v = (t.reshape(b, n, window, heads, d) for t in (q, k, v))
+    kc, vc = (t.reshape(b, n * per_window, heads, d) for t in (kc, vc))
+    exact = jnp.einsum("bwqhd,bwkhd->bwhqk", q, k) * scale
+    exact = jnp.where(jnp.tril(jnp.ones((window, window), bool)), exact, _NEG_INF)
+    remote = jnp.einsum("bwqhd,bchd->bwhqc", q, kc) * scale
+    admitted = (np.arange(n * per_window) // per_window)[None, :] < np.arange(n)[:, None]  # (window, summary)
+    remote = jnp.where(jnp.asarray(admitted)[None, :, None, None, :], remote, _NEG_INF)
+    probs = jax.nn.softmax(jnp.concatenate([exact, remote], -1).astype(F32), axis=-1).astype(v.dtype)
+    out = (jnp.einsum("bwhqk,bwkhd->bwqhd", probs[..., :window], v)
+           + jnp.einsum("bwhqc,bchd->bwqhd", probs[..., window:], vc))
+    return out.reshape(b, s, width)
+
+
+class EvaAttention(nn.Module):
+    """Windowed chunk attention: softmax attention that is exact and causal
+    inside a window of ``window`` tokens and reads everything before the
+    window as one pooled key and value per ``chunk`` tokens, under one softmax
+    (the module docstring has the equations). ``num_heads`` heads across
+    ``width``, whole heads rotated; two (heads, head size) float32 leaves, the
+    pooling's direction ``phi`` and the summaries' key offset ``mu``. q, k, v
+    and the summaries stay (b, s, h x d) around the kernel pair; the XLA core
+    takes a per-head view."""
+
+    width: int
+    num_heads: int
+    window: int
+    chunk: int
+    rope_theta: float
+    dtype: Any
+    attn_impl: str = "auto"
+
+    @nn.compact
+    def __call__(self, x):
+        from distributed_sigmoid_loss_tpu.ops.pallas_eva_attention import eva_attention_kernel
+
+        s = x.shape[1]
+        h, d = self.num_heads, self.width // self.num_heads
+        dense = partial(
+            nn.Dense, use_bias=False, dtype=self.dtype,
+            kernel_init=nn.initializers.xavier_uniform(),
+        )
+        sizes = eva_attention_core(self.attn_impl, self.dtype, s, self.window, self.chunk, d)
+        q, k, v = (dense(self.width, name=name)(x) for name in "qkv")
+        q, k = rope_on_the_lanes(q, h, self.rope_theta), rope_on_the_lanes(k, h, self.rope_theta)
+        phi = self.param("phi", nn.initializers.normal(1.0), (h, d), F32)
+        mu = self.param("mu", nn.initializers.normal(1.0), (h, d), F32)
+        with jax.named_scope(EVA_SUMMARY_SCOPE):
+            kc, vc = eva_summaries(k, v, phi, mu, self.chunk, self.dtype)
+        with jax.named_scope(EVA_CORE_SCOPE):
+            if sizes["core"] == "kernel":
+                core = partial(eva_attention_kernel, head_dim=d, window=self.window, scale=d**-0.5)
+                out = kernels_per_shard(core, h, q, k, v, kc, vc)  # a Mosaic kernel under a multi-chip jit sits in a shard_map
+            else:
+                out = eva_core_dense(q, k, v, kc, vc, heads=h, window=self.window, scale=d**-0.5)
+        return dense(self.width, name="out")(out.astype(self.dtype))

@@ -5,7 +5,8 @@ convention, test_distributed_sigmoid_loss.py:96-101). ``TextConfig``'s block
 options turn it into a language-model-class encoder: causal, rotary positions
 in place of the position table (or none: ``pos="none"``), RMSNorm sandwich
 blocks with a gated MLP, the stack run ``loops`` times on one set of weights, or
-a stack of several layer kinds with routed experts (``mixers``)."""
+a stack given layer by layer (``mixers``): several layer kinds with routed
+experts, or windowed chunk attention in every layer."""
 
 from __future__ import annotations
 
@@ -36,6 +37,9 @@ def layer_specs(cfg: TextConfig) -> tuple[LayerSpec, ...]:
             ("v_dim", cfg.mla_v_dim), ("kv_rank", cfg.mla_kv_rank), ("q_rank", cfg.mla_q_rank),
             ("rope_theta", cfg.rope_theta if cfg.pos == "rope" else None),
         ),
+        "eva": (
+            ("window", cfg.eva_window), ("chunk", cfg.eva_chunk), ("rope_theta", cfg.rope_theta),
+        ),
     }
     mixers = cfg.mixers or ("attn",) * cfg.depth
     if not set(mixers) <= set(mixer_fields):
@@ -47,6 +51,21 @@ def layer_specs(cfg: TextConfig) -> tuple[LayerSpec, ...]:
             f"mixers={mixers} is not built for pos={cfg.pos!r}: a recurrence ('kda') takes "
             "pos='none', latent attention ('mla') 'none' or 'rope'"
         )
+    if "eva" in mixers:
+        # Windowed chunk attention rotates whole heads by the positions of one whole
+        # sequence, cut into whole windows of whole chunks.
+        refused = {
+            f"pos={cfg.pos!r} (it takes 'rope')": cfg.pos != "rope",
+            f"context_length={cfg.context_length} (no multiple of eva_window={cfg.eva_window})":
+                cfg.eva_window < 1 or cfg.context_length % max(cfg.eva_window, 1) != 0,
+            f"eva_window={cfg.eva_window} (no multiple of eva_chunk={cfg.eva_chunk})":
+                cfg.eva_chunk < 1 or cfg.eva_window % max(cfg.eva_chunk, 1) != 0,
+        }
+        if any(refused.values()):
+            raise ValueError(
+                f"mixers={mixers}: windowed chunk attention ('eva') is not built for "
+                + ", ".join(k for k, v in refused.items() if v)
+            )
     if cfg.moe_router not in ("softmax", "sigmoid"):
         raise ValueError(f"unknown moe_router: {cfg.moe_router!r}")
     experts_fields = ()

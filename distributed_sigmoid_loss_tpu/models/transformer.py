@@ -127,6 +127,7 @@ class BlockStyle:
     use_bias: bool = True
     rope_theta: float | None = None  # None = no rotary positions
     norm_eps: float = 1e-6
+    norm_unit_offset: bool = False  # "rmsnorm" whose leaf is an offset from 1
 
     @classmethod
     def of(cls, cfg) -> "BlockStyle":
@@ -134,16 +135,37 @@ class BlockStyle:
             norm=cfg.norm, sandwich_norm=cfg.sandwich_norm, mlp=cfg.mlp,
             use_bias=cfg.use_bias,
             rope_theta=cfg.rope_theta if cfg.pos == "rope" else None,
-            norm_eps=cfg.norm_eps,
+            norm_eps=cfg.norm_eps, norm_unit_offset=cfg.norm_unit_offset,
         )
 
     def make_norm(self, dtype, name: str) -> nn.Module:
         """Both kinds compute their statistics in float32, at ``norm_eps``."""
+        if self.norm_unit_offset:
+            if self.norm != "rmsnorm":
+                raise ValueError(f"norm_unit_offset=True is an RMSNorm's, not built for norm={self.norm!r}")
+            return OffsetRMSNorm(epsilon=self.norm_eps, dtype=dtype, name=name)
         if self.norm == "rmsnorm":
             return nn.RMSNorm(epsilon=self.norm_eps, dtype=dtype, name=name)
         if self.norm == "layernorm":
             return nn.LayerNorm(epsilon=self.norm_eps, dtype=dtype, name=name)
         raise ValueError(f"unknown norm: {self.norm!r}")
+
+
+class OffsetRMSNorm(nn.Module):
+    """x rsqrt(mean(x^2) + eps) (1 + offset): an RMSNorm whose leaf is stored
+    as an offset from 1, initially 0. The numbers at initialisation are
+    ``nn.RMSNorm``'s; under weight decay the scale is pulled to 1, not to 0.
+    Statistics and the scaling in float32, the result in ``dtype``."""
+
+    epsilon: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        offset = self.param("offset", nn.initializers.zeros, (x.shape[-1],), jnp.float32)
+        x = x.astype(jnp.float32)
+        normed = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.epsilon)
+        return (normed * (1.0 + offset)).astype(self.dtype)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,10 +175,21 @@ class LayerSpec:
     mixer with its module's own size fields, and what the MLP is where
     ``moe_experts > 0``. The default is the block every tower had."""
 
-    mixer: str = "attn"  # "attn" | "kda" | "mla" (models/mixers.py)
-    mixer_fields: tuple = ()  # (name, value) pairs of KdaMixer / LatentAttention
+    mixer: str = "attn"  # "attn" | "kda" | "mla" | "eva" (models/mixers.py)
+    mixer_fields: tuple = ()  # (name, value) pairs of KdaMixer / LatentAttention / EvaAttention
     dense_mlp: bool = False  # a leading layer keeps the dense MLP
     experts_fields: tuple = ()  # (name, value) pairs of SharedExpertMoe; none = MoeMlp
+
+
+def rope_tables(s: int, dh: int, theta: float):
+    """(cos, signed sin) of :func:`rope` for one head: (s, dh) float32 from
+    float64, cos tiled over both halves, sin negated in the first."""
+    if dh % 2:
+        raise ValueError(f"pos='rope' needs an even head size, got {dh}")
+    angle = np.arange(s)[:, None] / theta ** (np.arange(0, dh, 2) / dh)  # (s, dh/2)
+    cos = np.concatenate([np.cos(angle), np.cos(angle)], -1)
+    sin = np.concatenate([-np.sin(angle), np.sin(angle)], -1)
+    return cos.astype(np.float32), sin.astype(np.float32)
 
 
 def rope(x, theta: float):
@@ -172,12 +205,7 @@ def rope(x, theta: float):
     1752 ms step at dh 128, s 256 (PERF.md section 6, PR 25). The tables are
     constants of the trace."""
     s, dh = x.shape[1], x.shape[-1]
-    if dh % 2:
-        raise ValueError(f"pos='rope' needs an even head size, got {dh}")
-    angle = np.arange(s)[:, None] / theta ** (np.arange(0, dh, 2) / dh)  # (s, dh/2)
-    cos = np.concatenate([np.cos(angle), np.cos(angle)], -1)
-    sin = np.concatenate([-np.sin(angle), np.sin(angle)], -1)
-    cos, sin = (t.astype(np.float32)[None, :, None, :] for t in (cos, sin))
+    cos, sin = (t[None, :, None, :] for t in rope_tables(s, dh, theta))
     swap = jnp.asarray(np.roll(np.eye(dh), dh // 2, axis=0), x.dtype)
     with jax.named_scope("rope"):
         swapped = jax.lax.dot_general(
@@ -428,6 +456,13 @@ class Block(nn.Module):
                 mixer = KdaMixer(**sized, name="kda")
             else:
                 mixer = LatentAttention(**sized, attn_impl=self.attn_impl, name="mla")
+        elif spec.mixer == "eva":
+            from distributed_sigmoid_loss_tpu.models.mixers import EvaAttention
+
+            mixer = EvaAttention(
+                width=self.width, num_heads=self.num_heads, dtype=self.dtype,
+                attn_impl=self.attn_impl, **dict(spec.mixer_fields), name="eva",
+            )
         else:
             raise ValueError(f"unknown mixer: mixers has {spec.mixer!r}")
         x = x + post("ln1_post", mixer(norm("ln1")(x)))
@@ -577,7 +612,9 @@ class Encoder(nn.Module):
     """Stack of blocks, then the final norm; optionally remat'd and scanned over
     depth. ``loops > 1`` runs that whole pass ``loops`` times on one set of
     weights, which then live under ``loop/`` (a flax path of its own: a profile
-    shows the looped stack's operations under it).
+    shows the looped stack's operations under it). Layers given one by one
+    (``layers``) that are all alike are one stack like any other, scanned where
+    ``scan_layers`` says; unlike layers run unrolled, remat per layer.
 
     A scanned stack run once takes a ``GRAD_SINK`` collection (applied with
     ``mutable=[GRAD_SINK]``): the gradient accumulator of its ``blocks``, which
@@ -610,7 +647,8 @@ class Encoder(nn.Module):
     style: BlockStyle = BlockStyle()
     loops: int = 1
     # One spec a layer, or none: every layer the default block. Layers that are
-    # all alike and attend are one stack (scanned where ``scan_layers`` says).
+    # all alike are one stack (scanned where ``scan_layers`` says), whatever
+    # their mixer; unlike layers run unrolled.
     layers: tuple[LayerSpec, ...] = ()
 
     @nn.compact
@@ -627,7 +665,8 @@ class Encoder(nn.Module):
             raise ValueError(f"mixers names {len(layers)} layers, depth={self.depth}")
         # Several layer kinds, or a leading dense layer: each layer is a module
         # of its own (no stack of like trees to scan), unrolled, remat per layer.
-        unlike = len(set(layers)) > 1 or layers[0].mixer != "attn"
+        # Like layers are one stack whatever their mixer: ``_ScanBody`` takes the spec.
+        unlike = len(set(layers)) > 1
         self._check_mixers(tuple(spec.mixer for spec in layers))
         if self.loops > 1:
             return self._looped(x)
@@ -694,9 +733,10 @@ class Encoder(nn.Module):
         }
         if set(mixers) != {"attn"} and any(refused.values()):
             raise ValueError(
-                f"mixers={mixers} (a recurrence or latent attention over one "
-                "whole causal sequence, unquantised, a recurrence with no position "
-                "encoding) is not built for " + ", ".join(k for k, v in refused.items() if v)
+                f"mixers={mixers} (a recurrence, latent attention or windowed chunk "
+                "attention over one whole causal sequence, unquantised, a recurrence "
+                "with no position encoding) is not built for "
+                + ", ".join(k for k, v in refused.items() if v)
             )
 
     def _looped(self, x):

@@ -291,7 +291,12 @@ def accum_placement(step) -> dict | None:
 
 def mixed_stack(step) -> dict | None:
     """What a traced train step's text tower runs where it is a stack of
-    several layer kinds with dropless routed experts, from the record
+    several layer kinds with dropless routed experts, or a stack of windowed
+    chunk attention (``eva``, by layer index: the core it took, ``"kernel"``:
+    the Pallas pair ``eva_attn_fwd`` / ``eva_attn_bwd``, or ``"dense"``: XLA;
+    the tokens a block of the kernel, the window, the chunk, the windows and
+    the summaries a sequence; ``scanned``: whether the layers are one scanned
+    stack; the experts' keys are then absent), from the record
     ``make_train_step`` writes while it traces (``step.stack_record``): the
     layers' kinds in order (``mixer+mlp`` / ``mixer+moe``), experts held / in
     all / per token, the assignments to held experts a token is expected to
@@ -328,6 +333,11 @@ def mixed_stack_line(record: dict | None) -> str | None:
         ran = f"heads {m['core_head_dim']}/{m['core_v_dim']}" + (" (zero-padded)" if m["padded"] else "")
         blocks = "" if m["block"] is None else f", {m['core_tokens'] // m['block']} blocks of {m['block']} tokens"
         parts.append(f"mla[{i}] core={m['core']} {ran}{blocks}")
+    for i, e in sorted(record.get("eva", {}).items()):
+        blocks = "" if e["block"] is None else f", blocks of {e['block']} tokens"
+        parts.append(f"eva[{i}] core={e['core']} {e['windows']} windows of {e['window']}, {e['summaries']} summaries{blocks}")
+    if "scanned" in record:
+        parts.append("scanned" if record["scanned"] else "unrolled")
     for i, k in sorted(record.get("kda_core", {}).items()):
         parts.append(f"kda[{i}] core={k['core']} qk_norm={k['qk_norm']} o_norm={k['o_norm']}, {k['chunks']} chunks")
     return "stack: " + "; ".join(parts)

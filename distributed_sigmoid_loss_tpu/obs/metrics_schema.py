@@ -34,6 +34,7 @@ __all__ = [
     "HEALTH_EVENT_FIELDS",
     "STACK_RECORD_FIELDS",
     "STACK_RECORD_MLA_FIELDS",
+    "STACK_RECORD_EVA_FIELDS",
     "validate_metrics",
 ]
 
@@ -128,6 +129,18 @@ STACK_RECORD_FIELDS = {
     "kda_core": "per delta-rule layer: the core it took (kernel / chunked), where its per-head norms ran "
                 "(qk_norm, o_norm: kernel / xla), rows, heads and chunks of a call",
     "mla": "per latent-attention layer: what it is made of (the fields below)",
+    "eva": "per windowed-chunk-attention layer: what it is made of (STACK_RECORD_EVA_FIELDS)",
+    "scanned": "whether the text stack's like layers are one scanned stack (the accumulator then rides the layer loop)",
+}
+# One windowed-chunk-attention layer's entry of ``eva``.
+STACK_RECORD_EVA_FIELDS = {
+    "window": "tokens a window: exact causal attention inside it",
+    "chunk": "tokens a chunk: one summary each",
+    "rope_theta": "the rotation's base (whole heads of q and k)",
+    "core": "kernel (the Pallas pair eva_attn_fwd / eva_attn_bwd on (b, s, h x d)) or dense (XLA), as the dispatcher chose",
+    "block": "tokens a block of the kernel; None for dense",
+    "windows": "windows a sequence",
+    "summaries": "summaries a sequence",
 }
 # One latent-attention layer's entry of ``mla``.
 STACK_RECORD_MLA_FIELDS = {

@@ -321,22 +321,33 @@ def _dropless_text(model):
     return t if t is not None and t.moe_experts and t.moe_router == "sigmoid" else None
 
 
+def _recorded_text(model):
+    """The text configuration of a model whose step keeps a ``stack_record``:
+    a dropless routed stack, or one of windowed chunk attention; else None."""
+    t = getattr(getattr(model, "cfg", None), "text", None)
+    return t if t is not None and (_dropless_text(model) is not None or "eva" in t.mixers) else None
+
+
 def stack_record_of(t, tokens_shape) -> dict:
-    """What a step with a dropless mixed text stack runs, from shapes alone
-    (``step.stack_record``, read by obs/attribution.py mixed_stack)."""
-    held = t.moe_experts_held or t.moe_experts
-    routed = [i >= t.leading_dense_layers for i in range(t.depth)]
+    """What a step with a dropless mixed text stack, or a stack of windowed
+    chunk attention, runs, from shapes alone (``step.stack_record``, read by
+    obs/attribution.py mixed_stack)."""
+    routed = [bool(t.moe_experts) and i >= t.leading_dense_layers for i in range(t.depth)]
     mixers = t.mixers or ("attn",) * t.depth
     tokens = math.prod(tokens_shape)
     record = {
         "layer_kinds": [f"{m}+{'moe' if r else 'mlp'}" for m, r in zip(mixers, routed)],
-        "experts_held": held, "experts_total": t.moe_experts,
-        "experts_per_token": t.moe_num_selected,
-        "expected_local_assignments_per_token": t.moe_num_selected * held / t.moe_experts,
         "tokens_per_microbatch": tokens,
-        # The sort's rows, the true worst case: every token picks held experts only.
-        "dispatch_rows_bound": tokens * t.moe_num_selected,
     }
+    if t.moe_experts:
+        held = t.moe_experts_held or t.moe_experts
+        record.update({
+            "experts_held": held, "experts_total": t.moe_experts,
+            "experts_per_token": t.moe_num_selected,
+            "expected_local_assignments_per_token": t.moe_num_selected * held / t.moe_experts,
+            # The sort's rows, the true worst case: every token picks held experts only.
+            "dispatch_rows_bound": tokens * t.moe_num_selected,
+        })
     if "kda" in mixers:
         from distributed_sigmoid_loss_tpu.models.mixers import CHUNK
         from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import delta_rule_core
@@ -369,6 +380,20 @@ def stack_record_of(t, tokens_shape) -> dict:
             **sizes,
         }
         record["mla"] = {i: dict(made_of) for i, m in enumerate(mixers) if m == "mla"}
+    if "eva" in mixers:
+        from distributed_sigmoid_loss_tpu.models.mixers import eva_attention_core
+        from distributed_sigmoid_loss_tpu.models.text import layer_specs
+
+        # By the rule EvaAttention's call runs by: the core each layer takes ("kernel":
+        # the Pallas pair on (b, s, h x d); "dense": XLA), the tokens a block of the
+        # kernel, the windows and the summaries a sequence; and whether the layers
+        # are one scanned stack (the accumulator then rides the backward layer loop).
+        sizes = eva_attention_core(
+            t.attn_impl, t.dtype, tokens_shape[-1], t.eva_window, t.eva_chunk, t.width // t.num_heads
+        )
+        made_of = {"window": t.eva_window, "chunk": t.eva_chunk, "rope_theta": t.rope_theta, **sizes}
+        record["eva"] = {i: dict(made_of) for i, m in enumerate(mixers) if m == "eva"}
+        record["scanned"] = bool(t.scan_layers) and len(set(layer_specs(t))) == 1  # Encoder's rule: like layers
     return record
 
 
@@ -904,6 +929,7 @@ def make_train_step(
         validate_pp_tower(model.cfg.text, pp_stages, "text")
 
     dropless = _dropless_text(model)
+    recorded = _recorded_text(model)
     stack_record: dict = {}
 
     def loss_fn(params, batch, sink=None):
@@ -915,8 +941,8 @@ def make_train_step(
             mutable.append(GRAD_SINK)
         if moe_aux_weight is not None or dropless is not None:
             mutable.append("intermediates")
-        if dropless is not None:
-            stack_record.update(stack_record_of(dropless, batch["tokens"].shape))
+        if recorded is not None:
+            stack_record.update(stack_record_of(recorded, batch["tokens"].shape))
         updated = {}
         if pp_microbatches:
             zimg, ztxt, lp = siglip_forward_pp(

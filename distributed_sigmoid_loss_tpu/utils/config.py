@@ -178,16 +178,24 @@ class TextConfig:
     loops: int = 1
     # The normalisations' epsilon (LayerNorm and RMSNorm alike).
     norm_eps: float = 1e-6
+    # "rmsnorm" with its leaf stored as an offset from 1: x rsqrt(mean(x^2) +
+    # eps) (1 + offset), the leaf ``offset`` initially 0. Weight decay then pulls
+    # the scale to 1 and not to 0: another training step, not another name.
+    norm_unit_offset: bool = False
     # A stack of several layer kinds in one order: the token mixer of each of
     # the ``depth`` layers, "attn" (the block's softmax attention), "kda" (a
     # chunked gated delta rule behind a short causal convolution,
     # ops/gated_delta_rule.py) or "mla" (latent attention: keys and values
     # expanded from one low-rank latent, a key part shared by all heads, value
-    # heads of their own width; models/mixers.py). Empty = "attn" in every
-    # layer, today's stack. A mixed stack is causal, takes ``pos="none"`` (or
-    # "rope" where no layer is a recurrence), and runs its layers unrolled with
-    # remat per layer: no two neighbours share a parameter tree to scan over, so
-    # ``scan_layers`` does not apply to it.
+    # heads of their own width; models/mixers.py) or "eva" (softmax attention
+    # that is exact inside ``eva_window`` tokens and reads every earlier window
+    # as one pooled key and value per ``eva_chunk`` tokens, under one softmax).
+    # Empty = "attn" in every layer, today's stack. Such a stack is causal and
+    # takes ``pos="none"`` (or "rope" where no layer is a recurrence; "eva"
+    # takes "rope" alone). Unlike layers run unrolled with remat per layer (no
+    # two neighbours share a parameter tree to scan over, so ``scan_layers``
+    # does not apply to them); layers that are all alike are one stack, scanned
+    # where ``scan_layers`` says, whatever their mixer.
     mixers: tuple[str, ...] = ()
     # The first layers keep the dense MLP where ``moe_experts > 0``.
     leading_dense_layers: int = 0
@@ -204,6 +212,11 @@ class TextConfig:
     # > 0: the queries come through a latent too, x Wqa -> RMSNorm -> Wqb, this
     # many channels wide; 0 = one projection.
     mla_q_rank: int = 0
+    # "eva": the tokens of a window (exact, causal attention inside it; the
+    # context is whole windows) and of a chunk (one summary each; a window is
+    # whole chunks).
+    eva_window: int = 2048
+    eva_chunk: int = 16
     # "sigmoid" = the router of the latent-attention language models: scores
     # sigmoid(x Wr) in float32, the ``moe_num_selected`` largest of scores + a
     # selection bias (a leaf that takes no gradient, decay or optimizer state),
@@ -245,6 +258,7 @@ BLOCK_OPTIONS = {
     "pos": "learned", "loops": 1, "norm_eps": 1e-6, "mixers": (),
     "leading_dense_layers": 0, "moe_router": "softmax", "moe_route_scale": 1.0,
     "moe_shared_experts": 0, "moe_hidden": 0, "moe_experts_held": 0, "mla_q_rank": 0,
+    "norm_unit_offset": False,
 }
 
 

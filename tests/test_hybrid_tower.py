@@ -345,7 +345,8 @@ def test_the_presets_keep_their_parameter_trees(preset):
 
 
 NEW_OPTIONS = dict(mixers=("kda", "mla"), leading_dense_layers=1, norm_eps=1e-5, moe_router="sigmoid",
-                   moe_route_scale=2.446, moe_shared_experts=1, moe_hidden=24, moe_experts_held=4, mla_q_rank=8)
+                   moe_route_scale=2.446, moe_shared_experts=1, moe_hidden=24, moe_experts_held=4, mla_q_rank=8,
+                   norm_unit_offset=True)  # the last one: an RMSNorm stored as an offset from 1 (tests/test_eva_tower.py)
 
 
 @pytest.mark.parametrize("option", sorted(NEW_OPTIONS))

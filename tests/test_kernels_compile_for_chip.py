@@ -134,3 +134,29 @@ def test_latent_attentions_kernel_pair_compiles_for_a_v5e(one_chip, heads, token
     asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
     plan = latent_attention_plan(tokens, dqk, dv)
     assert set(asked) == {"mla_attn_fwd", "mla_attn_bwd"} and max(asked.values()) <= plan["vmem_bytes"], (asked, plan)
+
+
+# Windowed chunk attention's core as the mixer calls it, the heads on the lanes: the cell's call (32 heads of 128, 8192
+# tokens in windows of 2048, chunks of 16: 512 summaries a sequence) and a small one (two windows of 256).
+@pytest.mark.parametrize("heads, tokens, window", [(32, 8192, 2048), (2, 512, 256)], ids=["evabyte-cell", "two-windows"])
+def test_windowed_chunk_attentions_kernel_pair_compiles_for_a_v5e(one_chip, heads, tokens, window):
+    from distributed_sigmoid_loss_tpu.ops.pallas_eva_attention import eva_attention_kernel, eva_attention_plan
+
+    def of(length):
+        return jax.ShapeDtypeStruct((2, length, heads * 128), jnp.bfloat16, sharding=one_chip)
+
+    args = (of(tokens),) * 3 + (of(tokens // 16),) * 2
+    assert eva_attention_plan(tokens, window, 16, 128)["windows"] == tokens // window
+    core = lambda *a: eva_attention_kernel(*a, head_dim=128, window=window)  # noqa: E731
+    forward = jax.jit(core).lower(*args).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1
+
+    def loss(*a):
+        return (core(*a).astype(jnp.float32) ** 2).sum()
+
+    grads = jax.grad(loss, argnums=tuple(range(5)))
+    both = jax.jit(grads).lower(*args).compile()
+    assert both.as_text().count("tpu_custom_call") == 2  # eva_attn_fwd leaving the log-sum-exp column, eva_attn_bwd
+    assert [x.shape for x in both.out_info] == [a.shape for a in args]
+    asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
+    assert set(asked) == {"eva_attn_fwd", "eva_attn_bwd"} and max(asked.values()) < 32 * 2**20, asked

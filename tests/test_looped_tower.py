@@ -309,6 +309,8 @@ CHANGED = dict(
     moe_route_scale=2.446, moe_shared_experts=1, moe_hidden=24, moe_experts_held=4,
     # latent attention's query latent (tests/test_glm_tower.py)
     mla_q_rank=8,
+    # an RMSNorm stored as an offset from 1 (tests/test_eva_tower.py)
+    norm_unit_offset=True,
 )
 
 
