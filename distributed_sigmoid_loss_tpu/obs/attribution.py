@@ -307,7 +307,10 @@ def mixed_stack(step) -> dict | None:
     its per-head norms ran (``qk_norm``, the l2 norm of q and k, and ``o_norm``,
     the head RMS norm of o: ``"kernel"``, on the head's tile inside the
     kernels, or ``"xla"``, on a (b, s, h, d) view around the core) with the
-    rows, heads and chunks of a call, and per latent-attention layer (``mla``,
+    rows, heads and chunks of a call and the bytes a differentiated call keeps
+    from its forward to its backward (``kept_bytes``: each chunk's incoming
+    state, what the forward solved of it and 1 / rms on the kernel path, 0 on
+    the chunked one), and per latent-attention layer (``mla``,
     by layer index) what it is made of: the queries' latent (``q_rank``, 0 = one
     projection) and the keys' and values' (``kv_rank``), the width of the
     rotated parts and their base (``rotated_dim`` 0 and ``rope_theta`` None
@@ -339,7 +342,8 @@ def mixed_stack_line(record: dict | None) -> str | None:
     if "scanned" in record:
         parts.append("scanned" if record["scanned"] else "unrolled")
     for i, k in sorted(record.get("kda_core", {}).items()):
-        parts.append(f"kda[{i}] core={k['core']} qk_norm={k['qk_norm']} o_norm={k['o_norm']}, {k['chunks']} chunks")
+        kept = f", {k['kept_bytes'] / 1e6:.0f} MB kept for the backward" if k["kept_bytes"] else ""
+        parts.append(f"kda[{i}] core={k['core']} qk_norm={k['qk_norm']} o_norm={k['o_norm']}, {k['chunks']} chunks{kept}")
     return "stack: " + "; ".join(parts)
 
 

@@ -127,7 +127,8 @@ STACK_RECORD_FIELDS = {
     "tokens_per_microbatch": "text tokens one microbatch routes",
     "dispatch_rows_bound": "the sort's rows: every token choosing held experts only",
     "kda_core": "per delta-rule layer: the core it took (kernel / chunked), where its per-head norms ran "
-                "(qk_norm, o_norm: kernel / xla), rows, heads and chunks of a call",
+                "(qk_norm, o_norm: kernel / xla), rows, heads and chunks of a call, and the bytes a differentiated "
+                "call keeps from its forward to its backward (kept_bytes)",
     "mla": "per latent-attention layer: what it is made of (the fields below)",
     "eva": "per windowed-chunk-attention layer: what it is made of (STACK_RECORD_EVA_FIELDS)",
     "scanned": "whether the text stack's like layers are one scanned stack (the accumulator then rides the layer loop)",

@@ -39,9 +39,12 @@ lanes run the chunk's work in two Pallas kernels (ops/pallas_delta_rule.py,
 ``kda_fwd`` / ``kda_bwd``): a chunk of a few heads stays in VMEM from q, k, v,
 g, beta to o, the state crosses chunks in VMEM scratch along a sequential grid
 axis, the operands are read where they lie ((b, s, h x d), a head an aligned
-128-lane window), and the backward is a kernel of its own that recomputes a
-chunk from the saved operands and the chunk's incoming state (which the
-differentiated forward writes: float32, 64 KB a chunk-head). The same halving,
+128-lane window), and the backward is a kernel of its own that reads, beside
+the saved operands, what the differentiated forward wrote of each chunk and
+head: the incoming state (float32, 64 KB) and what the forward solved, (I +
+A)^-1, A / beta, P, W and U (72 KB), so that it makes no score, no level of
+the inverse and no product from it again (``kept_bytes`` of
+:func:`delta_rule_core`). The same halving,
 the same block recursion for the inverse, the same operand types: bf16-grade
 gradients, as the fused attention kernels', hence a bf16 tower only. The
 mixer's per-head statistics run in them too (:func:`normed_chunk_gated_delta_rule`,
@@ -69,6 +72,7 @@ tried there: fewer operations, 20 % slower, three times the generated code.)
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -210,7 +214,13 @@ def delta_rule_core(rows: int, tokens: int, heads: int, dk: int, dv: int, dtype,
     ``LatentAttention`` choose their fused kernels) or ``"chunked"``;
     ``qk_norm`` and ``o_norm`` are where the mixer's l2 norm of q and k and its
     head RMS norm of o run, which follow the core: ``"kernel"`` (on the head's
-    tile, inside ``kda_fwd`` / ``kda_bwd``) or ``"xla"``. The mixer runs what
+    tile, inside ``kda_fwd`` / ``kda_bwd``) or ``"xla"``; ``kept_bytes`` is
+    what a differentiated call of the mixer keeps from its forward to its
+    backward beside its operands and o: on the kernel path each chunk's
+    incoming state, what the forward solved of the chunk (T, A / beta, P, W,
+    U) and each row's 1 / rms, which ``kda_bwd`` reads where it would make
+    them again (``pallas_delta_rule.kept_for_backward``); 0 on the chunked
+    path, which keeps nothing and runs its forward again. The mixer runs what
     this says and the step's trace-time record
     (``train_step.stack_record_of``) reports it."""
     from distributed_sigmoid_loss_tpu.ops import flash_attention  # the towers' one question about the backend
@@ -220,8 +230,15 @@ def delta_rule_core(rows: int, tokens: int, heads: int, dk: int, dv: int, dtype,
         and dk == dv and dk % 128 == 0
     )
     norms = "kernel" if kernel else "xla"
+    chunks = -(-tokens // chunk)
+    kept = 0
+    if kernel:
+        from distributed_sigmoid_loss_tpu.ops.pallas_delta_rule import kept_for_backward
+
+        kept = sum(math.prod(x.shape) * x.dtype.itemsize
+                   for _, x in kept_for_backward(rows, chunks * chunk, heads, dk, dv, dtype, chunk, o_norm=True))
     return {"core": "kernel" if kernel else "chunked", "qk_norm": norms, "o_norm": norms,
-            "rows": rows, "heads": heads, "chunks": -(-tokens // chunk)}
+            "rows": rows, "heads": heads, "chunks": chunks, "kept_bytes": kept}
 
 
 def _power_of_two(chunk):

@@ -2,7 +2,8 @@
 equations) as two Pallas TPU kernels: a chunk of C tokens of a few heads lives in
 VMEM from its operands to its output, the state crosses chunks in VMEM scratch
 along a sequential axis of the grid, and HBM sees q, k, v, g, beta, o (and, for
-the backward, each chunk's incoming state) once.
+the backward, each chunk's incoming state and what the forward solved of the
+chunk) once.
 
 One program = one batch row, ``heads`` heads (a static loop over aligned lane
 windows of the (1, C, heads x d) blocks, as ``short_attn_fwd`` cuts its heads),
@@ -36,13 +37,30 @@ one chunk. The grid is (rows, head groups, chunks), chunks innermost and in orde
   (:func:`_rms`). Around the kernels these would be reductions over d of a
   (b, s, h, d) view of a (b, s, h x d) array, on a TPU a copy each way.
 
-The backward recomputes all of that from the saved operands and the chunk's
-incoming state, and is the gradient of the same function: products take
-operands of the tower's dtype where the forward's do, the inverse's cotangent
--T^T dT T^T is float32. G enters a chunk only through factors (row e^G) and
-(column e^-G), so its cotangent is accumulated from the scaled operands'
-(+ for a level's row tokens, - for its column tokens; the reference token's
-cancels) and g's is one reversed running sum of it. The l2 norms' cotangents
+The backward is the gradient of the same function, and solves nothing again.
+Where the call is differentiated the forward writes, beside each chunk's
+incoming state, what it solved of the chunk and head: T = (I + A)^-1 and A /
+beta in float32 (the inverse's cotangent -T^T dT T^T and beta's sum(dA A /
+beta) are made of them at full precision) and P in the tower's dtype (its cast
+is all the backward uses), a program's heads side by side on the lanes of one
+(C, heads x C) block each, and W and U in the tower's dtype where o lies ((b,
+s, h x d)): 72 KB a chunk-head beside the state's 64, alive as long as the
+states are (:func:`kept_for_backward`). The backward reads them and makes
+again only what is cheap and wide, from the saved operands: the running sums
+and the eight exponentials, the l2 norms, the scaled operands; of the
+forward's 24 products a head it repeats the running sums' three, so its own
+twenty-three start when their operands are loaded and not behind the levels'
+seven score products, the inverse's ten dependent ones and the three from T to
+W, U and o (PERF.md section 6, PR 40: the backward waited 7.1 of its 16.6 ms
+a call for them). The plain forward (nothing differentiated: inference)
+writes o alone and is the program it was. Under ``jax.checkpoint`` both of a
+layer's forwards are the differentiated one: the first one's blocks are
+written and never read (PERF.md section 7).
+Products take operands of the tower's dtype where the forward's do, the
+inverse's cotangent is float32. G enters a chunk only through factors (row
+e^G) and (column e^-G), so its cotangent is accumulated from the scaled
+operands' (+ for a level's row tokens, - for its column tokens; the reference
+token's cancels) and g's is one reversed running sum of it. The l2 norms' cotangents
 rs (d - n (n . d)) are applied to the float32 d_q and d_k before their final
 cast, so what is returned are the raw operands' cotangents. The head norm's
 backward comes first and waits for nothing the chunk recomputes: it reads the
@@ -65,7 +83,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import L2_EPS
 
-__all__ = ["delta_rule_kernel", "heads_per_program"]
+__all__ = ["delta_rule_kernel", "heads_per_program", "kept_for_backward"]
 
 F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST
@@ -137,15 +155,21 @@ def _level_exponents(run):
     return out
 
 
-def _scores(q, k, g, ops, level):
-    """One head's chunk up to the matrix to invert. q, k (C, dk) in the tower's
-    dtype, g (C, dk) float32. Returns exp(G), exp(G_C - G) (C, dk), the levels'
-    decays, all <= 1, A / beta and P (C, C) float32."""
-    chunk = q.shape[0]
-    dt = q.dtype
+def _decays(g, ops):
+    """One head's decays from g (C, dk) float32: exp(G), exp(G_C - G) (C, dk) and
+    the levels' (:func:`_level_exponents`), all <= 1."""
+    chunk = g.shape[0]
     sums = _dot01(ops, g)  # G and G_C - G
     eg, ee = jnp.exp(sums[:chunk]), jnp.exp(sums[chunk:])
-    e_levels = [jnp.exp(x) for x in _level_exponents(sums[:chunk])]
+    return eg, ee, [jnp.exp(x) for x in _level_exponents(sums[:chunk])]
+
+
+def _scores(q, k, e_levels, level):
+    """One head's chunk up to the matrix to invert. q, k (C, dk) in the tower's
+    dtype, the levels' decays of :func:`_decays`. Returns A / beta and P (C, C)
+    float32."""
+    chunk = q.shape[0]
+    dt = q.dtype
     qf, kf = q.astype(F32), k.astype(F32)
     a0 = jnp.zeros((chunk, chunk), F32)
     p = jnp.zeros((chunk, chunk), F32)
@@ -155,7 +179,7 @@ def _scores(q, k, g, ops, level):
         a0 = jnp.where(level == lv, x[:chunk], a0)
         p = jnp.where(level == lv, x[chunk:], p)
     p = jnp.where(level == len(e_levels), _dot(q, k, 1, 1), p)  # q_t . k_t: a token reads its own write
-    return (eg, ee, e_levels), a0, p
+    return a0, p
 
 
 def _inverses(a_of, level):
@@ -197,6 +221,18 @@ def _outputs(q, k, v, beta, z, e, p, t_inv):
     z_next = z * end_decay + _dot(ub, ke, 0, 0)  # (dv, dk)
     return dict(qf=qf, kf=kf, eg=eg, ee=ee, kb=kb, vb=vb, tb=tb, wb=wb, zb=zb, qg=qg, ub=ub,
                 pb=pb, ke=ke, end_decay=end_decay, out=out, z_next=z_next)
+
+
+def _outputs_kept(q, k, v, beta, z, e, t_inv, pb, wb, ub):
+    """What the backward takes of :func:`_outputs`, from the T (float32), P, W
+    and U (the tower's dtype) the forward kept: the operands scaled again, no
+    product."""
+    chunk, dt = q.shape[0], q.dtype
+    qf, kf = q.astype(F32), k.astype(F32)
+    eg, ee, _ = e
+    return dict(qf=qf, kf=kf, eg=eg, ee=ee, kb=(beta * kf * eg).astype(dt), vb=(beta * v.astype(F32)).astype(dt),
+                tb=t_inv.astype(dt), wb=wb, zb=z.astype(dt), qg=(qf * eg).astype(dt), ub=ub, pb=pb,
+                ke=(kf * ee).astype(dt), end_decay=eg[chunk - 1:])
 
 
 def _cotangents_to_inverse(f, z, d_out, dz_next):
@@ -294,32 +330,29 @@ def _rms(out, eps):
     return out * r, r
 
 
-def _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads, qk_norm):
+def _loaded(q_ref, k_ref, v_ref, g_ref, beta_ref, heads, qk_norm):
     """What both kernels start with, per head of the program: its operands (q,
     k, v, g, beta (C, 1): aligned lane windows of the blocks; q and k
-    normalised here where they arrive raw, ``qk_norm``), its scores, and the
-    inverses, the heads side by side."""
+    normalised here where they arrive raw, ``qk_norm``)."""
     dk, dv = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
 
     def rows(ref, j, scale):
         x = ref[0, :, j * dk:(j + 1) * dk]
         return _l2norm(x, scale)[0] if qk_norm else x
 
-    operands = [
+    return [
         (rows(q_ref, j, dk**-0.5), rows(k_ref, j, 1.0), v_ref[0, :, j * dv:(j + 1) * dv],
          g_ref[0, :, j * dk:(j + 1) * dk], beta_ref[0, 0, :, j:j + 1])
         for j in range(heads)
     ]
-    scores = [_scores(q, k, g, ops, level) for q, k, _, g, _ in operands]
-    inverses = _inverses([a0 * beta for (_, a0, _), (*_, beta) in zip(scores, operands)], level)
-    return operands, scores, inverses
 
 
 def _fwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
                 heads, save_states, qk_norm, o_eps):
-    # the differentiated forward also writes each chunk's incoming state and, with the head norm, each row's 1 / rms
+    # the differentiated forward also writes each chunk's incoming state, what it solved (T, A / beta, P, W, U) and,
+    # with the head norm, each row's 1 / rms
     *saved, z_ref = rest
-    states_ref, r_ref = (*saved, None, None)[:2]
+    states_ref, t_ref, a_ref, p_ref, w_ref, u_ref, r_ref = (*saved, *[None] * 7)[:7]
     dk, dv = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
 
     @pl.when(pl.program_id(2) == 0)
@@ -327,14 +360,24 @@ def _fwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
         z_ref[...] = jnp.zeros_like(z_ref)
 
     ops, level = ops_ref[...], level_ref[...]
-    operands, scores, inverses = _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads, qk_norm)
-    lane = lax.broadcasted_iota(jnp.int32, (level.shape[0], heads), 1)
+    chunk = level.shape[0]
+    operands = _loaded(q_ref, k_ref, v_ref, g_ref, beta_ref, heads, qk_norm)
+    decays, scores = [], []
+    for q, k, _, g, _ in operands:  # a head's decays, then its scores, head by head
+        decays.append(_decays(g, ops))
+        scores.append(_scores(q, k, decays[-1][2], level))
+    inverses = _inverses([a0 * beta for (a0, _), (*_, beta) in zip(scores, operands)], level)
+    lane = lax.broadcasted_iota(jnp.int32, (chunk, heads), 1)
     rs = jnp.zeros(lane.shape, F32)
-    for j, ((q, k, v, _, beta), (e, _, p), t_inv) in enumerate(zip(operands, scores, inverses)):
+    for j, ((q, k, v, _, beta), e, (a0, p), t_inv) in enumerate(zip(operands, decays, scores, inverses)):
         z = z_ref[j]
+        f = _outputs(q, k, v, beta, z, e, p, t_inv)
         if save_states:
             states_ref[0, 0, :, j * dk:(j + 1) * dk] = z
-        f = _outputs(q, k, v, beta, z, e, p, t_inv)
+            for ref, x in ((t_ref, t_inv), (a_ref, a0), (p_ref, f["pb"])):
+                ref[0, 0, 0, :, j * chunk:(j + 1) * chunk] = x
+            w_ref[0, :, j * dk:(j + 1) * dk] = f["wb"]
+            u_ref[0, :, j * dv:(j + 1) * dv] = f["ub"]
         out = f["out"]
         if o_eps is not None:
             out, r = _rms(out, o_eps)
@@ -345,10 +388,10 @@ def _fwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
         r_ref[0, 0] = rs
 
 
-def _bwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref, *rest,
-                heads, qk_norm, o_norm):
-    # with the head norm: the normalised output the forward stored and its saved 1 / rms
-    unit_ref, r_ref = rest[:2] if o_norm else (None, None)
+def _bwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, t_ref, a_ref, p_ref, w_ref, u_ref,
+                *rest, heads, qk_norm, o_norm):
+    # with the head norm: its saved 1 / rms, last of what the forward kept, and after do the normalised output it stored
+    r_ref, do_ref, unit_ref = rest[:3] if o_norm else (None, rest[0], None)
     dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dz_ref = rest[-6:]
     dk, dv = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
 
@@ -358,11 +401,19 @@ def _bwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, states
 
     ops, level = ops_ref[...], level_ref[...]
     chunk = level.shape[0]
-    operands, scores, inverses = _to_inverses(q_ref, k_ref, v_ref, g_ref, beta_ref, ops, level, heads, qk_norm)
-    forwards, partials = [], []
-    for j, ((q, k, v, _, beta), (e, _, p), t_inv) in enumerate(zip(operands, scores, inverses)):
+    operands = _loaded(q_ref, k_ref, v_ref, g_ref, beta_ref, heads, qk_norm)
+
+    def solved(ref, j):  # head j's (C, C) window of a block the forward kept
+        return ref[0, 0, 0, :, j * chunk:(j + 1) * chunk]
+
+    # what the forward solved, read back: no score, no level of the inverse, neither W nor U is made again here
+    inverses = [solved(t_ref, j) for j in range(heads)]
+    decays, forwards, partials = [], [], []
+    for j, ((q, k, v, g, beta), t_inv) in enumerate(zip(operands, inverses)):
         z = states_ref[0, 0, :, j * dk:(j + 1) * dk]
-        f = _outputs(q, k, v, beta, z, e, p, t_inv)
+        e = _decays(g, ops)
+        f = _outputs_kept(q, k, v, beta, z, e, t_inv, solved(p_ref, j),
+                          w_ref[0, :, j * dk:(j + 1) * dk], u_ref[0, :, j * dv:(j + 1) * dv])
         d_out = do_ref[0, :, j * dv:(j + 1) * dv]
         if o_norm:  # the head norm's backward, from what the forward left: nothing here waits for the chunk's recomputation
             unit, d_unit = unit_ref[0, :, j * dv:(j + 1) * dv].astype(F32), d_out.astype(F32)
@@ -370,6 +421,7 @@ def _bwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, states
             d_out = d_out.astype(do_ref.dtype)
         c = _cotangents_to_inverse(f, z, d_out, dz_ref[j])
         dz_ref[j] = c["dz"]
+        decays.append(e)
         forwards.append(f)
         partials.append(c)
     # T = (I + A)^-1: dA = -T^T dT T^T on the strict lower triangle, the heads side by side
@@ -378,8 +430,8 @@ def _bwd_kernel(ops_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, states
             for t, x in zip(inverses, right)]
     lane = lax.broadcasted_iota(jnp.int32, (chunk, heads), 1)
     d_betas = jnp.zeros((chunk, heads), F32)
-    for j, ((q, k, v, _, beta), (e, a0, _), f, c, d_a) in enumerate(zip(operands, scores, forwards, partials, d_as)):
-        d_q, d_k, d_v, d_g, d_beta = _cotangents_of_operands(q, k, v, beta, e, a0, f, c, d_a, ops, level)
+    for j, ((q, k, v, _, beta), e, f, c, d_a) in enumerate(zip(operands, decays, forwards, partials, d_as)):
+        d_q, d_k, d_v, d_g, d_beta = _cotangents_of_operands(q, k, v, beta, e, solved(a_ref, j), f, c, d_a, ops, level)
         lk, lv = slice(j * dk, (j + 1) * dk), slice(j * dv, (j + 1) * dv)
         if qk_norm:  # the cotangents of the raw branches, before the final cast
             d_q = _l2norm_cotangent(q_ref[0, :, lk], d_q, dk**-0.5)
@@ -401,7 +453,9 @@ def _call(kernel, name, operands, outs, *, b, s, h, dk, dv, chunk, heads, backwa
     """One of the two kernels over the grid (rows, head groups, chunks), the
     backward's chunks last to first. ``operands`` are (kind, array) and ``outs``
     (kind, shape): "token" (b, s, h x d), "beta" (b, h / heads, s, heads),
-    "state" (b, chunks, dv, h x dk)."""
+    "state" (b, chunks, dv, h x dk), "solved" (b, chunks, h / heads, C, heads x
+    C): a chunk's (C, C) matrices, a program's heads side by side on the lanes
+    (a block is the array's whole last two dimensions, whatever ``heads``)."""
     n = s // chunk
 
     def at(c):
@@ -412,6 +466,7 @@ def _call(kernel, name, operands, outs, *, b, s, h, dk, dv, chunk, heads, backwa
             "token": ((1, chunk, shape[-1] // h * heads), lambda r, hg, c: (r, at(c), hg)),
             "beta": ((1, 1, chunk, heads), lambda r, hg, c: (r, hg, at(c), 0)),
             "state": ((1, 1, dv, heads * dk), lambda r, hg, c: (r, at(c), 0, hg)),
+            "solved": ((1, 1, 1, chunk, heads * chunk), lambda r, hg, c: (r, at(c), hg, 0, 0)),
         }[kind]
         return pl.BlockSpec(block, index, memory_space=pltpu.VMEM)
 
@@ -446,11 +501,11 @@ def _chunk_flops(chunk, dk, dv, backward):
     scores = (levels + 1) * 2 * cc * dk
     inverse = (levels - 1) * 2 * 6 * cc * chunk
     rest = 2 * cc * cd + 2 * chunk * dd + cc * dv + chunk * dd
-    forward = 2 * (sums + scores + inverse + rest)
     if not backward:
-        return forward
-    return forward + 2 * (5 * chunk * dd + 2 * cc * dv + 4 * cc * cd + 2 * 6 * cc * chunk
-                          + levels * 4 * cc * dk + 6 * cc * dk)
+        return 2 * (sums + scores + inverse + rest)
+    # the running sums again and its own products; what the forward solved is read, o and the next state are not made
+    return 2 * sums + 2 * (
+        5 * chunk * dd + 2 * cc * dv + 4 * cc * cd + 2 * 6 * cc * chunk + levels * 4 * cc * dk + 6 * cc * dk)
 
 
 def _operands(q, k, v, g, beta, heads):
@@ -462,17 +517,32 @@ def _sizes(q, v, beta):
     return dict(b=b, s=s, h=h, dk=q.shape[-1] // h, dv=v.shape[-1] // h, heads=heads)
 
 
+def kept_for_backward(b, s, h, dk, dv, dtype, chunk, o_norm):
+    """What a differentiated call keeps from its forward to its backward beside
+    its operands and o, as ``_call``'s (kind, shape) in the order both kernels
+    take them: each chunk's incoming state (float32); of what the forward
+    solved a chunk and head, T = (I + A)^-1 and A / beta (float32: the
+    inverse's cotangent and beta's are made of them at full precision), P, W
+    and U (``dtype``, the tower's: their casts are all that is used); and with
+    the head norm each row's 1 / rms. ``s`` a multiple of ``chunk``."""
+    heads, n = heads_per_program(h), s // chunk
+    solved = (b, n, h // heads, chunk, heads * chunk)
+    kept = [("state", jax.ShapeDtypeStruct((b, n, dv, h * dk), F32))]
+    kept += [("solved", jax.ShapeDtypeStruct(solved, x)) for x in (F32, F32, dtype)]
+    kept += [("token", jax.ShapeDtypeStruct((b, s, h * d), dtype)) for d in (dk, dv)]
+    if o_norm:
+        kept.append(("beta", jax.ShapeDtypeStruct((b, h // heads, s, heads), F32)))
+    return kept
+
+
 def _forward(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps, save_states):
     sizes = _sizes(q, v, beta)
-    b, s, h, dk, dv, heads = (sizes[x] for x in ("b", "s", "h", "dk", "dv", "heads"))
     outs = [("token", jax.ShapeDtypeStruct(v.shape, v.dtype))]
     if save_states:
-        outs.append(("state", jax.ShapeDtypeStruct((b, s // chunk, dv, h * dk), F32)))
-        if o_eps is not None:
-            outs.append(("beta", jax.ShapeDtypeStruct((b, h // heads, s, heads), F32)))
+        outs += kept_for_backward(*(sizes[x] for x in ("b", "s", "h", "dk", "dv")), v.dtype, chunk, o_eps is not None)
     out, *saved = _call(
-        functools.partial(_fwd_kernel, heads=heads, save_states=save_states, qk_norm=qk_norm, o_eps=o_eps),
-        "kda_fwd", _operands(q, k, v, g, beta, heads), outs,
+        functools.partial(_fwd_kernel, heads=sizes["heads"], save_states=save_states, qk_norm=qk_norm, o_eps=o_eps),
+        "kda_fwd", _operands(q, k, v, g, beta, sizes["heads"]), outs,
         **sizes, chunk=chunk, backward=False, interpret=interpret)
     return out, saved
 
@@ -483,22 +553,23 @@ def _wide_kernel(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps):
 
 
 def _vjp_fwd(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps):
-    out, (states, *r) = _forward(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps, True)
-    return out, (q, k, v, g, beta, states, *((out, *r) if o_eps is not None else ()))
+    out, kept = _forward(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps, True)
+    return out, ((q, k, v, g, beta), kept, (out,) if o_eps is not None else ())
 
 
 def _vjp_bwd(chunk, interpret, qk_norm, o_eps, residuals, d_out):
-    q, k, v, g, beta, states, *normed = residuals  # with the head norm: the normalised output and 1 / rms
+    (q, k, v, g, beta), kept, normed = residuals  # with the head norm: the normalised output too
     sizes = _sizes(q, v, beta)
     b, s, h, heads = (sizes[x] for x in ("b", "s", "h", "heads"))
+    kinds = [kind for kind, _ in kept_for_backward(b, s, h, sizes["dk"], sizes["dv"], v.dtype, chunk, o_eps is not None)]
 
     def like(x, dtype=None):
         return ("token", jax.ShapeDtypeStruct(x.shape, dtype or x.dtype))
 
     d_q, d_k, d_v, d_g, d_beta = _call(
         functools.partial(_bwd_kernel, heads=heads, qk_norm=qk_norm, o_norm=o_eps is not None), "kda_bwd",
-        [*_operands(q, k, v, g, beta, heads), ("state", states), ("token", d_out.astype(v.dtype)),
-         *zip(("token", "beta"), normed)],
+        [*_operands(q, k, v, g, beta, heads), *zip(kinds, kept), ("token", d_out.astype(v.dtype)),
+         *(("token", x) for x in normed)],
         [like(q), like(k), like(v), like(g, F32), ("beta", jax.ShapeDtypeStruct((b, h // heads, s, heads), F32))],
         **sizes, chunk=chunk, backward=True, interpret=interpret)
     d_beta = jnp.transpose(d_beta, (0, 2, 1, 3)).reshape(beta.shape)
@@ -521,11 +592,16 @@ def delta_rule_kernel(q, k, v, g, beta, chunk: int = 64, interpret: bool = False
     its scale), taken on the float32 tile before it is stored. The per-head form
     (b, s, h, d) is taken too and returned (free views around the same call).
     Differentiated, it saves its operands as they came, each chunk's incoming
-    state (float32, b x s / chunk x h x dk x dv: under a rematerialised layer
-    they live from the layer's second forward to its backward) and, with
-    ``o_eps``, what it returned and each row's 1 / rms (float32, b x s x h), so
-    that the norm's backward waits for nothing the chunk recomputes.
-    ``interpret=True`` runs the Pallas interpreter (CPU testing)."""
+    state (float32, b x s / chunk x h x dk x dv), what the forward solved of
+    each chunk and head, T = (I + A)^-1 and A / beta (float32) and P (v's
+    dtype), chunk x chunk each, and W and U (v's dtype, o's shape), so that the
+    backward starts at its own products and not at the scores and the
+    inverse's five levels (under a rematerialised layer all of these live from
+    the layer's second forward to its backward: :func:`kept_for_backward`
+    lists them, ``delta_rule_core`` reports their bytes), and, with ``o_eps``,
+    what it returned and each row's 1 / rms (float32, b x s x h), so that the
+    norm's backward waits for nothing the chunk recomputes. ``interpret=True``
+    runs the Pallas interpreter (CPU testing)."""
     if q.ndim == 3:
         return _wide_kernel(q, k, v, g, beta, chunk, interpret, qk_norm, o_eps)
     b, s, h, _ = q.shape

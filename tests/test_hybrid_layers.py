@@ -182,7 +182,8 @@ def test_a_sequence_that_is_no_multiple_of_the_chunk_through_the_kernels(monkeyp
     args = delta_rule_inputs(70, jnp.bfloat16, b=1, h=1, dk=128, dv=128)
     weight = ripple(args[2].shape)
     assert delta_rule_core(1, 70, 1, 128, 128, jnp.bfloat16) == {
-        "core": "kernel", "qk_norm": "kernel", "o_norm": "kernel", "rows": 1, "heads": 1, "chunks": 2}
+        "core": "kernel", "qk_norm": "kernel", "o_norm": "kernel", "rows": 1, "heads": 1, "chunks": 2,
+        "kept_bytes": 2 * (128 * 128 * 4 + 64 * 64 * (4 + 4 + 2) + 2 * 64 * 128 * 2 + 64 * 4)}  # a chunk: the state; T, A / beta, P; W, U; 1 / rms
     got_out, got = value_and_grads(lambda *a: chunk_gated_delta_rule(*a).astype(jnp.float32), args, weight)
     want_out, want = value_and_grads(gated_delta_rule_recurrent, args, weight)
     out = chunk_gated_delta_rule(*args)
@@ -360,6 +361,155 @@ def test_the_kernels_sit_in_a_shard_map_under_a_jit_over_several_chips(monkeypat
     assert got_grads[0].sharding.spec == P("dp")
 
 
+# -- (a+) what the differentiated forward keeps for the backward (PERF.md section 6, PR 40) --------
+
+
+def dense_chunks(q, k, v, g, beta, chunk):
+    """A / beta, T = (I + A)^-1 and P of every chunk and head, (b, chunks, h, C, C), W = T (beta K e^G), (b, chunks,
+    h, C, dk), and of each sequence's first chunk (no state yet) U = T (beta V), from the equations of
+    ops/gated_delta_rule.py written out in float64 (decays as exp(G_t - G_i) outright: these g stay in range): what
+    ``_chunked`` holds quadrant by quadrant while it grows T and P, whole."""
+    q, k, v, g, beta = (np.asarray(x, np.float64) for x in (*(x.astype(jnp.float32) for x in (q, k, v)), g, beta))
+    b, s, h, dk = q.shape
+    n = s // chunk
+    q, k, v, g = (np.moveaxis(x.reshape(b, n, chunk, h, -1), 3, 2) for x in (q, k, v, g))  # (b, n, h, C, d)
+    beta = np.moveaxis(beta.reshape(b, n, chunk, h), 3, 2)[..., None]  # (b, n, h, C, 1)
+    run = np.cumsum(g, -2)
+    decay = np.exp(np.minimum(run[..., :, None, :] - run[..., None, :, :], 0.0))  # (…, t, i, dk); i <= t is all that is read
+    a0 = np.tril(np.einsum("...tc,...ic,...tic->...ti", k, k, decay), -1)
+    p = np.tril(np.einsum("...tc,...ic,...tic->...ti", q, k, decay))
+    t_inv = np.linalg.inv(np.eye(chunk) + beta * a0)
+    return a0, t_inv, p, t_inv @ (beta * k * np.exp(run)), (t_inv @ (beta * v))[:, 0]
+
+
+def by_head(x, heads):
+    """A kept block array (b, chunks, h / heads, C, heads x C) as (b, chunks, h, C, C)."""
+    b, n, groups, chunk, _ = x.shape
+    return jnp.moveaxis(x.reshape(b, n, groups, chunk, heads, chunk), 4, 3).reshape(b, n, groups * heads, chunk, chunk)
+
+
+# float32 operands show the arithmetic (the dispatcher hands the kernels bf16 only); in bf16 the levels' products take
+# operands rounded to 8 bits as ``_chunked``'s do, and P is kept as its cast, the backward's only use of it.
+@pytest.mark.parametrize("dtype, chunk, d, bound, p_bound", [(jnp.float32, 16, 32, 2e-5, 2e-5), (jnp.bfloat16, 64, 128, 1e-2, 1.5e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", [1, 2, 4, 8], ids=lambda h: f"{h}-heads")
+def test_the_differentiated_forward_keeps_what_it_solved(heads, dtype, chunk, d, bound, p_bound):
+    """Beside each chunk's incoming state the forward of a differentiated call writes the chunk's T = (I + A)^-1
+    and A / beta in float32 and P in the tower's dtype, a program's heads side by side on the lanes, and W and U
+    where o lies: the values the backward made again before PR 40, now read."""
+    from distributed_sigmoid_loss_tpu.ops import pallas_delta_rule
+
+    per = pallas_delta_rule.heads_per_program(heads)
+    q, k, v, g, beta = delta_rule_inputs(2 * chunk, dtype, b=2, h=heads, dk=d, dv=d)
+    g = jnp.maximum(g, -2.0)  # exp(G_t - G_i) outright stays a float64 over a chunk
+    wide = tuple(x.reshape(2, 2 * chunk, -1) for x in (q, k, v, g))
+    out, kept = pallas_delta_rule._vjp_fwd(*wide, beta, chunk, True, False, None)
+    states, t_inv, a0, pb, wb, ub = kept[1]
+    assert [x.shape for x in (t_inv, a0, pb)] == [(2, 2, heads // per, chunk, per * chunk)] * 3 and wb.shape == ub.shape == out.shape
+    assert [x.dtype for x in (states, t_inv, a0, pb, wb, ub)] == [jnp.float32] * 3 + [dtype] * 3
+    np.testing.assert_array_equal(out, pallas_delta_rule.delta_rule_kernel(*wide, beta, chunk=chunk, interpret=True))
+    np.testing.assert_array_equal(states[:, 0], 0.0)  # a sequence starts from nothing
+    want_a0, want_t, want_p, want_w, want_u0 = dense_chunks(q, k, v, g, beta, chunk)
+    wb, ub = (jnp.moveaxis(x.reshape(2, 2, chunk, heads, d), 3, 2) for x in (wb, ub))  # (b, n, h, C, d)
+    assert reference_kimi._base.max_rel_err(wb, want_w) < p_bound and reference_kimi._base.max_rel_err(ub[:, 0], want_u0) < p_bound
+    assert reference_kimi._base.max_rel_err(by_head(a0, per), want_a0) < bound
+    assert reference_kimi._base.max_rel_err(by_head(t_inv, per), want_t) < bound
+    assert reference_kimi._base.max_rel_err(by_head(pb, per), want_p) < p_bound
+    strict = np.tril(np.ones((chunk, chunk)), -1)
+    np.testing.assert_array_equal(by_head(a0, per) * (1 - strict), 0.0)  # nothing on or above the diagonal
+    np.testing.assert_array_equal(by_head(t_inv, per) * (1 - strict), jnp.broadcast_to(jnp.eye(chunk), (2, 2, heads, chunk, chunk)))
+
+
+# The gradients through the backward that reads T, A / beta and P, under the bounds of
+# test_the_kernels_are_the_recurrence_and_the_chunked_form, at 1, 2 and 4 heads a program, whole chunks and not.
+@pytest.mark.parametrize("heads, tokens", [(1, 128), (2, 64), (4, 128), (1, 70), (4, 70)],
+                         ids=["1-head", "2-heads", "4-heads", "1-head-padded", "4-heads-padded"])
+def test_the_backward_that_reads_what_the_forward_solved_is_the_recurrences_and_the_chunked_forms(monkeypatch, heads, tokens):
+    from distributed_sigmoid_loss_tpu.ops import gated_delta_rule
+
+    as_kernel_call(monkeypatch)
+    args = delta_rule_inputs(tokens, jnp.bfloat16, b=1, h=heads, dk=128, dv=128)
+    weight = ripple(args[2].shape)
+    pad = -tokens % 64
+    chunked = lambda *a: jax.checkpoint(partial(gated_delta_rule._chunked, chunk=64, dt=jnp.bfloat16))(  # noqa: E731
+        *(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in a))[:, :tokens].astype(jnp.bfloat16)
+    out, want_out, twin_out = (jax.jit(fn)(*args) for fn in (chunk_gated_delta_rule, gated_delta_rule_recurrent, chunked))
+    assert out.shape == args[2].shape and out.dtype == jnp.bfloat16
+    assert reference_kimi._base.max_rel_err(out, want_out) < 5e-2 and reference_kimi._base.max_rel_err(out, twin_out) < 5e-3
+    (_, got), (_, want), (_, twin) = (
+        value_and_grads(fn, args, weight) for fn in (chunk_gated_delta_rule, gated_delta_rule_recurrent, chunked))
+    for name, g, w, t in zip("q k v g beta".split(), got, want, twin):
+        assert g.dtype == w.dtype and g.shape == w.shape and bool(jnp.isfinite(g.astype(jnp.float32)).all()), name
+        assert reference_kimi._base.max_rel_err(g, w) < 5e-2, name
+        assert reference_kimi._base.max_rel_err(g, t) < 1.5e-2, name
+
+
+def inner_jaxprs(eqn):
+    """The jaxprs an equation carries: a jit's, a remat's or a custom rule's body, the branches of a ``pl.when``."""
+    for value in eqn.params.values():
+        for inner in value if isinstance(value, (tuple, list)) else (value,):
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def kernel_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, by the kernel's name."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = eqn
+        else:
+            for inner in inner_jaxprs(eqn):
+                found.update(kernel_calls(inner))
+    return found
+
+
+def products(jaxpr):
+    """The precision of every ``dot_general`` of a kernel's body."""
+    found = [eqn.params["precision"] for eqn in jaxpr.eqns if eqn.primitive.name == "dot_general"]
+    return found + [p for eqn in jaxpr.eqns for inner in inner_jaxprs(eqn) for p in products(inner)]
+
+
+def highest(precisions):
+    return sum(p is not None and jax.lax.Precision.HIGHEST in (p if isinstance(p, tuple) else (p,)) for p in precisions)
+
+
+@pytest.mark.parametrize("norms", [{}, {"qk_norm": True, "o_eps": O_EPS}], ids=["bare", "norms-inside"])
+def test_the_plain_forward_writes_o_and_nothing_else(norms):
+    """Nothing is kept where nothing is differentiated (the first forward under remat, inference): ``kda_fwd`` has
+    the one output it had before PR 40; differentiated, it has what ``kept_for_backward`` lists beside o."""
+    from distributed_sigmoid_loss_tpu.ops.pallas_delta_rule import kept_for_backward
+
+    q, k, v, g, beta = delta_rule_inputs(128, jnp.bfloat16, b=2, h=4, dk=128, dv=128)
+    args = tuple(x.reshape(2, 128, -1) for x in (q, k, v, g)) + (beta,)
+    rule = partial(kernel_rule, **norms)
+    plain = kernel_calls(jax.make_jaxpr(rule)(*args).jaxpr)
+    assert list(plain) == ["kda_fwd"] and [x.aval.shape for x in plain["kda_fwd"].outvars] == [(2, 128, 512)]
+    both = kernel_calls(jax.make_jaxpr(jax.grad(lambda *a: rule(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))(*args).jaxpr)
+    assert list(both) == ["kda_fwd", "kda_bwd"]
+    kept = [x for _, x in kept_for_backward(2, 128, 4, 128, 128, jnp.bfloat16, 64, o_norm=bool(norms))]
+    assert len(kept) == (7 if norms else 6)  # the state, T, A / beta, P, W, U, and 1 / rms
+    assert [(x.aval.shape, x.aval.dtype) for x in both["kda_fwd"].outvars[1:]] == [(x.shape, x.dtype) for x in kept]
+    # and the backward takes them all in: the operands' five, the kept blocks, do, and with the head norm o
+    assert len(both["kda_bwd"].invars) == 2 + 5 + len(kept) + 1 + bool(norms)
+
+
+@pytest.mark.parametrize("heads", [1, 2, 4], ids=lambda h: f"{h}-heads")
+def test_the_backward_solves_nothing_again(heads):
+    """A head of ``kda_bwd`` holds three products at the MXU's full precision, the inverse's cotangent pair
+    (-T^T dT T^T) and d_g's reversed running sum, and 23 others: the running sums' three and its own twenty. No level
+    of the inverse (ten at full precision a head in ``kda_fwd``), none of the levels' seven score products, neither W
+    and U nor o and the next state (47 products a head before PR 40)."""
+    q, k, v, g, beta = delta_rule_inputs(64, jnp.bfloat16, b=1, h=heads, dk=128, dv=128)
+    args = tuple(x.reshape(1, 64, -1) for x in (q, k, v, g)) + (beta,)
+    rule = partial(kernel_rule, qk_norm=True, o_eps=O_EPS)
+    calls = kernel_calls(jax.make_jaxpr(jax.grad(lambda *a: rule(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))(*args).jaxpr)
+    forward, backward = (products(calls[name].params["jaxpr"]) for name in ("kda_fwd", "kda_bwd"))
+    assert (len(forward), highest(forward)) == (24 * heads, 10 * heads)  # the search finds the inverse's levels where they are
+    assert (len(backward), highest(backward)) == (26 * heads, 3 * heads)
+
+
 # -- (a3) the layer around the kernels stays on (b, s, h x d) -----------------------------
 
 
@@ -402,11 +552,8 @@ def per_head_values(jaxpr, heads, d):
             continue
         found += [(eqn.primitive.name, v.aval.shape) for v in (*eqn.invars, *eqn.outvars)
                   if getattr(v.aval, "ndim", 0) == 4 and v.aval.shape[2:] == (heads, d)]
-        for value in eqn.params.values():  # a jit's, a remat's or a custom rule's body
-            for inner in value if isinstance(value, (tuple, list)) else (value,):
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    found += per_head_values(inner, heads, d)
+        for inner in inner_jaxprs(eqn):
+            found += per_head_values(inner, heads, d)
     return found
 
 
@@ -436,7 +583,8 @@ def test_which_core_a_call_takes_follows_from_dtype_backend_and_head_size(monkey
 
     monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: tpu)
     assert delta_rule_core(3, 100, 2, d, d, dtype) == {
-        "core": core, **dict.fromkeys(("qk_norm", "o_norm"), "kernel" if core == "kernel" else "xla"), "rows": 3, "heads": 2, "chunks": 2}
+        "core": core, **dict.fromkeys(("qk_norm", "o_norm"), "kernel" if core == "kernel" else "xla"), "rows": 3, "heads": 2, "chunks": 2,
+        "kept_bytes": 3 * 2 * 2 * (d * d * 4 + 64 * 64 * (4 + 4 + 2) + 2 * 64 * d * 2 + 64 * 4) if core == "kernel" else 0}
     taken = []
     monkeypatch.setattr(pallas_delta_rule, "delta_rule_kernel",
                         lambda q, *a, **kw: taken.append("kernel") or jnp.zeros_like(q))
@@ -460,14 +608,21 @@ def test_the_steps_record_names_the_core_by_the_same_rule(monkeypatch):
     t = TextConfig(width=256, depth=3, num_heads=2, mixers=("kda", "mla", "kda"), pos="none", dtype="bfloat16",
                    moe_experts=4, moe_router="sigmoid", kda_head_dim=128)
     assert stack_record_of(t, (16, 1024))["kda_core"] == {
-        i: {"core": "chunked", "qk_norm": "xla", "o_norm": "xla", "rows": 16, "heads": 2, "chunks": 16} for i in (0, 2)}  # this CPU
+        i: {"core": "chunked", "qk_norm": "xla", "o_norm": "xla", "rows": 16, "heads": 2, "chunks": 16, "kept_bytes": 0}
+        for i in (0, 2)}  # this CPU
     monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
     assert stack_record_of(t, (16, 1024))["kda_core"] == {
-        i: {"core": "kernel", "qk_norm": "kernel", "o_norm": "kernel", "rows": 16, "heads": 2, "chunks": 16} for i in (0, 2)}
+        i: {"core": "kernel", "qk_norm": "kernel", "o_norm": "kernel", "rows": 16, "heads": 2, "chunks": 16,
+            "kept_bytes": (536870912 + 335544320 + 268435456 + 2097152) // 16} for i in (0, 2)}
+    # the hybrid cell's call (16 rows x 1024 tokens x 32 heads of 128): 537 MB of states, 335 MB of T, A / beta and P,
+    # 268 MB of W and U, 2 MB of 1 / rms kept from a layer's second forward to its backward
+    cell = TextConfig(width=256, depth=1, num_heads=32, mixers=("kda",), pos="none", dtype="bfloat16",
+                      moe_experts=4, moe_router="sigmoid", kda_head_dim=128)
+    assert stack_record_of(cell, (16, 1024))["kda_core"][0]["kept_bytes"] == 536870912 + 335544320 + 268435456 + 2097152 == 1142947840
     float32 = TextConfig(width=256, depth=1, num_heads=2, mixers=("kda",), pos="none", dtype="float32",
                          moe_experts=4, moe_router="sigmoid")
     assert stack_record_of(float32, (3, 100))["kda_core"] == {
-        0: {"core": "chunked", "qk_norm": "xla", "o_norm": "xla", "rows": 3, "heads": 2, "chunks": 2}}
+        0: {"core": "chunked", "qk_norm": "xla", "o_norm": "xla", "rows": 3, "heads": 2, "chunks": 2, "kept_bytes": 0}}
     assert "kda_core" not in stack_record_of(TextConfig(depth=2, moe_experts=4, moe_router="sigmoid"), (4, 8))
 
 

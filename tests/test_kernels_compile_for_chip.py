@@ -61,25 +61,39 @@ def test_the_delta_rule_kernels_compile_for_a_v5e(one_chip, heads, tokens):
 MOSAIC_VMEM_LIMIT = 16 * 2**20  # what a Mosaic kernel may use on a v5e unless it asks for more (these do not)
 
 
+def kernel_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, through the bodies of jits and custom rules."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+            continue
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from kernel_calls(inner)
+
+
 def vmem_asked(jaxpr) -> list:
     """Per ``pallas_call`` of a jaxpr (name, bytes): every block twice (the
     pipeline holds the next one) and the scratch."""
     found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            mapping = eqn.params["grid_mapping"]
-            blocks = sum(
-                math.prod(getattr(x, "block_size", None) or 1 for x in m.block_shape) * m.array_aval.dtype.itemsize
-                for m in mapping.block_mappings)
-            scratch = eqn.params["jaxpr"].invars[len(eqn.params["jaxpr"].invars) - mapping.num_scratch_operands:]
-            found.append((eqn.params["name"], 2 * blocks + sum(
-                math.prod(v.aval.shape) * v.aval.dtype.itemsize for v in scratch)))
-            continue
-        for value in eqn.params.values():  # a jit's or a custom rule's body
-            inner = getattr(value, "jaxpr", value)
-            if hasattr(inner, "eqns"):
-                found += vmem_asked(inner)
+    for eqn in kernel_calls(jaxpr):
+        mapping = eqn.params["grid_mapping"]
+        blocks = sum(
+            math.prod(getattr(x, "block_size", None) or 1 for x in m.block_shape) * m.array_aval.dtype.itemsize
+            for m in mapping.block_mappings)
+        scratch = eqn.params["jaxpr"].invars[len(eqn.params["jaxpr"].invars) - mapping.num_scratch_operands:]
+        found.append((eqn.params["name"], 2 * blocks + sum(
+            math.prod(v.aval.shape) * v.aval.dtype.itemsize for v in scratch)))
     return found
+
+
+def block_shapes(jaxpr) -> dict:
+    """Per ``pallas_call`` of a jaxpr, by name: the shapes of its blocks."""
+    return {
+        eqn.params["name"]: [tuple(getattr(x, "block_size", None) or 1 for x in m.block_shape)
+                             for m in eqn.params["grid_mapping"].block_mappings]
+        for eqn in kernel_calls(jaxpr)}
 
 
 # The mixer's call at the cell's shape (two rows of it): raw q and k, o over its head's rms, the
@@ -107,8 +121,16 @@ def test_the_kernels_with_the_norms_inside_compile_for_a_v5e(one_chip, heads, to
     both = jax.jit(grads).lower(*args).compile()
     assert both.as_text().count("tpu_custom_call") == 2  # kda_fwd writing the states, kda_bwd
     assert [x.shape for x in both.out_info] == [a.shape for a in args]
-    asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
+    traced = jax.make_jaxpr(grads)(*args).jaxpr
+    asked = dict(vmem_asked(traced))
     assert set(asked) == {"kda_fwd", "kda_bwd"} and max(asked.values()) < MOSAIC_VMEM_LIMIT // 2, asked
+    # what the forward solved and keeps for the backward (T, A / beta, P: a program's heads side by side on the lanes)
+    # is among the blocks counted: written by the one kernel, read by the other, two copies of each
+    per = heads_per_program(heads)
+    solved = 2 * 64 * per * 64 * (4 + 4 + 2)
+    for name, blocks in block_shapes(traced).items():
+        assert blocks.count((1, 1, 1, 64, per * 64)) == 3, (name, blocks)
+        assert asked[name] > solved
 
 
 # Latent attention's core as the mixer calls it, the heads on the lanes: the GLM cell's (20 heads of 256 / 256 at 4096
