@@ -634,6 +634,7 @@ TOWER_EXCLUSIONS: tuple = (
 PP_REFUSES: tuple = (
     "mixers", "leading_dense_layers", "norm_eps", "moe_router", "moe_route_scale",
     "moe_shared_experts", "moe_hidden", "moe_experts_held", "mla_q_rank", "norm_unit_offset",
+    "moe_shared_hidden", "num_kv_heads", "head_dim", "sublayers",
 )
 
 
@@ -674,7 +675,8 @@ def tower_exclusion_drift() -> list[str]:
             drift.append(f"{option} with {excluded} is not refused by {named!r} ({source}): {both}")
     if set(PP_REFUSES) - set(BLOCK_OPTIONS):
         drift.append(f"PP_REFUSES names no block option: {sorted(set(PP_REFUSES) - set(BLOCK_OPTIONS))}")
-    changed = {"mixers": ("kda", "mla"), "moe_router": "sigmoid", "norm_eps": 1e-5, "moe_route_scale": 2.5}
+    changed = {"mixers": ("kda", "mla"), "moe_router": "sigmoid", "norm_eps": 1e-5, "moe_route_scale": 2.5,
+               "sublayers": "single"}
     for name in PP_REFUSES:
         cfg = dc.replace(TextConfig.tiny_test(), scan_layers=True, **{name: changed.get(name, 1)})
         try:

@@ -1,10 +1,11 @@
 """Token mixers of a stack given layer by layer (``TextConfig.mixers``), beside
 the block's softmax ``Attention`` (models/transformer.py): a gated delta-rule
-layer ("kda"), latent attention ("mla") and windowed chunk attention ("eva").
-Imported only where a configuration names one. All are causal and carry no
-bias; their statistics, gates and decays are float32 whatever the tower's dtype.
-The recurrence takes no position encoding; latent attention none, or a rotation
-of its shared-width parts; windowed chunk attention rotates whole heads.
+layer ("kda"), latent attention ("mla"), windowed chunk attention ("eva") and a
+Mamba-2 state-space layer ("ssm"). Imported only where a configuration names
+one. All are causal and carry no bias (but the state-space layer's convolution);
+their statistics, gates and decays are float32 whatever the tower's dtype. The
+recurrences take no position encoding; latent attention none, or a rotation of
+its shared-width parts; windowed chunk attention rotates whole heads.
 
 Latent attention's core (scores, causal softmax, values; scope ``mla_core``) is
 one of three, by :func:`latent_attention_core`, from what the call can see:
@@ -43,6 +44,17 @@ With x the (s, width) normalised stream of one sequence:
           o_t  = (sum_j e^s_tj v_j + sum_c e^r_tc vc_c) / (sum_j e^s_tj + sum_c e^r_tc)   # scope eva_core: one softmax
           out  = concat_h(o_t) Wo
 
+    SSM   [z | xBC | dt] = x W_in                                           # width -> h P + (h P + 2 g N) + h
+          xBC = silu(conv(xBC) + b_conv) ;  [x' | B | C] = xBC               # conv: causal depthwise, per channel; scope ssm_conv
+          dt = softplus(dt + dt_bias) ;  A = -exp(A_log)                     # (h,), float32
+          S_t = exp(dt_t A_h) S_{t-1} + dt_t x'_t[h] (x) B_t[g] ;  y_t[h] = S_t C_t[g] + D_h x'_t[h]   # scope ssm_core, g = h // (h / groups)
+          y = y silu(z) ;  y = y rsqrt(mean over each group's h P / g lanes (y^2) + eps) w   # gated RMSNorm, the gate first
+          out = y W_out
+
+The state-space recurrence runs as ``ops/ssm.py ssm_scan`` has it (one form,
+chunked in XLA; :func:`~distributed_sigmoid_loss_tpu.ops.ssm.ssm_core` is the
+record of its sizes); x', B, C and y stay (b, s, h x P) / (b, s, g x N) around it.
+
 Windowed chunk attention's core is one of two, by :func:`eva_attention_core`:
 ``"kernel"``, the Pallas pair ``eva_attn_fwd`` / ``eva_attn_bwd``
 (``ops/pallas_eva_attention.py``) on (b, s, h x d), or ``"dense"``, XLA on a
@@ -77,6 +89,8 @@ MLA_CORE_SCOPE = "mla_core"  # scores, softmax and values, inside "mla"
 MLA_ROPE_SCOPE = "mla_rope"  # the rotation of the queries' and the key's shared-width parts, inside "mla"
 EVA_SUMMARY_SCOPE = "eva_summary"  # the pooling of keys and values into chunk summaries, inside "eva"
 EVA_CORE_SCOPE = "eva_core"  # both score sets, the one softmax and the values, inside "eva"
+SSM_CONV_SCOPE = "ssm_conv"  # the convolution, its bias and the silu, inside "ssm"
+SSM_CORE_SCOPE = "ssm_core"  # the recurrence alone (from x', B, C, dt, A, D to y), inside "ssm"
 # Tokens a chunk of the delta rule: what one program of the kernels (ops/pallas_delta_rule.py)
 # holds in VMEM per head, six halving levels and a 64 x 64 float32 inverse; the XLA form's too.
 CHUNK = 64
@@ -446,3 +460,90 @@ class EvaAttention(nn.Module):
             else:
                 out = eva_core_dense(q, k, v, kc, vc, heads=h, window=self.window, scale=d**-0.5)
         return dense(self.width, name="out")(out.astype(self.dtype))
+
+
+class CutDense(nn.Module):
+    """A bias-free ``nn.Dense`` whose output comes cut: one ``kernel`` (fan_in,
+    sum(cuts)) under the module's name, as ``nn.Dense`` would hold it, and one
+    product a cut with that cut's COLUMNS of it. The outputs are what slices of
+    the fused product would be, without an activation-sized slice forward or an
+    activation-sized join backward (the kernel's gradient is joined instead)."""
+
+    cuts: tuple
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.xavier_uniform(), (x.shape[-1], sum(self.cuts)), F32)
+        x, kernel = x.astype(self.dtype), kernel.astype(self.dtype)
+        edges = np.cumsum((0, *self.cuts))
+        return tuple(jnp.dot(x, kernel[:, a:b]) for a, b in zip(edges[:-1], edges[1:]))
+
+
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """The state-space layer's gated RMSNorm, in float32: the gate first, y
+    silu(z), then each of the ``groups`` groups of lanes over its own root mean
+    square, times ``scale``. y, z: (b, s, inner); scale: (inner,)."""
+    b, s, inner = y.shape
+    y = (y.astype(F32) * nn.silu(z.astype(F32))).reshape(b, s, groups, inner // groups)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + eps)
+    return y.reshape(b, s, inner) * scale
+
+
+class SsmMixer(nn.Module):
+    """A Mamba-2 state-space layer (the module docstring has the equations):
+    ``num_heads`` heads of ``head_dim`` channels in ``groups`` groups that share B
+    and C of ``state`` channels, one fused input projection cut into the gate z,
+    the convolved [x' | B | C] and dt, a causal depthwise convolution of
+    ``conv_size`` taps with a bias, the recurrence (``ops/ssm.py``), a gated
+    RMSNorm over each group's lanes, the output projection. ``A_log``,
+    ``dt_bias`` and ``D`` are (num_heads,) float32 leaves, initialised as
+    Mamba-2's (a rate uniform in [1, 16), a step log-uniform in [1e-3, 1e-1],
+    ones); the step is not clamped above."""
+
+    width: int
+    num_heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv_size: int
+    chunk: int
+    dtype: Any
+    norm_eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        from distributed_sigmoid_loss_tpu.ops.ssm import ssm_scan
+
+        b, s, _ = x.shape
+        h, g = self.num_heads, self.groups
+        inner, shared = h * self.head_dim, g * self.state
+        if h % g or inner % g:
+            raise ValueError(f"ssm_groups={g} does not divide ssm_num_heads={h}")
+        dense = partial(
+            nn.Dense, use_bias=False, dtype=self.dtype,
+            kernel_init=nn.initializers.xavier_uniform(),
+        )
+        bound = self.conv_size**-0.5
+
+        def conv_init(key, shape, dtype=F32):
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+        # One fused input projection cut five ways, and one convolution over [x | B | C], cut in the WEIGHTS' columns
+        # (as ``_recut`` does it): z, x, B, C and dt are born apart, and no activation is sliced or, backwards, joined.
+        z, xs, big_b, big_c, dt = CutDense((inner, inner, shared, shared, h), self.dtype, name="in_proj")(x)
+        with jax.named_scope(SSM_CONV_SCOPE):
+            taps = self.param("conv", conv_init, (self.conv_size, inner + 2 * shared), F32)
+            conv_bias = self.param("conv_bias", conv_init, (inner + 2 * shared,), F32)
+            edges = (0, inner, inner + shared, inner + 2 * shared)
+            xs, big_b, big_c = (
+                nn.silu(short_causal_conv(part, taps[:, a:b]) + conv_bias[a:b].astype(part.dtype))
+                for part, a, b in zip((xs, big_b, big_c), edges, edges[1:])
+            )
+        rate = -jnp.exp(self.param("A_log", _decay_rate_init, (h,), F32))
+        dt = jax.nn.softplus(dt.astype(F32) + self.param("dt_bias", _dt_bias_init, (h,), F32))
+        skip = self.param("D", nn.initializers.ones, (h,), F32)
+        with jax.named_scope(SSM_CORE_SCOPE):
+            y = ssm_scan(xs, big_b, big_c, dt, rate, skip, heads=h, groups=g, chunk=self.chunk, dtype=self.dtype)
+        y = gated_group_norm(y, z, self.param("norm", nn.initializers.ones, (inner,), F32), g, self.norm_eps)
+        return dense(self.width, name="out")(y.astype(self.dtype))

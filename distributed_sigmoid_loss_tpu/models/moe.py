@@ -53,8 +53,10 @@ __all__ = [
     "moe_capacity",
     "SharedExpertMoe",
     "SELECT_BIAS",
+    "BALANCE",
     "MOE_ROUTE_SCOPE",
     "sigmoid_route",
+    "balanced_select_bias",
     "dispatch_plan",
     "routed_experts",
 ]
@@ -272,6 +274,7 @@ class MoeMlp(nn.Module):
 #
 #     s = sigmoid(x Wr) in R^E ;  I = top_k(s + b) ;  w_i = scale * s_i / sum_{j in I} s_j
 #     y = Shared(x) + sum_{i in I, i held here} w_i E_i(x) ;  E(x) = (silu(x Wg) * (x Wu)) Wd
+#                                                        or, ungated ("relu2"), E(x) = relu(x Wu)^2 Wd
 #
 # ``b`` decides the selection only (SELECT_BIAS: no gradient reaches it, and
 # train/train_step.py gives it no decay and no optimizer state). A chip holds
@@ -283,6 +286,11 @@ class MoeMlp(nn.Module):
 # only the index arrays have that size: tokens are gathered a block at a time.
 
 SELECT_BIAS = "select_bias"
+# The collection whose being mutable makes a pass the initialisation's balancing
+# pass (``TextConfig.moe_balanced_init``, train/train_step.py balance_routers):
+# each routed layer then sets its selection bias from the tokens it is given by
+# :func:`balanced_select_bias`, routes by it and sows it there.
+BALANCE = "balance"
 # The program's name for everything of the layer but the expert and shared
 # products: scores, selection, the sort, the gathers and scatters.
 MOE_ROUTE_SCOPE = "moe_route"
@@ -292,12 +300,40 @@ BLOCK_ROWS = 512
 F32 = jnp.float32
 
 
+def router_scores(x, wr):
+    """sigmoid(x Wr) of ``(T, E)``: float32 at full matmul precision."""
+    return jax.nn.sigmoid(
+        jnp.dot(x.astype(F32), wr, precision=jax.lax.Precision.HIGHEST)
+    )
+
+
+def balanced_select_bias(scores, k: int, rounds: int = 20):
+    """The selection bias ``(E,)`` under which every expert is among the ``k``
+    best of ``T * k / E`` of the ``T`` tokens whose ``scores`` (T, E) these are:
+    what the recipe's balancing update holds a trained router at, found here at
+    once. A round sets each expert's bias to what gives it just that many tokens
+    while the others keep theirs: per token the bias the expert needs to be
+    chosen (the token's k-th best without it, less its own score), and of those
+    the ``T * k / E``-th smallest."""
+    tokens, experts = scores.shape
+    if k >= experts:  # every token takes every expert
+        return jnp.zeros((experts,), F32)
+    target = max(1, tokens * k // experts)
+
+    def one_round(_, bias):
+        biased = scores + bias
+        best, _ = jax.lax.top_k(biased, k + 1)
+        kth, following = best[:, k - 1:k], best[:, k:k + 1]
+        to_beat = jnp.where(biased >= kth, following, kth)
+        return jnp.sort(to_beat - scores, axis=0)[target - 1]
+
+    return jax.lax.fori_loop(0, rounds, one_round, jnp.zeros((experts,), F32))
+
+
 def sigmoid_route(x, wr, select_bias, k: int, scale: float):
     """``(idx, weights)`` of ``(T, k)`` for tokens ``x`` (T, d): float32 scores
     at full matmul precision, selection by score + bias, weights by score."""
-    scores = jax.nn.sigmoid(
-        jnp.dot(x.astype(F32), wr, precision=jax.lax.Precision.HIGHEST)
-    )
+    scores = router_scores(x, wr)
     _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias), k)
     chosen = jnp.take_along_axis(scores, idx, -1)
     return idx, scale * chosen / jnp.sum(chosen, -1, keepdims=True)
@@ -349,49 +385,77 @@ def _scatter_add_rows(y, to, rows):
         return y.at[to].add(rows, mode="drop")
 
 
-def _expert_mlp(xb, wg, wu, wd, e, dt):
-    """One block through expert ``e``: float32 gate and up, the hidden
-    activation and the output, with what the backward needs of them."""
-    wg_e, wu_e, wd_e = (jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False).astype(dt) for w in (wg, wu, wd))
+def _expert_mlp(xb, stacks, e, dt):
+    """One block through expert ``e`` of the stacked weights: ``(gate, up,
+    down)`` for a SwiGLU expert, ``(up, down)`` for a relu2 one (the kind is
+    what the stacks are). Float32 products, the hidden activation and the
+    output, with what the backward needs of them."""
+    ws = tuple(jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False).astype(dt) for w in stacks)
+    if len(ws) == 2:  # relu2: down(relu(up x)^2)
+        up = jnp.dot(xb, ws[0], preferred_element_type=F32)
+        hidden = jnp.square(jax.nn.relu(up)).astype(dt)
+        return ws, (up, hidden), jnp.dot(hidden, ws[1], preferred_element_type=F32)
+    wg_e, wu_e, wd_e = ws
     gate = jnp.dot(xb, wg_e, preferred_element_type=F32)
     up = jnp.dot(xb, wu_e, preferred_element_type=F32)
     sig = jax.nn.sigmoid(gate)
     hidden = (gate * sig * up).astype(dt)
-    return (wg_e, wu_e, wd_e), (gate, up, sig, hidden), jnp.dot(hidden, wd_e, preferred_element_type=F32)
+    return ws, (gate, up, sig, hidden), jnp.dot(hidden, wd_e, preferred_element_type=F32)
 
 
-def _routed_forward(x, wg, wu, wd, token, row_weight, starts, counts, block):
+def _expert_mlp_bwd(xb, ws, kept, dyw, dt):
+    """The expert's chain backwards from the weighted cotangent ``dyw`` of its
+    output: ``(dxb, products)``, the block's input cotangent (float32) and, per
+    stack, the two operands (a, b) whose a^T b is the stack's weight gradient."""
+    d_hidden = jnp.dot(dyw, ws[-1].T, preferred_element_type=F32)
+    if len(ws) == 2:
+        up, hidden = kept
+        d_up = (d_hidden * 2.0 * jax.nn.relu(up)).astype(dt)
+        return jnp.dot(d_up, ws[0].T, preferred_element_type=F32), ((xb, d_up), (hidden, dyw))
+    wg_e, wu_e, _ = ws
+    gate, up, sig, hidden = kept
+    d_up = (d_hidden * gate * sig).astype(dt)
+    d_gate = (d_hidden * up * sig * (1.0 + gate * (1.0 - sig))).astype(dt)
+    dxb = jnp.dot(d_gate, wg_e.T, preferred_element_type=F32) + jnp.dot(
+        d_up, wu_e.T, preferred_element_type=F32
+    )
+    return dxb, ((xb, d_gate), (xb, d_up), (hidden, dyw))
+
+
+def _routed_forward(x, stacks, token, row_weight, starts, counts, block):
     tokens, dt = x.shape[0], x.dtype
     total, first = _block_plan(starts, counts, block)
 
     def step(i, carry):
         y, done = carry
         e, _, valid, tok, to, wts = _block_rows(i, first, block, token, row_weight, starts, counts, tokens)
-        _, _, out = _expert_mlp(_gather_rows(x, tok), wg, wu, wd, e, dt)
+        _, _, out = _expert_mlp(_gather_rows(x, tok), stacks, e, dt)
         return _scatter_add_rows(y, to, out * wts[:, None]), done + jnp.sum(valid, dtype=jnp.int32)
 
     y, done = jax.lax.fori_loop(0, total, step, (jnp.zeros(x.shape, F32), jnp.zeros((), jnp.int32)))
     return y.astype(dt), done
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(8,))
-def routed_experts(x, wg, wu, wd, token, row_weight, starts, counts, block):
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def routed_experts(x, stacks, token, row_weight, starts, counts, block):
     """``(y, rows_done)``: the held experts' weighted part of the layer's output
     for tokens ``x`` (T, d), following :func:`dispatch_plan`, ``block`` rows at
     a time, one loop over every expert's blocks whose trip count the device
-    reads; ``rows_done`` counts the assignments that ran (all of them)."""
-    return _routed_forward(x, wg, wu, wd, token, row_weight, starts, counts, block)
+    reads; ``rows_done`` counts the assignments that ran (all of them).
+    ``stacks`` are the held experts' stacked weights and say the experts' kind:
+    ``(gate, up, down)`` SwiGLU, ``(up, down)`` relu2."""
+    return _routed_forward(x, stacks, token, row_weight, starts, counts, block)
 
 
-def _routed_experts_fwd(x, wg, wu, wd, token, row_weight, starts, counts, block):
-    out = _routed_forward(x, wg, wu, wd, token, row_weight, starts, counts, block)
-    return out, (x, wg, wu, wd, token, row_weight, starts, counts)
+def _routed_experts_fwd(x, stacks, token, row_weight, starts, counts, block):
+    out = _routed_forward(x, stacks, token, row_weight, starts, counts, block)
+    return out, (x, stacks, token, row_weight, starts, counts)
 
 
 def _routed_experts_bwd(block, saved, cts):
     """The same loop backwards, each block's forward recomputed; the weight
     gradients ride it in float32, a block adding into its expert's row."""
-    x, wg, wu, wd, token, row_weight, starts, counts = saved
+    x, stacks, token, row_weight, starts, counts = saved
     dy = cts[0]
     tokens, dt = x.shape[0], x.dtype
     total, first = _block_plan(starts, counts, block)
@@ -402,32 +466,25 @@ def _routed_experts_bwd(block, saved, cts):
         )
 
     def step(i, carry):
-        dx, d_weight, g_wg, g_wu, g_wd = carry
+        dx, d_weight, *grads = carry
         e, rows, valid, tok, to, wts = _block_rows(i, first, block, token, row_weight, starts, counts, tokens)
         xb = _gather_rows(x, tok)
-        (wg_e, wu_e, wd_e), (gate, up, sig, hidden), out = _expert_mlp(xb, wg, wu, wd, e, dt)
+        ws, kept, out = _expert_mlp(xb, stacks, e, dt)
         dyb = jnp.where(valid[:, None], _gather_rows(dy, tok).astype(F32), 0.0)
         d_weight = d_weight.at[jnp.where(valid, rows, d_weight.shape[0])].set(
             jnp.sum(dyb * out, -1), mode="drop"
         )
         dyw = (dyb * wts[:, None]).astype(dt)
-        d_hidden = jnp.dot(dyw, wd_e.T, preferred_element_type=F32)
-        d_up = (d_hidden * gate * sig).astype(dt)
-        d_gate = (d_hidden * up * sig * (1.0 + gate * (1.0 - sig))).astype(dt)
-        dxb = jnp.dot(d_gate, wg_e.T, preferred_element_type=F32) + jnp.dot(
-            d_up, wu_e.T, preferred_element_type=F32
-        )
+        dxb, products = _expert_mlp_bwd(xb, ws, kept, dyw, dt)
         return (
             _scatter_add_rows(dx, to, dxb), d_weight,
-            add_row(g_wg, e, jnp.dot(xb.T, d_gate, preferred_element_type=F32)),
-            add_row(g_wu, e, jnp.dot(xb.T, d_up, preferred_element_type=F32)),
-            add_row(g_wd, e, jnp.dot(hidden.T, dyw, preferred_element_type=F32)),
+            *(add_row(g, e, jnp.dot(a.T, b, preferred_element_type=F32)) for g, (a, b) in zip(grads, products)),
         )
 
-    zeros = (jnp.zeros(x.shape, F32), jnp.zeros(row_weight.shape, F32), *(jnp.zeros(w.shape, F32) for w in (wg, wu, wd)))
-    dx, d_weight, g_wg, g_wu, g_wd = jax.lax.fori_loop(0, total, step, zeros)
+    zeros = (jnp.zeros(x.shape, F32), jnp.zeros(row_weight.shape, F32), *(jnp.zeros(w.shape, F32) for w in stacks))
+    dx, d_weight, *grads = jax.lax.fori_loop(0, total, step, zeros)
     return (
-        dx.astype(dt), g_wg.astype(wg.dtype), g_wu.astype(wu.dtype), g_wd.astype(wd.dtype),
+        dx.astype(dt), tuple(g.astype(w.dtype) for g, w in zip(grads, stacks)),
         None, d_weight.astype(row_weight.dtype), None, None,
     )
 
@@ -436,11 +493,15 @@ routed_experts.defvjp(_routed_experts_fwd, _routed_experts_bwd)
 
 
 class SharedExpertMoe(nn.Module):
-    """The sigmoid-routed layer above: ``num_experts`` routed bias-free SwiGLU
-    experts ``hidden`` wide, ``num_selected`` a token, ``shared_experts`` more
-    that every token runs, and of the routed ones ``experts_held`` (0 = all)
-    here, from ``first_held`` on. Sows ``moe_load`` into ``"intermediates"``:
-    the held experts' token counts and the assignments that did not run (0)."""
+    """The sigmoid-routed layer above: ``num_experts`` routed bias-free experts
+    ``hidden`` wide, ``num_selected`` a token, ``shared_experts`` more that
+    every token runs (as one MLP ``shared_hidden`` wide, 0 = ``shared_experts x
+    hidden``), and of the routed ones ``experts_held`` (0 = all) here, from
+    ``first_held`` on. ``kind`` is every expert's, the shared one's too:
+    ``"swiglu"``, down(silu(gate x) * (up x)), three stacks; ``"relu2"``,
+    down(relu(up x)^2), two (no ``wg``). Sows ``moe_load`` into
+    ``"intermediates"``: the held experts' token counts and the assignments
+    that did not run (0)."""
 
     width: int
     hidden: int
@@ -451,6 +512,8 @@ class SharedExpertMoe(nn.Module):
     shared_experts: int = 0
     experts_held: int = 0
     first_held: int = 0
+    kind: str = "swiglu"  # "swiglu" | "relu2"
+    shared_hidden: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -458,6 +521,8 @@ class SharedExpertMoe(nn.Module):
 
         d, e, k = self.width, self.num_experts, self.num_selected
         held = self.experts_held or e
+        if self.kind not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown expert kind: {self.kind!r} (want 'swiglu' or 'relu2')")
         if not 0 < k <= e or not 0 <= self.first_held <= e - held:
             raise ValueError(
                 f"moe_num_selected={k}, moe_experts_held={held} (from {self.first_held}) "
@@ -469,18 +534,23 @@ class SharedExpertMoe(nn.Module):
         with jax.named_scope(MOE_ROUTE_SCOPE):
             wr = self.param("router", nn.initializers.normal(0.02), (d, e), F32)
             select_bias = self.param(SELECT_BIAS, nn.initializers.zeros, (e,), F32)
+            if self.is_mutable_collection(BALANCE) and not self.is_initializing():
+                select_bias = balanced_select_bias(router_scores(xt, wr), k)
+                self.sow(BALANCE, SELECT_BIAS, select_bias)
             idx, weights = sigmoid_route(xt, wr, select_bias, k, self.route_scale)
             token, row_weight, starts, counts = dispatch_plan(idx, weights, self.first_held, held)
         per_expert = nn.initializers.variance_scaling(1.0, "fan_avg", "uniform", batch_axis=(0,))
-        wg = self.param("wg", per_expert, (held, d, self.hidden), F32)
-        wu = self.param("wi", per_expert, (held, d, self.hidden), F32)
-        wd = self.param("wo", per_expert, (held, self.hidden, d), F32)
-        y, done = routed_experts(xt, wg, wu, wd, token, row_weight, starts, counts, block)
+        stacks = () if self.kind == "relu2" else (self.param("wg", per_expert, (held, d, self.hidden), F32),)
+        stacks += (
+            self.param("wi", per_expert, (held, d, self.hidden), F32),
+            self.param("wo", per_expert, (held, self.hidden, d), F32),
+        )
+        y, done = routed_experts(xt, stacks, token, row_weight, starts, counts, block)
         self.sow("intermediates", "moe_load", {"tokens": counts, "dropped": jnp.sum(counts) - done})
         y = y.reshape(*lead, d)
         if self.shared_experts:
             y = y + Mlp(
-                d, self.shared_experts * self.hidden / d, self.dtype,
-                kind="swiglu", use_bias=False, name="shared",
+                d, (self.shared_hidden or self.shared_experts * self.hidden) / d, self.dtype,
+                kind=self.kind, use_bias=False, name="shared",
             )(x)
         return y

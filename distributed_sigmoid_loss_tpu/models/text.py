@@ -6,7 +6,9 @@ options turn it into a language-model-class encoder: causal, rotary positions
 in place of the position table (or none: ``pos="none"``), RMSNorm sandwich
 blocks with a gated MLP, the stack run ``loops`` times on one set of weights, or
 a stack given layer by layer (``mixers``): several layer kinds with routed
-experts, or windowed chunk attention in every layer."""
+experts, windowed chunk attention in every layer, or layers of ONE sub-layer
+each (``sublayers="single"``: a state-space mixer, a grouped-head attention or
+a routed feed-forward part alone)."""
 
 from __future__ import annotations
 
@@ -23,14 +25,24 @@ from distributed_sigmoid_loss_tpu.models.transformer import (
 from distributed_sigmoid_loss_tpu.utils.config import TextConfig, tower_quant_mode
 
 
+# What a layer of a one-sub-layer stack may be (``mixers`` names it): a state-space mixer alone, an
+# attention alone, or the routed feed-forward part alone. Nothing else runs so yet.
+SINGLE_LAYERS = ("ssm", "attn", "moe")
+
+
 def layer_specs(cfg: TextConfig) -> tuple[LayerSpec, ...]:
     """``Encoder``'s layers where the configuration gives the stack layer by
-    layer (``mixers``, ``leading_dense_layers``) or asks for the sigmoid-routed
-    experts; none for the stack every tower had."""
-    if not (cfg.mixers or cfg.leading_dense_layers or cfg.moe_router != "softmax"):
+    layer (``mixers``, ``leading_dense_layers``, ``sublayers="single"``), asks
+    for the sigmoid-routed experts or gives ``Attention`` head sizes of its own
+    (``num_kv_heads``, ``head_dim``); none for the stack every tower had."""
+    single = cfg.sublayers == "single"
+    attn_sizes = tuple((k, v) for k, v in (("num_kv_heads", cfg.num_kv_heads), ("head_dim", cfg.head_dim)) if v)
+    if not (cfg.mixers or cfg.leading_dense_layers or cfg.moe_router != "softmax" or attn_sizes or single):
         return ()
+    if cfg.sublayers not in ("pair", "single"):
+        raise ValueError(f"unknown sublayers: {cfg.sublayers!r}")
     mixer_fields = {
-        "attn": (),
+        "attn": attn_sizes,
         "kda": (("head_dim", cfg.kda_head_dim), ("conv_size", cfg.kda_conv_size)),
         "mla": (
             ("nope_dim", cfg.mla_qk_nope_dim), ("shared_dim", cfg.mla_qk_shared_dim),
@@ -40,15 +52,36 @@ def layer_specs(cfg: TextConfig) -> tuple[LayerSpec, ...]:
         "eva": (
             ("window", cfg.eva_window), ("chunk", cfg.eva_chunk), ("rope_theta", cfg.rope_theta),
         ),
+        "ssm": (
+            ("num_heads", cfg.ssm_num_heads), ("head_dim", cfg.ssm_head_dim), ("state", cfg.ssm_state),
+            ("groups", cfg.ssm_groups), ("conv_size", cfg.ssm_conv_size), ("chunk", cfg.ssm_chunk),
+        ),
     }
     mixers = cfg.mixers or ("attn",) * cfg.depth
-    if not set(mixers) <= set(mixer_fields):
-        raise ValueError(f"unknown mixer in mixers={mixers}: want one of {sorted(mixer_fields)}")
+    # A pair layer takes the mixers that ran beside an MLP before; the state-space mixer is a layer alone.
+    known = set(SINGLE_LAYERS) if single else set(mixer_fields) - {"ssm"}
+    if not set(mixers) <= known:
+        raise ValueError(
+            f"unknown mixer in mixers={mixers} with sublayers={cfg.sublayers!r}: want one of {sorted(known)} "
+            "('ssm' and 'moe' name a layer of sublayers='single'; 'kda', 'mla' and 'eva' one of 'pair')"
+        )
+    if single:
+        # One sub-layer a layer: ``mixers`` names it, a mixer alone or the routed feed-forward part alone.
+        refused = {
+            "mixers=() (it names every layer's one sub-layer)": not cfg.mixers,
+            f"leading_dense_layers={cfg.leading_dense_layers} (no layer of the stack is a dense MLP)":
+                cfg.leading_dense_layers != 0,
+            f"moe_experts={cfg.moe_experts} (a 'moe' layer routes over experts)":
+                "moe" in mixers and cfg.moe_experts < 1,
+        }
+        if any(refused.values()):
+            raise ValueError("sublayers='single' is not built for " + ", ".join(k for k, v in refused.items() if v))
     # Latent attention rotates its shared-width parts; a recurrence carries the
     # order itself, and neither takes a position table.
-    if "kda" in mixers and cfg.pos != "none" or "mla" in mixers and cfg.pos == "learned":
+    recurrent = {"kda", "ssm"} & set(mixers)
+    if recurrent and cfg.pos != "none" or "mla" in mixers and cfg.pos == "learned":
         raise ValueError(
-            f"mixers={mixers} is not built for pos={cfg.pos!r}: a recurrence ('kda') takes "
+            f"mixers={mixers} is not built for pos={cfg.pos!r}: a recurrence ('kda', 'ssm') takes "
             "pos='none', latent attention ('mla') 'none' or 'rope'"
         )
     if "eva" in mixers:
@@ -74,6 +107,12 @@ def layer_specs(cfg: TextConfig) -> tuple[LayerSpec, ...]:
             ("hidden", cfg.moe_hidden or int(round(cfg.width * cfg.mlp_ratio))),
             ("route_scale", cfg.moe_route_scale), ("shared_experts", cfg.moe_shared_experts),
             ("experts_held", cfg.moe_experts_held),
+        ) + ((("shared_hidden", cfg.moe_shared_hidden),) if cfg.moe_shared_hidden else ())
+    if single:
+        return tuple(
+            LayerSpec("none", experts_fields=experts_fields) if kind == "moe"
+            else LayerSpec(kind, mixer_fields[kind], feed_forward=False)
+            for kind in mixers
         )
     return tuple(
         LayerSpec(kind, mixer_fields[kind], i < cfg.leading_dense_layers, experts_fields)

@@ -34,6 +34,9 @@ DP_AXIS = "dp"
 # program's name for the accumulator's traffic (benchmark/scopes.py reads it).
 GRAD_SINK = "grad_sink"
 ACCUM_SCOPE = "accum"
+# The program's name for the device time of ``Attention``'s single-device core: scores,
+# softmax and values, whichever kernel or XLA form runs them (benchmark/scopes_nemotron.py).
+ATTN_CORE_SCOPE = "attn_core"
 
 
 def _dtype(name: str):
@@ -123,7 +126,7 @@ class BlockStyle:
 
     norm: str = "layernorm"  # "layernorm" | "rmsnorm"
     sandwich_norm: bool = False
-    mlp: str = "gelu"  # "gelu" | "swiglu"
+    mlp: str = "gelu"  # "gelu" | "swiglu" | "relu2"
     use_bias: bool = True
     rope_theta: float | None = None  # None = no rotary positions
     norm_eps: float = 1e-6
@@ -175,10 +178,12 @@ class LayerSpec:
     mixer with its module's own size fields, and what the MLP is where
     ``moe_experts > 0``. The default is the block every tower had."""
 
-    mixer: str = "attn"  # "attn" | "kda" | "mla" | "eva" (models/mixers.py)
-    mixer_fields: tuple = ()  # (name, value) pairs of KdaMixer / LatentAttention / EvaAttention
+    mixer: str = "attn"  # "attn" | "kda" | "mla" | "eva" | "ssm" (models/mixers.py) | "none": no mixer
+    # (name, value) pairs of KdaMixer / LatentAttention / EvaAttention / SsmMixer, or Attention's own head sizes
+    mixer_fields: tuple = ()
     dense_mlp: bool = False  # a leading layer keeps the dense MLP
     experts_fields: tuple = ()  # (name, value) pairs of SharedExpertMoe; none = MoeMlp
+    feed_forward: bool = True  # False: no feed-forward part. A layer without one, or without a mixer, has one norm
 
 
 def rope_tables(s: int, dh: int, theta: float):
@@ -223,14 +228,14 @@ class Mlp(nn.Module):
     mlp_ratio: int | float
     dtype: Any
     quant: bool | str = False  # "" | "int8" | "int8_ste" (see _dot_general)
-    kind: str = "gelu"  # "gelu": wo(gelu(wi x)) | "swiglu": wo(silu(wg x) * (wi x))
+    kind: str = "gelu"  # "gelu": wo(gelu(wi x)) | "swiglu": wo(silu(wg x) * (wi x)) | "relu2": wo(relu(wi x)^2)
     use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
         hidden = int(round(self.width * self.mlp_ratio))
         dg = _dot_general(self.quant)
-        if self.kind not in ("gelu", "swiglu"):
+        if self.kind not in ("gelu", "swiglu", "relu2"):
             raise ValueError(f"unknown mlp: {self.kind!r}")
 
         # Column-parallel in, row-parallel out: the tp all-reduce happens once, after wo.
@@ -261,8 +266,69 @@ class Mlp(nn.Module):
         hidden_act = checkpoint_name(column_parallel("wi")(x), "mlp_hidden")
         if self.kind == "gelu":
             return wo(nn.gelu(hidden_act, approximate=True))
+        if self.kind == "relu2":
+            return wo(jnp.square(nn.relu(hidden_act)))
         gate = checkpoint_name(column_parallel("wg")(x), "mlp_hidden")
         return wo(nn.silu(gate) * hidden_act)
+
+
+def attention_core(attn_impl: str, dtype, tokens: int, heads: int, kv_heads: int, head_dim: int, causal: bool,
+                   self_attention: bool = True) -> dict:
+    """Which single-device core an ``Attention`` call takes, from what it can
+    see: ``"dense"`` (XLA einsum softmax), ``"short"`` (the VMEM-resident
+    ``short_attn_*`` kernels, ops/pallas_short_attention.py), ``"flash"`` (the
+    library's blocked kernel, ops/flash_attention.py) or ``"kernel"`` (the repo's
+    causal pair ``mla_attn_fwd`` / ``mla_attn_bwd``, ops/pallas_latent_attention.py,
+    on (b, s, h x d)). "auto" picks a fused kernel only for bf16 self-attention
+    on a TPU: the fused backward matmuls are bf16-grade, which is exactly right
+    for bf16 training but would silently degrade an f32 parity run; "flash" asks
+    for one whatever the dtype. Of the fused ones, heads that each have their
+    own keys and values take the short kernels where a program's footprint fits
+    their VMEM budget and the blocked kernel past that, as they always have.
+    GROUPED heads (``kv_heads < heads``), causal, with a head size in whole
+    128-lane registers whose sequence fits the pair's VMEM take the pair, which
+    reads a group's one key / value head where it lies: ``kv_repeated`` is then
+    False. Every other core takes one key and value head a query head, so
+    grouped keys and values are repeated to that form first (``kv_repeated``
+    True: the CPU, float32, a sequence the pair does not admit). ``block`` is
+    the tokens a block of the pair or the blocked kernel (None otherwise). The
+    module runs what this says and the step's trace-time record
+    (``train_step.stack_record_of``) reports it for grouped layers."""
+    from distributed_sigmoid_loss_tpu.ops.flash_attention import flash_attention_available, flash_attention_plan
+    from distributed_sigmoid_loss_tpu.ops.pallas_latent_attention import latent_attention_plan
+    from distributed_sigmoid_loss_tpu.ops.pallas_short_attention import short_attention_fits
+
+    if attn_impl == "flash" and not self_attention:
+        raise ValueError(
+            "attn_impl='flash' requires self-attention (the fused kernels "
+            "assume q/k/v share one sequence); use 'auto' or 'dense' for "
+            "cross-attention"
+        )
+    if attn_impl == "flash" and not flash_attention_available():
+        raise ValueError(
+            "attn_impl='flash' requires a TPU backend (current: "
+            f"{jax.default_backend()!r}); use 'auto' to fall back to the "
+            "dense path automatically"
+        )
+    use_fused = attn_impl == "flash" or (
+        attn_impl == "auto"
+        and self_attention
+        and dtype == jnp.bfloat16
+        and flash_attention_available()
+    )
+    itemsize = jnp.dtype(dtype).itemsize
+    grouped = kv_heads != heads
+    pair = latent_attention_plan(tokens, head_dim, head_dim, itemsize) if use_fused and grouped and causal else None
+    if not use_fused:
+        core, block = "dense", None
+    elif pair is not None:
+        core, block = "kernel", pair["block"]
+    elif short_attention_fits(tokens, heads * head_dim, itemsize):
+        core, block = "short", None
+    else:
+        core, block = "flash", flash_attention_plan(tokens)["block"]
+    return {"core": core, "block": block, "heads": heads, "kv_heads": kv_heads, "head_dim": head_dim,
+            "kv_repeated": grouped and core != "kernel"}
 
 
 class Attention(nn.Module):
@@ -274,7 +340,13 @@ class Attention(nn.Module):
 
     ``attn_impl`` selects the single-device core: "dense" (XLA einsum softmax),
     "flash" (Pallas fused kernel, TPU only), or "auto" (flash on TPU when the shape
-    qualifies, dense otherwise)."""
+    qualifies, dense otherwise): :func:`attention_core` has the rule.
+
+    ``num_kv_heads`` (0 = ``num_heads``) groups the query heads over fewer key /
+    value heads: query head h reads key / value head h // (num_heads /
+    num_kv_heads). ``head_dim`` (0 = ``width // num_heads``) is a head size of
+    its own: q is width -> num_heads x head_dim, k and v width -> num_kv_heads x
+    head_dim, out num_heads x head_dim -> width."""
 
     width: int
     num_heads: int
@@ -286,12 +358,23 @@ class Attention(nn.Module):
     quant: bool | str = False  # "" | "int8" | "int8_ste" (see _dot_general)
     use_bias: bool = True
     rope_theta: float | None = None  # rotary positions on q and k (see rope)
+    num_kv_heads: int = 0  # 0 = num_heads
+    head_dim: int = 0  # 0 = width // num_heads
 
     @nn.compact
     def __call__(self, x_q, x_kv=None):
         is_self_attention = x_kv is None
         x_kv = x_q if x_kv is None else x_kv
-        head_dim = self.width // self.num_heads
+        head_dim = self.head_dim or self.width // self.num_heads
+        kv_heads = self.num_kv_heads or self.num_heads
+        inner = self.num_heads * head_dim  # the width, for heads of width // num_heads
+        if self.num_heads % kv_heads:
+            raise ValueError(f"num_kv_heads={kv_heads} does not divide num_heads={self.num_heads}")
+        if kv_heads != self.num_heads and (self.sp_axis is not None or not is_self_attention):
+            raise ValueError(
+                f"num_kv_heads={kv_heads} (grouped heads) is not built for sequence-parallel attention "
+                f"(sequence_parallel_axis={self.sp_axis!r}) or cross-attention"
+            )
         dg = _dot_general(self.quant)
         if self.rope_theta is not None and (self.sp_axis is not None or not is_self_attention):
             raise ValueError(
@@ -303,12 +386,12 @@ class Attention(nn.Module):
         out_init = nn.with_partitioning(nn.initializers.xavier_uniform(), (TP_AXIS, None))
         dense = partial(nn.Dense, dtype=self.dtype, use_bias=self.use_bias, dot_general=dg)
 
-        q = dense(self.width, kernel_init=qkv_init, name="q")(x_q)
-        k = dense(self.width, kernel_init=qkv_init, name="k")(x_kv)
-        v = dense(self.width, kernel_init=qkv_init, name="v")(x_kv)
+        q = dense(inner, kernel_init=qkv_init, name="q")(x_q)
+        k = dense(kv_heads * head_dim, kernel_init=qkv_init, name="k")(x_kv)
+        v = dense(kv_heads * head_dim, kernel_init=qkv_init, name="v")(x_kv)
 
         def split(t):
-            return t.reshape(t.shape[:-1] + (self.num_heads, head_dim))
+            return t.reshape(t.shape[:-1] + (-1, head_dim))
 
         # Named for the "save_all_hot" remat policy (saves the projections too, so
         # backward recompute is layernorm+gelu only).
@@ -347,60 +430,46 @@ class Attention(nn.Module):
                 axis_names={self.sp_axis},
             )(q, k, v)
         else:
-            from distributed_sigmoid_loss_tpu.ops.flash_attention import (
-                flash_attention_available,
-                flash_self_attention,
-            )
-            from distributed_sigmoid_loss_tpu.ops.pallas_short_attention import (
-                short_attention_fits,
-                short_self_attention,
-            )
+            from distributed_sigmoid_loss_tpu.ops.flash_attention import flash_self_attention
+            from distributed_sigmoid_loss_tpu.ops.pallas_short_attention import short_self_attention
             from distributed_sigmoid_loss_tpu.parallel.ring_attention import (
                 dense_attention,
             )
 
-            # "auto" picks a fused Pallas kernel only for bf16 self-attention: the
-            # fused backward matmuls are bf16-grade, which is exactly right for
-            # bf16 training but would silently degrade an f32 parity run. Short
-            # sequences (towers) take the VMEM-resident kernel when its per-program
-            # footprint fits the VMEM budget; otherwise the blockwise flash kernel.
-            if self.attn_impl == "flash" and not is_self_attention:
-                raise ValueError(
-                    "attn_impl='flash' requires self-attention (the fused kernels "
-                    "assume q/k/v share one sequence); use 'auto' or 'dense' for "
-                    "cross-attention"
-                )
-            if self.attn_impl == "flash" and not flash_attention_available():
-                raise ValueError(
-                    "attn_impl='flash' requires a TPU backend (current: "
-                    f"{jax.default_backend()!r}); use 'auto' to fall back to the "
-                    "dense path automatically"
-                )
-            use_fused = self.attn_impl == "flash" or (
-                self.attn_impl == "auto"
-                and is_self_attention
-                and self.dtype == jnp.bfloat16
-                and flash_attention_available()
+            sizes = attention_core(
+                self.attn_impl, self.dtype, q.shape[1], self.num_heads, kv_heads, head_dim, self.causal,
+                is_self_attention,
             )
-            if not use_fused:
-                kernel = dense_attention
-            elif short_attention_fits(
-                q.shape[1], self.width, jnp.dtype(self.dtype).itemsize
-            ):
-                kernel = short_self_attention
-            else:
-                kernel = flash_self_attention
-            attend = partial(kernel, causal=self.causal)
-            out = (
-                _fused_attention_per_shard(attend, q, k, v) if use_fused
-                else attend(q, k, v)
-            )
+            with jax.named_scope(ATTN_CORE_SCOPE):
+                if sizes["core"] == "kernel":
+                    # Grouped heads through the causal pair, the heads on the lanes: a group's
+                    # key / value head is read where it lies, nothing is repeated in HBM.
+                    from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import kernels_per_shard
+                    from distributed_sigmoid_loss_tpu.ops.pallas_latent_attention import latent_attention_kernel
+
+                    def pair(q, k, v):  # a shard of whole key / value heads reads its heads off the widths
+                        return latent_attention_kernel(
+                            q, k, v, head_dims=(head_dim, head_dim), kv_heads=k.shape[-1] // head_dim
+                        )
+
+                    wide = (t.reshape(t.shape[:-2] + (-1,)) for t in (q, k, v))
+                    out = kernels_per_shard(pair, kv_heads, *wide)
+                    out = out.reshape(out.shape[:-1] + (self.num_heads, head_dim))
+                else:
+                    if sizes["kv_repeated"]:
+                        k, v = (jnp.repeat(t, self.num_heads // kv_heads, axis=-2) for t in (k, v))
+                    kernel = {"dense": dense_attention, "short": short_self_attention, "flash": flash_self_attention}
+                    attend = partial(kernel[sizes["core"]], causal=self.causal)
+                    out = (
+                        _fused_attention_per_shard(attend, q, k, v) if sizes["core"] != "dense"
+                        else attend(q, k, v)
+                    )
             out = out.astype(self.dtype)
         # Named for the "save_hot" remat policy: with the core output saved, the
         # backward pass needs only q/k/v (for the attention VJP) — the s² core
         # forward is never re-run.
         out = checkpoint_name(out, "attn_core")
-        out = out.reshape(out.shape[:-2] + (self.width,))
+        out = out.reshape(out.shape[:-2] + (inner,))
         return dense(self.width, kernel_init=out_init, name="out")(out)
 
 
@@ -409,7 +478,10 @@ class Block(nn.Module):
     mixture-of-experts layer (models/moe.py) whose expert weights shard over the
     ``ep`` mesh axis; the residual stream is unchanged, so MoE composes with
     remat/scan/sp exactly like the dense block. ``style`` picks the norm, a
-    second norm on each sub-layer's output, the MLP and rotary positions."""
+    second norm on each sub-layer's output, the MLP and rotary positions.
+    ``spec`` may leave out the mixer (``mixer="none"``) or the feed-forward part
+    (``feed_forward=False``): the layer is then ONE sub-layer, x + f(norm(x)),
+    under one norm (``ln1``), and has no ``ln2``."""
 
     width: int
     num_heads: int
@@ -437,13 +509,17 @@ class Block(nn.Module):
         def post(name, y):  # the sandwich's second norm, on a sub-layer's output
             return norm(name)(y) if style.sandwich_norm else y
 
-        if spec.mixer == "attn":
+        if spec.mixer == "none" and not spec.feed_forward:
+            raise ValueError("a layer with neither a mixer nor a feed-forward part is no layer")
+        if spec.mixer == "none":
+            mixer = None
+        elif spec.mixer == "attn":
             mixer = Attention(
                 self.width, self.num_heads, self.dtype,
                 sp_axis=self.sp_axis, sp_impl=self.sp_impl,
                 attn_impl=self.attn_impl, causal=self.causal,
                 quant=self.quant, use_bias=style.use_bias, rope_theta=style.rope_theta,
-                name="attn",
+                **dict(spec.mixer_fields), name="attn",
             )
         elif spec.mixer in ("kda", "mla"):
             from distributed_sigmoid_loss_tpu.models.mixers import KdaMixer, LatentAttention
@@ -463,21 +539,32 @@ class Block(nn.Module):
                 width=self.width, num_heads=self.num_heads, dtype=self.dtype,
                 attn_impl=self.attn_impl, **dict(spec.mixer_fields), name="eva",
             )
+        elif spec.mixer == "ssm":
+            from distributed_sigmoid_loss_tpu.models.mixers import SsmMixer
+
+            mixer = SsmMixer(
+                width=self.width, dtype=self.dtype, norm_eps=style.norm_eps, **dict(spec.mixer_fields), name="ssm",
+            )
         else:
             raise ValueError(f"unknown mixer: mixers has {spec.mixer!r}")
-        x = x + post("ln1_post", mixer(norm("ln1")(x)))
+        if mixer is not None:
+            x = x + post("ln1_post", mixer(norm("ln1")(x)))
+        if not spec.feed_forward:
+            return x
+        # The one norm of a layer without a mixer is its first.
+        ln, ln_post = ("ln1", "ln1_post") if mixer is None else ("ln2", "ln2_post")
         routed = self.moe_experts > 0 and not spec.dense_mlp
         if routed and spec.experts_fields:
             from distributed_sigmoid_loss_tpu.models.moe import SharedExpertMoe
 
-            if style.mlp != "swiglu" or style.use_bias or self.quant:
+            if style.mlp not in ("swiglu", "relu2") or style.use_bias or self.quant:
                 raise ValueError(
-                    "moe_router='sigmoid' has the bias-free, unquantised SwiGLU experts only: "
+                    "moe_router='sigmoid' has the bias-free, unquantised SwiGLU or relu2 experts only: "
                     f"mlp={style.mlp!r}, use_bias={style.use_bias}, quant={self.quant!r} are not built"
                 )
             mlp = SharedExpertMoe(
                 width=self.width, num_experts=self.moe_experts, num_selected=self.moe_num_selected,
-                dtype=self.dtype, **dict(spec.experts_fields), name="moe",
+                dtype=self.dtype, kind=style.mlp, **dict(spec.experts_fields), name="moe",
             )
         elif routed:
             from distributed_sigmoid_loss_tpu.models.moe import MoeMlp
@@ -500,7 +587,7 @@ class Block(nn.Module):
                 self.width, self.mlp_ratio, self.dtype, quant=self.quant,
                 kind=style.mlp, use_bias=style.use_bias, name="mlp",
             )
-        x = x + post("ln2_post", mlp(norm("ln2")(x)))
+        x = x + post(ln_post, mlp(norm(ln)(x)))
         return x
 
 
@@ -727,7 +814,7 @@ class Encoder(nn.Module):
             f"sequence_parallel_axis={self.sp_axis!r}": self.sp_axis is not None,
             f"quant={self.quant!r}": bool(self.quant),
             f"rope_theta={self.style.rope_theta!r} (pos='rope')":
-                self.style.rope_theta is not None and "kda" in mixers,
+                self.style.rope_theta is not None and bool({"kda", "ssm"} & set(mixers)),
             "sandwich_norm=True": self.style.sandwich_norm,
             f"loops={self.loops}": self.loops > 1,
         }
@@ -735,7 +822,7 @@ class Encoder(nn.Module):
             raise ValueError(
                 f"mixers={mixers} (a recurrence, latent attention or windowed chunk "
                 "attention over one whole causal sequence, unquantised, a recurrence "
-                "with no position encoding) is not built for "
+                "with no position encoding; or a stack of one-sub-layer layers) is not built for "
                 + ", ".join(k for k, v in refused.items() if v)
             )
 

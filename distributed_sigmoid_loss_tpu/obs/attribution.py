@@ -319,8 +319,15 @@ def mixed_stack(step) -> dict | None:
     ``"flash"``: the library's blocked kernel; ``"dense"``: XLA), the query/key
     and value head sizes, the sizes the core ran them at and whether any head
     was zero-padded to them, the tokens a block of a fused core and the sequence
-    with the zero rows that fill its last block. None for a step that has not
-    traced yet or runs no such tower. :func:`mixed_stack_line` is the same on
+    with the zero rows that fill its last block; per state-space layer (``ssm``,
+    by layer index) the core ``ops/ssm.py ssm_core`` names with its chunk, chunks
+    a sequence, rows a pass, heads, groups, head size, state and ``kept_bytes``;
+    per attention layer with head sizes of its own (``attn``, by layer index)
+    what ``models/transformer.py attention_core`` says: the core, the query and
+    key / value heads, the head size, whether keys and values were repeated. A
+    stack of one-sub-layer layers (``TextConfig.sublayers="single"``) names
+    each layer's one kind in ``layer_kinds`` ("ssm", "attn", "moe"). None for a
+    step that has not traced yet or runs no such tower. :func:`mixed_stack_line` is the same on
     one line, which ``train`` prints once a run."""
     return dict(getattr(step, "stack_record", None) or {}) or None
 
@@ -341,6 +348,13 @@ def mixed_stack_line(record: dict | None) -> str | None:
         parts.append(f"eva[{i}] core={e['core']} {e['windows']} windows of {e['window']}, {e['summaries']} summaries{blocks}")
     if "scanned" in record:
         parts.append("scanned" if record["scanned"] else "unrolled")
+    for i, c in sorted(record.get("ssm", {}).items()):
+        parts.append(f"ssm[{i}] core={c['core']} {c['chunks']} chunks of {c['chunk']}, {c['heads']} heads of "
+                     f"{c['head_dim']} in {c['groups']} groups, state {c['state']}, {c['rows_per_pass']} rows a pass")
+    for i, a in sorted(record.get("attn", {}).items()):
+        blocks = "" if a["block"] is None else f", blocks of {a['block']} tokens"
+        repeated = " (keys and values repeated)" if a["kv_repeated"] else ""
+        parts.append(f"attn[{i}] core={a['core']} {a['heads']}/{a['kv_heads']} heads of {a['head_dim']}{repeated}{blocks}")
     for i, k in sorted(record.get("kda_core", {}).items()):
         kept = f", {k['kept_bytes'] / 1e6:.0f} MB kept for the backward" if k["kept_bytes"] else ""
         parts.append(f"kda[{i}] core={k['core']} qk_norm={k['qk_norm']} o_norm={k['o_norm']}, {k['chunks']} chunks{kept}")

@@ -119,7 +119,11 @@ HEALTH_EVENT_FIELDS = frozenset({"metric", "step", "event", "detail"})
 # line: what the step is made of, from shapes alone, beside the counters above
 # that say what the routing did. Field -> meaning.
 STACK_RECORD_FIELDS = {
-    "layer_kinds": "each layer as mixer+mlp or mixer+moe, in order",
+    "layer_kinds": "each layer as mixer+mlp or mixer+moe, in order; a one-sub-layer layer as its one kind (ssm, attn, moe)",
+    "ssm": "per state-space layer: the core it took (ops/ssm.py ssm_core: chunked), chunk, chunks a sequence, "
+           "rows_per_pass, heads, groups, head_dim, state, and the bytes a differentiated call keeps (kept_bytes)",
+    "attn": "per attention layer with head sizes of its own: the core it took (dense / short / flash / kernel), block, "
+            "heads, kv_heads, head_dim, and whether grouped keys and values were repeated for it (kv_repeated)",
     "experts_held": "routed experts this chip holds",
     "experts_total": "routed experts the router scores",
     "experts_per_token": "experts a token chooses",

@@ -169,18 +169,24 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_
     dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _sizes(q, v, h):
-    (b, s, _), dqk, dv = q.shape, q.shape[-1] // h, v.shape[-1] // h
+def _sizes(q, v, h, group=1):
+    (b, s, _), dqk, dv = q.shape, q.shape[-1] // h, v.shape[-1] // (h // group)
     return b, s, dqk, dv, latent_attention_plan(s, dqk, dv, q.dtype.itemsize)["block"]  # s is whole blocks here
 
 
-def _call(kernel, name, operands, outs, scratch, *, b, s, h, dqk, dv, block, products, interpret):
+def _call(kernel, name, operands, outs, scratch, *, b, s, h, dqk, dv, block, products, interpret, group=1):
     """One of the two kernels over the grid (rows, heads). ``operands`` and
     ``outs`` are (kind, array or shape): "qk" (b, s, h x dqk), "v" (b, s, h x
-    dv), "lse" (b, h, s / block, block)."""
+    dv), "lse" (b, h, s / block, block); "k_shared" and "v_shared", the keys and
+    values of grouped heads, (b, s, h / group x d): query head j reads head j //
+    ``group`` of them, where they lie (consecutive programs of a group name the
+    same block, which is then not fetched again)."""
     def spec(kind):
         if kind == "lse":
             return pl.BlockSpec((1, 1, s // block, block), lambda r, j: (r, j, 0, 0), memory_space=pltpu.VMEM)
+        if kind.endswith("_shared"):
+            return pl.BlockSpec((1, s, dqk if kind == "k_shared" else dv), lambda r, j: (r, 0, j // group),
+                                memory_space=pltpu.VMEM)
         return pl.BlockSpec((1, s, dqk if kind == "qk" else dv), lambda r, j: (r, 0, j), memory_space=pltpu.VMEM)
 
     moved = sum(x.size * x.dtype.itemsize for _, x in [*operands, *outs])
@@ -201,42 +207,52 @@ def _call(kernel, name, operands, outs, scratch, *, b, s, h, dqk, dv, block, pro
     )(*(x for _, x in operands))
 
 
-def _forward(q, k, v, h, scale, interpret):
-    b, s, dqk, dv, block = _sizes(q, v, h)
+def _forward(q, k, v, h, scale, interpret, group=1):
+    b, s, dqk, dv, block = _sizes(q, v, h, group)
+    k_kind, v_kind = ("k_shared", "v_shared") if group > 1 else ("qk", "v")
     o, lse = _call(
         functools.partial(_fwd_kernel, scale=scale, block=block), "mla_attn_fwd",
-        [("qk", q), ("qk", k), ("v", v)],
-        [("v", jax.ShapeDtypeStruct(v.shape, v.dtype)), ("lse", jax.ShapeDtypeStruct((b, h, s // block, block), F32))],
-        [], b=b, s=s, h=h, dqk=dqk, dv=dv, block=block, products=2, interpret=interpret)
+        [("qk", q), (k_kind, k), (v_kind, v)],
+        [("v", jax.ShapeDtypeStruct((b, s, h * dv), v.dtype)),
+         ("lse", jax.ShapeDtypeStruct((b, h, s // block, block), F32))],
+        [], b=b, s=s, h=h, dqk=dqk, dv=dv, block=block, products=2, interpret=interpret, group=group)
     return o, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _wide_kernel(q, k, v, h, scale, interpret):
-    return _forward(q, k, v, h, scale, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _wide_kernel(q, k, v, h, scale, interpret, group=1):
+    return _forward(q, k, v, h, scale, interpret, group)[0]
 
 
-def _vjp_fwd(q, k, v, h, scale, interpret):
-    o, lse = _forward(q, k, v, h, scale, interpret)
+def _vjp_fwd(q, k, v, h, scale, interpret, group=1):
+    o, lse = _forward(q, k, v, h, scale, interpret, group)
     return o, (q, k, v, o, lse)
 
 
-def _vjp_bwd(h, scale, interpret, residuals, do):
+def _vjp_bwd(h, scale, interpret, group, residuals, do):
     q, k, v, o, lse = residuals
-    b, s, dqk, dv, block = _sizes(q, v, h)
-    return tuple(_call(
+    b, s, dqk, dv, block = _sizes(q, v, h, group)
+    k_kind, v_kind = ("k_shared", "v_shared") if group > 1 else ("qk", "v")
+    # Grouped heads: dk and dv come out a QUERY head each (every program writes its own block)
+    # and a group's are summed outside; k and v themselves are read where they lie.
+    dq, dk, dv_ = _call(
         functools.partial(_bwd_kernel, scale=scale, block=block), "mla_attn_bwd",
-        [("qk", q), ("qk", k), ("v", v), ("v", o), ("v", do.astype(v.dtype)), ("lse", lse)],
-        [(kind, jax.ShapeDtypeStruct(x.shape, x.dtype)) for kind, x in (("qk", q), ("qk", k), ("v", v))],
+        [("qk", q), (k_kind, k), (v_kind, v), ("v", o), ("v", do.astype(v.dtype)), ("lse", lse)],
+        [("qk", jax.ShapeDtypeStruct(q.shape, q.dtype)), ("qk", jax.ShapeDtypeStruct((b, s, h * dqk), k.dtype)),
+         ("v", jax.ShapeDtypeStruct((b, s, h * dv), v.dtype))],
         [pltpu.VMEM((s, dqk), F32), pltpu.VMEM((s // block, block), F32)],  # dq of the head, di of its rows
-        b=b, s=s, h=h, dqk=dqk, dv=dv, block=block, products=5, interpret=interpret))
+        b=b, s=s, h=h, dqk=dqk, dv=dv, block=block, products=5, interpret=interpret, group=group)
+    if group > 1:
+        dk, dv_ = (x.astype(F32).reshape(b, s, h // group, group, -1).sum(3).reshape(like.shape).astype(like.dtype)
+                   for x, like in ((dk, k), (dv_, v)))
+    return dq, dk, dv_
 
 
 _wide_kernel.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 def latent_attention_kernel(q, k, v, *, head_dims: tuple[int, int], scale: float | None = None,
-                            interpret: bool = False):
+                            interpret: bool = False, kv_heads: int | None = None):
     """Causal self-attention through the kernel pair, the heads on the lanes as
     the kernels read them: q, k: (b, s, h x dqk) and v: (b, s, h x dv) with
     ``head_dims`` = (dqk, dv), a head an aligned window of the lanes. The number
@@ -248,14 +264,22 @@ def latent_attention_kernel(q, k, v, *, head_dims: tuple[int, int], scale: float
     padded row's cotangent is zero), the output cut back. Returns o in v's shape
     and dtype; ``scale`` defaults to dqk^-1/2. Differentiated, it saves its
     operands, o and the log-sum-exp column (b, h, s) float32.
-    ``interpret=True`` runs the Pallas interpreter (CPU testing)."""
+    ``interpret=True`` runs the Pallas interpreter (CPU testing).
+
+    ``kv_heads`` (default: every query head its own) is for grouped heads: k
+    and v then hold that many heads, (b, s, kv_heads x d), query head j reads
+    head j // (h / kv_heads) of them where they lie (nothing is repeated in
+    HBM), and o has the queries' heads. Backwards dk and dv leave the kernel a
+    query head each and a group's are summed in XLA."""
     (b, s, width), (dqk, dv) = q.shape, head_dims
     h = width // dqk
+    kv_heads = h if kv_heads is None else kv_heads
     plan = latent_attention_plan(s, dqk, dv, q.dtype.itemsize)
-    if plan is None or (k.shape[-1], v.shape[-1]) != (h * dqk, h * dv) or width % dqk:
+    if (plan is None or (k.shape[-1], v.shape[-1]) != (kv_heads * dqk, kv_heads * dv) or width % dqk
+            or kv_heads < 1 or h % kv_heads):
         raise ValueError(f"latent_attention_kernel: {q.shape}, {k.shape}, {v.shape} in heads of {dqk} / {dv} "
-                         "are not its shapes")
+                         f"({kv_heads} key / value heads) are not its shapes")
     scale = dqk**-0.5 if scale is None else scale
     if plan["tokens"] != s:
         q, k, v = (jnp.pad(x, ((0, 0), (0, plan["tokens"] - s), (0, 0))) for x in (q, k, v))
-    return _wide_kernel(q, k, v, h, scale, interpret)[:, :s]
+    return _wide_kernel(q, k, v, h, scale, interpret, h // kv_heads)[:, :s]

@@ -323,20 +323,31 @@ def _dropless_text(model):
 
 def _recorded_text(model):
     """The text configuration of a model whose step keeps a ``stack_record``:
-    a dropless routed stack, or one of windowed chunk attention; else None."""
+    a dropless routed stack, one of windowed chunk attention, one with a
+    state-space layer, of one-sub-layer layers or with attention heads of their
+    own sizes; else None."""
     t = getattr(getattr(model, "cfg", None), "text", None)
-    return t if t is not None and (_dropless_text(model) is not None or "eva" in t.mixers) else None
+    if t is None:
+        return None
+    recorded = (
+        _dropless_text(model) is not None or {"eva", "ssm"} & set(t.mixers) or t.sublayers == "single"
+        or t.num_kv_heads or t.head_dim
+    )
+    return t if recorded else None
 
 
 def stack_record_of(t, tokens_shape) -> dict:
-    """What a step with a dropless mixed text stack, or a stack of windowed
-    chunk attention, runs, from shapes alone (``step.stack_record``, read by
-    obs/attribution.py mixed_stack)."""
+    """What a step with a dropless mixed text stack, a stack of windowed chunk
+    attention, one with state-space layers or one of one-sub-layer layers runs,
+    from shapes alone (``step.stack_record``, read by obs/attribution.py
+    mixed_stack)."""
     routed = [bool(t.moe_experts) and i >= t.leading_dense_layers for i in range(t.depth)]
     mixers = t.mixers or ("attn",) * t.depth
     tokens = math.prod(tokens_shape)
     record = {
-        "layer_kinds": [f"{m}+{'moe' if r else 'mlp'}" for m, r in zip(mixers, routed)],
+        # A one-sub-layer layer is its one kind; a pair is mixer+mlp or mixer+moe.
+        "layer_kinds": list(mixers) if t.sublayers == "single"
+        else [f"{m}+{'moe' if r else 'mlp'}" for m, r in zip(mixers, routed)],
         "tokens_per_microbatch": tokens,
     }
     if t.moe_experts:
@@ -380,6 +391,25 @@ def stack_record_of(t, tokens_shape) -> dict:
             **sizes,
         }
         record["mla"] = {i: dict(made_of) for i, m in enumerate(mixers) if m == "mla"}
+    if "ssm" in mixers:
+        from distributed_sigmoid_loss_tpu.ops.ssm import ssm_core
+
+        # The sizes SsmMixer's call runs at (ops/ssm.py has one form): the core of each
+        # state-space layer, its chunk, the chunks a sequence, the rows a pass, heads,
+        # groups, head size and state, and the bytes a differentiated call keeps.
+        rows, length = tokens_shape
+        core = ssm_core(rows, length, t.ssm_num_heads, t.ssm_head_dim, t.ssm_groups, t.ssm_state, t.dtype, t.ssm_chunk)
+        record["ssm"] = {i: dict(core) for i, m in enumerate(mixers) if m == "ssm"}
+    if "attn" in mixers and (t.num_kv_heads or t.head_dim):
+        from distributed_sigmoid_loss_tpu.models.transformer import _dtype, attention_core
+
+        # By the rule Attention's call runs by: the core each attention layer with head
+        # sizes of its own takes, and whether grouped keys and values are repeated for it.
+        sizes = attention_core(
+            t.attn_impl, _dtype(t.dtype), tokens_shape[-1], t.num_heads, t.num_kv_heads or t.num_heads,
+            t.head_dim or t.width // t.num_heads, t.causal,
+        )
+        record["attn"] = {i: dict(sizes) for i, m in enumerate(mixers) if m == "attn"}
     if "eva" in mixers:
         from distributed_sigmoid_loss_tpu.models.mixers import eva_attention_core
         from distributed_sigmoid_loss_tpu.models.text import layer_specs
@@ -702,9 +732,47 @@ def init_params(
             ),
             out_shardings=unboxed_shardings,
         )()
-    return jax.jit(
+    params = jax.jit(
         lambda r: nn.meta.unbox(init_fn(r)), out_shardings=unboxed_shardings
     )(rng)
+    text = getattr(getattr(model, "cfg", None), "text", None)
+    if getattr(text, "moe_balanced_init", False):
+        params = balance_routers(rng, model, params, tokens[0], mesh, unboxed_shardings)
+    return params
+
+
+def balance_routers(rng: jax.Array, model: nn.Module, params: Any, tokens_shape, mesh: Mesh, shardings: Any) -> Any:
+    """``params`` with every sigmoid router's selection bias set so that its
+    experts are chosen evenly (``TextConfig.moe_balanced_init``): one forward
+    pass of the text tower over uniform token ids of ``tokens_shape``, in which
+    each routed layer, in its turn, finds its bias from the tokens that reach it
+    (models/moe.py balanced_select_bias) and routes by it, so a later layer
+    balances on what the balanced earlier ones hand it. The ids are drawn from
+    ``rng``; the bias follows the weights, which route every id's token nearly
+    alike, and another batch of such ids then loads each expert to a few percent
+    of the same count."""
+    from flax import traverse_util
+
+    from distributed_sigmoid_loss_tpu.models.moe import BALANCE
+
+    text = model.cfg.text
+    if text.moe_router != "sigmoid" or not text.moe_experts:
+        raise ValueError(
+            f"moe_balanced_init=True sets the selection bias of sigmoid-routed layers: "
+            f"moe_router={text.moe_router!r}, moe_experts={text.moe_experts} has none"
+        )
+    vocab = text.vocab_size
+
+    def balanced(params, key):
+        ids = jax.random.randint(key, tokens_shape, 0, vocab, jnp.int32)
+        with trace_on(mesh):
+            _, sown = model.apply({"params": params}, ids, method="encode_text", mutable=[BALANCE])
+        flat = traverse_util.flatten_dict(params)
+        for path, (bias,) in traverse_util.flatten_dict(sown[BALANCE]).items():
+            flat[path] = bias
+        return traverse_util.unflatten_dict(flat)
+
+    return jax.jit(balanced, out_shardings=shardings, donate_argnums=0)(params, jax.random.fold_in(rng, 1))
 
 
 def create_train_state(

@@ -161,8 +161,10 @@ class TextConfig:
     # A second norm on each sub-layer's OUTPUT, before the residual add:
     # x + norm(f(norm(x))), four norms a layer.
     sandwich_norm: bool = False
-    # "swiglu" = gated MLP, three matmuls: wo(silu(wg x) * (wi x)).
-    mlp: Literal["gelu", "swiglu"] = "gelu"
+    # "swiglu" = gated MLP, three matmuls: wo(silu(wg x) * (wi x)); "relu2" =
+    # ungated, two: wo(relu(wi x)^2). With ``moe_router="sigmoid"`` it is the
+    # routed and shared experts' kind too ("swiglu" or "relu2").
+    mlp: Literal["gelu", "swiglu", "relu2"] = "gelu"
     # False drops the bias of the blocks' attention and MLP projections.
     use_bias: bool = True
     # "rope" = rotary positions on q and k (rotate-half convention, positions
@@ -189,7 +191,9 @@ class TextConfig:
     # expanded from one low-rank latent, a key part shared by all heads, value
     # heads of their own width; models/mixers.py) or "eva" (softmax attention
     # that is exact inside ``eva_window`` tokens and reads every earlier window
-    # as one pooled key and value per ``eva_chunk`` tokens, under one softmax).
+    # as one pooled key and value per ``eva_chunk`` tokens, under one softmax) or
+    # "ssm" (a Mamba-2 state-space layer: a scalar-decay recurrence behind a short
+    # causal convolution, under a gated group norm; ops/ssm.py).
     # Empty = "attn" in every layer, today's stack. Such a stack is causal and
     # takes ``pos="none"`` (or "rope" where no layer is a recurrence; "eva"
     # takes "rope" alone). Unlike layers run unrolled with remat per layer (no
@@ -233,6 +237,40 @@ class TextConfig:
     # router keeps its ``moe_experts`` outputs and its top-k, this chip computes
     # its own experts' part and leaves out what the absent ones would add.
     moe_experts_held: int = 0
+    # The shared expert's own hidden width (one expert that wide, of the routed
+    # experts' kind); 0 = ``moe_shared_experts`` x ``moe_hidden``.
+    moe_shared_hidden: int = 0
+    # True: initialisation ends by setting every sigmoid router's selection bias
+    # to where the recipe's balancing update holds a trained router: on a batch of
+    # uniform token ids every expert is chosen by tokens x ``moe_num_selected`` /
+    # ``moe_experts`` tokens (train/train_step.py balance_routers). False leaves
+    # the bias zero: routers drawn at random route as unevenly as they fall. The
+    # step is the same either way.
+    moe_balanced_init: bool = False
+    # An "attn" layer's key / value heads (0 = ``num_heads``: every query head its
+    # own; else query head h reads key / value head h // (num_heads /
+    # num_kv_heads)) and its head size (0 = ``width // num_heads``; else the
+    # projections are width -> heads x head_dim and back).
+    num_kv_heads: int = 0
+    head_dim: int = 0
+    # "pair" = every layer is a token mixer AND a feed-forward part, each under
+    # its own norm (every block so far). "single" = every layer is ONE sub-layer,
+    # x + f(norm(x)) under one norm: ``mixers`` then names each layer's f, a
+    # mixer alone ("ssm", "attn": no feed-forward part) or the routed
+    # feed-forward part alone ("moe": no mixer). "ssm" is a layer of such a
+    # stack only.
+    sublayers: Literal["pair", "single"] = "pair"
+    # "ssm" (a Mamba-2 state-space layer, models/mixers.py SsmMixer): heads of
+    # ``ssm_head_dim`` channels in ``ssm_groups`` groups that share B and C of
+    # ``ssm_state`` channels, behind a causal depthwise convolution of
+    # ``ssm_conv_size`` taps; ``ssm_chunk`` tokens a chunk of the scan (how the
+    # recurrence is computed, not what it computes: ops/ssm.py).
+    ssm_num_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 8
+    ssm_conv_size: int = 4
+    ssm_chunk: int = 128
 
     def __post_init__(self):
         # A configuration file gives a list; modules hash their configuration.
@@ -258,7 +296,8 @@ BLOCK_OPTIONS = {
     "pos": "learned", "loops": 1, "norm_eps": 1e-6, "mixers": (),
     "leading_dense_layers": 0, "moe_router": "softmax", "moe_route_scale": 1.0,
     "moe_shared_experts": 0, "moe_hidden": 0, "moe_experts_held": 0, "mla_q_rank": 0,
-    "norm_unit_offset": False,
+    "norm_unit_offset": False, "moe_shared_hidden": 0, "num_kv_heads": 0, "head_dim": 0,
+    "sublayers": "pair",
 }
 
 

@@ -346,7 +346,9 @@ def test_the_presets_keep_their_parameter_trees(preset):
 
 NEW_OPTIONS = dict(mixers=("kda", "mla"), leading_dense_layers=1, norm_eps=1e-5, moe_router="sigmoid",
                    moe_route_scale=2.446, moe_shared_experts=1, moe_hidden=24, moe_experts_held=4, mla_q_rank=8,
-                   norm_unit_offset=True)  # the last one: an RMSNorm stored as an offset from 1 (tests/test_eva_tower.py)
+                   norm_unit_offset=True,  # an RMSNorm stored as an offset from 1 (tests/test_eva_tower.py)
+                   # a one-sub-layer stack's, grouped heads' and a shared expert's own width (tests/test_nemotron_tower.py)
+                   sublayers="single", num_kv_heads=1, head_dim=8, moe_shared_hidden=40)
 
 
 @pytest.mark.parametrize("option", sorted(NEW_OPTIONS))
@@ -369,7 +371,7 @@ def test_pipelined_towers_and_hf_import_refuse_the_new_options_by_name(option):
     (dict(causal=False), "causal=False"),
     (dict(loops=2), "loops=2"),
     (dict(mixers=("kda",)), "depth=5"),
-    (dict(mixers=("kda", "kda", "kda", "ssm", "kda")), "mixers"),
+    (dict(mixers=("kda", "kda", "kda", "gru", "kda")), "mixers"),
     (dict(mlp="gelu"), "moe_router='sigmoid'"),
 ])
 def test_what_a_mixed_stack_does_not_run_with_says_so_by_name(bad, match):

@@ -158,6 +158,30 @@ def test_latent_attentions_kernel_pair_compiles_for_a_v5e(one_chip, heads, token
     assert set(asked) == {"mla_attn_fwd", "mla_attn_bwd"} and max(asked.values()) <= plan["vmem_bytes"], (asked, plan)
 
 
+# The same pair under grouped heads, as ``Attention`` calls it: the one-sub-layer cell's call (32 query heads over 2 key /
+# value heads of 128 at 4096 tokens: k and v read where they lie, nothing repeated) and a small one padded to its block.
+@pytest.mark.parametrize("heads, kv_heads, tokens", [(32, 2, 4096), (4, 2, 300)], ids=["nemotron-cell", "padded-length"])
+def test_the_pair_under_grouped_heads_compiles_for_a_v5e(one_chip, heads, kv_heads, tokens):
+    def of(h):
+        return jax.ShapeDtypeStruct((2, tokens, h * 128), jnp.bfloat16, sharding=one_chip)
+
+    args = (of(heads), of(kv_heads), of(kv_heads))
+    core = lambda q, k, v: latent_attention_kernel(q, k, v, head_dims=(128, 128), kv_heads=kv_heads)  # noqa: E731
+    forward = jax.jit(core).lower(*args).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1 and forward.out_info.shape == args[0].shape
+
+    def loss(*a):
+        return (core(*a).astype(jnp.float32) ** 2).sum()
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    both = jax.jit(grads).lower(*args).compile()
+    assert both.as_text().count("tpu_custom_call") == 2
+    assert [x.shape for x in both.out_info] == [a.shape for a in args]  # a group's dk and dv summed to its one head
+    asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
+    plan = latent_attention_plan(tokens, 128, 128)
+    assert set(asked) == {"mla_attn_fwd", "mla_attn_bwd"} and max(asked.values()) <= plan["vmem_bytes"], (asked, plan)
+
+
 # Windowed chunk attention's core as the mixer calls it, the heads on the lanes: the cell's call (32 heads of 128, 8192
 # tokens in windows of 2048, chunks of 16: 512 summaries a sequence) and a small one (two windows of 256).
 @pytest.mark.parametrize("heads, tokens, window", [(32, 8192, 2048), (2, 512, 256)], ids=["evabyte-cell", "two-windows"])

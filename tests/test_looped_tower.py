@@ -311,6 +311,8 @@ CHANGED = dict(
     mla_q_rank=8,
     # an RMSNorm stored as an offset from 1 (tests/test_eva_tower.py)
     norm_unit_offset=True,
+    # a one-sub-layer stack's, grouped heads' and a shared expert's own width (tests/test_nemotron_tower.py)
+    sublayers="single", num_kv_heads=1, head_dim=8, moe_shared_hidden=40,
 )
 
 
