@@ -51,9 +51,9 @@ With x the (s, width) normalised stream of one sequence:
           y = y silu(z) ;  y = y rsqrt(mean over each group's h P / g lanes (y^2) + eps) w   # gated RMSNorm, the gate first
           out = y W_out
 
-The state-space recurrence runs as ``ops/ssm.py ssm_scan`` has it (one form,
-chunked in XLA; :func:`~distributed_sigmoid_loss_tpu.ops.ssm.ssm_core` is the
-record of its sizes); x', B, C and y stay (b, s, h x P) / (b, s, g x N) around it.
+The state-space recurrence runs as ``ops/ssm.py ssm_scan`` has it, in the form ``ssm_core`` there names from what the
+call sees: ``"kernel"`` (the Pallas pair ``ssd_fwd`` / ``ssd_bwd`` of ``ops/pallas_ssm.py``: bfloat16, a TPU, whole
+registers) or ``"chunked"`` (XLA); x', B, C and y stay (b, s, h x P) / (b, s, g x N) around either.
 
 Windowed chunk attention's core is one of two, by :func:`eva_attention_core`:
 ``"kernel"``, the Pallas pair ``eva_attn_fwd`` / ``eva_attn_bwd``
@@ -483,11 +483,16 @@ class CutDense(nn.Module):
 def gated_group_norm(y, z, scale, groups: int, eps: float):
     """The state-space layer's gated RMSNorm, in float32: the gate first, y
     silu(z), then each of the ``groups`` groups of lanes over its own root mean
-    square, times ``scale``. y, z: (b, s, inner); scale: (inner,)."""
-    b, s, inner = y.shape
-    y = (y.astype(F32) * nn.silu(z.astype(F32))).reshape(b, s, groups, inner // groups)
-    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + eps)
-    return y.reshape(b, s, inner) * scale
+    square, times ``scale``. y, z: (b, s, inner); scale: (inner,). A group's sum
+    of squares and its way back onto the group's lanes are products with a 0/1
+    matrix at full precision: a (b, s, groups, inner / groups) view of an array
+    whose lanes hold (groups x width) is a relayout through HBM on a TPU."""
+    inner = y.shape[-1]
+    member = (np.arange(inner)[:, None] // (inner // groups) == np.arange(groups)[None, :]).astype(np.float32)
+    y = y.astype(F32) * nn.silu(z.astype(F32))
+    hi = jax.lax.Precision.HIGHEST
+    mean_sq = jnp.einsum("bsi,ig->bsg", y * y, member, precision=hi) * (groups / inner)
+    return y * jnp.einsum("bsg,ig->bsi", jax.lax.rsqrt(mean_sq + eps), member, precision=hi) * scale
 
 
 class SsmMixer(nn.Module):

@@ -349,8 +349,9 @@ def mixed_stack_line(record: dict | None) -> str | None:
     if "scanned" in record:
         parts.append("scanned" if record["scanned"] else "unrolled")
     for i, c in sorted(record.get("ssm", {}).items()):
+        kept = f", {c['kept_bytes'] / 1e6:.0f} MB kept for the backward" if c["kept_bytes"] else ""
         parts.append(f"ssm[{i}] core={c['core']} {c['chunks']} chunks of {c['chunk']}, {c['heads']} heads of "
-                     f"{c['head_dim']} in {c['groups']} groups, state {c['state']}, {c['rows_per_pass']} rows a pass")
+                     f"{c['head_dim']} in {c['groups']} groups, state {c['state']}, {c['rows_per_pass']} rows a pass{kept}")
     for i, a in sorted(record.get("attn", {}).items()):
         blocks = "" if a["block"] is None else f", blocks of {a['block']} tokens"
         repeated = " (keys and values repeated)" if a["kv_repeated"] else ""

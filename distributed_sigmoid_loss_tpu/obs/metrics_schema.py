@@ -120,7 +120,7 @@ HEALTH_EVENT_FIELDS = frozenset({"metric", "step", "event", "detail"})
 # that say what the routing did. Field -> meaning.
 STACK_RECORD_FIELDS = {
     "layer_kinds": "each layer as mixer+mlp or mixer+moe, in order; a one-sub-layer layer as its one kind (ssm, attn, moe)",
-    "ssm": "per state-space layer: the core it took (ops/ssm.py ssm_core: chunked), chunk, chunks a sequence, "
+    "ssm": "per state-space layer: the core it took (ops/ssm.py ssm_core: kernel or chunked), chunk, chunks a sequence, "
            "rows_per_pass, heads, groups, head_dim, state, and the bytes a differentiated call keeps (kept_bytes)",
     "attn": "per attention layer with head sizes of its own: the core it took (dense / short / flash / kernel), block, "
             "heads, kv_heads, head_dim, and whether grouped keys and values were repeated for it (kv_repeated)",

@@ -24,19 +24,26 @@ state crosses chunks in a scan of s / L steps. A decay is a scalar a head, so
 exp(G_t - G_i) for i <= t is one exponent <= 0: nothing overflows and nothing is
 factored. Any chunking gives the same y: L is how it is computed, not what.
 
-**The form that runs** is one, ``"chunked"`` (:func:`ssm_core` is the record of
-a call's sizes, not a choice): XLA operations on operands that stay (b, s, h x P) /
-(b, s, g x N) up to the core, products in ``dtype`` with float32 accumulation,
-decays, sums and the carried state in float32, no (s x s) array (the largest
-intermediate is a chunk's L x L scores a head), its backward ``jax.grad``'s,
-recomputed (``jax.checkpoint``: a differentiated call keeps its operands and
-nothing else) and run a few batch rows at a time (``lax.map``) so that a pass's
-float32 scores stay under ``_PASS_BYTES``. A kernel pair would make
-:func:`ssm_core` a rule with two outcomes, as ``delta_rule_core`` is.
+**The form that runs** is one of two, by :func:`ssm_core`, a rule from what the
+call can see, as ``delta_rule_core`` is. ``"kernel"``: the Pallas pair
+``ssd_fwd`` / ``ssd_bwd`` (``ops/pallas_ssm.py``) for bfloat16 operands on a TPU
+at whole-register shapes: a chunk of one group lives in VMEM from x, B, C, dt to
+y, a head's (L, L) decays and mixed scores never reach HBM, the state crosses
+chunks in VMEM scratch, all rows of the batch in one call; a differentiated call
+keeps each chunk's incoming state for its backward. ``"chunked"``, everywhere
+else (float32, the CPU, odd shapes): XLA operations on operands that stay (b, s,
+h x P) / (b, s, g x N) up to the core, products in ``dtype`` with float32
+accumulation, decays, sums and the carried state in float32, no (s x s) array
+(the largest intermediate is a chunk's L x L scores a head), its backward
+``jax.grad``'s, recomputed (``jax.checkpoint``: a differentiated call keeps its
+operands and nothing else) and run a few batch rows at a time (``lax.map``) so
+that a pass's float32 scores stay under ``_PASS_BYTES``. Both round where the
+other does: products on operands of ``dtype``, everything else float32.
 """
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -124,28 +131,51 @@ def _rows_per_pass(b: int, s: int, h: int, chunk: int) -> int:
 
 
 def ssm_core(rows: int, tokens: int, heads: int, head_dim: int, groups: int, state: int, dtype, chunk: int = 128) -> dict:
-    """The sizes a call of :func:`ssm_scan` runs at (there is one form, so
-    nothing is chosen here): ``core`` (``"chunked"``: the XLA form above),
-    ``chunk`` (tokens a chunk: the configuration's, or the whole sequence
-    where that is shorter), ``chunks`` a sequence (its last one
-    zero-padded), ``rows_per_pass`` (batch rows one pass of the form holds),
-    ``heads``, ``groups``, ``head_dim``, ``state`` and ``kept_bytes``, what a
+    """Which form a call of :func:`ssm_scan` takes, from what it can see, and the
+    sizes it runs at: ``core`` is ``"kernel"`` (the Pallas pair of
+    ``ops/pallas_ssm.py``: bfloat16 operands, a TPU backend, and whole
+    registers: the chunk and the state multiples of 128, a group's heads x
+    head_dim a multiple of 128 and head_dim a divisor or a multiple of 128, so
+    that every head lies in whole 128-lane columns or shares one evenly) or
+    ``"chunked"`` (the XLA form above); ``chunk`` (tokens a chunk: the
+    configuration's, or the whole sequence where that is shorter), ``chunks`` a
+    sequence (its last one zero-padded), ``rows_per_pass`` (batch rows one pass
+    holds: all of them on the kernel path, which has no (rows, chunks, heads, L,
+    L) array; what fits ``_PASS_BYTES`` on the chunked one), ``heads``,
+    ``groups``, ``head_dim``, ``state`` and ``kept_bytes``, what a
     differentiated call keeps from its forward to its backward beside its
-    operands: 0, the form runs its forward again. The mixer runs what this says
-    and the step's trace-time record (``train_step.stack_record_of``) reports it."""
+    operands and y: each chunk's incoming state on the kernel path
+    (``pallas_ssm.kept_for_backward``: float32, rows x chunks x state x heads x
+    head_dim), 0 on the chunked one, which runs its forward again. The mixer runs
+    what this says and the step's trace-time record
+    (``train_step.stack_record_of``) reports it."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention  # the towers' one question about the backend
+
     chunk = min(chunk, tokens)
     chunks = -(-tokens // chunk)
-    return {"core": "chunked", "chunk": chunk, "chunks": chunks,
-            "rows_per_pass": _rows_per_pass(rows, chunks * chunk, heads, chunk),
-            "heads": heads, "groups": groups, "head_dim": head_dim, "state": state, "kept_bytes": 0}
+    per_group = heads // groups * head_dim
+    kernel = (
+        jnp.dtype(dtype) == jnp.bfloat16 and flash_attention.flash_attention_available()
+        and chunk % 128 == 0 and state % 128 == 0 and per_group % 128 == 0
+        and (head_dim % 128 == 0 or 128 % head_dim == 0)
+    )
+    kept = 0
+    if kernel:
+        from distributed_sigmoid_loss_tpu.ops.pallas_ssm import kept_for_backward
+
+        kept = sum(math.prod(x.shape) * x.dtype.itemsize
+                   for _, x in kept_for_backward(rows, chunks * chunk, heads, head_dim, state, chunk))
+    return {"core": "kernel" if kernel else "chunked", "chunk": chunk, "chunks": chunks,
+            "rows_per_pass": rows if kernel else _rows_per_pass(rows, chunks * chunk, heads, chunk),
+            "heads": heads, "groups": groups, "head_dim": head_dim, "state": state, "kept_bytes": kept}
 
 
 def ssm_scan(x, B, C, dt, A, D, *, heads: int, groups: int, chunk: int = 128, dtype=None):
-    """y of the recurrence above, chunked: the mixer's call. x: (b, s, heads x
-    P); B, C: (b, s, groups x N); dt: (b, s, heads) float32 > 0; A, D: (heads,)
-    float32, A < 0. ``dtype`` is the operand type of the chunk's matrix products
-    (default: x's); decays, sums and the carried state are float32. Returns (b,
-    s, heads x P) float32."""
+    """y of the recurrence above, chunked, in the form :func:`ssm_core` names:
+    the mixer's call. x: (b, s, heads x P); B, C: (b, s, groups x N); dt: (b, s,
+    heads) float32 > 0; A, D: (heads,) float32, A < 0. ``dtype`` is the operand
+    type of the chunk's matrix products (default: x's); decays, sums and the
+    carried state are float32. Returns (b, s, heads x P) float32."""
     dt_ = jnp.dtype(dtype or x.dtype)
     b, s, inner = x.shape
     if heads % groups or inner % heads or B.shape[-1] % groups or B.shape != C.shape:
@@ -154,6 +184,13 @@ def ssm_scan(x, B, C, dt, A, D, *, heads: int, groups: int, chunk: int = 128, dt
     pad = plan["chunks"] * plan["chunk"] - s
     if pad:  # dt = 0 and x = 0: a padded token neither decays nor writes, and none precedes a real one
         x, B, C, dt = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (x, B, C, dt))
+    if plan["core"] == "kernel":
+        from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import kernels_per_shard
+        from distributed_sigmoid_loss_tpu.ops.pallas_ssm import ssd_kernel
+
+        # rows over dp under a jit over several chips; every shard runs all heads (A and D are whole)
+        kernel = lambda *wide: ssd_kernel(*wide, A, D, heads=heads, groups=groups, chunk=plan["chunk"])  # noqa: E731
+        return kernels_per_shard(kernel, 1, x.astype(dt_), B.astype(dt_), C.astype(dt_), dt)[:, :s]
     core = jax.checkpoint(partial(_chunked, heads=heads, groups=groups, chunk=plan["chunk"], dt_=dt_))
     rows = plan["rows_per_pass"]
     if rows == b:

@@ -394,7 +394,7 @@ def stack_record_of(t, tokens_shape) -> dict:
     if "ssm" in mixers:
         from distributed_sigmoid_loss_tpu.ops.ssm import ssm_core
 
-        # The sizes SsmMixer's call runs at (ops/ssm.py has one form): the core of each
+        # What SsmMixer's call runs (ops/ssm.py ssm_core, kernel or chunked): the core of each
         # state-space layer, its chunk, the chunks a sequence, the rows a pass, heads,
         # groups, head size and state, and the bytes a differentiated call keeps.
         rows, length = tokens_shape

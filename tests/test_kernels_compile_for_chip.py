@@ -1,8 +1,9 @@
-"""The delta-rule kernels and latent attention's kernel pair compiled by the chip's
-own compiler for a TPU v5e that is described and not attached, at the two routed
-cells' widths: what Mosaic refuses
-(a slice off the tiling, too much VMEM) the interpreter accepts, so the CPU
-tests of tests/test_hybrid_layers.py cannot see it. Nothing runs. Every test
+"""The delta-rule kernels, latent attention's kernel pair, windowed chunk
+attention's and the state-space recurrence's compiled by the chip's own compiler
+for a TPU v5e that is described and not attached, at the cells' widths: what
+Mosaic refuses (a slice off the tiling, too much VMEM) the interpreter accepts,
+so the CPU tests of tests/test_hybrid_layers.py and tests/test_nemotron_tower.py
+cannot see it. Nothing runs. Every test
 that describes a topology lives in this one file (one worker loads the TPU's
 library, inside a fixture, never at import)."""
 
@@ -206,3 +207,31 @@ def test_windowed_chunk_attentions_kernel_pair_compiles_for_a_v5e(one_chip, head
     assert [x.shape for x in both.out_info] == [a.shape for a in args]
     asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
     assert set(asked) == {"eva_attn_fwd", "eva_attn_bwd"} and max(asked.values()) < 32 * 2**20, asked
+
+
+# The state-space recurrence's pair as ``ssm_scan`` calls it, the heads and groups on the lanes: the cell's call (4 rows of
+# 4096 tokens, 64 heads of 64 in 8 groups, state 128: a program holds a chunk of 128 tokens of one group's 512 lanes) and a
+# two-chunk call of one group of two heads (its dt blocks are two lanes and two sublanes wide).
+@pytest.mark.parametrize("rows, tokens, heads, groups", [(4, 4096, 64, 8), (2, 256, 2, 1)], ids=["nemotron-cell", "two-chunks"])
+def test_the_state_space_kernel_pair_compiles_for_a_v5e(one_chip, rows, tokens, heads, groups):
+    from distributed_sigmoid_loss_tpu.ops.pallas_ssm import ssd_kernel
+
+    def of(width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((rows, tokens, width), dtype, sharding=one_chip)
+
+    per_head = jax.ShapeDtypeStruct((heads,), jnp.float32, sharding=one_chip)
+    args = (of(heads * 64), of(groups * 128), of(groups * 128), of(heads, jnp.float32), per_head, per_head)
+    core = lambda *a: ssd_kernel(*a, heads=heads, groups=groups, chunk=128)  # noqa: E731
+    forward = jax.jit(core).lower(*args).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1
+    assert (forward.out_info.shape, forward.out_info.dtype) == ((rows, tokens, heads * 64), jnp.float32)
+
+    def loss(*a):
+        return (core(*a) ** 2).sum()
+
+    grads = jax.grad(loss, argnums=tuple(range(6)))
+    both = jax.jit(grads).lower(*args).compile()
+    assert both.as_text().count("tpu_custom_call") == 2  # ssd_fwd writing each chunk's incoming state, ssd_bwd
+    assert [(x.shape, x.dtype) for x in both.out_info] == [(a.shape, a.dtype) for a in args]
+    asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
+    assert set(asked) == {"ssd_fwd", "ssd_bwd"} and max(asked.values()) < MOSAIC_VMEM_LIMIT // 2, asked

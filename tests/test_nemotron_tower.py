@@ -142,6 +142,199 @@ def test_the_core_refuses_shapes_that_do_not_cut_into_heads_and_groups():
         ssm_scan(x, big_b, big_c, dt, rate, skip, heads=H, groups=3)
 
 
+# -- (a') the Pallas pair (ops/pallas_ssm.py) in the interpreter, at a small whole-register shape ------
+
+KB, KS, KH, KG, KP, KN, KL = 2, 256, 4, 2, 64, 128, 128  # 2 rows x 256 tokens x 4 heads of 64 in 2 groups, state 128, chunks of 128
+
+
+def kernel_operands(seed=0, s=KS, dtype=jnp.float32):
+    """As the mixer's: steps of a tenth of a token's decay time, B over sqrt(N)."""
+    k = jax.random.split(jax.random.key(seed), 6)
+    x, big_b, big_c = (jax.random.normal(k[i], (KB, s, w)).astype(dtype) for i, w in enumerate((KH * KP, KG * KN, KG * KN)))
+    dt = jax.nn.softplus(jax.random.normal(k[3], (KB, s, KH)) - 2.0)
+    return x, big_b * KN**-0.5, big_c, dt, -jnp.exp(jax.random.normal(k[4], (KH,))), jax.random.normal(k[5], (KH,))
+
+
+def interpreted_pair():
+    from distributed_sigmoid_loss_tpu.ops.pallas_ssm import ssd_kernel
+
+    return partial(ssd_kernel, heads=KH, groups=KG, chunk=KL, interpret=True)
+
+
+def l2_error(got, want):
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def test_the_kernel_pairs_y_is_the_recurrences():
+    """Two chunks a row, so the state crosses one boundary in scratch; two heads
+    of 64 share each 128-lane column."""
+    args = kernel_operands()
+    want = ssm_recurrent(*args, heads=KH, groups=KG)
+    with jax.default_matmul_precision("highest"):
+        got = interpreted_pair()(*args)
+    assert got.shape == want.shape == (KB, KS, KH * KP) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-5)
+
+
+@pytest.fixture(scope="module")
+def kernel_gradients():
+    args = kernel_operands(seed=1)
+    weights = jax.random.normal(jax.random.key(9), (KB, KS, KH * KP))
+
+    def loss(core):
+        return lambda *a: jnp.sum(core(*a) * weights)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(loss(partial(ssm_recurrent, heads=KH, groups=KG)), argnums=range(6))(*args)
+        got = jax.grad(loss(interpreted_pair()), argnums=range(6))(*args)
+    return want, got, args
+
+
+@pytest.mark.parametrize("operand", OPERANDS)
+def test_the_kernel_pairs_backward_is_jax_grad_of_the_recurrence(kernel_gradients, operand):
+    want, got, args = kernel_gradients
+    i = OPERANDS.index(operand)
+    assert got[i].shape == args[i].shape and got[i].dtype == args[i].dtype
+    assert np.abs(np.asarray(want[i])).max() > 1e-2
+    np.testing.assert_allclose(got[i], want[i], atol=2e-5 * float(np.abs(np.asarray(want[i])).max()))
+
+
+def test_the_kernel_pair_without_decay_is_a_running_sum():
+    """A = 0 (the benchmark's ``no_decay`` control passes it): every exponent is
+    0, the state only grows, and the gradients stay what ``jax.grad`` says."""
+    x, big_b, big_c, dt, rate, skip = kernel_operands(seed=2)
+    args = (x, big_b, big_c, dt, jnp.zeros_like(rate), skip)
+    with jax.default_matmul_precision("highest"):
+        want, want_grads = jax.value_and_grad(lambda *a: jnp.sum(ssm_recurrent(*a, heads=KH, groups=KG) ** 2), (0, 3, 4))(*args)
+        got, got_grads = jax.value_and_grad(lambda *a: jnp.sum(interpreted_pair()(*a) ** 2), (0, 3, 4))(*args)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for g, w in zip(got_grads, want_grads):
+        assert np.isfinite(np.asarray(g)).all() and l2_error(g, w) < 1e-4
+
+
+@pytest.fixture()
+def kernel_path_on_the_cpu(monkeypatch):
+    """``ssm_scan`` as on a TPU, its kernels in the interpreter; the shapes they were given."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_ssm
+
+    seen, real = [], pallas_ssm.ssd_kernel
+
+    def interpreted(x, *a, **kw):
+        seen.append(x.shape)
+        return real(x, *a, interpret=True, **kw)
+
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
+    monkeypatch.setattr(pallas_ssm, "ssd_kernel", interpreted)
+    return seen
+
+
+def test_a_sequence_that_is_no_multiple_of_the_chunk_is_padded_for_the_kernels(kernel_path_on_the_cpu):
+    """200 tokens: the second chunk's last 56 are x = 0, dt = 0, which neither
+    decay nor write; their outputs and cotangents are cut off."""
+    args = kernel_operands(seed=3, s=200, dtype=jnp.bfloat16)
+    weights = jax.random.normal(jax.random.key(9), (KB, 200, KH * KP))
+
+    def loss(core):
+        return lambda *a: jnp.sum(core(*a) * weights)
+
+    got = ssm_scan(*args, heads=KH, groups=KG, chunk=KL)
+    assert kernel_path_on_the_cpu == [(KB, 256, KH * KP)] and got.shape == (KB, 200, KH * KP) and got.dtype == jnp.float32
+    want = ssm_recurrent(*args, heads=KH, groups=KG)
+    assert l2_error(got, want) < 5e-3  # bfloat16 operands
+    got_grads = jax.grad(loss(partial(ssm_scan, heads=KH, groups=KG, chunk=KL)), argnums=range(6))(*args)
+    want_grads = jax.grad(loss(partial(ssm_recurrent, heads=KH, groups=KG)), argnums=range(6))(*args)
+    for name, g, w, a in zip(OPERANDS, got_grads, want_grads, args):
+        assert g.shape == a.shape and g.dtype == a.dtype and l2_error(g, w) < 2e-2, name
+
+
+@pytest.fixture(scope="module")
+def pair_beside_chunked():
+    """Both forms on the same bfloat16 operands: value and the six gradients."""
+    from distributed_sigmoid_loss_tpu.ops.ssm import _chunked
+
+    args = kernel_operands(seed=4, dtype=jnp.bfloat16)
+    weights = jax.random.normal(jax.random.key(9), (KB, KS, KH * KP))
+    chunked = partial(_chunked, heads=KH, groups=KG, chunk=KL, dt_=jnp.dtype(jnp.bfloat16))
+    out = {}
+    for name, core in (("kernel", interpreted_pair()), ("chunked", chunked)):
+        out[name] = jax.value_and_grad(lambda *a, core=core: jnp.sum(core(*a) * weights), argnums=range(6))(*args)
+        out[name + "_y"] = core(*args)
+    return out
+
+
+@pytest.mark.parametrize("what", ("y",) + OPERANDS)
+def test_the_kernel_pair_rounds_where_the_chunked_form_does(pair_beside_chunked, what):
+    """Products on bfloat16 operands with float32 accumulation, the mixed scores
+    and the decayed x rounded before their products, everything else float32, in
+    both: far inside the cell's limits (2.5e-2 on a row, 6.5e-1 on a leaf)."""
+    if what == "y":
+        assert l2_error(pair_beside_chunked["kernel_y"], pair_beside_chunked["chunked_y"]) < 1e-3
+        return
+    i = OPERANDS.index(what)
+    got, want = pair_beside_chunked["kernel"][1][i], pair_beside_chunked["chunked"][1][i]
+    assert got.dtype == want.dtype and l2_error(got, want) < 1e-2
+
+
+@pytest.mark.parametrize("dtype, tpu, chunk, tokens, state, heads, head_dim, groups, core", [
+    (jnp.bfloat16, True, 128, 4096, 128, 64, 64, 8, "kernel"),  # the cell's call
+    (jnp.bfloat16, True, 128, 256, 128, 4, 64, 2, "kernel"),  # the tests' shape: two heads share a column
+    (jnp.bfloat16, True, 256, 4096, 256, 8, 128, 8, "kernel"),  # a head a column
+    (jnp.bfloat16, True, 128, 4096, 128, 8, 256, 4, "kernel"),  # a head two columns
+    (jnp.float32, True, 128, 4096, 128, 64, 64, 8, "chunked"),  # float32 is XLA's
+    (jnp.bfloat16, False, 128, 4096, 128, 64, 64, 8, "chunked"),  # no TPU
+    (jnp.bfloat16, True, 64, 4096, 128, 64, 64, 8, "chunked"),  # half a register of tokens
+    (jnp.bfloat16, True, 128, 100, 128, 64, 64, 8, "chunked"),  # a sequence shorter than a chunk is one odd chunk
+    (jnp.bfloat16, True, 128, 4096, 64, 64, 64, 8, "chunked"),  # half a register of state
+    (jnp.bfloat16, True, 128, 4096, 128, 8, 64, 8, "chunked"),  # a group of 64 lanes
+    (jnp.bfloat16, True, 128, 4096, 128, 16, 96, 4, "chunked"),  # heads that straddle columns unevenly
+])
+def test_which_core_the_recurrence_takes_follows_from_what_the_call_can_see(monkeypatch, dtype, tpu, chunk, tokens, state,
+                                                                           heads, head_dim, groups, core):
+    from distributed_sigmoid_loss_tpu.ops import flash_attention
+    from distributed_sigmoid_loss_tpu.ops.ssm import _rows_per_pass
+
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: tpu)
+    plan = ssm_core(4, tokens, heads, head_dim, groups, state, dtype, chunk)
+    length = min(chunk, tokens)
+    chunks = -(-tokens // length)
+    assert plan == {
+        "core": core, "chunk": length, "chunks": chunks, "heads": heads, "groups": groups, "head_dim": head_dim, "state": state,
+        # the kernels hold no (rows, chunks, heads, L, L) array: all rows in one call, and each chunk's incoming state kept
+        "rows_per_pass": 4 if core == "kernel" else _rows_per_pass(4, chunks * length, heads, length),
+        "kept_bytes": 4 * chunks * state * heads * head_dim * 4 if core == "kernel" else 0}
+
+
+def test_the_recurrences_kernels_sit_in_a_shard_map_under_a_jit_over_several_chips(kernel_path_on_the_cpu):
+    """As the delta rule's: traced on a mesh the pair sees a chip's rows (every
+    head: A and D are whole), bare without one; the same values and gradients."""
+    import contextlib
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_sigmoid_loss_tpu.parallel.mesh import make_mesh, trace_on
+
+    mesh = make_mesh(2)
+    one = kernel_operands(seed=5, dtype=jnp.bfloat16)
+    args = tuple(jax.device_put(jnp.concatenate([t, t[::-1]]), NamedSharding(mesh, P("dp"))) for t in one[:4]) + one[4:]
+
+    def grads(on_mesh):
+        def loss(*a):
+            with trace_on(mesh) if on_mesh else contextlib.nullcontext():
+                return (ssm_scan(*a, heads=KH, groups=KG, chunk=KL) ** 2).sum()
+        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6))))(*args)
+
+    want, want_grads = grads(False)
+    assert set(kernel_path_on_the_cpu) == {(4, KS, KH * KP)}
+    kernel_path_on_the_cpu.clear()
+    got, got_grads = grads(True)
+    assert set(kernel_path_on_the_cpu) == {(2, KS, KH * KP)}  # a chip's rows
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for name, g, w in zip(OPERANDS, got_grads, want_grads):
+        assert l2_error(g, w) < 1e-5, name
+    assert got_grads[0].sharding.spec == P("dp")
+
+
 # -- (b) the layers against the reference --------------------------------------------------
 
 
@@ -163,6 +356,26 @@ def test_the_state_space_layer_matches_the_reference():
     for leaf in ("conv_bias", "D", "norm", "dt_bias", "A_log"):
         other = layer.apply({"params": {**params, leaf: params[leaf] + 0.3}}, x)
         assert float(jnp.abs(other - got).max()) > 1e-3, leaf
+
+
+def test_the_gated_norms_sums_over_a_groups_lanes_are_those_of_the_view():
+    """The norm takes each group's mean square by a 0/1 product on (b, s, inner),
+    never a (b, s, groups, width) view: the same values and gradients."""
+    from distributed_sigmoid_loss_tpu.models.mixers import gated_group_norm
+
+    k = jax.random.split(jax.random.key(0), 3)
+    y, z, scale = jax.random.normal(k[0], (2, 5, 24)), jax.random.normal(k[1], (2, 5, 24)), 1.0 + jax.random.normal(k[2], (24,))
+
+    def by_view(y, z, scale):
+        gated = (y * nn.silu(z)).reshape(2, 5, 3, 8)
+        return (gated * jax.lax.rsqrt(jnp.mean(gated * gated, -1, keepdims=True) + 1e-5)).reshape(2, 5, 24) * scale
+
+    np.testing.assert_allclose(gated_group_norm(y, z, scale, 3, 1e-5), by_view(y, z, scale), atol=1e-6)
+    got = jax.grad(lambda *a: jnp.sum(gated_group_norm(*a, 3, 1e-5) ** 3), (0, 1, 2))(y, z, scale)
+    want = jax.grad(lambda *a: jnp.sum(by_view(*a) ** 3), (0, 1, 2))(y, z, scale)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+    assert "reshape" not in str(jax.make_jaxpr(lambda *a: gated_group_norm(*a, 3, 1e-5))(y, z, scale))
 
 
 def test_the_state_space_layers_leaves_start_as_mamba_2s():
@@ -609,8 +822,9 @@ def cell_config(name=CELL):
 
 def test_the_record_of_the_cells_stack_on_a_tpu(monkeypatch):
     """By the rules the layers run by: on a TPU in bf16 the cell's three state-space
-    layers take the chunked form, 32 chunks of 128 a sequence, one row a pass, and
-    its attention layer the kernel pair with nothing repeated."""
+    layers take the kernel pair, 32 chunks of 128 a sequence, a microbatch's four rows
+    in one call, each chunk's incoming state kept for the backward (268 MB a call),
+    and its attention layer the kernel pair with nothing repeated."""
     from distributed_sigmoid_loss_tpu.obs.attribution import mixed_stack_line
     from distributed_sigmoid_loss_tpu.ops import flash_attention
     from distributed_sigmoid_loss_tpu.train.train_step import stack_record_of
@@ -620,13 +834,14 @@ def test_the_record_of_the_cells_stack_on_a_tpu(monkeypatch):
     record = stack_record_of(cfg.text, (4, 4096))
     assert record["layer_kinds"] == ["ssm", "moe", "ssm", "moe", "ssm", "attn", "moe"]
     assert sorted(record["ssm"]) == [0, 2, 4] and record["ssm"][0] == {
-        "core": "chunked", "chunk": 128, "chunks": 32, "rows_per_pass": 1, "heads": 64, "groups": 8, "head_dim": 64,
-        "state": 128, "kept_bytes": 0}
+        "core": "kernel", "chunk": 128, "chunks": 32, "rows_per_pass": 4, "heads": 64, "groups": 8, "head_dim": 64,
+        "state": 128, "kept_bytes": 4 * 32 * 128 * 64 * 64 * 4}
     assert record["attn"] == {5: {"core": "kernel", "block": 512, "heads": 32, "kv_heads": 2, "head_dim": 128, "kv_repeated": False}}
     assert (record["experts_held"], record["experts_total"], record["expected_local_assignments_per_token"]) == (8, 128, 0.375)
     assert record["tokens_per_microbatch"] == 16384 and record["dispatch_rows_bound"] == 16384 * 6
     line = mixed_stack_line(record)
-    assert "ssm[4] core=chunked 32 chunks of 128, 64 heads of 64 in 8 groups, state 128, 1 rows a pass" in line
+    assert ("ssm[4] core=kernel 32 chunks of 128, 64 heads of 64 in 8 groups, state 128, 4 rows a pass, "
+            "268 MB kept for the backward") in line
     assert "attn[5] core=kernel 32/2 heads of 128, blocks of 512 tokens" in line
     # the cells the benchmark had keep their records: no state-space or attention entry
     for other, shape in (("kimi-b16-p64-s1024", (16, 1024)), ("glm-b16-p16-s4096", (4, 4096))):
