@@ -284,6 +284,15 @@ class MoeMlp(nn.Module):
 # blocks of rows, as many blocks as its load needs (one loop over all of them,
 # whose trip count the device reads). The rows are bounded by T * k, the true worst case, and
 # only the index arrays have that size: tokens are gathered a block at a time.
+#
+# The float32 sums the loops scatter a block's rows into (y forward, dx backward)
+# are carried as (T, n, 8, 128), n = ceil(d / 1024), a token's row in n whole
+# tiles with zeros after lane d, and become (T, d) once, after the loop. The chip
+# keeps float32 in (8, 128) tiles: of a 2-D (T, d) sum a tile holds 128 lanes of
+# EIGHT tokens, and the compiler's scatter reads and writes all eight rows to add
+# one (0.23-0.35 ms a block of 512 rows: PERF.md section 6, PR 45). With the token
+# on the leading axis a scattered row moves its own tiles. The additions and their
+# order are a 2-D sum's, bit for bit (tests/test_moe.py keeps that form as oracle).
 
 SELECT_BIAS = "select_bias"
 # The collection whose being mutable makes a pass the initialisation's balancing
@@ -380,9 +389,37 @@ def _gather_rows(x, tok):
         return x[tok]
 
 
-def _scatter_add_rows(y, to, rows):
+TILE = (8, 128)  # sublanes x lanes of one float32 tile
+
+
+def _row_tiles(d: int):
+    """``(n, padded width)``: the whole tiles a ``d``-wide float32 row takes."""
+    n = -(-d // (TILE[0] * TILE[1]))
+    return n, n * TILE[0] * TILE[1]
+
+
+def _zero_sum(tokens: int, d: int):
+    """The float32 sum over ``tokens`` rows of ``d``, a row in whole tiles."""
     with jax.named_scope(MOE_ROUTE_SCOPE):
-        return y.at[to].add(rows, mode="drop")
+        return jnp.zeros((tokens, _row_tiles(d)[0], *TILE), F32)
+
+
+def _scatter_add_rows(acc, to, rows):
+    """``rows`` (block, d) float32 added into ``acc`` (T, n, 8, 128) at tokens
+    ``to`` (one past the end drops a row): the rows are padded to whole tiles,
+    so the scatter moves each token's own tiles and not its seven neighbours'
+    (the note above the layer)."""
+    with jax.named_scope(MOE_ROUTE_SCOPE):
+        d = rows.shape[-1]
+        tiles = jnp.pad(rows, ((0, 0), (0, _row_tiles(d)[1] - d))).reshape(-1, *acc.shape[1:])
+        return acc.at[to].add(tiles, mode="drop")
+
+
+def _sum_rows(acc, d: int, dtype):
+    """The sum as ``(T, d)`` of ``dtype``, after the loop; cast first, on the
+    tiles, where the compiler puts the cast anyway (under its own name and no scope)."""
+    with jax.named_scope(MOE_ROUTE_SCOPE):
+        return acc.astype(dtype).reshape(acc.shape[0], -1)[:, :d]
 
 
 def _expert_mlp(xb, stacks, e, dt):
@@ -432,8 +469,8 @@ def _routed_forward(x, stacks, token, row_weight, starts, counts, block):
         _, _, out = _expert_mlp(_gather_rows(x, tok), stacks, e, dt)
         return _scatter_add_rows(y, to, out * wts[:, None]), done + jnp.sum(valid, dtype=jnp.int32)
 
-    y, done = jax.lax.fori_loop(0, total, step, (jnp.zeros(x.shape, F32), jnp.zeros((), jnp.int32)))
-    return y.astype(dt), done
+    y, done = jax.lax.fori_loop(0, total, step, (_zero_sum(*x.shape), jnp.zeros((), jnp.int32)))
+    return _sum_rows(y, x.shape[1], dt), done
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(6,))
@@ -471,8 +508,10 @@ def _routed_experts_bwd(block, saved, cts):
         xb = _gather_rows(x, tok)
         ws, kept, out = _expert_mlp(xb, stacks, e, dt)
         dyb = jnp.where(valid[:, None], _gather_rows(dy, tok).astype(F32), 0.0)
-        d_weight = d_weight.at[jnp.where(valid, rows, d_weight.shape[0])].set(
-            jnp.sum(dyb * out, -1), mode="drop"
+        # a block's rows are consecutive from its first, so a slice is written and not a scatter
+        old = jax.lax.dynamic_slice_in_dim(d_weight, rows[0], block)
+        d_weight = jax.lax.dynamic_update_slice_in_dim(
+            d_weight, jnp.where(valid, jnp.sum(dyb * out, -1), old), rows[0], 0
         )
         dyw = (dyb * wts[:, None]).astype(dt)
         dxb, products = _expert_mlp_bwd(xb, ws, kept, dyw, dt)
@@ -481,11 +520,15 @@ def _routed_experts_bwd(block, saved, cts):
             *(add_row(g, e, jnp.dot(a.T, b, preferred_element_type=F32)) for g, (a, b) in zip(grads, products)),
         )
 
-    zeros = (jnp.zeros(x.shape, F32), jnp.zeros(row_weight.shape, F32), *(jnp.zeros(w.shape, F32) for w in stacks))
+    # d_weight is one block longer than the rows, so that the last block's slice fits whatever its tail
+    zeros = (
+        _zero_sum(*x.shape), jnp.zeros((row_weight.shape[0] + block,), F32),
+        *(jnp.zeros(w.shape, F32) for w in stacks),
+    )
     dx, d_weight, *grads = jax.lax.fori_loop(0, total, step, zeros)
     return (
-        dx.astype(dt), tuple(g.astype(w.dtype) for g, w in zip(grads, stacks)),
-        None, d_weight.astype(row_weight.dtype), None, None,
+        _sum_rows(dx, x.shape[1], dt), tuple(g.astype(w.dtype) for g, w in zip(grads, stacks)),
+        None, d_weight[: row_weight.shape[0]].astype(row_weight.dtype), None, None,
     )
 
 

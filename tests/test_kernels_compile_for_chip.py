@@ -8,6 +8,7 @@ that describes a topology lives in this one file (one worker loads the TPU's
 library, inside a fixture, never at import)."""
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -235,3 +236,40 @@ def test_the_state_space_kernel_pair_compiles_for_a_v5e(one_chip, rows, tokens, 
     assert [(x.shape, x.dtype) for x in both.out_info] == [(a.shape, a.dtype) for a in args]
     asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
     assert set(asked) == {"ssd_fwd", "ssd_bwd"} and max(asked.values()) < MOSAIC_VMEM_LIMIT // 2, asked
+
+
+# The routed layers' two loops (models/moe.py routed_experts and its backward) at the three cells' calls: 16384 tokens a
+# microbatch, 8 held experts, blocks of 512 rows. What the tile-aligned sums rest on, read off the chip's own compiler:
+# the float32 sums y and dx are scattered into as (T, n, 8, 128) and never as (T, d), nothing copies a carried sum,
+# d_weight is written by slices, and one block's scatter-add moves under half the bytes of the 2-D form's (93.6 / 80.2 /
+# 71.3 MB at 2688 / 2304 / 2048: eight tokens' rows to add one), under a limit set from what the new form reads (43.3 /
+# 42.5 / 21.0 MB: the scatter, and the block's rows padded and turned into tiles).
+@pytest.mark.parametrize("d, hidden, kind, scatter_mb", [(2048, 1536, "swiglu", 24), (2304, 1024, "swiglu", 47), (2688, 1856, "relu2", 48)],
+                         ids=["glm-cell", "kimi-cell", "nemotron-cell"])
+def test_the_routed_loops_scatter_into_whole_tiles_on_a_v5e(one_chip, d, hidden, kind, scatter_mb):
+    from distributed_sigmoid_loss_tpu.models import moe
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tokens, held, top, block = 16384, 8, 6, 512
+    n = moe._row_tiles(d)[0]
+    shapes = ([(held, d, hidden)] if kind == "swiglu" else []) + [(held, d, hidden), (held, hidden, d)]
+    args = (of((tokens, d), jnp.bfloat16), tuple(of(s, jnp.float32) for s in shapes), of((tokens * top,), jnp.int32),
+            of((tokens * top,), jnp.float32), of((held,), jnp.int32), of((held,), jnp.int32))
+
+    def loss(x, stacks, token, row_weight, starts, counts):
+        return (moe.routed_experts(x, stacks, token, row_weight, starts, counts, block)[0].astype(jnp.float32) ** 2).sum()
+
+    both = jax.jit(jax.grad(loss, argnums=(0, 1, 3))).lower(*args).compile()
+    text = both.as_text()
+    scattered = re.findall(r"= (f32\[[\d,]+\])\S* scatter\(", text)
+    assert scattered == [f"f32[{tokens},{n},8,128]"] * 2, scattered  # y's and dx's; no (T, d) sum, no 1-D d_weight scatter
+    assert not re.findall(rf"= f32\[{tokens},[\d,]+\]\S* copy\(", text)  # no copy of a carried sum, in a body or outside
+    assert [(x.shape, x.dtype) for x in jax.tree.leaves(both.out_info)] == [(a.shape, a.dtype) for a in jax.tree.leaves((args[:2], args[3]))]
+
+    rows = of((block, d), jnp.float32)
+    one = jax.jit(moe._scatter_add_rows, donate_argnums=0).lower(of((tokens, n, 8, 128), jnp.float32), of((block,), jnp.int32), rows)
+    flat = jax.jit(lambda y, to, r: y.at[to].add(r, mode="drop"), donate_argnums=0).lower(of((tokens, d), jnp.float32), of((block,), jnp.int32), rows)
+    moved, moved_2d = (c.compile().cost_analysis()["bytes accessed"] for c in (one, flat))
+    assert moved < scatter_mb * 1e6 and moved < 0.55 * moved_2d, (moved, moved_2d)

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from distributed_sigmoid_loss_tpu.models import moe as moe_lib
 from distributed_sigmoid_loss_tpu.models.moe import MoeMlp
 from distributed_sigmoid_loss_tpu.parallel.mesh import make_mesh
 
@@ -320,3 +321,127 @@ def test_build_dispatch_bf16_keeps_f32_routing():
     np.testing.assert_allclose(
         np.asarray(c16, np.float32), np.asarray(c32), rtol=1e-2, atol=1e-3
     )
+
+
+# -- the sigmoid-routed layer's loops (routed_experts): the tile-aligned sums ----
+#
+# routed_experts carries its float32 sums (y forward, dx backward) as
+# (T, n, 8, 128), a token's row in whole tiles, and writes d_weight by slices.
+# The oracle below is the loop as it was before: a 2-D (T, d) sum, a scatter-add
+# of a block's rows into it, d_weight by a scatter. Same additions, same order,
+# one rounding at the same place: everything is equal bit for bit.
+
+F32 = jnp.float32
+
+
+def _oracle_forward(x, stacks, token, row_weight, starts, counts, block):
+    tokens, dt = x.shape[0], x.dtype
+    total, first = moe_lib._block_plan(starts, counts, block)
+
+    def step(i, y):
+        e, _, _, tok, to, wts = moe_lib._block_rows(i, first, block, token, row_weight, starts, counts, tokens)
+        _, _, out = moe_lib._expert_mlp(x[tok], stacks, e, dt)
+        return y.at[to].add(out * wts[:, None], mode="drop")
+
+    return jax.lax.fori_loop(0, total, step, jnp.zeros(x.shape, F32)).astype(dt)
+
+
+def _oracle_backward(x, stacks, token, row_weight, starts, counts, block, dy):
+    tokens, dt = x.shape[0], x.dtype
+    total, first = moe_lib._block_plan(starts, counts, block)
+
+    def add_row(acc, e, g):
+        return jax.lax.dynamic_update_index_in_dim(
+            acc, jax.lax.dynamic_index_in_dim(acc, e, 0, keepdims=False) + g, e, 0
+        )
+
+    def step(i, carry):
+        dx, d_weight, *grads = carry
+        e, rows, valid, tok, to, wts = moe_lib._block_rows(i, first, block, token, row_weight, starts, counts, tokens)
+        xb = x[tok]
+        ws, kept, out = moe_lib._expert_mlp(xb, stacks, e, dt)
+        dyb = jnp.where(valid[:, None], dy[tok].astype(F32), 0.0)
+        d_weight = d_weight.at[jnp.where(valid, rows, d_weight.shape[0])].set(jnp.sum(dyb * out, -1), mode="drop")
+        dyw = (dyb * wts[:, None]).astype(dt)
+        dxb, products = moe_lib._expert_mlp_bwd(xb, ws, kept, dyw, dt)
+        return (
+            dx.at[to].add(dxb, mode="drop"), d_weight,
+            *(add_row(g, e, jnp.dot(a.T, b, preferred_element_type=F32)) for g, (a, b) in zip(grads, products)),
+        )
+
+    zeros = (jnp.zeros(x.shape, F32), jnp.zeros(row_weight.shape, F32), *(jnp.zeros(w.shape, F32) for w in stacks))
+    dx, d_weight, *grads = jax.lax.fori_loop(0, total, step, zeros)
+    return dx.astype(dt), tuple(g.astype(w.dtype) for g, w in zip(grads, stacks)), d_weight.astype(row_weight.dtype)
+
+
+ROUTED_T, ROUTED_BLOCK, ROUTED_HIDDEN = 80, 32, 16
+
+
+@pytest.fixture(scope="module", params=[(1024, "swiglu"), (1024, "relu2"), (1152, "swiglu"), (1152, "relu2"),
+                                        (2688, "swiglu"), (2688, "relu2")], ids=lambda p: f"d{p[0]}-{p[1]}")
+def routed_pair(request):
+    """``(got, want, plan)`` at one width and kind: routed_experts and its
+    gradients beside the 2-D oracle's, bf16 tokens as the cells have them. Eight
+    experts, three a token, held here 2..5: expert 5 is never chosen (empty),
+    expert 2 by every second token (40 rows: a block of 32 and a ragged one of 8)."""
+    d, kind = request.param
+    k = jax.random.split(jax.random.key(d), 8)
+    t, e, held, first, top = ROUTED_T, 8, 4, 2, 3
+    x = jax.random.normal(k[0], (t, d)).astype(jnp.bfloat16)
+    shapes = ([(held, d, ROUTED_HIDDEN)] if kind == "swiglu" else []) + [(held, d, ROUTED_HIDDEN), (held, ROUTED_HIDDEN, d)]
+    stacks = tuple(jax.random.normal(kk, s) * s[1] ** -0.5 for kk, s in zip(k[1:4], shapes))
+    others = jnp.stack([jax.random.permutation(kk, jnp.asarray([0, 1, 3, 4, 6, 7]))[:top] for kk in jax.random.split(k[4], t)])
+    idx = jnp.where((jnp.arange(t) % 2 == 0)[:, None] & (jnp.arange(top) == 0), 2, others)
+    weights = jax.random.uniform(k[5], (t, top)) + 0.2
+    dy = jax.random.normal(k[6], (t, d)).astype(jnp.bfloat16)
+    token, row_weight, starts, counts = moe_lib.dispatch_plan(idx, weights, first, held)
+
+    def routed(x, stacks, row_weight):
+        return moe_lib.routed_experts(x, stacks, token, row_weight, starts, counts, ROUTED_BLOCK)
+
+    (y, done), back = jax.vjp(routed, x, stacks, row_weight)
+    dx, d_stacks, d_weight = back((dy, jnp.zeros((), jax.dtypes.float0)))
+    want_y = _oracle_forward(x, stacks, token, row_weight, starts, counts, ROUTED_BLOCK)
+    want_dx, want_stacks, want_weight = _oracle_backward(x, stacks, token, row_weight, starts, counts, ROUTED_BLOCK, dy)
+    got = {"y": y, "dx": dx, "stacks": d_stacks, "d_weight": d_weight}
+    want = {"y": want_y, "dx": want_dx, "stacks": want_stacks, "d_weight": want_weight}
+    return got, want, (token, counts, int(done))
+
+
+@pytest.mark.parametrize("leaf", ["y", "dx", "stacks", "d_weight"])
+def test_routed_experts_is_the_2d_scatter_adds_bit_for_bit(routed_pair, leaf):
+    got, want, (token, counts, done) = routed_pair
+    counts = np.asarray(counts)
+    assert counts[3] == 0 and counts[0] == ROUTED_T // 2 and counts[0] % ROUTED_BLOCK  # an empty expert, a ragged tail
+    assert done == counts.sum() and np.bincount(np.asarray(token)[:done]).max() > 1  # a token of several held experts
+    for g, w in zip(jax.tree.leaves(got[leaf]), jax.tree.leaves(want[leaf])):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert float(jnp.abs(w.astype(F32)).max()) > 1e-3
+        np.testing.assert_array_equal(np.asarray(g.astype(F32)), np.asarray(w.astype(F32)))
+
+
+@pytest.mark.parametrize("d, tiles", [(8, 1), (1024, 1), (1152, 2), (2048, 2), (2304, 3), (2688, 3)])
+def test_the_sums_shape_follows_from_the_width_alone(d, tiles):
+    """A token's row of the carried sums is whole (8, 128) float32 tiles, and the
+    loops scatter into nothing 2-D: read off the jaxpr of the layer's gradient."""
+    assert moe_lib._row_tiles(d) == (tiles, tiles * 1024)
+    t, hidden = 24, 8
+    x = jnp.zeros((t, d), jnp.bfloat16)
+    stacks = (jnp.zeros((2, d, hidden)), jnp.zeros((2, hidden, d)))
+    plan = moe_lib.dispatch_plan(jnp.zeros((t, 1), jnp.int32), jnp.ones((t, 1)), 0, 2)
+
+    def loss(x, stacks, w):
+        return moe_lib.routed_experts(x, stacks, plan[0], w, *plan[2:], 16)[0].astype(F32).sum()
+
+    def scatters(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name.startswith("scatter"):
+                yield eqn.invars[0].aval.shape
+            for value in eqn.params.values():
+                for inner in value if isinstance(value, (tuple, list)) else (value,):
+                    inner = getattr(inner, "jaxpr", inner)
+                    if hasattr(inner, "eqns"):
+                        yield from scatters(inner)
+
+    found = list(scatters(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, stacks, plan[1]).jaxpr))
+    assert found == [(t, tiles, 8, 128)] * 2, found  # y's and dx's; d_weight is written by slices
