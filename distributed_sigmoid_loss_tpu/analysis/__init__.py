@@ -15,9 +15,9 @@ Three halves, one Finding stream:
   the step-config axes: a constraint table, a solver enumerating the legal
   product (the lattice source for the traced sample), and a drift check
   probing every config through the real imperative refusal layers.
-- :mod:`.repo_lint` is an AST pass over the package + bench.py enforcing
-  repo invariants (trace-time mutable globals, doc staleness, slow markers,
-  bench record schema).
+- :mod:`.repo_lint` is an AST pass over the package and the documents
+  enforcing repo invariants (trace-time mutable globals, doc staleness in
+  both directions, slow markers, the metrics schema).
 - :mod:`.lock_flow` ("graftguard") is the concurrency half: guarded-by
   inference over every lock-owning class (unguarded writes, un-looped
   ``Condition.wait``, blocking calls under a lock, orphan threads), the

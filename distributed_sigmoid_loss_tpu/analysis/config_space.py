@@ -362,7 +362,7 @@ _TIER1_EXTRAS = (
 def tier1_sample() -> dict:
     """label -> StepConfig for the tier-1 (and default ``lint``) trace set:
     the fifteen legacy configs plus one coverage config per previously
-    untraced axis. ~23 traces — sized for the 870 s tier-1 budget."""
+    untraced axis. ~23 traces — sized for tier-1's time limit."""
     out = dict(LEGACY_CONFIGS)
     for cfg in _TIER1_EXTRAS:
         assert is_legal(cfg), f"tier1 extra violates the table: {cfg}"

@@ -1,8 +1,7 @@
 """The one Finding type every graftlint rule reports through.
 
-Stdlib-only on purpose: ``bench_schema`` (imported by bench.py, whose
-top-level imports must stay stdlib-only) and the AST linter share it without
-pulling jax into processes that never trace anything.
+Stdlib-only on purpose: the AST linter and the schema modules share it
+without pulling jax into processes that never trace anything.
 """
 
 from __future__ import annotations

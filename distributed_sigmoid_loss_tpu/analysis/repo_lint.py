@@ -11,18 +11,17 @@ review:
 - ``repo-doc-stale``: every CLI flag and LossConfig field must appear in
   README.md or docs/ (a flag nobody can discover is a flag nobody A/Bs).
 - ``repo-slow-marker``: the registered multi-minute suites must carry the
-  module-level ``slow`` marker (protects the 870 s time-boxed tier-1 budget).
-- ``repo-bench-record``: every record-field string literal in bench.py must
-  be registered in ``analysis/bench_schema.py`` (per-emit-path field drift).
+  module-level ``slow`` marker (protects tier-1's time limit: the driver runs
+  ``-n 6 --dist loadfile -m 'not slow'`` under a 1470 s timeout, 777 s at PR 45).
+- ``repo-doc-code``: the reverse of repo-doc-stale. Every repo path a document
+  names in backticks exists, every ``python ...`` command it shows names a
+  script, module or sub-command that exists, and every ``--flag`` it gives
+  for a sub-command is in that sub-command's parser (a recipe for a file that
+  is gone outlives the file by twenty PRs otherwise).
 - ``repo-metrics-schema``: every train metrics-line / serve ``stats()`` /
   health-event field literal in the emitting modules must be registered in
-  ``obs/metrics_schema.py`` — the same drift class as repo-bench-record, for
-  the OTHER two record streams (a metric added in one step builder but not
+  ``obs/metrics_schema.py`` (a metric added in one step builder but not
   declared is invisible to every downstream parser until it breaks one).
-- ``repo-ledger-emit``: bench.py's record prints (``print(json.dumps(...))``)
-  may happen ONLY inside ``_emit``, and ``_emit`` must append to the run
-  ledger (``obs/ledger.py append_record``) — a new emit path that prints its
-  own JSON bypasses both the schema validator and the perf trajectory.
 - ``repo-chaos-gate``: every fault-injection point in serve/ must be a
   ``maybe_inject("<point>")`` call whose point is a string constant
   registered in ``serve/siege.py CHAOS_POINTS`` with a non-empty rationale,
@@ -38,7 +37,10 @@ a known-bad fixture; the defaults audit the real repo.
 from __future__ import annotations
 
 import ast
+import fnmatch
 import os
+import re
+import shlex
 
 from distributed_sigmoid_loss_tpu.analysis.findings import Finding
 
@@ -48,22 +50,21 @@ __all__ = [
     "check_mutable_globals",
     "check_doc_staleness",
     "check_slow_markers",
-    "check_bench_record_fields",
+    "check_docs_against_code",
     "check_metrics_schema",
-    "check_ledger_emit",
     "check_chaos_gate",
     "MUTABLE_GLOBAL_ALLOWLIST",
     "SLOW_REQUIRED_TEST_MODULES",
     "METRICS_SCHEMA_FILES",
+    "DOCUMENTS",
 ]
 
 REPO_RULES = (
     "repo-mutable-global",
     "repo-doc-stale",
     "repo-slow-marker",
-    "repo-bench-record",
+    "repo-doc-code",
     "repo-metrics-schema",
-    "repo-ledger-emit",
     "repo-chaos-gate",
 )
 
@@ -78,9 +79,9 @@ _REPO_ROOT = os.path.dirname(_PACKAGE_DIR)
 MUTABLE_GLOBAL_ALLOWLIST = {
     "ops/pallas_sigmoid_loss.py::_TRACED_LOSS_KERNELS": (
         "trace-time recorder for the streaming-loss-kernel dispatch "
-        "(streaming / streaming_int8 / xla fallback); bench.py cross-checks "
-        "records against it (_pallas_record_fields) so use_pallas can never "
-        "be claimed while every block fell back (append-only at trace time; "
+        "(streaming / streaming_int8 / xla fallback); chip_smoke.py and "
+        "tests/test_pallas_loss.py read it so use_pallas can never be "
+        "claimed while every block fell back (append-only at trace time; "
         "cleared only by the test-isolation reset)"
     ),
     "data/native_loader.py::_lib": (
@@ -94,11 +95,6 @@ MUTABLE_GLOBAL_ALLOWLIST = {
     "data/native_decode.py::_lib_failed": (
         "host-side build-failure latch paired with _lib; never read inside "
         "traced code"
-    ),
-    "obs/ledger.py::_FINGERPRINT_CACHE": (
-        "host-side memo for the ledger's environment fingerprint (git sha "
-        "subprocess result); never read inside traced code — the ledger is "
-        "a stdlib emit path"
     ),
     "serve/siege.py::_INJECTORS": (
         "host-side armed-fault registry for the chaos harness; never read "
@@ -115,9 +111,10 @@ MUTABLE_GLOBAL_ALLOWLIST = {
     ),
 }
 
-# The suites whose full-module runtime is multi-minute on the 1-core tier-1
-# host (measured; see CHANGES.md PR 1-3): each must carry a module-level
-# `pytestmark = pytest.mark.slow` so the time-boxed gate never collects them.
+# The suites whose full-module runtime is multi-minute on one core (measured;
+# see CHANGES.md PR 1-3): each must carry a module-level
+# `pytestmark = pytest.mark.slow` so tier-1 (`-m 'not slow'`) never collects
+# them.
 SLOW_REQUIRED_TEST_MODULES = (
     "test_cli.py",
     "test_grad_compression.py",
@@ -382,7 +379,7 @@ def check_slow_markers(
     sources=None, required=None,
 ) -> list[Finding]:
     """repo-slow-marker: registered multi-minute suites carry the module-level
-    slow pytestmark (the 870 s tier-1 budget's structural guard)."""
+    slow pytestmark (the structural guard of tier-1's 1470 s time limit)."""
     required = SLOW_REQUIRED_TEST_MODULES if required is None else required
     if sources is None:
         sources = {}
@@ -409,8 +406,8 @@ def check_slow_markers(
             findings.append(Finding(
                 "repo-slow-marker", f"tests/{fn}",
                 "multi-minute suite without a module-level `pytestmark = "
-                "pytest.mark.slow` — it would land inside the time-boxed "
-                "870 s tier-1 gate and blow the budget",
+                "pytest.mark.slow` — it would land inside tier-1's "
+                "`-m 'not slow'` run and its 1470 s time limit",
             ))
     return findings
 
@@ -442,71 +439,327 @@ def _has_module_slow_mark(tree: ast.Module) -> bool:
     return False
 
 
-def check_bench_record_fields(bench_source: str | None = None) -> list[Finding]:
-    """repo-bench-record: record-field string literals in bench.py are all
-    registered in the shared schema (analysis/bench_schema.py)."""
-    from distributed_sigmoid_loss_tpu.analysis.bench_schema import (
-        BENCH_RECORD_FIELDS,
-    )
+# The documents repo-doc-code holds to the tree (repo-root-relative paths).
+DOCUMENTS = (
+    "README.md",
+    "ARCHITECTURE.md",
+    "docs/ANALYSIS.md",
+    "docs/MIGRATION.md",
+    "docs/OBSERVABILITY.md",
+    "docs/SERVING.md",
+)
 
-    if bench_source is None:
-        with open(os.path.join(_REPO_ROOT, "bench.py"), encoding="utf-8") as f:
-            bench_source = f.read()
-    tree = ast.parse(bench_source)
-    # Names whose dict keys ARE record fields: the per-mode `record` dicts,
-    # the `fields` dict _pallas_record_fields merges into records, and any
-    # dict literal passed straight to _emit(...)/json.dumps(...).
-    record_names = {"record", "fields"}
+_PACKAGE_NAME = os.path.basename(_PACKAGE_DIR)
+_SOURCE_EXTS = (".py", ".md", ".cc")
+_DATA_EXTS = (".json", ".jsonl", ".toml")
+_FENCED = re.compile(r"^```[^\n]*\n(.*?)^```", re.S | re.M)
+_PATH_TOKEN = re.compile(r"^[A-Za-z0-9_*\-][A-Za-z0-9_.*\-]*(/[A-Za-z0-9_.*\-]+)*/?$")
+_LINE_SUFFIX = re.compile(r":\d+(-\d+)?$")
+_SHELL_BREAKS = {"|", "||", "&&", ";", ">", ">>", "2>&1", "&"}
+
+
+def _backticked(text: str) -> list[str]:
+    """A document's inline code spans, fenced blocks aside. A span may wrap
+    over a line end but not over a paragraph's, so that one stray backtick
+    cannot flip the pairing for the rest of the document."""
+    spans = []
+    for paragraph in re.split(r"\n\s*\n", _FENCED.sub("", text)):
+        spans.extend(" ".join(m.split()) for m in re.findall(r"`([^`]+)`", paragraph))
+    return spans
+
+
+def _tree_paths(repo_root: str) -> set[str]:
+    """Every file and directory of the checkout, '/'-separated and
+    root-relative. Hidden directories, ``__pycache__`` and the scratch
+    directories git ignores (a leading underscore, ``chiprun_out``) are not
+    the tree: a copy of the parent commit unpacked there must not answer for
+    a file the change removed."""
+    paths: set[str] = set()
+    for dirpath, dirnames, filenames in os.walk(repo_root):
+        dirnames[:] = [
+            d for d in dirnames
+            if not d.startswith((".", "_")) and d != "chiprun_out"
+        ]
+        rel = os.path.relpath(dirpath, repo_root).replace(os.sep, "/")
+        prefix = "" if rel == "." else rel + "/"
+        paths.update(prefix + d for d in dirnames)
+        paths.update(prefix + f for f in filenames)
+    return paths
+
+
+class _Tree:
+    """The checkout as repo-doc-code sees it: a named path resolves when it is
+    the tail of some path in the tree (documents name ``ops/quant.py`` and
+    ``fleet/leases.py`` from inside the package), globs included."""
+
+    def __init__(self, paths: set[str]):
+        self.tails: set[str] = set()
+        self.dir_names: set[str] = set()
+        for p in paths:
+            parts = p.split("/")
+            self.tails.update("/".join(parts[i:]) for i in range(len(parts)))
+            self.dir_names.update(parts[:-1])
+
+    def has(self, token: str) -> bool:
+        token = token.rstrip("/")
+        if "*" in token:
+            return any(fnmatch.fnmatchcase(t, token) for t in self.tails)
+        return token in self.tails
+
+    def resolves(self, token: str) -> bool:
+        """``token`` as written, as a module (``ops/quant`` for
+        ``ops/quant.py``), or less a dotted attribute
+        (``ops/quant.quantize_int8``)."""
+        cand = token
+        while True:
+            if self.has(cand) or self.has(cand + ".py"):
+                return True
+            head, dot, _ = cand.rpartition(".")
+            if not dot or "/" in cand[len(head):]:
+                return False
+            cand = head
+
+
+def _named_repo_path(span_word: str, tree: _Tree) -> str | None:
+    """The repo path a backticked word names, or None when it names none: a
+    word of path characters that ends in a source extension, or whose first
+    segment is a directory of the tree (``DIR/telemetry.json``, ``pairs/s`` and
+    ``/tmp/x`` are not repo paths), or a capitalised data file at the root
+    (``PERF_LEDGER.jsonl``; ``telemetry.json`` is a run's output)."""
+    word = span_word.strip("()[],;").rstrip(".:")
+    word = _LINE_SUFFIX.sub("", word.split("::")[0])
+    if not _PATH_TOKEN.match(word):
+        return None
+    if "/" in word.rstrip("/"):
+        return word if word.split("/")[0] in tree.dir_names else None
+    if word.endswith("/"):
+        return None
+    if word.endswith(_SOURCE_EXTS):
+        return word
+    if word.endswith(_DATA_EXTS) and word[0].isupper():
+        return word
+    return None
+
+
+def _subcommand_parsers(cli_source: str) -> dict[str, dict]:
+    """sub-command -> {"flags": set of option strings, "choices": the
+    choices of its first positional or None}, read from cli.py's
+    ``X = sub.add_parser("name")`` / ``X.add_argument(...)`` statements and
+    from the helpers a parser is handed to (``_add_obs_args(ob)``,
+    ``add_data_bench_args(db)``)."""
+    tree = ast.parse(cli_source)
+    imported: dict[str, str] = {}
+    local_defs: dict[str, ast.FunctionDef] = {}
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and node.module and (
+            node.module.startswith(_PACKAGE_NAME + ".")
+        ):
+            for alias in node.names:
+                imported[alias.asname or alias.name] = node.module
+        elif isinstance(node, ast.FunctionDef):
+            local_defs.setdefault(node.name, node)
+
+    def helper_def(name: str) -> ast.FunctionDef | None:
+        if name in local_defs:
+            return local_defs[name]
+        if name in imported:
+            rel = imported[name].split(".", 1)[1].replace(".", os.sep) + ".py"
+            path = os.path.join(_PACKAGE_DIR, rel)
+            if os.path.exists(path):
+                with open(path, encoding="utf-8") as f:
+                    for node in ast.parse(f.read()).body:
+                        if isinstance(node, ast.FunctionDef) and node.name == name:
+                            return node
+        return None
+
+    def add_argument_calls(scope: ast.AST, var: str):
+        for node in ast.walk(scope):
+            if (
+                isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "add_argument"
+                and isinstance(node.func.value, ast.Name)
+                and node.func.value.id == var
+            ):
+                yield node
+
+    def take(call: ast.Call, spec: dict) -> None:
+        names = [
+            a.value for a in call.args
+            if isinstance(a, ast.Constant) and isinstance(a.value, str)
+        ]
+        if names and names[0].startswith("-"):
+            spec["flags"].update(names)
+        elif names and "choices" not in spec:  # the first positional
+            spec["choices"] = None
+            for kw in call.keywords:
+                if kw.arg == "choices" and isinstance(kw.value, (ast.List, ast.Tuple)):
+                    spec["choices"] = [
+                        e.value for e in kw.value.elts if isinstance(e, ast.Constant)
+                    ]
+
+    parsers: dict[str, dict] = {}
+    spec_of: dict[str, dict] = {}  # parser variable -> its sub-command's spec
+    for node in ast.walk(tree):
+        if (
+            isinstance(node, ast.Assign)
+            and len(node.targets) == 1
+            and isinstance(node.targets[0], ast.Name)
+            and isinstance(node.value, ast.Call)
+            and isinstance(node.value.func, ast.Attribute)
+            and node.value.func.attr == "add_parser"
+            and node.value.args
+            and isinstance(node.value.args[0], ast.Constant)
+        ):
+            spec_of[node.targets[0].id] = parsers[node.value.args[0].value] = {
+                "flags": {"-h", "--help"}
+            }
+    for var, spec in spec_of.items():
+        for call in add_argument_calls(tree, var):
+            take(call, spec)
+    for node in ast.walk(tree):  # a parser handed to a helper: `helper(var)`
+        if (
+            isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Name)
+            and len(node.args) == 1
+            and isinstance(node.args[0], ast.Name)
+            and node.args[0].id in spec_of
+        ):
+            fn = helper_def(node.func.id)
+            if fn is not None and fn.args.args:
+                for call in add_argument_calls(fn, fn.args.args[0].arg):
+                    take(call, spec_of[node.args[0].id])
+    for spec in parsers.values():
+        spec.setdefault("choices", None)
+    return parsers
+
+
+def _command_words(text: str) -> list[list[str]]:
+    """Every command a document shows, as words: the lines of its fenced
+    blocks (continuations joined, comments cut) and its backticked spans."""
+    commands = []
+    for block in _FENCED.findall(text):
+        commands.extend(block.replace("\\\n", " ").splitlines())
+    commands.extend(_backticked(text))
+    out = []
+    for line in commands:
+        try:
+            words = shlex.split(line, comments=True)
+        except ValueError:
+            words = line.split("#")[0].split()
+        if words:
+            out.append(words)
+    return out
+
+
+def _after_python(words: list[str]) -> list[str] | None:
+    """The words that follow the interpreter in a command line, or None."""
+    for i, w in enumerate(words):
+        if os.path.basename(w) in ("python", "python3"):
+            return words[i + 1:]
+    return None
+
+
+def documented_invocations(text: str, subcommands) -> list[tuple[str, list[str]]]:
+    """(sub-command, the words after it) for every CLI invocation a document
+    shows: ``python -m <package> CMD ...`` and a backticked ``CMD ...``. A
+    name that is not a sub-command comes back too, when the package's module
+    was invoked with it."""
+    found = []
+    for words in _command_words(text):
+        rest = _after_python(words)
+        if rest is not None:
+            rest = rest[2:] if rest[:2] == ["-m", _PACKAGE_NAME] else None
+        elif words[0] in subcommands and len(words) > 1:
+            rest = words
+        if rest and not rest[0].startswith("-"):
+            args = []
+            for w in rest[1:]:
+                if w in _SHELL_BREAKS:
+                    break
+                args.append(w)
+            found.append((rest[0], args))
+    return found
+
+
+def check_docs_against_code(
+    documents: dict[str, str] | None = None,
+    cli_source: str | None = None,
+    repo_root: str | None = None,
+) -> list[Finding]:
+    """repo-doc-code: what a document names exists. ``documents``:
+    ``{name: text}`` (default: :data:`DOCUMENTS` read from the tree).
+
+    Three halves, each a way a document outlives the code it describes: (a) a
+    repo path in backticks that is not in the tree; (b) a ``python ...``
+    command whose script, package module or sub-command does not exist; (c) a
+    ``--flag`` (or, where the first positional has choices, an action) given
+    for a sub-command whose parser has none such."""
+    repo_root = _REPO_ROOT if repo_root is None else repo_root
+    if documents is None:
+        documents = {}
+        for name in DOCUMENTS:
+            with open(os.path.join(repo_root, name), encoding="utf-8") as f:
+                documents[name] = f.read()
+    if cli_source is None:
+        with open(os.path.join(_PACKAGE_DIR, "cli.py"), encoding="utf-8") as f:
+            cli_source = f.read()
+    tree = _Tree(_tree_paths(repo_root))
+    parsers = _subcommand_parsers(cli_source)
     findings = []
 
-    def check_keys(keys, line) -> None:
-        for k in keys:
-            if k not in BENCH_RECORD_FIELDS:
-                findings.append(Finding(
-                    "repo-bench-record",
-                    f"bench.py::{k}",
-                    f"record field {k!r} (line {line}) is not registered in "
-                    "analysis/bench_schema.py BENCH_RECORD_FIELDS — "
-                    "unregistered fields drift per emit path; register it "
-                    "(and document it if it encodes a new config knob)",
-                ))
+    def report(doc: str, what: str, why: str) -> None:
+        finding = Finding("repo-doc-code", f"{doc}::{what}", why)
+        if finding not in findings:
+            findings.append(finding)
 
-    def dict_keys(d: ast.Dict) -> list[str]:
-        return [
-            k.value
-            for k in d.keys
-            if isinstance(k, ast.Constant) and isinstance(k.value, str)
-        ]
-
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Assign):
-            for t in node.targets:
-                if (
-                    isinstance(t, ast.Name)
-                    and t.id in record_names
-                    and isinstance(node.value, ast.Dict)
+    for doc, text in documents.items():
+        # (a) repo paths in backticks
+        for span in _backticked(text):
+            for word in span.split():
+                path = _named_repo_path(word, tree)
+                if path is not None and not tree.resolves(path):
+                    report(doc, path,
+                           f"names `{path}`, which is not in the tree — "
+                           "reword the passage or drop it with the file")
+        # (b) python commands: scripts and package modules
+        for words in _command_words(text):
+            rest = _after_python(words) or [""]
+            if rest[0] == "-m" and len(rest) > 1:
+                module = rest[1]
+                if module.startswith(_PACKAGE_NAME + ".") and not tree.resolves(
+                    module.replace(".", "/")
                 ):
-                    check_keys(dict_keys(node.value), node.lineno)
-                if (
-                    isinstance(t, ast.Subscript)
-                    and isinstance(t.value, ast.Name)
-                    and t.value.id in record_names
-                    and isinstance(t.slice, ast.Constant)
-                    and isinstance(t.slice.value, str)
-                ):
-                    check_keys([t.slice.value], node.lineno)
-        if isinstance(node, ast.Call):
-            fname = None
-            if isinstance(node.func, ast.Name):
-                fname = node.func.id
-            elif isinstance(node.func, ast.Attribute):
-                fname = node.func.attr
-            if fname in ("_emit", "dumps") and node.args and isinstance(
-                node.args[0], ast.Dict
+                    report(doc, f"python -m {module}",
+                           f"shows `python -m {module}`: no such module")
+            elif rest[0].endswith(".py") and not tree.resolves(rest[0]):
+                report(doc, f"python {rest[0]}",
+                       f"shows `python {rest[0]}`: no such script")
+        # (b) sub-commands, (c) their flags and actions
+        for cmd, args in documented_invocations(text, parsers):
+            if cmd not in parsers:
+                report(doc, cmd,
+                       f"shows `python -m {_PACKAGE_NAME} {cmd}`: cli.py has "
+                       f"no such sub-command (it has {', '.join(sorted(parsers))})")
+                continue
+            spec = parsers[cmd]
+            if spec["choices"] and args and not args[0].startswith("-") and (
+                args[0] not in spec["choices"]
             ):
-                check_keys(dict_keys(node.args[0]), node.lineno)
+                report(doc, f"{cmd} {args[0]}",
+                       f"gives `{cmd} {args[0]}`: `{cmd}` takes one of "
+                       f"{', '.join(spec['choices'])}")
+            for word in args:
+                # `--data-dir/--data-shards`: alternatives, each a flag
+                for part in word.split("/"):
+                    flag = part.split("=")[0].rstrip(",.;:)")
+                    if flag.startswith("--") and len(flag) > 2 and (
+                        flag not in spec["flags"]
+                    ):
+                        report(doc, f"{cmd} {flag}",
+                               f"gives {flag} for `{cmd}`, whose parser has "
+                               "no such option")
     return findings
-
 
 _METRIC_DICT_NAMES = {"metrics", "line", "snap"}
 
@@ -625,96 +878,6 @@ def check_metrics_schema(sources=None, files=None) -> list[Finding]:
                 "undeclared fields drift per emit path and are invisible "
                 "to downstream parsers; register it (and document it in "
                 "docs/OBSERVABILITY.md if it encodes a new signal)",
-            ))
-    return findings
-
-
-def _json_record_prints(tree: ast.Module) -> dict[str, list[int]]:
-    """function_name -> lines where ``print(json.dumps(...))`` (or
-    ``print(dumps(...))``) occurs — the record-emit signature the ledger rule
-    keys on. Module-level prints land under the pseudo-name ``<module>``."""
-
-    def is_dumps(call: ast.AST) -> bool:
-        if not isinstance(call, ast.Call):
-            return False
-        f = call.func
-        return (isinstance(f, ast.Attribute) and f.attr == "dumps") or (
-            isinstance(f, ast.Name) and f.id == "dumps"
-        )
-
-    out: dict[str, list[int]] = {}
-
-    def visit(node: ast.AST, owner: str) -> None:
-        for child in ast.iter_child_nodes(node):
-            name = owner
-            if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                name = child.name
-            if (
-                isinstance(child, ast.Call)
-                and isinstance(child.func, ast.Name)
-                and child.func.id == "print"
-                and child.args
-                and is_dumps(child.args[0])
-            ):
-                out.setdefault(owner, []).append(child.lineno)
-            visit(child, name)
-
-    visit(tree, "<module>")
-    return out
-
-
-def check_ledger_emit(bench_source: str | None = None) -> list[Finding]:
-    """repo-ledger-emit: every bench.py record print routes through the ONE
-    ledger-appending emitter.
-
-    Two statically-checkable halves: (a) ``_emit`` must call the ledger
-    append (``append_record``); (b) no ``print(json.dumps(...))`` may appear
-    outside ``_emit`` — a path printing its own JSON bypasses the ledger (and
-    the schema validator) exactly the way pre-round-4 emit paths drifted.
-    """
-    if bench_source is None:
-        with open(os.path.join(_REPO_ROOT, "bench.py"), encoding="utf-8") as f:
-            bench_source = f.read()
-    tree = ast.parse(bench_source)
-    findings = []
-    emit_fns = [
-        node for node in ast.walk(tree)
-        if isinstance(node, ast.FunctionDef) and node.name == "_emit"
-    ]
-    if not emit_fns:
-        findings.append(Finding(
-            "repo-ledger-emit", "bench.py::_emit",
-            "no _emit function found — bench.py has no single schema-"
-            "validating, ledger-appending emit path",
-        ))
-    else:
-        calls_append = any(
-            isinstance(node, ast.Call)
-            and (
-                (isinstance(node.func, ast.Name)
-                 and node.func.id == "append_record")
-                or (isinstance(node.func, ast.Attribute)
-                    and node.func.attr == "append_record")
-            )
-            for node in ast.walk(emit_fns[0])
-        )
-        if not calls_append:
-            findings.append(Finding(
-                "repo-ledger-emit", "bench.py::_emit",
-                "_emit does not call obs.ledger append_record — records "
-                "print to stdout but never enter the perf trajectory; the "
-                "next backend outage is invisible again (the BENCH_r04/r05 "
-                "blind spot)",
-            ))
-    for owner, lines in sorted(_json_record_prints(tree).items()):
-        if owner == "_emit":
-            continue
-        for line in lines:
-            findings.append(Finding(
-                "repo-ledger-emit", f"bench.py::{owner}",
-                f"print(json.dumps(...)) at line {line} outside _emit — a "
-                "record emit path bypassing the ledger append (and the "
-                "schema validator); route it through _emit",
             ))
     return findings
 
@@ -900,9 +1063,8 @@ def run_repo_lint(disabled=()) -> list[Finding]:
         "repo-mutable-global": check_mutable_globals,
         "repo-doc-stale": check_doc_staleness,
         "repo-slow-marker": check_slow_markers,
-        "repo-bench-record": check_bench_record_fields,
+        "repo-doc-code": check_docs_against_code,
         "repo-metrics-schema": check_metrics_schema,
-        "repo-ledger-emit": check_ledger_emit,
         "repo-chaos-gate": check_chaos_gate,
     }
     findings: list[Finding] = []

@@ -12,8 +12,6 @@ The subcommands tie the subsystems together:
 - ``export`` — AOT-export a lowered train/forward step to a StableHLO artifact
   (``jax.export``): deployable without model code, replayable on a matching
   topology.
-- ``bench`` — the headline throughput benchmark (delegates to bench.py when run
-  from a repo checkout; the measured JSON contract is documented there).
 - ``serve-bench`` — online-serving micro-bench: concurrent client threads
   through the batched/cached/bucketed ``serve/`` stack (engine + micro-batcher
   + LRU cache + retrieval index) on synthetic data; prints the ``stats()``
@@ -23,8 +21,7 @@ The subcommands tie the subsystems together:
   / augment / host→device commit in isolation, plus the composed real-data
   pipeline (read-ahead + fused batcher + prefetch) vs the synthetic loader,
   as schema-validated JSON records with the ``synthetic_ratio`` acceptance
-  figure and a decode worker-scaling curve. CPU-runnable —
-  docs/PERF.md "Feeding the headline".
+  figure and a decode worker-scaling curve. CPU-runnable.
 - ``lint`` — graftlint: the repo-invariant AST linter, the graftprove
   config-space drift check (declarative solver vs the real imperative
   refusals), and the jaxpr collective/dtype/dataflow auditor traced over the
@@ -39,8 +36,8 @@ The subcommands tie the subsystems together:
 
 ``train`` and ``eval`` accept ``--cpu-devices N`` to emulate an N-chip mesh on
 CPU — the TPU-native analogue of the reference's ``mp.spawn`` + Gloo localhost
-harness. ``bench`` runs on the real chip only (its numbers are the measured
-contract; an emulated mesh would record meaningless throughput).
+harness. A rate or a utilisation comes from ``benchmark/run.py`` on the chip,
+never from this CLI.
 """
 
 from __future__ import annotations
@@ -160,9 +157,8 @@ def _model_config(args):
             text=dataclasses.replace(cfg.text, quant_train=args.quant_train),
         )
     if getattr(args, "remat_policy", ""):
-        # Same override bench.py carries: the measured-best policies are
-        # per-model AND per-batch (docs/PERF.md round-4 sweep), so the train
-        # CLI exposes the knob rather than hard-coding one winner.
+        # The best policy differs by model AND by batch, so the train CLI
+        # exposes the knob rather than hard-coding one winner.
         if not (cfg.vision.remat or cfg.text.remat):
             # tiny_test() disables remat entirely — the policy would be
             # silently ignored (Encoder applies it only under remat=True).
@@ -1073,9 +1069,8 @@ def cmd_train(args) -> int:
     )
 
     # graftshard placement fields on every metrics line: the mode plus the
-    # measured at-rest optimizer bytes per replica (compiler accounting, the
-    # same figure bench records) — so a training-run JSONL alone shows the
-    # W× shard saving without a separate bench invocation.
+    # measured at-rest optimizer bytes per replica (compiler accounting) —
+    # so a training-run JSONL alone shows the W× shard saving.
     upd_fields = {}
     if update_mode != "off":
         from distributed_sigmoid_loss_tpu.parallel.update_shard import (
@@ -1151,13 +1146,13 @@ def cmd_train(args) -> int:
             put=lambda b, m, a: place_spanned(b), stats=input_stats,
         )
 
-    # Soak-run telemetry (graftledger): under --obs-dir the latest metrics
+    # Soak-run telemetry: under --obs-dir the latest metrics
     # line is ALSO mirrored into DIR/telemetry.json via atomic rename each
     # log interval — tail the run's live state without parsing (or racing)
     # the metrics log stream.
     telemetry_env = None
     if args.obs_dir:
-        from distributed_sigmoid_loss_tpu.obs.ledger import (
+        from distributed_sigmoid_loss_tpu.obs.telemetry import (
             environment_fingerprint,
         )
 
@@ -1698,28 +1693,10 @@ def cmd_export(args) -> int:
     return 0
 
 
-def cmd_bench(extra: list[str]) -> int:
-    if any(a == "--cpu-devices" or a.startswith("--cpu-devices=") for a in extra):
-        print(
-            "bench runs on the real chip only (emulated-mesh throughput would be "
-            "meaningless); use `train --cpu-devices N` for CPU-mesh smoke runs",
-            file=sys.stderr,
-        )
-        return 2
-    # bench.py lives at the repo root (it is the driver's measured contract, not
-    # package code); delegate when available.
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = os.path.join(repo_root, "bench.py")
-    if not os.path.exists(bench):
-        print("bench.py not found (requires a repo checkout)", file=sys.stderr)
-        return 2
-    os.execv(sys.executable, [sys.executable, bench] + extra)
-
-
 def _emit_serve_record(record: dict, *, strict_zero_drops: bool = False) -> int:
     """The serve-bench emit contract (shared by the snapshot and scenario
     paths): validate against the declared record schema, warn on stderr,
-    never lose the measurement, append to the run ledger. With
+    never lose the measurement. With
     ``strict_zero_drops`` a non-zero ``silent_drops`` count fails the run —
     the chaos scenarios' every-outcome-is-typed acceptance gate."""
     import json
@@ -1727,16 +1704,12 @@ def _emit_serve_record(record: dict, *, strict_zero_drops: bool = False) -> int:
     from distributed_sigmoid_loss_tpu.analysis.bench_schema import (
         validate_record,
     )
-    from distributed_sigmoid_loss_tpu.obs.ledger import append_record
 
     problems = validate_record(record)
     if problems:
         print("WARNING: serve-bench record schema violation: "
               + "; ".join(problems), file=sys.stderr)
     print(json.dumps(record))
-    # graftledger: serve-bench/siege records join the same append-only
-    # trajectory as the train headline (obs/ledger.py; never fatal).
-    append_record(record, source="serve-bench", problems=problems)
     if strict_zero_drops and record.get("silent_drops"):
         print(
             f"WARNING: {record['silent_drops']} silent drop(s) — a request "
@@ -1750,7 +1723,7 @@ def _emit_serve_record(record: dict, *, strict_zero_drops: bool = False) -> int:
 
 def cmd_serve_bench(args) -> int:
     """Drive the serve/ stack on synthetic data with concurrent clients and
-    print the ``stats()`` snapshot as one JSON record (bench.py style).
+    print the ``stats()`` snapshot as one JSON record.
 
     The operational proof of the serving layer: with warmed buckets the
     printed ``compile_count`` equals ``bucket_space`` (the number of shape
@@ -2116,9 +2089,7 @@ def cmd_serve_bench(args) -> int:
 
 
 def cmd_data_bench(args) -> int:
-    """Run the input-pipeline stage bench (data/data_bench.py) — the
-    CPU-runnable surface; ``bench.py --data-bench`` queues the same runner on
-    the chip host."""
+    """Run the input-pipeline stage bench (data/data_bench.py)."""
     _bootstrap_devices(args)
     from distributed_sigmoid_loss_tpu.data.data_bench import run_data_bench
 
@@ -2157,216 +2128,60 @@ def _add_obs_args(p) -> None:
     ``main`` (so `obs` shows up in --help) and the standalone intermixed
     parser the obs short-circuit builds, keeping the two in lockstep."""
     p.add_argument("action",
-                   choices=["summarize", "ledger", "diff"],
+                   choices=["summarize", "diff"],
                    help="summarize: aggregate host spans + device op time "
-                        "under DIR; ledger: per-metric trajectory summary; "
-                        "diff: field-level diff of two records or two run "
-                        "dirs' span summaries")
+                        "under DIR; diff: two run dirs' span summaries "
+                        "side by side")
     p.add_argument("paths", nargs="*",
-                   help="summarize: DIR; diff: two operands (metric@N "
-                        "ledger selector, entry index, record-JSON path, "
-                        "or run dir); ledger: none")
+                   help="summarize: DIR; diff: two run dirs")
     p.add_argument("--top", type=int, default=12,
                    help="rows per device-op table (obs summarize)")
-    p.add_argument("--ledger", default="", metavar="PATH",
-                   help="ledger file for `obs ledger`/`obs diff` (default: "
-                        "DSL_LEDGER_PATH or LEDGER.jsonl at the repo root)")
-    p.add_argument("--metric", default="", metavar="NAME",
-                   help="restrict `obs ledger` to one metric stream")
-    p.add_argument("--backfill", action="store_true",
-                   help="before summarizing, seed the ledger from the "
-                        "committed BENCH_r*/MULTICHIP_r* round files "
-                        "(idempotent; rounds whose backend was down land "
-                        "as status=no-backend)")
 
 
 def cmd_obs(args) -> int:
-    """The graftscope/graftledger offline surface:
+    """The graftscope offline surface:
 
     - ``obs summarize DIR`` — merged host-span + device-trace report.
-    - ``obs ledger`` — the per-metric perf trajectory from the append-only
-      run ledger (no-backend/deferred/error rounds listed but excluded from
-      the baseline stats); ``--backfill`` seeds it from the committed
-      BENCH_r*/MULTICHIP_r* round files.
-    - ``obs diff A B`` — field-level diff of two records (ledger selectors
-      like ``metric@-1``, entry indices, or record-JSON paths) or of two
-      run directories' span summaries.
+    - ``obs diff DIR_A DIR_B`` — two run directories' span summaries, side
+      by side with the change in each span's mean.
     """
-    if args.action == "ledger":
-        return _obs_ledger(args)
     if args.action == "diff":
         return _obs_diff(args)
     return _obs_summarize(args)
 
 
-def _obs_ledger(args) -> int:
-    from distributed_sigmoid_loss_tpu.obs.ledger import (
-        backfill_round_files,
-        ledger_path,
-        read_ledger,
-        trajectory,
-        trajectory_summary,
-    )
-
-    path = args.ledger or None
-    if args.backfill:
-        added = backfill_round_files(path=path)
-        print(f"backfilled {len(added)} entr(y/ies) from the committed "
-              f"round files -> {ledger_path(path)}", file=sys.stderr)
-    entries = read_ledger(path)
-    if not entries:
-        print(f"ledger {ledger_path(path)!r} is empty (bench runs append "
-              "automatically; seed history with `obs ledger --backfill`)",
-              file=sys.stderr)
-        return 2
-    traj = trajectory(entries, metric=args.metric or None)
-    if not traj:
-        print(f"no entries for metric {args.metric!r}", file=sys.stderr)
-        return 2
-    for metric in sorted(traj):
-        points = traj[metric]
-        print(f"== {metric} ({len(points)} entr(y/ies))")
-        for p in points:
-            rnd = f"r{p['round']:02d}" if p.get("round") is not None else "  -"
-            val = p.get("value")
-            val_s = f"{val:>12.2f}" if isinstance(val, (int, float)) else (
-                f"{val!r:>12}"
-            )
-            extra = p.get("device_kind", "")
-            print(f"  {rnd:>4} {val_s} {p.get('unit', ''):<13}"
-                  f"{p['status']:<12}{p['source']:<28}{extra}")
-        s = trajectory_summary(points)
-        if s["n"]:
-            last = s["last"]
-            print(f"  -> baseline over {s['n']} measured "
-                  f"(excluded {s['excluded']} non-measurement): "
-                  f"last {last['value']} ({last.get('status')}), "
-                  f"best {s['best']}, mean {round(s['mean'], 2)}")
-        else:
-            print(f"  -> no measured entries ({s['excluded']} excluded: "
-                  "outages/deferrals are not baselines)")
-    return 0
-
-
-def _resolve_diff_operand(op: str, entries):
-    """One `obs diff` operand -> ("record", dict) | ("spans", dir).
-
-    Accepts: a run directory (span summaries), a JSON file (a raw record, a
-    ledger entry, or a driver round file whose ``tail`` holds record lines),
-    ``metric@N`` (the N-th ledger entry of that metric, negatives from the
-    end), or a bare integer (global ledger entry index).
-    """
-    import json as jsonmod
-
-    from distributed_sigmoid_loss_tpu.obs.ledger import _records_in_tail
-
-    if os.path.isdir(op):
-        return "spans", op
-    if os.path.exists(op):
-        with open(op, encoding="utf-8") as f:
-            data = jsonmod.load(f)
-        if not isinstance(data, dict):
-            raise ValueError(f"{op}: not a JSON object")
-        if "metric" in data:
-            return "record", data
-        if isinstance(data.get("record"), dict):
-            return "record", data["record"]
-        if "tail" in data:
-            recs = _records_in_tail(data.get("tail", ""))
-            if recs:
-                return "record", recs[-1]
-        raise ValueError(f"{op}: no bench record found in the file")
-    if "@" in op:
-        metric, _, idx_s = op.rpartition("@")
-        matching = [e for e in entries
-                    if e.get("record", {}).get("metric") == metric]
-        if not matching:
-            raise ValueError(f"no ledger entries for metric {metric!r}")
-        try:
-            return "record", matching[int(idx_s)]["record"]
-        except (ValueError, IndexError):
-            raise ValueError(
-                f"{op}: index {idx_s!r} out of range "
-                f"({len(matching)} entr(y/ies) for {metric!r})"
-            ) from None
-    try:
-        return "record", entries[int(op)]["record"]
-    except ValueError:
-        raise ValueError(
-            f"{op}: not a path, metric@N selector, or entry index"
-        ) from None
-    except IndexError:
-        raise ValueError(
-            f"{op}: ledger has {len(entries)} entr(y/ies)"
-        ) from None
-
-
 def _obs_diff(args) -> int:
-    from distributed_sigmoid_loss_tpu.obs.ledger import (
-        diff_records,
-        read_ledger,
-    )
+    from distributed_sigmoid_loss_tpu.obs.spans import summarize_spans
 
-    if len(args.paths) != 2:
-        print("obs diff needs exactly two operands (ledger selector "
-              "metric@N, entry index, record-JSON path, or run dir)",
-              file=sys.stderr)
+    if len(args.paths) != 2 or not all(os.path.isdir(p) for p in args.paths):
+        print("obs diff needs exactly two run directories (train with "
+              "--obs-dir)", file=sys.stderr)
         return 2
-    entries = read_ledger(args.ledger or None)
-    try:
-        (kind_a, a), (kind_b, b) = (
-            _resolve_diff_operand(op, entries) for op in args.paths
-        )
-    except ValueError as e:
-        print(f"obs diff: {e}", file=sys.stderr)
+    a, b = args.paths
+    rows_a = summarize_spans(_load_host_spans(a)[1])
+    rows_b = summarize_spans(_load_host_spans(b)[1])
+    if not rows_a or not rows_b:
+        print("obs diff: one of the run dirs has no host spans "
+              "(train with --obs-dir)", file=sys.stderr)
         return 2
-    if {kind_a, kind_b} == {"spans"}:
-        from distributed_sigmoid_loss_tpu.obs.spans import summarize_spans
-
-        rows_a = summarize_spans(_load_host_spans(a)[1])
-        rows_b = summarize_spans(_load_host_spans(b)[1])
-        if not rows_a or not rows_b:
-            print("obs diff: one of the run dirs has no host spans "
-                  "(train with --obs-dir)", file=sys.stderr)
-            return 2
-        print(f"== span summary diff (A={a} B={b})")
-        print(f"  {'span':<28}{'A mean ms':>11}{'B mean ms':>11}{'delta':>9}")
-        for name in sorted(set(rows_a) | set(rows_b)):
-            ma = rows_a.get(name, {}).get("mean_ms")
-            mb = rows_b.get(name, {}).get("mean_ms")
-            if ma is None or mb is None:
-                only = "A" if mb is None else "B"
-                print(f"  {name:<28}{'(only in ' + only + ')':>31}")
-                continue
-            print(f"  {name:<28}{ma:>11.2f}{mb:>11.2f}{mb - ma:>+9.2f}")
-        return 0
-    if kind_a != "record" or kind_b != "record":
-        print("obs diff: cannot diff a run dir against a record — pass two "
-              "of the same kind", file=sys.stderr)
-        return 2
-    d = diff_records(a, b)
-    print(f"== record diff (A={args.paths[0]} B={args.paths[1]})")
-    for k, entry in d["changed"].items():
-        delta = ""
-        if "rel" in entry:
-            delta = f"  ({entry['delta']:+g}, {entry['rel']:+.1%})"
-        elif "delta" in entry:
-            delta = f"  ({entry['delta']:+g})"
-        print(f"  {k:<28}{entry['a']!r} -> {entry['b']!r}{delta}")
-    if d["added"]:
-        print(f"  only in B: {', '.join(d['added'])}")
-    if d["removed"]:
-        print(f"  only in A: {', '.join(d['removed'])}")
-    if not (d["changed"] or d["added"] or d["removed"]):
-        print("  records are identical")
+    print(f"== span summary diff (A={a} B={b})")
+    print(f"  {'span':<28}{'A mean ms':>11}{'B mean ms':>11}{'delta':>9}")
+    for name in sorted(set(rows_a) | set(rows_b)):
+        ma = rows_a.get(name, {}).get("mean_ms")
+        mb = rows_b.get(name, {}).get("mean_ms")
+        if ma is None or mb is None:
+            only = "A" if mb is None else "B"
+            print(f"  {name:<28}{'(only in ' + only + ')':>31}")
+            continue
+        print(f"  {name:<28}{ma:>11.2f}{mb:>11.2f}{mb - ma:>+9.2f}")
     return 0
 
 
 def _obs_summarize(args) -> int:
     """``obs summarize DIR``: one offline report of a run's host spans
     (``host_spans.trace.json`` written by ``train --obs-dir``) and any device
-    trace capture (``*.trace.json.gz`` from ``utils.profiling.trace`` /
-    ``bench --profile``) found under DIR — two tables, no TensorBoard needed.
+    trace capture (``*.trace.json.gz`` from ``jax.profiler.trace``) found
+    under DIR — two tables, no TensorBoard needed.
     For both on one time axis open the profiler's own capture: while it runs,
     every enabled span is also a ``TraceAnnotation`` in its host plane
     (obs/spans.py).
@@ -2388,7 +2203,7 @@ def _obs_summarize(args) -> int:
     if not spans and not device_files:
         print(f"no host_spans.trace.json or *.trace.json.gz under "
               f"{root!r} (train with --obs-dir and/or capture a device "
-              "trace with utils.profiling.trace / bench --profile)",
+              "trace with jax.profiler.trace)",
               file=sys.stderr)
         return 2
 
@@ -2612,15 +2427,14 @@ def main(argv=None) -> int:
                     choices=["", "nothing", "save_hot", "save_all_hot",
                              "save_mlp"],
                     help="override both towers' remat policy (default: the "
-                         "model config's own; measured winners per shape in "
-                         "docs/PERF.md — e.g. save_hot for b16/l14 "
-                         "microbatch-128 recipes, save_mlp for so400m)")
+                         "model config's own; benchmark/traffic/*.json "
+                         "name the one each cell runs)")
     tr.add_argument("--quant-train", choices=["", "int8"], default="",
                     help="trainable int8: block projection matmuls run the "
                          "dynamic symmetric int8 recipe FORWARD (v5e int8 "
                          "MXU = 2x bf16 peak) with the full-precision VJP "
                          "BACKWARD (straight-through estimator) — the int8 "
-                         "training track (docs/PERF.md roofline rationale)")
+                         "training track")
     tr.add_argument("--accum-negatives", choices=["local", "global"],
                     default="local",
                     help="with --accum > 1: 'local' contrasts each microbatch "
@@ -2728,7 +2542,7 @@ def main(argv=None) -> int:
                          "the lowest-EF-ratio tensors first; budgeted "
                          "allocates a global loss-impact budget — per-rung "
                          "error-per-byte-saved knapsack descent over "
-                         "ef_ratio/gvar/gnorm (docs/PERF.md graftcodec)")
+                         "ef_ratio/gvar/gnorm")
     tr.add_argument("--emu-dcn-mbps", type=float, default=None,
                     metavar="MBPS",
                     help="honest DCN emulation (parallel/dcn_emu.py): ship "
@@ -2744,8 +2558,8 @@ def main(argv=None) -> int:
                          "rung; the narrow rung keeps F/4)")
     tr.add_argument("--topk-exact", action="store_true",
                     help="exact lax.top_k selection instead of the default "
-                         "approx_max_k (4x slower on TPU at gradient scale "
-                         "-- docs/PERF.md; use for bit-reproducibility)")
+                         "approx_max_k (4x slower on TPU at gradient scale; "
+                         "use for bit-reproducibility)")
     tr.add_argument("--ema-decay", type=float, default=None,
                     help="maintain an EMA of the params in the train state "
                          "(e.g. 0.9999, warmed up)")
@@ -2889,11 +2703,6 @@ def main(argv=None) -> int:
     ex.add_argument("--cpu-devices", type=int, default=0,
                     help="emulate N CPU devices (export for an N-device mesh)")
 
-    bn = sub.add_parser(
-        "bench", help="headline throughput benchmark (extra args pass through)"
-    )
-    bn.add_argument("rest", nargs=argparse.REMAINDER)
-
     sb = sub.add_parser(
         "serve-bench",
         help="online serving micro-bench: concurrent clients through the "
@@ -2948,7 +2757,7 @@ def main(argv=None) -> int:
                          "endpoint during the bench on this port (0 = an "
                          "ephemeral port, printed on stderr; -1 = off) — "
                          "scrape qps/latency/compile_count mid-run "
-                         "(docs/OBSERVABILITY.md 'graftledger')")
+                         "(docs/OBSERVABILITY.md 'Live telemetry')")
     sb.add_argument("--scenario", default="",
                     choices=["", "burst", "skew", "slowloris", "hostloss",
                              "swapstorm"],
@@ -3006,7 +2815,7 @@ def main(argv=None) -> int:
         help="input-pipeline stage bench: shard read / decode / tokenize / "
              "augment / h2d commit in isolation + the composed real-data "
              "pipeline vs the synthetic loader (schema-validated JSON "
-             "records; CPU-runnable) — docs/PERF.md 'Feeding the headline'",
+             "records; CPU-runnable)",
     )
     from distributed_sigmoid_loss_tpu.data.data_bench import (
         add_data_bench_args,
@@ -3019,10 +2828,9 @@ def main(argv=None) -> int:
 
     ob = sub.add_parser(
         "obs",
-        help="graftscope/graftledger reports: `obs summarize DIR` (merged "
-             "host+device timeline), `obs ledger` (the perf trajectory from "
-             "the append-only run ledger), `obs diff A B` (record or span "
-             "diffs) — docs/OBSERVABILITY.md",
+        help="graftscope reports: `obs summarize DIR` (merged host+device "
+             "timeline), `obs diff DIR_A DIR_B` (two runs' span summaries) "
+             "— docs/OBSERVABILITY.md",
     )
     _add_obs_args(ob)
 
@@ -3060,16 +2868,10 @@ def main(argv=None) -> int:
                          "(default 8 — the same emulated mesh the tests use)")
 
     argv = sys.argv[1:] if argv is None else list(argv)
-    # bench forwards its arguments to bench.py untouched; argparse REMAINDER
-    # cannot capture a LEADING option (`bench --use-pallas` errors), so bench is
-    # routed before parsing. The subparser stays registered for --help and as a
-    # fallback if this short-circuit is ever bypassed.
-    if argv[:1] == ["bench"]:
-        return cmd_bench(argv[1:])
     # obs mixes nargs="*" positionals (diff's two operands) with options;
     # plain parse_args consumes positionals greedily, so flags were only
-    # accepted trailing (`obs diff A B --ledger P` worked, `obs diff
-    # --ledger P A B` errored). parse_intermixed_args fixes that but cannot
+    # accepted trailing (`obs summarize DIR --top 5` worked, `obs summarize
+    # --top 5 DIR` errored). parse_intermixed_args fixes that but cannot
     # traverse subparsers, so obs is routed through a standalone parser
     # built from the same _add_obs_args. The subparser stays registered for
     # --help and as a fallback.
@@ -3085,7 +2887,6 @@ def main(argv=None) -> int:
         "eval": cmd_eval,
         "export": cmd_export,
         "tokenizer": cmd_tokenizer,
-        "bench": lambda a: cmd_bench(a.rest),
         "serve-bench": cmd_serve_bench,
         "data-bench": cmd_data_bench,
         "lint": cmd_lint,

@@ -1,13 +1,13 @@
 """Stage-level input-pipeline benchmark — the ``data-bench`` subcommand.
 
-The headline train bench feeds the chip synthetic batches generated on-device;
+The benchmark's cells feed the chip synthetic batches generated on-device;
 SigLIP-scale pretraining needs the HOST to sustain the same rate through the
 real path: tar shard read → JPEG decode → tokenize → (on-device) augment →
 host→device commit. Until this bench existed, none of those stages had a
 measured number, so a host-bound headline would have been invisible.
 
-What it measures (one JSON record per line, bench.py's record contract,
-validated against ``analysis/bench_schema.py``):
+What it measures (one JSON record per line, validated against
+``analysis/bench_schema.py``):
 
 - each stage in ISOLATION (``data_bench_stage`` records: shard_read, decode,
   tokenize, augment, h2d_commit — items/s each), plus a decode
@@ -20,8 +20,7 @@ validated against ``analysis/bench_schema.py``):
   attributes the bound stage.
 
 CPU-runnable end to end (shards are generated when ``--data-shards`` is not
-given); the same runner backs ``bench.py --data-bench`` for chip-queueable
-runs. jax is imported inside the runner so the module stays importable (e.g.
+given). jax is imported inside the runner so the module stays importable (e.g.
 by argparse plumbing) without initializing a backend.
 """
 
@@ -41,8 +40,7 @@ __all__ = ["add_data_bench_args", "run_data_bench", "make_synthetic_shards"]
 
 
 def add_data_bench_args(ap) -> None:
-    """The data-bench argument surface — shared verbatim by the CLI
-    subcommand and (a subset, via defaults) bench.py's ``--data-bench``."""
+    """The data-bench argument surface of the CLI subcommand."""
     ap.add_argument("--batch", type=int, default=64,
                     help="global batch size (pairs per composed-pipeline "
                          "batch)")
@@ -126,8 +124,7 @@ def make_synthetic_shards(
 
 
 def _emit_record(record: dict, collected: list) -> None:
-    """One JSON line per record, schema-validated (warn, never drop — same
-    contract as bench.py's _emit)."""
+    """One JSON line per record, schema-validated (warn, never drop)."""
     from distributed_sigmoid_loss_tpu.analysis.bench_schema import (
         validate_record,
     )
@@ -141,11 +138,6 @@ def _emit_record(record: dict, collected: list) -> None:
         )
     collected.append(record)
     print(json.dumps(record), flush=True)
-    # graftledger: data-bench records join the same append-only trajectory
-    # as every other bench stream (obs/ledger.py; never fatal).
-    from distributed_sigmoid_loss_tpu.obs.ledger import append_record
-
-    append_record(record, source="data-bench", problems=problems)
 
 
 def _timed(fn, reps: int) -> float:
@@ -159,7 +151,7 @@ def run_data_bench(args, collected: list | None = None) -> int:
     """Run every stage + the composed comparison; returns the exit code.
 
     ``collected`` (a list) receives every emitted record dict — the
-    introspection channel tests and bench.py's relay use.
+    introspection channel the tests use.
     """
     import glob as globmod
 

@@ -9,8 +9,8 @@ the native loader). The train loop always runs a prefetch thread and the main
 oversubscribing a 1-core TPU-VM host with 4 generator threads just adds
 context-switch tax to the exact path the pipeline is trying to hide.
 
-Stdlib-only: imported by modules (native bindings, bench.py's data mode) that
-must not initialize jax at import time.
+Stdlib-only: imported by modules (native bindings, the data-bench's argument
+plumbing) that must not initialize jax at import time.
 """
 
 from __future__ import annotations
@@ -43,8 +43,8 @@ def default_data_workers(reserve: int = RESERVED_HOST_THREADS) -> int:
 
 
 def resolve_data_workers(requested: int | None) -> int:
-    """CLI/bench ``--data-workers`` resolution: 0/None = auto-derive, else the
-    explicit positive value. The resolved number is what bench records carry —
+    """CLI ``--data-workers`` resolution: 0/None = auto-derive, else the
+    explicit positive value. The resolved number is what data-bench records carry —
     a record that says "auto" is not reproducible on a different host."""
     if requested:
         if requested < 0:

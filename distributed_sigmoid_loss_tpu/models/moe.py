@@ -62,8 +62,8 @@ __all__ = [
 ]
 
 
-# Pure stages of the MoE layer, factored out so the per-component perf
-# breakdown (bench.py --moe-breakdown) times EXACTLY the code the module runs.
+# Pure stages of the MoE layer, factored out so a per-stage timing or test
+# (tests/test_moe.py) runs EXACTLY the code the module runs.
 
 
 def router_topk(xg: jax.Array, wr: jax.Array, k: int):

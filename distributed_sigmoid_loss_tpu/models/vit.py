@@ -7,7 +7,7 @@ path. Measured A/B on the chip: perf-NEUTRAL vs nn.Conv (773.4 vs 771.6
 pairs/s headline, run noise) — XLA was already lowering this conv well. (A
 trace initially suggested otherwise: `convolution_add_fusion` at 11.8% of
 device time — but on TPU that op name is XLA's label for MATMUL+bias fusions,
-which run at 175 TFLOP/s there; see docs/PERF.md round-3 notes.) Params keep
+which run at 175 TFLOP/s there; a builder's run from before PR 22.) Params keep
 nn.Conv's exact HWIO kernel layout so checkpoints are interchangeable with the
 conv form.
 Output is the L2-normalizable image embedding; normalization stays OUTSIDE the

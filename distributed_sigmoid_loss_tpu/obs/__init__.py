@@ -1,9 +1,8 @@
 """graftscope: the unified observability layer — host tracing spans and the
 process's record of start-up, static step attribution, a training health
-watchdog, the metrics schema, the run ledger, live telemetry and the lock
-witness.
+watchdog, the metrics schema, live telemetry and the lock witness.
 
-Seven parts, one goal — every perf or robustness claim arrives with its
+Six parts, one goal — every perf or robustness claim arrives with its
 evidence attached, chip or no chip:
 
 - :mod:`.spans` — thread-safe ring-buffered host spans (train loop stages,
@@ -19,20 +18,17 @@ evidence attached, chip or no chip:
 - :mod:`.attribution` — static per-step FLOPs, bytes, and per-kind
   collective wire bytes from the traced jaxpr (no compile), plus compiled-
   executable cost/memory readout, and the chip-free roofline ``mfu_est``
-  stamped on every train metrics line and bench record.
+  stamped on every train metrics line.
 - :mod:`.health` — host-side NaN/Inf + loss-spike watchdog emitting
   structured events, and the flight recorder that dumps the last N metrics
   lines on crash/SIGTERM through the resilience path.
 - :mod:`.metrics_schema` — the declared registry of every train-metrics and
   serve-stats field, validated at emit by ``MetricsLogger`` and enforced
   statically by graftlint's ``repo-metrics-schema`` rule.
-- :mod:`.ledger` — graftledger: the append-only JSONL perf-trajectory ledger
-  every bench emit path appends to (record + environment fingerprint +
-  explicit status, so a dead backend lands as ``no-backend`` instead of a
-  0.0 "measurement"); summarized/diffed by ``obs ledger`` / ``obs diff``.
 - :mod:`.telemetry` — live pull-based metrics: the OpenMetrics-style
   ``/metrics`` exporter the serving stack mounts, plus the atomic-rename
-  telemetry file the train loop writes under ``--obs-dir``.
+  telemetry file the train loop writes under ``--obs-dir`` and the
+  environment fingerprint it stamps there.
 - :mod:`.lockwatch` — graftguard's runtime half: the ``named_lock`` factory
   every host-stack lock routes through, a Goodlock-style potential-deadlock
   witness recording the runtime lock-acquisition graph when
@@ -67,16 +63,6 @@ from distributed_sigmoid_loss_tpu.obs.lockwatch import (  # noqa: F401
     watched_lock,
     witness,
 )
-from distributed_sigmoid_loss_tpu.obs.ledger import (  # noqa: F401
-    append_record,
-    backfill_round_files,
-    diff_records,
-    environment_fingerprint,
-    read_ledger,
-    record_status,
-    trajectory,
-    trajectory_summary,
-)
 from distributed_sigmoid_loss_tpu.obs.spans import (  # noqa: F401
     RECORDER,
     Span,
@@ -86,6 +72,7 @@ from distributed_sigmoid_loss_tpu.obs.spans import (  # noqa: F401
 )
 from distributed_sigmoid_loss_tpu.obs.telemetry import (  # noqa: F401
     TelemetryExporter,
+    environment_fingerprint,
     render_openmetrics,
     write_telemetry_file,
 )
@@ -104,13 +91,6 @@ __all__ = [
     "SERVE_STATS_FIELDS",
     "HEALTH_EVENT_FIELDS",
     "validate_metrics",
-    "append_record",
-    "read_ledger",
-    "record_status",
-    "backfill_round_files",
-    "trajectory",
-    "trajectory_summary",
-    "diff_records",
     "environment_fingerprint",
     "TelemetryExporter",
     "render_openmetrics",

@@ -78,8 +78,8 @@ __all__ = [
 ]
 
 # device_kind -> (peak dense bf16 TFLOP/s, HBM GB/s, aggregate ICI GB/s per
-# chip). Public spec-sheet figures; the TFLOP/s column matches bench.py's
-# PEAK_BF16_TFLOPS so MFU and mfu_est share one basis.
+# chip). Public spec-sheet figures: a second peaks table beside
+# benchmark/peaks.json, which is the one a reported MFU uses (ROADMAP D2b).
 CHIP_SPECS = {
     "TPU v4": (275.0, 1228.0, 300.0),
     "TPU v5 lite": (197.0, 819.0, 200.0),

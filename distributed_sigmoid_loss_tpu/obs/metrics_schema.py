@@ -1,7 +1,7 @@
 """THE declared schema for train metrics lines and serve ``stats()`` fields.
 
-``analysis/bench_schema.py`` fixed per-emit-path drift for bench.py's JSON
-records; this module is the same registry for the OTHER two record streams —
+``analysis/bench_schema.py`` fixed per-emit-path drift for the host-side
+benches' JSON records; this module is the same registry for the OTHER two record streams —
 the train loop's metrics lines (``MetricsLogger.log``) and the serving
 stack's ``stats()`` snapshots / health events (``MetricsLogger.write``).
 Before it, a metric field added in ``train_step.py`` but not

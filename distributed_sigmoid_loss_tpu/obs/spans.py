@@ -1,6 +1,6 @@
 """Host-side tracing spans: the host half of graftscope's unified timeline.
 
-``utils.profiling.trace`` captures what the DEVICE did (XLA op spans with
+``jax.profiler.trace`` captures what the DEVICE did (XLA op spans with
 ``hlo_category`` / ``model_flops`` annotations); nothing captured what the
 HOST did around it — where a step interval went between fetch, h2d commit,
 dispatch, eval and checkpoint, or where a serve request sat between queue,
@@ -17,7 +17,7 @@ batch assembly and the engine call. :class:`SpanRecorder` fills that half:
   recording on (pinned by the bounded-overhead test in tests/test_obs.py).
 - **On the profiler's clock while a capture runs**: an enabled ``span()``
   also enters a ``jax.profiler.TraceAnnotation`` of the same name, so any
-  capture that is running (``utils.profiling.trace``, ``bench --profile``,
+  capture that is running (``jax.profiler.trace``, ``chip_smoke.py --time-attention``,
   the benchmark's traced run) carries ``fetch``, ``h2d_commit``, ``step``,
   ``eval``, ``checkpoint`` and the serve stages in its host plane, on the
   clock of the device events: an idle gap of the device can be put down to

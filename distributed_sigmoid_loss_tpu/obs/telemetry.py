@@ -23,16 +23,22 @@ log. Two pieces:
 Plus :func:`write_telemetry_file` — the train loop's push-side twin: an
 atomic-rename (tmp + ``os.replace``) JSON file a soak run overwrites each
 log interval, so ``watch cat telemetry.json`` style tailing never sees a
-torn write and never touches the metrics log.
+torn write and never touches the metrics log. :func:`environment_fingerprint`
+is what the train loop stamps into that file: host, git sha, jax version and,
+where a backend already runs, its device kind and count.
 
 Stdlib-only module (the obs import discipline: no jax at import time).
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import re
+import socket
+import subprocess
+import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -44,7 +50,12 @@ __all__ = [
     "render_openmetrics",
     "TelemetryExporter",
     "write_telemetry_file",
+    "environment_fingerprint",
 ]
+
+_REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 _PERCENTILE_KEY = re.compile(r"p(\d+)_ms$")
 
@@ -291,3 +302,41 @@ def write_telemetry_file(path: str, payload: Mapping) -> None:
         f.flush()
         os.fsync(f.fileno())
     os.replace(tmp, path)
+
+
+@functools.lru_cache(maxsize=1)
+def _git_sha() -> str:
+    try:
+        r = subprocess.run(
+            ["git", "-C", _REPO_ROOT, "rev-parse", "--short", "HEAD"],
+            capture_output=True, text=True, timeout=5,
+        )
+        return r.stdout.strip() if r.returncode == 0 else ""
+    except Exception:
+        return ""
+
+
+def environment_fingerprint() -> dict:
+    """Who/what produced this telemetry: host, git sha, jax version and — only
+    when a backend is ALREADY initialized — device kind/count.
+
+    Deliberately passive about jax: importing it here would drag a multi-GB
+    runtime into a stdlib module, and touching ``jax.devices()`` on an
+    uninitialized process initialises the backend — it would claim the
+    host's accelerator just to stamp a fingerprint. An already-imported,
+    already-initialized jax is read; anything else is left alone.
+    """
+    env = {"host": socket.gethostname(), "git_sha": _git_sha()}
+    jax_mod = sys.modules.get("jax")
+    if jax_mod is not None:
+        env["jax"] = getattr(jax_mod, "__version__", "?")
+        try:
+            from jax._src import xla_bridge  # noqa: PLC0415
+
+            if getattr(xla_bridge, "_backends", None):
+                devs = jax_mod.devices()
+                env["device_kind"] = devs[0].device_kind
+                env["device_count"] = len(devs)
+        except Exception:
+            pass
+    return env

@@ -71,8 +71,8 @@ __all__ = [
 NEGATIVE_ONLY_OFFSET = -(2 ** 24)
 
 # Default tile sizes: one MXU-native 128-sublane image tile against a
-# 256-lane text tile keeps the per-step working set ~1.2 MB at d=768 (budget
-# math in docs/PERF.md "Streaming 2-D kernel") while the 256-wide tile
+# 256-lane text tile keeps the per-step working set ~1.2 MB at d=768 (two
+# operand tiles, the logits tile and its temporaries) while the 256-wide tile
 # amortizes the revisit traffic on zimg.
 DEFAULT_TILE_B = 128
 DEFAULT_TILE_N = 256
@@ -81,7 +81,7 @@ DEFAULT_TILE_N = 256
 # "streaming" / "streaming_int8" when a dispatch picked the kernel, "xla" when
 # a use_pallas request fell back to the XLA block. A record claiming
 # use_pallas while every block traced the fallback is config drift between
-# argv and the program — bench.py cross-checks against THIS,
+# argv and the program — chip_smoke.py and the tests read THIS,
 # not argv (registered in analysis/repo_lint.py MUTABLE_GLOBAL_ALLOWLIST).
 _TRACED_LOSS_KERNELS: set[str] = set()
 

@@ -30,7 +30,7 @@ Two gears, one recipe:
   backward is EXACTLY the unquantized ``lax.dot_general`` VJP on the saved
   full-precision operands — the gradient the bf16/f32 layer would have
   produced for the same cotangent. This is what breaks the bf16 roofline
-  (docs/PERF.md "Why an int8 training track"): the v5e int8 MXU peak is 2x
+  (the reason for an int8 training track): the v5e int8 MXU peak is 2x
   bf16, and the bf16 MFU=1.0 ceiling sits below the 1.5x-A100 target.
   ``int8_expert_matmul_ste`` is the MoE-expert analogue.
 

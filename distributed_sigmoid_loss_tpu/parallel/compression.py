@@ -75,7 +75,7 @@ def sparsify_topk(
 
     ``approximate=True`` (default) selects via ``lax.approx_max_k`` — the
     TPU-optimized bucketed top-k. Measured on chip at b16 gradient scale
-    (docs/PERF.md): exact ``lax.top_k`` costs 227 ms/step (61% of a train
+    (before PR 22): exact ``lax.top_k`` costs 227 ms/step (61% of a train
     step — compute-prohibitive), approx 55 ms at 98.5% recall. Bucketed
     selection can occasionally miss entries ABOVE the k-th magnitude (bucket
     collisions keep only the bucket max), so approximation is only sound
@@ -124,7 +124,7 @@ def compressed_axis_mean(tree, axis_name: str, ef=None, method: str = "int8",
     8 bytes/kept entry — ~50x fewer at the standard 1%; run it WITH error
     feedback, the dropped 99% is pure bias otherwise).
     ``topk_approximate=False`` switches the topk selection to exact
-    ``lax.top_k`` (4x slower on TPU at gradient scale, docs/PERF.md).
+    ``lax.top_k`` (4x slower on TPU at gradient scale, see ``sparsify_topk``).
 
     Returns ``(mean_tree, new_ef)`` — ``mean_tree`` replicated over the axis,
     ``new_ef`` the residual ``(t + ef) - decompress(compress(t + ef))`` to

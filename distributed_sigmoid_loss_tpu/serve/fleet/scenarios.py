@@ -2,7 +2,7 @@
 
 Three scenarios, run through the same :func:`~..siege.run_scenario`
 closed-loop multi-tenant harness as the single-host drills (same typed
-outcome taxonomy, same LEDGER.jsonl record contract, fleet fields added):
+outcome taxonomy, same one-line record contract, fleet fields added):
 
 - ``fleet-rolling-swap`` — a coordinated swap wave every 200ms under the
   burst load shape: zero errors, per-session versions monotone, never two

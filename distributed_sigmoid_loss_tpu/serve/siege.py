@@ -21,7 +21,7 @@ contracts that are never exercised rot. This module makes them drillable:
   :class:`~.admission.AdmissionController`-fronted submit callable and
   emits one schema-validated degradation record (p99 vs offered load,
   per-tenant shed_rate, recovery_time_s, silent_drops) for the
-  ``serve-bench --scenario`` path to land in LEDGER.jsonl.
+  ``serve-bench --scenario`` path to print.
 
 Module-level imports stay stdlib + admission + utils (``serve.batcher``
 imports this module for its injection point, so importing service/engine

@@ -927,7 +927,7 @@ def make_compressed_train_step(
     sharded_step._cache_size = (
         lambda: _jitted[0]._cache_size() if _jitted else 0
     )
-    # AOT path (bench.py's step.lower(...).compile()): same capture, same
+    # AOT path (a caller's step.lower(...).compile()): same capture, same
     # single inner jit — lowering and calling share one executable.
     sharded_step.lower = lambda state, batch: _inner(state).lower(state, batch)
     return sharded_step, batch_sharding

@@ -908,7 +908,7 @@ def make_train_step(
     per-microstep bf16 round-off — a ~``sqrt(M) * 2^-9`` relative random walk
     on the sum, far below gradient noise at M=16. What it buys: the
     params-sized accumulator's read+write per microstep halves (the HBM
-    traffic diagnosed as the accumulation tax in docs/PERF.md), and its
+    traffic behind the accumulation tax: PERF.md's accum_ms), and its
     resident footprint halves — the lever that lets larger microbatches fit.
     Parity oracles keep the f32 default (tests/test_train_step.py).
 
@@ -922,7 +922,7 @@ def make_train_step(
     ``gradcache_embed_dtype`` (e.g. ``"bfloat16"``, with
     ``accum_negatives="global"``) stores the GradCache embedding stash in that
     dtype — see :func:`run_gradcache`; attacks the exact-negatives path's
-    bandwidth share of its ~21% tax (docs/PERF.md) at the cost of bf16
+    bandwidth share of its tax (~21% before PR 22) at the cost of bf16
     rounding on the island's loss/cotangents.
     """
     validate_trainable_quant(model)
@@ -1219,7 +1219,7 @@ def make_train_step(
     sharded_step._cache_size = (
         lambda: _jitted[0]._cache_size() if _jitted else 0
     )
-    # AOT path (bench.py's step.lower(...).compile()): same capture, same
+    # AOT path (a caller's step.lower(...).compile()): same capture, same
     # single inner jit — lowering and calling share one executable.
     sharded_step.lower = lambda state, batch: _inner(state).lower(state, batch)
     sharded_step.accum_record, sharded_step.stack_record = accum_record, stack_record

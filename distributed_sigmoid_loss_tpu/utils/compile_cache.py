@@ -2,7 +2,7 @@
 compiler did in this process — the one place that says.
 
 A cold B/16 train step costs minutes of compile; every entry point (``cli.main``,
-``bench.py``, ``chip_smoke.py``, ``__graft_entry__.py``, the test bootstrap, the
+``chip_smoke.py``, ``__graft_entry__.py``, the test bootstrap, the
 benchmark's harness) calls :func:`configure_compile_cache` so repeated runs hit disk
 instead. The directory must be placeable from outside (a chip machine may mount its
 own), hence the rule:

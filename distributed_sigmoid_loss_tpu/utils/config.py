@@ -344,7 +344,7 @@ class SigLIPConfig:
     @classmethod
     def l14(cls, **vision_kw) -> "SigLIPConfig":
         """ViT-L/14 + width-1024 text tower (BASELINE.json config #5). The single
-        source of truth for the L/14 pairing — bench and CLI both build from here."""
+        source of truth for the L/14 pairing: the CLI builds from here."""
         return cls(
             vision=ViTConfig.vit_l14(**vision_kw),
             text=TextConfig(width=1024, num_heads=16),

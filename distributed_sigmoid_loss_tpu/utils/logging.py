@@ -68,9 +68,9 @@ class MetricsLogger:
     ``schema`` (a field set from ``obs/metrics_schema.py``, with
     ``schema_prefixes`` for dynamic families like ``eval/``) turns on
     emit-time validation: an undeclared field warns on stderr but the line
-    still prints — a metric must never be lost to its own validator (the
-    bench ``_emit`` convention; graftlint's ``repo-metrics-schema`` rule is
-    the static tier-1 enforcement of the same registry).
+    still prints — a metric must never be lost to its own validator
+    (graftlint's ``repo-metrics-schema`` rule is the static tier-1
+    enforcement of the same registry).
     """
 
     def __init__(self, stream: IO | None = None, every: int = 1,

@@ -8,7 +8,6 @@ where-the-time-goes table PERF.md wants, offline — no TensorBoard needed:
 
 from __future__ import annotations
 
-import contextlib
 import glob as _glob
 import gzip
 import json
@@ -21,7 +20,6 @@ from typing import Callable
 import jax
 
 __all__ = [
-    "trace",
     "time_step",
     "throughput",
     "compiled_memory_stats",
@@ -29,17 +27,6 @@ __all__ = [
     "summarize_trace",
     "summarize_device_ops",
 ]
-
-
-@contextlib.contextmanager
-def trace(logdir: str):
-    """Capture a Perfetto/XPlane trace of the enclosed region (view with TensorBoard or
-    ui.perfetto.dev)."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 def time_step(fn: Callable, *args, warmup: int = 3, iters: int = 10) -> float:
@@ -80,13 +67,12 @@ def memory_stats_of_compiled(compiled) -> dict | None:
 
     Returns the ``memory_analysis()`` figures as a plain dict — the
     ``_MEM_FIELDS`` byte counts plus ``peak_bytes`` (arguments + outputs +
-    temps + generated code − aliased, the figure bench.py publishes as
-    ``peak_hbm_gb``) — or None when the backend doesn't expose the analysis.
+    temps + generated code − aliased) — or None when the backend doesn't expose the analysis.
     ``temp_size_in_bytes`` is the number a memory OPTIMIZATION should be
     judged by: arguments/outputs are fixed by the program's signature, temps
     are what the implementation choice actually changes.
 
-    Static-analysis caveat (docs/PERF.md round-3): the sum can exceed
+    Static-analysis caveat: the sum can exceed
     physical HBM because the allocator reuses buffers the analysis counts
     separately — comparisons between two programs are meaningful, the
     absolute number is an upper bound.
@@ -223,7 +209,7 @@ def summarize_device_ops(logdir: str, top: int = 12) -> dict:
     attribution axis. Op NAMES mislead on TPU: a ``convolution_add_fusion``
     there is usually a MATMUL+bias fusion ("convolution" is how XLA:TPU frames
     dots in fusion names), so name-based tables make matmul time look like conv
-    waste (this bit us: docs/PERF.md round-3 notes).
+    waste (it did, in an early reading of the patch embedding).
 
     Returns ``{"categories": [(category, ms, share, tflops, gbps), ...],
     "top_ops": [(dedup_name, ms, count, tflops, gbps), ...]}`` where ``tflops``

@@ -4,7 +4,7 @@ formats (parallel/compression.py), on one chip.
 The collectives need >= 2 slices, but the quantize/sparsify halves run per
 device and their cost lands on every training step — this measures that
 overhead at real gradient scale (a b16-shaped gradient tree, ~110M f32 entries) so the
-feature's price is a recorded number, not a guess (docs/PERF.md). The
+feature's price is a measured number, not a guess. The
 tree below sums to ~110M entries — b16's 86M tower params plus the
 32k-vocab embedding table's gradient.
 

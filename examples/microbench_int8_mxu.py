@@ -6,7 +6,7 @@ variants (bf16; raw int8 with both operands pre-quantized; dynamic int8
 quantizing both in-step; static int8 with weights pre-quantized) at a
 serving-relevant GEMM shape (the b16 wi projection at batch 512, s=196:
 M=100352) and prints achieved TOP/s so the int8 serving design can be
-grounded in what the compiler actually emits (docs/PERF.md "int8 serving").
+grounded in what the compiler actually emits.
 """
 
 import jax

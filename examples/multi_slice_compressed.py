@@ -6,8 +6,8 @@ The scenario: data parallelism spans two TPU slices. Within a slice,
 gradients sync over ICI at f32 (bandwidth is ample); between slices they
 cross DCN — the slow link — so the framework quantizes that hop to int8 (or
 top-k-sparsifies it) with error feedback carrying the residual into the next
-step (train/compressed_step.py, parallel/compression.py; measured prices in
-docs/PERF.md). The same thing via the CLI:
+step (train/compressed_step.py, parallel/compression.py; for the compute
+price on one chip see examples/microbench_grad_compression.py). The same thing via the CLI:
 
     python -m distributed_sigmoid_loss_tpu train --cpu-devices 8 --tiny \\
         --dcn-slices 2 --grad-compression int8 --steps 20 --batch 16

@@ -29,19 +29,6 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
 
-# graftledger isolation: every bench/serve-bench/data-bench emit path appends
-# to the run ledger (obs/ledger.py), which defaults to the COMMITTED
-# LEDGER.jsonl at the repo root — test runs (including the bench.py
-# subprocesses the suites spawn, which inherit the env) must land in a
-# scratch file instead of dirtying the real trajectory. Tests that exercise
-# the ledger itself pass explicit paths.
-if "DSL_LEDGER_PATH" not in os.environ:
-    import tempfile
-
-    os.environ["DSL_LEDGER_PATH"] = os.path.join(
-        tempfile.gettempdir(), "dsl_test_ledger.jsonl"
-    )
-
 # XLA compile reuse: the tier-1 gate's dominant cost is CPU XLA compiles,
 # and the subprocess suites (cli export, quant eval, pallas train,
 # serve-bench, multihost workers) each cold-recompile tiny-model steps that
@@ -76,7 +63,6 @@ jax.config.update("jax_enable_x64", False)
 _STANDARD_MODULES = {
     "test_adaptive_compression",
     "test_analysis",
-    "test_bench_records",
     "test_chip_smoke",
     "test_bf16_numerics",
     "test_compat",
@@ -87,7 +73,7 @@ _STANDARD_MODULES = {
     "test_distindex",
     "test_distributed_parity",
     "test_fleet",
-    "test_graftledger",
+    "test_telemetry",
     "test_learned_codec",
     "test_lockwatch",
     "test_obs",

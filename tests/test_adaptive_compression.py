@@ -21,7 +21,7 @@ Oracles, in the established compression-suite style (test_grad_compression):
   residual fixtures (plain and shard_map-wrapped) and the new schema /
   config-space rows are registered, with unregistered-neighbor falsification.
 
-Tiering (the 870s tier-1 budget): the module is conftest-standard, but the
+Tiering (tier-1's time limit): the module is conftest-standard, but the
 step-level oracles that compile the full (2, 4) hybrid step — parity vs the
 uncompressed step, the scheme-swap no-recompile pin, the 0.25x-bf16 wire
 oracle, the zero1+accum composition, and the full config-product ef-indices
@@ -862,9 +862,6 @@ def test_step_config_jaxprs_arm_ef_indices():
 
 
 def test_new_fields_registered_with_falsification():
-    from distributed_sigmoid_loss_tpu.analysis.bench_schema import (
-        validate_record,
-    )
     from distributed_sigmoid_loss_tpu.obs.metrics_schema import (
         validate_metrics,
     )
@@ -876,14 +873,7 @@ def test_new_fields_registered_with_falsification():
     }
     assert validate_metrics(line) == []
     assert validate_metrics({"dcn_wire_bytez": 1.0}) != []
-
-    rec = {
-        "metric": "m", "value": 1.0, "unit": "u",
-        "grad_compression": "adaptive", "dcn_slices": 2,
-        "dcn_budget_mbps": 50.0, "topk_frac": 0.01, **line,
-    }
-    assert validate_record(rec) == []
-    assert validate_record({**rec, "scheme_hist": []}) != []
+    assert validate_metrics({**line, "scheme_hist": []}) != []
 
 
 def test_config_space_adaptive_rows():
@@ -940,31 +930,33 @@ def test_cli_dcn_budget_without_adaptive_exits_2():
     assert "--dcn-budget-mbps" in proc.stderr
 
 
-def test_bench_adaptive_refusals_exit_2():
-    import os
+@pytest.mark.parametrize("kw, msg", [
+    # a dcn axis nothing compresses
+    (dict(dcn_slices=2), "--dcn-slices without --grad-compression is a silent no-op"),
+    # the compressed sync has no ring form
+    (dict(dcn_slices=2, grad_compression="adaptive", variant="ring"),
+     "--variant all_gather or unset"),
+])
+def test_train_conflict_predicate_pins_dcn_refusals(kw, msg):
+    """The two refusals of `train`'s argument check that no CLI run above
+    reaches: in process, on the predicate `cmd_train` exits 2 with."""
+    import argparse
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for argv, msg in (
-        (["--grad-compression", "adaptive"], "--dcn-slices >= 2"),
-        (["--dcn-slices", "2"], "silent no-op"),
-        (
-            [
-                "--grad-compression", "int8", "--dcn-slices", "2",
-                "--variant", "all_gather", "--dcn-budget-mbps", "9",
-            ],
-            "adaptive/learned only",
-        ),
-        (
-            ["--grad-compression", "adaptive", "--dcn-slices", "2"],
-            "--variant all_gather",
-        ),
-    ):
-        proc = subprocess.run(
-            [sys.executable, "bench.py", "4", "2", "tiny", *argv],
-            capture_output=True, text=True, timeout=120, cwd=repo,
-        )
-        assert proc.returncode == 2, (argv, proc.stderr[-300:])
-        assert msg in proc.stderr, (argv, proc.stderr[-300:])
+    from distributed_sigmoid_loss_tpu.cli import _train_config_conflicts
+
+    base = dict(
+        ep=1, moe_aux_weight=None, moe_experts=0, pp=1, pp_microbatches=0,
+        accum=1, accum_bf16=False, accum_negatives="local",
+        gradcache_bf16=False, loss_impl="fused", variant="all_gather",
+        ring_overlap=False, zero1=False, update_sharding="",
+        grad_compression="", use_pallas=False, loss_family="sigmoid",
+        ema_decay=None, watchdog="warn", ckpt_dir="",
+        topk_frac=0.01, topk_exact=False, dcn_slices=1,
+        dcn_budget_mbps=None, controller=None, emu_dcn_mbps=None,
+    )
+    assert _train_config_conflicts(argparse.Namespace(**base)) is None
+    got = _train_config_conflicts(argparse.Namespace(**{**base, **kw}))
+    assert got and msg in got, got
 
 
 @pytest.mark.slow

@@ -905,26 +905,25 @@ def test_fleet_stats_fields_registered_both_sides():
         "serve/fleet/router.py::bogus_fleet_stat"
     ]
     # bench-record side: the fleet_siege record fields are registered...
-    assert repo_lint.check_bench_record_fields(
-        'record = {"metric": "fleet_siege", "fleet_replicas": 3,\n'
-        '          "lease_ttl_s": 0.5, "ceiling_rate": 120.0,\n'
-        '          "peak_admitted_rate": 90.0, "over_ceiling_samples": 0,\n'
-        '          "reroutes": 1, "lease_reclaims": 2, "wave_id": 7}\n'
-    ) == []
+    rec = {
+        "metric": "fleet_siege", "value": 0.0, "unit": "shed_rate",
+        "fleet_replicas": 3, "lease_ttl_s": 0.5, "ceiling_rate": 120.0,
+        "peak_admitted_rate": 90.0, "over_ceiling_samples": 0,
+        "reroutes": 1, "lease_reclaims": 2, "wave_id": 7,
+    }
+    assert validate_record(rec) == []
     # ...and an invented one trips (the falsification half).
-    bad_rec = repo_lint.check_bench_record_fields(
-        'record = {"metric": "fleet_siege", "bogus_fleet_field": 1}\n'
+    assert any(
+        "bogus_fleet_field" in p
+        for p in validate_record({**rec, "bogus_fleet_field": 1})
     )
-    assert _rules_of(bad_rec) == ["repo-bench-record"]
-    assert bad_rec[0].subject == "bench.py::bogus_fleet_field"
 
 
 def test_graftcodec_fields_registered_both_sides():
-    """graftcodec schema, both sides: the five new fields
-    (codec_recon_err / error_budget / controller_mode / dcn_measured_mbps /
-    wire_savings_wallclock_ratio) ride the train metrics line AND the bench
-    record, with an invented neighbor tripping each registry (the
-    falsification half — a typo'd stamp must not validate)."""
+    """graftcodec schema: the five fields (codec_recon_err / error_budget /
+    controller_mode / dcn_measured_mbps / wire_savings_wallclock_ratio) ride
+    the train metrics line, with an invented neighbor tripping the registry
+    (the falsification half — a typo'd stamp must not validate)."""
     good_line = (
         'metrics = {"loss": 1, "codec_recon_err": 0.03,\n'
         '           "error_budget": 0.12, "controller_mode": "budgeted",\n'
@@ -950,86 +949,14 @@ def test_graftcodec_fields_registered_both_sides():
         "wire_savings_wallclock_ratio": 1.31,
     }) == []
     assert validate_metrics({"wire_savings_wallclock_ration": 1.3}) != []
-    # Bench-record side: the emulated-A/B stamps are registered...
-    assert repo_lint.check_bench_record_fields(
-        'record = {"metric": "m", "controller_mode": "greedy",\n'
-        '          "error_budget": 0.02, "codec_recon_err": 0.04,\n'
-        '          "emu_dcn_mbps": 200.0, "dcn_measured_mbps": 171.5,\n'
-        '          "wire_savings_wallclock_ratio": 1.22}\n'
-    ) == []
-    rec = {
-        "metric": "m", "value": 1.0, "unit": "u",
-        "controller_mode": "budgeted", "error_budget": 0.1,
-        "codec_recon_err": 0.02, "emu_dcn_mbps": 200.0,
-        "dcn_measured_mbps": 171.5, "wire_savings_wallclock_ratio": 1.22,
-    }
-    assert validate_record(rec) == []
-    # ...and the invented neighbor trips both registries.
-    assert validate_record({**rec, "dcn_measured_mbpz": 1.0}) != []
-    bad_rec = repo_lint.check_bench_record_fields(
-        'record = {"metric": "m", "emu_dcn_mbpz": 200.0}\n'
-    )
-    assert _rules_of(bad_rec) == ["repo-bench-record"]
-    assert bad_rec[0].subject == "bench.py::emu_dcn_mbpz"
 
 
 def test_metrics_schema_green_on_shipped_tree():
     assert repo_lint.check_metrics_schema() == []
 
 
-def test_unregistered_bench_record_field_trips():
-    src = 'record = {"metric": "m", "value": 1.0, "bogus_field": 2}\n'
-    findings = repo_lint.check_bench_record_fields(src)
-    assert _rules_of(findings) == ["repo-bench-record"]
-    assert findings[0].subject == "bench.py::bogus_field"
-    # subscript-assign and _emit literals are covered too
-    assert repo_lint.check_bench_record_fields(
-        'record["another_bogus"] = 1\n'
-    )[0].subject == "bench.py::another_bogus"
-    assert repo_lint.check_bench_record_fields(
-        '_emit({"metric": "m", "value": 0.0, "unit": "x"})\n'
-    ) == []
-
-
-def test_ledger_emit_rule_trips_on_bypass_and_missing_append():
-    """repo-ledger-emit: a record print outside _emit (a path bypassing the
-    ledger) and an _emit without the ledger append both trip; the shipped
-    discipline — every print(json.dumps(...)) inside a ledger-appending
-    _emit — stays green."""
-    good = (
-        "import json\n"
-        "def _emit(record):\n"
-        "    from distributed_sigmoid_loss_tpu.obs.ledger import "
-        "append_record\n"
-        "    print(json.dumps(record))\n"
-        "    append_record(record)\n"
-    )
-    assert repo_lint.check_ledger_emit(good) == []
-    rogue = good + (
-        "def sneaky(record):\n"
-        "    print(json.dumps(record))\n"
-    )
-    findings = repo_lint.check_ledger_emit(rogue)
-    assert _rules_of(findings) == ["repo-ledger-emit"]
-    assert findings[0].subject == "bench.py::sneaky"
-    no_append = (
-        "import json\n"
-        "def _emit(record):\n"
-        "    print(json.dumps(record))\n"
-    )
-    findings = repo_lint.check_ledger_emit(no_append)
-    assert [f.subject for f in findings] == ["bench.py::_emit"]
-    # no _emit at all: the single-emitter contract itself is gone
-    none = repo_lint.check_ledger_emit("x = 1\n")
-    assert [f.subject for f in none] == ["bench.py::_emit"]
-
-
-def test_ledger_emit_green_on_shipped_tree():
-    assert repo_lint.check_ledger_emit() == []
-
-
 # ---------------------------------------------------------------------------
-# bench record schema (shared by bench.py _emit and the lint rule)
+# host-side bench record schema (data-bench, serve-bench, siege, fleet)
 # ---------------------------------------------------------------------------
 
 
@@ -1044,22 +971,6 @@ def test_validate_record_contract():
     )
     assert any("bogus" in p for p in unknown)
     assert validate_record([1, 2]) != []
-
-
-def test_bench_emit_paths_validate_against_schema(capsys):
-    import bench
-
-    bench._emit({"metric": "m", "value": 1.0, "unit": "pairs/s/chip",
-                 "model": "tiny", "per_chip_batch": 4, "steps": 2})
-    out, err = capsys.readouterr()
-    rec = json.loads(out.strip())
-    assert validate_record(rec) == []
-    assert "schema violation" not in err
-    # and the validator actually guards _emit: an unregistered field warns
-    bench._emit({"metric": "m", "value": 0.0, "unit": "x", "bogus": 1})
-    out, err = capsys.readouterr()
-    assert json.loads(out.strip())["bogus"] == 1  # record never lost
-    assert "schema violation" in err
 
 
 # ---------------------------------------------------------------------------
@@ -1084,7 +995,7 @@ def test_cli_lint_json_report(capsys):
     report = json.loads(out)
     assert report["findings"] == []
     assert "repo-doc-stale" in report["disabled"]
-    assert "repo-bench-record" in report["rules_checked"]
+    assert "repo-doc-code" in report["rules_checked"]
     assert "repo-doc-stale" not in report["rules_checked"]
 
 

@@ -136,12 +136,6 @@ def test_eval_missing_checkpoint_clear_error(tmp_path):
     assert "no checkpoint found" in proc.stderr
 
 
-def test_bench_rejects_cpu_devices():
-    proc = _run(["bench", "--cpu-devices", "8"], timeout=60)
-    assert proc.returncode == 2
-    assert "real chip" in proc.stderr
-
-
 def test_train_two_process_coordinator():
     """`train --coordinator` runs one job across two real OS processes (each with
     2 virtual CPU devices) and both report identical global losses."""

@@ -691,13 +691,9 @@ def test_cli_fleet_grammar_refusals_exit_2():
     ) == 2  # no sibling to reroute to
 
 
-def test_cli_fleet_hostloss_emits_schema_valid_ledger_record(
-    tmp_path, monkeypatch, capsys,
-):
+def test_cli_fleet_hostloss_emits_schema_valid_record(capsys):
     from distributed_sigmoid_loss_tpu.cli import main as cli_main
 
-    ledger = tmp_path / "ledger.jsonl"
-    monkeypatch.setenv("DSL_LEDGER_PATH", str(ledger))
     rc = cli_main(
         ["serve-bench", "--fleet-scenario", "fleet-hostloss",
          "--fleet-replicas", "3", "--lease-ttl-s", "0.3",
@@ -710,11 +706,3 @@ def test_cli_fleet_hostloss_emits_schema_valid_ledger_record(
     assert record["silent_drops"] == 0
     assert record["over_ceiling_samples"] == 0
     assert validate_record(record) == []
-    # The same record landed in the run ledger (the trajectory contract).
-    rows = [json.loads(ln) for ln in ledger.read_text().splitlines()]
-    entry = next(
-        r for r in rows
-        if r.get("record", {}).get("metric") == "fleet_siege"
-    )
-    assert entry["source"] == "serve-bench"
-    assert "schema_violations" not in entry

@@ -20,7 +20,7 @@ Oracles, in the adaptive-suite style (test_adaptive_compression):
   step over a 10-step sweep with the CodecTrainer retraining online (codec
   re-staged every round, jit cache stays at 1) while the scheme hist shows
   rung 6 engaged;
-- the CLI and bench refuse the new knobs where they would be silent no-ops
+- the CLI refuses the new knobs where they would be silent no-ops
   (``--controller`` without an adaptive family, ``--emu-dcn-mbps`` without a
   dcn mesh axis), exit 2 with the real reason.
 
@@ -426,33 +426,3 @@ def test_cli_emu_without_dcn_axis_exits_2():
     assert proc.returncode == 2, (proc.returncode, proc.stderr[-500:])
     assert "--emu-dcn-mbps" in proc.stderr
     assert "--dcn-slices >= 2" in proc.stderr
-
-
-def test_bench_codec_refusals_exit_2():
-    import os
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for argv, msg in (
-        (["--controller", "budgeted"], "silent no-op"),
-        (
-            [
-                "--grad-compression", "int8", "--dcn-slices", "2",
-                "--variant", "all_gather", "--controller", "budgeted",
-            ],
-            "adaptive/learned only",
-        ),
-        (["--emu-dcn-mbps", "100"], "silent no-op"),
-        (
-            [
-                "--grad-compression", "int8", "--dcn-slices", "2",
-                "--variant", "all_gather", "--emu-dcn-mbps", "0",
-            ],
-            "must be > 0",
-        ),
-    ):
-        proc = subprocess.run(
-            [sys.executable, "bench.py", "4", "2", "tiny", *argv],
-            capture_output=True, text=True, timeout=120, cwd=repo,
-        )
-        assert proc.returncode == 2, (argv, proc.stderr[-300:])
-        assert msg in proc.stderr, (argv, proc.stderr[-300:])

@@ -209,7 +209,7 @@ def host_plane_events(logdir, names):
 def test_obs_summarize_merges_host_and_device(tmp_path, capsys):
     """The acceptance surface: one `obs summarize DIR` over a dir holding
     BOTH a host-span export and a device capture (the gzipped Perfetto JSON
-    utils.profiling.trace writes) prints the host table AND the device
+    jax.profiler.trace writes) prints the host table AND the device
     hlo_category table. The one file that holds both halves on one clock is
     the profiler's own: while a capture runs, an enabled recorder's span is
     in its host plane, nested as it was on the host and as long as the

@@ -121,8 +121,8 @@ def test_negative_only_and_offset_blocks():
 
 def test_engagement_recorder_truths():
     """The trace-time recorder: kernel engagement, int8 engagement, and the
-    XLA fallback are all distinguishable — what bench.py's record
-    cross-check (pallas_engaged/pallas_mismatch) reads."""
+    XLA fallback are all distinguishable — what chip_smoke.py reads to see
+    that a use_pallas request engaged the kernel."""
     zimg, ztxt = batch(32, 32, 128, seed=2)
     p = init_loss_params()
     reset_traced_loss_kernels()

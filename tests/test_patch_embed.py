@@ -1,7 +1,7 @@
 """PatchEmbed (reshape+matmul) must be a drop-in for the strided conv.
 
 The patchify layer was rewritten from ``nn.Conv`` to an explicit reshape + one
-matmul: measured perf-neutral on the chip (docs/PERF.md round-3 notes), kept
+matmul: measured perf-neutral on the chip (a builder's run before PR 22), kept
 because the MXU lowering is explicit rather than trusted to XLA's conv path.
 These tests pin the contract that made the swap safe: the
 param tree is nn.Conv's exact HWIO layout, and outputs match the conv to f32

@@ -13,7 +13,6 @@ from distributed_sigmoid_loss_tpu.utils.profiling import (
     summarize_trace,
     throughput,
     time_step,
-    trace,
 )
 
 
@@ -22,7 +21,7 @@ def test_trace_and_summarize(tmp_path):
     f = jax.jit(lambda x: (x @ x).sum())
     x = jnp.ones((128, 128))
     float(f(x))  # compile outside the capture
-    with trace(d):
+    with jax.profiler.trace(d):
         for _ in range(3):
             float(f(x))
     summary = summarize_trace(d, top=5)

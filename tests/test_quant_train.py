@@ -17,7 +17,7 @@ compressed DCN sync) and the convergence-parity oracle live in
 tests/test_quant_train_convergence.py (slow tier).
 
 No reference analogue (the reference has no model layer); this is the
-TPU-first route to the >bf16-roofline perf target (docs/PERF.md "Why an int8
+TPU-first route to the >bf16-roofline perf target ("Why an int8
 training track").
 """
 

@@ -229,18 +229,20 @@ def test_opt_memory_drops_at_least_point6_w_at_w8():
 # ------------------------------------------------- record / schema fixtures
 
 
-def test_bench_record_fields_registered():
-    from distributed_sigmoid_loss_tpu.analysis.bench_schema import (
-        validate_record,
+def test_metrics_line_fields_registered():
+    """The two graftshard fields every `train` metrics line carries under
+    --update-sharding are declared in the train-metrics schema, and a typo'd
+    neighbour is not."""
+    from distributed_sigmoid_loss_tpu.obs.metrics_schema import (
+        validate_metrics,
     )
 
     good = {
-        "metric": "siglip_vittiny_train_pairs_per_sec_per_chip",
-        "value": 1.0, "unit": "pairs/s/chip",
+        "loss": 1.0,
         "update_sharding": "full", "opt_mem_bytes_per_replica": 90872,
     }
-    assert validate_record(good) == []
-    assert validate_record(
+    assert validate_metrics(good) == []
+    assert validate_metrics(
         {**good, "opt_mem_bytes_per_rep1ica": 1}
     ) != []
 
