@@ -441,7 +441,8 @@ def probe_imperative(cfg: StepConfig) -> tuple[bool, str]:
     environmental, not config-space, and are out of probe scope: the text
     tower's block options (utils.config.BLOCK_OPTIONS: norm, sandwich_norm,
     mlp, use_bias, pos, loops, norm_eps, norm_unit_offset, mixers,
-    leading_dense_layers, mla_q_rank and the moe_router group) are no axis of this lattice, every step
+    leading_dense_layers, mla_q_rank, the moe_router group, and an "attn" layer's attn_windows, rope_layers,
+    attn_qk_norm, attn_gate and embed_scale) are no axis of this lattice, every step
     builder takes them as it takes any tower, and the one axis whose builder
     re-implements the block (``pp``) refuses each by name in
     validate_pp_tower. What a block option excludes beside ``pp`` is stated
@@ -634,7 +635,8 @@ TOWER_EXCLUSIONS: tuple = (
 PP_REFUSES: tuple = (
     "mixers", "leading_dense_layers", "norm_eps", "moe_router", "moe_route_scale",
     "moe_shared_experts", "moe_hidden", "moe_experts_held", "mla_q_rank", "norm_unit_offset",
-    "moe_shared_hidden", "num_kv_heads", "head_dim", "sublayers",
+    "moe_shared_hidden", "num_kv_heads", "head_dim", "sublayers", "attn_windows", "rope_layers", "attn_qk_norm",
+    "attn_gate", "embed_scale",
 )
 
 
@@ -676,7 +678,7 @@ def tower_exclusion_drift() -> list[str]:
     if set(PP_REFUSES) - set(BLOCK_OPTIONS):
         drift.append(f"PP_REFUSES names no block option: {sorted(set(PP_REFUSES) - set(BLOCK_OPTIONS))}")
     changed = {"mixers": ("kda", "mla"), "moe_router": "sigmoid", "norm_eps": 1e-5, "moe_route_scale": 2.5,
-               "sublayers": "single"}
+               "sublayers": "single", "attn_windows": (4, 0), "rope_layers": "window", "embed_scale": 2.0}
     for name in PP_REFUSES:
         cfg = dc.replace(TextConfig.tiny_test(), scan_layers=True, **{name: changed.get(name, 1)})
         try:

@@ -8,7 +8,10 @@ blocks with a gated MLP, the stack run ``loops`` times on one set of weights, or
 a stack given layer by layer (``mixers``): several layer kinds with routed
 experts, windowed chunk attention in every layer, or layers of ONE sub-layer
 each (``sublayers="single"``: a state-space mixer, a grouped-head attention or
-a routed feed-forward part alone)."""
+a routed feed-forward part alone). An "attn" layer may take a window
+(``attn_windows``, layer by layer, beside full layers in one stack), rotate or
+not by its kind (``rope_layers``), norm the heads of q and k and gate its
+output; ``embed_scale`` scales the embedding."""
 
 from __future__ import annotations
 
@@ -33,16 +36,39 @@ SINGLE_LAYERS = ("ssm", "attn", "moe")
 def layer_specs(cfg: TextConfig) -> tuple[LayerSpec, ...]:
     """``Encoder``'s layers where the configuration gives the stack layer by
     layer (``mixers``, ``leading_dense_layers``, ``sublayers="single"``), asks
-    for the sigmoid-routed experts or gives ``Attention`` head sizes of its own
-    (``num_kv_heads``, ``head_dim``); none for the stack every tower had."""
+    for the sigmoid-routed experts or gives ``Attention`` head sizes or options
+    of its own (``num_kv_heads``, ``head_dim``, ``attn_windows``, ``rope_layers``,
+    ``attn_qk_norm``, ``attn_gate``); none for the stack every tower had."""
     single = cfg.sublayers == "single"
-    attn_sizes = tuple((k, v) for k, v in (("num_kv_heads", cfg.num_kv_heads), ("head_dim", cfg.head_dim)) if v)
-    if not (cfg.mixers or cfg.leading_dense_layers or cfg.moe_router != "softmax" or attn_sizes or single):
+    attn_sizes = tuple((k, v) for k, v in (
+        ("num_kv_heads", cfg.num_kv_heads), ("head_dim", cfg.head_dim), ("qk_norm", cfg.attn_qk_norm),
+        ("out_gate", cfg.attn_gate),
+    ) if v)
+    by_layer = bool(cfg.attn_windows) or cfg.rope_layers != "all"
+    if not (cfg.mixers or cfg.leading_dense_layers or cfg.moe_router != "softmax" or attn_sizes or single or by_layer):
         return ()
     if cfg.sublayers not in ("pair", "single"):
         raise ValueError(f"unknown sublayers: {cfg.sublayers!r}")
+    mixers = cfg.mixers or ("attn",) * cfg.depth
+    windows = cfg.attn_windows or (0,) * len(mixers)
+    refused = {
+        f"attn_windows={cfg.attn_windows} (one number a layer, depth={cfg.depth}; 0 = a full layer)":
+            len(windows) != len(mixers) or any(w < 0 for w in windows),
+        f"attn_windows={cfg.attn_windows} (a window is an 'attn' layer's) with mixers={mixers}":
+            any(w and m != "attn" for w, m in zip(windows, mixers)),
+        f"rope_layers={cfg.rope_layers!r} (want 'all' or 'window')": cfg.rope_layers not in ("all", "window"),
+        f"rope_layers={cfg.rope_layers!r} with pos={cfg.pos!r} (it says which layers pos='rope' rotates)":
+            cfg.rope_layers == "window" and cfg.pos != "rope",
+    }
+    if any(refused.values()):
+        raise ValueError("the text tower is not built for " + ", ".join(k for k, v in refused.items() if v))
+
+    def attn_fields(window):
+        """An "attn" layer's own: the tower's head sizes and options, its window, and whether it rotates."""
+        rotates = (("rope_theta", cfg.rope_theta if window else None),) if cfg.rope_layers == "window" else ()
+        return attn_sizes + ((("window", window),) if window else ()) + rotates
+
     mixer_fields = {
-        "attn": attn_sizes,
         "kda": (("head_dim", cfg.kda_head_dim), ("conv_size", cfg.kda_conv_size)),
         "mla": (
             ("nope_dim", cfg.mla_qk_nope_dim), ("shared_dim", cfg.mla_qk_shared_dim),
@@ -57,9 +83,8 @@ def layer_specs(cfg: TextConfig) -> tuple[LayerSpec, ...]:
             ("groups", cfg.ssm_groups), ("conv_size", cfg.ssm_conv_size), ("chunk", cfg.ssm_chunk),
         ),
     }
-    mixers = cfg.mixers or ("attn",) * cfg.depth
     # A pair layer takes the mixers that ran beside an MLP before; the state-space mixer is a layer alone.
-    known = set(SINGLE_LAYERS) if single else set(mixer_fields) - {"ssm"}
+    known = set(SINGLE_LAYERS) if single else {"attn"} | set(mixer_fields) - {"ssm"}
     if not set(mixers) <= known:
         raise ValueError(
             f"unknown mixer in mixers={mixers} with sublayers={cfg.sublayers!r}: want one of {sorted(known)} "
@@ -108,15 +133,16 @@ def layer_specs(cfg: TextConfig) -> tuple[LayerSpec, ...]:
             ("route_scale", cfg.moe_route_scale), ("shared_experts", cfg.moe_shared_experts),
             ("experts_held", cfg.moe_experts_held),
         ) + ((("shared_hidden", cfg.moe_shared_hidden),) if cfg.moe_shared_hidden else ())
+    fields = [attn_fields(w) if kind == "attn" else mixer_fields.get(kind) for kind, w in zip(mixers, windows)]
     if single:
         return tuple(
             LayerSpec("none", experts_fields=experts_fields) if kind == "moe"
-            else LayerSpec(kind, mixer_fields[kind], feed_forward=False)
-            for kind in mixers
+            else LayerSpec(kind, of_layer, feed_forward=False)
+            for kind, of_layer in zip(mixers, fields)
         )
     return tuple(
-        LayerSpec(kind, mixer_fields[kind], i < cfg.leading_dense_layers, experts_fields)
-        for i, kind in enumerate(mixers)
+        LayerSpec(kind, of_layer, i < cfg.leading_dense_layers, experts_fields)
+        for i, (kind, of_layer) in enumerate(zip(mixers, fields))
     )
 
 
@@ -136,7 +162,7 @@ class TextTransformer(nn.Module):
             embedding_init=nn.initializers.normal(stddev=0.02),
             name="token_embed",
         )(token_ids)
-        x = emb.astype(dtype)
+        x = emb.astype(dtype) if cfg.embed_scale == 1.0 else (emb * cfg.embed_scale).astype(dtype)
         if cfg.pos == "learned":
             pos = self.param(
                 "pos_embed",

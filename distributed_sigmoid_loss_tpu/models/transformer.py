@@ -37,6 +37,11 @@ ACCUM_SCOPE = "accum"
 # The program's name for the device time of ``Attention``'s single-device core: scores,
 # softmax and values, whichever kernel or XLA form runs them (benchmark/scopes_nemotron.py).
 ATTN_CORE_SCOPE = "attn_core"
+# The same core in a layer with a window (a causal band), so that a profile tells the two kinds of
+# layer of one stack apart, and the head norms and the sigmoid gate of a normed, gated
+# ``Attention`` (benchmark/scopes_trinity.py).
+WINDOW_CORE_SCOPE = "window_attn_core"
+ATTN_GATE_SCOPE = "attn_gate"
 
 
 def _dtype(name: str):
@@ -179,7 +184,8 @@ class LayerSpec:
     ``moe_experts > 0``. The default is the block every tower had."""
 
     mixer: str = "attn"  # "attn" | "kda" | "mla" | "eva" | "ssm" (models/mixers.py) | "none": no mixer
-    # (name, value) pairs of KdaMixer / LatentAttention / EvaAttention / SsmMixer, or Attention's own head sizes
+    # (name, value) pairs of KdaMixer / LatentAttention / EvaAttention / SsmMixer, or of Attention: its own
+    # head sizes, its window, whether it rotates (``rope_theta``), the head norms and the gate
     mixer_fields: tuple = ()
     dense_mlp: bool = False  # a leading layer keeps the dense MLP
     experts_fields: tuple = ()  # (name, value) pairs of SharedExpertMoe; none = MoeMlp
@@ -197,6 +203,33 @@ def rope_tables(s: int, dh: int, theta: float):
     return cos.astype(np.float32), sin.astype(np.float32)
 
 
+# Tables of more elements than this are made in the program (:func:`long_rope_tables`);
+# up to it they are constants of the trace. A constant lies in the serialized program
+# once for every place that reads it: at 8192 tokens and 128-wide heads two tables are
+# 8 MB, q's and k's in four layers 67 MB of the StableHLO, and with remat's forward and
+# the backward 0.27 GB of a 0.34 GB executable, which no 192 MiB compile cache keeps
+# (PERF.md section 6, PR 47). 2^18 is the longest any older cell reads (4096 x 64).
+ROPE_CONSTANT_ELEMENTS = 1 << 18
+_ROPE_STRIDE = 64
+
+
+def long_rope_tables(s: int, dh: int, theta: float):
+    """:func:`rope_tables` made in the program from two short constant ones, by the
+    angle of position 64 a + b being the sum of two: cos(A + B) = cos A cos B - sin A
+    sin B, sin(A + B) = sin A cos B + cos A sin B, A over positions 0, 64, 128, ..
+    and B over 0..63, each from float64 as there. (s, dh) float32, equal to
+    ``rope_tables`` to two roundings (2e-7)."""
+    tiled = lambda t: jnp.asarray(np.concatenate([t, t], -1), jnp.float32)  # noqa: E731
+    rate = 1.0 / theta ** (np.arange(0, dh, 2) / dh)
+    far, near = np.arange(0, s, _ROPE_STRIDE)[:, None] * rate, np.arange(_ROPE_STRIDE)[:, None] * rate
+    cos_a, sin_a = (tiled(f(far))[:, None, :] for f in (np.cos, np.sin))
+    cos_b, sin_b = (tiled(f(near))[None, :, :] for f in (np.cos, np.sin))
+    sign = jnp.asarray(np.repeat([-1.0, 1.0], dh // 2), jnp.float32)
+    cos = (cos_a * cos_b - sin_a * sin_b).reshape(-1, dh)[:s]
+    sin = ((sin_a * cos_b + cos_a * sin_b) * sign).reshape(-1, dh)[:s]
+    return cos, sin
+
+
 def rope(x, theta: float):
     """Rotary positions on a (b, s, h, dh) projection, rotate-half convention:
     with the head's lanes cut in halves (x1, x2) and angle[p, i] = p / theta^(2i/dh),
@@ -208,11 +241,13 @@ def rope(x, theta: float):
     elementwise pass in float32. Written as split / negate / concatenate, XLA
     moved the halves by relayouts and materialised the float32 copy: 187 ms of a
     1752 ms step at dh 128, s 256 (PERF.md section 6, PR 25). The tables are
-    constants of the trace."""
+    constants of the trace up to ``ROPE_CONSTANT_ELEMENTS`` and made in the
+    program past it."""
     s, dh = x.shape[1], x.shape[-1]
-    cos, sin = (t[None, :, None, :] for t in rope_tables(s, dh, theta))
+    tables = long_rope_tables if s * dh > ROPE_CONSTANT_ELEMENTS else rope_tables
     swap = jnp.asarray(np.roll(np.eye(dh), dh // 2, axis=0), x.dtype)
     with jax.named_scope("rope"):
+        cos, sin = (t[None, :, None, :] for t in tables(s, dh, theta))
         swapped = jax.lax.dot_general(
             x, swap, (((3,), (0,)), ((), ())),
             precision=jax.lax.Precision.HIGHEST,  # a float32 tower's permutation stays exact
@@ -273,7 +308,7 @@ class Mlp(nn.Module):
 
 
 def attention_core(attn_impl: str, dtype, tokens: int, heads: int, kv_heads: int, head_dim: int, causal: bool,
-                   self_attention: bool = True) -> dict:
+                   self_attention: bool = True, window: int = 0) -> dict:
     """Which single-device core an ``Attention`` call takes, from what it can
     see: ``"dense"`` (XLA einsum softmax), ``"short"`` (the VMEM-resident
     ``short_attn_*`` kernels, ops/pallas_short_attention.py), ``"flash"`` (the
@@ -291,13 +326,29 @@ def attention_core(attn_impl: str, dtype, tokens: int, heads: int, kv_heads: int
     False. Every other core takes one key and value head a query head, so
     grouped keys and values are repeated to that form first (``kv_repeated``
     True: the CPU, float32, a sequence the pair does not admit). ``block`` is
-    the tokens a block of the pair or the blocked kernel (None otherwise). The
+    the tokens a block of the pair or the blocked kernel (None otherwise).
+
+    A ``window`` (> 0: a causal band, query t reads keys t - window + 1 .. t)
+    shorter than the sequence has two cores: the pair, which visits the band's
+    block pairs only, wherever a fused core would run and the pair admits the
+    head size and the sequence, grouped heads or not; ``dense`` with the band as
+    a mask everywhere else. The short and the blocked kernels take no window, so
+    "auto" passes them by and ``attn_impl="flash"`` without the pair is refused
+    by name. A window of at least the sequence is the causal layer itself.
+    ``block_pairs`` and ``admitted_pairs`` count, from the shapes, the (query
+    block, key block) pairs the pair's loops visit for a head and the (query,
+    key) pairs a causal head's softmax admits (None where not causal). The
     module runs what this says and the step's trace-time record
-    (``train_step.stack_record_of``) reports it for grouped layers."""
+    (``train_step.stack_record_of``) reports it."""
     from distributed_sigmoid_loss_tpu.ops.flash_attention import flash_attention_available, flash_attention_plan
-    from distributed_sigmoid_loss_tpu.ops.pallas_latent_attention import latent_attention_plan
+    from distributed_sigmoid_loss_tpu.ops.pallas_latent_attention import (
+        admitted_pairs,
+        latent_attention_plan,
+        visited_block_pairs,
+    )
     from distributed_sigmoid_loss_tpu.ops.pallas_short_attention import short_attention_fits
 
+    window = window if 0 < window < tokens else 0
     if attn_impl == "flash" and not self_attention:
         raise ValueError(
             "attn_impl='flash' requires self-attention (the fused kernels "
@@ -318,8 +369,15 @@ def attention_core(attn_impl: str, dtype, tokens: int, heads: int, kv_heads: int
     )
     itemsize = jnp.dtype(dtype).itemsize
     grouped = kv_heads != heads
-    pair = latent_attention_plan(tokens, head_dim, head_dim, itemsize) if use_fused and grouped and causal else None
-    if not use_fused:
+    paired = use_fused and causal and (grouped or bool(window))
+    pair = latent_attention_plan(tokens, head_dim, head_dim, itemsize) if paired else None
+    if window and attn_impl == "flash" and pair is None:
+        raise ValueError(
+            f"attn_impl='flash' with window={window}: of the fused cores only the causal pair (mla_attn_fwd / "
+            f"mla_attn_bwd) takes a window, and it does not admit {tokens} tokens in heads of {head_dim} "
+            "(a head size in whole 128-lane registers, a head's sequence in VMEM); use 'auto' or 'dense'"
+        )
+    if not use_fused or (window and pair is None):
         core, block = "dense", None
     elif pair is not None:
         core, block = "kernel", pair["block"]
@@ -328,7 +386,9 @@ def attention_core(attn_impl: str, dtype, tokens: int, heads: int, kv_heads: int
     else:
         core, block = "flash", flash_attention_plan(tokens)["block"]
     return {"core": core, "block": block, "heads": heads, "kv_heads": kv_heads, "head_dim": head_dim,
-            "kv_repeated": grouped and core != "kernel"}
+            "kv_repeated": grouped and core != "kernel", "window": window or None,
+            "block_pairs": visited_block_pairs(pair["tokens"], block, window) if core == "kernel" else None,
+            "admitted_pairs": admitted_pairs(tokens, window) if causal and self_attention else None}
 
 
 class Attention(nn.Module):
@@ -360,6 +420,10 @@ class Attention(nn.Module):
     rope_theta: float | None = None  # rotary positions on q and k (see rope)
     num_kv_heads: int = 0  # 0 = num_heads
     head_dim: int = 0  # 0 = width // num_heads
+    window: int = 0  # > 0: a causal band, query t reads keys t - window + 1 .. t
+    qk_norm: bool = False  # RMSNorm over each head of q and of k, before the rotation
+    out_gate: bool = False  # sigmoid(x W_gate) times the heads' outputs, before ``out``
+    norm_eps: float = 1e-6  # the head norms'
 
     @nn.compact
     def __call__(self, x_q, x_kv=None):
@@ -376,6 +440,15 @@ class Attention(nn.Module):
                 f"(sequence_parallel_axis={self.sp_axis!r}) or cross-attention"
             )
         dg = _dot_general(self.quant)
+        refused = {
+            "causal=False": not self.causal, "cross-attention": not is_self_attention,
+            f"sequence_parallel_axis={self.sp_axis!r}": self.sp_axis is not None, f"quant={self.quant!r}": bool(self.quant),
+        }
+        if self.window < 0 or self.window and any(refused.values()):
+            raise ValueError(
+                f"window={self.window} (a causal band over one whole sequence, unquantised) is not built for "
+                + (", ".join(k for k, v in refused.items() if v) or "a negative number of keys")
+            )
         if self.rope_theta is not None and (self.sp_axis is not None or not is_self_attention):
             raise ValueError(
                 "pos='rope' numbers the positions 0..s-1 of one whole sequence: "
@@ -397,6 +470,10 @@ class Attention(nn.Module):
         # backward recompute is layernorm+gelu only).
         q, k, v = (checkpoint_name(t, n) for t, n in
                    ((split(q), "q_proj"), (split(k), "k_proj"), (split(v), "v_proj")))
+        if self.qk_norm:
+            with jax.named_scope(ATTN_GATE_SCOPE):
+                q = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="q_norm")(q)
+                k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="k_norm")(k)
         if self.rope_theta is not None:
             # Outside the attention kernel: one elementwise pass over q and k.
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
@@ -438,9 +515,10 @@ class Attention(nn.Module):
 
             sizes = attention_core(
                 self.attn_impl, self.dtype, q.shape[1], self.num_heads, kv_heads, head_dim, self.causal,
-                is_self_attention,
+                is_self_attention, window=self.window,
             )
-            with jax.named_scope(ATTN_CORE_SCOPE):
+            window = sizes["window"] or 0  # 0 where the window holds the whole sequence: the causal layer
+            with jax.named_scope(WINDOW_CORE_SCOPE if window else ATTN_CORE_SCOPE):
                 if sizes["core"] == "kernel":
                     # Grouped heads through the causal pair, the heads on the lanes: a group's
                     # key / value head is read where it lies, nothing is repeated in HBM.
@@ -449,7 +527,7 @@ class Attention(nn.Module):
 
                     def pair(q, k, v):  # a shard of whole key / value heads reads its heads off the widths
                         return latent_attention_kernel(
-                            q, k, v, head_dims=(head_dim, head_dim), kv_heads=k.shape[-1] // head_dim
+                            q, k, v, head_dims=(head_dim, head_dim), kv_heads=k.shape[-1] // head_dim, window=window
                         )
 
                     wide = (t.reshape(t.shape[:-2] + (-1,)) for t in (q, k, v))
@@ -459,7 +537,7 @@ class Attention(nn.Module):
                     if sizes["kv_repeated"]:
                         k, v = (jnp.repeat(t, self.num_heads // kv_heads, axis=-2) for t in (k, v))
                     kernel = {"dense": dense_attention, "short": short_self_attention, "flash": flash_self_attention}
-                    attend = partial(kernel[sizes["core"]], causal=self.causal)
+                    attend = partial(kernel[sizes["core"]], causal=self.causal, **({"window": window} if window else {}))
                     out = (
                         _fused_attention_per_shard(attend, q, k, v) if sizes["core"] != "dense"
                         else attend(q, k, v)
@@ -470,6 +548,10 @@ class Attention(nn.Module):
         # forward is never re-run.
         out = checkpoint_name(out, "attn_core")
         out = out.reshape(out.shape[:-2] + (inner,))
+        if self.out_gate:
+            gate = dense(inner, kernel_init=qkv_init, name="gate")(x_q)
+            with jax.named_scope(ATTN_GATE_SCOPE):
+                out = out * nn.sigmoid(gate)
         return dense(self.width, kernel_init=out_init, name="out")(out)
 
 
@@ -518,8 +600,9 @@ class Block(nn.Module):
                 self.width, self.num_heads, self.dtype,
                 sp_axis=self.sp_axis, sp_impl=self.sp_impl,
                 attn_impl=self.attn_impl, causal=self.causal,
-                quant=self.quant, use_bias=style.use_bias, rope_theta=style.rope_theta,
-                **dict(spec.mixer_fields), name="attn",
+                quant=self.quant, use_bias=style.use_bias, norm_eps=style.norm_eps,
+                # A layer's own fields come last: where only some layers rotate, each says whether it does.
+                **{"rope_theta": style.rope_theta, **dict(spec.mixer_fields)}, name="attn",
             )
         elif spec.mixer in ("kda", "mla"):
             from distributed_sigmoid_loss_tpu.models.mixers import KdaMixer, LatentAttention

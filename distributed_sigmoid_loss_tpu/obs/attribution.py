@@ -355,7 +355,13 @@ def mixed_stack_line(record: dict | None) -> str | None:
     for i, a in sorted(record.get("attn", {}).items()):
         blocks = "" if a["block"] is None else f", blocks of {a['block']} tokens"
         repeated = " (keys and values repeated)" if a["kv_repeated"] else ""
-        parts.append(f"attn[{i}] core={a['core']} {a['heads']}/{a['kv_heads']} heads of {a['head_dim']}{repeated}{blocks}")
+        if a["block_pairs"] is not None:
+            blocks += f", {a['block_pairs']} block pairs a head"
+        # A layer with a window or options of its own says so; Nemotron's line stays what it was.
+        window = f" window {a['window']}, {a['admitted_pairs']} pairs a head," if a["window"] else ""
+        options = "".join(f" {name}" for name in ("rotated", "qk_norm", "gated") if a[name])
+        parts.append(f"attn[{i}] core={a['core']}{window} {a['heads']}/{a['kv_heads']} heads of "
+                     f"{a['head_dim']}{repeated}{options}{blocks}")
     for i, k in sorted(record.get("kda_core", {}).items()):
         kept = f", {k['kept_bytes'] / 1e6:.0f} MB kept for the backward" if k["kept_bytes"] else ""
         parts.append(f"kda[{i}] core={k['core']} qk_norm={k['qk_norm']} o_norm={k['o_norm']}, {k['chunks']} chunks{kept}")

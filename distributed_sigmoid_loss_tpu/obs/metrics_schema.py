@@ -119,11 +119,15 @@ HEALTH_EVENT_FIELDS = frozenset({"metric", "step", "event", "detail"})
 # line: what the step is made of, from shapes alone, beside the counters above
 # that say what the routing did. Field -> meaning.
 STACK_RECORD_FIELDS = {
-    "layer_kinds": "each layer as mixer+mlp or mixer+moe, in order; a one-sub-layer layer as its one kind (ssm, attn, moe)",
+    "layer_kinds": "each layer as mixer+mlp or mixer+moe, in order; a one-sub-layer layer as its one kind (ssm, attn, moe); "
+                   "an attention layer with a window shorter than the sequence as window_attn",
     "ssm": "per state-space layer: the core it took (ops/ssm.py ssm_core: kernel or chunked), chunk, chunks a sequence, "
            "rows_per_pass, heads, groups, head_dim, state, and the bytes a differentiated call keeps (kept_bytes)",
-    "attn": "per attention layer with head sizes of its own: the core it took (dense / short / flash / kernel), block, "
-            "heads, kv_heads, head_dim, and whether grouped keys and values were repeated for it (kv_repeated)",
+    "attn": "per attention layer with head sizes or options of its own: the core it took (dense / short / flash / kernel), "
+            "block, heads, kv_heads, head_dim, whether grouped keys and values were repeated for it (kv_repeated), its "
+            "window (None: a full layer), the block pairs the kernel pair visits and the pairs the softmax admits for a "
+            "head (block_pairs, admitted_pairs: counts from shapes), and whether it rotates q and k (rotated), norms "
+            "their heads (qk_norm) and gates its output (gated)",
     "experts_held": "routed experts this chip holds",
     "experts_total": "routed experts the router scores",
     "experts_per_token": "experts a token chooses",

@@ -29,14 +29,20 @@ __all__ = ["ring_self_attention", "dense_attention"]
 _NEG_INF = -1e30
 
 
-def dense_attention(q, k, v, *, causal=False, scale=None):
-    """Reference single-device attention. q/k/v: (b, s, h, dh) → (b, s, h, dh)."""
+def dense_attention(q, k, v, *, causal=False, scale=None, window=0):
+    """Reference single-device attention. q/k/v: (b, s, h, dh) → (b, s, h, dh).
+    ``window`` > 0 (causal only) narrows the mask to a band: a query reads its
+    own key and the ``window`` - 1 before it."""
+    if window and not causal:
+        raise ValueError(f"window={window} is a causal band: causal=False is not built")
     dh = q.shape[-1]
     scale = (dh ** -0.5) if scale is None else scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         s_q, s_k = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
+        if window:
+            mask &= ~jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q - window)
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)

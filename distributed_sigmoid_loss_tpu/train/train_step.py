@@ -344,10 +344,13 @@ def stack_record_of(t, tokens_shape) -> dict:
     routed = [bool(t.moe_experts) and i >= t.leading_dense_layers for i in range(t.depth)]
     mixers = t.mixers or ("attn",) * t.depth
     tokens = math.prod(tokens_shape)
+    windows = t.attn_windows or (0,) * len(mixers)
+    # An attention layer with a window shorter than the sequence is a kind of its own.
+    named = [f"window_{m}" if 0 < w < tokens_shape[-1] else m for m, w in zip(mixers, windows)]
     record = {
         # A one-sub-layer layer is its one kind; a pair is mixer+mlp or mixer+moe.
-        "layer_kinds": list(mixers) if t.sublayers == "single"
-        else [f"{m}+{'moe' if r else 'mlp'}" for m, r in zip(mixers, routed)],
+        "layer_kinds": named if t.sublayers == "single"
+        else [f"{m}+{'moe' if r else 'mlp'}" for m, r in zip(named, routed)],
         "tokens_per_microbatch": tokens,
     }
     if t.moe_experts:
@@ -400,16 +403,23 @@ def stack_record_of(t, tokens_shape) -> dict:
         rows, length = tokens_shape
         core = ssm_core(rows, length, t.ssm_num_heads, t.ssm_head_dim, t.ssm_groups, t.ssm_state, t.dtype, t.ssm_chunk)
         record["ssm"] = {i: dict(core) for i, m in enumerate(mixers) if m == "ssm"}
-    if "attn" in mixers and (t.num_kv_heads or t.head_dim):
+    if "attn" in mixers and (t.num_kv_heads or t.head_dim or any(windows) or t.attn_qk_norm or t.attn_gate):
         from distributed_sigmoid_loss_tpu.models.transformer import _dtype, attention_core
 
         # By the rule Attention's call runs by: the core each attention layer with head
-        # sizes of its own takes, and whether grouped keys and values are repeated for it.
-        sizes = attention_core(
-            t.attn_impl, _dtype(t.dtype), tokens_shape[-1], t.num_heads, t.num_kv_heads or t.num_heads,
-            t.head_dim or t.width // t.num_heads, t.causal,
-        )
-        record["attn"] = {i: dict(sizes) for i, m in enumerate(mixers) if m == "attn"}
+        # sizes or options of its own takes, whether grouped keys and values are repeated
+        # for it, its window (None: a full layer), the block pairs the kernel pair visits
+        # and the pairs the softmax admits for a head, whether it rotates q and k, norms
+        # their heads and gates its output.
+        def made_of(window):
+            sizes = attention_core(
+                t.attn_impl, _dtype(t.dtype), tokens_shape[-1], t.num_heads, t.num_kv_heads or t.num_heads,
+                t.head_dim or t.width // t.num_heads, t.causal, window=window,
+            )
+            rotated = t.pos == "rope" and (t.rope_layers == "all" or bool(window))
+            return {**sizes, "rotated": rotated, "qk_norm": t.attn_qk_norm, "gated": t.attn_gate}
+
+        record["attn"] = {i: made_of(w) for i, (m, w) in enumerate(zip(mixers, windows)) if m == "attn"}
     if "eva" in mixers:
         from distributed_sigmoid_loss_tpu.models.mixers import eva_attention_core
         from distributed_sigmoid_loss_tpu.models.text import layer_specs
@@ -737,20 +747,27 @@ def init_params(
     )(rng)
     text = getattr(getattr(model, "cfg", None), "text", None)
     if getattr(text, "moe_balanced_init", False):
-        params = balance_routers(rng, model, params, tokens[0], mesh, unboxed_shardings)
+        ids = sample_batch["tokens"]
+        if not hasattr(ids, "__array__"):  # shapes alone: uniform ids stand in
+            ids = jax.random.randint(jax.random.fold_in(rng, 1), tokens[0], 0, text.vocab_size, jnp.int32)
+        params = balance_routers(model, params, ids, mesh, unboxed_shardings)
     return params
 
 
-def balance_routers(rng: jax.Array, model: nn.Module, params: Any, tokens_shape, mesh: Mesh, shardings: Any) -> Any:
+def balance_routers(model: nn.Module, params: Any, ids, mesh: Mesh, shardings: Any) -> Any:
     """``params`` with every sigmoid router's selection bias set so that its
     experts are chosen evenly (``TextConfig.moe_balanced_init``): one forward
-    pass of the text tower over uniform token ids of ``tokens_shape``, in which
-    each routed layer, in its turn, finds its bias from the tokens that reach it
+    pass of the text tower over the token ids ``ids``, in which each routed
+    layer, in its turn, finds its bias from the tokens that reach it
     (models/moe.py balanced_select_bias) and routes by it, so a later layer
-    balances on what the balanced earlier ones hand it. The ids are drawn from
-    ``rng``; the bias follows the weights, which route every id's token nearly
-    alike, and another batch of such ids then loads each expert to a few percent
-    of the same count."""
+    balances on what the balanced earlier ones hand it. ``init_params`` hands it
+    the sample batch's own ids where the batch carries them (the trainer's first
+    batch: the routers start balanced on the data) and uniform ids drawn from its
+    key where it carries shapes. How far the balance holds on ANOTHER batch is the
+    stack's: where every token's router input is its own (Nemotron's), to a few
+    percent of the same count; where a norm after each attention hands a
+    sequence's tokens a common part (sandwich norms at random weights), a
+    sequence loads single experts several times over (PERF.md section 6, PR 47)."""
     from flax import traverse_util
 
     from distributed_sigmoid_loss_tpu.models.moe import BALANCE
@@ -761,10 +778,8 @@ def balance_routers(rng: jax.Array, model: nn.Module, params: Any, tokens_shape,
             f"moe_balanced_init=True sets the selection bias of sigmoid-routed layers: "
             f"moe_router={text.moe_router!r}, moe_experts={text.moe_experts} has none"
         )
-    vocab = text.vocab_size
 
-    def balanced(params, key):
-        ids = jax.random.randint(key, tokens_shape, 0, vocab, jnp.int32)
+    def balanced(params, ids):
         with trace_on(mesh):
             _, sown = model.apply({"params": params}, ids, method="encode_text", mutable=[BALANCE])
         flat = traverse_util.flatten_dict(params)
@@ -772,7 +787,7 @@ def balance_routers(rng: jax.Array, model: nn.Module, params: Any, tokens_shape,
             flat[path] = bias
         return traverse_util.unflatten_dict(flat)
 
-    return jax.jit(balanced, out_shardings=shardings, donate_argnums=0)(params, jax.random.fold_in(rng, 1))
+    return jax.jit(balanced, out_shardings=shardings, donate_argnums=0)(params, ids)
 
 
 def create_train_state(

@@ -241,8 +241,9 @@ class TextConfig:
     # experts' kind); 0 = ``moe_shared_experts`` x ``moe_hidden``.
     moe_shared_hidden: int = 0
     # True: initialisation ends by setting every sigmoid router's selection bias
-    # to where the recipe's balancing update holds a trained router: on a batch of
-    # uniform token ids every expert is chosen by tokens x ``moe_num_selected`` /
+    # to where the recipe's balancing update holds a trained router: on the sample
+    # batch's token ids (uniform ids where ``create_train_state`` is given shapes
+    # alone) every expert is chosen by tokens x ``moe_num_selected`` /
     # ``moe_experts`` tokens (train/train_step.py balance_routers). False leaves
     # the bias zero: routers drawn at random route as unevenly as they fall. The
     # step is the same either way.
@@ -253,6 +254,22 @@ class TextConfig:
     # projections are width -> heads x head_dim and back).
     num_kv_heads: int = 0
     head_dim: int = 0
+    # Each layer's window, one number a layer (empty = none anywhere): 0 = a full
+    # causal layer; w > 0 = a window layer, whose query t reads keys t - w + 1 .. t
+    # (w keys, the token itself among them). "attn" layers of a causal tower only.
+    attn_windows: tuple[int, ...] = ()
+    # Which "attn" layers ``pos="rope"`` rotates: "all", or "window": the layers
+    # with a window alone, a full layer then taking no position at all.
+    rope_layers: Literal["all", "window"] = "all"
+    # An "attn" layer's two options: an RMSNorm (``norm_eps``) over the ``head_dim``
+    # lanes of every head of q and of k, one scale each shared by the heads, before
+    # the rotation; and a gate, a fifth projection width -> heads x head_dim whose
+    # sigmoid multiplies the heads' outputs before the output projection.
+    attn_qk_norm: bool = False
+    attn_gate: bool = False
+    # The token embedding's output times this, before the first layer (muP:
+    # sqrt(width)).
+    embed_scale: float = 1.0
     # "pair" = every layer is a token mixer AND a feed-forward part, each under
     # its own norm (every block so far). "single" = every layer is ONE sub-layer,
     # x + f(norm(x)) under one norm: ``mixers`` then names each layer's f, a
@@ -275,6 +292,7 @@ class TextConfig:
     def __post_init__(self):
         # A configuration file gives a list; modules hash their configuration.
         object.__setattr__(self, "mixers", tuple(self.mixers))
+        object.__setattr__(self, "attn_windows", tuple(self.attn_windows))
 
     @classmethod
     def base(cls, **kw) -> "TextConfig":
@@ -297,7 +315,8 @@ BLOCK_OPTIONS = {
     "leading_dense_layers": 0, "moe_router": "softmax", "moe_route_scale": 1.0,
     "moe_shared_experts": 0, "moe_hidden": 0, "moe_experts_held": 0, "mla_q_rank": 0,
     "norm_unit_offset": False, "moe_shared_hidden": 0, "num_kv_heads": 0, "head_dim": 0,
-    "sublayers": "pair",
+    "sublayers": "pair", "attn_windows": (), "rope_layers": "all", "attn_qk_norm": False, "attn_gate": False,
+    "embed_scale": 1.0,
 }
 
 

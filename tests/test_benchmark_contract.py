@@ -18,7 +18,7 @@ if BENCH_DIR not in sys.path:
 FILES = (
     "test_manifest", "test_flops", "test_flops_looped", "test_scopes", "test_flops_kimi", "test_scopes_kimi",
     "test_flops_glm", "test_scopes_glm", "test_setup_record", "test_flops_eva", "test_scopes_eva",
-    "test_flops_nemotron", "test_scopes_nemotron",
+    "test_flops_nemotron", "test_scopes_nemotron", "test_flops_trinity", "test_scopes_trinity",
 )
 
 

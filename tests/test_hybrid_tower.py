@@ -348,7 +348,10 @@ NEW_OPTIONS = dict(mixers=("kda", "mla"), leading_dense_layers=1, norm_eps=1e-5,
                    moe_route_scale=2.446, moe_shared_experts=1, moe_hidden=24, moe_experts_held=4, mla_q_rank=8,
                    norm_unit_offset=True,  # an RMSNorm stored as an offset from 1 (tests/test_eva_tower.py)
                    # a one-sub-layer stack's, grouped heads' and a shared expert's own width (tests/test_nemotron_tower.py)
-                   sublayers="single", num_kv_heads=1, head_dim=8, moe_shared_hidden=40)
+                   sublayers="single", num_kv_heads=1, head_dim=8, moe_shared_hidden=40,
+                   # an attention layer's window, rotation by layer kind, head norms and gate, and the embedding's
+                   # scale (tests/test_trinity_tower.py)
+                   attn_windows=(4, 0), rope_layers="window", attn_qk_norm=True, attn_gate=True, embed_scale=2.0)
 
 
 @pytest.mark.parametrize("option", sorted(NEW_OPTIONS))

@@ -184,6 +184,36 @@ def test_the_pair_under_grouped_heads_compiles_for_a_v5e(one_chip, heads, kv_hea
     assert set(asked) == {"mla_attn_fwd", "mla_attn_bwd"} and max(asked.values()) <= plan["vmem_bytes"], (asked, plan)
 
 
+# The pair with a window (a causal band), as a window layer's ``Attention`` calls it: the window / full attention cell's
+# call (32 query heads over 4 key / value heads of 128 at 8192 tokens, 2048 keys a query: 70 of the 136 block pairs), a
+# window that is no whole number of blocks over a padded length, and a window inside one block.
+@pytest.mark.parametrize("heads, kv_heads, tokens, window", [(32, 4, 8192, 2048), (4, 2, 1300, 700), (4, 4, 1024, 100)],
+                         ids=["trinity-cell", "odd-window-padded-length", "window-inside-a-block"])
+def test_the_pair_with_a_window_compiles_for_a_v5e(one_chip, heads, kv_heads, tokens, window):
+    from distributed_sigmoid_loss_tpu.ops.pallas_latent_attention import visited_block_pairs
+
+    def of(h):
+        return jax.ShapeDtypeStruct((2, tokens, h * 128), jnp.bfloat16, sharding=one_chip)
+
+    args = (of(heads), of(kv_heads), of(kv_heads))
+    core = lambda q, k, v: latent_attention_kernel(q, k, v, head_dims=(128, 128), kv_heads=kv_heads, window=window)  # noqa: E731
+    forward = jax.jit(core).lower(*args).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1 and forward.out_info.shape == args[0].shape
+
+    def loss(*a):
+        return (core(*a).astype(jnp.float32) ** 2).sum()
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    both = jax.jit(grads).lower(*args).compile()
+    assert both.as_text().count("tpu_custom_call") == 2
+    assert [x.shape for x in both.out_info] == [a.shape for a in args]
+    asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
+    plan = latent_attention_plan(tokens, 128, 128)
+    assert set(asked) == {"mla_attn_fwd", "mla_attn_bwd"} and max(asked.values()) <= plan["vmem_bytes"], (asked, plan)
+    if tokens == 8192:  # the band's block pairs alone: what the cost estimate tells the scheduler
+        assert (visited_block_pairs(8192, 512, 2048), visited_block_pairs(8192, 512)) == (70, 136)
+
+
 # Windowed chunk attention's core as the mixer calls it, the heads on the lanes: the cell's call (32 heads of 128, 8192
 # tokens in windows of 2048, chunks of 16: 512 summaries a sequence) and a small one (two windows of 256).
 @pytest.mark.parametrize("heads, tokens, window", [(32, 8192, 2048), (2, 512, 256)], ids=["evabyte-cell", "two-windows"])

@@ -313,6 +313,9 @@ CHANGED = dict(
     norm_unit_offset=True,
     # a one-sub-layer stack's, grouped heads' and a shared expert's own width (tests/test_nemotron_tower.py)
     sublayers="single", num_kv_heads=1, head_dim=8, moe_shared_hidden=40,
+    # an attention layer's window, rotation by layer kind, head norms and gate, and the embedding's scale
+    # (tests/test_trinity_tower.py)
+    attn_windows=(4, 0), rope_layers="window", attn_qk_norm=True, attn_gate=True, embed_scale=2.0,
 )
 
 

@@ -807,7 +807,9 @@ def test_the_tower_through_the_train_step():
     assert set(record["ssm"]) == {0, 2} and record["ssm"][0] == {
         "core": "chunked", "chunk": 8, "chunks": 3, "rows_per_pass": 4, "heads": 4, "groups": 2, "head_dim": 8, "state": 16,
         "kept_bytes": 0}
-    assert record["attn"] == {3: {"core": "dense", "block": None, "heads": 4, "kv_heads": 2, "head_dim": 16, "kv_repeated": True}}
+    assert record["attn"] == {3: {"core": "dense", "block": None, "heads": 4, "kv_heads": 2, "head_dim": 16, "kv_repeated": True,
+                                  "window": None, "block_pairs": None, "admitted_pairs": 24 * 25 // 2, "rotated": False,
+                                  "qk_norm": False, "gated": False}}
     line = mixed_stack_line(record)
     assert line.startswith("stack: ssm moe ssm attn moe; ssm[0] core=chunked 3 chunks of 8, 4 heads of 8 in 2 groups, state 16")
     assert "attn[3] core=dense 4/2 heads of 16 (keys and values repeated)" in line
@@ -836,7 +838,9 @@ def test_the_record_of_the_cells_stack_on_a_tpu(monkeypatch):
     assert sorted(record["ssm"]) == [0, 2, 4] and record["ssm"][0] == {
         "core": "kernel", "chunk": 128, "chunks": 32, "rows_per_pass": 4, "heads": 64, "groups": 8, "head_dim": 64,
         "state": 128, "kept_bytes": 4 * 32 * 128 * 64 * 64 * 4}
-    assert record["attn"] == {5: {"core": "kernel", "block": 512, "heads": 32, "kv_heads": 2, "head_dim": 128, "kv_repeated": False}}
+    assert record["attn"] == {5: {"core": "kernel", "block": 512, "heads": 32, "kv_heads": 2, "head_dim": 128, "kv_repeated": False,
+                                  "window": None, "block_pairs": 36, "admitted_pairs": 4096 * 4097 // 2, "rotated": False,
+                                  "qk_norm": False, "gated": False}}
     assert (record["experts_held"], record["experts_total"], record["expected_local_assignments_per_token"]) == (8, 128, 0.375)
     assert record["tokens_per_microbatch"] == 16384 and record["dispatch_rows_bound"] == 16384 * 6
     line = mixed_stack_line(record)
