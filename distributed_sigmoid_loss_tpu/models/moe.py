@@ -293,6 +293,18 @@ class MoeMlp(nn.Module):
 # one (0.23-0.35 ms a block of 512 rows: PERF.md section 6, PR 45). With the token
 # on the leading axis a scattered row moves its own tiles. The additions and their
 # order are a 2-D sum's, bit for bit (tests/test_moe.py keeps that form as oracle).
+#
+# Outside the loops the plan indexes no array by a computed position. The chip
+# takes 7-10 ns an ELEMENT to gather from, or scatter-add into, a 1-D array at
+# computed places (0.5-1.35 ms for the T * k = 65536-131072 assignments) and
+# sorts the same elements, two operands carried along, in 0.06-0.14 ms (PERF.md
+# section 6, PR 48). So what the permutation must move, the sort moves: one stable
+# sort by expert carries each assignment's position and weight, a sort by position
+# takes the weights' cotangent back, the chosen scores are a masked sum over E
+# (one dense pass over (T, k, E), dense backwards too) and the segments' edges are
+# counted. The values and their places are the indexed form's, bit for bit, forward
+# and backward: no sum here ever adds two non-zero terms (tests/test_moe.py keeps
+# that form as oracle, and reads the jaxprs for a gather or a scatter).
 
 SELECT_BIAS = "select_bias"
 # The collection whose being mutable makes a pass the initialisation's balancing
@@ -301,7 +313,7 @@ SELECT_BIAS = "select_bias"
 # :func:`balanced_select_bias`, routes by it and sows it there.
 BALANCE = "balance"
 # The program's name for everything of the layer but the expert and shared
-# products: scores, selection, the sort, the gathers and scatters.
+# products: scores, selection, the sorts, the loops' row gathers and scatters.
 MOE_ROUTE_SCOPE = "moe_route"
 # Rows of one block of an expert's segment: a held expert of the cell sees 512
 # tokens a microbatch, so a balanced expert is one block and one pair of products.
@@ -339,27 +351,67 @@ def balanced_select_bias(scores, k: int, rounds: int = 20):
     return jax.lax.fori_loop(0, rounds, one_round, jnp.zeros((experts,), F32))
 
 
+def _chosen(scores, idx):
+    """``scores`` (T, E) at ``idx`` (T, k), as a masked sum over E: one term of
+    each sum is the score and the others are 0.0, so the sum is the score to the
+    bit; backwards a token's k cotangents go to k different experts, so nothing
+    is added there either. One fused pass over (T, k, E) and no gather (the note
+    above the layer). The barrier keeps the caller's sum over k out of the pass:
+    the compiler would fold the two into one sum over (k, E), which adds a token's
+    scores in another order (weights off in the last bit) and runs slower."""
+    experts = jnp.arange(scores.shape[-1], dtype=idx.dtype)
+    chosen = jnp.sum(jnp.where(idx[..., None] == experts, scores[:, None, :], 0.0), -1)
+    return jax.lax.optimization_barrier(chosen)
+
+
 def sigmoid_route(x, wr, select_bias, k: int, scale: float):
     """``(idx, weights)`` of ``(T, k)`` for tokens ``x`` (T, d): float32 scores
-    at full matmul precision, selection by score + bias, weights by score."""
+    at full matmul precision, selection by score + bias, weights by score (the
+    chosen scores by :func:`_chosen`, not by index)."""
     scores = router_scores(x, wr)
     _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias), k)
-    chosen = jnp.take_along_axis(scores, idx, -1)
+    chosen = _chosen(scores, idx)
     return idx, scale * chosen / jnp.sum(chosen, -1, keepdims=True)
+
+
+@jax.custom_vjp
+def _sort_by_key(key, values):
+    """``(sorted key, order, values[order])`` for ``key`` and ``values`` of
+    ``(n,)``, ``order`` the stable argsort: ONE sort that carries each element's
+    position and value along, so nothing is indexed by ``order`` afterwards."""
+    position = jnp.arange(key.shape[0], dtype=jnp.int32)
+    return jax.lax.sort((key, position, values), num_keys=1, is_stable=True)
+
+
+def _sort_by_key_fwd(key, values):
+    out = _sort_by_key(key, values)
+    return out, out[1]
+
+
+def _sort_by_key_bwd(order, cts):
+    """``order`` is a permutation: sorted by it, every cotangent is back at its
+    position, and none is added to another (what the sort's own rule, a gather
+    transposed, does by a scatter-add: the same values)."""
+    return None, jax.lax.sort((order, cts[2]), num_keys=1)[1]
+
+
+_sort_by_key.defvjp(_sort_by_key_fwd, _sort_by_key_bwd)
 
 
 def dispatch_plan(idx, weights, first: int, held: int):
     """The assignments to experts ``[first, first + held)`` sorted by expert:
     ``(token, row_weight, starts, counts)``, the first two ``(T * k,)`` (rows
     past ``starts[-1] + counts[-1]`` belong to absent experts), the last two
-    ``(held,)``: expert e's rows are ``starts[e] : starts[e] + counts[e]``."""
+    ``(held,)``: expert e's rows are ``starts[e] : starts[e] + counts[e]``.
+    The sort moves what the plan needs (:func:`_sort_by_key`) and the segments'
+    edges are counted, not searched: no array is indexed by a computed position."""
     k = idx.shape[-1]
     local = idx - first
     key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    edges = jnp.searchsorted(key[order], jnp.arange(held + 1, dtype=key.dtype))
+    sorted_key, order, row_weight = _sort_by_key(key, weights.reshape(-1))
+    edges = jnp.searchsorted(sorted_key, jnp.arange(held + 1, dtype=key.dtype), method="compare_all")
     return (
-        (order // k).astype(jnp.int32), weights.reshape(-1)[order],
+        (order // k).astype(jnp.int32), row_weight,
         edges[:-1].astype(jnp.int32), jnp.diff(edges).astype(jnp.int32),
     )
 

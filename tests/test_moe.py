@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from distributed_sigmoid_loss_tpu.analysis import jaxpr_audit
 from distributed_sigmoid_loss_tpu.models import moe as moe_lib
 from distributed_sigmoid_loss_tpu.models.moe import MoeMlp
 from distributed_sigmoid_loss_tpu.parallel.mesh import make_mesh
@@ -445,3 +446,187 @@ def test_the_sums_shape_follows_from_the_width_alone(d, tiles):
 
     found = list(scatters(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, stacks, plan[1]).jaxpr))
     assert found == [(t, tiles, 8, 128)] * 2, found  # y's and dx's; d_weight is written by slices
+
+
+# -- the sigmoid-routed layer's plan (sigmoid_route, dispatch_plan): nothing indexed ----
+#
+# The plan indexes no array by a computed position: the chosen scores are a masked
+# sum over E, one stable sort carries key, position and weight, the weights'
+# cotangent goes back by a sort by position, the segments' edges are counted. The
+# oracle below is the plan as it was before: take_along_axis, argsort, two 1-D
+# gathers and a searched edge, whose transposes are scatter-adds. The same values
+# at the same places and no addition of two non-zero terms anywhere: everything
+# is equal bit for bit.
+
+
+def _oracle_chosen(scores, idx):
+    return jnp.take_along_axis(scores, idx, -1)
+
+
+def _oracle_route(x, wr, select_bias, k, scale):
+    scores = moe_lib.router_scores(x, wr)
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias), k)
+    chosen = _oracle_chosen(scores, idx)
+    return idx, scale * chosen / jnp.sum(chosen, -1, keepdims=True)
+
+
+def _oracle_plan(idx, weights, first, held):
+    k = idx.shape[-1]
+    local = idx - first
+    key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    edges = jnp.searchsorted(key[order], jnp.arange(held + 1, dtype=key.dtype))
+    return (
+        (order // k).astype(jnp.int32), weights.reshape(-1)[order],
+        edges[:-1].astype(jnp.int32), jnp.diff(edges).astype(jnp.int32),
+    )
+
+
+# name: (T, k, E, first held, held, how the experts are chosen)
+PLAN_CASES = {
+    "small-e8": (24, 2, 8, 2, 4, "scores"),
+    "e64-of-0": (96, 4, 64, 0, 8, "scores"),
+    "e256-of-8": (64, 8, 256, 8, 8, "scores"),
+    "rows65536-e64": (16384, 4, 64, 0, 8, "scores"),
+    "all-held": (40, 3, 8, 0, 8, "scores"),
+    "ties": (48, 2, 8, 2, 4, "same"),  # every token chooses experts 3 and 6: one long run of equal keys a side
+    "no-row": (56, 3, 8, 2, 4, "never-4"),  # held expert 4 (local 2) is never chosen
+}
+
+
+@pytest.fixture(scope="module", params=list(PLAN_CASES), ids=list(PLAN_CASES))
+def plan_pair(request):
+    """``(got, want, counts)`` of one case: the plan and the cotangents of its
+    weights and of the scores, from models/moe.py and from the oracle."""
+    t, k, e, first, held, how = PLAN_CASES[request.param]
+    keys = jax.random.split(jax.random.key(t * k + e), 4)
+    scores = jax.nn.sigmoid(jax.random.normal(keys[0], (t, e)))
+    select = {"scores": scores, "same": jnp.zeros((e,)).at[jnp.asarray([3, 6])].set(1.0) + 0.0 * scores,
+              "never-4": scores.at[:, 4].set(-1.0)}[how]
+    _, idx = jax.lax.top_k(select, k)
+    d_row = jax.random.normal(keys[1], (t * k,))
+    d_chosen = jax.random.normal(keys[2], (t, k))
+
+    def both(chosen_of, plan_of):
+        def weights_of(scores):
+            chosen = chosen_of(scores, idx)
+            return 2.5 * chosen / jnp.sum(chosen, -1, keepdims=True)
+
+        weights, back_scores = jax.vjp(weights_of, scores)
+        _, back = jax.vjp(lambda w: plan_of(idx, w, first, held)[1], weights)
+        token, row_weight, starts, counts = plan_of(idx, weights, first, held)
+        (d_weights,) = back(d_row)
+        return {
+            "weights": weights, "token": token, "row_weight": row_weight, "starts": starts, "counts": counts,
+            "d_weights": d_weights, "d_scores": back_scores(d_weights)[0],
+            "chosen": chosen_of(scores, idx), "d_scores_of_chosen": jax.vjp(lambda s: chosen_of(s, idx), scores)[1](d_chosen)[0],
+        }
+
+    return both(moe_lib._chosen, moe_lib.dispatch_plan), both(_oracle_chosen, _oracle_plan), request.param
+
+
+@pytest.mark.parametrize("leaf", ["weights", "token", "row_weight", "starts", "counts", "d_weights", "d_scores",
+                                  "chosen", "d_scores_of_chosen"])
+def test_plan_is_the_argsort_and_gathers_bit_for_bit(plan_pair, leaf):
+    got, want, name = plan_pair
+    t, k, e, first, held, how = PLAN_CASES[name]
+    counts = np.asarray(want["counts"])
+    assert counts.sum() <= t * k and (held == e) == (counts.sum() == t * k)  # absent experts unless all are held
+    if how == "same":
+        assert counts.tolist() == [0, t, 0, 0]  # expert 3 of 2..5 by every token; expert 6 is absent
+    if how == "never-4":
+        assert counts[2] == 0 and counts[[0, 1, 3]].min() > 0
+    g, w = got[leaf], want[leaf]
+    assert g.dtype == w.dtype and g.shape == w.shape
+    assert float(jnp.abs(w).max()) > 0
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.fixture(scope="module", params=[(8, 2, "swiglu"), (8, 2, "relu2"), (64, 4, "swiglu"), (64, 4, "relu2"),
+                                        (256, 8, "swiglu"), (256, 8, "relu2")], ids=lambda p: f"e{p[0]}-k{p[1]}-{p[2]}")
+def layer_pair(request):
+    """``(got, want)``: a whole SharedExpertMoe's output and the gradients of
+    its parameters and its tokens, as the module is and with the oracle's route
+    and plan in their place. Experts 2..5 held of E: absent experts exist."""
+    e, k, kind = request.param
+    layer = moe_lib.SharedExpertMoe(
+        width=32, hidden=16, num_experts=e, num_selected=k, dtype=jnp.float32, route_scale=2.5,
+        shared_experts=1, experts_held=4, first_held=2, kind=kind,
+    )
+    keys = jax.random.split(jax.random.key(e), 3)
+    x = jax.random.normal(keys[0], (2, 40, 32))
+    params = layer.init(keys[1], x)["params"]
+    # a router that spreads its choices, so held experts get rows at every E
+    params = dict(params, router=jax.random.normal(keys[2], params["router"].shape))
+
+    def loss(params, x):
+        y, state = layer.apply({"params": params}, x, mutable=["intermediates"])
+        return jnp.sum(jnp.sin(y)), (y, state["intermediates"]["moe_load"][0]["tokens"])
+
+    def run():
+        (_, (y, load)), (d_params, dx) = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(params, x)
+        return {"y": y, "load": load, "dx": dx, **{f"d_{name}": g for name, g in d_params.items() if name != moe_lib.SELECT_BIAS}}
+
+    got = run()
+    patch = pytest.MonkeyPatch()
+    patch.setattr(moe_lib, "sigmoid_route", _oracle_route)
+    patch.setattr(moe_lib, "dispatch_plan", _oracle_plan)
+    try:
+        want = run()
+    finally:
+        patch.undo()
+    return got, want
+
+
+@pytest.mark.parametrize("leaf", ["y", "load", "dx", "d_router", "d_wi", "d_wo", "d_shared"])
+def test_layer_gradients_are_the_gathers_bit_for_bit(layer_pair, leaf):
+    got, want = layer_pair
+    assert got.keys() == want.keys()
+    if leaf == "load":
+        assert 0 < int(want["load"].sum()) < 80 * 8  # held experts have rows, absent ones too
+    for g, w in zip(jax.tree.leaves(got[leaf]), jax.tree.leaves(want[leaf]), strict=True):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert float(jnp.abs(w).max()) > 0
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in ``jaxpr`` and in whatever its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for _, inner in jaxpr_audit._sub_jaxprs(eqn.params):
+            yield from _primitives(inner)
+
+
+@pytest.mark.parametrize("k, e, first, held", [(2, 8, 2, 4), (4, 64, 0, 8), (6, 128, 0, 8), (8, 128, 0, 16), (8, 256, 0, 8)])
+def test_the_plan_indexes_nothing(k, e, first, held):
+    """No gather in sigmoid_route + dispatch_plan and no scatter in their VJP:
+    on the chip a 1-D gather or scatter-add costs 7-10 ns an ELEMENT, a sort of
+    the same elements a hundredth of that (the note above the layer in
+    models/moe.py), so neither may come back unnoticed. The oracle's form is
+    read the same way, so the walk is known to find them."""
+    t, d = 32, 16
+    x, wr, bias = jnp.ones((t, d)), jnp.ones((d, e)), jnp.zeros((e,))
+
+    def plan_of(route, plan):
+        def run(x, wr, bias):
+            idx, weights = route(x, wr, bias, k, 2.5)
+            return plan(idx, weights, first, held)
+        return run
+
+    def vjp_of(run):
+        def back(x, wr, bias, ct):
+            return jax.vjp(lambda x, wr: run(x, wr, bias)[1], x, wr)[1](ct)
+        return back
+
+    def indexed(fn, *args):
+        return sorted({p for p in _primitives(jax.make_jaxpr(fn)(*args).jaxpr) if p.startswith(("gather", "scatter", "dynamic"))})
+
+    ours = plan_of(moe_lib.sigmoid_route, moe_lib.dispatch_plan)
+    was = plan_of(_oracle_route, _oracle_plan)
+    ct = jnp.ones((t * k,))
+    assert indexed(was, x, wr, bias) == ["gather"] and indexed(vjp_of(was), x, wr, bias, ct) == ["gather", "scatter-add"]
+    assert indexed(ours, x, wr, bias) == []
+    assert indexed(vjp_of(ours), x, wr, bias, ct) == []
+    sorts = [p for p in _primitives(jax.make_jaxpr(vjp_of(ours))(x, wr, bias, ct).jaxpr) if p == "sort"]
+    assert len(sorts) == 2, sorts  # the plan's, and the one that takes the weights' cotangent back
