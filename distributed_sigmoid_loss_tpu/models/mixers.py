@@ -55,6 +55,11 @@ The state-space recurrence runs as ``ops/ssm.py ssm_scan`` has it, in the form `
 call sees: ``"kernel"`` (the Pallas pair ``ssd_fwd`` / ``ssd_bwd`` of ``ops/pallas_ssm.py``: bfloat16, a TPU, whole
 registers) or ``"chunked"`` (XLA); x', B, C and y stay (b, s, h x P) / (b, s, g x N) around either.
 
+Both recurrences' layers run ``silu(conv(.) + b)`` as ``ops/gated_delta_rule.py short_conv_silu`` has it, in the form
+``short_conv_core`` there names: ``"kernel"`` (the Pallas pair ``short_conv_fwd`` / ``short_conv_bwd`` of
+``ops/pallas_short_conv.py``: bfloat16, a TPU, the channels in whole 128-lane registers and the sequence in whole 16-row
+tiles; one pass over HBM each way) or ``"xla"``.
+
 Windowed chunk attention's core is one of two, by :func:`eva_attention_core`:
 ``"kernel"``, the Pallas pair ``eva_attn_fwd`` / ``eva_attn_bwd``
 (``ops/pallas_eva_attention.py``) on (b, s, h x d), or ``"dense"``, XLA on a
@@ -78,7 +83,7 @@ import numpy as np
 from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import (
     kernels_per_shard,
     normed_chunk_gated_delta_rule,
-    short_causal_conv,
+    short_conv_silu,
 )
 
 F32 = jnp.float32
@@ -111,7 +116,9 @@ def _dt_bias_init(key, shape, dtype=F32):
 
 class KdaMixer(nn.Module):
     """The gated delta-rule layer: ``num_heads`` heads with ``head_dim`` key and
-    value channels each, chunks of ``CHUNK`` tokens."""
+    value channels each, chunks of ``CHUNK`` tokens. The q, k and v branches'
+    convolution and silu are one ``short_conv_silu`` each (the Pallas pair
+    ``short_conv_fwd`` / ``short_conv_bwd`` in bf16 on a TPU, XLA elsewhere)."""
 
     width: int
     num_heads: int
@@ -135,7 +142,7 @@ class KdaMixer(nn.Module):
         def branch(name):
             y = dense(h * d, name=name)(x)
             taps = self.param(name + "_conv", conv_init, (self.conv_size, h * d), F32)
-            return nn.silu(short_causal_conv(y, taps))
+            return short_conv_silu(y, taps)
 
         # q and k go to the core raw, o comes back over its head's rms times the scale: the per-head
         # norms run where the core runs, and nothing here is viewed (b, s, h, d).
@@ -500,8 +507,10 @@ class SsmMixer(nn.Module):
     ``num_heads`` heads of ``head_dim`` channels in ``groups`` groups that share B
     and C of ``state`` channels, one fused input projection cut into the gate z,
     the convolved [x' | B | C] and dt, a causal depthwise convolution of
-    ``conv_size`` taps with a bias, the recurrence (``ops/ssm.py``), a gated
-    RMSNorm over each group's lanes, the output projection. ``A_log``,
+    ``conv_size`` taps with a bias (``short_conv_silu`` on each of x', B and C
+    with its columns of the taps and the bias: the Pallas pair ``short_conv_fwd``
+    / ``short_conv_bwd`` in bf16 on a TPU, XLA elsewhere), the recurrence
+    (``ops/ssm.py``), a gated RMSNorm over each group's lanes, the output projection. ``A_log``,
     ``dt_bias`` and ``D`` are (num_heads,) float32 leaves, initialised as
     Mamba-2's (a rate uniform in [1, 16), a step log-uniform in [1e-3, 1e-1],
     ones); the step is not clamped above."""
@@ -542,7 +551,7 @@ class SsmMixer(nn.Module):
             conv_bias = self.param("conv_bias", conv_init, (inner + 2 * shared,), F32)
             edges = (0, inner, inner + shared, inner + 2 * shared)
             xs, big_b, big_c = (
-                nn.silu(short_causal_conv(part, taps[:, a:b]) + conv_bias[a:b].astype(part.dtype))
+                short_conv_silu(part, taps[:, a:b], conv_bias[a:b])
                 for part, a, b in zip((xs, big_b, big_c), edges, edges[1:])
             )
         rate = -jnp.exp(self.param("A_log", _decay_rate_init, (h,), F32))

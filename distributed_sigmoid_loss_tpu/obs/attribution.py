@@ -324,7 +324,13 @@ def mixed_stack(step) -> dict | None:
     a sequence, rows a pass, heads, groups, head size, state and ``kept_bytes``;
     per attention layer with head sizes of its own (``attn``, by layer index)
     what ``models/transformer.py attention_core`` says: the core, the query and
-    key / value heads, the head size, whether keys and values were repeated. A
+    key / value heads, the head size, whether keys and values were repeated;
+    per delta-rule and state-space layer (``short_conv``, by layer index) one
+    entry for each width its short convolution runs at (a delta-rule layer's q,
+    k and v share one; a state-space layer has x' and B, C), as
+    ``ops/gated_delta_rule.py short_conv_core`` says: ``form`` (``"kernel"``:
+    the Pallas pair ``short_conv_fwd`` / ``short_conv_bwd``; ``"xla"``), the
+    ``channels`` and a program's ``tile`` of (tokens, channels), None in XLA. A
     stack of one-sub-layer layers (``TextConfig.sublayers="single"``) names
     each layer's one kind in ``layer_kinds`` ("ssm", "attn", "moe"). None for a
     step that has not traced yet or runs no such tower. :func:`mixed_stack_line` is the same on
@@ -365,6 +371,10 @@ def mixed_stack_line(record: dict | None) -> str | None:
     for i, k in sorted(record.get("kda_core", {}).items()):
         kept = f", {k['kept_bytes'] / 1e6:.0f} MB kept for the backward" if k["kept_bytes"] else ""
         parts.append(f"kda[{i}] core={k['core']} qk_norm={k['qk_norm']} o_norm={k['o_norm']}, {k['chunks']} chunks{kept}")
+    for i, convs in sorted(record.get("short_conv", {}).items()):
+        said = ", ".join(c["form"] + (f" {c['tile'][0]}x{c['tile'][1]}" if c["tile"] else "") + f" of {c['channels']}"
+                         for c in convs)
+        parts.append(f"conv[{i}] {said}")
     return "stack: " + "; ".join(parts)
 
 

@@ -137,6 +137,9 @@ STACK_RECORD_FIELDS = {
     "kda_core": "per delta-rule layer: the core it took (kernel / chunked), where its per-head norms ran "
                 "(qk_norm, o_norm: kernel / xla), rows, heads and chunks of a call, and the bytes a differentiated "
                 "call keeps from its forward to its backward (kept_bytes)",
+    "short_conv": "per delta-rule and state-space layer, one entry a width its short convolution runs at: the form it took "
+                  "(ops/gated_delta_rule.py short_conv_core: kernel, the Pallas pair short_conv_fwd / short_conv_bwd, or "
+                  "xla), the channels and a program's tile of (tokens, channels), None in XLA",
     "mla": "per latent-attention layer: what it is made of (the fields below)",
     "eva": "per windowed-chunk-attention layer: what it is made of (STACK_RECORD_EVA_FIELDS)",
     "scanned": "whether the text stack's like layers are one scanned stack (the accumulator then rides the layer loop)",

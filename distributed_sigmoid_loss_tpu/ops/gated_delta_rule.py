@@ -68,6 +68,12 @@ hundred passes over HBM a call (PERF.md section 6, PR 32 and 34: at 16 rows x
 1024 tokens x 32 heads 46.4 ms forward and 151.3 forward + backward where the
 kernels take 10.9 and 26.9). (All six levels as one masked batched product were
 tried there: fewer operations, 20 % slower, three times the generated code.)
+
+**The branches' short convolution** (:func:`short_conv_silu`, the state-space
+layer's too) takes the Pallas pair ``short_conv_fwd`` / ``short_conv_bwd``
+(ops/pallas_short_conv.py) by the same kind of rule, :func:`short_conv_core`:
+bfloat16 on a TPU, the channels a multiple of 128 and the sequence of 16; the
+XLA form, :func:`short_causal_conv` with the bias and ``silu`` after it, anywhere else.
 """
 
 from __future__ import annotations
@@ -80,7 +86,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "gated_delta_rule_recurrent", "chunk_gated_delta_rule", "normed_chunk_gated_delta_rule", "delta_rule_core",
-    "l2norm", "short_causal_conv", "kernels_per_shard",
+    "l2norm", "short_causal_conv", "short_conv_silu", "short_conv_core", "kernels_per_shard",
 ]
 
 F32 = jnp.float32
@@ -105,6 +111,45 @@ def short_causal_conv(x, kernel):
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     s = x.shape[1]
     return sum(padded[:, j : j + s] * kernel[j].astype(x.dtype) for j in range(taps))
+
+
+def short_conv_core(tokens: int, channels: int, taps: int, dtype) -> dict:
+    """Which form a call of :func:`short_conv_silu` takes, from what it can
+    see: ``form`` is ``"kernel"`` (the Pallas pair ``short_conv_fwd`` /
+    ``short_conv_bwd`` of ops/pallas_short_conv.py: bfloat16 operands, a TPU
+    backend, the channels a multiple of 128 lanes and the sequence of 16 rows,
+    as ``pallas_short_conv.short_conv_plan`` admits them) or ``"xla"``
+    (:func:`short_causal_conv`, the bias and ``silu`` as XLA operations);
+    ``tile`` is the (tokens, channels) block a program of the pair holds, None
+    on the XLA form. The mixers run what this says and the step's trace-time
+    record (``train_step.stack_record_of``) reports it."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention  # the towers' one question about the backend
+    from distributed_sigmoid_loss_tpu.ops.pallas_short_conv import short_conv_plan
+
+    plan = None
+    if jnp.dtype(dtype) == jnp.bfloat16 and flash_attention.flash_attention_available():
+        plan = short_conv_plan(tokens, channels, taps)
+    return {"form": "kernel" if plan else "xla", "channels": channels,
+            "tile": (plan["tokens"], plan["channels"]) if plan else None}
+
+
+def short_conv_silu(x, taps, bias=None):
+    """``silu(short_causal_conv(x, taps) + bias)``, the mixers' call: x (b, s,
+    channels), ``taps`` (k, channels) float32, ``bias`` (channels,) float32 or
+    None; returns x's dtype. Where :func:`short_conv_core` says ``"kernel"``
+    one Pallas kernel each way (x read once and y written once; backward x and
+    dy read once for dx and the taps' and the bias's float32 cotangents; the
+    shifted products summed in float32 and rounded once); anywhere else
+    (float32, the CPU, odd widths) the XLA operations, which round each shifted
+    product and the bias to x's dtype. Under a ``jit`` over a mesh the kernels
+    sit in a ``shard_map`` (:func:`kernels_per_shard`: rows over ``dp``, the
+    channels whole)."""
+    if short_conv_core(x.shape[1], x.shape[2], taps.shape[0], x.dtype)["form"] == "kernel":
+        from distributed_sigmoid_loss_tpu.ops import pallas_short_conv
+
+        return kernels_per_shard(lambda rows: pallas_short_conv.short_conv_kernel(rows, taps, bias), 1, x)
+    y = short_causal_conv(x, taps)
+    return jax.nn.silu(y if bias is None else y + bias.astype(x.dtype))
 
 
 def gated_delta_rule_recurrent(q, k, v, g, beta):

@@ -364,7 +364,7 @@ def stack_record_of(t, tokens_shape) -> dict:
         })
     if "kda" in mixers:
         from distributed_sigmoid_loss_tpu.models.mixers import CHUNK
-        from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import delta_rule_core
+        from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import delta_rule_core, short_conv_core
 
         # By the rule KdaMixer's call runs by: which core each delta-rule layer takes
         # ("kernel" / "chunked"), where its per-head norms run ("kernel" / "xla") and
@@ -372,6 +372,10 @@ def stack_record_of(t, tokens_shape) -> dict:
         rows, length = tokens_shape
         core = delta_rule_core(rows, length, t.num_heads, t.kda_head_dim, t.kda_head_dim, t.dtype, CHUNK)
         record["kda_core"] = {i: dict(core) for i, m in enumerate(mixers) if m == "kda"}
+        # By the rule short_conv_silu runs by: the form the q, k and v branches' convolution takes
+        # ("kernel": the Pallas pair short_conv_fwd / short_conv_bwd; "xla") and a program's tile.
+        conv = short_conv_core(length, t.num_heads * t.kda_head_dim, t.kda_conv_size, t.dtype)
+        record["short_conv"] = {i: [dict(conv)] for i, m in enumerate(mixers) if m == "kda"}
     if "mla" in mixers:
         from distributed_sigmoid_loss_tpu.models.mixers import latent_attention_core
 
@@ -395,6 +399,7 @@ def stack_record_of(t, tokens_shape) -> dict:
         }
         record["mla"] = {i: dict(made_of) for i, m in enumerate(mixers) if m == "mla"}
     if "ssm" in mixers:
+        from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import short_conv_core
         from distributed_sigmoid_loss_tpu.ops.ssm import ssm_core
 
         # What SsmMixer's call runs (ops/ssm.py ssm_core, kernel or chunked): the core of each
@@ -403,6 +408,10 @@ def stack_record_of(t, tokens_shape) -> dict:
         rows, length = tokens_shape
         core = ssm_core(rows, length, t.ssm_num_heads, t.ssm_head_dim, t.ssm_groups, t.ssm_state, t.dtype, t.ssm_chunk)
         record["ssm"] = {i: dict(core) for i, m in enumerate(mixers) if m == "ssm"}
+        # The convolution's form and tile, as above, at its two widths: x', and B and C.
+        convs = [short_conv_core(length, width, t.ssm_conv_size, t.dtype)
+                 for width in (t.ssm_num_heads * t.ssm_head_dim, t.ssm_groups * t.ssm_state)]
+        record.setdefault("short_conv", {}).update({i: [dict(c) for c in convs] for i, m in enumerate(mixers) if m == "ssm"})
     if "attn" in mixers and (t.num_kv_heads or t.head_dim or any(windows) or t.attn_qk_norm or t.attn_gate):
         from distributed_sigmoid_loss_tpu.models.transformer import _dtype, attention_core
 

@@ -170,11 +170,12 @@ def test_the_kernels_take_any_batch_and_several_head_groups(rows, heads):
 def as_kernel_call(monkeypatch):
     """Send :func:`chunk_gated_delta_rule` down the kernel path on this CPU: the
     backend question answered as on a TPU, the kernels interpreted."""
-    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_delta_rule
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_delta_rule, pallas_short_conv
 
     interpreted = partial(pallas_delta_rule.delta_rule_kernel, interpret=True)
     monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
     monkeypatch.setattr(pallas_delta_rule, "delta_rule_kernel", interpreted)
+    monkeypatch.setattr(pallas_short_conv, "short_conv_kernel", partial(pallas_short_conv.short_conv_kernel, interpret=True))
 
 
 def test_a_sequence_that_is_no_multiple_of_the_chunk_through_the_kernels(monkeypatch):
@@ -525,8 +526,22 @@ def kda_layer_loss(tokens=70, heads=2, d=128):
     return (lambda p, x: (layer.apply({"params": p}, x).astype(jnp.float32) * weight).sum()), layer, params, x
 
 
-def test_a_delta_rule_layer_on_the_kernel_path_is_the_layer_on_the_chunked_path(monkeypatch):
-    loss, layer, params, x = kda_layer_loss()
+# 70 tokens: the branches' convolution stays XLA's (no whole 16-row tiles); 64: it runs its kernel pair too
+def kernel_call_sites(jaxpr) -> dict:
+    """How many ``pallas_call`` equations a jaxpr holds, by the kernel's name (a jit's body counts once a call)."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = found.get(eqn.params["name"], 0) + 1
+        for inner in inner_jaxprs(eqn):
+            for name, n in kernel_call_sites(inner).items():
+                found[name] = found.get(name, 0) + n
+    return found
+
+
+@pytest.mark.parametrize("tokens, convolutions", [(70, 0), (64, 3)], ids=["odd-sequence", "convolution-kernels-too"])
+def test_a_delta_rule_layer_on_the_kernel_path_is_the_layer_on_the_chunked_path(monkeypatch, tokens, convolutions):
+    loss, layer, params, x = kda_layer_loss(tokens)
 
     def run():  # fresh functions a call: each trace asks the backend question again
         out = jax.jit(lambda p, x: layer.apply({"params": p}, x))(params, x)
@@ -535,6 +550,9 @@ def test_a_delta_rule_layer_on_the_kernel_path_is_the_layer_on_the_chunked_path(
     want_out, want, want_x = run()
     as_kernel_call(monkeypatch)
     got_out, got, got_x = run()
+    traced = jax.make_jaxpr(jax.grad(lambda p, x: loss(p, x), argnums=(0, 1)))(params, x)
+    pair = {"short_conv_fwd": convolutions, "short_conv_bwd": convolutions} if convolutions else {}  # of q, k and v
+    assert kernel_call_sites(traced.jaxpr) == {"kda_fwd": 1, "kda_bwd": 1, **pair}
     assert got_out.dtype == jnp.bfloat16 and not np.array_equal(got_out, want_out)  # another path did run
     assert reference_kimi._base.max_rel_err(got_out, want_out) < 1.5e-2
     errs = reference_kimi.tree_max_rel_err(got, want)
@@ -624,6 +642,184 @@ def test_the_steps_record_names_the_core_by_the_same_rule(monkeypatch):
     assert stack_record_of(float32, (3, 100))["kda_core"] == {
         0: {"core": "chunked", "qk_norm": "xla", "o_norm": "xla", "rows": 3, "heads": 2, "chunks": 2, "kept_bytes": 0}}
     assert "kda_core" not in stack_record_of(TextConfig(depth=2, moe_experts=4, moe_router="sigmoid"), (4, 8))
+
+
+# -- (a4) the branches' short convolution through its kernel pair (ops/pallas_short_conv.py), interpreted ----
+
+
+def conv_operands(rows, tokens, channels, bias, taps=4, seed=11):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    x = (2 * jax.random.normal(keys[0], (rows, tokens, channels))).astype(jnp.bfloat16)
+    d_y = jax.random.normal(keys[1], (rows, tokens, channels)).astype(jnp.bfloat16)
+    w = jax.random.uniform(keys[2], (taps, channels), jnp.float32, -0.5, 0.5)
+    return x, w, (jax.random.uniform(keys[3], (channels,), jnp.float32, -0.5, 0.5) if bias else None), d_y
+
+
+def float32_form(x, w, bias):
+    """silu(short_causal_conv(x, taps) + bias) in float32 on the taps as the products take them, rounded to
+    bfloat16 (their cotangent passes the rounding unrounded)."""
+    from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import short_causal_conv
+
+    w = w + jax.lax.stop_gradient(w.astype(jnp.bfloat16).astype(jnp.float32) - w)
+    y = short_causal_conv(x.astype(jnp.float32), w)
+    return jax.nn.silu(y if bias is None else y + bias)
+
+
+def pair_and_form(monkeypatch, x, w, bias, d_y, rows=None, block_bytes=None, lanes=None):
+    """Value and the cotangents of (x, taps, bias) under ``d_y``, through the interpreted pair and the float32 form."""
+    from distributed_sigmoid_loss_tpu.ops import pallas_short_conv
+
+    for name, value in (("_ROWS", rows), ("_BLOCK_BYTES", block_bytes), ("_MAX_CHANNELS", lanes)):
+        if value:
+            monkeypatch.setattr(pallas_short_conv, name, value)
+    got, got_pull = jax.vjp(partial(pallas_short_conv.short_conv_kernel, interpret=True), x, w, bias)
+    want, want_pull = jax.vjp(float32_form, x.astype(jnp.float32), w, bias)  # x's cotangent in float32 too
+    return (got, *got_pull(d_y)), (want, *want_pull(d_y.astype(jnp.float32)))
+
+
+def assert_the_pair_is_the_form(got, want, x, w, bias):
+    y, d_x, d_w, d_bias = got
+    assert (y.shape, y.dtype, d_x.shape, d_x.dtype) == (x.shape, x.dtype, x.shape, x.dtype)
+    assert (d_w.shape, d_w.dtype) == (w.shape, jnp.float32)
+    # one rounding of a float32 sum: half a bfloat16 step of the value, at most 2^-8 of it
+    for name, g, t in (("y", y, want[0]), ("dx", d_x, want[1])):
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(t, np.float32), rtol=1.01 * 2**-8, atol=1e-6, err_msg=name)
+    np.testing.assert_allclose(d_w, want[2], rtol=1e-5, atol=1e-4, err_msg="taps")  # float32 sums in another order
+    if bias is None:
+        assert d_bias is None and want[3] is None
+    else:
+        assert d_bias.dtype == jnp.float32
+        np.testing.assert_allclose(d_bias, want[3], rtol=1e-5, atol=1e-4, err_msg="bias")
+
+
+@pytest.mark.parametrize("bias", [False, True], ids=["no-bias", "bias"])
+@pytest.mark.parametrize("tokens, rows, block", [(48, None, None), (128, 32, None), (128, 32, 64)],
+                         ids=["one-tile", "four-tiles", "two-blocks-of-two-tiles"])
+@pytest.mark.parametrize("channels, lanes", [(128, None), (256, None), (256, 128)],
+                         ids=["one-column", "two-columns", "two-channel-blocks"])
+def test_the_short_convolutions_pair_is_the_float32_form_forward_and_in_its_cotangents(monkeypatch, channels, lanes, tokens,
+                                                                                       rows, block, bias):
+    """y, dx, the taps' and the bias's cotangents (none where there is no bias): whatever the tiles a column is walked
+    in, the blocks a sequence is cut into (a tile's neighbour rows come in registers, a block's as blocks of their
+    own) and the columns and channel blocks side by side."""
+    from distributed_sigmoid_loss_tpu.ops.pallas_short_conv import short_conv_plan
+
+    x, w, b, d_y = conv_operands(2, tokens, channels, bias)
+    got, want = pair_and_form(monkeypatch, x, w, b, d_y, rows, block and block * (lanes or channels) * 2, lanes)
+    assert short_conv_plan(tokens, channels, 4) == {"tokens": block or tokens, "channels": lanes or channels, "rows": rows or tokens}
+    assert_the_pair_is_the_form(got, want, x, w, b)
+
+
+@pytest.mark.parametrize("taps", [2, 4, 7])
+def test_nothing_lies_before_a_sequence_and_nothing_after_it(monkeypatch, taps):
+    """The first taps - 1 tokens of y see zeros before them, the last taps - 1 of dx zeros after them, in every batch
+    row and at every block's edge: batch rows whose neighbours in memory are large do not see them, and a token's y
+    is its own x times the last tap where everything before it is zero."""
+    x, w, b, d_y = conv_operands(3, 64, 128, True, taps=taps)
+    x = x.at[0, -8:].set(300.0).at[2, :8].set(-300.0)  # what row 1 would see of its neighbours
+    (y, d_x, d_w, d_b), want = pair_and_form(monkeypatch, x, w, b, d_y, rows=16, block_bytes=32 * 128 * 2)
+    assert_the_pair_is_the_form((y, d_x, d_w, d_b), want, x, w, b)
+    rounded = w.astype(jnp.bfloat16).astype(jnp.float32)
+    first = jax.nn.silu(x[:, 0].astype(jnp.float32) * rounded[-1] + b)  # token 0: its own product alone
+    np.testing.assert_allclose(np.asarray(y[:, 0], np.float32), first, rtol=1.01 * 2**-8, atol=1e-6)
+    # the last token's x reaches its own pre alone: dx = taps[-1] dy silu'(pre)
+    alone = jax.vjp(lambda x: float32_form(x, w, b), x.astype(jnp.float32))[1](d_y.astype(jnp.float32).at[:, :-1].set(0.0))[0]
+    np.testing.assert_allclose(np.asarray(d_x[:, -1], np.float32), alone[:, -1], rtol=1.01 * 2**-8, atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype, tpu, tokens, channels, taps, form", [
+    (jnp.bfloat16, True, 1024, 4096, 4, "kernel"),  # the delta-rule cell's call
+    (jnp.bfloat16, True, 4096, 1024, 4, "kernel"),  # the state-space cell's B and C
+    (jnp.bfloat16, True, 48, 128, 2, "kernel"),
+    (jnp.float32, True, 1024, 4096, 4, "xla"),  # float32 is XLA's
+    (jnp.bfloat16, False, 1024, 4096, 4, "xla"),  # no TPU
+    (jnp.bfloat16, True, 1024, 4000, 4, "xla"),  # a width off the 128 lanes
+    (jnp.bfloat16, True, 70, 4096, 4, "xla"),  # a sequence off the 16-row tiles
+    (jnp.bfloat16, True, 1024, 4096, 9, "xla"),  # taps that reach past a register's eight rows
+], ids=["kimi", "nemotron-bc", "small", "float32", "cpu", "odd-width", "odd-sequence", "nine-taps"])
+def test_which_form_the_short_convolution_takes_follows_from_what_the_call_can_see(monkeypatch, dtype, tpu, tokens, channels,
+                                                                                  taps, form):
+    """No flag: dtype, backend and shape decide, as for the cores; and the XLA form is the expression the mixers
+    had, bit for bit."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_short_conv
+    from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import short_causal_conv, short_conv_core, short_conv_silu
+
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: tpu)
+    core = short_conv_core(tokens, channels, taps, dtype)
+    assert core["form"] == form and core["channels"] == channels and (core["tile"] is None) == (form == "xla")
+    if form == "kernel":
+        assert core["tile"] == {1024: (1024, 512), 4096: (2048, 512), 48: (48, 128)}[tokens]
+    taken = []
+    monkeypatch.setattr(pallas_short_conv, "short_conv_kernel", lambda x, *a, **kw: taken.append("kernel") or x)
+    x, w, b, _ = conv_operands(1, min(tokens, 96) if tokens % 16 == 0 else tokens, channels, True, taps=taps)
+    y = short_conv_silu(x.astype(dtype), w, b)
+    assert taken == (["kernel"] if form == "kernel" else [])
+    if form == "xla":
+        assert "pallas_call" not in str(jax.make_jaxpr(short_conv_silu)(x.astype(dtype), w, b))
+        was = nn.silu(short_causal_conv(x.astype(dtype), w) + b.astype(dtype))
+        assert y.dtype == dtype and np.array_equal(np.asarray(y, np.float32), np.asarray(was, np.float32))
+        bare = short_conv_silu(x.astype(dtype), w)
+        assert np.array_equal(np.asarray(bare, np.float32), np.asarray(nn.silu(short_causal_conv(x.astype(dtype), w)), np.float32))
+
+
+def test_the_convolutions_pair_sits_in_a_shard_map_under_a_jit_over_several_chips(monkeypatch):
+    """Rows over ``dp``, the channels, the taps and the bias whole: the same values and cotangents, the taps' and the
+    bias's summed over the chips."""
+    import contextlib
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_sigmoid_loss_tpu.ops import pallas_short_conv
+    from distributed_sigmoid_loss_tpu.ops.gated_delta_rule import short_conv_silu
+    from distributed_sigmoid_loss_tpu.parallel.mesh import make_mesh, trace_on
+
+    seen, real = [], pallas_short_conv.short_conv_kernel
+    as_kernel_call(monkeypatch)
+    monkeypatch.setattr(pallas_short_conv, "short_conv_kernel",
+                        lambda x, *a, **kw: seen.append(x.shape) or real(x, *a, interpret=True, **kw))
+    mesh = make_mesh(2)
+    x, w, b, d_y = conv_operands(4, 32, 128, True)
+    x = jax.device_put(x, NamedSharding(mesh, P("dp")))
+
+    def grads(on_mesh):
+        def loss(x, w, b):
+            with trace_on(mesh) if on_mesh else contextlib.nullcontext():
+                return (short_conv_silu(x, w, b).astype(jnp.float32) * d_y.astype(jnp.float32)).sum()
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(x, w, b)
+
+    want, want_grads = grads(False)
+    assert set(seen) == {(4, 32, 128)}
+    seen.clear()
+    got, got_grads = grads(True)
+    assert set(seen) == {(2, 32, 128)}  # a chip's rows
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for name, g, t in zip(("x", "taps", "bias"), got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(t, np.float32), rtol=1e-5, atol=1e-5, err_msg=name)
+    assert got_grads[0].sharding.spec == P("dp")
+
+
+@pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "tpu"])
+def test_the_steps_record_names_the_convolutions_form_by_the_same_rule(monkeypatch, tpu):
+    """Per delta-rule layer the form its q, k and v branches' convolution takes and a program's tile: "xla" on this
+    CPU, the pair in bf16 on a TPU, and the line `train` prints says so."""
+    from distributed_sigmoid_loss_tpu.obs.attribution import mixed_stack_line
+    from distributed_sigmoid_loss_tpu.obs.metrics_schema import STACK_RECORD_FIELDS
+    from distributed_sigmoid_loss_tpu.ops import flash_attention
+    from distributed_sigmoid_loss_tpu.train.train_step import stack_record_of
+    from distributed_sigmoid_loss_tpu.utils.config import TextConfig
+
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: tpu)
+    cell = TextConfig(width=256, depth=3, num_heads=32, mixers=("kda", "mla", "kda"), pos="none", dtype="bfloat16",
+                      moe_experts=4, moe_router="sigmoid", kda_head_dim=128)
+    record = stack_record_of(cell, (16, 1024))
+    assert set(record) <= set(STACK_RECORD_FIELDS)
+    entry = {"form": "kernel", "channels": 4096, "tile": (1024, 512)} if tpu else {"form": "xla", "channels": 4096, "tile": None}
+    assert record["short_conv"] == {0: [entry], 2: [entry]}
+    assert ("conv[2] kernel 1024x512 of 4096" if tpu else "conv[2] xla of 4096") in mixed_stack_line(record)
+    float32 = stack_record_of(TextConfig(width=256, depth=1, num_heads=2, mixers=("kda",), pos="none", dtype="float32",
+                                         moe_experts=4, moe_router="sigmoid"), (3, 100))
+    assert float32["short_conv"] == {0: [{"form": "xla", "channels": 2 * 128, "tile": None}]}
+    assert "short_conv" not in stack_record_of(TextConfig(depth=2, moe_experts=4, moe_router="sigmoid"), (4, 8))
 
 
 # -- (b) latent attention ----------------------------------------------------------

@@ -1,5 +1,5 @@
 """The delta-rule kernels, latent attention's kernel pair, windowed chunk
-attention's and the state-space recurrence's compiled by the chip's own compiler
+attention's, the state-space recurrence's and the short convolution's compiled by the chip's own compiler
 for a TPU v5e that is described and not attached, at the cells' widths: what
 Mosaic refuses (a slice off the tiling, too much VMEM) the interpreter accepts,
 so the CPU tests of tests/test_hybrid_layers.py and tests/test_nemotron_tower.py
@@ -266,6 +266,44 @@ def test_the_state_space_kernel_pair_compiles_for_a_v5e(one_chip, rows, tokens, 
     assert [(x.shape, x.dtype) for x in both.out_info] == [(a.shape, a.dtype) for a in args]
     asked = dict(vmem_asked(jax.make_jaxpr(grads)(*args).jaxpr))
     assert set(asked) == {"ssd_fwd", "ssd_bwd"} and max(asked.values()) < MOSAIC_VMEM_LIMIT // 2, asked
+
+
+# The mixers' short convolution with its bias and silu as ``short_conv_silu`` calls it: the delta-rule cell's branches (16
+# rows of 1024 tokens, 4096 channels, no bias: a program holds the whole sequence of 512 lanes), the state-space cell's x' and
+# its B and C (4 rows of 4096 tokens, 4096 and 1024 channels, with their bias: 2048 tokens of 512 lanes, so a block's
+# neighbour rows come in as blocks of their own), and a small odd call (three 16-row tiles of 384 lanes, two taps). The
+# sublane rotations that make the shifted copies and the tiles' dynamic starts are what the interpreter would accept anyway.
+@pytest.mark.parametrize("shape, taps, bias, tile", [
+    ((16, 1024, 4096), 4, False, (1024, 512)), ((4, 4096, 4096), 4, True, (2048, 512)), ((4, 4096, 1024), 4, True, (2048, 512)),
+    ((2, 48, 384), 2, True, (48, 384))], ids=["kimi-branch", "nemotron-x", "nemotron-b-c", "small-odd"])
+def test_the_short_convolutions_kernel_pair_compiles_for_a_v5e(one_chip, shape, taps, bias, tile):
+    from distributed_sigmoid_loss_tpu.ops.pallas_short_conv import _VMEM_LIMIT, short_conv_kernel, short_conv_plan
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    plan = short_conv_plan(shape[1], shape[2], taps)
+    assert (plan["tokens"], plan["channels"]) == tile
+    args = (of(shape, jnp.bfloat16), of((taps, shape[2]), jnp.float32)) + ((of(shape[2:], jnp.float32),) if bias else ())
+    core = lambda x, w, b=None: short_conv_kernel(x, w, b)  # noqa: E731
+    forward = jax.jit(core).lower(*args).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1
+    assert (forward.out_info.shape, forward.out_info.dtype) == (shape, jnp.bfloat16)
+
+    def loss(*a):
+        return (core(*a).astype(jnp.float32) ** 2).sum()
+
+    grads = jax.grad(loss, argnums=tuple(range(len(args))))
+    both = jax.jit(grads).lower(*args).compile()
+    assert both.as_text().count("tpu_custom_call") == 2  # short_conv_fwd for the loss's y, short_conv_bwd
+    assert [(x.shape, x.dtype) for x in both.out_info] == [(a.shape, a.dtype) for a in args]
+    traced = jax.make_jaxpr(grads)(*args).jaxpr
+    asked = dict(vmem_asked(traced))
+    assert set(asked) == {"short_conv_fwd", "short_conv_bwd"} and max(asked.values()) < _VMEM_LIMIT // 2, asked
+    # x, its sixteen rows before the block and after it, dy and its sixteen after; the taps (and the bias); dx and the sums
+    blocks = block_shapes(traced)["short_conv_bwd"]
+    assert blocks == [(1, *tile), (1, 16, tile[1]), (1, 16, tile[1]), (1, *tile), (1, 16, tile[1]), (taps, tile[1]),
+                      *([(1, tile[1])] if bias else []), (1, *tile), (1, 8, tile[1])]
 
 
 # The routed layers' two loops (models/moe.py routed_experts and its backward) at the three cells' calls: 16384 tokens a

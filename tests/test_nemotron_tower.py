@@ -215,8 +215,8 @@ def test_the_kernel_pair_without_decay_is_a_running_sum():
 
 @pytest.fixture()
 def kernel_path_on_the_cpu(monkeypatch):
-    """``ssm_scan`` as on a TPU, its kernels in the interpreter; the shapes they were given."""
-    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_ssm
+    """``ssm_scan`` as on a TPU, its kernels (and the convolution's) in the interpreter; the shapes they were given."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention, pallas_short_conv, pallas_ssm
 
     seen, real = [], pallas_ssm.ssd_kernel
 
@@ -226,6 +226,7 @@ def kernel_path_on_the_cpu(monkeypatch):
 
     monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: True)
     monkeypatch.setattr(pallas_ssm, "ssd_kernel", interpreted)
+    monkeypatch.setattr(pallas_short_conv, "short_conv_kernel", partial(pallas_short_conv.short_conv_kernel, interpret=True))
     return seen
 
 
@@ -356,6 +357,54 @@ def test_the_state_space_layer_matches_the_reference():
     for leaf in ("conv_bias", "D", "norm", "dt_bias", "A_log"):
         other = layer.apply({"params": {**params, leaf: params[leaf] + 0.3}}, x)
         assert float(jnp.abs(other - got).max()) > 1e-3, leaf
+
+
+def pallas_calls(jaxpr, under="") -> list:
+    """(kernel name, name stack) of every ``pallas_call`` of a jaxpr, through the bodies of jits and custom rules
+    (an inner jaxpr's stacks start where its equation's ends)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        scope = f"{under}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], scope))
+            continue
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                found += pallas_calls(inner, scope)
+    return found
+
+
+@pytest.mark.parametrize("tokens, kernels", [(32, 6), (24, 0)], ids=["whole-tiles", "odd-sequence"])
+def test_a_state_space_layers_convolution_through_its_kernel_pair_is_the_layer_with_xlas(kernel_path_on_the_cpu, monkeypatch,
+                                                                                        tokens, kernels):
+    """In bf16 on a TPU x', B and C (128 lanes each here) go through ``short_conv_fwd`` / ``short_conv_bwd`` with their
+    columns of the taps and the bias, under the ``ssm_conv`` scope; a sequence that is no whole 16-row tiles keeps
+    XLA's. (The recurrence stays chunked: these sizes are no whole registers.) Value, every leaf's gradient and x's."""
+    from distributed_sigmoid_loss_tpu.ops import flash_attention
+
+    layer = SsmMixer(width=32, num_heads=4, head_dim=32, state=64, groups=2, conv_size=4, chunk=8, dtype=jnp.bfloat16)
+    x = jax.random.normal(jax.random.key(0), (2, tokens, 32), jnp.bfloat16)
+    params = moved(layer.init(jax.random.key(1), x)["params"], scale=0.2)
+    weight = jax.random.normal(jax.random.key(2), (2, tokens, 32))
+
+    def run():
+        loss = lambda p, x: (layer.apply({"params": p}, x).astype(jnp.float32) * weight).sum()  # noqa: E731
+        return jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, x), *jax.value_and_grad(loss, (0, 1))(params, x)
+
+    traced, got, (got_p, got_x) = run()
+    calls = pallas_calls(traced.jaxpr)
+    assert kernel_path_on_the_cpu == []  # no ssd kernel
+    assert sorted(name for name, _ in calls) == ["short_conv_bwd"] * (kernels // 2) + ["short_conv_fwd"] * (kernels // 2)
+    assert all(SSM_CONV_SCOPE in scope for _, scope in calls)  # where ssm_conv_ms reads them
+    monkeypatch.setattr(flash_attention, "flash_attention_available", lambda: False)
+    traced, want, (want_p, want_x) = run()
+    assert "pallas_call" not in str(traced)
+    np.testing.assert_allclose(got, want, rtol=2e-2)
+    assert set(got_p) == set(want_p) and l2_error(got_x, want_x) < 2e-2
+    for leaf in ("conv", "conv_bias", "in_proj", "out", "dt_bias", "A_log", "D", "norm"):
+        g, w = (jax.tree.leaves(t[leaf])[0] for t in (got_p, want_p))
+        assert g.shape == w.shape and g.dtype == w.dtype and l2_error(g, w) < 3e-2, leaf
 
 
 def test_the_gated_norms_sums_over_a_groups_lanes_are_those_of_the_view():
@@ -810,8 +859,11 @@ def test_the_tower_through_the_train_step():
     assert record["attn"] == {3: {"core": "dense", "block": None, "heads": 4, "kv_heads": 2, "head_dim": 16, "kv_repeated": True,
                                   "window": None, "block_pairs": None, "admitted_pairs": 24 * 25 // 2, "rotated": False,
                                   "qk_norm": False, "gated": False}}
+    # on this CPU the convolution of x' (32 lanes) and of B and C (32) is XLA's in both state-space layers
+    assert record["short_conv"] == {i: [{"form": "xla", "channels": 32, "tile": None}] * 2 for i in (0, 2)}
     line = mixed_stack_line(record)
     assert line.startswith("stack: ssm moe ssm attn moe; ssm[0] core=chunked 3 chunks of 8, 4 heads of 8 in 2 groups, state 16")
+    assert "conv[2] xla of 32, xla of 32" in line
     assert "attn[3] core=dense 4/2 heads of 16 (keys and values repeated)" in line
     assert step._cache_size() == 1
 
@@ -847,6 +899,10 @@ def test_the_record_of_the_cells_stack_on_a_tpu(monkeypatch):
     assert ("ssm[4] core=kernel 32 chunks of 128, 64 heads of 64 in 8 groups, state 128, 4 rows a pass, "
             "268 MB kept for the backward") in line
     assert "attn[5] core=kernel 32/2 heads of 128, blocks of 512 tokens" in line
+    # the convolution of x' (4096 lanes) and of B and C (1024 each) takes its kernel pair in every state-space layer
+    conv = [{"form": "kernel", "channels": 4096, "tile": (2048, 512)}, {"form": "kernel", "channels": 1024, "tile": (2048, 512)}]
+    assert record["short_conv"] == {0: conv, 2: conv, 4: conv}
+    assert "conv[4] kernel 2048x512 of 4096, kernel 2048x512 of 1024" in line
     # the cells the benchmark had keep their records: no state-space or attention entry
     for other, shape in (("kimi-b16-p64-s1024", (16, 1024)), ("glm-b16-p16-s4096", (4, 4096))):
         assert not {"ssm", "attn"} & set(stack_record_of(cell_config(other)[1].text, shape))
